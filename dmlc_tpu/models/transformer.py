@@ -187,15 +187,7 @@ def _attention(x, p, positions, axes: ShardAxes):
     if axes.sp is not None:
         o = ring_attention(q, k, v, axis_name=axes.sp, causal=True)
     else:
-        from ..ops import flash_attention as _flash
-
-        if (jax.default_backend() == "tpu"
-                and _flash.supports(q.shape, k.shape)):
-            # single-chip MXU hot path: O(T) memory instead of the
-            # oracle's materialized [B,H,T,T] score matrix
-            o = _flash.flash_attention(q, k, v, causal=True)
-        else:
-            o = ring_attention_reference(q, k, v, causal=True)
+        o = _causal_attention(q, k, v)
     y = jnp.einsum("bthd,hde->bte", o, p["wo"])
     if axes.tp is not None:
         y = lax.psum(y, axes.tp)
@@ -461,15 +453,19 @@ def _rope_at(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def _prefill_attention(q, k, v):
-    """Causal full-sequence attention for prefill: the Pallas flash
-    kernel on TPU when shapes allow, the materialized oracle elsewhere
-    (same dispatch as the training path's unsharded branch)."""
+def _causal_attention(q, k, v):
+    """Causal full-sequence attention with the whole sequence on this
+    device (serving prefill; training without an sp axis): the Pallas
+    flash kernel — O(T) memory instead of a materialized [B,H,T,T]
+    score matrix — or the lax oracle, as ops/dispatch decides."""
+    from ..ops import dispatch
     from ..ops import flash_attention as _flash
 
-    if jax.default_backend() == "tpu" and _flash.supports(q.shape, k.shape):
-        return _flash.flash_attention(q, k, v, causal=True)
-    return ring_attention_reference(q, k, v, causal=True)
+    mode = dispatch.choose(_flash.supports(q.shape, k.shape))
+    if mode == dispatch.LAX:
+        return ring_attention_reference(q, k, v, causal=True)
+    return _flash.flash_attention(q, k, v, causal=True,
+                                  interpret=mode == dispatch.INTERPRET)
 
 
 def _cached_attention(q, k_new, v_new, k_cache, v_cache, lengths):
@@ -520,7 +516,7 @@ def _prefill_trunk(params, ids, cfg: TransformerConfig):
             v = jnp.einsum("bte,ehd->bthd", xn, p["wv"])
             q = rope(q, positions)
             k = rope(k, positions)
-            o = _prefill_attention(q, k, v)
+            o = _causal_attention(q, k, v)
             x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
             x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
             ks.append(k)

@@ -19,9 +19,9 @@ makes long-context training fit in HBM.  The ring-step
 (its (pv, m, l) outputs feed the ring combine, whose rescales cancel
 analytically).
 
-Falls back to the pure-lax path off-TPU or for unaligned head dims;
-interpret=True runs the kernels on CPU for tests.  Layout/tiling per
-/opt/skills/guides/pallas_guide.md.
+Whether a call site gets these kernels or the pure-lax reference is
+decided in ops/dispatch.py; interpret=True runs the kernels on CPU for
+tests.  Layout/tiling per /opt/skills/guides/pallas_guide.md.
 """
 
 from __future__ import annotations
@@ -40,6 +40,16 @@ _NEG_BIG = -1e30
 _POS_BIG = 1e30
 
 
+def _out_struct(shape, dtype, *operands):
+    """``out_shape`` entry for a ``pallas_call`` traced inside
+    ``jax.shard_map``: with ``check_vma=True`` (the train step's
+    default) the output must say which mesh axes it varies over, and it
+    varies over every axis any operand does.  Outside shard_map the
+    set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 # Kernel structure note (performance-critical): the KV/Q walk lives in
 # the GRID, not in an in-kernel fori_loop.  A loop whose trip count
 # depends on program_id lowers to an unpipelined while loop in Mosaic —
@@ -53,7 +63,7 @@ _POS_BIG = 1e30
 
 def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
             pv_ref, m_ref, l_ref, *, block_q: int, block_k: int,
-            causal: bool, kv_padded: bool, scale: float):
+            causal: bool, kv_padded: bool, scale: float, interpret: bool):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -114,9 +124,21 @@ def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.broadcast_to(m_new[..., None], (g, bq, 8))
         l_ref[...] = jnp.broadcast_to(l_new[..., None], (g, bq, 8))
 
-    _dispatch_masked_step(pl, step, qi, j, block_q, block_k, causal,
-                          kv_padded, kvend_ref, qoff=qoff_ref[0],
-                          kvoff=kvoff_ref[0])
+    def walk():
+        _dispatch_masked_step(pl, step, qi, j, block_q, block_k, causal,
+                              kv_padded, kvend_ref, qoff=qoff_ref[0],
+                              kvoff=kvoff_ref[0])
+
+    if interpret:
+        # The Pallas interpreter (jax 0.9.0) evaluates a kernel's
+        # top-level equations one by one under the caller's VMA check,
+        # where an offset that varies over a shard_map axis (the ring
+        # step) cannot meet a constant, but it takes a cond whole: an
+        # always-true pl.when lets the CPU tests drive this kernel
+        # inside the VMA-checked train step.  Compiled kernels skip it.
+        pl.when(j >= 0)(walk)
+    else:
+        walk()
 
 
 def supports(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...]) -> bool:
@@ -262,17 +284,20 @@ def _flash_forward(static, q, k, v, qoff, kvoff):
             pl.BlockSpec((g, block_q, 8), lambda bi, qi, kj, *_: (bi, qi, 0)),
         ],
     )
+    operands = (qoff, kvoff, kvend, qt, kt, vt)
     pv, m, l = pl.pallas_call(
         functools.partial(_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, kv_padded=kv_padded, scale=scale),
+                          causal=causal, kv_padded=kv_padded, scale=scale,
+                          interpret=bool(interpret)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tq_p, 8), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tq_p, 8), jnp.float32),
+            _out_struct((bh, tq_p, d), jnp.float32, *operands),
+            _out_struct((bh, tq_p, 8), jnp.float32, *operands),
+            _out_struct((bh, tq_p, 8), jnp.float32, *operands),
         ],
+        name="flash_fwd",
         interpret=interpret,
-    )(qoff, kvoff, kvend, qt, kt, vt)
+    )(*operands)
 
     pv = pv.reshape(b, h, tq_p, d).transpose(0, 2, 1, 3)[:, :tq]
     m = m[..., 0].reshape(b, h, tq_p)[:, :, :tq]
@@ -472,6 +497,7 @@ def _flash_backward(static, q, k, v, o, lse, do):
     q_of_q = pl.BlockSpec((1, block_q, d), lambda bi, kj, qi, *_: (bi, qi, 0))
     k_of_kv = pl.BlockSpec((1, block_k, d), lambda bi, kj, qi, *_: (bi, kj, 0))
     s_of_q = pl.BlockSpec((1, block_q, 8), lambda bi, kj, qi, *_: (bi, qi, 0))
+    operands = (kvend, qt, dot, kt, vt, lse8, delta8)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                           causal=causal, kv_padded=kv_padded, scale=scale),
@@ -482,8 +508,8 @@ def _flash_backward(static, q, k, v, o, lse, do):
             out_specs=[k_of_kv, k_of_kv],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tk_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tk_p, d), jnp.float32),
+            _out_struct((bh, tk_p, d), jnp.float32, *operands),
+            _out_struct((bh, tk_p, d), jnp.float32, *operands),
         ],
         # bh and the accumulator's home dim are independent; only the
         # innermost (accumulating) dim is order-dependent — measured
@@ -491,8 +517,9 @@ def _flash_backward(static, q, k, v, o, lse, do):
         # kernel regresses badly with the same hint, so it stays plain.)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_dkv",
         interpret=interpret,
-    )(kvend, qt, dot, kt, vt, lse8, delta8)
+    )(*operands)
 
     # dq grid (bh, q, kv): accumulator in the q-indexed output block
     q_of_q2 = pl.BlockSpec((1, block_q, d), lambda bi, qi, kj, *_: (bi, qi, 0))
@@ -508,11 +535,12 @@ def _flash_backward(static, q, k, v, o, lse, do):
                       s_of_q2],
             out_specs=q_of_q2,
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), jnp.float32),
+        out_shape=_out_struct((bh, tq_p, d), jnp.float32, *operands),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_dq",
         interpret=interpret,
-    )(kvend, qt, dot, kt, vt, lse8, delta8)
+    )(*operands)
 
     def unpack(x, t):
         return x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)[:, :t]

@@ -23,15 +23,30 @@ new tokens living at their real paged addresses instead of a dense
 tail.  Dead batch rows (length 0, table all zeros) read block 0 and
 produce garbage the engine never samples.
 
-Two implementations behind one dispatcher: a Pallas TPU kernel whose
-block-table indirection lives in the BlockSpec index map (the scalar-
-prefetched table picks which physical block each grid step DMAs — the
-PagedAttention trick), and a ``lax``-composed fallback (gather inside
-jit) that runs everywhere and is the parity oracle.  interpret=True
-runs the kernel on CPU for tests.  Layout/tiling per
-/opt/skills/guides/pallas_guide.md; grid/accumulator structure mirrors
-ops/flash_attention.py (KV walk in the grid, f32 accumulators in the
-revisited output blocks, predicated skip of fully-masked blocks).
+Two implementations: a Pallas TPU kernel whose block-table indirection
+lives in the BlockSpec index map (the scalar-prefetched table picks
+which physical block each grid step DMAs — the PagedAttention trick),
+and a ``lax``-composed reference (gather inside jit) that runs
+everywhere and is the parity oracle; ops/dispatch.py decides which a
+call site gets.  interpret=True runs the kernel on CPU for tests.
+Layout/tiling per /opt/skills/guides/pallas_guide.md; the step body
+mirrors ops/flash_attention.py's forward at G=1 (KV walk in the grid,
+f32 accumulators in the revisited output blocks, predicated skip of
+fully-masked blocks).
+
+Kernel layout.  The TPU lowering only takes blocks whose last two dims
+are tile multiples or the whole array dims, so a ``[bs, 1, D]`` slice
+of one head out of a ``[bs, H, D]`` page is not a legal block.  The
+kernel therefore takes WHOLE pages: the pool is viewed (free reshape)
+as ``[n_blocks, bs*H, D]`` and one grid step ``(b, j)`` multiplies all
+``H*S`` query rows of sequence ``b`` against all ``bs*H`` key rows of
+its j-th page in one 2-D matmul.  Query row ``(h, s)`` may only see
+key row ``(t, h')`` when ``h == h'`` and the position is in range;
+both conditions fold into ONE static int32 table ``pair[r, c] = t - s``
+(a huge value where the heads differ), compared against the scalar
+``lengths[b] - j*bs``.  The cross-head products are wasted MXU work
+(H-fold), which decode has to spare; the grid and its speed are
+ROADMAP A4.
 """
 
 from __future__ import annotations
@@ -41,18 +56,19 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+import numpy as np
 
 _NEG_BIG = -1e30
 
 __all__ = ["paged_attention", "supports"]
 
 
-def supports(head_dim: int, block_size: int) -> bool:
+def supports(head_dim: int, block_size: int, n_heads: int) -> bool:
     """Whether the Pallas kernel serves these shapes: the head dim must
-    fill whole 128-element lanes and the KV block whole 8-row sublanes
-    (f32 minimal tile); everything else is handled by padding."""
-    return head_dim % 128 == 0 and block_size % 8 == 0
+    fill whole 128-element lanes, and so must one page's ``bs*H`` key
+    rows (they are the score matrix's lane dim); the window is padded
+    to whole sublanes inside."""
+    return head_dim % 128 == 0 and (block_size * n_heads) % 128 == 0
 
 
 def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale):
@@ -78,12 +94,12 @@ def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale):
     return out.astype(q.dtype)
 
 
-def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, pv_ref, m_ref, l_ref,
-            *, bs: int, s_pad: int, s_real: int, scale: float):
+def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, pair_ref,
+            pv_ref, m_ref, l_ref, *, bs: int, s_real: int, scale: float):
     from jax.experimental import pallas as pl
 
     bi = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -92,35 +108,46 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, pv_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref[...])
 
     # the last pool position any window row of this sequence may
-    # attend; blocks entirely past it are predicated no-op visits
+    # attend; pages entirely past it are predicated no-op visits
     limit = len_ref[bi] + s_real - 1
 
     @pl.when(j * bs <= limit)
     def _step():
-        q = q_ref[0, 0]                                # [S_pad, D]
-        kb = k_ref[:, :, 0].reshape(bs, -1)            # [bs, D]
-        vb = v_ref[:, :, 0].reshape(bs, -1)
+        q = q_ref[...]                                 # [1, H*S_pad, D]
+        kb = k_ref[...]                                # [1, bs*H, D]
+        vb = v_ref[...]
         s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [S_pad, bs]
-        k_pos = j * bs + lax.broadcasted_iota(jnp.int32, (s_pad, bs), 1)
-        q_lim = len_ref[bi] + lax.broadcasted_iota(jnp.int32, (s_pad, bs), 0)
-        keep = k_pos <= q_lim
+            q, kb, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [1, rows, cols]
+        # same head AND key position j*bs + t <= lengths[b] + s
+        keep = pair_ref[...] <= len_ref[bi] - j * bs
         s = jnp.where(keep, s, _NEG_BIG)
-        m_old = m_ref[0, 0, :, 0]                      # [S_pad]
-        l_old = l_ref[0, 0, :, 0]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-        p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
+        m_old = m_ref[..., 0]                          # [1, rows]
+        l_old = l_ref[..., 0]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=2))
+        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
         corr = jnp.exp(m_old - m_new)
-        l_new = l_old * corr + jnp.sum(p, axis=1)
+        l_new = l_old * corr + jnp.sum(p, axis=2)
         pv = jax.lax.dot_general(
-            p, vb.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [S_pad, D]
-        pv_ref[0, 0] = pv_ref[0, 0] * corr[:, None] + pv
+            p, vb.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)        # [1, rows, D]
+        pv_ref[...] = pv_ref[...] * corr[..., None] + pv
         # per-row scalars broadcast over an 8-lane minor axis (Mosaic
         # lane tiling, same storage trick as flash_attention)
-        m_ref[0, 0] = jnp.broadcast_to(m_new[:, None], (s_pad, 8))
-        l_ref[0, 0] = jnp.broadcast_to(l_new[:, None], (s_pad, 8))
+        m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
+
+
+@functools.lru_cache(maxsize=8)  # read-only; one per (H, window, page)
+def _pair_table(h: int, s_pad: int, bs: int) -> np.ndarray:
+    """``[1, H*S_pad, bs*H]`` int32: ``t - s`` where query row
+    ``r = h*S_pad + s`` and key row ``c = t*H + h'`` share a head, a
+    value no length can reach where they do not."""
+    r = np.arange(h * s_pad)
+    c = np.arange(bs * h)
+    same = (r // s_pad)[:, None] == (c % h)[None, :]
+    diff = (c // h)[None, :] - (r % s_pad)[:, None]
+    return np.where(same, diff, 1 << 30).astype(np.int32)[None]
 
 
 def _pallas_paged_attention(q, k_pool, v_pool, block_tables, lengths,
@@ -130,46 +157,50 @@ def _pallas_paged_attention(q, k_pool, v_pool, block_tables, lengths,
 
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
-    bs = k_pool.shape[1]
+    n_blocks, bs = k_pool.shape[:2]
     s_pad = -(-s_w // 8) * 8  # window rows fill whole sublanes
+    rows, cols = h * s_pad, bs * h
     qt = jnp.transpose(q, (0, 2, 1, 3))                  # [B, H, S, D]
     if s_pad != s_w:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, s_pad - s_w), (0, 0)))
+    qt = qt.reshape(b, rows, d)
+    kf = k_pool.reshape(n_blocks, cols, d)               # row = t*H + h
+    vf = v_pool.reshape(n_blocks, cols, d)
     tbl = block_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
+    pair = jnp.asarray(_pair_table(h, s_pad, bs))
 
     # the paged indirection: the K/V index maps read the scalar-
-    # prefetched block table to pick which PHYSICAL block each grid
-    # step DMAs — the kernel walks row b's logical blocks j=0..W-1 but
+    # prefetched block table to pick which PHYSICAL page each grid
+    # step DMAs — the kernel walks row b's logical pages j=0..W-1 but
     # the pool is only ever touched at the table's addresses
-    q_spec = pl.BlockSpec((1, 1, s_pad, d),
-                          lambda bi, hi, j, tbl_, lens_: (bi, hi, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, 1, d),
-                           lambda bi, hi, j, tbl_, lens_:
-                           (tbl_[bi, j], 0, hi, 0))
-    acc_spec = pl.BlockSpec((1, 1, s_pad, d),
-                            lambda bi, hi, j, tbl_, lens_: (bi, hi, 0, 0))
-    ml_spec = pl.BlockSpec((1, 1, s_pad, 8),
-                           lambda bi, hi, j, tbl_, lens_: (bi, hi, 0, 0))
-    pv, m, l = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, s_pad=s_pad, s_real=s_w,
-                          scale=scale),
+    of_seq = lambda bi, j, tbl_, lens_: (bi, 0, 0)  # noqa: E731
+    q_spec = pl.BlockSpec((1, rows, d), of_seq)
+    kv_spec = pl.BlockSpec((1, cols, d),
+                           lambda bi, j, tbl_, lens_: (tbl_[bi, j], 0, 0))
+    pair_spec = pl.BlockSpec((1, rows, cols),
+                             lambda bi, j, tbl_, lens_: (0, 0, 0))
+    acc_spec = pl.BlockSpec((1, rows, d), of_seq)
+    ml_spec = pl.BlockSpec((1, rows, 8), of_seq)
+    pv, _, l = pl.pallas_call(
+        functools.partial(_kernel, bs=bs, s_real=s_w, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h, w),  # innermost block walk revisits (bi, hi)
-            in_specs=[q_spec, kv_spec, kv_spec],
+            grid=(b, w),  # innermost page walk revisits sequence bi
+            in_specs=[q_spec, kv_spec, kv_spec, pair_spec],
             out_specs=[acc_spec, ml_spec, ml_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, s_pad, 8), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, s_pad, 8), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, 8), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, 8), jnp.float32),
         ],
+        name="paged_attn",
         interpret=interpret,
-    )(tbl, lens, qt, k_pool, v_pool)
-    out = pv / jnp.maximum(l[..., :1], 1e-37)            # [B, H, S_pad, D]
-    out = jnp.transpose(out[:, :, :s_w], (0, 2, 1, 3))
-    return out.astype(q.dtype)
+    )(tbl, lens, qt, kf, vf, pair)
+    out = pv / jnp.maximum(l[..., :1], 1e-37)            # [B, rows, D]
+    out = out.reshape(b, h, s_pad, d)[:, :, :s_w]
+    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
@@ -178,21 +209,24 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
     """Window attention against one layer's paged KV pool.
 
     See the module docstring for shapes and the mask contract.  Returns
-    ``[B, S, H, D]`` in q's dtype.  ``impl``: "auto" picks the Pallas
-    kernel on TPU when :func:`supports` allows and the lax fallback
-    everywhere else; "pallas"/"lax" force a path (tests drive the
-    kernel on CPU with ``impl="pallas", interpret=True``).
+    ``[B, S, H, D]`` in q's dtype.  ``impl``: "auto" takes the Pallas
+    kernel or the lax reference as ops/dispatch decides (the kernel on
+    a TPU when :func:`supports` allows); "pallas"/"lax" force a path
+    (tests drive the kernel on CPU with ``impl="pallas"``, which runs
+    it under the interpreter there, as ``interpret=True`` always does).
     """
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / d ** 0.5
     if impl not in ("auto", "pallas", "lax"):
         raise ValueError(f"unknown paged-attention impl {impl!r}")
-    use_pallas = impl == "pallas" or (
-        impl == "auto" and jax.default_backend() == "tpu"
-        and supports(d, int(k_pool.shape[1])))
-    if use_pallas:
-        return _pallas_paged_attention(q, k_pool, v_pool, block_tables,
-                                       lengths, float(scale), interpret)
+    from . import dispatch
+
+    mode = dispatch.choose(
+        supports(d, int(k_pool.shape[1]), int(q.shape[2])), impl)
+    if mode != dispatch.LAX:
+        return _pallas_paged_attention(
+            q, k_pool, v_pool, block_tables, lengths, float(scale),
+            interpret or mode == dispatch.INTERPRET)
     return _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths,
                                 float(scale))

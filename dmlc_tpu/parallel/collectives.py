@@ -144,10 +144,7 @@ def match_vma(x, ref):
     varying from the start.  No-op outside shard_map / when already
     varying on ref's axes.
     """
-    try:
-        want = jax.typeof(ref).vma - jax.typeof(x).vma
-    except AttributeError:
-        return x
+    want = jax.typeof(ref).vma - jax.typeof(x).vma
     if not want:
         return x
     return lax.pcast(x, tuple(want), to="varying")

@@ -57,7 +57,7 @@ def pipeline_spmd(
     telemetry.observe("pipeline", "microbatches_per_run", float(m))
     # carries vary over the input's axes AND pp (my-dependent writes,
     # ppermuted state): match x's vma then add pp via `my`, which is
-    # already pp-varying — keeping match_vma's version-compat guard.
+    # already pp-varying.
     state0 = match_vma(match_vma(jnp.zeros_like(x_microbatches[0]), x_microbatches), my)
     outputs0 = match_vma(match_vma(jnp.zeros_like(x_microbatches), x_microbatches), my)
     perm_fwd = [(j, (j + 1) % n) for j in range(n)]

@@ -53,9 +53,9 @@ def ring_attention(
     touches the sequence dimension.
 
     ``impl``: 'auto' routes each ring step through the Pallas flash
-    kernel (ops/flash_attention) on TPU when the shapes pass its
-    alignment gate, pure-lax otherwise; 'flash' forces the kernel
-    (interpret mode off-TPU, for tests); 'lax' forces the fallback.
+    kernel (ops/flash_attention) or the pure-lax reference as
+    ops/dispatch decides; 'flash' forces the kernel (interpret mode
+    off-TPU, for tests); 'lax' forces the reference.
     """
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
@@ -64,6 +64,7 @@ def ring_attention(
         scale = 1.0 / (d ** 0.5)
 
     from .. import telemetry
+    from ..ops import dispatch
     from ..ops import flash_attention as _flash
 
     # trace-time accounting (the ring loop runs device-side): each of
@@ -82,19 +83,11 @@ def ring_attention(
                               int(kv_bytes), "impl": impl}):
         pass
 
-    interpret = False
-    if impl == "auto":
-        use_flash = (
-            jax.default_backend() == "tpu"
-            and _flash.supports(q.shape, k.shape)
-        )
-    elif impl == "flash":
-        use_flash = True
-        interpret = jax.default_backend() != "tpu"
-    elif impl == "lax":
-        use_flash = False
-    else:
+    if impl not in ("auto", "flash", "lax"):
         raise ValueError(f"unknown ring_attention impl {impl!r}")
+    mode = dispatch.choose(_flash.supports(q.shape, k.shape), impl)
+    use_flash = mode != dispatch.LAX
+    interpret = mode == dispatch.INTERPRET
 
     if n == 1 and use_flash:
         # degenerate ring (sp axis of size 1 — e.g. dp-only meshes): the

@@ -137,10 +137,12 @@ class _ProfiledJit:
     arguments (shape, dtype, weak_type — exactly what jit traces on)
     plus the values of the static arguments, compiles each fresh
     signature once through the AOT path, and dispatches cache hits
-    straight to the compiled executable.  Any AOT surprise (an
-    unlowerable transform, a sharding mismatch at call time) falls back
-    to the plain jit call and is counted, never raised — profiling must
-    not be able to break the model.
+    straight to the compiled executable.  A lowering or compile error
+    is the program's own and is raised (compiling a second time through
+    plain jit would only spend the same minutes on the same error); a
+    CALL-time surprise (an unhashable static argument, a committed-
+    sharding mismatch against the AOT executable) falls back to the
+    plain jit call and is counted in ``aot_fallbacks``.
     """
 
     def __init__(self, fn, *, site: str, static_argnums=(),
@@ -165,13 +167,16 @@ class _ProfiledJit:
         self.last_cost: Optional[Dict] = None
         self.last_signature: Optional[str] = None
         self._trace_times: deque = deque(maxlen=256)
-        # identity-keyed memo for REPEATED pytree arguments: serving
-        # passes the same params dict every call, and hashing its ~30
-        # leaves per step is pure dispatch tax.  Keyed on id() with a
-        # strong ref pinning the object (so the id cannot be reused),
-        # bounded, and only for container args (an ndarray can be
-        # mutated in place, a params pytree's leaf STRUCTURE cannot
-        # change shape without being a new tree in practice)
+        # identity memo for a REPEATED pytree argument: serving passes
+        # the same params dict every call, and hashing its ~30 leaves
+        # per step is pure dispatch tax.  One entry per argument
+        # position, holding the object itself (so its id cannot be
+        # reused) — and ONLY the latest one: a training loop hands in a
+        # fresh params tree every step, and remembering more than the
+        # last would pin every superseded copy of the weights in HBM.
+        # Dict args only (an ndarray can be mutated in place, a params
+        # pytree's leaf STRUCTURE cannot change shape without being a
+        # new tree in practice)
         # dmlc-check: unguarded(benign race: GIL-atomic dict ops; strong ref defeats id reuse)
         self._arg_sig_memo: Dict[int, Tuple[Any, Any]] = {}
         with _lock:
@@ -187,13 +192,12 @@ class _ProfiledJit:
             if i in self._static:
                 parts.append(("static", a))
             elif isinstance(a, dict):
-                memo = self._arg_sig_memo.get(id(a))
+                memo = self._arg_sig_memo.get(i)
                 if memo is not None and memo[0] is a:
                     parts.append(memo[1])
                     continue
                 part = self._tree_sig(a)
-                if len(self._arg_sig_memo) < 64:
-                    self._arg_sig_memo[id(a)] = (a, part)
+                self._arg_sig_memo[i] = (a, part)
                 parts.append(part)
             else:
                 parts.append(self._tree_sig(a))
@@ -236,12 +240,7 @@ class _ProfiledJit:
                     f"raise the cap")
             sig = _sig_text(key)
             t0 = time.perf_counter()
-            try:
-                compiled = self._jit.lower(*args).compile()
-            except Exception:  # noqa: BLE001 - AOT must not break the model
-                self.aot_fallbacks += 1
-                core.inc("compute", "aot_fallbacks")
-                compiled = None
+            compiled = self._jit.lower(*args).compile()
             t1 = time.perf_counter()
             self.traces += 1
             n_traces = self.traces
@@ -249,7 +248,7 @@ class _ProfiledJit:
             self._trace_times.append((time.time(), sig))
             self.compile_secs_total += t1 - t0
             self.last_signature = sig
-            cost = _extract_cost(compiled) if compiled is not None else None
+            cost = _extract_cost(compiled)
             self.last_cost = cost
             entry = (compiled, cost)
             self._cache[key] = entry
@@ -282,8 +281,6 @@ class _ProfiledJit:
         if entry is None:
             entry = self._compile(key, args)
         compiled, _cost = entry
-        if compiled is None:
-            return self._jit(*args)
         dyn = tuple(a for i, a in enumerate(args)
                     if i not in self._static)
         try:
@@ -324,17 +321,12 @@ class _ProfiledJit:
 
 
 def _extract_cost(compiled) -> Optional[Dict]:
-    """FLOPs / bytes-accessed from an executable's XLA cost analysis.
-
-    ``cost_analysis()`` returns a list of per-module dicts on current
-    jax (one module per jit) — tolerate both that and a bare dict, and
-    missing keys on exotic backends."""
+    """FLOPs / bytes-accessed from an executable's XLA cost analysis
+    (a dict; keys a backend does not report are left out)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 - optional backend feature
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     out = {}

@@ -92,6 +92,10 @@ METRIC_NAMES = frozenset({
     "dmlc_feed_resizes",
     "dmlc_feed_stage_stall_secs",
     "dmlc_feed_staging_pool_bytes",
+    # kernel-or-reference dispatch (ops/dispatch.py), once per trace
+    "dmlc_kernels_mosaic_traces",
+    "dmlc_kernels_interpret_traces",
+    "dmlc_kernels_lax_traces",
     # flash attention
     "dmlc_flash_fwd_calls",
     "dmlc_flash_fwd_flops",

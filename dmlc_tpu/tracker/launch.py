@@ -38,7 +38,7 @@ PASS_ENVS = [
     "OMP_NUM_THREADS", "LD_LIBRARY_PATH", "PYTHONPATH",
     "AWS_ACCESS_KEY_ID", "AWS_SECRET_ACCESS_KEY",
     "GOOGLE_APPLICATION_CREDENTIALS", "JAX_PLATFORMS", "XLA_FLAGS",
-    "TPU_WORKER_ID", "TPU_WORKER_HOSTNAMES",
+    "JAX_COMPILATION_CACHE_DIR", "TPU_WORKER_ID", "TPU_WORKER_HOSTNAMES",
     # -- registry pass_to_workers knobs (config_registry.py order) ----
     "DMLC_INTERFACE", "DMLC_FEED_WORKERS", "DMLC_FEED_DEPTH",
     "DMLC_FEED_AUTOTUNE", "DMLC_FEED_WORKERS_MIN",

@@ -43,6 +43,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 SEQ = 128
 VOCAB = 512
+DTYPE = "float32"  # this 256-wide demo model's, whatever it runs on
 
 
 def make_data(path, n_records=2048, seed=0):
@@ -230,6 +231,7 @@ def main():
     import optax
 
     from dmlc_tpu import metrics
+    from dmlc_tpu.compile_cache import place_compile_cache
     from dmlc_tpu.feed import recordio_feed
     from dmlc_tpu.models import (TransformerConfig, init_params,
                                  make_train_step, unsharded_loss)
@@ -237,6 +239,7 @@ def main():
     from dmlc_tpu.parallel.collectives import initialize_distributed
     from dmlc_tpu.tracker.client import WorldResized
 
+    place_compile_cache()
     elastic = _elastic_enabled()
     if not elastic:
         # under dmlc-submit with world > 1 this joins every launched
@@ -251,10 +254,7 @@ def main():
     mesh = build_mesh(n_dev, dp=n_dev, sp=1, tp=1, pp=1, ep=1)
     cfg = TransformerConfig(
         vocab=VOCAB, d_model=256, n_heads=4, head_dim=64, d_ff=512,
-        n_layers=4, n_experts=1, microbatches=1,
-        dtype="bfloat16" if jax.devices()[0].platform == "tpu"
-        else "float32",
-        remat=True)
+        n_layers=4, n_experts=1, microbatches=1, dtype=DTYPE, remat=True)
     params = init_params(jax.random.PRNGKey(0), cfg, n_stages=1)
     optimizer = optax.adamw(3e-4)
     if not elastic:

@@ -1,0 +1,165 @@
+"""What the chip will be asked to compile, checked without a chip.
+
+Tier-1 forces the CPU, where ops/dispatch.py never takes a kernel
+branch — so a kernel the TPU refuses, or one that cannot be traced the
+way ``make_train_step`` calls it, used to be invisible here.  Two
+guards:
+
+* every Pallas kernel is cross-lowered for the TPU from the CPU at the
+  flagship shapes ``chip_smoke.py`` runs.  This reaches the Pallas TPU
+  lowering (BlockSpec tiling rules, unsupported primitives) but not
+  Mosaic's own compile, which only the chip run sees;
+* the kernel branch is driven (interpret mode, flipped through the one
+  dispatch function) through ``make_train_step`` on 1- and 4-device
+  meshes, with loss parity against ``unsharded_loss``.
+
+And one about processes: a chip belongs to whichever process touched
+JAX's backend first, so importing the package must never do that.
+"""
+
+import subprocess
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from dmlc_tpu import telemetry
+from dmlc_tpu.models import (TransformerConfig, init_params,
+                             make_train_step, unsharded_loss)
+from dmlc_tpu.ops import dispatch
+from dmlc_tpu.ops import flash_attention as flash
+from dmlc_tpu.ops import paged_attention as paged
+from dmlc_tpu.parallel import build_mesh
+
+# (B, T, H, D) of the two flagship training shapes
+FLAGSHIP_SHAPES = [(8, 1024, 16, 128), (1, 8192, 16, 128)]
+
+
+def _lower_for_tpu(fn, *avals) -> str:
+    with dispatch.force_kernel_mode(dispatch.MOSAIC):
+        text = jax.jit(fn).trace(*avals).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _one_device_mesh():
+    return build_mesh(1, dp=1, sp=1, tp=1, pp=1, ep=1)
+
+
+@pytest.mark.parametrize("shape", FLAGSHIP_SHAPES)
+def test_flash_kernels_lower_for_tpu_inside_shard_map(shape):
+    """Forward, dkv and dq at the flagship shapes, called the way the
+    train step calls them: inside a VMA-checked shard_map."""
+    spec = P("dp", "sp", "tp", None)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    grad = jax.shard_map(jax.grad(loss, argnums=(0, 1, 2)),
+                         mesh=_one_device_mesh(),
+                         in_specs=(spec, spec, spec),
+                         out_specs=(spec, spec, spec))
+    text = _lower_for_tpu(grad, x, x, x)
+    for name in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert name in text, f"{name} missing from the lowered step"
+
+
+def test_ring_step_kernel_lowers_for_tpu_inside_shard_map():
+    spec = P("dp", "sp", "tp", None)
+    x = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16)
+
+    def step(q, k, v):
+        my = jax.lax.axis_index("sp")
+        return flash.block_attend_flash(
+            q, k, v, scale=128 ** -0.5, causal=True,
+            q_offset=my * 4096, kv_offset=my * 4096)
+
+    out = (spec, P("dp", "tp", "sp"), P("dp", "tp", "sp"))
+    fn = jax.shard_map(step, mesh=_one_device_mesh(),
+                       in_specs=(spec, spec, spec), out_specs=out)
+    _lower_for_tpu(fn, x, x, x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("s_w", [1, 4])
+def test_paged_attention_lowers_for_tpu(dtype, s_w):
+    """H=16, block 16: the shape whose per-head [bs, 1, D] block the TPU
+    lowering refused."""
+    b, h, d, bs, n_blocks, w = 8, 16, 128, 16, 256, 66
+    assert paged.supports(d, bs, h)
+    q = jax.ShapeDtypeStruct((b, s_w, h, d), dtype)
+    pool = jax.ShapeDtypeStruct((n_blocks, bs, h, d), dtype)
+    tables = jax.ShapeDtypeStruct((b, w), jnp.int32)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
+    text = _lower_for_tpu(paged.paged_attention,
+                          q, pool, pool, tables, lengths)
+    assert "paged_attn" in text
+
+
+def test_dispatch_is_one_flippable_function():
+    counts = lambda: telemetry.counters_snapshot().get("kernels", {})  # noqa: E731
+    assert dispatch.kernel_mode() == dispatch.LAX  # tier-1 is CPU
+    with dispatch.force_kernel_mode(dispatch.INTERPRET):
+        before = counts().get("interpret_traces", 0)
+        assert dispatch.choose(True) == dispatch.INTERPRET
+        assert dispatch.choose(False) == dispatch.LAX
+        assert dispatch.choose(True, "lax") == dispatch.LAX
+        assert counts()["interpret_traces"] == before + 1
+    assert dispatch.kernel_mode() == dispatch.LAX
+    assert dispatch.choose(False, "pallas") == dispatch.INTERPRET
+    with pytest.raises(ValueError):
+        with dispatch.force_kernel_mode("cuda"):
+            pass
+
+
+@pytest.mark.parametrize("axes", [
+    {"dp": 1, "sp": 1, "tp": 1},
+    {"dp": 2, "sp": 2, "tp": 1},
+    {"dp": 2, "sp": 1, "tp": 2},
+])
+def test_train_step_through_kernel_branch_matches_oracle(axes):
+    """``make_train_step`` with every attention call on the kernel
+    branch: the standalone flash kernels (fwd + dkv + dq) where sp == 1
+    and the ring-step kernel where sp == 2."""
+    n = axes["dp"] * axes["sp"] * axes["tp"]
+    mesh = build_mesh(n, pp=1, ep=1, **axes)
+    cfg = TransformerConfig(vocab=256, d_model=64, n_heads=2, head_dim=128,
+                            d_ff=128, n_layers=2, n_experts=1,
+                            microbatches=1, dtype="float32", remat=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0, cfg.vocab)
+    labels = jnp.roll(ids, -1, axis=1)
+    want = float(unsharded_loss(params, ids, labels, cfg))
+
+    before = telemetry.counters_snapshot().get("kernels", {})
+    with dispatch.force_kernel_mode(dispatch.INTERPRET):
+        step, init_state = make_train_step(mesh, cfg, ledger=False)
+        opt_state = init_state(params)
+        params, opt_state, loss0 = step(params, opt_state, ids, labels)
+        params, opt_state, loss1 = step(params, opt_state, ids, labels)
+    after = telemetry.counters_snapshot().get("kernels", {})
+    assert (after.get("interpret_traces", 0)
+            > before.get("interpret_traces", 0))
+    assert after.get("lax_traces", 0) == before.get("lax_traces", 0)
+    assert abs(float(loss0) - want) < 1e-4 * max(abs(want), 1.0)
+    assert float(loss1) < float(loss0)
+
+
+def test_importing_the_package_initialises_no_backend():
+    """The load generator, the launcher and chip_smoke.py's bookkeeping
+    all import dmlc_tpu next to a process that owns the chip; an import
+    that created a backend would take the chip from it (or hang)."""
+    code = (
+        "import importlib, pkgutil, dmlc_tpu\n"
+        "for m in pkgutil.walk_packages(dmlc_tpu.__path__, 'dmlc_tpu.'):\n"
+        "    if '.lib' not in m.name:  # the ctypes-loaded native .so files\n"
+        "        importlib.import_module(m.name)\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)

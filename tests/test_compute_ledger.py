@@ -97,6 +97,40 @@ def test_unhashable_static_falls_back_like_plain_jit():
     assert pj.stats()["aot_fallbacks"] >= 1
 
 
+def test_lowering_error_is_raised_not_compiled_twice():
+    """A program that cannot lower is the caller's error: it surfaces
+    from the first (AOT) attempt, with no fallback counted and no
+    second trace through plain jit."""
+    traces = []
+
+    def bad(x):
+        traces.append(1)
+        raise TypeError("cannot lower this")
+
+    pj = compute.profiled_jit(bad, site="t.bad")
+    with pytest.raises(TypeError, match="cannot lower"):
+        pj(jnp.ones((2,)))
+    assert len(traces) == 1
+    assert pj.stats()["aot_fallbacks"] == 0
+
+
+def test_signature_memo_does_not_pin_superseded_pytrees():
+    """A training loop hands the step a fresh params dict every call;
+    the identity memo may remember the latest one, never the history
+    (each remembered tree would pin a copy of the weights)."""
+    import gc
+    import weakref
+
+    pj = compute.profiled_jit(lambda p: p["w"] * 2, site="t.memo")
+    old = {"w": jnp.ones((4,))}
+    ref = weakref.ref(old["w"])  # a dict itself takes no weak reference
+    pj(old)
+    del old
+    pj({"w": jnp.ones((4,))})
+    gc.collect()
+    assert ref() is None, "the memo kept a superseded argument alive"
+
+
 def test_signature_cap_raises_dmlc_error():
     pj = compute.profiled_jit(lambda x: x, site="t.cap",
                               max_signatures=2)

@@ -87,25 +87,35 @@ def test_paged_vs_gather_parity_matrix(s_w):
     np.testing.assert_allclose(paged, dense, rtol=1e-5, atol=1e-5)
 
 
-def test_paged_attention_pallas_interpret_parity():
+@pytest.mark.parametrize("h,bs,s_w,dtype,tol", [
+    (1, 8, 1, np.float32, 1e-5),
+    (16, 16, 1, np.float32, 1e-5),      # flagship heads and page size
+    (16, 16, 4, np.float32, 1e-5),      # ... under a verify window
+    (16, 16, 4, "bfloat16", 2e-2),      # ... in the serving dtype
+])
+def test_paged_attention_pallas_interpret_parity(h, bs, s_w, dtype, tol):
     """The Pallas kernel (interpret mode on CPU) agrees with the lax
-    fallback on supported shapes — same matrix of lengths."""
-    bs, w, d = 8, 3, 128
-    assert supports(d, bs)
-    lengths = [1, bs, bs + 1, w * bs - 1]
+    reference: lengths that sit on, before and after page boundaries,
+    and one dead row (garbage by contract, not compared)."""
+    import jax.numpy as jnp
+
+    w, d = 3, 128
+    assert supports(d, bs, h) == (h == 16)
+    lengths = [1, bs - 1, bs, bs + 1, w * bs - s_w, 0]
     rng = np.random.default_rng(1)
-    n_blocks, h, s_w = 6, 1, 1
-    k_pool = _rand(rng, n_blocks, bs, h, d)
-    v_pool = _rand(rng, n_blocks, bs, h, d)
-    tables = np.stack([rng.permutation(n_blocks)[:w]
-                       for _ in lengths]).astype(np.int32)
-    q = _rand(rng, len(lengths), s_w, h, d)
+    n_blocks = len(lengths) * w
+    k_pool = jnp.asarray(_rand(rng, n_blocks, bs, h, d), dtype)
+    v_pool = jnp.asarray(_rand(rng, n_blocks, bs, h, d), dtype)
+    tables = rng.permutation(n_blocks).reshape(-1, w).astype(np.int32)
+    q = jnp.asarray(_rand(rng, len(lengths), s_w, h, d), dtype)
     lens = np.asarray(lengths, np.int32)
+    live = lens > 0
     ref = np.asarray(paged_attention(q, k_pool, v_pool, tables, lens,
-                                     impl="lax"))
+                                     impl="lax"), np.float32)
     got = np.asarray(paged_attention(q, k_pool, v_pool, tables, lens,
-                                     impl="pallas", interpret=True))
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+                                     impl="pallas", interpret=True),
+                     np.float32)
+    np.testing.assert_allclose(got[live], ref[live], rtol=tol, atol=tol)
 
 
 def test_paged_attention_rejects_unknown_impl():
