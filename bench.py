@@ -244,7 +244,7 @@ def bench_transformer():
         telemetry.reset_steps()  # ledger records for THIS run only
         with contextlib.ExitStack() as stack:
             if trace_dir:  # guarantees stop_trace even on a failing step
-                stack.enter_context(metrics.trace(trace_dir))
+                stack.enter_context(jax.profiler.trace(trace_dir))
                 log(f"bench: capturing jax profiler trace to {trace_dir}")
             t0 = time.perf_counter()
             for _ in range(n_steps):

@@ -2,7 +2,9 @@
 cross-file check.
 
 Every metric family literal telemetry call sites can emit
-(``telemetry.inc("stage", "name")`` -> ``dmlc_<stage>_<name>``), plus
+(``telemetry.inc("stage", "name")`` -> ``dmlc_<stage>_<name>``; a
+``telemetry.span("stage.a.b", stage="stage")`` -> the pair
+``dmlc_<stage>_a_b_secs`` / ``dmlc_<stage>_a_b_count``), plus
 every literal ``dmlc_*`` token anywhere (scrape assertions,
 hand-rendered families), must be registered in
 ``dmlc_tpu/telemetry/metric_names.py`` — the MIGRATION.md "no renames,
@@ -23,6 +25,7 @@ from .core import Finding, Pass, RepoIndex
 METRIC_ROOTS = ("dmlc_tpu", "scripts", "examples", "bench.py")
 _METRIC_FUNCS = {"inc", "set_gauge", "observe", "observe_duration",
                  "timed"}
+_SPAN_FUNCS = {"span", "_span"}
 _METRIC_TOKEN_RE = re.compile(r"dmlc_[a-z0-9]+(?:_[a-z0-9]+)*")
 _METRIC_SUFFIXES = ("_bucket", "_sum", "_count", "_total")
 
@@ -31,6 +34,33 @@ def _registry():
     from ..telemetry import metric_names
 
     return metric_names
+
+
+def _literal(node):
+    return (node.value if isinstance(node, ast.Constant)
+            and isinstance(node.value, str) else None)
+
+
+def _span_families(node: ast.Call, fname: str) -> List[str]:
+    """The counter pair a literal ``span(name, stage=...)`` call site
+    feeds (telemetry.core.span_counter_suffix's rule); the serving
+    engine's ``self._span(name)`` helper is stage ``serving``."""
+    name = _literal(node.args[0]) if node.args else None
+    if name is None:
+        return []
+    stage = "serving" if fname == "_span" else "dmlc"
+    if fname == "span":
+        if len(node.args) > 1:
+            stage = _literal(node.args[1])
+        for kw in node.keywords:
+            if kw.arg == "stage":
+                stage = _literal(kw.value)
+    if stage is None:
+        return []
+    from ..telemetry.core import span_counter_suffix
+
+    base = f"dmlc_{stage}_{span_counter_suffix(name, stage)}"
+    return [base + "_secs", base + "_count"]
 
 
 def _is_registered(token: str, known: set) -> bool:
@@ -84,6 +114,13 @@ class MetricsPass(Pass):
                                 f"metric family {name!r} not in "
                                 f"telemetry/metric_names.py (add it, or "
                                 f"fix the typo'd stage/name)"))
+                    if fname in _SPAN_FUNCS:
+                        for name in _span_families(node, fname):
+                            if name not in known:
+                                findings.append(Finding(
+                                    ctx.rel, node.lineno, "metric-name",
+                                    f"span counter family {name!r} not "
+                                    f"in telemetry/metric_names.py"))
                 # literal names: scrape assertions, hand-rendered rows
                 if (isinstance(node, ast.Constant)
                         and isinstance(node.value, str)):

@@ -148,8 +148,7 @@ def save_pytree(uri: str, tree: Any, *, process_index: int = 0) -> None:
     from .. import telemetry
 
     with telemetry.span("checkpoint.save", stage="checkpoint",
-                        args={"uri": uri}), \
-            telemetry.timed("checkpoint", "save"):
+                        args={"uri": uri}):
         _ensure_dir(uri)
         leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
         manifest: Dict[str, Any] = {"format": 1, "leaves": {}}
@@ -271,8 +270,7 @@ def restore_pytree(uri: str, template: Any, *, mesh=None) -> Any:
     from .. import telemetry
 
     with telemetry.span("checkpoint.restore", stage="checkpoint",
-                        args={"uri": uri}), \
-            telemetry.timed("checkpoint", "restore"):
+                        args={"uri": uri}):
         out = _restore_pytree(uri, template, mesh=mesh)
     telemetry.inc("checkpoint", "restores")
     return out

@@ -345,10 +345,6 @@ KNOBS: Tuple[Knob, ...] = (
        "cost/roofline accounting, HBM gauges (counter/gauge cost "
        "only); 0 = plain jax.jit, zero per-call overhead", ship=True,
        group="telemetry"),
-    _k("DMLC_COMPUTE_TRACE_PHASES", bool, False,
-       "deep device-phase tracing: profiler TraceAnnotation scopes "
-       "around decode/train phases (profile-capture runs only)",
-       ship=True, group="telemetry"),
     _k("DMLC_COMPUTE_STORM_WINDOW_S", float, 60.0,
        "recompile-storm sliding window (seconds)", ship=True,
        group="telemetry"),

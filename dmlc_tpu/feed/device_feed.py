@@ -461,7 +461,7 @@ class DeviceFeed:
         try:
             while True:
                 with telemetry.span("feed.assemble", stage="feed"), \
-                        telemetry.timed("feed", "assemble"), self._cv:
+                        self._cv:
                     # "assembly" = waiting for the parser workers to
                     # complete this step's staging buffer
                     while not (self._error is not None
@@ -806,8 +806,7 @@ def libsvm_feed(uri: str, mesh, *, batch_size: int, max_nnz: int,
                 start, n = 0, len(chunk)
                 while start < n:
                     with telemetry.span("feed.parse_native",
-                                        stage="feed"), \
-                            telemetry.timed("feed", "parse_native"):
+                                        stage="feed"):
                         r, start = native.parse_libsvm_into(
                             chunk, start, r, max_nnz, 0, out)
                     if r == batch_size:
@@ -964,8 +963,7 @@ def _chunk_spans(mv: memoryview, source=None, base=None):
     from .. import native, telemetry
     from ..io.recordio import KMAGIC
 
-    with telemetry.span("feed.parse_native", stage="feed"), \
-            telemetry.timed("feed", "parse_native"):
+    with telemetry.span("feed.parse_native", stage="feed"):
         sp = native.recordio_spans(mv, KMAGIC, verify=True)
         if sp is None:  # no native library: fused Python walk
             sp = _py_chunk_spans(mv)

@@ -420,8 +420,7 @@ class RecordIOChunkReader:
         self._tail = (size, rem) if rem and owns_tail else None
         self._corrupt_seen = False
         # per-chunk span (bounded: one per partition scan, not per record)
-        with telemetry.span("recordio.partition_scan", stage="recordio"), \
-                telemetry.timed("recordio", "partition_scan"):
+        with telemetry.span("recordio.partition_scan", stage="recordio"):
             self._pbegin = find_next_record_head(self._buf, begin, size)
             self._pend = find_next_record_head(self._buf, end, size)
 

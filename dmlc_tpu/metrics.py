@@ -7,7 +7,7 @@ call sites (io/input_split.py, feed/device_feed.py,
 models/transformer.py, data/parser.py, bench.py, examples) keep
 working unchanged:
 
-  * ``inc`` / ``timed`` / ``annotate`` / ``trace`` delegate directly
+  * ``inc`` / ``timed`` / ``annotate`` delegate directly
     (``timed`` additionally feeds a histogram now — free distributions
     for every previously flat ``<name>_secs`` counter);
   * ``snapshot()`` returns the legacy flat ``{stage: {name: value}}``
@@ -21,12 +21,11 @@ from typing import Dict
 
 from . import telemetry
 
-__all__ = ["inc", "timed", "snapshot", "reset", "annotate", "trace"]
+__all__ = ["inc", "timed", "snapshot", "reset", "annotate"]
 
 inc = telemetry.inc
 timed = telemetry.timed
 annotate = telemetry.annotate
-trace = telemetry.trace
 reset = telemetry.reset
 
 

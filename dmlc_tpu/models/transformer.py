@@ -420,24 +420,6 @@ def decode_flops_per_token(cfg: TransformerConfig, ctx: int) -> float:
     return train_flops_per_token(cfg, ctx, causal=False) / 3.0
 
 
-def decode_phase_flops(cfg: TransformerConfig, ctx: int) -> dict:
-    """Per-phase breakdown of :func:`decode_flops_per_token` — the
-    analytic FLOP shares the compute phase ledger
-    (telemetry.compute.phase_estimate) uses to apportion the decode
-    step's device residual across attention / mlp / unembed when deep
-    per-phase tracing is off.  The three values sum exactly to
-    ``decode_flops_per_token(cfg, ctx)`` (qkvo projections count as
-    attention; the KV gather and sampling phases are host-measured and
-    carry no matmul FLOPs)."""
-    e, hd, f, x = (cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff,
-                   cfg.n_experts)
-    return {
-        "attention": float(cfg.n_layers * (2 * 4 * e * hd + 4 * ctx * hd)),
-        "mlp": float(cfg.n_layers * (2 * 3 * e * f * x)),
-        "unembed": float(2 * e * cfg.vocab),
-    }
-
-
 def _rope_at(x, positions, theta: float = 10000.0):
     """Rotary embedding for decode: x [B, 1, H, D] with a PER-SEQUENCE
     position [B] (continuous batching puts every active request at a

@@ -26,6 +26,17 @@ load signal) and streams TTFT/TBT/outcomes into the
 :class:`telemetry.SLOMonitor` (``engine.slo``, the ``DMLC_SLO_*``
 burn-rate objectives behind ``/slo``).
 
+Where an iteration's time goes is measured from inside, with
+``telemetry.span``: ``serving.iteration`` and under it disjoint
+children (``serving.schedule``, ``serving.prefill`` with ``.run`` and
+``.kv_to_host``, ``serving.kv_write``, ``serving.first_token``,
+``serving.decode`` with ``.dispatch`` / ``.fetch`` / ``.commit`` /
+``.deliver`` / ``.bookkeeping``), each carrying ``args.iter``; an idle
+episode of the loop is one ``serving.starved`` span.  Every span is a
+host event in a profiler capture and a ``<suffix>_secs`` /
+``<suffix>_count`` counter pair; the bytes that cross the host link
+are counted at the same boundaries (README "Serving").
+
 Shape discipline (XLA recompiles per shape, so both are bucketed):
 prefill pads prompts up to a whole number of KV blocks (safe under
 causal attention), and decode always runs the full ``max_active``-row
@@ -71,6 +82,19 @@ class EngineDraining(DMLCError):
 
 
 _JIT_CACHE: dict = {}
+
+#: every counter the serving spans and byte counts feed: start() sets
+#: them to 0, so a window in which a phase never ran reads 0 and not
+#: "nothing to read"
+_SPAN_FAMILIES = (
+    "iteration", "schedule", "prefill", "prefill_run",
+    "prefill_kv_to_host", "kv_write", "kv_upload", "first_token",
+    "decode", "decode_dispatch", "decode_fetch", "decode_commit",
+    "decode_deliver", "decode_bookkeeping", "starved", "http")
+_ZEROED_COUNTERS = tuple(
+    f + kind for f in _SPAN_FAMILIES for kind in ("_secs", "_count")
+) + ("decode_d2h_bytes", "prefill_d2h_bytes", "kv_upload_bytes",
+     "queue_wait_secs", "queue_wait_count", "latency_secs")
 
 
 class _DedupeTable:
@@ -312,6 +336,9 @@ class InferenceEngine:
         # XLA prefill compile, worth a log line and a counter
         # dmlc-check: unguarded(engine-thread-confined)
         self._prompt_buckets: set = set()
+        # number of the iteration in flight (``args.iter`` of its spans)
+        # dmlc-check: unguarded(engine-thread-confined)
+        self._iter = 0
 
     # ---- client surface -------------------------------------------------
     def submit(self, prompt_ids: List[int],
@@ -447,6 +474,8 @@ class InferenceEngine:
         if self._stop.is_set():
             raise DMLCError("engine is closed")
         self._stop.clear()
+        for name in _ZEROED_COUNTERS:
+            telemetry.inc("serving", name, 0)
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="serving-engine")
         self._thread.start()
@@ -532,8 +561,19 @@ class InferenceEngine:
             except AlreadyFinished:
                 pass  # racing terminal transition already happened
 
+    def _span(self, name: str, **args):
+        """An engine-thread span of the iteration in flight."""
+        args["iter"] = self._iter
+        return telemetry.span(name, stage="serving", args=args)
+
     def _loop(self) -> None:
+        # the open serving.starved span: one per episode in which the
+        # loop finds no work, not one per sleep
+        starved = None
         while not self._stop.is_set():
+            if starved is not None and any(self.scheduler.counts()):
+                starved.__exit__(None, None, None)
+                starved = None
             crashed = False
             try:
                 did = self.step()
@@ -576,12 +616,17 @@ class InferenceEngine:
             else:
                 self.availability.set_state("starved_idle")
             if not did:
+                if starved is None:
+                    starved = self._span("serving.starved")
+                    starved.__enter__()
                 # idle: nothing waiting, nothing active — but the SLO
                 # windows keep aging, so evaluation must keep running
                 # (a violation flips back when its burst expires even
                 # if no request ever arrives again; throttled inside)
                 self.slo.maybe_evaluate()
                 time.sleep(0.002)
+        if starved is not None:
+            starved.__exit__(None, None, None)
 
     # ---- one iteration --------------------------------------------------
     def step(self) -> bool:
@@ -598,24 +643,29 @@ class InferenceEngine:
         single-step the engine deterministically."""
         self._step_seq += 1
         try:
-            did = False
-            while True:
-                req = self.scheduler.next_prefill()
-                if req is None:
-                    break
-                self._run_prefill(req)
-                did = True
-                if req.state == WAITING:
-                    # allocate lost a race and requeued the request;
-                    # bail rather than spin on it inside one iteration
-                    break
-            active = self.scheduler.active_requests()
-            if active:
-                self._run_decode(active)
-                did = True
-            return did
+            if not any(self.scheduler.counts()):
+                return False  # the loop's serving.starved span has this
+            self._iter += 1
+            self.requests.iteration = self._iter
+            with self._span("serving.iteration"):
+                return self._iterate()
         finally:
             self._step_seq += 1
+
+    def _iterate(self) -> bool:
+        did = False
+        while True:
+            with self._span("serving.schedule"):
+                req = self.scheduler.next_prefill()
+            if req is None:
+                break
+            self._run_prefill(req)
+            did = True
+            if req.state == WAITING:
+                # allocate lost a race and requeued the request;
+                # bail rather than spin on it inside one iteration
+                break
+        return self._run_decode() or did
 
     def _finish(self, req: Request, error: Optional[str] = None,
                 reason: Optional[str] = None) -> None:
@@ -648,15 +698,16 @@ class InferenceEngine:
         would deterministically re-derive that very token and duplicate
         it in the output.  The resume's next token comes from the decode
         step that consumes ``generated[-1]``."""
-        ctx = req.context_ids()
-        n = len(ctx)
-        bs = self.cache.block_size
-        if not self.cache.allocate(req.id, n):
-            # admission checked the free list, but a decode in the same
-            # iteration window can race it; retry next iteration
-            self.scheduler.requeue_front(req)
-            return
-        resume = bool(req.generated)
+        with self._span("serving.schedule", req=req.id):
+            ctx = req.context_ids()
+            n = len(ctx)
+            bs = self.cache.block_size
+            if not self.cache.allocate(req.id, n):
+                # admission checked the free list, but a decode in the
+                # same iteration window can race it; retry next iteration
+                self.scheduler.requeue_front(req)
+                return
+            resume = bool(req.generated)
         try:
             padded = n + (-n % bs)
             if padded not in self._prompt_buckets:
@@ -668,25 +719,33 @@ class InferenceEngine:
                     len(self._prompt_buckets))
             ids = np.zeros((1, padded), np.int32)
             ids[0, :n] = ctx
-            t0 = time.perf_counter()
-            self.requests.on_prefill_begin(req.id, t=t0, resume=resume)
-            with telemetry.span("serving.prefill", stage="serving",
-                                args={"tokens": n, "req": req.id}):
-                logits, k, v = self._prefill(
-                    self.params, ids, np.array([n - 1], np.int32),
-                    self.cfg)
-                logits = np.asarray(logits[0])
-                k = np.asarray(k)[:, 0, :n]
-                v = np.asarray(v)[:, 0, :n]
-            telemetry.observe_duration("serving", "prefill",
-                                       time.perf_counter() - t0)
+            self.requests.on_prefill_begin(req.id, resume=resume)
+            with self._span("serving.prefill", tokens=n, req=req.id):
+                with self._span("serving.prefill.run", req=req.id):
+                    logits, k, v = self._prefill(
+                        self.params, ids, np.array([n - 1], np.int32),
+                        self.cfg)
+                    logits = np.asarray(logits[0])
+                with self._span("serving.prefill.kv_to_host",
+                                req=req.id) as crossed:
+                    k = np.asarray(k)
+                    v = np.asarray(v)
+                    crossed["bytes"] = k.nbytes + v.nbytes
+            telemetry.inc("serving", "prefill_d2h_bytes",
+                          logits.nbytes + crossed["bytes"])
             telemetry.inc("serving", "prefill_tokens", n)
-            self.cache.write(req.id, k, v, start=0)
+            with self._span("serving.kv_write", req=req.id):
+                self.cache.write(req.id, k[:, 0, :n], v[:, 0, :n], start=0)
         except Exception as e:  # noqa: BLE001 - fail THIS request only
             logger.error("prefill of request %d failed: %r", req.id, e)
             self._finish(req, error=f"prefill failed: {e!r}",
                          reason="prefill")
             return
+        with self._span("serving.first_token", req=req.id):
+            self._after_prefill(req, logits, resume)
+
+    def _after_prefill(self, req: Request, logits, resume: bool) -> None:
+        """Sample the first token of a fresh request and activate it."""
         if not resume:
             if not np.isfinite(logits).all():
                 # same guard at the prefill sample point: the first
@@ -786,15 +845,31 @@ class InferenceEngine:
                 return ctx[p + m:p + m + self.spec_k]
         return []
 
-    def _run_decode(self, active: List[Request]) -> None:
+    def _run_decode(self) -> bool:
+        """One decode window for every active request; whether there
+        was one to run."""
         s_w = self._spec_window
-        active, n_preempted = self._ensure_decode_capacity(active, s_w)
+        with self._span("serving.schedule"):
+            active = self.scheduler.active_requests()
+            if not active:
+                return False
+            active, n_preempted = self._ensure_decode_capacity(active, s_w)
+            if active:
+                inputs = self._decode_inputs(active)
         if not active:
             if n_preempted:
                 self.requests.on_iteration(
                     active=0, waiting=self.scheduler.n_waiting,
                     preempted=n_preempted, kv_stats=self.cache.stats())
-            return
+            return True
+        with self._span("serving.decode", rows=len(active)):
+            self._decode_step(active, n_preempted, *inputs)
+        return True
+
+    def _decode_inputs(self, active: List[Request]) -> tuple:
+        """What the decode program takes from the host: ``(ids,
+        positions, drafts, tables, lengths, base_lens)``."""
+        s_w = self._spec_window
         b = len(active)
         pad_b = self.max_active
         # the decode window: column 0 is the token each row consumes
@@ -823,6 +898,13 @@ class InferenceEngine:
                 ids[i, 1:1 + len(d)] = d
             drafts.append(d)
         positions[:b] = base_lens[:, None] + np.arange(s_w)
+        return ids, positions, drafts, tables, lengths, base_lens
+
+    def _decode_step(self, active: List[Request], n_preempted: int,
+                     ids, positions, drafts, tables, lengths,
+                     base_lens) -> None:
+        s_w = self._spec_window
+        b = len(active)
         compute = telemetry.compute
         if not self._flops_declared:
             # per-token FLOPs vary with context; declared once for the
@@ -832,61 +914,44 @@ class InferenceEngine:
             # the decode roofline needs the dtype's peak FLOPs/HBM BW
             telemetry.declare_dtype(self.cfg.dtype)
             self._flops_declared = True
+        # the ledger's step is the device program + the commit, nothing
+        # else: its span encloses those three and closes (LIFO) before
+        # delivery and bookkeeping open
         telemetry.step_begin()
-        if self._use_paged:
-            # fast path: NO dense gather, NO re-placement copy — the
-            # program reads the device-resident pools in place through
-            # the block tables (a [B, W] int32 array is all that ships)
-            k_pool, v_pool = self.cache.device_pools()
-            ctx_depth = tables.shape[1] * self.cache.block_size
-            t_dev = time.perf_counter()
-            logits, k_pool, v_pool, k_new, v_new = self._decode(
-                self.params, ids, positions, k_pool, v_pool, tables,
-                lengths, self.cfg)
-            self.cache.adopt_device_pools(k_pool, v_pool)
-        else:
-            with compute.phase("gather"):
-                k, v, lengths = self.cache.gather(
-                    [r.id for r in active], pad_batch=pad_b)
-                k, v = self.cache.shard_gathered(k, v)
-            ctx_depth = int(k.shape[2])
-            t_dev = time.perf_counter()
-            if s_w > 1:
-                logits, k_new, v_new = self._decode(
-                    self.params, ids, positions, k, v, lengths, self.cfg)
-            else:
-                logits, k_new, v_new = self._decode(
-                    self.params, ids[:, 0], positions[:, 0], k, v,
+        with self._span("serving.decode.dispatch"):
+            if self._use_paged:
+                # fast path: NO dense gather, NO re-placement copy — the
+                # program reads the device-resident pools in place
+                # through the block tables (a [B, W] int32 array is all
+                # that ships)
+                k_pool, v_pool = self.cache.device_pools()
+                logits, k_pool, v_pool, k_new, v_new = self._decode(
+                    self.params, ids, positions, k_pool, v_pool, tables,
                     lengths, self.cfg)
-        logits = np.asarray(logits)
-        k_new = np.asarray(k_new)
-        v_new = np.asarray(v_new)
-        if logits.ndim == 2:  # single-token gather program: [B, V]
-            logits = logits[:, None]
-            k_new = k_new[:, :, None]
-            v_new = v_new[:, :, None]
-        dev_s = time.perf_counter() - t_dev
-        # executed FLOPs: every window position runs the full forward
-        # whether or not its token commits (verify is the price of
-        # speculation; MFU is accounted on work actually executed).
-        # Context depths repeat heavily across rows and steps, so the
-        # per-token figure is memoized (engine-thread-confined cache)
-        fpt_at = self._fpt_cache
-        flops = 0.0
-        for i in range(b):
-            base = int(base_lens[i])
-            for s in range(s_w):
-                c = base + s + 1
-                f = fpt_at.get(c)
-                if f is None:
-                    f = fpt_at[c] = tfm.decode_flops_per_token(self.cfg, c)
-                flops += f
-        if compute.enabled():
-            # the fused decode executable's internal split is not host
-            # observable; apportion its wall time by the model's exact
-            # per-phase FLOP breakdown at the batch's context depth
-            compute.phase_estimate(
-                tfm.decode_phase_flops(self.cfg, ctx_depth), dev_s)
+                self.cache.adopt_device_pools(k_pool, v_pool)
+            else:
+                with compute.phase("gather"):
+                    k, v, lengths = self.cache.gather(
+                        [r.id for r in active], pad_batch=self.max_active)
+                    k, v = self.cache.shard_gathered(k, v)
+                if s_w > 1:
+                    logits, k_new, v_new = self._decode(
+                        self.params, ids, positions, k, v, lengths,
+                        self.cfg)
+                else:
+                    logits, k_new, v_new = self._decode(
+                        self.params, ids[:, 0], positions[:, 0], k, v,
+                        lengths, self.cfg)
+        with self._span("serving.decode.fetch") as crossed:
+            logits = np.asarray(logits)
+            k_new = np.asarray(k_new)
+            v_new = np.asarray(v_new)
+            crossed["bytes"] = logits.nbytes + k_new.nbytes + v_new.nbytes
+            if logits.ndim == 2:  # single-token gather program: [B, V]
+                logits = logits[:, None]
+                k_new = k_new[:, :, None]
+                v_new = v_new[:, :, None]
+        telemetry.inc("serving", "decode_d2h_bytes", crossed["bytes"])
         # per-sequence numeric health: a non-finite logit row (NaN/Inf
         # from a poisoned cache page or an overflowed activation) would
         # serve garbage silently.  Checking only the sampled position is
@@ -904,43 +969,45 @@ class InferenceEngine:
         n_tokens = 0
         n_proposed = 0
         n_accepted = 0
-        with compute.phase("sampling"):
-            # one vectorized argmax + finiteness probe over the whole
-            # [B, S_w] window: the walk below touches only python ints
-            # (per-position np.argmax calls were a measurable slice of
-            # the step wall at batch 8 × window 8)
-            amax = np.argmax(logits[:b], axis=2)
-            fin = np.isfinite(
-                np.take_along_axis(logits[:b], amax[:, :, None],
-                                   axis=2))[:, :, 0]
-            outcomes = []
-            for i, req in enumerate(active):
-                draft = drafts[i]
-                n_proposed += len(draft)
-                n_row = 0
-                fail = False
-                done = False
-                for s in range(1 + len(draft)):
-                    if not fin[i, s]:
-                        telemetry.inc("serving", "nonfinite_failures")
-                        logger.error(
-                            "request %d produced non-finite logits at "
-                            "decode position %d", req.id,
-                            int(base_lens[i]) + s)
-                        fail = True
+        with self._span("serving.decode.commit"):
+            with compute.phase("sampling"):
+                # one vectorized argmax + finiteness probe over the
+                # whole [B, S_w] window: the walk below touches only
+                # python ints (per-position np.argmax calls were a
+                # measurable slice of the step wall at batch 8 ×
+                # window 8)
+                amax = np.argmax(logits[:b], axis=2)
+                fin = np.isfinite(
+                    np.take_along_axis(logits[:b], amax[:, :, None],
+                                       axis=2))[:, :, 0]
+                outcomes = []
+                for i, req in enumerate(active):
+                    draft = drafts[i]
+                    n_proposed += len(draft)
+                    n_row = 0
+                    fail = False
+                    done = False
+                    for s in range(1 + len(draft)):
+                        if not fin[i, s]:
+                            telemetry.inc("serving", "nonfinite_failures")
+                            logger.error(
+                                "request %d produced non-finite logits "
+                                "at decode position %d", req.id,
+                                int(base_lens[i]) + s)
+                            fail = True
+                            break
+                        next_id = int(amax[i, s])
+                        req.generated.append(next_id)
+                        n_row += 1
+                        if req.is_finished_by(next_id):
+                            done = True
+                            break
+                        if s < len(draft) and draft[s] == next_id:
+                            n_accepted += 1
+                            continue
                         break
-                    next_id = int(amax[i, s])
-                    req.generated.append(next_id)
-                    n_row += 1
-                    if req.is_finished_by(next_id):
-                        done = True
-                        break
-                    if s < len(draft) and draft[s] == next_id:
-                        n_accepted += 1
-                        continue
-                    break
-                outcomes.append((req, i, n_row, fail, done))
-                n_tokens += n_row
+                    outcomes.append((req, i, n_row, fail, done))
+                    n_tokens += n_row
             # ONE batched host-mirror write covering every row's
             # committed prefix (contiguous by construction): the
             # per-row write calls were dominated by lock/GIL
@@ -953,6 +1020,21 @@ class InferenceEngine:
             for req, i, n_row, fail, done in outcomes:
                 if n_row:
                     self.requests.on_token(req.id, n=n_row)
+        # executed FLOPs: every window position runs the full forward
+        # whether or not its token commits (verify is the price of
+        # speculation; MFU is accounted on work actually executed).
+        # Context depths repeat heavily across rows and steps, so the
+        # per-token figure is memoized (engine-thread-confined cache)
+        fpt_at = self._fpt_cache
+        flops = 0.0
+        for i in range(b):
+            base = int(base_lens[i])
+            for s in range(s_w):
+                c = base + s + 1
+                f = fpt_at.get(c)
+                if f is None:
+                    f = fpt_at[c] = tfm.decode_flops_per_token(self.cfg, c)
+                flops += f
         stats_fn = getattr(self._decode, "stats", None)
         cost = stats_fn() if stats_fn else None
         telemetry.step_end(
@@ -966,14 +1048,25 @@ class InferenceEngine:
         # handler thread (and everything it does with the core next) is
         # response streaming, not decode work — the step ledger's wall
         # must cover the device program + the commit, nothing else
-        for req, _i, _n, fail, done in outcomes:
-            if fail:
-                self._finish(
-                    req, error="non-finite logits during decode "
-                    "(numeric corruption); retry the request",
-                    reason="nonfinite")
-            elif done:
-                self._finish(req)
+        with self._span("serving.decode.deliver"):
+            for req, _i, _n, fail, done in outcomes:
+                if fail:
+                    self._finish(
+                        req, error="non-finite logits during decode "
+                        "(numeric corruption); retry the request",
+                        reason="nonfinite")
+                elif done:
+                    self._finish(req)
+        with self._span("serving.decode.bookkeeping"):
+            self._decode_bookkeeping(b, n_tokens, n_proposed, n_accepted,
+                                     n_preempted, cost)
+
+    def _decode_bookkeeping(self, b: int, n_tokens: int, n_proposed: int,
+                            n_accepted: int, n_preempted: int,
+                            cost) -> None:
+        """Counters, gauges and the ledgers' per-iteration records."""
+        s_w = self._spec_window
+        compute = telemetry.compute
         if n_tokens:
             telemetry.inc("serving", "tokens_generated", n_tokens)
         telemetry.inc("serving", "decode_steps")

@@ -447,10 +447,13 @@ class PagedKVCache:
         if padded > n:
             idx = np.concatenate([idx, np.full(padded - n, idx[0],
                                                np.int32)])
-        k_blk = self.k_pool[:, idx]
-        v_blk = self.v_pool[:, idx]
-        self._dev_k = self._upload_jit(self._dev_k, idx, k_blk)
-        self._dev_v = self._upload_jit(self._dev_v, idx, v_blk)
+        with telemetry.span("serving.kv_upload", stage="serving") as crossed:
+            k_blk = self.k_pool[:, idx]
+            v_blk = self.v_pool[:, idx]
+            crossed["bytes"] = k_blk.nbytes + v_blk.nbytes
+            self._dev_k = self._upload_jit(self._dev_k, idx, k_blk)
+            self._dev_v = self._upload_jit(self._dev_v, idx, v_blk)
+        telemetry.inc("serving", "kv_upload_bytes", crossed["bytes"])
 
     def device_pools(self):
         """The device-resident ``(k_pool, v_pool)`` twins.  First call

@@ -203,6 +203,14 @@ class ServingHTTPServer:
                     telemetry.inc("serving", "http_404")
                     self._send(404, "text/plain", b"not found\n")
                     return
+                # the request's time on this handler thread, body parse
+                # to response written: what it spends outside the engine
+                # is serving.http_secs - serving.latency_secs
+                with telemetry.span("serving.http",
+                                    stage="serving") as span_args:
+                    self._generate(span_args)
+
+            def _generate(self, span_args):
                 # NB the drain gate lives in eng.submit (raising
                 # EngineDraining → 503 below), not here: the dedupe
                 # lookup must run first so a router retry of
@@ -268,6 +276,7 @@ class ServingHTTPServer:
                     # the client's 400, not a size problem
                     self._answer(400, {"error": str(e)})
                     return
+                span_args["req"] = req.id
                 if not req.wait(wait_s):
                     self._answer(503, {"error": "generation timed out",
                                        "id": req.id})

@@ -103,7 +103,6 @@ from .core import (  # noqa: F401
     spans,
     spans_since,
     timed,
-    trace,
 )
 from .events import (  # noqa: F401
     events_tail,
@@ -214,5 +213,4 @@ __all__ = [
     "to_chrome_trace",
     "to_chrome_trace_json",
     "to_prometheus_text",
-    "trace",
 ]

@@ -24,16 +24,15 @@ This module opens it along four axes:
     (CPU) that report none, plus a headroom gauge future autoscaling /
     KV-quantization work gates on.
   * **phase decomposition** — host-measured spans for the host-side
-    decode phases (KV gather, sampling) and an analytic split of the
-    device residual across attention / MLP / unembed, exported as
-    per-phase time shares.
+    decode phases (KV gather, sampling), exported as per-phase time
+    shares.  What the device does inside the fused decode program is
+    a profiler capture's to say (``benchmarks/reduce_trace.py``), not
+    a host clock's.
 
 Everything here is dark-cheap: ``DMLC_COMPUTE_PROFILE=1`` (default)
 costs counters and one dict lookup per jitted call; ``=0`` makes
 :func:`profiled_jit` return the plain ``jax.jit`` object — zero
-per-call overhead, no registry entries.  Deep per-phase device
-tracing (profiler ``TraceAnnotation`` scopes) sits behind
-``DMLC_COMPUTE_TRACE_PHASES=1``.
+per-call overhead, no registry entries.
 """
 
 from __future__ import annotations
@@ -49,28 +48,22 @@ from ..concurrency import make_lock
 from . import core
 
 __all__ = [
-    "PHASES", "profiled_jit", "enabled", "phases_enabled", "sites",
-    "roofline", "sample_hbm", "phase", "phase_estimate", "phase_shares",
+    "PHASES", "profiled_jit", "enabled", "sites",
+    "roofline", "sample_hbm", "phase", "phase_shares",
     "recompiles_total", "status", "report", "prometheus_text",
     "reset_compute",
 ]
 
 logger = logging.getLogger("dmlc_tpu.telemetry")
 
-# the fixed decode-phase vocabulary: gather + sampling are measured on
-# the host (they ARE host work), attention/mlp/unembed split the
-# device residual analytically from the model's FLOP breakdown
-PHASES = ("gather", "attention", "mlp", "unembed", "sampling")
+# the fixed decode-phase vocabulary: both are measured on the host
+# (they ARE host work)
+PHASES = ("gather", "sampling")
 
 
 def enabled() -> bool:
     """Compile/cost/HBM ledgers on (the dark-cheap default)."""
     return bool(get_env("DMLC_COMPUTE_PROFILE", True))
-
-
-def phases_enabled() -> bool:
-    """Deep device-phase tracing (profiler annotations) requested."""
-    return bool(get_env("DMLC_COMPUTE_TRACE_PHASES", False))
 
 
 # ---------------------------------------------------------------------------
@@ -549,46 +542,17 @@ def _add_phase(name: str, secs: float) -> None:
 
 @contextlib.contextmanager
 def phase(name: str):
-    """Host-measured phase scope (gather / sampling / ...).
-
-    Always accounts wall time into the phase-share estimate (two clock
-    reads — dark-cheap); additionally opens a profiler
-    ``TraceAnnotation`` scope when deep tracing is on, so the phase
-    shows up as a named region in captured device profiles."""
+    """Host-measured phase scope (gather / sampling): the span
+    ``compute.<name>`` plus the phase-share accounting."""
     if not enabled():
         yield
         return
-    ctx = core.annotate(name) if phases_enabled() \
-        else contextlib.nullcontext()
     t0 = time.perf_counter()
     try:
-        with ctx:
+        with core.span("compute." + name, stage="compute"):
             yield
     finally:
         _add_phase(name, time.perf_counter() - t0)
-
-
-def phase_estimate(shares: Dict[str, float], secs: float) -> None:
-    """Split a device-residual interval across phases analytically.
-
-    The device computation is one fused executable — its internal
-    phase split is not host-observable without a profiler capture, but
-    the model's FLOP breakdown (attention vs. MLP vs. unembed) is
-    exact, so the residual wall time is apportioned by it.  The result
-    is an *estimate* and is labeled as one on /compute."""
-    if not enabled() or secs <= 0 or not shares:
-        return
-    total = sum(v for v in shares.values() if v and v > 0)
-    if total <= 0:
-        return
-    with _phase_lock:
-        for name, v in shares.items():
-            if name in _phase_secs and v and v > 0:
-                _phase_secs[name] += secs * (v / total)
-    for name in shares:
-        if name in _phase_secs:
-            core.set_gauge("compute", f"phase_{name}_share",
-                           phase_shares().get(name, 0.0))
 
 
 def phase_shares() -> Dict[str, float]:
@@ -642,7 +606,6 @@ def report() -> Dict:
         hbm = _last_hbm
     return {
         "enabled": enabled(),
-        "deep_phase_tracing": phases_enabled(),
         "sites": site_map,
         "traces_total": sum(s["traces"] for s in site_map.values()),
         "cache_hits_total": sum(s["hits"] for s in site_map.values()),
@@ -652,9 +615,7 @@ def report() -> Dict:
                                    for s in site_map.values()),
         "storm": _storm_doc(),
         "hbm": hbm if hbm is not None else sample_hbm(),
-        "phases": {"shares": phase_shares(),
-                   "estimated": ("attention", "mlp", "unembed"),
-                   "measured": ("gather", "sampling")},
+        "phases": {"shares": phase_shares(), "measured": PHASES},
         "roofline": _step_roofline(),
     }
 

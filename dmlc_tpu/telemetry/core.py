@@ -13,20 +13,27 @@ Four primitives, all process-global and thread-safe:
   * ``inc(stage, name, v)``        monotonic counters (dict add under a lock)
   * ``set_gauge(stage, name, v)``  last-write-wins gauges
   * ``observe(stage, name, v)``    fixed-bucket histograms (p50/p90/p99)
-  * ``span(name, stage=...)``      nested, thread-aware timed spans in a
-                                   bounded ring buffer (Chrome-trace
-                                   exportable; see telemetry.exporters)
+  * ``span(name, stage=...)``      THE way to time a block: a nested,
+                                   thread-aware span in a bounded ring
+                                   buffer (Chrome-trace exportable; see
+                                   telemetry.exporters), a
+                                   ``jax.profiler.TraceAnnotation`` of
+                                   the same name (so the block is a
+                                   host event on the device trace's
+                                   clock), and the counter pair
+                                   ``<suffix>_secs`` / ``<suffix>_count``
 
-``timed`` records both the counter and the histogram under
-``<name>_secs``; ``annotate`` records a span AND bridges to
-``jax.profiler.TraceAnnotation`` when JAX is importable, so feed batches
-and train steps still show up in a real profiler trace.
+``timed`` records the counter and the histogram under ``<name>_secs``
+only (no span); ``annotate(name)`` is ``span(name, stage="annotate")``.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import itertools
 import math
+import sys
 import threading
 import time
 from bisect import bisect_left
@@ -51,7 +58,6 @@ __all__ = [
     "open_spans",
     "anchor_epoch",
     "annotate",
-    "trace",
     "snapshot",
     "counters_snapshot",
     "reset",
@@ -171,6 +177,7 @@ _gauges: Dict[str, Dict[str, float]] = defaultdict(dict)
 _hists: Dict[str, Dict[str, Histogram]] = defaultdict(dict)
 _spans: deque = deque(maxlen=_MAX_SPANS)
 _span_seq = 0  # monotone id per recorded span (incremental trace shipping)
+_span_ids = itertools.count(1)  # id per OPENED span (next() is GIL-atomic)
 _T0 = time.perf_counter()  # session-relative span clock (µs in exports)
 # wall-clock moment of _T0: span ts + _T0_EPOCH places a span on this
 # process's wall clock, which the tracker's per-rank clock offset then
@@ -204,17 +211,21 @@ def observe(stage: str, name: str, value: float, bounds=None) -> None:
         h.observe(value)
 
 
+def _observe_duration_locked(stage: str, name: str, secs: float) -> None:
+    key = name + "_secs"
+    _counters[stage][key] += secs
+    h = _hists[stage].get(key)
+    if h is None:
+        h = _hists[stage][key] = Histogram()
+    h.observe(secs)
+
+
 def observe_duration(stage: str, name: str, secs: float) -> None:
     """Duration convention: counter ``<name>_secs`` += secs (the flat
     total old call sites read) plus a histogram observation under the
     same key (the distribution new consumers read)."""
-    key = name + "_secs"
     with _lock:
-        _counters[stage][key] += secs
-        h = _hists[stage].get(key)
-        if h is None:
-            h = _hists[stage][key] = Histogram()
-        h.observe(secs)
+        _observe_duration_locked(stage, name, secs)
 
 
 @contextlib.contextmanager
@@ -241,40 +252,87 @@ def _span_stack() -> List[Dict]:
     return stack
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation once resolved; False = none
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once JAX is loaded in this
+    process (a TraceMe is inert without a profiler session, and a
+    session needs JAX loaded: a host-only process pays no import)."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:  # pragma: no cover - jax present in tests
+            _ANNOTATION = False
+    return _ANNOTATION or None
+
+
+def span_counter_suffix(name: str, stage: str) -> str:
+    """A span's counter family: its name without the ``<stage>.``
+    prefix (the whole name where it has none), dots to ``_``."""
+    if name.startswith(stage + "."):
+        name = name[len(stage) + 1:]
+    return name.replace(".", "_")
+
+
 @contextlib.contextmanager
 def span(name: str, stage: str = "dmlc", args: Optional[Dict] = None):
-    """Nested, thread-aware timed span recorded into the bounded ring.
+    """The one way to time a block: a nested, thread-aware span.
 
-    Nesting is tracked per thread (a span opened inside another on the
-    same thread records ``depth`` = enclosing count); Perfetto nests by
-    ts/dur containment per tid, so exports render the tree directly.
-    """
+    The record in the bounded ring carries ``id`` (assigned when the
+    span opens), ``parent`` (the ``id`` of the span open around it on
+    the same thread, None at the top), ``depth`` (enclosing count) and
+    ``seq`` (close order: the ``spans_since`` cursor).  The block also
+    runs inside a ``jax.profiler.TraceAnnotation(name)``, so it is a
+    host event on the device trace's clock, and closing it adds
+    ``<suffix>_secs`` (counter + histogram) and ``<suffix>_count`` to
+    ``stage``'s counters: a ratio of counters gives time per unit
+    where the work happens.  Yields its ``args`` dict (a copy of the
+    caller's), so the block can add fields it learns inside."""
     global _span_seq
     stack = _span_stack()
+    a = dict(args) if args else {}
+    ann = _trace_annotation()
+    rec = {"name": name, "cat": stage, "id": next(_span_ids),
+           "parent": stack[-1]["id"] if stack else None,
+           "depth": len(stack), "args": a}
     t0 = time.perf_counter()
-    stack.append({"name": name, "cat": stage, "ts": (t0 - _T0) * 1e6,
-                  "args": dict(args) if args else None})
+    rec["ts"] = (t0 - _T0) * 1e6
+    stack.append(rec)
     try:
-        yield
+        if ann is None:
+            yield a
+        else:
+            with ann(name):
+                yield a
     finally:
         t1 = time.perf_counter()
-        stack.pop()
+        # the top of the stack, unless closed out of order (the step
+        # ledger abandoning a step): the spans opened after it stay
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i] is rec:
+                del stack[i]
+                break
         th = threading.current_thread()
-        rec = {
-            "name": name,
-            "cat": stage,
-            "ts": (t0 - _T0) * 1e6,
-            "dur": (t1 - t0) * 1e6,
-            "tid": th.ident,
-            "thread": th.name,
-            "depth": len(stack),
-        }
-        if args:
-            rec["args"] = dict(args)
+        rec["dur"] = (t1 - t0) * 1e6
+        rec["tid"] = th.ident
+        rec["thread"] = th.name
+        if not a:
+            del rec["args"]
+        suffix = span_counter_suffix(name, stage)
         with _lock:
             _span_seq += 1
             rec["seq"] = _span_seq
             _spans.append(rec)
+            _observe_duration_locked(stage, suffix, t1 - t0)
+            _counters[stage][suffix + "_count"] += 1
+
+
+#: a named span under stage ``annotate`` (the pre-bridge name of the
+#: profiler-visible span; every span is one now)
+annotate = functools.partial(span, stage="annotate")
 
 
 def record_span(name: str, stage: str = "dmlc", *, t0: float, t1: float,
@@ -290,12 +348,15 @@ def record_span(name: str, stage: str = "dmlc", *, t0: float, t1: float,
     record lands in the ordinary ring it ships through the heartbeat
     ``trace`` path onto the tracker's merged ``/trace`` with no extra
     plumbing.  Synthetic spans do not touch the per-thread open-span
-    stacks (they are closed by construction)."""
+    stacks (they are closed by construction: ``parent`` is None), feed
+    no counters and open no profiler annotation."""
     global _span_seq
     th = threading.current_thread()
     rec: Dict = {
         "name": name,
         "cat": stage,
+        "id": next(_span_ids),
+        "parent": None,
         "ts": (t0 - _T0) * 1e6,
         "dur": max(t1 - t0, 0.0) * 1e6,
         "tid": th.ident if tid is None else tid,
@@ -328,9 +389,15 @@ def spans_since(after_seq: int, limit: Optional[int] = None) -> tuple:
     recoverable) rather than resending the whole ring forever; when
     ``limit`` truncated, it is the last RETURNED span's seq, so the
     still-retained remainder ships next call."""
+    out = []
     with _lock:
-        out = [r for r in _spans if r["seq"] > after_seq]
+        # seq grows along the ring: stop at the first span already seen
+        for r in reversed(_spans):
+            if r["seq"] <= after_seq:
+                break
+            out.append(r)
         last = _span_seq
+    out.reverse()
     if limit is not None and len(out) > limit:
         out = out[:limit]
         last = out[-1]["seq"]
@@ -378,9 +445,11 @@ def open_spans() -> List[Dict]:
                 continue
             out.append({
                 "name": rec["name"], "cat": rec["cat"], "ts": rec["ts"],
+                "id": rec["id"], "parent": rec["parent"],
                 "open_us": now_ts - rec["ts"], "tid": th.ident,
                 "thread": th.name, "depth": depth,
-                **({"args": rec["args"]} if rec.get("args") else {}),
+                # the block may still be adding fields: copy
+                **({"args": dict(rec["args"])} if rec.get("args") else {}),
             })
     return out
 
@@ -388,45 +457,6 @@ def open_spans() -> List[Dict]:
 def anchor_epoch() -> float:
     """Wall-clock time (time.time) corresponding to span ts == 0."""
     return _T0_EPOCH
-
-
-_ANNOTATION = False  # False = unresolved; None = jax unavailable
-
-
-def _trace_annotation():
-    global _ANNOTATION
-    if _ANNOTATION is False:
-        try:
-            from jax.profiler import TraceAnnotation
-            _ANNOTATION = TraceAnnotation
-        except Exception:  # pragma: no cover - jax present in tests
-            _ANNOTATION = None
-    return _ANNOTATION
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named span in BOTH our ring buffer and the JAX profiler trace
-    (the jax half is a no-op without jax)."""
-    ann = _trace_annotation()
-    with span(name, stage="annotate"):
-        if ann is None:
-            yield
-        else:
-            with ann(name):
-                yield
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a jax.profiler trace around a block (e.g. a bench run)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -36,7 +36,7 @@ METRIC_NAMES = frozenset({
     "dmlc_anomaly_recompile_storm_flags",
     # compute observability (telemetry.compute): compile ledger
     # (hand-rendered per-site *_total families + registry families),
-    # HBM accounting, per-phase time shares
+    # HBM accounting, time shares of the host-measured decode phases
     "dmlc_compute_recompiles_total",
     "dmlc_compute_traces_total",
     "dmlc_compute_cache_hits_total",
@@ -46,9 +46,6 @@ METRIC_NAMES = frozenset({
     "dmlc_compute_hbm_peak_bytes",
     "dmlc_compute_hbm_headroom_bytes",
     "dmlc_compute_phase_gather_share",
-    "dmlc_compute_phase_attention_share",
-    "dmlc_compute_phase_mlp_share",
-    "dmlc_compute_phase_unembed_share",
     "dmlc_compute_phase_sampling_share",
     # elastic world resize (tracker generations + client + launcher)
     "dmlc_elastic_resizes_total",
@@ -330,6 +327,93 @@ METRIC_NAMES = frozenset({
     "dmlc_train_steps",
     # smoke-harness fixtures (scripts/telemetry_smoke.py workers)
     "dmlc_smoke_beats",
+    # span counter pairs: every telemetry.span() feeds
+    # <stage>.<suffix>_secs (counter + histogram) and
+    # <stage>.<suffix>_count, suffix = the span's name without its
+    # "<stage>." prefix, dots to "_" (telemetry.core).  Where a
+    # *_secs family of a pair is older than the rule it is listed
+    # with its layer above.  Serving: the engine iteration phase by
+    # phase (serving/engine.py), the handler thread (serving.http)
+    "dmlc_bench_collective_build_secs",
+    "dmlc_bench_collective_build_count",
+    "dmlc_bench_collective_host_run_secs",
+    "dmlc_bench_collective_host_run_count",
+    "dmlc_bench_collective_loopback_probe_secs",
+    "dmlc_bench_collective_loopback_probe_count",
+    "dmlc_bench_collective_run_secs",
+    "dmlc_bench_collective_run_count",
+    "dmlc_checkpoint_restore_count",
+    "dmlc_checkpoint_save_count",
+    "dmlc_collective_allreduce_secs",
+    "dmlc_collective_allreduce_count",
+    "dmlc_collective_broadcast_secs",
+    "dmlc_collective_broadcast_count",
+    "dmlc_collective_bucket_secs",
+    "dmlc_collective_bucket_count",
+    "dmlc_collective_join_secs",
+    "dmlc_collective_join_count",
+    "dmlc_feed_assemble_count",
+    "dmlc_feed_parse_secs",
+    "dmlc_feed_parse_count",
+    "dmlc_feed_parse_native_count",
+    "dmlc_feed_place_secs",
+    "dmlc_feed_place_count",
+    "dmlc_feed_stage_secs",
+    "dmlc_feed_stage_count",
+    "dmlc_feed_wait_secs",
+    "dmlc_feed_wait_count",
+    "dmlc_flash_flash_attention_trace_secs",
+    "dmlc_flash_flash_attention_trace_count",
+    "dmlc_pipeline_run_secs",
+    "dmlc_pipeline_run_count",
+    "dmlc_recordio_partition_scan_count",
+    "dmlc_recordio_reassemble_secs",
+    "dmlc_recordio_reassemble_count",
+    "dmlc_ring_ring_attention_run_secs",
+    "dmlc_ring_ring_attention_run_count",
+    "dmlc_ring_ring_attention_trace_secs",
+    "dmlc_ring_ring_attention_trace_count",
+    "dmlc_serving_decode_secs",
+    "dmlc_serving_decode_count",
+    "dmlc_serving_decode_bookkeeping_secs",
+    "dmlc_serving_decode_bookkeeping_count",
+    "dmlc_serving_decode_commit_secs",
+    "dmlc_serving_decode_commit_count",
+    "dmlc_serving_decode_deliver_secs",
+    "dmlc_serving_decode_deliver_count",
+    "dmlc_serving_decode_dispatch_secs",
+    "dmlc_serving_decode_dispatch_count",
+    "dmlc_serving_decode_fetch_secs",
+    "dmlc_serving_decode_fetch_count",
+    "dmlc_serving_first_token_secs",
+    "dmlc_serving_first_token_count",
+    "dmlc_serving_http_secs",
+    "dmlc_serving_http_count",
+    "dmlc_serving_iteration_secs",
+    "dmlc_serving_iteration_count",
+    "dmlc_serving_kv_upload_secs",
+    "dmlc_serving_kv_upload_count",
+    "dmlc_serving_kv_write_secs",
+    "dmlc_serving_kv_write_count",
+    "dmlc_serving_prefill_count",
+    "dmlc_serving_prefill_kv_to_host_secs",
+    "dmlc_serving_prefill_kv_to_host_count",
+    "dmlc_serving_prefill_run_secs",
+    "dmlc_serving_prefill_run_count",
+    "dmlc_serving_schedule_secs",
+    "dmlc_serving_schedule_count",
+    "dmlc_serving_starved_secs",
+    "dmlc_serving_starved_count",
+    "dmlc_smoke_scrape_secs",
+    "dmlc_smoke_scrape_count",
+    "dmlc_step_step_secs",
+    "dmlc_step_step_count",
+    # bytes across the host link at the serving spans' boundaries, and
+    # the count beside queue_wait_secs
+    "dmlc_serving_decode_d2h_bytes",
+    "dmlc_serving_kv_upload_bytes",
+    "dmlc_serving_prefill_d2h_bytes",
+    "dmlc_serving_queue_wait_count",
 })
 
 #: span / jax-profiler annotation names that look like metric tokens in

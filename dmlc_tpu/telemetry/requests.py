@@ -168,6 +168,11 @@ class RequestLedger:
         self._n_done = 0
         self._n_failed = 0
         self._preempt_total = 0
+        #: the engine iteration in flight (the engine thread sets it):
+        #: every trace row carries it as ``args.iter``, so a request's
+        #: slow phase joins to the ``serving.iteration`` that ran it
+        # dmlc-check: unguarded(single-writer engine thread; GIL-atomic int reads)
+        self.iteration: Optional[int] = None
 
     # ---- lifecycle hooks (engine-driven) -------------------------------
     def on_submit(self, req_id: int, n_prompt: int,
@@ -204,6 +209,7 @@ class RequestLedger:
                 st.queue_s = t - st.submit_t
         if not resume and st.queue_s is not None:
             core.observe_duration("serving", "queue_wait", st.queue_s)
+            core.inc("serving", "queue_wait_count")
             self._row(st, "serving.queue", st.submit_t, t)
 
     def on_first_token(self, req_id: int,
@@ -380,6 +386,8 @@ class RequestLedger:
         if not self.trace_rows:
             return
         a = {"req": st.id}
+        if self.iteration is not None:
+            a["iter"] = self.iteration
         if st.trace_id is not None:
             a["trace_id"] = st.trace_id
         if args:

@@ -68,8 +68,8 @@ PASS_ENVS = [
     "DMLC_TELEMETRY_MAX_EVENTS", "DMLC_TELEMETRY_SHIP_TRACE",
     "DMLC_TELEMETRY_MAX_BEAT_BYTES", "DMLC_POSTMORTEM_DIR",
     "DMLC_STEP_LEDGER_MAX", "DMLC_PEAK_FLOPS", "DMLC_PEAK_HBM_GBPS",
-    "DMLC_COMPUTE_PROFILE", "DMLC_COMPUTE_TRACE_PHASES",
-    "DMLC_COMPUTE_STORM_WINDOW_S", "DMLC_COMPUTE_STORM_TRACES",
+    "DMLC_COMPUTE_PROFILE", "DMLC_COMPUTE_STORM_WINDOW_S",
+    "DMLC_COMPUTE_STORM_TRACES",
     "DMLC_TRACE_FLEET", "DMLC_TRACE_EXEMPLARS",
     "DMLC_GOODPUT_MIN_FRACTION", "DMLC_GOODPUT_WINDOW_S",
     "DMLC_GOODPUT_MAX_INTERVALS",

@@ -325,6 +325,20 @@ def test_metrics_pass_catches_unregistered_family(tmp_path):
     assert _checks(MetricsPass().run(idx), "metric-name")
 
 
+def test_metrics_pass_derives_a_spans_counter_pair(tmp_path):
+    src = ('from dmlc_tpu import telemetry\n\n'
+           'with telemetry.span("bogus.a.b", stage="bogus"):\n'
+           '    pass\n'
+           'with telemetry.span("serving.decode.fetch", stage="serving"):\n'
+           '    pass\n')
+    idx = _index(tmp_path, {"dmlc_tpu/mod.py": src})
+    found = _checks(MetricsPass().run(idx), "metric-name")
+    # the registered serving pair passes; the bogus span's pair does not
+    # (built, not spelled: a literal would be a finding of this tree)
+    assert sorted(f.message.split("'")[1] for f in found) == [
+        "dmlc" + "_bogus_a_b" + kind for kind in ("_count", "_secs")]
+
+
 def test_suppression_comment_and_counting(tmp_path):
     src = ("import threading\n"
            "import time\n\n"

@@ -242,37 +242,22 @@ def test_sample_hbm_host_rss_fallback(monkeypatch):
 # phase decomposition
 # ---------------------------------------------------------------------------
 
-def test_phase_shares_mix_measured_and_estimated():
+def test_phase_shares_are_measured_spans():
+    telemetry.reset()
     with compute.phase("gather"):
         time.sleep(0.002)
-    # analytic split of a 10ms device residual by FLOP fractions
-    compute.phase_estimate({"attention": 3.0, "mlp": 6.0,
-                            "unembed": 1.0}, 0.010)
+    with compute.phase("sampling"):
+        time.sleep(0.001)
     shares = compute.phase_shares()
-    assert set(shares) == set(compute.PHASES)
+    assert set(shares) == set(compute.PHASES) == {"gather", "sampling"}
     assert sum(shares.values()) == pytest.approx(1.0)
-    assert shares["mlp"] > shares["attention"] > shares["unembed"]
-    assert shares["gather"] > 0
-    assert shares["sampling"] == 0.0
-
-
-def test_phase_estimate_ignores_garbage():
-    compute.phase_estimate({}, 1.0)
-    compute.phase_estimate({"attention": 0.0}, 1.0)
-    compute.phase_estimate({"attention": 1.0}, -1.0)
-    assert compute.phase_shares() == {}
-
-
-def test_decode_phase_flops_sums_to_decode_flops():
-    from dmlc_tpu.models import transformer as tfm
-
-    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=2,
-                                head_dim=8, d_ff=64, n_layers=2,
-                                n_experts=1, dtype="float32")
-    shares = tfm.decode_phase_flops(cfg, ctx=40)
-    assert set(shares) == {"attention", "mlp", "unembed"}
-    assert sum(shares.values()) == pytest.approx(
-        tfm.decode_flops_per_token(cfg, 40))
+    assert shares["gather"] > shares["sampling"] > 0
+    # a phase IS a span: in the ring (and so in a profiler capture),
+    # with its counter pair
+    names = [r["name"] for r in telemetry.spans()]
+    assert names == ["compute.gather", "compute.sampling"]
+    counters = telemetry.counters_snapshot()["compute"]
+    assert counters["gather_count"] == 1 and counters["gather_secs"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +282,7 @@ def test_status_and_report_schema():
     assert rep["recompiles_total"] == 1
     assert rep["storm"]["threshold"] >= 1
     assert rep["hbm"]["peak_bytes"] > 0
-    assert set(rep["phases"]) == {"shares", "estimated", "measured"}
+    assert set(rep["phases"]) == {"shares", "measured"}
     assert "bound" in rep["roofline"]
 
 
@@ -341,7 +326,6 @@ def test_disabled_phase_scope_accumulates_nothing(monkeypatch):
     monkeypatch.setenv("DMLC_COMPUTE_PROFILE", "0")
     with compute.phase("gather"):
         time.sleep(0.001)
-    compute.phase_estimate({"attention": 1.0}, 1.0)
     assert compute.phase_shares() == {}
 
 
@@ -389,13 +373,14 @@ def test_render_compute_pane_replica_shape():
     top = _load_top()
     pj = compute.profiled_jit(lambda x: x, site="t.pane")
     pj(jnp.zeros((2,), jnp.float32))
-    compute.phase_estimate({"attention": 1.0, "mlp": 2.0}, 0.01)
+    with compute.phase("sampling"):
+        time.sleep(0.001)
     compute.sample_hbm()
     lines = top.render_compute_pane({"compute": compute.report()})
     text = "\n".join(lines)
     assert "compute  traces=1" in text
     assert "storm=ok" in text
-    assert "phases" in text and "mlp=67%" in text
+    assert "phases" in text and "sampling=100%" in text
 
 
 def test_render_compute_pane_tracker_shape():

@@ -1101,3 +1101,264 @@ def test_engine_fails_only_nonfinite_logit_request():
     st = eng.stats()
     assert st["kv"]["blocks_in_use"] == 0  # failed request freed its blocks
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the iteration measured from inside: spans, counters, bytes
+# ---------------------------------------------------------------------------
+
+def _engine_thread_spans():
+    """The ring without the per-request rows RequestLedger draws."""
+    return [r for r in telemetry.spans()
+            if not r["thread"].startswith("req ")]
+
+
+def _warm_engine(**kw):
+    """An engine whose prefill and decode programs are compiled and
+    whose pools are on the device, with one request still decoding."""
+    params, cfg = _tiny_model()
+    kw.setdefault("max_active", 4)
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          queue_depth=8, **kw)
+    eng.submit([1, 2, 3, 4, 5], max_new_tokens=40)
+    # four steps: the decode program of the block-table width the next
+    # three steps use is compiled (a width lasts block_size steps)
+    for _ in range(4):
+        eng.step()
+    return eng
+
+
+def test_engine_iteration_yields_the_span_tree():
+    eng = _warm_engine()
+    telemetry.reset()
+    req = eng.submit([9, 8, 7, 6, 5, 4], max_new_tokens=6)
+    assert eng.step()  # one iteration: a prefill and a decode window
+    recs = _engine_thread_spans()
+    by_id = {r["id"]: r for r in recs}
+    tree = sorted((r["name"], by_id[r["parent"]]["name"]
+                   if r["parent"] else None) for r in recs)
+    assert tree == sorted([
+        ("serving.iteration", None),
+        ("serving.schedule", "serving.iteration"),   # next_prefill
+        ("serving.schedule", "serving.iteration"),   # allocation
+        ("serving.prefill", "serving.iteration"),
+        ("serving.prefill.run", "serving.prefill"),
+        ("serving.prefill.kv_to_host", "serving.prefill"),
+        ("serving.kv_write", "serving.iteration"),
+        ("serving.kv_upload", "serving.kv_write"),
+        ("serving.first_token", "serving.iteration"),
+        ("serving.schedule", "serving.iteration"),   # next_prefill: none
+        ("serving.schedule", "serving.iteration"),   # the decode batch
+        ("serving.decode", "serving.iteration"),
+        ("step", "serving.decode"),                  # the step ledger's
+        ("serving.decode.dispatch", "step"),
+        ("serving.decode.fetch", "step"),
+        ("serving.decode.commit", "step"),
+        ("compute.sampling", "serving.decode.commit"),
+        ("serving.decode.deliver", "serving.decode"),
+        ("serving.decode.bookkeeping", "serving.decode"),
+    ])
+    (it,) = [r for r in recs if r["name"] == "serving.iteration"]
+    for r in recs:
+        if r["name"].startswith("serving.") \
+                and r["name"] != "serving.kv_upload":
+            assert r["args"]["iter"] == it["args"]["iter"], r
+    owned = {r["name"] for r in recs
+             if r.get("args", {}).get("req") == req.id}
+    assert owned == {"serving.schedule", "serving.prefill",
+                     "serving.prefill.run", "serving.prefill.kv_to_host",
+                     "serving.kv_write", "serving.first_token"}
+    eng.close()
+
+
+def test_engine_iteration_children_are_disjoint_and_account_for_it():
+    eng = _warm_engine()
+    telemetry.reset()
+    eng.submit([9, 8, 7, 6, 5, 4], max_new_tokens=30)
+    for _ in range(6):
+        assert eng.step()
+    recs = _engine_thread_spans()
+    iterations = [r for r in recs if r["name"] == "serving.iteration"]
+    assert len(iterations) == 6
+    covered = total = 0.0
+    for it in iterations:
+        kids = sorted((r for r in recs if r["parent"] == it["id"]),
+                      key=lambda r: r["ts"])
+        assert kids[0]["ts"] >= it["ts"]
+        assert kids[-1]["ts"] + kids[-1]["dur"] <= it["ts"] + it["dur"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"], (a, b)  # disjoint
+        covered += sum(k["dur"] for k in kids)
+        total += it["dur"]
+    # what the children leave out is python between two spans
+    assert covered >= 0.95 * total, (covered, total)
+    # ... and the counters say the same as the ring
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["iteration_count"] == 6 and c["decode_count"] == 6
+    assert c["iteration_secs"] == pytest.approx(total / 1e6)
+    assert c["prefill_count"] == 1 and c["prefill_tokens"] == 6
+    eng.close()
+
+
+def test_engine_starved_is_one_span_per_episode():
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    telemetry.reset()
+    eng.start()
+    try:
+        time.sleep(0.1)  # ~50 empty passes of the loop: ONE episode
+        assert eng.submit([1, 2, 3], max_new_tokens=3).wait(300)
+        time.sleep(0.1)  # a second episode
+        assert eng.submit([4, 5, 6], max_new_tokens=3).wait(300)
+    finally:
+        eng.close()  # the loop's exit closes the episode in flight
+    recs = _engine_thread_spans()
+    starved = [r for r in recs if r["name"] == "serving.starved"]
+    assert len(starved) == 3, [r["dur"] for r in starved]
+    assert starved[0]["dur"] >= 0.08e6 and starved[1]["dur"] >= 0.08e6
+    # an iteration is a step() that found work: none inside an episode,
+    # none empty
+    iterations = [r for r in recs if r["name"] == "serving.iteration"]
+    assert iterations and all(r["parent"] is None for r in iterations)
+    for it in iterations:
+        assert any(r["parent"] == it["id"] for r in recs)
+        for s in starved:
+            assert (it["ts"] >= s["ts"] + s["dur"]
+                    or it["ts"] + it["dur"] <= s["ts"])
+    assert telemetry.counters_snapshot()["serving"]["starved_count"] == 3
+
+
+def test_engine_byte_counters_equal_what_crossed():
+    eng = _warm_engine()
+    telemetry.reset()
+    crossed = {"prefill": 0, "decode": 0}
+    real_prefill, real_decode = eng._prefill, eng._decode
+
+    def prefill(*a):
+        logits, k, v = real_prefill(*a)
+        crossed["prefill"] += (np.asarray(logits[0]).nbytes
+                               + np.asarray(k).nbytes + np.asarray(v).nbytes)
+        return logits, k, v
+
+    def decode(*a):
+        # gather program: (logits, k_new, v_new); paged: the pools between
+        out = real_decode(*a)
+        crossed["decode"] += sum(np.asarray(o).nbytes
+                                 for o in (out[0], out[-2], out[-1]))
+        return out
+
+    eng._prefill, eng._decode = prefill, decode
+    eng.submit([9, 8, 7, 6, 5, 4], max_new_tokens=4)
+    for _ in range(3):
+        eng.step()
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["prefill_d2h_bytes"] == crossed["prefill"] > 0
+    assert c["decode_d2h_bytes"] == crossed["decode"] > 0
+    # the six-token prompt takes two blocks of 4: K and V of both go
+    # back up, [L=2, 2 blocks, 4, H=2, D=8] float32 each
+    assert c["kv_upload_bytes"] == 2 * (2 * 2 * 4 * 2 * 8 * 4)
+    recs = _engine_thread_spans()
+    for name, counter in (("serving.decode.fetch", "decode_d2h_bytes"),
+                          ("serving.kv_upload", "kv_upload_bytes")):
+        assert sum(r["args"]["bytes"] for r in recs
+                   if r["name"] == name) == c[counter]
+    eng.close()
+
+
+def test_engine_start_zeroes_its_phase_counters():
+    from dmlc_tpu.serving.engine import _ZEROED_COUNTERS
+
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    telemetry.reset()
+    eng.start()
+    c = telemetry.counters_snapshot()["serving"]
+    eng.close()
+    # a window in which a phase never ran reads 0, not "nothing"
+    assert set(_ZEROED_COUNTERS) <= set(c)
+    for name in ("decode_fetch_secs", "decode_fetch_count", "http_count",
+                 "kv_upload_bytes", "queue_wait_count", "starved_secs"):
+        assert c[name] == 0.0, name
+
+
+def test_request_rows_carry_the_iteration_that_drew_them():
+    eng = _warm_engine()
+    telemetry.reset()
+    req = eng.submit([9, 8, 7, 6], max_new_tokens=3)
+    while not req.wait(0):
+        eng.step()
+    rows = {r["name"]: r for r in telemetry.spans()
+            if r["thread"] == f"req {req.id}"}
+    assert set(rows) == {"serving.queue", "serving.prefill",
+                         "serving.decode"}
+    (ran,) = [r for r in _engine_thread_spans()
+              if r["name"] == "serving.prefill"]
+    assert rows["serving.queue"]["args"]["iter"] == ran["args"]["iter"]
+    assert rows["serving.prefill"]["args"]["iter"] == ran["args"]["iter"]
+    # the decode slice closes in the iteration that finished the request
+    assert rows["serving.decode"]["args"]["iter"] == eng._iter
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["queue_wait_count"] == 1 and c["queue_wait_secs"] > 0
+    eng.close()
+
+
+def test_http_span_times_the_request_outside_the_engine():
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    eng.start()
+    srv = ServingHTTPServer(eng, port=0)
+    telemetry.reset()
+    try:
+        doc = _post(srv.url, {"prompt": [1, 2, 3], "max_tokens": 4})
+        with pytest.raises(urllib.error.HTTPError):
+            _post(srv.url, {"prompt": "not a list"})
+    finally:
+        srv.close()
+        eng.close()
+    http = [r for r in telemetry.spans() if r["name"] == "serving.http"]
+    assert len(http) == 2
+    assert http[0]["args"] == {"req": doc["id"]}  # known once admitted
+    assert "args" not in http[1]                  # refused before that
+    assert http[0]["thread"] != "serving-engine"
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["http_count"] == 2
+    # body parse to response written encloses submit to finish
+    assert c["http_secs"] > c["latency_secs"] > 0
+
+
+def test_engine_spans_are_host_events_of_a_profile(tmp_path):
+    """A real (CPU) profiler capture holds the engine's spans as host
+    events: what benchmarks/reduce_trace.py attributes idle time to."""
+    import glob
+
+    import jax
+
+    eng = _warm_engine()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    eng.close()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    seen = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("serving."):
+                        seen[ev.name] = seen.get(ev.name, 0) + 1
+    assert seen.get("serving.iteration") == 3, seen
+    for name in ("serving.schedule", "serving.decode",
+                 "serving.decode.dispatch", "serving.decode.fetch",
+                 "serving.decode.commit", "serving.decode.deliver",
+                 "serving.decode.bookkeeping"):
+        assert seen.get(name, 0) >= 3, (name, seen)

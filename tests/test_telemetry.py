@@ -124,12 +124,183 @@ def test_span_nesting_and_thread_attribution():
             <= recs["main.outer"]["ts"] + recs["main.outer"]["dur"] + 1e-3)
 
 
-def test_span_ring_is_bounded():
+def test_span_ids_and_parents_nest_per_thread():
+    def worker():
+        with telemetry.span("w.outer", stage="t"):
+            with telemetry.span("w.inner", stage="t"):
+                pass
+
+    with telemetry.span("main.outer", stage="t"):
+        t = threading.Thread(target=worker, name="span-worker")
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        with telemetry.span("main.inner", stage="t"):
+            with telemetry.span("main.leaf", stage="t"):
+                pass
+
+    recs = {r["name"]: r for r in telemetry.spans()}
+    ids = [r["id"] for r in recs.values()]
+    assert len(set(ids)) == 5 and all(isinstance(i, int) for i in ids)
+    # parent = the span open around it ON THE SAME THREAD: the worker's
+    # spans ran while main.outer was open and are not its children
+    assert recs["main.outer"]["parent"] is None
+    assert recs["w.outer"]["parent"] is None
+    assert recs["w.inner"]["parent"] == recs["w.outer"]["id"]
+    assert recs["main.inner"]["parent"] == recs["main.outer"]["id"]
+    assert recs["main.leaf"]["parent"] == recs["main.inner"]["id"]
+    # an id is assigned when the span OPENS: a parent's is the smaller
+    assert recs["main.outer"]["id"] < recs["main.inner"]["id"]
+
+
+def test_span_seq_is_close_order_and_spans_since_semantics():
+    with telemetry.span("outer", stage="t"):
+        with telemetry.span("inner", stage="t"):
+            pass
+        cursor = telemetry.core.span_seq()
+        with telemetry.span("second", stage="t"):
+            pass
+    recs = {r["name"]: r for r in telemetry.spans()}
+    # seq counts CLOSES (the shipping cursor), id counts opens
+    assert (recs["inner"]["seq"] < recs["second"]["seq"]
+            < recs["outer"]["seq"])
+    assert recs["outer"]["id"] < recs["inner"]["id"] < recs["second"]["id"]
+    new, last = telemetry.spans_since(cursor)
+    assert [r["name"] for r in new] == ["second", "outer"]  # oldest first
+    assert last == recs["outer"]["seq"]
+    # limit keeps the OLDEST and resumes from the last one returned
+    new, last = telemetry.spans_since(0, limit=2)
+    assert [r["name"] for r in new] == ["inner", "second"]
+    assert last == recs["second"]["seq"]
+    assert telemetry.spans_since(last)[0][0]["name"] == "outer"
+    assert telemetry.spans_since(recs["outer"]["seq"]) == (
+        [], recs["outer"]["seq"])
+
+
+def test_spans_since_skips_the_evicted_gap():
     cap = telemetry.core._spans.maxlen
     for i in range(cap + 50):
-        with telemetry.span(f"s{i}"):
+        with telemetry.span("s", args={"i": i}):
             pass
-    assert len(telemetry.spans()) == cap
+    assert len(telemetry.spans()) == cap  # the ring is bounded
+    new, last = telemetry.spans_since(10)
+    # spans 11..50 are gone from the ring: the cursor moves past them
+    assert new[0]["args"]["i"] == 50 and len(new) == cap
+    assert last == telemetry.core.span_seq()
+
+
+def test_span_feeds_its_counter_pair():
+    for _ in range(3):
+        with telemetry.span("lay.phase.part", stage="lay"):
+            pass
+    with telemetry.span("bare", stage="lay"):
+        pass
+    with telemetry.span("other.name"):  # stage defaults to dmlc
+        pass
+    snap = telemetry.snapshot()
+    lay = snap["counters"]["lay"]
+    # suffix: the name without its "<stage>." prefix, dots to "_"
+    assert lay["phase_part_count"] == 3 and lay["phase_part_secs"] > 0
+    assert lay["bare_count"] == 1 and "bare_secs" in lay
+    assert snap["counters"]["dmlc"]["other_name_count"] == 1
+    # the duration goes through the observe_duration convention:
+    # the same key is a histogram too
+    assert snap["histograms"]["lay"]["phase_part_secs"]["count"] == 3
+    assert snap["histograms"]["lay"]["phase_part_secs"]["sum"] == \
+        pytest.approx(lay["phase_part_secs"])
+    spans = [r for r in telemetry.spans() if r["name"] == "lay.phase.part"]
+    assert sum(r["dur"] for r in spans) / 1e6 == pytest.approx(
+        lay["phase_part_secs"])
+
+
+def test_record_span_feeds_no_counters_and_has_no_parent():
+    with telemetry.span("around", stage="t"):
+        rec = telemetry.record_span("drawn.row", stage="t", t0=1.0, t1=3.0,
+                                    tid=77, thread="req 7")
+    assert rec["parent"] is None and rec["depth"] == 0
+    assert rec["id"] != telemetry.spans()[-1]["id"]
+    assert rec["dur"] == pytest.approx(2e6)
+    counters = telemetry.counters_snapshot()["t"]
+    assert set(counters) == {"around_secs", "around_count"}
+
+
+class _StandInAnnotation:
+    """What span() asks of jax.profiler.TraceAnnotation."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_span_enters_a_trace_annotation_of_its_own_name(monkeypatch):
+    monkeypatch.setattr(telemetry.core, "_ANNOTATION", _StandInAnnotation)
+    monkeypatch.setattr(_StandInAnnotation, "log", [])
+    with telemetry.span("lay.outer", stage="lay"):
+        with telemetry.annotate("plain"):
+            pass
+    with pytest.raises(KeyError):
+        with telemetry.span("lay.raises", stage="lay"):
+            raise KeyError("inside")
+    assert _StandInAnnotation.log == [
+        ("enter", "lay.outer"), ("enter", "plain"), ("exit", "plain"),
+        ("exit", "lay.outer"), ("enter", "lay.raises"),
+        ("exit", "lay.raises")]
+    # the raising block is a span and a count like any other
+    assert telemetry.spans()[-1]["name"] == "lay.raises"
+    assert telemetry.counters_snapshot()["lay"]["raises_count"] == 1
+
+
+def test_span_bridges_to_the_real_profiler_annotation():
+    import jax.profiler
+
+    with telemetry.span("bridged", stage="t"):
+        pass
+    assert telemetry.core._ANNOTATION is jax.profiler.TraceAnnotation
+
+
+def test_span_yields_args_for_fields_known_inside():
+    given = {"iter": 3}
+    with telemetry.span("lay.fetch", stage="lay", args=given) as a:
+        a["bytes"] = 4096
+        (inside,) = [s for s in telemetry.open_spans()
+                     if s["name"] == "lay.fetch"]
+        assert inside["args"] == {"iter": 3, "bytes": 4096}
+    with telemetry.span("lay.noargs", stage="lay") as a:
+        assert a == {}
+    recs = {r["name"]: r for r in telemetry.spans()}
+    assert recs["lay.fetch"]["args"] == {"iter": 3, "bytes": 4096}
+    assert given == {"iter": 3}  # the caller's dict is not the span's
+    assert "args" not in recs["lay.noargs"]
+
+
+def test_annotate_is_span_under_stage_annotate():
+    assert telemetry.annotate.func is telemetry.span
+    assert telemetry.annotate.keywords == {"stage": "annotate"}
+    with telemetry.annotate("batch"):
+        pass
+    assert telemetry.spans()[-1]["cat"] == "annotate"
+    assert telemetry.counters_snapshot()["annotate"]["batch_count"] == 1
+    assert not hasattr(telemetry, "trace")  # jax.profiler.trace is the one
+
+
+def test_span_closed_out_of_order_leaves_later_spans_open():
+    first = telemetry.span("first", stage="t")
+    first.__enter__()
+    with telemetry.span("later", stage="t"):
+        first.__exit__(None, None, None)  # an abandoned step's span
+        assert [s["name"] for s in telemetry.open_spans()] == ["later"]
+        with telemetry.span("child", stage="t"):
+            pass
+    recs = {r["name"]: r for r in telemetry.spans()}
+    assert recs["child"]["parent"] == recs["later"]["id"]
+    assert telemetry.open_spans() == []
 
 
 def test_annotate_records_span_and_runs_under_jit():
@@ -527,6 +698,10 @@ def test_checkpoint_save_restore_spans(tmp_path):
     flat = telemetry.counters_snapshot()["checkpoint"]
     assert flat["bytes_written"] == 32 and flat["bytes_read"] == 32
     assert "save_secs" in flat and "restore_secs" in flat
+    # the span is the one timer of the block: nothing counts twice
+    hists = telemetry.snapshot()["histograms"]["checkpoint"]
+    assert flat["save_count"] == 1 == hists["save_secs"]["count"]
+    assert flat["restore_count"] == 1 == hists["restore_secs"]["count"]
 
 
 # ---------------------------------------------------------------------------
