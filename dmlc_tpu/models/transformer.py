@@ -402,10 +402,10 @@ def unsharded_loss(params, ids, labels, cfg: TransformerConfig):
 
 
 # ---------------------------------------------------------------------------
-# serving forward paths: prefill (full sequence, returns per-layer K/V)
-# and single-token decode against an externally supplied KV cache
-# (dmlc_tpu.serving drives these; the paged cache lives in
-# serving/kv_cache.py — the model only sees dense gathered views)
+# serving forward paths: prefill (full sequence) and decode against the
+# KV cache dmlc_tpu.serving owns (serving/kv_cache.py).  The paged heads
+# read and write the device-resident pools in place; the gather heads
+# see dense gathered views and hand the new K/V back to the host
 # ---------------------------------------------------------------------------
 
 
@@ -522,18 +522,52 @@ def forward_prefill(params, ids, cfg: TransformerConfig):
     return logits, k, v
 
 
+def _logits_at(params, x, last_index):
+    """Unembed ONE position per sequence: ``x [B, T, E]`` at
+    ``last_index [B]`` -> ``[B, V]``.  The unembed is the model's
+    largest single matmul at flagship vocab — projecting all T padded
+    positions just to slice one row would multiply the serving
+    prefill's dominant term by T."""
+    x_last = jnp.take_along_axis(
+        x, last_index[:, None, None].astype(jnp.int32), axis=1)  # [B,1,E]
+    return jnp.einsum("bte,ev->btv", x_last, params["unembed"])[:, 0]
+
+
 def forward_prefill_last(params, ids, last_index, cfg: TransformerConfig):
     """Prefill with logits at ONE position per sequence:
     ``(logits [B, V], k, v)`` for ``last_index`` [B] (each sequence's
-    final real token in a right-padded batch).  The unembed is the
-    model's largest single matmul at flagship vocab — projecting all T
-    padded positions just to slice one row would multiply the serving
-    prefill's dominant term by T, so the engine uses this head."""
+    final real token in a right-padded batch).  The gather path's
+    prefill: the caller copies ``k, v`` into its host-resident cache."""
     x, k, v = _prefill_trunk(params, ids, cfg)
-    x_last = jnp.take_along_axis(
-        x, last_index[:, None, None].astype(jnp.int32), axis=1)  # [B,1,E]
-    logits = jnp.einsum("bte,ev->btv", x_last, params["unembed"])[:, 0]
-    return logits, k, v
+    return _logits_at(params, x, last_index), k, v
+
+
+def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
+                          block_ids, cfg: TransformerConfig):
+    """Prefill of ONE sequence that writes its K/V into the paged pools
+    on the device: ``(logits [1, V], k_pool, v_pool)``.
+
+    ids [1, T] with T a whole number of blocks (the engine pads prompts
+    so); k_pool / v_pool [L, n_blocks, block_size, H, hd]; block_ids
+    [T / block_size] int32, the sequence's block table.  Logical block
+    j of every layer lands in physical block ``block_ids[j]``, the
+    layout :func:`forward_decode_paged` reads.  Slots of the last block
+    past the prompt's true length hold the pad tokens' K/V: nothing
+    reads a slot at or past a row's length, and decode overwrites each
+    before the length passes it (the contract of the decode window's
+    uncommitted slots).  The caller donates the pools, so the scatter
+    is in place and no K/V leaves the device.
+    """
+    x, k, v = _prefill_trunk(params, ids, cfg)
+    n_layers, _, t, h, d = k.shape
+    bs = k_pool.shape[2]
+
+    def paged(a, pool):
+        return a.reshape(n_layers, t // bs, bs, h, d).astype(pool.dtype)
+
+    k_pool = k_pool.at[:, block_ids].set(paged(k, k_pool))
+    v_pool = v_pool.at[:, block_ids].set(paged(v, v_pool))
+    return _logits_at(params, x, last_index), k_pool, v_pool
 
 
 def forward_decode(params, ids, positions, k_cache, v_cache, lengths,
@@ -684,10 +718,10 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
     Dead rows (length 0) route their scatter out of bounds
     (``mode="drop"``) so padding can never corrupt a live block.
 
-    Returns ``(logits [B, S, V], k_pool, v_pool, k_new, v_new)``: the
-    updated pools (the caller adopts them — window slots past what it
-    commits hold garbage by the same contract as gather padding) and
-    the window K/V ``[L, B, S, H, hd]`` for the host-mirror append.
+    Returns ``(logits [B, S, V], k_pool, v_pool)``: the updated pools
+    are the cache (the caller adopts them and advances each row's
+    length by what it commits — window slots past that hold garbage by
+    the same contract as gather padding).
     """
     from ..ops import paged_attention as _paged
 
@@ -706,7 +740,6 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
     blocks = params["blocks"]
     n_stages, lps = blocks["ln1"].shape[0], blocks["ln1"].shape[1]
-    k_news, v_news = [], []
     li = 0
     for s in range(n_stages):
         for i in range(lps):
@@ -727,13 +760,11 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
                 x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
             with jax.named_scope("mlp"):
                 x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
-            k_news.append(k)
-            v_news.append(v)
             li += 1
     with jax.named_scope("unembed"):
         x = rms_norm(x, params["ln_f"])
         logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
-    return logits, k_pool, v_pool, jnp.stack(k_news), jnp.stack(v_news)
+    return logits, k_pool, v_pool
 
 
 def make_train_step(mesh, cfg: TransformerConfig, optimizer=None,

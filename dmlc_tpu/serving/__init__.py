@@ -5,7 +5,9 @@ inference server over the flagship transformer, built from the pieces
 the repo already trusts —
 
   * ``kv_cache``   paged (block-granular) KV storage with a free-list
-                   allocator; gathered views shard over parallel.mesh
+                   allocator; the bytes live on the device (paged path)
+                   or on the host, whose gathered views shard over
+                   parallel.mesh (gather path)
   * ``scheduler``  Orca-style iteration-level admit/evict with
                    preemption-by-recompute under memory pressure
   * ``engine``     the prefill/decode loop: jitted model programs,

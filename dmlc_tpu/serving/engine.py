@@ -1,8 +1,8 @@
 """Inference engine: the per-iteration prefill/decode loop.
 
-The engine owns the compute half of serving: jitted
-``models.forward_prefill`` / ``models.forward_decode`` programs, the
-paged cache's data plane, greedy sampling, and the instrumentation
+The engine owns the compute half of serving: the jitted prefill and
+decode programs of ``models.transformer``, the paged cache's data
+plane, greedy sampling, and the instrumentation
 contract — every decode iteration is a **step** on the PR 5
 :class:`telemetry.StepLedger` (``step_begin``/``step_end`` with the
 batch's token count and the exact forward FLOPs given each sequence's
@@ -28,14 +28,24 @@ burn-rate objectives behind ``/slo``).
 
 Where an iteration's time goes is measured from inside, with
 ``telemetry.span``: ``serving.iteration`` and under it disjoint
-children (``serving.schedule``, ``serving.prefill`` with ``.run`` and
-``.kv_to_host``, ``serving.kv_write``, ``serving.first_token``,
-``serving.decode`` with ``.dispatch`` / ``.fetch`` / ``.commit`` /
-``.deliver`` / ``.bookkeeping``), each carrying ``args.iter``; an idle
-episode of the loop is one ``serving.starved`` span.  Every span is a
-host event in a profiler capture and a ``<suffix>_secs`` /
-``<suffix>_count`` counter pair; the bytes that cross the host link
-are counted at the same boundaries (README "Serving").
+children (``serving.schedule``, ``serving.prefill`` with ``.run``,
+``serving.kv_write``, ``serving.first_token``, ``serving.decode`` with
+``.dispatch`` / ``.fetch`` / ``.commit`` / ``.deliver`` /
+``.bookkeeping``), each carrying ``args.iter``; an idle episode of the
+loop is one ``serving.starved`` span.  Every span is a host event in a
+profiler capture and a ``<suffix>_secs`` / ``<suffix>_count`` counter
+pair; the bytes that cross the host link are counted at the same
+boundaries (README "Serving").
+
+Where the cache's bytes live follows the data path.  On the paged path
+the device pools are the cache: the prefill program scatters the
+prompt's K/V into the sequence's blocks and the decode program the
+window's, the engine adopts the pools they return, and only logits
+cross the link (``serving.kv_write`` is then the adoption and the
+length bookkeeping).  On the gather path (``DMLC_SERVE_PAGED_ATTN=off``
+or a mesh that shards the cache) the cache is host-resident: prefill's
+K/V come to numpy under ``serving.prefill.kv_to_host``, ``kv_write``
+copies them in, and decode hands its new K/V back the same way.
 
 Shape discipline (XLA recompiles per shape, so both are bucketed):
 prefill pads prompts up to a whole number of KV blocks (safe under
@@ -58,7 +68,7 @@ from .. import concurrency, telemetry
 from ..base import DMLCError, get_env
 from ..concurrency import BufferPool, make_lock
 from ..models import transformer as tfm
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, kv_partition_spec
 from .scheduler import (ACTIVE, WAITING, AlreadyFinished,
                         ContinuousBatchScheduler, Request,
                         coerce_priority)
@@ -85,7 +95,10 @@ _JIT_CACHE: dict = {}
 
 #: every counter the serving spans and byte counts feed: start() sets
 #: them to 0, so a window in which a phase never ran reads 0 and not
-#: "nothing to read"
+#: "nothing to read".  ``prefill_kv_to_host`` opens on the gather path
+#: only and ``kv_upload`` nowhere since the device pools became the
+#: cache; BENCHMARK.json's K/V round-trip metrics sum them with
+#: ``kv_write``, so they stay, at 0
 _SPAN_FAMILIES = (
     "iteration", "schedule", "prefill", "prefill_run",
     "prefill_kv_to_host", "kv_write", "kv_upload", "first_token",
@@ -156,12 +169,18 @@ def _jitted_programs(use_paged: bool = False, window: int = 1):
     and smokes build several engines and must not pay XLA again for
     identical shapes).
 
-    The decode program depends on the engine's data path: the gather
+    Both programs depend on the engine's data path.  Prefill, site
+    ``serving.prefill`` either way (an engine runs one of the two):
+    ``forward_prefill_paged`` with the pools DONATED — it has this one
+    caller, which replaces its references with the returned pools at
+    once, and an undonated scatter would copy both pools per prompt —
+    or the gather path's ``forward_prefill_last``.  Decode: the gather
     oracle (``forward_decode``, site ``serving.decode``), its
     multi-token speculative-verify twin (``forward_decode_spec``, site
     ``serving.decode_spec``), or the paged fast path
     (``forward_decode_paged``, site ``serving.decode_paged`` — one
-    program serves any verify window, the window is a shape).  All go
+    program serves any verify window, the window is a shape; its pools
+    are not donated yet, ROADMAP A3).  All go
     through :func:`telemetry.compute.profiled_jit`, which is plain
     ``jax.jit`` when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on
     that mode so toggling the knob between tests cannot hand a plain
@@ -171,7 +190,13 @@ def _jitted_programs(use_paged: bool = False, window: int = 1):
     growth is a bug worth failing loudly on."""
     compute = telemetry.compute
     mode = "profiled" if compute.enabled() else "plain"
+    prefill_key = (mode, "prefill")
+    prefill_fn, prefill_kw = tfm.forward_prefill_last, {
+        "static_argnums": (3,)}
     if use_paged:
+        prefill_key = (mode, "prefill_paged")
+        prefill_fn, prefill_kw = tfm.forward_prefill_paged, {
+            "static_argnums": (6,), "donate_argnums": (3, 4)}
         decode_key = (mode, "decode_paged")
         builder = lambda cap: compute.profiled_jit(  # noqa: E731
             tfm.forward_decode_paged, site="serving.decode_paged",
@@ -186,7 +211,6 @@ def _jitted_programs(use_paged: bool = False, window: int = 1):
         builder = lambda cap: compute.profiled_jit(  # noqa: E731
             tfm.forward_decode, site="serving.decode",
             static_argnums=(6,), max_signatures=cap)
-    prefill_key = (mode, "prefill")
     progs = (_JIT_CACHE.get(prefill_key), _JIT_CACHE.get(decode_key))
     if progs[0] is None or progs[1] is None:
         # this cache outlives any one engine — if the first engine of
@@ -200,8 +224,7 @@ def _jitted_programs(use_paged: bool = False, window: int = 1):
         try:
             if progs[0] is None:
                 _JIT_CACHE[prefill_key] = compute.profiled_jit(
-                    tfm.forward_prefill_last, site="serving.prefill",
-                    static_argnums=(3,))
+                    prefill_fn, site="serving.prefill", **prefill_kw)
             if progs[1] is None:
                 _JIT_CACHE[decode_key] = builder(
                     get_env("DMLC_SERVE_MAX_DECODE_SIGS", 64))
@@ -254,13 +277,34 @@ class InferenceEngine:
         self.priority_default = min(
             max(0, get_env("DMLC_SERVE_PRIORITY_DEFAULT", 1)),
             self.priority_levels - 1)
+        # decode fast path: paged attention reads the pool in place
+        # (no per-step dense gather / re-placement copy) and an n-gram
+        # drafter turns one verify launch into up to spec_k+1 committed
+        # tokens.  "auto" takes the paged path except when the mesh
+        # demands the gather view's dp/tp re-placement (the paged
+        # program is single-chip for now)
+        self.paged_mode = str(get_env("DMLC_SERVE_PAGED_ATTN",
+                                      "auto")).lower()
+        if self.paged_mode not in ("auto", "on", "off"):
+            raise ValueError(
+                f"DMLC_SERVE_PAGED_ATTN must be auto|on|off, got "
+                f"{self.paged_mode!r}")
+        if self.paged_mode == "auto":
+            self._use_paged = (mesh is None
+                               or kv_partition_spec(mesh) is None)
+        else:
+            self._use_paged = self.paged_mode == "on"
+        # the path decides where the cache's bytes live: on the paged
+        # path the device pools are the cache, on the gather path the
+        # host's numpy pools are
         self.cache = PagedKVCache(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
             n_blocks=(n_blocks if n_blocks is not None
                       else get_env("DMLC_SERVE_KV_BLOCKS", 256)),
             block_size=(block_size if block_size is not None
                         else get_env("DMLC_SERVE_KV_BLOCK_SIZE", 16)),
-            dtype=np.dtype(cfg.dtype), mesh=mesh)
+            dtype=np.dtype(cfg.dtype), mesh=mesh,
+            device_resident=self._use_paged)
         self.scheduler = ContinuousBatchScheduler(
             self.cache, max_active=self.max_active)
         depth = (queue_depth if queue_depth is not None
@@ -288,29 +332,9 @@ class InferenceEngine:
         self._dedupe = _DedupeTable(get_env("DMLC_SERVE_DEDUPE_MAX", 512))
         self._crash_requeue_max = get_env(
             "DMLC_SERVE_CRASH_REQUEUE_MAX", 2)
-        # decode fast path: paged attention reads the pool in place
-        # (no per-step dense gather / re-placement copy) and an n-gram
-        # drafter turns one verify launch into up to spec_k+1 committed
-        # tokens.  "auto" takes the paged path except when the mesh
-        # demands the gather view's dp/tp re-placement (the paged
-        # program is single-chip for now)
-        self.paged_mode = str(get_env("DMLC_SERVE_PAGED_ATTN",
-                                      "auto")).lower()
-        if self.paged_mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"DMLC_SERVE_PAGED_ATTN must be auto|on|off, got "
-                f"{self.paged_mode!r}")
         self.spec_k = max(0, int(get_env("DMLC_SERVE_SPEC_K", 0)))
         self.spec_min_ctx = max(1, int(get_env("DMLC_SERVE_SPEC_MIN_CTX",
                                                4)))
-        if self.paged_mode == "auto":
-            from .kv_cache import kv_partition_spec
-
-            sharded = mesh is not None and \
-                kv_partition_spec(mesh) is not None
-            self._use_paged = not sharded
-        else:
-            self._use_paged = self.paged_mode == "on"
         self._spec_window = 1 + self.spec_k
         self._prefill, self._decode = _jitted_programs(
             self._use_paged, self._spec_window)
@@ -691,7 +715,11 @@ class InferenceEngine:
             self._slots.release(slot)
 
     def _run_prefill(self, req: Request) -> None:
-        """Prefill ``req``'s context and cache its K/V.  A fresh request
+        """Prefill ``req``'s context and cache its K/V: on the paged
+        path inside the device program, which scatters them into the
+        request's blocks of the pools it is donated (only the logits
+        come to the host); on the gather path through numpy into the
+        host-resident cache.  A fresh request
         also samples its first token here (that IS the TTFT moment); a
         preemption resume must NOT sample — its context already excludes
         the un-consumed ``generated[-1]``, so the last-position logits
@@ -719,30 +747,64 @@ class InferenceEngine:
                     len(self._prompt_buckets))
             ids = np.zeros((1, padded), np.int32)
             ids[0, :n] = ctx
+            last = np.array([n - 1], np.int32)
             self.requests.on_prefill_begin(req.id, resume=resume)
-            with self._span("serving.prefill", tokens=n, req=req.id):
-                with self._span("serving.prefill.run", req=req.id):
-                    logits, k, v = self._prefill(
-                        self.params, ids, np.array([n - 1], np.int32),
-                        self.cfg)
-                    logits = np.asarray(logits[0])
-                with self._span("serving.prefill.kv_to_host",
-                                req=req.id) as crossed:
-                    k = np.asarray(k)
-                    v = np.asarray(v)
-                    crossed["bytes"] = k.nbytes + v.nbytes
-            telemetry.inc("serving", "prefill_d2h_bytes",
-                          logits.nbytes + crossed["bytes"])
+            if self._use_paged:
+                logits = self._prefill_paged(req, ids, last, n)
+            else:
+                logits = self._prefill_gather(req, ids, last, n)
             telemetry.inc("serving", "prefill_tokens", n)
-            with self._span("serving.kv_write", req=req.id):
-                self.cache.write(req.id, k[:, 0, :n], v[:, 0, :n], start=0)
         except Exception as e:  # noqa: BLE001 - fail THIS request only
             logger.error("prefill of request %d failed: %r", req.id, e)
             self._finish(req, error=f"prefill failed: {e!r}",
                          reason="prefill")
+            if self._use_paged and self.cache.drop_lost_pools():
+                # the program failed AFTER its donated pools were given
+                # up (a device fault, not a compile error): every live
+                # sequence's K/V went with them.  That is an iteration
+                # crash, not one request's failure: _loop requeues the
+                # active requests, whose re-prefill fills fresh pools
+                raise
             return
         with self._span("serving.first_token", req=req.id):
             self._after_prefill(req, logits, resume)
+
+    def _prefill_paged(self, req: Request, ids, last, n: int):
+        """The device program writes the K/V into the request's blocks
+        (prefill pads to whole blocks, so its block table IS the padded
+        prompt's); the logits alone cross the link."""
+        with self._span("serving.prefill", tokens=n, req=req.id):
+            with self._span("serving.prefill.run", req=req.id):
+                k_pool, v_pool = self.cache.device_pools()
+                logits, k_pool, v_pool = self._prefill(
+                    self.params, ids, last, k_pool, v_pool,
+                    np.asarray(self.cache.block_table(req.id), np.int32),
+                    self.cfg)
+                logits = np.asarray(logits[0])
+        telemetry.inc("serving", "prefill_d2h_bytes", logits.nbytes)
+        with self._span("serving.kv_write", req=req.id):
+            self.cache.adopt_device_pools(k_pool, v_pool)
+            self.cache.advance_many([(req.id, n)])
+        return logits
+
+    def _prefill_gather(self, req: Request, ids, last, n: int):
+        """K and V come to numpy and are copied into the host-resident
+        cache."""
+        with self._span("serving.prefill", tokens=n, req=req.id):
+            with self._span("serving.prefill.run", req=req.id):
+                logits, k, v = self._prefill(self.params, ids, last,
+                                             self.cfg)
+                logits = np.asarray(logits[0])
+            with self._span("serving.prefill.kv_to_host",
+                            req=req.id) as crossed:
+                k = np.asarray(k)
+                v = np.asarray(v)
+                crossed["bytes"] = k.nbytes + v.nbytes
+        telemetry.inc("serving", "prefill_d2h_bytes",
+                      logits.nbytes + crossed["bytes"])
+        with self._span("serving.kv_write", req=req.id):
+            self.cache.write(req.id, k[:, 0, :n], v[:, 0, :n], start=0)
+        return logits
 
     def _after_prefill(self, req: Request, logits, resume: bool) -> None:
         """Sample the first token of a fresh request and activate it."""
@@ -921,11 +983,11 @@ class InferenceEngine:
         with self._span("serving.decode.dispatch"):
             if self._use_paged:
                 # fast path: NO dense gather, NO re-placement copy — the
-                # program reads the device-resident pools in place
-                # through the block tables (a [B, W] int32 array is all
-                # that ships)
+                # program reads and writes the device-resident pools in
+                # place through the block tables (a [B, W] int32 array
+                # is all that ships) and hands no K/V back
                 k_pool, v_pool = self.cache.device_pools()
-                logits, k_pool, v_pool, k_new, v_new = self._decode(
+                logits, k_pool, v_pool = self._decode(
                     self.params, ids, positions, k_pool, v_pool, tables,
                     lengths, self.cfg)
                 self.cache.adopt_device_pools(k_pool, v_pool)
@@ -944,13 +1006,15 @@ class InferenceEngine:
                         lengths, self.cfg)
         with self._span("serving.decode.fetch") as crossed:
             logits = np.asarray(logits)
-            k_new = np.asarray(k_new)
-            v_new = np.asarray(v_new)
-            crossed["bytes"] = logits.nbytes + k_new.nbytes + v_new.nbytes
-            if logits.ndim == 2:  # single-token gather program: [B, V]
-                logits = logits[:, None]
-                k_new = k_new[:, :, None]
-                v_new = v_new[:, :, None]
+            crossed["bytes"] = logits.nbytes
+            if not self._use_paged:
+                k_new = np.asarray(k_new)
+                v_new = np.asarray(v_new)
+                crossed["bytes"] += k_new.nbytes + v_new.nbytes
+                if logits.ndim == 2:  # single-token program: [B, V]
+                    logits = logits[:, None]
+                    k_new = k_new[:, :, None]
+                    v_new = v_new[:, :, None]
         telemetry.inc("serving", "decode_d2h_bytes", crossed["bytes"])
         # per-sequence numeric health: a non-finite logit row (NaN/Inf
         # from a poisoned cache page or an overflowed activation) would
@@ -1008,15 +1072,22 @@ class InferenceEngine:
                         break
                     outcomes.append((req, i, n_row, fail, done))
                     n_tokens += n_row
-            # ONE batched host-mirror write covering every row's
-            # committed prefix (contiguous by construction): the
-            # per-row write calls were dominated by lock/GIL
-            # crossings, not bytes moved.  Must land before any
-            # _finish below — finishing frees blocks.
-            self.cache.write_many(
-                [(req.id, k_new[:, i, :n_row], v_new[:, i, :n_row])
-                 for req, i, n_row, _, _ in outcomes if n_row],
-                device_synced=self._use_paged)
+            # ONE batched cache visit covering every row's committed
+            # prefix (contiguous by construction): per-row calls were
+            # dominated by lock/GIL crossings, not bytes moved.  The
+            # paged program already wrote the window's K/V at each
+            # row's length, so there the commit is the lengths alone
+            # (a rejected draft's slots stay garbage past the length).
+            # Must land before any _finish below — finishing frees
+            # blocks.
+            if self._use_paged:
+                self.cache.advance_many(
+                    [(req.id, n_row)
+                     for req, _, n_row, _, _ in outcomes if n_row])
+            else:
+                self.cache.write_many(
+                    [(req.id, k_new[:, i, :n_row], v_new[:, i, :n_row])
+                     for req, i, n_row, _, _ in outcomes if n_row])
             for req, i, n_row, fail, done in outcomes:
                 if n_row:
                     self.requests.on_token(req.id, n=n_row)

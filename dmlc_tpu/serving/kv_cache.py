@@ -13,26 +13,32 @@ Pool layout (layer-major, mirroring the paged-attention kernel shapes):
 
     k_pool / v_pool : [n_layers, n_blocks, block_size, n_heads, head_dim]
 
-Two decode data paths share this bookkeeping.  The paged fast path
-(``DMLC_SERVE_PAGED_ATTN``) keeps device-resident pool twins
-(:meth:`device_pools` / :meth:`adopt_device_pools`) and ships only the
-tiny int32 :meth:`block_tables_array` per step — the model attends the
-pool in place (ops/paged_attention) and no dense view is ever built.
-The gather path remains the oracle twin and the sharded-mesh route:
-:meth:`PagedKVCache.gather` materializes a dense padded
-``[L, B, T, H, D]`` view for a decode batch (whole blocks are copied;
-slots past a sequence's length carry garbage the attention mask
-ignores), and :meth:`shard_gathered` places that view over a
-``parallel.mesh`` — batch over ``dp``, heads over ``tp`` — so the
-decode matmuls run sharded under jit.  Prefill attention goes through
-the model layer's existing dispatch (Pallas flash on TPU, the
-materialized oracle elsewhere); an sp-sharded ring/Ulysses prefill for
-very long prompts is future work — the cache is layout-ready for it
-(it only ever stores the resulting per-layer K/V).
+The bookkeeping (allocator, block tables, lengths, ``stats()``) is
+shared; the bytes live in exactly ONE place, chosen at construction,
+and the two residences share no data-plane code:
+
+* *device* (``device_resident=True``, the engine's paged path): the
+  pools are device arrays and they ARE the cache.  Device programs
+  write them (``models.forward_prefill_paged`` scatters a prompt's K/V
+  into its blocks, ``forward_decode_paged`` the decode window's) and
+  the engine swaps in what they return (:meth:`device_pools` /
+  :meth:`adopt_device_pools`); the host only advances lengths
+  (:meth:`advance_many`) and ships the tiny int32
+  :meth:`block_tables_array` per step.  No K/V ever crosses the host
+  link and no numpy pool exists; ``write`` / ``gather`` refuse.
+* *host* (the default; the engine's gather path — the oracle twin and
+  the sharded-mesh route): numpy pools.  :meth:`write` /
+  :meth:`write_many` copy K/V in, :meth:`PagedKVCache.gather`
+  materializes a dense padded ``[L, B, T, H, D]`` view for a decode
+  batch (whole blocks are copied; slots past a sequence's length carry
+  garbage the attention mask ignores), and :meth:`shard_gathered`
+  places that view over a ``parallel.mesh`` — batch over ``dp``, heads
+  over ``tp`` — so the decode matmuls run sharded under jit.  No device
+  twin; ``device_pools`` refuses.
 
 Thread-safety: all bookkeeping is lock-protected, but the data plane
-(write/gather) assumes the engine's single step thread — the same
-contract as the training feed.
+assumes the engine's single step thread — the same contract as the
+training feed.
 """
 
 from __future__ import annotations
@@ -139,13 +145,17 @@ class PagedKVCache:
 
     ``n_layers/n_heads/head_dim`` come from the model config;
     ``n_blocks × block_size`` is the total token capacity shared by all
-    concurrent requests.  ``mesh`` (optional) enables
-    :meth:`shard_gathered` device placement.
+    concurrent requests.  ``device_resident`` picks where the bytes
+    live (module docstring): device arrays that device programs write,
+    or numpy pools that ``write`` fills and ``gather`` reads.  ``mesh``
+    (optional, host residence) enables :meth:`shard_gathered` device
+    placement.
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int, *,
                  n_blocks: int = 256, block_size: int = 16,
-                 dtype=np.float32, mesh=None):
+                 dtype=np.float32, mesh=None,
+                 device_resident: bool = False):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.n_layers = int(n_layers)
@@ -154,27 +164,24 @@ class PagedKVCache:
         self.n_blocks = int(n_blocks)
         self.block_size = int(block_size)
         self.mesh = mesh
-        shape = (self.n_layers, self.n_blocks, self.block_size,
-                 self.n_heads, self.head_dim)
+        self.dtype = np.dtype(dtype)
+        self.device_resident = bool(device_resident)
+        self.pool_shape = (self.n_layers, self.n_blocks, self.block_size,
+                           self.n_heads, self.head_dim)
+        # host residence: the numpy pools (None on a device-resident
+        # cache, which never holds K/V on the host)
+        host = not self.device_resident
         # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self.k_pool = np.zeros(shape, dtype)
+        self.k_pool = np.zeros(self.pool_shape, self.dtype) if host else None
         # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self.v_pool = np.zeros(shape, dtype)
-        # device twins of the pools for the paged-attention fast path:
-        # lazily created, kept in sync block-granularly — host writes
-        # (prefill) mark their blocks dirty and device_pools() uploads
-        # just those; decode-step scatter happens IN the jitted program,
-        # whose updated pools the engine hands back via
-        # adopt_device_pools (the host mirror gets the same tokens
-        # through append_from_device, which skips the dirty mark)
+        self.v_pool = np.zeros(self.pool_shape, self.dtype) if host else None
+        # device residence: the pools, made as zeros ON the device by
+        # the first device_pools() and replaced by whatever the last
+        # prefill / decode program returned
         # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
         self._dev_k = None
         # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
         self._dev_v = None
-        # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self._dirty_blocks: set = set()
-        # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self._upload_jit = None
         # block-table memo: the tables themselves change only when some
         # sequence gains or loses blocks (every ~block_size committed
         # tokens), not every decode step — the version counter lets
@@ -312,60 +319,37 @@ class PagedKVCache:
             raise DMLCError(f"unknown sequence {seq_id}")
         return ent
 
-    # ---- data plane -----------------------------------------------------
-    def write(self, seq_id: int, k, v, start: Optional[int] = None, *,
-              device_synced: bool = False) -> None:
-        """Write ``k/v [L, T, H, D]`` at token offset ``start`` (default:
-        the current length — append semantics).  Capacity must already
-        be reserved (allocate/extend); writing past it raises rather
-        than silently growing, keeping the eviction policy in the
-        scheduler where it belongs.  ``device_synced`` marks a write
-        whose bytes the device pools ALREADY hold (a decode-step
-        scatter adopted via :meth:`adopt_device_pools`) — it updates
-        the host mirror without dirtying the blocks for re-upload."""
-        k = np.asarray(k)
-        v = np.asarray(v)
-        t = k.shape[1]
-        with self._lock:
-            ent = self._seq(seq_id)
-            pos = ent.length if start is None else int(start)
-            end = pos + t
-            if self.blocks_for(end) > len(ent.blocks):
-                raise DMLCError(
-                    f"write past reservation: seq {seq_id} end={end} "
-                    f"blocks={len(ent.blocks)}×{self.block_size}")
-            blocks = list(ent.blocks)
-            new_len = max(ent.length, end)
-            self._cached_tokens += new_len - ent.length
-            ent.length = new_len
-        bs = self.block_size
-        off = 0
-        touched = set()
-        while off < t:
-            p = pos + off
-            blk = blocks[p // bs]
-            slot = p % bs
-            n = min(bs - slot, t - off)
-            self.k_pool[:, blk, slot:slot + n] = k[:, off:off + n]
-            self.v_pool[:, blk, slot:slot + n] = v[:, off:off + n]
-            if not device_synced:
-                touched.add(blk)
-            off += n
-        if touched:
-            if self._dev_k is not None:
-                # write-through: upload NOW, once per prefill/resume,
-                # so the decode hot loop never pays an upload — before
-                # this, every decode step following a prefill re-synced
-                # dirty blocks and the eager scatter dispatch was ~half
-                # the decode step wall on small models
-                self._upload_blocks(touched)
-            else:
-                self._dirty_blocks.update(touched)
+    # ---- lengths (both residences) --------------------------------------
+    def _grow_to(self, seq_id: int, ent: _SeqEntry, end: int) -> None:
+        """Lock held: the sequence now holds tokens up to ``end``.
+        Capacity must already be reserved (allocate/extend); growing
+        past it raises rather than silently allocating, keeping the
+        eviction policy in the scheduler where it belongs."""
+        if self.blocks_for(end) > len(ent.blocks):
+            raise DMLCError(
+                f"write past reservation: seq {seq_id} end={end} "
+                f"blocks={len(ent.blocks)}×{self.block_size}")
+        if end > ent.length:
+            self._cached_tokens += end - ent.length
+            ent.length = end
 
-    def write_many(self, updates, *, device_synced: bool = False) -> None:
+    def _residence(self, device: bool, what: str) -> None:
+        if self.device_resident != device:
+            raise DMLCError(
+                f"{what} is for a {'device' if device else 'host'}-resident "
+                f"cache; this one keeps its K/V on the "
+                f"{'device' if self.device_resident else 'host'}")
+
+    # ---- data plane, host residence -------------------------------------
+    def write(self, seq_id: int, k, v, start: Optional[int] = None) -> None:
+        """Write ``k/v [L, T, H, D]`` at token offset ``start`` (default:
+        the current length — append semantics)."""
+        self.write_many([(seq_id, k, v)], start=start)
+
+    def write_many(self, updates, *, start: Optional[int] = None) -> None:
         """Batched :meth:`write`: ``updates`` is ``[(seq_id, k, v), ...]``
-        with each ``k/v [L, T, H, D]`` appended at that sequence's
-        current length.
+        with each ``k/v [L, T, H, D]`` written at ``start`` (default:
+        appended at that sequence's current length).
 
         One lock acquisition covers the whole batch.  The per-row
         ``write`` calls on the decode commit path were dominated not by
@@ -373,27 +357,19 @@ class PagedKVCache:
         handler threads live, every release is a chance to lose the GIL
         for a scheduler quantum, and the commit walk made one such
         crossing per row per step."""
-        if not updates:
-            return
+        self._residence(False, "write")
         plans = []
         with self._lock:
             for seq_id, k, v in updates:
                 k = np.asarray(k)
                 v = np.asarray(v)
-                t = k.shape[1]
                 ent = self._seq(seq_id)
-                pos = ent.length
-                end = pos + t
-                if self.blocks_for(end) > len(ent.blocks):
-                    raise DMLCError(
-                        f"write past reservation: seq {seq_id} end={end} "
-                        f"blocks={len(ent.blocks)}×{self.block_size}")
-                self._cached_tokens += end - ent.length
-                ent.length = end
-                plans.append((list(ent.blocks), pos, t, k, v))
+                pos = ent.length if start is None else int(start)
+                self._grow_to(seq_id, ent, pos + k.shape[1])
+                plans.append((list(ent.blocks), pos, k, v))
         bs = self.block_size
-        touched = set()
-        for blocks, pos, t, k, v in plans:
+        for blocks, pos, k, v in plans:
+            t = k.shape[1]
             off = 0
             while off < t:
                 p = pos + off
@@ -402,91 +378,63 @@ class PagedKVCache:
                 n = min(bs - slot, t - off)
                 self.k_pool[:, blk, slot:slot + n] = k[:, off:off + n]
                 self.v_pool[:, blk, slot:slot + n] = v[:, off:off + n]
-                if not device_synced:
-                    touched.add(blk)
                 off += n
-        if touched:
-            if self._dev_k is not None:
-                self._upload_blocks(touched)
-            else:
-                self._dirty_blocks.update(touched)
 
     def append(self, seq_id: int, k, v) -> None:
-        """Append ONE token's ``k/v [L, H, D]`` (the per-decode-step
-        write path)."""
+        """Append ONE token's ``k/v [L, H, D]``."""
         self.write(seq_id, np.asarray(k)[:, None], np.asarray(v)[:, None])
 
-    def append_from_device(self, seq_id: int, k, v) -> None:
-        """Append ONE token's ``k/v [L, H, D]`` that the device pools
-        already hold (the paged decode program scattered it in place):
-        host-mirror bookkeeping only, no dirty mark, no re-upload."""
-        self.write(seq_id, np.asarray(k)[:, None], np.asarray(v)[:, None],
-                   device_synced=True)
-
-    # ---- device twins (paged-attention fast path) ----------------------
-    def _upload_blocks(self, blocks) -> None:
-        """Block-granular host→device sync of ``blocks`` into the
-        existing device twins.
-
-        Runs through a jitted scatter (eager ``.at[].set`` dispatch cost
-        roughly tripled prefill wall on small models).  The block count
-        is padded to the next power of two by REPEATING the first
-        (index, data) pair — duplicate scatter indices carrying
-        identical values are deterministic — so the jit sees a handful
-        of shapes total instead of one per count."""
-        import jax
-
-        if self._upload_jit is None:
-            self._upload_jit = jax.jit(
-                lambda pool, idx, data: pool.at[:, idx].set(data))
-        idx = np.asarray(sorted(blocks), np.int32)
-        n = len(idx)
-        padded = 1
-        while padded < n:
-            padded *= 2
-        if padded > n:
-            idx = np.concatenate([idx, np.full(padded - n, idx[0],
-                                               np.int32)])
-        with telemetry.span("serving.kv_upload", stage="serving") as crossed:
-            k_blk = self.k_pool[:, idx]
-            v_blk = self.v_pool[:, idx]
-            crossed["bytes"] = k_blk.nbytes + v_blk.nbytes
-            self._dev_k = self._upload_jit(self._dev_k, idx, k_blk)
-            self._dev_v = self._upload_jit(self._dev_v, idx, v_blk)
-        telemetry.inc("serving", "kv_upload_bytes", crossed["bytes"])
-
+    # ---- data plane, device residence -----------------------------------
     def device_pools(self):
-        """The device-resident ``(k_pool, v_pool)`` twins.  First call
-        uploads the whole pool once and flips :meth:`write` into
-        write-through mode (each prefill/resume uploads its own blocks
-        as it lands); any blocks dirtied BEFORE that first call are
-        drained here.  Steady-state decode therefore pays no upload at
-        all — the program's in-place scatter keeps the device copy
-        freshest and :meth:`adopt_device_pools` installs it."""
-        import jax.numpy as jnp
-
+        """The ``(k_pool, v_pool)`` device arrays: the cache itself.
+        Made as zeros on the device at first use (nothing is uploaded);
+        afterwards whatever :meth:`adopt_device_pools` installed last."""
+        self._residence(True, "device_pools")
         if self._dev_k is None:
-            self._dev_k = jnp.asarray(self.k_pool)
-            self._dev_v = jnp.asarray(self.v_pool)
-            self._dirty_blocks.clear()
-        elif self._dirty_blocks:
-            self._upload_blocks(self._dirty_blocks)
-            self._dirty_blocks.clear()
+            import jax.numpy as jnp
+
+            self._dev_k = jnp.zeros(self.pool_shape, self.dtype)
+            self._dev_v = jnp.zeros(self.pool_shape, self.dtype)
         return self._dev_k, self._dev_v
 
     def adopt_device_pools(self, k_pool, v_pool) -> None:
-        """Install the pools a paged decode program returned (its
-        in-program scatter made them the freshest copy)."""
+        """Install the pools a prefill or decode program returned (its
+        in-program scatter made them the cache)."""
+        self._residence(True, "adopt_device_pools")
         self._dev_k = k_pool
         self._dev_v = v_pool
+
+    def drop_lost_pools(self) -> bool:
+        """After a failed device call: whether the pools were lost with
+        it.  A program the pools are DONATED to (prefill) owns their
+        buffers from dispatch on; if it then raises, the arrays this
+        cache still references are deleted and every live sequence's
+        K/V is gone.  Forgets them (the next :meth:`device_pools`
+        starts from zeros) and returns True so the caller can recompute
+        the live sequences; False when the pools are intact."""
+        self._residence(True, "drop_lost_pools")
+        if self._dev_k is None or not (self._dev_k.is_deleted()
+                                       or self._dev_v.is_deleted()):
+            return False
+        self._dev_k = self._dev_v = None
+        return True
+
+    def advance_many(self, updates) -> None:
+        """The length-only twin of :meth:`write_many`: ``updates`` is
+        ``[(seq_id, n_tokens), ...]``, tokens whose K/V a device program
+        already wrote at each sequence's current length."""
+        self._residence(True, "advance_many")
+        with self._lock:
+            for seq_id, n in updates:
+                ent = self._seq(seq_id)
+                self._grow_to(seq_id, ent, ent.length + int(n))
 
     def block_tables_array(self, seq_ids: Sequence[int], *,
                            pad_width: Optional[int] = None,
                            pad_batch: Optional[int] = None
                            ) -> Tuple[np.ndarray, np.ndarray]:
-        """Per-sequence block tables as one dense int32 array — the
-        small indirection the paged-attention kernel ships to the
-        device INSTEAD of a gathered cache.
+        """Per-sequence block tables as one dense int32 array — all the
+        paged decode program needs from the host besides the lengths.
 
         Returns ``(tables [B, W], lengths [B])``; ``W`` = ``pad_width``
         or the max owned-block count (min 1), ``B`` = ``pad_batch`` or
@@ -542,6 +490,7 @@ class PagedKVCache:
         the decode mask ignores, used to pin the jit batch shape).
         Whole blocks are copied, so slots in [length, T) are garbage by
         contract."""
+        self._residence(False, "gather")
         with self._lock:
             ents = [self._seq(s) for s in seq_ids]
             tables = [list(e.blocks) for e in ents]
@@ -562,8 +511,8 @@ class PagedKVCache:
             t = need
         b = max(pad_batch or 0, len(seq_ids))
         shape = (self.n_layers, b, t, self.n_heads, self.head_dim)
-        k_out = np.zeros(shape, self.k_pool.dtype)
-        v_out = np.zeros(shape, self.v_pool.dtype)
+        k_out = np.zeros(shape, self.dtype)
+        v_out = np.zeros(shape, self.dtype)
         for i, (table, n) in enumerate(zip(tables, lens)):
             for j in range(self.blocks_for(n)):
                 blk = table[j]

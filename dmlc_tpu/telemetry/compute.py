@@ -306,11 +306,14 @@ class _ProfiledJit:
                        if now - t <= window_s)
 
     def reregister(self) -> None:
-        """Re-enter the site registry after a test-time
+        """Take the site in the registry: after a test-time
         :func:`reset_compute` orphaned a long-lived wrapper (the
-        serving engine caches its jitted programs process-wide)."""
+        serving engine caches its jitted programs process-wide), or
+        from another wrapper of the same site (the engine's paged and
+        gather prefill programs share ``serving.prefill``; the engine
+        built last runs one of them, and that one is the site)."""
         with _lock:
-            _sites.setdefault(self.site, self)
+            _sites[self.site] = self
 
 
 def _extract_cost(compiled) -> Optional[Dict]:

@@ -7,10 +7,13 @@ The engine-level tests pin the end-to-end contract instead: greedy
 outputs bit-identical with the fast path on, off, and with speculative
 decoding enabled, each through a forced preemption episode (the
 resume path is where a paged/spec bookkeeping bug would corrupt
-output).  The cache tests guard the host-mirror twins the fast path
-leans on: freed blocks' bytes never reach a live gather row, and the
+output).  The cache tests guard its two residences: host (the gather
+path: freed blocks' bytes never reach a live gather row, and the
 batched commit write is byte-equivalent to the per-row writes it
-replaced.
+replaced) and device (the paged path: prefill writes a sequence's
+blocks inside the program and nothing else, each residence refuses
+the other's data plane, and a prefill that fails fails alone unless it
+took the donated pools with it).
 """
 
 import json
@@ -127,7 +130,7 @@ def test_paged_attention_rejects_unknown_impl():
 
 
 # ---------------------------------------------------------------------------
-# host-mirror hardening: freed bytes, batched writes
+# the host residence (gather path): freed bytes, batched writes
 # ---------------------------------------------------------------------------
 
 def _kv(rng, n, *, layers=2, heads=2, dim=3):
@@ -211,6 +214,122 @@ def test_write_many_matches_per_row_writes():
 
 
 # ---------------------------------------------------------------------------
+# the device residence (paged path)
+# ---------------------------------------------------------------------------
+
+def test_each_residence_refuses_the_others_data_plane():
+    k, v = _kv(np.random.default_rng(0), 3)
+    dev = PagedKVCache(2, 2, 3, n_blocks=4, block_size=4,
+                       device_resident=True)
+    assert dev.k_pool is None and dev.v_pool is None  # no numpy pool
+    assert dev.allocate(1, 3)
+    for call in (lambda: dev.write(1, k, v),
+                 lambda: dev.write_many([(1, k, v)]),
+                 lambda: dev.append(1, k[:, 0], v[:, 0]),
+                 lambda: dev.gather([1])):
+        with pytest.raises(DMLCError, match="host-resident"):
+            call()
+    assert dev.length(1) == 0  # a refused write moved no length
+    k_pool, v_pool = dev.device_pools()
+    assert k_pool.shape == v_pool.shape == (2, 4, 4, 2, 3)
+    assert not np.asarray(k_pool).any()  # made as zeros, on the device
+    dev.advance_many([(1, 3)])
+    assert dev.length(1) == 3 and dev.stats()["cached_tokens"] == 3
+    with pytest.raises(DMLCError, match="past reservation"):
+        dev.advance_many([(1, 2)])  # 5 tokens in a 1-block reservation
+
+    host = PagedKVCache(2, 2, 3, n_blocks=4, block_size=4)
+    assert host.allocate(1, 3)
+    for call in (host.device_pools,
+                 lambda: host.adopt_device_pools(k_pool, v_pool),
+                 lambda: host.advance_many([(1, 3)]),
+                 host.drop_lost_pools):
+        with pytest.raises(DMLCError, match="device-resident"):
+            call()
+    host.write(1, k, v)
+    assert host.length(1) == 3
+
+
+@pytest.mark.parametrize("n", [8, 6])  # n % block_size == 0 and != 0
+def test_paged_prefill_writes_exactly_the_sequences_blocks(n):
+    """After a paged prefill the pool blocks of the sequence's table
+    hold exactly the k, v that forward_prefill_last returns for [:n],
+    the logits are the same, and no other block changed."""
+    import jax
+
+    from dmlc_tpu.models import transformer as tfm
+
+    params, cfg = _tiny_model()
+    bs, n_blocks = 4, 7
+    padded = n + (-n % bs)
+    ids = np.zeros((1, padded), np.int32)
+    ids[0, :n] = np.arange(1, n + 1)
+    last = np.array([n - 1], np.int32)
+    want_logits, want_k, want_v = jax.jit(
+        tfm.forward_prefill_last, static_argnums=(3,))(params, ids, last,
+                                                       cfg)
+    rng = np.random.default_rng(n)
+    shape = (cfg.n_layers, n_blocks, bs, cfg.n_heads, cfg.head_dim)
+    k0, v0 = _rand(rng, *shape), _rand(rng, *shape)
+    table = np.array([5, 2], np.int32)  # neither contiguous nor ordered
+    prog = jax.jit(tfm.forward_prefill_paged, static_argnums=(6,),
+                   donate_argnums=(3, 4))  # as the engine compiles it
+    logits, k_pool, v_pool = prog(params, ids, last, jax.numpy.asarray(k0),
+                                  jax.numpy.asarray(v0), table, cfg)
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    for got, want, before in ((np.asarray(k_pool), np.asarray(want_k), k0),
+                              (np.asarray(v_pool), np.asarray(want_v), v0)):
+        seq = got[:, table].reshape(cfg.n_layers, padded, cfg.n_heads,
+                                    cfg.head_dim)
+        np.testing.assert_array_equal(seq[:, :n], want[:, 0, :n])
+        others = np.setdiff1d(np.arange(n_blocks), table)
+        np.testing.assert_array_equal(got[:, others], before[:, others])
+
+
+@pytest.mark.parametrize("pools_lost", [False, True])
+def test_failed_prefill_fails_alone_unless_it_took_the_pools(pools_lost):
+    """An injected prefill failure fails its own request.  Raised
+    before the donated pools were given up, nothing else notices; raised
+    after (the arrays the cache holds are deleted), the engine treats
+    it as an iteration crash: the live request is requeued, re-prefilled
+    into fresh pools, and still emits the oracle's tokens."""
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
+                          max_active=2, queue_depth=4)
+    assert eng._use_paged
+    real = eng._prefill
+    poison = 63
+
+    def prefill(p, ids, last, k_pool, v_pool, blocks, c):
+        if ids[0, 0] == poison:
+            if pools_lost:
+                k_pool.delete()  # what a donating call that raised after
+                v_pool.delete()  # dispatch leaves behind
+            raise RuntimeError("injected prefill failure")
+        return real(p, ids, last, k_pool, v_pool, blocks, c)
+
+    eng._prefill = prefill
+    before = telemetry.counters_snapshot().get("serving", {}).get(
+        "crash_requeues", 0)
+    eng.start()
+    try:
+        good = eng.submit([1, 2, 3, 4, 5], max_new_tokens=12)
+        while good.n_generated < 3:  # live, with K/V in the pools
+            assert not good.wait(0.005)
+        bad = eng.submit([poison, 1, 2], max_new_tokens=4)
+        assert bad.wait(300) and good.wait(300)
+    finally:
+        eng.close()
+    assert bad.error is not None and "prefill failed" in bad.error
+    assert good.error is None
+    assert good.generated == _greedy_oracle(params, cfg, [1, 2, 3, 4, 5], 12)
+    after = telemetry.counters_snapshot()["serving"].get("crash_requeues", 0)
+    assert after - before == (1 if pools_lost else 0)
+    assert eng.cache.n_blocks_in_use == 0
+
+
+# ---------------------------------------------------------------------------
 # engine-level bit-parity (real jitted compute, tiny config)
 # ---------------------------------------------------------------------------
 
@@ -236,14 +355,25 @@ def _greedy_oracle(params, cfg, prompt, n):
     return ctx[len(prompt):]
 
 
+def _prompt(i):
+    """Request i's prompt: 4, 5 and 6 tokens over blocks of 4, so a
+    prefill ends on a block boundary and inside a block, and every
+    request decodes across one."""
+    return [i + 1] * (4 + i)
+
+
 def _run_requests(params, cfg, *, n_blocks=6, max_new=10):
     """3 requests through a pool too small for them to coexist: forces
     preemption + recompute-resume.  Returns their outputs."""
     eng = InferenceEngine(params, cfg, n_blocks=n_blocks, block_size=4,
                           max_active=3, queue_depth=8)
+    # the paged path keeps no K/V on the host, the gather path none on
+    # the device
+    assert (eng.cache.k_pool is None) == eng._use_paged
+    assert eng.cache.device_resident == eng._use_paged
     eng.start()
     try:
-        reqs = [eng.submit([i + 1] * 4, max_new_tokens=max_new)
+        reqs = [eng.submit(_prompt(i), max_new_tokens=max_new)
                 for i in range(3)]
         for r in reqs:
             assert r.wait(300), f"request {r.id} never finished"
@@ -269,27 +399,34 @@ def test_paged_on_off_bit_identical_through_preemption(monkeypatch):
     assert after > before, "tiny pool must have forced preemption"
     assert outs["on"] == outs["off"]
     for i in range(3):
-        assert outs["on"][i] == _greedy_oracle(params, cfg, [i + 1] * 4, 10)
+        assert outs["on"][i] == _greedy_oracle(params, cfg, _prompt(i), 10)
 
 
-def test_spec_decode_bit_parity_through_preemption(monkeypatch):
+@pytest.mark.parametrize("paged", ["on", "off"])
+def test_spec_decode_bit_parity_through_preemption(monkeypatch, paged):
     """Speculative decoding (k=3) through the same preemption-forcing
-    pool: greedy output stays bit-identical to the oracle, and the
-    drafter actually proposed (the accept walk, not drafter silence,
-    is what kept the output exact)."""
+    pool, on the paged path (the commit advances each length by the
+    accepted count; rejected window slots stay garbage in the device
+    pool) and on the gather path: greedy output stays bit-identical to
+    the oracle, and the drafter actually proposed (the accept walk, not
+    drafter silence, is what kept the output exact)."""
     params, cfg = _tiny_model()
+    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", paged)
     monkeypatch.setenv("DMLC_SERVE_SPEC_K", "3")
     monkeypatch.setenv("DMLC_SERVE_SPEC_MIN_CTX", "4")
     snap = telemetry.snapshot()["counters"].get("serving", {})
     before_prop = snap.get("spec_proposed", 0)
+    before_acc = snap.get("spec_accepted", 0)
     before_pre = snap.get("preemptions", 0)
     outs = _run_requests(params, cfg, max_new=12)
     counters = telemetry.snapshot()["counters"]["serving"]
     assert counters.get("spec_proposed", 0) > before_prop, \
         "drafter never proposed — the spec path was not exercised"
+    assert counters.get("spec_accepted", 0) > before_acc, \
+        "no draft accepted — no commit advanced a length by more than one"
     assert counters["preemptions"] > before_pre
     for i in range(3):
-        assert outs[i] == _greedy_oracle(params, cfg, [i + 1] * 4, 12)
+        assert outs[i] == _greedy_oracle(params, cfg, _prompt(i), 12)
 
 
 def test_ngram_drafter_proposes_from_own_context(monkeypatch):

@@ -415,9 +415,10 @@ def test_decode_capacity_eviction_of_already_checked_survivor():
     y = Request([3, 4], 4)   # the active list, older second: inversion
     y.submit_t = x.submit_t - 10.0
     assert eng.cache.allocate(x.id, 13)   # 4 blocks; extend stays inside
-    eng.cache.write(x.id, *_seq_kv_model(cfg, 13))
     assert eng.cache.allocate(y.id, 4)    # 1 full block; extend needs +1
-    eng.cache.write(y.id, *_seq_kv_model(cfg, 4))
+    # the paged engine's cache is device-resident: the capacity pass
+    # sees lengths alone
+    eng.cache.advance_many([(x.id, 13), (y.id, 4)])
     eng.scheduler.activate(x)
     eng.scheduler.activate(y)
     alive, n_preempted = eng._ensure_decode_capacity([x, y])
@@ -426,13 +427,6 @@ def test_decode_capacity_eviction_of_already_checked_survivor():
     assert x.state == WAITING and x.preemptions == 1
     assert x.id not in eng.cache.live_sequences()
     eng.close()
-
-
-def _seq_kv_model(cfg, n):
-    rng = np.random.default_rng(n)
-    shape = (cfg.n_layers, n, cfg.n_heads, cfg.head_dim)
-    return (rng.standard_normal(shape).astype(np.float32),
-            rng.standard_normal(shape).astype(np.float32))
 
 
 def test_engine_rejects_oversized_and_overflowing_requests():
@@ -1128,12 +1122,23 @@ def _warm_engine(**kw):
     return eng
 
 
-def test_engine_iteration_yields_the_span_tree():
+@pytest.mark.parametrize("paged", ["on", "off"])
+def test_engine_iteration_yields_the_span_tree(monkeypatch, paged):
+    """One tree on both data paths, except that only the gather path
+    (host-resident cache) brings K and V to the host."""
+    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", paged)
+    to_host = [("serving.prefill.kv_to_host", "serving.prefill")] \
+        if paged == "off" else []
+    gather = [("compute.gather", "serving.decode.dispatch")] \
+        if paged == "off" else []
     eng = _warm_engine()
     telemetry.reset()
     req = eng.submit([9, 8, 7, 6, 5, 4], max_new_tokens=6)
     assert eng.step()  # one iteration: a prefill and a decode window
-    recs = _engine_thread_spans()
+    # (the gather path's decode compiles anew whenever its dense view
+    # grows a block: a compile row is not part of the tree)
+    recs = [r for r in _engine_thread_spans()
+            if not r["name"].startswith("compile:")]
     by_id = {r["id"]: r for r in recs}
     tree = sorted((r["name"], by_id[r["parent"]]["name"]
                    if r["parent"] else None) for r in recs)
@@ -1143,9 +1148,7 @@ def test_engine_iteration_yields_the_span_tree():
         ("serving.schedule", "serving.iteration"),   # allocation
         ("serving.prefill", "serving.iteration"),
         ("serving.prefill.run", "serving.prefill"),
-        ("serving.prefill.kv_to_host", "serving.prefill"),
         ("serving.kv_write", "serving.iteration"),
-        ("serving.kv_upload", "serving.kv_write"),
         ("serving.first_token", "serving.iteration"),
         ("serving.schedule", "serving.iteration"),   # next_prefill: none
         ("serving.schedule", "serving.iteration"),   # the decode batch
@@ -1157,17 +1160,16 @@ def test_engine_iteration_yields_the_span_tree():
         ("compute.sampling", "serving.decode.commit"),
         ("serving.decode.deliver", "serving.decode"),
         ("serving.decode.bookkeeping", "serving.decode"),
-    ])
+    ] + to_host + gather)
     (it,) = [r for r in recs if r["name"] == "serving.iteration"]
     for r in recs:
-        if r["name"].startswith("serving.") \
-                and r["name"] != "serving.kv_upload":
+        if r["name"].startswith("serving."):
             assert r["args"]["iter"] == it["args"]["iter"], r
     owned = {r["name"] for r in recs
              if r.get("args", {}).get("req") == req.id}
     assert owned == {"serving.schedule", "serving.prefill",
-                     "serving.prefill.run", "serving.prefill.kv_to_host",
-                     "serving.kv_write", "serving.first_token"}
+                     "serving.prefill.run", "serving.kv_write",
+                     "serving.first_token"} | {name for name, _ in to_host}
     eng.close()
 
 
@@ -1229,23 +1231,30 @@ def test_engine_starved_is_one_span_per_episode():
     assert telemetry.counters_snapshot()["serving"]["starved_count"] == 3
 
 
-def test_engine_byte_counters_equal_what_crossed():
+@pytest.mark.parametrize("paged", ["on", "off"])
+def test_engine_byte_counters_equal_what_crossed(monkeypatch, paged):
+    """On the paged path the logits are all that crosses the link, for
+    a prefill and for a decode step; the gather path also brings the
+    K/V of both to its host-resident cache.  Nothing goes back up."""
+    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", paged)
     eng = _warm_engine()
     telemetry.reset()
     crossed = {"prefill": 0, "decode": 0}
     real_prefill, real_decode = eng._prefill, eng._decode
+    # paged programs return (logits, k_pool, v_pool) and the pools stay
+    # on the device; gather programs return (logits, k, v), all fetched
+    kv = slice(1, 1) if paged == "on" else slice(1, 3)
 
     def prefill(*a):
-        logits, k, v = real_prefill(*a)
-        crossed["prefill"] += (np.asarray(logits[0]).nbytes
-                               + np.asarray(k).nbytes + np.asarray(v).nbytes)
-        return logits, k, v
+        out = real_prefill(*a)
+        crossed["prefill"] += np.asarray(out[0][0]).nbytes + sum(
+            np.asarray(o).nbytes for o in out[kv])
+        return out
 
     def decode(*a):
-        # gather program: (logits, k_new, v_new); paged: the pools between
         out = real_decode(*a)
-        crossed["decode"] += sum(np.asarray(o).nbytes
-                                 for o in (out[0], out[-2], out[-1]))
+        crossed["decode"] += np.asarray(out[0]).nbytes + sum(
+            np.asarray(o).nbytes for o in out[kv])
         return out
 
     eng._prefill, eng._decode = prefill, decode
@@ -1255,14 +1264,20 @@ def test_engine_byte_counters_equal_what_crossed():
     c = telemetry.counters_snapshot()["serving"]
     assert c["prefill_d2h_bytes"] == crossed["prefill"] > 0
     assert c["decode_d2h_bytes"] == crossed["decode"] > 0
-    # the six-token prompt takes two blocks of 4: K and V of both go
-    # back up, [L=2, 2 blocks, 4, H=2, D=8] float32 each
-    assert c["kv_upload_bytes"] == 2 * (2 * 2 * 4 * 2 * 8 * 4)
+    assert c.get("kv_upload_bytes", 0) == 0
     recs = _engine_thread_spans()
-    for name, counter in (("serving.decode.fetch", "decode_d2h_bytes"),
-                          ("serving.kv_upload", "kv_upload_bytes")):
-        assert sum(r["args"]["bytes"] for r in recs
-                   if r["name"] == name) == c[counter]
+    assert not [r for r in recs if r["name"] == "serving.kv_upload"]
+    assert sum(r["args"]["bytes"] for r in recs
+               if r["name"] == "serving.decode.fetch") \
+        == c["decode_d2h_bytes"]
+    vocab_row = 64 * 4  # one float32 row of the tiny model's logits
+    if paged == "on":
+        assert c["prefill_d2h_bytes"] == vocab_row
+        # every step fetches the whole padded batch: max_active rows
+        assert c["decode_d2h_bytes"] == 3 * eng.max_active * vocab_row
+    else:
+        # + K and V of the padded prompt, [L=2, 1, 8, H=2, D=8] float32
+        assert c["prefill_d2h_bytes"] == vocab_row + 2 * (2 * 8 * 2 * 8 * 4)
     eng.close()
 
 
@@ -1278,8 +1293,12 @@ def test_engine_start_zeroes_its_phase_counters():
     eng.close()
     # a window in which a phase never ran reads 0, not "nothing"
     assert set(_ZEROED_COUNTERS) <= set(c)
+    # ... among them the K/V round trip's, which the paged path never
+    # opens: the benchmark's readers still find a number there
     for name in ("decode_fetch_secs", "decode_fetch_count", "http_count",
-                 "kv_upload_bytes", "queue_wait_count", "starved_secs"):
+                 "kv_upload_bytes", "queue_wait_count", "starved_secs",
+                 "prefill_kv_to_host_secs", "prefill_kv_to_host_count",
+                 "kv_upload_secs", "kv_upload_count"):
         assert c[name] == 0.0, name
 
 
