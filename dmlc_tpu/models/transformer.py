@@ -70,10 +70,60 @@ class TransformerConfig:
     # observe capacity-overflow token drops via a metrics counter (debug
     # callback per step — off by default: it adds a host sync point)
     moe_debug_overflow: bool = False
+    # ---- a second block family, served only (the paged path): latent
+    # attention + a leading dense layer + sigmoid-routed dropless
+    # experts with a shared expert (DeepSeek-V3's block, which A.X-K1
+    # shares).  The defaults above and below leave the MHA tree and
+    # programs exactly as they are.
+    attention: str = "mha"       # "mla": multi-head latent attention
+    q_lora_rank: int = 0         # MLA: query latent width
+    kv_lora_rank: int = 0        # MLA: the cached K/V latent width
+    qk_nope_head_dim: int = 0    # MLA: per-head score width without RoPE
+    qk_rope_head_dim: int = 0    # MLA: RoPE'd score width, one key for all heads
+    v_head_dim: int = 0          # MLA: per-head value width
+    rope_theta: float = 10000.0
+    # yarn (MLA only; factor 1 = plain RoPE): blended frequencies, and
+    # the softmax scale times (0.1 * mscale_all_dim * ln factor + 1)^2
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+    # layers [0, n_dense_layers) carry the dense d_ff SwiGLU, the rest
+    # experts; "sigmoid" routing is the dropless layer (_moe_held_ffn):
+    # ``n_experts`` routed experts are HELD here, [moe_held_start,
+    # moe_held_start + n_experts) of the router's moe_n_routed outputs
+    n_dense_layers: int = 0
+    moe_router: str = "softmax"  # "sigmoid": scores, top-k over all, renormalised
+    moe_n_routed: int = 0        # router width (0: n_experts, all held)
+    moe_held_start: int = 0
+    moe_d_ff: int = 0            # width of one routed (and one shared) expert
+    moe_n_shared: int = 0        # shared experts, computed for every token
+    moe_routed_scale: float = 1.0
 
     @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def latent(self) -> bool:
+        return self.attention == "mla"
+
+    def kv_pool_shapes(self, n_blocks: int, block_size: int) -> tuple:
+        """Shapes of the paged cache's pools: K and V pages of
+        ``[block_size, H, D]``, or under latent attention ONE pool
+        whose page is ``[row, block_size]``: per token the normed K/V
+        latent beside the RoPE'd key all heads share, with the slots as
+        the minor axis (a page is then K^T as the score product takes
+        it, 576 x 128 tiles without padding, and the array's default
+        device layout is the one the kernel reads: with the row minor
+        the chip's compiler laid the slots minor anyway and copied the
+        whole pool into and out of every program)."""
+        if self.latent:
+            row = self.kv_lora_rank + self.qk_rope_head_dim
+            return ((self.n_layers, n_blocks, row, block_size),)
+        return ((self.n_layers, n_blocks, block_size, self.n_heads,
+                 self.head_dim),) * 2
 
 
 def flagship_config() -> TransformerConfig:
@@ -96,10 +146,35 @@ def flagship_config() -> TransformerConfig:
 
 def count_params(cfg: TransformerConfig) -> int:
     """Total parameter count of init_params' pytree."""
+    if cfg.latent:
+        return sum(int(a.size) for a in jax.tree.leaves(
+            jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))))
     e, hd, f, x = (cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff,
                    cfg.n_experts)
     per_layer = 2 * e + 4 * e * hd + e * x + 3 * x * e * f
     return cfg.n_layers * per_layer + 2 * cfg.vocab * e + e
+
+
+def _latent_forward_flops(cfg: TransformerConfig, t: int,
+                          causal: bool) -> float:
+    """Forward matmul FLOPs one token needs under the latent block at
+    context ``t``: the five MLA projections, scores at qk and values at
+    v width, the dense or the held-expert FFN (a token's k picks land
+    here in the held share of the router's outputs), the unembed."""
+    e, h = cfg.d_model, cfg.n_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    mla = (e * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
+           + e * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+           + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+           + h * cfg.v_head_dim * e)
+    attn = (1 if causal else 2) * t * h * (qk + cfg.v_head_dim)
+    routed = cfg.moe_n_routed or cfg.n_experts
+    expert = 3 * e * cfg.moe_d_ff * (
+        cfg.moe_topk * cfg.n_experts / routed + cfg.moe_n_shared) + e * routed
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    return (cfg.n_layers * (2 * mla + attn)
+            + cfg.n_dense_layers * 2 * 3 * e * cfg.d_ff
+            + n_moe * 2 * expert + 2 * e * cfg.vocab)
 
 
 def train_flops_per_token(cfg: TransformerConfig, t: int,
@@ -109,6 +184,8 @@ def train_flops_per_token(cfg: TransformerConfig, t: int,
     matmuls.  With ``causal`` the attention term is halved — the flash
     kernels skip fully-masked KV blocks, so full-T counting would inflate
     MFU (conservative: the partially-masked diagonal blocks run full)."""
+    if cfg.latent:
+        return 3.0 * _latent_forward_flops(cfg, t, causal)
     e, hd, f, x = (cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff,
                    cfg.n_experts)
     attn = (2 if causal else 4) * t * hd
@@ -127,6 +204,8 @@ def train_step_flops(cfg: TransformerConfig, batch: int, t: int,
 
 def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
     """Global (unsharded) parameter pytree; blocks stacked [S, L/S, ...]."""
+    if cfg.latent:
+        return _init_latent_params(key, cfg, n_stages)
     assert cfg.n_layers % n_stages == 0
     lps = cfg.n_layers // n_stages
     e, h, d, f, x = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff, cfg.n_experts
@@ -152,6 +231,55 @@ def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
         "unembed": norm(next(keys), (e, cfg.vocab)),
         "ln_f": jnp.ones((e,), cfg.jdtype),
         "blocks": blk,
+    }
+
+
+def _init_latent_params(key, cfg: TransformerConfig, n_stages: int = 1):
+    """The latent family's tree: a leading-dense group ``dense``
+    stacked [n_dense_layers, ...] beside the expert layers ``blocks``
+    stacked [S, L/S, ...] as the MHA tree's are.  Both carry the MLA
+    projections (w_qa, q_norm, w_qb, w_kva, kv_norm, w_kvb, wo);
+    ``dense`` the d_ff SwiGLU, ``blocks`` the router ``gate`` over all
+    moe_n_routed experts, the n_experts held routed experts and the
+    shared expert(s) as one SwiGLU of moe_n_shared x moe_d_ff."""
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    assert n_moe % n_stages == 0 and cfg.moe_router == "sigmoid"
+    e, h, x = cfg.d_model, cfg.n_heads, cfg.n_experts
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    fm, fs = cfg.moe_d_ff, cfg.moe_n_shared * cfg.moe_d_ff
+    routed = cfg.moe_n_routed or x
+    keys = iter(jax.random.split(key, 32))
+
+    def norm(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(cfg.jdtype)
+
+    def group(lead, ffn):
+        return {
+            "ln1": jnp.ones(lead + (e,), cfg.jdtype),
+            "ln2": jnp.ones(lead + (e,), cfg.jdtype),
+            "w_qa": norm(lead + (e, rq)),
+            "q_norm": jnp.ones(lead + (rq,), cfg.jdtype),
+            "w_qb": norm(lead + (rq, h, nope + pe)),
+            "w_kva": norm(lead + (e, rkv + pe)),
+            "kv_norm": jnp.ones(lead + (rkv,), cfg.jdtype),
+            "w_kvb": norm(lead + (rkv, h, nope + dv)),
+            "wo": norm(lead + (h, dv, e)),
+            **{name: norm(lead + shape) for name, shape in ffn.items()},
+        }
+
+    return {
+        "embed": norm((cfg.vocab, e)),
+        "unembed": norm((e, cfg.vocab)),
+        "ln_f": jnp.ones((e,), cfg.jdtype),
+        "dense": group((cfg.n_dense_layers,), {
+            "w_in": (e, cfg.d_ff), "w_gate": (e, cfg.d_ff),
+            "w_out": (cfg.d_ff, e)}),
+        "blocks": group((n_stages, n_moe // n_stages), {
+            "gate": (e, routed),
+            "w_in": (x, e, fm), "w_gate": (x, e, fm), "w_out": (x, fm, e),
+            "s_in": (e, fs), "s_gate": (e, fs), "s_out": (fs, e)}),
     }
 
 
@@ -361,6 +489,10 @@ def forward_local(params, ids, labels, cfg: TransformerConfig, axes: ShardAxes,
     would transpose into one fused gradient reduction at the very end
     of backward, fully exposed.
     """
+    if cfg.latent:
+        raise NotImplementedError(
+            "the latent block (attention='mla') is served only: it has no "
+            "train step, sharding specs or backward attention kernel yet")
     b, t_local = ids.shape
     sp_rank = lax.axis_index(axes.sp) if axes.sp is not None else 0
     positions = sp_rank * t_local + jnp.arange(t_local)
@@ -435,18 +567,19 @@ def _rope_at(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def _causal_attention(q, k, v):
+def _causal_attention(q, k, v, scale=None):
     """Causal full-sequence attention with the whole sequence on this
     device (serving prefill; training without an sp axis): the Pallas
     flash kernel — O(T) memory instead of a materialized [B,H,T,T]
-    score matrix — or the lax oracle, as ops/dispatch decides."""
+    score matrix — or the lax oracle, as ops/dispatch decides.  ``v``
+    may have its own head size (latent attention: qk 192, v 128)."""
     from ..ops import dispatch
     from ..ops import flash_attention as _flash
 
-    mode = dispatch.choose(_flash.supports(q.shape, k.shape))
+    mode = dispatch.choose(_flash.supports(q.shape, k.shape, v.shape))
     if mode == dispatch.LAX:
-        return ring_attention_reference(q, k, v, causal=True)
-    return _flash.flash_attention(q, k, v, causal=True,
+        return ring_attention_reference(q, k, v, causal=True, scale=scale)
+    return _flash.flash_attention(q, k, v, causal=True, scale=scale,
                                   interpret=mode == dispatch.INTERPRET)
 
 
@@ -765,6 +898,335 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
         x = rms_norm(x, params["ln_f"])
         logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
     return logits, k_pool, v_pool
+
+
+# ---------------------------------------------------------------------------
+# the latent family on the serving path: multi-head latent attention
+# whose paged cache holds one latent row per token and layer, a leading
+# dense layer, and sigmoid-routed dropless experts of which this chip
+# holds a share.  Paged path only (the device pool is the cache).
+# ---------------------------------------------------------------------------
+
+#: rows of the sorted (token, pick) list one grouped product takes.  At
+#: 512 rows an expert's three [E, F] matrices are read once per 512 x
+#: E x F x 6 FLOPs, past the v5e's ridge; XLA's grouped matmul tiles
+#: its rows by 512 too
+MOE_TILE = 512
+
+
+def _yarn_inv_freq(cfg: TransformerConfig):
+    """RoPE frequencies [qk_rope_head_dim / 2], yarn-blended: the
+    published base frequencies where a dimension turns more than
+    beta_fast times over the original context, those divided by the
+    factor where it turns less than beta_slow times, a linear ramp
+    between (numpy: these are trace-time constants)."""
+    import numpy as np
+
+    dim = cfg.qk_rope_head_dim
+    base = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.rope_yarn_factor == 1.0:
+        return jnp.asarray(base, jnp.float32)
+
+    def turns_at(n_rot):  # the dimension that makes n_rot turns
+        return dim * np.log(cfg.rope_yarn_original / (n_rot * 2 * np.pi)) \
+            / (2 * np.log(cfg.rope_theta))
+
+    low = max(np.floor(turns_at(cfg.rope_yarn_beta_fast)), 0)
+    high = min(np.ceil(turns_at(cfg.rope_yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    blended = base / cfg.rope_yarn_factor * ramp + base * (1 - ramp)
+    return jnp.asarray(blended, jnp.float32)
+
+
+def _mla_scale(cfg: TransformerConfig) -> float:
+    """Softmax scale: qk width^-0.5 times yarn's mscale squared."""
+    import math
+
+    m = 1.0
+    if cfg.rope_yarn_factor > 1.0:
+        m += 0.1 * cfg.rope_yarn_mscale_all_dim * math.log(
+            cfg.rope_yarn_factor)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _rope_rows(x, positions, inv_freq):
+    """x [B, T, ..., D] rotated by halves at per-token positions
+    [B, T] (mscale = mscale_all_dim: cos and sin are not scaled)."""
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B,T,D/2]
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3)
+                            + angles.shape[-1:])
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return out.astype(x.dtype)
+
+
+def _mla_project(xn, p, positions, cfg: TransformerConfig):
+    """The MLA projections of normed activations ``xn`` [B, T, E] at
+    ``positions`` [B, T]: ``(q_nope [B,T,H,nope], q_pe [B,T,H,pe],
+    row [B,T,rkv+pe])`` where ``row`` = [rms(c_kv) | rope(k_pe)] is
+    what the cache holds per token and layer."""
+    rkv, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    inv_freq = _yarn_inv_freq(cfg)
+    c_q = rms_norm(jnp.einsum("bte,er->btr", xn, p["w_qa"]), p["q_norm"])
+    q = jnp.einsum("btr,rhd->bthd", c_q, p["w_qb"])
+    kva = jnp.einsum("bte,er->btr", xn, p["w_kva"])
+    c_kv = rms_norm(kva[..., :rkv], p["kv_norm"])
+    k_pe = _rope_rows(kva[..., rkv:], positions, inv_freq)
+    q_pe = _rope_rows(q[..., nope:], positions, inv_freq)
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], -1)
+
+
+def _mla_prefill_attention(xn, p, positions, cfg: TransformerConfig):
+    """Causal MLA over a whole sequence, up-projected: keys
+    [k_nope | k_pe] at qk width and values at v width per head, through
+    the flash kernel (or its lax twin).  Returns ``(y [B,T,E], row)``."""
+    rkv, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q_nope, q_pe, row = _mla_project(xn, p, positions, cfg)
+    kv = jnp.einsum("btr,rhd->bthd", row[..., :rkv], p["w_kvb"])
+    k_pe = jnp.broadcast_to(row[:, :, None, rkv:],
+                            q_pe.shape[:2] + (cfg.n_heads, q_pe.shape[-1]))
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate([kv[..., :nope], k_pe], -1)
+    # the scope states the call's T: the benchmark's roofline reader
+    # counts each call's operations at its own length
+    with jax.named_scope(f"prefill_attn_t{q.shape[1]}"):
+        o = _causal_attention(q, k, kv[..., nope:], scale=_mla_scale(cfg))
+    return jnp.einsum("bthd,hde->bte", o, p["wo"]), row
+
+
+def _mla_absorbed_queries(q_nope, q_pe, p, cfg: TransformerConfig):
+    """Decode's queries against the latent rows themselves: the key
+    up-projection absorbed into q (q' = q_nope W_kvb,k^T), beside q_pe."""
+    w_k = p["w_kvb"][..., :cfg.qk_nope_head_dim]                 # [rkv,H,nope]
+    q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, w_k)
+    return jnp.concatenate([q_abs.astype(q_pe.dtype), q_pe], -1)
+
+
+def _mla_absorbed_output(o_lat, p, cfg: TransformerConfig):
+    """o' = p . c_kv per head [B,S,H,rkv] -> the value up-projection
+    and the output projection."""
+    w_v = p["w_kvb"][..., cfg.qk_nope_head_dim:]                 # [rkv,H,v]
+    o = jnp.einsum("bshr,rhd->bshd", o_lat, w_v)
+    return jnp.einsum("bshd,hde->bse", o, p["wo"])
+
+
+def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
+                  first_group: int = 0):
+    """Sigmoid-routed experts of which this chip holds a share, with
+    the shared expert: dropless.
+
+    x [B, T, E].  The router scores all ``moe_n_routed`` experts in
+    float32, picks the ``moe_topk`` largest and weighs them
+    ``moe_routed_scale * s_i / (sum of the picked s + 1e-20)`` (the
+    normaliser is over all picks, held or not).  The (token, pick)
+    pairs whose expert is held here, [moe_held_start, + n_experts), are
+    sorted by expert; the sorted list is walked in tiles of
+    :data:`MOE_TILE` rows under a trip count that follows how many
+    pairs landed here, each tile one grouped product per matrix
+    (``lax.ragged_dot``), scattered back onto its tokens.  No capacity,
+    no bound that can overflow: every held pair is computed whatever
+    the routing, and what the absent experts would add is left out.
+    On one chip the layer runs without its exchange.
+
+    ``p["w_in"]`` / ``["w_gate"]`` / ``["w_out"]`` are [G, E, F] /
+    [G, F, E] with this layer's experts at groups [first_group,
+    first_group + n_experts): the callers pass every expert layer's
+    stack as ONE group axis, because a grouped product takes its
+    operand whole and a per-layer slice of the stack would be copied
+    (336 MB a matrix at A.X-K1's widths); the other layers' groups are
+    empty and cost nothing.
+
+    Returns ``(y [B, T, E], counts [n_experts + 1] int32)``: pairs per
+    held expert, then all pairs routed anywhere, both over the tokens
+    ``valid`` [B, T] marks (default all)."""
+    b, t, e = x.shape
+    n, k, x_l = b * t, cfg.moe_topk, cfg.n_experts
+    xf = x.reshape(n, e)
+    # float32 from the operands as stored: products of bf16 values are
+    # exact in float32, so accumulating there IS the float32 router,
+    # without a float32 copy of the activations
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "ne,ex->nx", xf, p["gate"], preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST))
+    top_s, top_i = lax.top_k(scores, k)                          # [n, k]
+    weight = cfg.moe_routed_scale * top_s / (
+        jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+
+    # the pairs that landed here, sorted by held expert; the others go
+    # to the end of the list under the key x_l
+    local = (top_i >= cfg.moe_held_start) & (
+        top_i < cfg.moe_held_start + x_l)
+    key = jnp.where(local, top_i - cfg.moe_held_start, x_l)      # [n, k]
+
+    def per_expert(keys):
+        return jnp.zeros(x_l + 1, jnp.int32).at[keys.reshape(-1)].add(
+            1)[:x_l]
+
+    order = jnp.argsort(key.reshape(-1), stable=True)
+    sizes = per_expert(key)
+    ends = jnp.cumsum(sizes)
+    n_held = ends[-1]
+    tile = min(MOE_TILE, -(-n * k // 8) * 8)
+    pad = -(n * k) % tile
+    token = jnp.pad(order // k, (0, pad))                        # [pairs]
+    w_sorted = jnp.pad(weight.reshape(-1)[order], (0, pad))
+    n_groups = p["w_in"].shape[0]
+
+    def one_tile(i, y):
+        lo = i * tile
+        rows = lax.dynamic_slice_in_dim(token, lo, tile)
+        # this tile's rows of each expert: the sorted groups cut at its edges
+        hi = jnp.clip(ends, lo, lo + tile)
+        groups = jnp.zeros(n_groups, jnp.int32).at[
+            first_group:first_group + x_l].set(
+                hi - jnp.concatenate([jnp.full((1,), lo, hi.dtype), hi[:-1]]))
+        xs = jnp.take(xf, rows, axis=0)
+        hidden = lax.ragged_dot(xs, p["w_in"], groups) * jax.nn.silu(
+            lax.ragged_dot(xs, p["w_gate"], groups))
+        out = lax.ragged_dot(hidden, p["w_out"], groups)
+        # rows past the held pairs belong to no group: their product is
+        # whatever the buffer held, so they are selected out, not scaled
+        held = (lo + jnp.arange(tile) < n_held)[:, None]
+        w = lax.dynamic_slice_in_dim(w_sorted, lo, tile)[:, None]
+        return y.at[rows].add(jnp.where(held, out.astype(jnp.float32) * w, 0.0))
+
+    y = lax.fori_loop(0, -(-n_held // tile), one_tile,
+                      jnp.zeros((n, e), jnp.float32))
+    y = y.astype(x.dtype).reshape(b, t, e)
+    if cfg.moe_n_shared:
+        y = y + swiglu_ffn(x, p["s_in"], p["s_gate"], p["s_out"], ShardAxes())
+    routed = jnp.asarray(n * k, jnp.int32)
+    if valid is not None:
+        sizes = per_expert(jnp.where(valid.reshape(n, 1), key, x_l))
+        routed = jnp.sum(valid.astype(jnp.int32)) * k
+    return y, jnp.concatenate([sizes, routed.reshape(1)])
+
+
+_EXPERT_STACKS = ("w_in", "w_gate", "w_out")
+
+
+def _latent_layers(params):
+    """Yields ``(layer params, first_group)`` in order: the leading
+    dense group (``first_group`` None), then the expert layers, whose
+    routed experts stay ONE stack [S * L/S * X, ...] with the layer's
+    own at ``first_group`` (see :func:`_moe_held_ffn`)."""
+    dense, blocks = params["dense"], params["blocks"]
+    for i in range(dense["ln1"].shape[0]):
+        yield jax.tree.map(lambda a: a[i], dense), None
+    n_stages, lps = blocks["ln1"].shape[:2]
+    stacks = {name: blocks[name].reshape((-1,) + blocks[name].shape[3:])
+              for name in _EXPERT_STACKS}
+    n_held = blocks["w_in"].shape[2]
+    rest = {name: a for name, a in blocks.items() if name not in stacks}
+    for s in range(n_stages):
+        for i in range(lps):
+            yield ({**_layer_params(rest, s, i), **stacks},
+                   (s * lps + i) * n_held)
+
+
+def _latent_ffn(x, p, first_group, cfg: TransformerConfig, valid, counts):
+    """The layer's second half on the residual stream; an expert
+    layer's routing counts are appended to ``counts``."""
+    xn = rms_norm(x, p["ln2"])
+    if first_group is None:
+        with jax.named_scope("mlp"):
+            return x + swiglu_ffn(xn, p["w_in"], p["w_gate"], p["w_out"],
+                                  ShardAxes())
+    with jax.named_scope("moe"):
+        y, c = _moe_held_ffn(xn, p, cfg, valid, first_group)
+    counts.append(c)
+    return x + y
+
+
+def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
+                              cfg: TransformerConfig):
+    """:func:`forward_prefill_paged` of the latent family: prefill of
+    ONE sequence that writes its latent rows into the paged pool on the
+    device.  ids [1, T], T a whole number of blocks; pool [L, n_blocks,
+    kv_lora_rank + qk_rope_head_dim, block_size] (a page is [row,
+    slot]: ``TransformerConfig.kv_pool_shapes``); block_ids [T /
+    block_size].  Attention runs up-projected at qk / v width (the
+    flash kernel); only ``[rms(c_kv) | rope(k_pe)]`` is cached.  Returns
+    ``(logits [1, V], pool, moe [n_moe_layers, n_experts + 1])``: the
+    pool donated and updated in place, and the routing counts of the
+    prompt's real tokens (see :func:`_moe_held_ffn`)."""
+    _, t = ids.shape
+    bs = pool.shape[3]
+    positions = jnp.arange(t)[None]
+    valid = positions <= last_index[:, None]
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
+    counts = []
+    for li, (p, first_group) in enumerate(_latent_layers(params)):
+        with jax.named_scope("mla"):
+            y, row = _mla_prefill_attention(rms_norm(x, p["ln1"]), p,
+                                            positions, cfg)
+            x = x + y
+            pool = pool.at[li, block_ids].set(jnp.swapaxes(
+                row.reshape(t // bs, bs, -1), 1, 2).astype(pool.dtype))
+        x = _latent_ffn(x, p, first_group, cfg, valid, counts)
+    x = rms_norm(x, params["ln_f"])
+    return _logits_at(params, x, last_index), pool, jnp.stack(counts)
+
+
+def _write_latent_rows(pool, layer: int, blocks, slots, row):
+    """The decode window's latent rows ``row`` [B, S, r] into pages
+    ``blocks`` [B, S] at ``slots`` [B, S] of one layer (a block index
+    past the pool drops the write: dead rows).  Whole pages are read,
+    given their new column and written back, one window position at a
+    time: a page is [r, slot], and a scatter of columns would have the
+    chip's compiler lay the whole pool row-minor and copy it for the
+    kernel in every layer, where a page is 147 KB."""
+    bs = pool.shape[3]
+    for s in range(row.shape[1]):
+        pages = pool.at[layer, blocks[:, s]].get(mode="clip")      # [B, r, bs]
+        mine = (jnp.arange(bs)[None, None, :] == slots[:, s, None, None])
+        pages = jnp.where(mine, row[:, s, :, None].astype(pool.dtype), pages)
+        pool = pool.at[layer, blocks[:, s]].set(pages, mode="drop")
+    return pool
+
+
+def forward_decode_paged_mla(params, ids, positions, pool, block_tables,
+                             lengths, cfg: TransformerConfig):
+    """:func:`forward_decode_paged` of the latent family, absorbed:
+    each layer scatters the window's latent rows into the pool at
+    ``lengths[b] + s`` and attends the rows themselves (K = the row,
+    V = its first kv_lora_rank values) through
+    :func:`ops.paged_attention.latent_paged_attention`, for all heads
+    from the one page.  Returns ``(logits [B, S, V], pool, moe)``; dead
+    rows (length 0) scatter out of bounds and are not counted."""
+    from ..ops import paged_attention as _paged
+
+    b, s_w = ids.shape
+    n_blocks, bs = pool.shape[1], pool.shape[3]
+    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]
+    wb = jnp.take_along_axis(
+        block_tables, jnp.clip(pos_w // bs, 0, block_tables.shape[1] - 1),
+        axis=1)
+    wb = jnp.where(lengths[:, None] > 0, wb, n_blocks)           # OOB-drop
+    ws = pos_w % bs
+    valid = jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
+    counts = []
+    for li, (p, first_group) in enumerate(_latent_layers(params)):
+        with jax.named_scope("mla"):
+            q_nope, q_pe, row = _mla_project(rms_norm(x, p["ln1"]), p,
+                                             positions, cfg)
+            pool = _write_latent_rows(pool, li, wb, ws, row)
+            # the layers' pools as one run of pages: a per-layer slice
+            # of the pool would be copied for the kernel (ROADMAP A3)
+            o_lat = _paged.latent_paged_attention(
+                _mla_absorbed_queries(q_nope, q_pe, p, cfg),
+                pool.reshape((-1,) + pool.shape[2:]),
+                block_tables + li * n_blocks, lengths,
+                v_dim=cfg.kv_lora_rank, scale=_mla_scale(cfg))
+            x = x + _mla_absorbed_output(o_lat, p, cfg)
+        x = _latent_ffn(x, p, first_group, cfg, valid, counts)
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["ln_f"])
+        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
+    return logits, pool, jnp.stack(counts)
 
 
 def make_train_step(mesh, cfg: TransformerConfig, optimizer=None,

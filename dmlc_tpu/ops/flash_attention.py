@@ -141,14 +141,38 @@ def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
         walk()
 
 
-def supports(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...]) -> bool:
+def _kernel_o(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref, o_ref,
+              pv_ref, m_ref, l_ref, *, n_kv: int, **static):
+    """:func:`_kernel` with its accumulators in VMEM scratch and the
+    normalised ``o = pv / l`` written once, in the inputs' dtype, at
+    the sequence's last KV step: for a forward that needs no (m, l).
+    In HBM an 8-lane f32 row is padded to 128 lanes, so m and l of a
+    16k-token, 64-head call cost 512 MB each, and pv in f32 twice o."""
+    from jax.experimental import pallas as pl
+
+    _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
+            pv_ref, m_ref, l_ref, **static)
+
+    @pl.when(pl.program_id(2) == n_kv - 1)
+    def _write():
+        o_ref[...] = (pv_ref[...] / jnp.maximum(l_ref[..., :1], 1e-20)
+                      ).astype(o_ref.dtype)
+
+
+def supports(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
+             v_shape: Optional[Tuple[int, ...]] = None) -> bool:
     """Kernel applicability gate: lane dim multiple of 128, seq dims big
     enough to tile.  Unaligned seq lengths are handled by the kernel's
     pad-and-mask path and block sizes are clamped internally, so neither
-    disqualifies."""
+    disqualifies.  ``v`` may have a head size of its own (forward only);
+    beside whole lanes the one qk size Mosaic was shown to take is
+    latent attention's 192 (128 without RoPE + 64 with), the block's
+    last dim being the whole array's."""
     _, tq, _, d = q_shape
     tk = k_shape[1]
-    return d % 128 == 0 and tq >= 8 and tk >= 8
+    dv = d if v_shape is None else v_shape[-1]
+    return ((d % 128 == 0 or d == 192) and dv % 128 == 0
+            and tq >= 8 and tk >= 8)
 
 
 def lax_block_attend(q, k, v, *, scale, mask):
@@ -231,13 +255,16 @@ def _pad_seq(x, pad):
     return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
 
 
-def _flash_forward(static, q, k, v, qoff, kvoff):
+def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False):
+    """``(pv, m, l)`` partials, or with ``normalized`` the finished
+    ``o [B, Tq, H, dv]`` in q's dtype (kernel ``flash_fwd_o``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     scale, causal, block_q, block_k, interpret = static[:5]
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    dv = v.shape[-1]  # its own size under latent attention (qk 192, v 128)
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     bh = b * h
@@ -256,7 +283,7 @@ def _flash_forward(static, q, k, v, qoff, kvoff):
 
     qt = q.transpose(0, 2, 1, 3).reshape(bh, tq_p, d)
     kt = k.transpose(0, 2, 1, 3).reshape(bh, tk_p, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(bh, tk_p, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(bh, tk_p, dv)
     kvend = kvoff + tk
 
     # The kernel body is written batched over G fused (b,h) pairs per
@@ -270,28 +297,48 @@ def _flash_forward(static, q, k, v, qoff, kvoff):
     while g * 2 <= gmax and bh % (g * 2) == 0:  # never exceed the cap
         g *= 2
 
+    grid = (bh // g, tq_p // block_q, tk_p // block_k)
+    in_specs = [
+        pl.BlockSpec((g, block_q, d), lambda bi, qi, kj, *_: (bi, qi, 0)),
+        pl.BlockSpec((g, block_k, d), lambda bi, qi, kj, *_: (bi, kj, 0)),
+        pl.BlockSpec((g, block_k, dv), lambda bi, qi, kj, *_: (bi, kj, 0)),
+    ]
+    operands = (qoff, kvoff, kvend, qt, kt, vt)
+    kernel_static = dict(block_q=block_q, block_k=block_k, causal=causal,
+                         kv_padded=kv_padded, scale=scale,
+                         interpret=bool(interpret))
+    if normalized:
+        o = pl.pallas_call(
+            functools.partial(_kernel_o, n_kv=grid[2], **kernel_static),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
+                out_specs=pl.BlockSpec(
+                    (g, block_q, dv), lambda bi, qi, kj, *_: (bi, qi, 0)),
+                scratch_shapes=[pltpu.VMEM((g, block_q, dv), jnp.float32),
+                                pltpu.VMEM((g, block_q, 8), jnp.float32),
+                                pltpu.VMEM((g, block_q, 8), jnp.float32)],
+            ),
+            out_shape=_out_struct((bh, tq_p, dv), q.dtype, *operands),
+            name="flash_fwd_o",
+            interpret=interpret,
+        )(*operands)
+        return o.reshape(b, h, tq_p, dv).transpose(0, 2, 1, 3)[:, :tq]
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(bh // g, tq_p // block_q, tk_p // block_k),
-        in_specs=[
-            pl.BlockSpec((g, block_q, d), lambda bi, qi, kj, *_: (bi, qi, 0)),
-            pl.BlockSpec((g, block_k, d), lambda bi, qi, kj, *_: (bi, kj, 0)),
-            pl.BlockSpec((g, block_k, d), lambda bi, qi, kj, *_: (bi, kj, 0)),
-        ],
+        grid=grid,
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((g, block_q, d), lambda bi, qi, kj, *_: (bi, qi, 0)),
+            pl.BlockSpec((g, block_q, dv), lambda bi, qi, kj, *_: (bi, qi, 0)),
             pl.BlockSpec((g, block_q, 8), lambda bi, qi, kj, *_: (bi, qi, 0)),
             pl.BlockSpec((g, block_q, 8), lambda bi, qi, kj, *_: (bi, qi, 0)),
         ],
     )
-    operands = (qoff, kvoff, kvend, qt, kt, vt)
     pv, m, l = pl.pallas_call(
-        functools.partial(_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, kv_padded=kv_padded, scale=scale,
-                          interpret=bool(interpret)),
+        functools.partial(_kernel, **kernel_static),
         grid_spec=grid_spec,
         out_shape=[
-            _out_struct((bh, tq_p, d), jnp.float32, *operands),
+            _out_struct((bh, tq_p, dv), jnp.float32, *operands),
             _out_struct((bh, tq_p, 8), jnp.float32, *operands),
             _out_struct((bh, tq_p, 8), jnp.float32, *operands),
         ],
@@ -299,7 +346,7 @@ def _flash_forward(static, q, k, v, qoff, kvoff):
         interpret=interpret,
     )(*operands)
 
-    pv = pv.reshape(b, h, tq_p, d).transpose(0, 2, 1, 3)[:, :tq]
+    pv = pv.reshape(b, h, tq_p, dv).transpose(0, 2, 1, 3)[:, :tq]
     m = m[..., 0].reshape(b, h, tq_p)[:, :, :tq]
     l = l[..., 0].reshape(b, h, tq_p)[:, :, :tq]
     return pv, m, l
@@ -592,7 +639,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     interpret: bool = False):
     """Standalone exact attention via the flash kernels (single device).
 
-    q/k/v: [B, T, H, D].  The oracle-equivalent of
+    q/k/v: [B, T, H, D]; v may have its own head size (latent
+    attention's prefill), and the call is then forward-only: the
+    backward kernels assume one size.  The oracle-equivalent of
     ring_attention_reference with O(T) memory in BOTH directions: the
     backward recomputes P from the saved (o, lse) residuals in blocks
     (dkv + dq kernels) instead of materializing the T×T matrix.
@@ -618,7 +667,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # (2 matmuls of [tq,tk]x[tk,d] per head; causal halves the visited
     # area), so the counter is exact per compiled call — MFU math reads
     # it straight off /metrics without re-deriving shapes
-    flops = 4.0 * b * h * tq * tk * d * (0.5 if causal else 1.0)
+    dv = v.shape[-1]
+    flops = 2.0 * b * h * tq * tk * (d + dv) * (0.5 if causal else 1.0)
     telemetry.inc("flash", "fwd_calls")
     telemetry.inc("flash", "fwd_flops", flops)
     telemetry.observe("flash", "seq_len_q", float(tq),
@@ -641,4 +691,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         block_k = get_env("DMLC_FLASH_BLOCK_K", 0) or 1024
     static = (float(scale), bool(causal), int(block_q), int(block_k),
               bool(interpret), int(bwd_q), int(bwd_k))
+    if dv != d:
+        zero = jnp.zeros(1, jnp.int32)
+        return _flash_forward(static, q, k, v, zero, zero, normalized=True)
     return _flash_attn(static, q, k, v)

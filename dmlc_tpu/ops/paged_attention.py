@@ -47,6 +47,15 @@ both conditions fold into ONE static int32 table ``pair[r, c] = t - s``
 ``lengths[b] - j*bs``.  The cross-head products are wasted MXU work
 (H-fold), which decode has to spare; the grid and its speed are
 ROADMAP A4.
+
+Latent attention (MLA) decodes against another cache: ONE row per
+token and layer, ``[rms(c_kv) | rope(k_pe)]``, with no head axis and no
+separate V.  :func:`latent_paged_attention` is that kernel
+(``mla_paged_attn``) and its lax twin: the absorbed queries of all
+heads ``[S*H, row]`` meet a page in one 2-D product with no cross-head
+waste (every head reads the same keys), and the values are the row's
+first ``v_dim`` entries of the page already in VMEM.  A page lies
+``[row, bs]``, slots minor: K^T as the score product takes it.
 """
 
 from __future__ import annotations
@@ -60,7 +69,8 @@ import numpy as np
 
 _NEG_BIG = -1e30
 
-__all__ = ["paged_attention", "supports"]
+__all__ = ["paged_attention", "supports", "latent_paged_attention",
+           "latent_supports"]
 
 
 def supports(head_dim: int, block_size: int, n_heads: int) -> bool:
@@ -230,3 +240,160 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
             interpret or mode == dispatch.INTERPRET)
     return _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths,
                                 float(scale))
+
+
+# ---- latent attention: one row per token, shared by every head --------
+
+def latent_supports(row_dim: int, v_dim: int, block_size: int) -> bool:
+    """Whether the latent kernel serves these shapes: the values fill
+    whole 128-element lanes and the RoPE'd tail starts on one (so both
+    slices of a page are lane-aligned), and a page's rows, the score
+    matrix's lane dim, fill whole lanes too."""
+    return (v_dim % 128 == 0 and v_dim < row_dim
+            and block_size % 128 == 0)
+
+
+def _lax_latent_paged_attention(q, pool, block_tables, lengths, v_dim,
+                                scale):
+    """Gather-composed twin of the latent kernel: same mask, f32 score
+    path."""
+    b, s_w, h, r = q.shape
+    w = block_tables.shape[1]
+    bs = pool.shape[2]
+    ctx = jnp.swapaxes(pool[block_tables], 2, 3).reshape(b, w * bs, r)
+    s = jnp.einsum("bqhr,bkr->bhqk", q, ctx,
+                   preferred_element_type=jnp.float32) * scale
+    limit = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
+    keep = jnp.arange(w * bs)[None, None, :] <= limit[:, :, None]
+    s = jnp.where(keep[:, None], s, _NEG_BIG)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhqk,bkr->bqhr", p.astype(ctx.dtype),
+                     ctx[..., :v_dim], preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _latent_kernel(tbl_ref, len_ref, q_ref, kv_ref, pair_ref,
+                   pv_ref, m_ref, l_ref, *, bs: int, s_real: int,
+                   v_dim: int, scale: float):
+    from jax.experimental import pallas as pl
+
+    bi = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        pv_ref[...] = jnp.zeros_like(pv_ref[...])
+        m_ref[...] = jnp.full_like(m_ref[...], _NEG_BIG)
+        l_ref[...] = jnp.zeros_like(l_ref[...])
+
+    limit = len_ref[bi] + s_real - 1
+
+    @pl.when(j * bs <= limit)
+    def _step():
+        q = q_ref[...]                                 # [1, S*H, row]
+        page = kv_ref[...]                             # [1, row, bs]
+        # the score in its two lane-aligned parts: latent . latent and
+        # rope . rope (q's row is 4.5 lane tiles wide)
+        dims = (((2,), (1,)), ((0,), (0,)))
+        s = (jax.lax.dot_general(q[..., :v_dim], page[:, :v_dim], dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(q[..., v_dim:], page[:, v_dim:], dims,
+                                   preferred_element_type=jnp.float32)
+             ) * scale                                 # [1, S*H, bs]
+        # key position j*bs + t <= lengths[b] + s
+        keep = pair_ref[...] <= len_ref[bi] - j * bs
+        s = jnp.where(keep, s, _NEG_BIG)
+        m_old = m_ref[..., 0]
+        l_old = l_ref[..., 0]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=2))
+        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m_old - m_new)
+        l_new = l_old * corr + jnp.sum(p, axis=2)
+        pv = jax.lax.dot_general(
+            p.astype(page.dtype), page[:, :v_dim],
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)        # [1, S*H, v_dim]
+        pv_ref[...] = pv_ref[...] * corr[..., None] + pv
+        m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
+
+
+@functools.lru_cache(maxsize=8)
+def _latent_pair_table(rows: int, h: int, bs: int) -> np.ndarray:
+    """``[1, rows, bs]`` int32: ``t - s`` for query row ``r = s*H + h``
+    and key row ``t`` of a page; padding rows (past S*H) see nothing."""
+    r = np.arange(rows)
+    diff = np.arange(bs)[None, :] - (r // h)[:, None]
+    return diff.astype(np.int32)[None]
+
+
+def _pallas_latent_paged_attention(q, pool, block_tables, lengths, v_dim,
+                                   scale, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s_w, h, r = q.shape
+    w = block_tables.shape[1]
+    bs = pool.shape[2]
+    rows = -(-s_w * h // 16) * 16  # whole sublane tiles, bf16's 16 too
+    qf = q.reshape(b, s_w * h, r)                        # row = s*H + h
+    if rows != s_w * h:
+        qf = jnp.pad(qf, ((0, 0), (0, rows - s_w * h), (0, 0)))
+    pair = jnp.asarray(_latent_pair_table(rows, h, bs))
+    of_seq = lambda bi, j, tbl_, lens_: (bi, 0, 0)  # noqa: E731
+    pv, _, l = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, s_real=s_w, v_dim=v_dim,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, w),
+            in_specs=[
+                pl.BlockSpec((1, rows, r), of_seq),
+                # the paged indirection, as in paged_attn
+                pl.BlockSpec((1, r, bs),
+                             lambda bi, j, tbl_, lens_: (tbl_[bi, j], 0, 0)),
+                pl.BlockSpec((1, rows, bs),
+                             lambda bi, j, tbl_, lens_: (0, 0, 0)),
+            ],
+            out_specs=[pl.BlockSpec((1, rows, v_dim), of_seq),
+                       pl.BlockSpec((1, rows, 8), of_seq),
+                       pl.BlockSpec((1, rows, 8), of_seq)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, rows, v_dim), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, 8), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, 8), jnp.float32),
+        ],
+        name="mla_paged_attn",
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qf, pool,
+      pair)
+    out = pv / jnp.maximum(l[..., :1], 1e-37)
+    return out[:, :s_w * h].reshape(b, s_w, h, v_dim).astype(q.dtype)
+
+
+def latent_paged_attention(q, pool, block_tables, lengths, *, v_dim: int,
+                           scale: float, impl: str = "auto",
+                           interpret: bool = False):
+    """Window attention of absorbed latent queries against one layer's
+    paged latent pool.
+
+    q [B, S, H, row] (``[q_nope W_kvb,k^T | q_pe]``); pool [n_blocks,
+    row, block_size], a row being ``[rms(c_kv) | rope(k_pe)]``;
+    block_tables / lengths and the mask as :func:`paged_attention`
+    (scatter-then-attend).  The keys are the rows, the values their
+    first ``v_dim`` entries.  Returns ``o' [B, S, H, v_dim]`` in q's
+    dtype, to be up-projected by the caller.  ``impl`` as
+    :func:`paged_attention`."""
+    if impl not in ("auto", "pallas", "lax"):
+        raise ValueError(f"unknown paged-attention impl {impl!r}")
+    from . import dispatch
+
+    mode = dispatch.choose(
+        latent_supports(int(q.shape[-1]), v_dim, int(pool.shape[2])), impl)
+    if mode != dispatch.LAX:
+        return _pallas_latent_paged_attention(
+            q, pool, block_tables, lengths, v_dim, float(scale),
+            interpret or mode == dispatch.INTERPRET)
+    return _lax_latent_paged_attention(q, pool, block_tables, lengths,
+                                       v_dim, float(scale))

@@ -163,7 +163,14 @@ class _DedupeTable:
                 self._done.pop(self._order.popleft(), None)
 
 
-def _jitted_programs(use_paged: bool = False, window: int = 1):
+#: growth of the expert layers' routing counts (models.transformer
+#: ``_moe_held_ffn``), fed when a prefill or decode span closes
+_MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held",
+                 "moe_expert_load_max", "moe_expert_load_mean")
+
+
+def _jitted_programs(use_paged: bool = False, window: int = 1,
+                     latent: bool = False):
     """Process-wide jitted prefill/decode (one jit wrapper per program
     variant, so every engine instance shares one compile cache — tests
     and smokes build several engines and must not pay XLA again for
@@ -174,7 +181,10 @@ def _jitted_programs(use_paged: bool = False, window: int = 1):
     ``forward_prefill_paged`` with the pools DONATED — it has this one
     caller, which replaces its references with the returned pools at
     once, and an undonated scatter would copy both pools per prompt —
-    or the gather path's ``forward_prefill_last``.  Decode: the gather
+    or the gather path's ``forward_prefill_last``.  A latent-attention
+    model (``latent``) runs the paged path's twins at the same two
+    sites, ``forward_prefill_paged_mla`` / ``forward_decode_paged_mla``,
+    both donated their one pool.  Decode: the gather
     oracle (``forward_decode``, site ``serving.decode``), its
     multi-token speculative-verify twin (``forward_decode_spec``, site
     ``serving.decode_spec``), or the paged fast path
@@ -193,7 +203,15 @@ def _jitted_programs(use_paged: bool = False, window: int = 1):
     prefill_key = (mode, "prefill")
     prefill_fn, prefill_kw = tfm.forward_prefill_last, {
         "static_argnums": (3,)}
-    if use_paged:
+    if latent:
+        prefill_key = (mode, "prefill_paged_mla")
+        prefill_fn, prefill_kw = tfm.forward_prefill_paged_mla, {
+            "static_argnums": (5,), "donate_argnums": (3,)}
+        decode_key = (mode, "decode_paged_mla")
+        builder = lambda cap: compute.profiled_jit(  # noqa: E731
+            tfm.forward_decode_paged_mla, site="serving.decode_paged",
+            static_argnums=(6,), donate_argnums=(3,), max_signatures=cap)
+    elif use_paged:
         prefill_key = (mode, "prefill_paged")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged, {
             "static_argnums": (6,), "donate_argnums": (3, 4)}
@@ -294,17 +312,25 @@ class InferenceEngine:
                                or kv_partition_spec(mesh) is None)
         else:
             self._use_paged = self.paged_mode == "on"
+        if cfg.latent and not self._use_paged:
+            raise ValueError(
+                "a latent-attention model is served on the paged path "
+                "only (its cache is the device pool of latent rows); "
+                f"DMLC_SERVE_PAGED_ATTN={self.paged_mode!r} or the mesh "
+                "chose the gather path")
         # the path decides where the cache's bytes live: on the paged
         # path the device pools are the cache, on the gather path the
         # host's numpy pools are
+        n_blocks = (n_blocks if n_blocks is not None
+                    else get_env("DMLC_SERVE_KV_BLOCKS", 256))
+        block_size = (block_size if block_size is not None
+                      else get_env("DMLC_SERVE_KV_BLOCK_SIZE", 16))
         self.cache = PagedKVCache(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
-            n_blocks=(n_blocks if n_blocks is not None
-                      else get_env("DMLC_SERVE_KV_BLOCKS", 256)),
-            block_size=(block_size if block_size is not None
-                        else get_env("DMLC_SERVE_KV_BLOCK_SIZE", 16)),
+            n_blocks=n_blocks, block_size=block_size,
             dtype=np.dtype(cfg.dtype), mesh=mesh,
-            device_resident=self._use_paged)
+            device_resident=self._use_paged,
+            pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size))
         self.scheduler = ContinuousBatchScheduler(
             self.cache, max_active=self.max_active)
         depth = (queue_depth if queue_depth is not None
@@ -337,7 +363,7 @@ class InferenceEngine:
                                                4)))
         self._spec_window = 1 + self.spec_k
         self._prefill, self._decode = _jitted_programs(
-            self._use_paged, self._spec_window)
+            self._use_paged, self._spec_window, cfg.latent)
         self._stop = threading.Event()
         self._draining = threading.Event()
         # iteration seqlock: odd = an engine iteration is mid-flight
@@ -498,7 +524,8 @@ class InferenceEngine:
         if self._stop.is_set():
             raise DMLCError("engine is closed")
         self._stop.clear()
-        for name in _ZEROED_COUNTERS:
+        for name in _ZEROED_COUNTERS + (
+                _MOE_COUNTERS if self.cfg.moe_router == "sigmoid" else ()):
             telemetry.inc("serving", name, 0)
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="serving-engine")
@@ -775,17 +802,41 @@ class InferenceEngine:
         prompt's); the logits alone cross the link."""
         with self._span("serving.prefill", tokens=n, req=req.id):
             with self._span("serving.prefill.run", req=req.id):
-                k_pool, v_pool = self.cache.device_pools()
-                logits, k_pool, v_pool = self._prefill(
-                    self.params, ids, last, k_pool, v_pool,
+                logits, pools, moe = self._on_pools(
+                    self._prefill, self.params, ids, last,
                     np.asarray(self.cache.block_table(req.id), np.int32),
-                    self.cfg)
+                    at=3)
                 logits = np.asarray(logits[0])
+                moe = [np.asarray(m) for m in moe]
         telemetry.inc("serving", "prefill_d2h_bytes", logits.nbytes)
+        self._count_moe(moe)
         with self._span("serving.kv_write", req=req.id):
-            self.cache.adopt_device_pools(k_pool, v_pool)
+            self.cache.adopt_device_pools(*pools)
             self.cache.advance_many([(req.id, n)])
         return logits
+
+    def _on_pools(self, program, *args, at: int):
+        """Call a paged program with the cache's pools spliced in at
+        argument ``at`` and the config last; split what it returns into
+        ``(logits, pools, rest)``.  ``rest`` is empty for the MHA
+        programs and the routing counts for the latent family's."""
+        pools = self.cache.device_pools()
+        out = program(*args[:at], *pools, *args[at:], self.cfg)
+        return out[0], out[1:1 + len(pools)], out[1 + len(pools):]
+
+    def _count_moe(self, moe) -> None:
+        """Add one program call's routing counts ``[n_moe_layers,
+        n_experts + 1]`` (pairs per held expert, then pairs routed
+        anywhere) to the ``serving.moe_*`` counters."""
+        for counts in moe:
+            held = counts[:, :-1]
+            telemetry.inc("serving", "moe_pairs_total",
+                          float(counts[:, -1].sum()))
+            telemetry.inc("serving", "moe_pairs_held", float(held.sum()))
+            telemetry.inc("serving", "moe_expert_load_max",
+                          float(held.max(axis=1).sum()))
+            telemetry.inc("serving", "moe_expert_load_mean",
+                          float(held.mean(axis=1).sum()))
 
     def _prefill_gather(self, req: Request, ids, last, n: int):
         """K and V come to numpy and are copied into the host-resident
@@ -986,12 +1037,19 @@ class InferenceEngine:
                 # program reads and writes the device-resident pools in
                 # place through the block tables (a [B, W] int32 array
                 # is all that ships) and hands no K/V back
-                k_pool, v_pool = self.cache.device_pools()
-                logits, k_pool, v_pool = self._decode(
-                    self.params, ids, positions, k_pool, v_pool, tables,
-                    lengths, self.cfg)
-                self.cache.adopt_device_pools(k_pool, v_pool)
+                try:
+                    logits, pools, moe = self._on_pools(
+                        self._decode, self.params, ids, positions, tables,
+                        lengths, at=3)
+                except Exception:
+                    # a donated pool (the latent family's) went with the
+                    # failed call: the loop's requeue re-prefills into
+                    # fresh ones
+                    self.cache.drop_lost_pools()
+                    raise
+                self.cache.adopt_device_pools(*pools)
             else:
+                moe = ()
                 with compute.phase("gather"):
                     k, v, lengths = self.cache.gather(
                         [r.id for r in active], pad_batch=self.max_active)
@@ -1007,6 +1065,7 @@ class InferenceEngine:
         with self._span("serving.decode.fetch") as crossed:
             logits = np.asarray(logits)
             crossed["bytes"] = logits.nbytes
+            moe = [np.asarray(m) for m in moe]
             if not self._use_paged:
                 k_new = np.asarray(k_new)
                 v_new = np.asarray(v_new)
@@ -1016,6 +1075,7 @@ class InferenceEngine:
                     k_new = k_new[:, :, None]
                     v_new = v_new[:, :, None]
         telemetry.inc("serving", "decode_d2h_bytes", crossed["bytes"])
+        self._count_moe(moe)
         # per-sequence numeric health: a non-finite logit row (NaN/Inf
         # from a poisoned cache page or an overflowed activation) would
         # serve garbage silently.  Checking only the sampled position is

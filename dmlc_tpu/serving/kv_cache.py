@@ -13,6 +13,12 @@ Pool layout (layer-major, mirroring the paged-attention kernel shapes):
 
     k_pool / v_pool : [n_layers, n_blocks, block_size, n_heads, head_dim]
 
+What the pools are is the model's to say (``pool_shapes``, from
+``TransformerConfig.kv_pool_shapes``): K and V heads as above, or under
+latent attention ONE pool of rows ``[rms(c_kv) | rope(k_pe)]`` with no
+head axis and no separate V, ``[n_layers, n_blocks, 576, block_size]``.
+The allocator, block tables, lengths and ``stats()`` do not care.
+
 The bookkeeping (allocator, block tables, lengths, ``stats()``) is
 shared; the bytes live in exactly ONE place, chosen at construction,
 and the two residences share no data-plane code:
@@ -143,7 +149,10 @@ def kv_partition_spec(mesh) -> Optional[tuple]:
 class PagedKVCache:
     """Block-paged K/V storage for a set of live sequences.
 
-    ``n_layers/n_heads/head_dim`` come from the model config;
+    ``n_layers/n_heads/head_dim`` come from the model config, or
+    ``pool_shapes`` does (one shape per pool; the default is K and V
+    pools of ``[n_layers, n_blocks, block_size, n_heads, head_dim]``;
+    anything else lives on the device only).
     ``n_blocks × block_size`` is the total token capacity shared by all
     concurrent requests.  ``device_resident`` picks where the bytes
     live (module docstring): device arrays that device programs write,
@@ -155,7 +164,8 @@ class PagedKVCache:
     def __init__(self, n_layers: int, n_heads: int, head_dim: int, *,
                  n_blocks: int = 256, block_size: int = 16,
                  dtype=np.float32, mesh=None,
-                 device_resident: bool = False):
+                 device_resident: bool = False,
+                 pool_shapes: Optional[tuple] = None):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.n_layers = int(n_layers)
@@ -168,6 +178,13 @@ class PagedKVCache:
         self.device_resident = bool(device_resident)
         self.pool_shape = (self.n_layers, self.n_blocks, self.block_size,
                            self.n_heads, self.head_dim)
+        self.pool_shapes = (self.pool_shape,) * 2 if pool_shapes is None \
+            else tuple(tuple(int(d) for d in shape) for shape in pool_shapes)
+        if self.pool_shapes != (self.pool_shape,) * 2 \
+                and not self.device_resident:
+            raise ValueError(
+                f"pools {self.pool_shapes} are not K and V heads: only a "
+                "device-resident cache holds them (the paged path)")
         # host residence: the numpy pools (None on a device-resident
         # cache, which never holds K/V on the host)
         host = not self.device_resident
@@ -179,9 +196,7 @@ class PagedKVCache:
         # the first device_pools() and replaced by whatever the last
         # prefill / decode program returned
         # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self._dev_k = None
-        # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self._dev_v = None
+        self._dev: Optional[tuple] = None
         # block-table memo: the tables themselves change only when some
         # sequence gains or loses blocks (every ~block_size committed
         # tokens), not every decode step — the version counter lets
@@ -385,24 +400,25 @@ class PagedKVCache:
         self.write(seq_id, np.asarray(k)[:, None], np.asarray(v)[:, None])
 
     # ---- data plane, device residence -----------------------------------
-    def device_pools(self):
-        """The ``(k_pool, v_pool)`` device arrays: the cache itself.
+    def device_pools(self) -> tuple:
+        """The device arrays that are the cache itself, one per entry
+        of ``pool_shapes``: ``(k_pool, v_pool)``, or the one latent pool.
         Made as zeros on the device at first use (nothing is uploaded);
         afterwards whatever :meth:`adopt_device_pools` installed last."""
         self._residence(True, "device_pools")
-        if self._dev_k is None:
+        if self._dev is None:
             import jax.numpy as jnp
 
-            self._dev_k = jnp.zeros(self.pool_shape, self.dtype)
-            self._dev_v = jnp.zeros(self.pool_shape, self.dtype)
-        return self._dev_k, self._dev_v
+            self._dev = tuple(jnp.zeros(shape, self.dtype)
+                              for shape in self.pool_shapes)
+        return self._dev
 
-    def adopt_device_pools(self, k_pool, v_pool) -> None:
+    def adopt_device_pools(self, *pools) -> None:
         """Install the pools a prefill or decode program returned (its
         in-program scatter made them the cache)."""
         self._residence(True, "adopt_device_pools")
-        self._dev_k = k_pool
-        self._dev_v = v_pool
+        assert len(pools) == len(self.pool_shapes), len(pools)
+        self._dev = tuple(pools)
 
     def drop_lost_pools(self) -> bool:
         """After a failed device call: whether the pools were lost with
@@ -413,10 +429,9 @@ class PagedKVCache:
         starts from zeros) and returns True so the caller can recompute
         the live sequences; False when the pools are intact."""
         self._residence(True, "drop_lost_pools")
-        if self._dev_k is None or not (self._dev_k.is_deleted()
-                                       or self._dev_v.is_deleted()):
+        if self._dev is None or not any(p.is_deleted() for p in self._dev):
             return False
-        self._dev_k = self._dev_v = None
+        self._dev = None
         return True
 
     def advance_many(self, updates) -> None:

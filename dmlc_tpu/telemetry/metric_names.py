@@ -414,6 +414,11 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_kv_upload_bytes",
     "dmlc_serving_prefill_d2h_bytes",
     "dmlc_serving_queue_wait_count",
+    # routing of the held experts (latent family), per program call
+    "dmlc_serving_moe_pairs_total",
+    "dmlc_serving_moe_pairs_held",
+    "dmlc_serving_moe_expert_load_max",
+    "dmlc_serving_moe_expert_load_mean",
 })
 
 #: span / jax-profiler annotation names that look like metric tokens in
