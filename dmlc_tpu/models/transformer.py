@@ -849,7 +849,12 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
     the gather path applies to its dense view, with the window tokens
     at their real paged addresses instead of a concatenated tail.
     Dead rows (length 0) route their scatter out of bounds
-    (``mode="drop"``) so padding can never corrupt a live block.
+    (``mode="drop"``) so padding can never corrupt a live block.  The
+    scatter addresses the 5-D pool (``[li, block, slot]``, block
+    ``n_blocks`` is out of bounds in every layer); the kernel gets all
+    layers' pages as one run with the tables shifted to layer ``li``,
+    so no layer's slice of a pool is ever made.  The engine donates
+    the pools, which makes the scatter in place.
 
     Returns ``(logits [B, S, V], k_pool, v_pool)``: the updated pools
     are the cache (the caller adopts them and advances each row's
@@ -888,8 +893,13 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
                     k.astype(k_pool.dtype), mode="drop")
                 v_pool = v_pool.at[li, wb, ws].set(
                     v.astype(v_pool.dtype), mode="drop")
-                o = _paged.paged_attention(q, k_pool[li], v_pool[li],
-                                           block_tables, lengths)
+                # the layers' pools as one run of pages, a free view:
+                # a per-layer slice of a pool would be copied for the
+                # kernel
+                o = _paged.paged_attention(
+                    q, k_pool.reshape((-1,) + k_pool.shape[2:]),
+                    v_pool.reshape((-1,) + v_pool.shape[2:]),
+                    block_tables + li * n_blocks, lengths)
                 x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
             with jax.named_scope("mlp"):
                 x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
@@ -1215,7 +1225,7 @@ def forward_decode_paged_mla(params, ids, positions, pool, block_tables,
                                              positions, cfg)
             pool = _write_latent_rows(pool, li, wb, ws, row)
             # the layers' pools as one run of pages: a per-layer slice
-            # of the pool would be copied for the kernel (ROADMAP A3)
+            # of the pool would be copied for the kernel
             o_lat = _paged.latent_paged_attention(
                 _mla_absorbed_queries(q_nope, q_pe, p, cfg),
                 pool.reshape((-1,) + pool.shape[2:]),

@@ -5,12 +5,17 @@ of tokens (one token in plain decode, ``k+1`` in a speculative-verify
 step) attend against that sequence's KV blocks *in place*, addressed
 through a per-sequence block table — no dense ``[B, maxlen, H, D]``
 gather is ever materialized and no re-placement copy runs per
-iteration.  The pool keeps the cache's layer-major layout
-(``kv_cache.PagedKVCache``); callers pass ONE layer's slice:
+iteration.  A pool here is any run of pages.  The cache keeps its
+pools layer-major (``kv_cache.PagedKVCache``), and the decode program
+passes ALL layers' pages as one run, the free view ``[L * n_blocks,
+...]`` of its donated pool, with layer ``li``'s tables shifted by
+``li * n_blocks``: a per-layer slice ``pool[li]`` would be copied for
+the kernel at every call.
 
-    k_pool / v_pool : [n_blocks, block_size, H, D]
-    block_tables    : [B, W] int32   (row b's physical block ids;
-                                      rows padded with 0 — masked off)
+    k_pool / v_pool : [n_pages, block_size, H, D]
+    block_tables    : [B, W] int32   (row b's physical page ids;
+                                      rows padded with 0, or a layer's
+                                      page 0 — masked off)
     lengths         : [B]    int32   (committed tokens before the window)
     q               : [B, S, H, D]   (post-rope window queries)
 
@@ -20,8 +25,8 @@ the caller must have scattered the window's own K/V into the pool at
 positions ``lengths[b] .. lengths[b]+S-1`` first (scatter-then-attend),
 so this is exactly the gather path's "cache + new token" mask with the
 new tokens living at their real paged addresses instead of a dense
-tail.  Dead batch rows (length 0, table all zeros) read block 0 and
-produce garbage the engine never samples.
+tail.  Dead batch rows (length 0, table all padding) read the padding
+page and produce garbage the engine never samples.
 
 Two implementations: a Pallas TPU kernel whose block-table indirection
 lives in the BlockSpec index map (the scalar-prefetched table picks
@@ -216,7 +221,7 @@ def _pallas_paged_attention(q, k_pool, v_pool, block_tables, lengths,
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
                     scale: Optional[float] = None, impl: str = "auto",
                     interpret: bool = False):
-    """Window attention against one layer's paged KV pool.
+    """Window attention against a paged KV pool.
 
     See the module docstring for shapes and the mask contract.  Returns
     ``[B, S, H, D]`` in q's dtype.  ``impl``: "auto" takes the Pallas

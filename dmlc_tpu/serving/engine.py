@@ -190,7 +190,9 @@ def _jitted_programs(use_paged: bool = False, window: int = 1,
     ``serving.decode_spec``), or the paged fast path
     (``forward_decode_paged``, site ``serving.decode_paged`` — one
     program serves any verify window, the window is a shape; its pools
-    are not donated yet, ROADMAP A3).  All go
+    are DONATED like the prefill's, so a step scatters into them in
+    place, and whoever calls the jitted program loses the arrays it
+    passed in and goes on with the ones returned).  All go
     through :func:`telemetry.compute.profiled_jit`, which is plain
     ``jax.jit`` when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on
     that mode so toggling the knob between tests cannot hand a plain
@@ -218,7 +220,8 @@ def _jitted_programs(use_paged: bool = False, window: int = 1,
         decode_key = (mode, "decode_paged")
         builder = lambda cap: compute.profiled_jit(  # noqa: E731
             tfm.forward_decode_paged, site="serving.decode_paged",
-            static_argnums=(7,), max_signatures=cap)
+            static_argnums=(7,), donate_argnums=(3, 4),
+            max_signatures=cap)
     elif window > 1:
         decode_key = (mode, "decode_spec")
         builder = lambda cap: compute.profiled_jit(  # noqa: E731
@@ -1042,9 +1045,9 @@ class InferenceEngine:
                         self._decode, self.params, ids, positions, tables,
                         lengths, at=3)
                 except Exception:
-                    # a donated pool (the latent family's) went with the
-                    # failed call: the loop's requeue re-prefills into
-                    # fresh ones
+                    # the donated pools went with a call that failed
+                    # after dispatch: the loop's requeue re-prefills
+                    # into fresh ones
                     self.cache.drop_lost_pools()
                     raise
                 self.cache.adopt_device_pools(*pools)

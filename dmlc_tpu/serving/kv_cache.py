@@ -25,10 +25,13 @@ and the two residences share no data-plane code:
 
 * *device* (``device_resident=True``, the engine's paged path): the
   pools are device arrays and they ARE the cache.  Device programs
-  write them (``models.forward_prefill_paged`` scatters a prompt's K/V
-  into its blocks, ``forward_decode_paged`` the decode window's) and
-  the engine swaps in what they return (:meth:`device_pools` /
-  :meth:`adopt_device_pools`); the host only advances lengths
+  write them in place (``models.forward_prefill_paged`` scatters a
+  prompt's K/V into its blocks, ``forward_decode_paged`` the decode
+  window's; the engine DONATES the pools to both, so the arrays handed
+  in are deleted by the call) and the engine swaps in what they return
+  (:meth:`device_pools` / :meth:`adopt_device_pools`, and
+  :meth:`drop_lost_pools` after a call that failed with the pools in
+  its hands); the host only advances lengths
   (:meth:`advance_many`) and ships the tiny int32
   :meth:`block_tables_array` per step.  No K/V ever crosses the host
   link and no numpy pool exists; ``write`` / ``gather`` refuse.
@@ -422,12 +425,13 @@ class PagedKVCache:
 
     def drop_lost_pools(self) -> bool:
         """After a failed device call: whether the pools were lost with
-        it.  A program the pools are DONATED to (prefill) owns their
-        buffers from dispatch on; if it then raises, the arrays this
-        cache still references are deleted and every live sequence's
-        K/V is gone.  Forgets them (the next :meth:`device_pools`
-        starts from zeros) and returns True so the caller can recompute
-        the live sequences; False when the pools are intact."""
+        it.  A program the pools are DONATED to (prefill and decode
+        alike) owns their buffers from dispatch on; if it then raises,
+        the arrays this cache still references are deleted and every
+        live sequence's K/V is gone.  Forgets them (the next
+        :meth:`device_pools` starts from zeros) and returns True so the
+        caller can recompute the live sequences; False when the pools
+        are intact."""
         self._residence(True, "drop_lost_pools")
         if self._dev is None or not any(p.is_deleted() for p in self._dev):
             return False

@@ -13,7 +13,11 @@ batched commit write is byte-equivalent to the per-row writes it
 replaced) and device (the paged path: prefill writes a sequence's
 blocks inside the program and nothing else, each residence refuses
 the other's data plane, and a prefill that fails fails alone unless it
-took the donated pools with it).
+took the donated pools with it).  The decode program owns its pools
+for the length of a call: donated at the engine's jit site, never
+sliced by layer, dead rows harmless in every layer, and a call that
+fails with the pools in its hands is an iteration crash the loop
+recovers from.
 """
 
 import json
@@ -326,6 +330,188 @@ def test_failed_prefill_fails_alone_unless_it_took_the_pools(pools_lost):
     assert good.generated == _greedy_oracle(params, cfg, [1, 2, 3, 4, 5], 12)
     after = telemetry.counters_snapshot()["serving"].get("crash_requeues", 0)
     assert after - before == (1 if pools_lost else 0)
+    assert eng.cache.n_blocks_in_use == 0
+
+
+# ---------------------------------------------------------------------------
+# the decode program owns its pools: donated, never sliced by layer
+# ---------------------------------------------------------------------------
+
+def _decode_branch(branch):
+    """``(params, cfg, kernel mode)`` for one branch of the decode
+    program's attention: "lax" on the tiny model, "kernel" (the Pallas
+    kernel, interpreted) on two layers at the flagship's page (16 heads
+    x 128), the smallest the kernel serves."""
+    import jax
+
+    from dmlc_tpu.models import transformer as tfm
+    from dmlc_tpu.ops import dispatch
+
+    if branch == "lax":
+        return _tiny_model() + (dispatch.LAX,)
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=16,
+                                head_dim=128, d_ff=64, n_layers=2,
+                                n_experts=1, microbatches=1)
+    return (tfm.init_params(jax.random.PRNGKey(0), cfg), cfg,
+            dispatch.INTERPRET)
+
+
+def test_engine_decode_program_donates_both_pools():
+    """The MHA decode program as the engine jits it marks both pools
+    donated in its lowering; a step deletes the arrays the cache held
+    and leaves it holding what the program returned."""
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
+                          max_active=2, queue_depth=4)
+    assert eng._use_paged
+    returned = []
+    real = eng._decode
+
+    def decode(*a):
+        out = real(*a)
+        returned.append(out[1:3])
+        return out
+
+    eng._decode = decode
+    eng.submit([1, 2, 3, 4, 5], max_new_tokens=6)
+    eng.step()  # the prefill, and the first decode window with it
+    assert len(returned) == 1
+    for _ in range(2):
+        held = eng.cache.device_pools()
+        assert not any(p.is_deleted() for p in held)
+        eng.step()  # one decode window
+        assert all(p.is_deleted() for p in held)
+        now = eng.cache.device_pools()
+        assert all(a is b for a, b in zip(now, returned[-1]))
+        assert not any(p.is_deleted() for p in now)
+    assert len(returned) == 3
+    ids = np.zeros((2, 1), np.int32)
+    lowered = getattr(real, "_jit", real).lower(
+        params, ids, ids, *eng.cache.device_pools(),
+        np.zeros((2, 2), np.int32), np.zeros((2,), np.int32), cfg)
+    donated = [a.donated for a in lowered.args_info[0][1:]]
+    assert donated == [False, False, True, True, False, False]
+    assert lowered.as_text().count("tf.aliasing_output") == 2
+
+
+def _shapes_in(jaxpr, seen):
+    """Every intermediate's shape, sub-jaxprs (pjit, scan, cond, the
+    kernel's body) included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            seen.add(tuple(getattr(v.aval, "shape", ())))
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _shapes_in(sub, seen)
+    return seen
+
+
+@pytest.mark.parametrize("branch", ["lax", "kernel"])
+def test_decode_program_never_makes_one_layers_pool(branch):
+    """No intermediate of the traced forward_decode_paged has one
+    layer's pool shape, as 4-D pages or as the kernel's 3-D view: a
+    per-layer slice is a copy of the layer's pool at every call."""
+    import jax
+
+    from dmlc_tpu.models import transformer as tfm
+    from dmlc_tpu.ops import dispatch
+
+    params, cfg, mode = _decode_branch(branch)
+    n_blocks, bs, b, w = 7, 16, 3, 2
+    h, d = cfg.n_heads, cfg.head_dim
+    pool = jax.ShapeDtypeStruct((cfg.n_layers, n_blocks, bs, h, d),
+                                np.float32)
+    ids = np.zeros((b, 1), np.int32)
+    with dispatch.force_kernel_mode(mode):
+        traced = jax.make_jaxpr(tfm.forward_decode_paged,
+                                static_argnums=(7,))(
+            params, ids, ids, pool, pool, np.zeros((b, w), np.int32),
+            np.zeros((b,), np.int32), cfg)
+    seen = _shapes_in(traced.jaxpr, set())
+    assert (cfg.n_layers * n_blocks, bs, h, d) in seen  # the flat view
+    assert ((cfg.n_layers * n_blocks, bs * h, d) in seen) == (
+        branch == "kernel")
+    assert (n_blocks, bs, h, d) not in seen
+    assert (n_blocks, bs * h, d) not in seen
+    assert (1, n_blocks, bs, h, d) not in seen
+
+
+@pytest.mark.parametrize("branch", ["lax", "kernel"])
+def test_dead_rows_touch_no_page_of_any_layer(branch):
+    """One live row between two dead ones (length 0, table all zeros):
+    in every layer the step writes the live row's one slot and nothing
+    else.  Block 0 of the NEXT layer is the page a dead row's dropped
+    scatter would hit if it were addressed in the kernel's flat view."""
+    import jax.numpy as jnp
+
+    from dmlc_tpu.models import transformer as tfm
+    from dmlc_tpu.ops import dispatch
+
+    params, cfg, mode = _decode_branch(branch)
+    n_blocks, bs = 5, 16
+    shape = (cfg.n_layers, n_blocks, bs, cfg.n_heads, cfg.head_dim)
+    rng = np.random.default_rng(7)
+    k0, v0 = _rand(rng, *shape), _rand(rng, *shape)
+    tables = np.array([[0, 0], [3, 1], [0, 0]], np.int32)
+    lengths = np.array([0, bs + 1, 0], np.int32)  # live: block 1, slot 1
+    ids = np.array([[5], [9], [0]], np.int32)
+    positions = lengths[:, None]
+    with dispatch.force_kernel_mode(mode):
+        logits, k1, v1 = tfm.forward_decode_paged(
+            params, ids, positions, jnp.asarray(k0), jnp.asarray(v0),
+            tables, lengths, cfg)
+    assert np.isfinite(np.asarray(logits[1])).all()
+    written = np.zeros(shape[:3], bool)
+    written[:, 1, 1] = True
+    for before, after in ((k0, np.asarray(k1)), (v0, np.asarray(v1))):
+        np.testing.assert_array_equal(after[~written], before[~written])
+        changed = (after[written] != before[written]).reshape(
+            cfg.n_layers, -1)
+        assert changed.any(axis=1).all()  # each layer wrote its slot
+
+
+def test_failed_decode_that_took_the_pools_is_recovered():
+    """A decode call that raises after dispatch has taken the donated
+    pools with it: the live requests are requeued, re-prefilled into
+    fresh pools and finish with the ids of an undisturbed run."""
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
+                          max_active=2, queue_depth=4)
+    assert eng._use_paged
+    real = eng._decode
+    calls = {"n": 0, "lost": None}
+
+    def decode(p, ids, positions, k_pool, v_pool, tables, lengths, c):
+        calls["n"] += 1
+        if calls["n"] == 3:
+            k_pool.delete()  # what a donating call that raised after
+            v_pool.delete()  # dispatch leaves behind
+            calls["lost"] = (k_pool, v_pool)
+            raise RuntimeError("injected decode failure")
+        return real(p, ids, positions, k_pool, v_pool, tables, lengths, c)
+
+    eng._decode = decode
+    before = telemetry.counters_snapshot().get("serving", {}).get(
+        "crash_requeues", 0)
+    prompts = [[1, 2, 3, 4, 5], [7, 8, 9]]
+    eng.start()
+    try:
+        reqs = [eng.submit(p, max_new_tokens=10) for p in prompts]
+        for r in reqs:
+            assert r.wait(300), f"request {r.id} never finished"
+    finally:
+        eng.close()
+    assert calls["lost"] is not None and calls["n"] > 3
+    for r, p in zip(reqs, prompts):
+        assert r.error is None
+        assert r.generated == _greedy_oracle(params, cfg, p, 10)
+    after = telemetry.counters_snapshot()["serving"].get("crash_requeues", 0)
+    assert after - before >= 1  # every request that was live went back
+    fresh = eng.cache.device_pools()
+    assert not any(p.is_deleted() for p in fresh)
+    assert all(a is not b for a, b in zip(fresh, calls["lost"]))
     assert eng.cache.n_blocks_in_use == 0
 
 

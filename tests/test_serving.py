@@ -1207,18 +1207,34 @@ def test_engine_starved_is_one_span_per_episode():
     eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
                           max_active=2, queue_depth=8)
     telemetry.reset()
+    passes = 25
+
+    def idle_passes(n):
+        """Wait for n passes of the loop that found no work.  A pass
+        ticks ``_step_seq`` twice; one more tick covers the pass that
+        was finishing the last request when the wait began."""
+        want = eng._step_seq + 2 * n + 1
+        deadline = time.monotonic() + 300
+        while eng._step_seq < want:
+            assert time.monotonic() < deadline, "the loop stopped passing"
+            time.sleep(0.001)
+
     eng.start()
     try:
-        time.sleep(0.1)  # ~50 empty passes of the loop: ONE episode
+        idle_passes(passes)  # many empty passes of the loop: ONE episode
         assert eng.submit([1, 2, 3], max_new_tokens=3).wait(300)
-        time.sleep(0.1)  # a second episode
+        idle_passes(passes)  # a second episode
         assert eng.submit([4, 5, 6], max_new_tokens=3).wait(300)
+        idle_passes(2)  # the third has opened
     finally:
         eng.close()  # the loop's exit closes the episode in flight
     recs = _engine_thread_spans()
     starved = [r for r in recs if r["name"] == "serving.starved"]
     assert len(starved) == 3, [r["dur"] for r in starved]
-    assert starved[0]["dur"] >= 0.08e6 and starved[1]["dur"] >= 0.08e6
+    # one span over the episode's passes, each of which slept 2 ms (the
+    # first may have opened it, the last may be cut by the submit)
+    for episode in starved[:2]:
+        assert episode["dur"] >= (passes - 2) * 0.002e6
     # an iteration is a step() that found work: none inside an episode,
     # none empty
     iterations = [r for r in recs if r["name"] == "serving.iteration"]
