@@ -459,10 +459,6 @@ KNOBS: Tuple[Knob, ...] = (
     _k("DMLC_SERVE_PRIORITY_DEFAULT", int, 1,
        "priority assigned to a request that carries none",
        group="serving"),
-    _k("DMLC_SERVE_PAGED_ATTN", str, "auto",
-       "decode fast path: attend the paged KV pool in place "
-       "(auto|on|off; auto falls back to the dense gather only when "
-       "the mesh shards the gathered view)", group="serving"),
     _k("DMLC_SERVE_SPEC_K", int, 0,
        "speculative decoding: draft tokens per verify window "
        "(0 = off; greedy output stays bit-identical)", group="serving"),

@@ -11,7 +11,6 @@ from .transformer import (  # noqa: F401
     count_params,
     decode_flops_per_token,
     flagship_config,
-    forward_decode,
     forward_local,
     forward_prefill,
     forward_prefill_last,

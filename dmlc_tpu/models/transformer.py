@@ -535,9 +535,8 @@ def unsharded_loss(params, ids, labels, cfg: TransformerConfig):
 
 # ---------------------------------------------------------------------------
 # serving forward paths: prefill (full sequence) and decode against the
-# KV cache dmlc_tpu.serving owns (serving/kv_cache.py).  The paged heads
-# read and write the device-resident pools in place; the gather heads
-# see dense gathered views and hand the new K/V back to the host
+# KV cache dmlc_tpu.serving owns (serving/kv_cache.py), whose
+# device-resident pools the paged heads read and write in place
 # ---------------------------------------------------------------------------
 
 
@@ -550,21 +549,6 @@ def decode_flops_per_token(cfg: TransformerConfig, ctx: int) -> float:
     is exactly the forward third of ``train_flops_per_token`` counted
     without the causal discount."""
     return train_flops_per_token(cfg, ctx, causal=False) / 3.0
-
-
-def _rope_at(x, positions, theta: float = 10000.0):
-    """Rotary embedding for decode: x [B, 1, H, D] with a PER-SEQUENCE
-    position [B] (continuous batching puts every active request at a
-    different depth, so the shared-[T] ``rope`` signature cannot serve)."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # [B, half]
-    cos = jnp.cos(angles)[:, None, None, :]
-    sin = jnp.sin(angles)[:, None, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
 
 
 def _causal_attention(q, k, v, scale=None):
@@ -581,31 +565,6 @@ def _causal_attention(q, k, v, scale=None):
         return ring_attention_reference(q, k, v, causal=True, scale=scale)
     return _flash.flash_attention(q, k, v, causal=True, scale=scale,
                                   interpret=mode == dispatch.INTERPRET)
-
-
-def _cached_attention(q, k_new, v_new, k_cache, v_cache, lengths):
-    """One-token attention over an external cache.
-
-    q/k_new/v_new: [B, 1, H, D] (the token being consumed, post-rope);
-    k_cache/v_cache: [B, Tc, H, D] — slot j of row b is valid iff
-    j < lengths[b] (paged gathers pad with garbage past the length).
-    The new token's K/V ride along explicitly so the caller can write
-    them into the cache AFTER the step (the cache never holds a token
-    the model has not consumed yet).
-    """
-    d = q.shape[-1]
-    tc = k_cache.shape[1]
-    k_all = jnp.concatenate([k_cache, k_new], axis=1)  # [B, Tc+1, H, D]
-    v_all = jnp.concatenate([v_cache, v_new], axis=1)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_all,
-                   preferred_element_type=jnp.float32) * (1.0 / d ** 0.5)
-    idx = jnp.arange(tc + 1)
-    valid = (idx[None, :] < lengths[:, None]) | (idx[None, :] == tc)
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v_all.dtype), v_all,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
 
 
 def _layer_params(blocks, stage: int, layer: int):
@@ -669,8 +628,8 @@ def _logits_at(params, x, last_index):
 def forward_prefill_last(params, ids, last_index, cfg: TransformerConfig):
     """Prefill with logits at ONE position per sequence:
     ``(logits [B, V], k, v)`` for ``last_index`` [B] (each sequence's
-    final real token in a right-padded batch).  The gather path's
-    prefill: the caller copies ``k, v`` into its host-resident cache."""
+    final real token in a right-padded batch).  The K/V come back
+    dense: what :func:`forward_prefill_paged` must put into the pools."""
     x, k, v = _prefill_trunk(params, ids, cfg)
     return _logits_at(params, x, last_index), k, v
 
@@ -703,52 +662,6 @@ def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
     return _logits_at(params, x, last_index), k_pool, v_pool
 
 
-def forward_decode(params, ids, positions, k_cache, v_cache, lengths,
-                   cfg: TransformerConfig):
-    """Single-token decode step against an externally supplied KV cache.
-
-    ids / positions / lengths: [B] — the token each sequence consumes
-    this step, its absolute position, and how many tokens of that
-    sequence the cache currently holds (positions == lengths for a
-    healthy cache; they are separate arguments so tests can probe).
-    k_cache / v_cache: [L, B, Tc, H, hd] dense gathered views (padded;
-    see :func:`_cached_attention` for validity).
-
-    Returns ``(logits [B, V], k_new, v_new [L, B, H, hd])``: the
-    next-token logits and this token's per-layer K/V for the caller to
-    append to the cache.  Batch rows are independent, so a continuous
-    batcher can pad the batch with dead rows (length 0) freely.
-    """
-    x = embed_lookup(params["embed"], ids[:, None],
-                     ShardAxes()).astype(cfg.jdtype)  # [B, 1, E]
-    blocks = params["blocks"]
-    n_stages, lps = blocks["ln1"].shape[0], blocks["ln1"].shape[1]
-    k_news, v_news = [], []
-    li = 0
-    for s in range(n_stages):
-        for i in range(lps):
-            p = _layer_params(blocks, s, i)
-            with jax.named_scope("attention"):
-                xn = rms_norm(x, p["ln1"])
-                q = jnp.einsum("bte,ehd->bthd", xn, p["wq"])
-                k = jnp.einsum("bte,ehd->bthd", xn, p["wk"])
-                v = jnp.einsum("bte,ehd->bthd", xn, p["wv"])
-                q = _rope_at(q, positions)
-                k = _rope_at(k, positions)
-                o = _cached_attention(q, k, v, k_cache[li], v_cache[li],
-                                      lengths)
-                x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
-            with jax.named_scope("mlp"):
-                x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
-            k_news.append(k[:, 0])
-            v_news.append(v[:, 0])
-            li += 1
-    with jax.named_scope("unembed"):
-        x = rms_norm(x, params["ln_f"])
-        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])[:, 0]
-    return logits, jnp.stack(k_news), jnp.stack(v_news)
-
-
 def _rope_window(x, positions, theta: float = 10000.0):
     """Rotary embedding for a decode WINDOW: x [B, S, H, D] with
     per-token positions [B, S] (speculative verify places each window
@@ -762,74 +675,6 @@ def _rope_window(x, positions, theta: float = 10000.0):
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
-
-
-def _cached_window_attention(q, k_new, v_new, k_cache, v_cache, lengths):
-    """Window generalization of :func:`_cached_attention`: S window
-    tokens per row (q/k_new/v_new [B, S, H, D]) attend the cache plus a
-    causal prefix of the window itself — window position s sees cache
-    slots j < lengths[b] and window slots <= s.  S=1 reduces exactly to
-    the single-token mask."""
-    d = q.shape[-1]
-    tc = k_cache.shape[1]
-    s_w = q.shape[1]
-    k_all = jnp.concatenate([k_cache, k_new], axis=1)  # [B, Tc+S, H, D]
-    v_all = jnp.concatenate([v_cache, v_new], axis=1)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_all,
-                   preferred_element_type=jnp.float32) * (1.0 / d ** 0.5)
-    idx = jnp.arange(tc + s_w)
-    in_cache = idx[None, None, :] < lengths[:, None, None]       # [B, 1, K]
-    in_window = ((idx[None, None, :] >= tc)
-                 & (idx[None, None, :] - tc
-                    <= jnp.arange(s_w)[None, :, None]))          # [1, S, K]
-    valid = in_cache | in_window                                 # [B, S, K]
-    s = jnp.where(valid[:, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v_all.dtype), v_all,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
-
-
-def forward_decode_spec(params, ids, positions, k_cache, v_cache, lengths,
-                        cfg: TransformerConfig):
-    """Multi-token verify step against a dense gathered cache.
-
-    The speculative-decoding scorer on the gather path: ids/positions
-    [B, S] are each row's window — position 0 the token being consumed,
-    positions 1..S-1 drafted continuations — and the step returns
-    logits at ALL window positions (``[B, S, V]``) so the engine's
-    longest-accepted-prefix walk can verify every draft from one
-    program launch.  k_new/v_new come back ``[L, B, S, H, hd]``; the
-    caller appends exactly the prefix it commits.  S=1 is numerically
-    the plain :func:`forward_decode` (same mask, same f32 score path).
-    """
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
-    blocks = params["blocks"]
-    n_stages, lps = blocks["ln1"].shape[0], blocks["ln1"].shape[1]
-    k_news, v_news = [], []
-    li = 0
-    for s in range(n_stages):
-        for i in range(lps):
-            p = _layer_params(blocks, s, i)
-            with jax.named_scope("attention"):
-                xn = rms_norm(x, p["ln1"])
-                q = jnp.einsum("bte,ehd->bthd", xn, p["wq"])
-                k = jnp.einsum("bte,ehd->bthd", xn, p["wk"])
-                v = jnp.einsum("bte,ehd->bthd", xn, p["wv"])
-                q = _rope_window(q, positions)
-                k = _rope_window(k, positions)
-                o = _cached_window_attention(q, k, v, k_cache[li],
-                                             v_cache[li], lengths)
-                x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
-            with jax.named_scope("mlp"):
-                x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
-            k_news.append(k)
-            v_news.append(v)
-            li += 1
-    with jax.named_scope("unembed"):
-        x = rms_norm(x, params["ln_f"])
-        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
-    return logits, jnp.stack(k_news), jnp.stack(v_news)
 
 
 def forward_decode_paged(params, ids, positions, k_pool, v_pool,

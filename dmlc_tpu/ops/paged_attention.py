@@ -23,10 +23,10 @@ Window position ``s`` of row ``b`` attends pool positions
 ``p <= lengths[b] + s`` within the table's ``W * block_size`` span —
 the caller must have scattered the window's own K/V into the pool at
 positions ``lengths[b] .. lengths[b]+S-1`` first (scatter-then-attend),
-so this is exactly the gather path's "cache + new token" mask with the
-new tokens living at their real paged addresses instead of a dense
-tail.  Dead batch rows (length 0, table all padding) read the padding
-page and produce garbage the engine never samples.
+so this is the causal "cache + new token" mask with the new tokens
+living at their real paged addresses.  Dead batch rows (length 0,
+table all padding) read the padding page and produce garbage the
+engine never samples.
 
 Two implementations: a Pallas TPU kernel whose block-table indirection
 lives in the BlockSpec index map (the scalar-prefetched table picks
@@ -89,9 +89,9 @@ def supports(head_dim: int, block_size: int, n_heads: int) -> bool:
 def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale):
     """Gather-composed fallback: the block gather happens INSIDE jit
     (one fused gather per layer, no host staging, no dense [B, maxlen]
-    intermediate on the host) and the math mirrors the model's
-    ``_cached_attention`` f32 score path bit-for-bit modulo summation
-    order — the 1e-5 parity contract."""
+    intermediate on the host) and the math is dense softmax attention
+    with an f32 score path — the 1e-5 parity contract the kernel is
+    held to."""
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
     bs = k_pool.shape[1]

@@ -5,9 +5,8 @@ inference server over the flagship transformer, built from the pieces
 the repo already trusts —
 
   * ``kv_cache``   paged (block-granular) KV storage with a free-list
-                   allocator; the bytes live on the device (paged path)
-                   or on the host, whose gathered views shard over
-                   parallel.mesh (gather path)
+                   allocator; the bytes are device pools that the
+                   prefill and decode programs write in place
   * ``scheduler``  Orca-style iteration-level admit/evict with
                    preemption-by-recompute under memory pressure
   * ``engine``     the prefill/decode loop: jitted model programs,

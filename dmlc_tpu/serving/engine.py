@@ -37,21 +37,17 @@ profiler capture and a ``<suffix>_secs`` / ``<suffix>_count`` counter
 pair; the bytes that cross the host link are counted at the same
 boundaries (README "Serving").
 
-Where the cache's bytes live follows the data path.  On the paged path
-the device pools are the cache: the prefill program scatters the
+The device pools are the cache: the prefill program scatters the
 prompt's K/V into the sequence's blocks and the decode program the
 window's, the engine adopts the pools they return, and only logits
-cross the link (``serving.kv_write`` is then the adoption and the
-length bookkeeping).  On the gather path (``DMLC_SERVE_PAGED_ATTN=off``
-or a mesh that shards the cache) the cache is host-resident: prefill's
-K/V come to numpy under ``serving.prefill.kv_to_host``, ``kv_write``
-copies them in, and decode hands its new K/V back the same way.
+cross the link (``serving.kv_write`` is the adoption and the length
+bookkeeping).
 
 Shape discipline (XLA recompiles per shape, so both are bucketed):
 prefill pads prompts up to a whole number of KV blocks (safe under
 causal attention), and decode always runs the full ``max_active``-row
-batch with dead rows masked by length 0, growing the gathered context
-in whole-block steps.
+batch with dead rows masked by length 0, its block tables growing a
+block at a time.
 """
 
 from __future__ import annotations
@@ -68,7 +64,7 @@ from .. import concurrency, telemetry
 from ..base import DMLCError, get_env
 from ..concurrency import BufferPool, make_lock
 from ..models import transformer as tfm
-from .kv_cache import PagedKVCache, kv_partition_spec
+from .kv_cache import PagedKVCache
 from .scheduler import (ACTIVE, WAITING, AlreadyFinished,
                         ContinuousBatchScheduler, Request,
                         coerce_priority)
@@ -95,10 +91,10 @@ _JIT_CACHE: dict = {}
 
 #: every counter the serving spans and byte counts feed: start() sets
 #: them to 0, so a window in which a phase never ran reads 0 and not
-#: "nothing to read".  ``prefill_kv_to_host`` opens on the gather path
-#: only and ``kv_upload`` nowhere since the device pools became the
-#: cache; BENCHMARK.json's K/V round-trip metrics sum them with
-#: ``kv_write``, so they stay, at 0
+#: "nothing to read".  No code opens ``prefill_kv_to_host`` or
+#: ``kv_upload`` or adds to ``kv_upload_bytes`` (the device pools are
+#: the cache); three of BENCHMARK.json's per-layer metrics sum them with
+#: ``kv_write`` by name and read null without a term, so they stay, at 0
 _SPAN_FAMILIES = (
     "iteration", "schedule", "prefill", "prefill_run",
     "prefill_kv_to_host", "kv_write", "kv_upload", "first_token",
@@ -169,42 +165,33 @@ _MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held",
                  "moe_expert_load_max", "moe_expert_load_mean")
 
 
-def _jitted_programs(use_paged: bool = False, window: int = 1,
-                     latent: bool = False):
-    """Process-wide jitted prefill/decode (one jit wrapper per program
-    variant, so every engine instance shares one compile cache — tests
-    and smokes build several engines and must not pay XLA again for
+def _jitted_programs(latent: bool = False):
+    """Process-wide jitted prefill/decode (one jit wrapper per program,
+    so every engine instance shares one compile cache — tests and
+    smokes build several engines and must not pay XLA again for
     identical shapes).
 
-    Both programs depend on the engine's data path.  Prefill, site
-    ``serving.prefill`` either way (an engine runs one of the two):
-    ``forward_prefill_paged`` with the pools DONATED — it has this one
-    caller, which replaces its references with the returned pools at
-    once, and an undonated scatter would copy both pools per prompt —
-    or the gather path's ``forward_prefill_last``.  A latent-attention
-    model (``latent``) runs the paged path's twins at the same two
-    sites, ``forward_prefill_paged_mla`` / ``forward_decode_paged_mla``,
-    both donated their one pool.  Decode: the gather
-    oracle (``forward_decode``, site ``serving.decode``), its
-    multi-token speculative-verify twin (``forward_decode_spec``, site
-    ``serving.decode_spec``), or the paged fast path
-    (``forward_decode_paged``, site ``serving.decode_paged`` — one
-    program serves any verify window, the window is a shape; its pools
-    are DONATED like the prefill's, so a step scatters into them in
-    place, and whoever calls the jitted program loses the arrays it
-    passed in and goes on with the ones returned).  All go
+    Which pair depends on the model family alone.  Prefill, site
+    ``serving.prefill``: ``forward_prefill_paged`` with the pools
+    DONATED — it has this one caller, which replaces its references
+    with the returned pools at once, and an undonated scatter would
+    copy both pools per prompt.  Decode, site ``serving.decode_paged``:
+    ``forward_decode_paged`` — one program serves any verify window,
+    the window is a shape; its pools are DONATED like the prefill's, so
+    a step scatters into them in place, and whoever calls the jitted
+    program loses the arrays it passed in and goes on with the ones
+    returned.  A latent-attention model (``latent``) runs their twins
+    at the same two sites, ``forward_prefill_paged_mla`` /
+    ``forward_decode_paged_mla``, both donated their one pool.  All go
     through :func:`telemetry.compute.profiled_jit`, which is plain
     ``jax.jit`` when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on
     that mode so toggling the knob between tests cannot hand a plain
-    engine a profiled program or vice versa.  Decode sites carry the
-    ``DMLC_SERVE_MAX_DECODE_SIGS`` signature cap — every distinct
+    engine a profiled program or vice versa.  The decode site carries
+    the ``DMLC_SERVE_MAX_DECODE_SIGS`` signature cap — every distinct
     context depth is a full XLA recompile, so unbounded signature
     growth is a bug worth failing loudly on."""
     compute = telemetry.compute
     mode = "profiled" if compute.enabled() else "plain"
-    prefill_key = (mode, "prefill")
-    prefill_fn, prefill_kw = tfm.forward_prefill_last, {
-        "static_argnums": (3,)}
     if latent:
         prefill_key = (mode, "prefill_paged_mla")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged_mla, {
@@ -213,7 +200,7 @@ def _jitted_programs(use_paged: bool = False, window: int = 1,
         builder = lambda cap: compute.profiled_jit(  # noqa: E731
             tfm.forward_decode_paged_mla, site="serving.decode_paged",
             static_argnums=(6,), donate_argnums=(3,), max_signatures=cap)
-    elif use_paged:
+    else:
         prefill_key = (mode, "prefill_paged")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged, {
             "static_argnums": (6,), "donate_argnums": (3, 4)}
@@ -222,16 +209,6 @@ def _jitted_programs(use_paged: bool = False, window: int = 1,
             tfm.forward_decode_paged, site="serving.decode_paged",
             static_argnums=(7,), donate_argnums=(3, 4),
             max_signatures=cap)
-    elif window > 1:
-        decode_key = (mode, "decode_spec")
-        builder = lambda cap: compute.profiled_jit(  # noqa: E731
-            tfm.forward_decode_spec, site="serving.decode_spec",
-            static_argnums=(6,), max_signatures=cap)
-    else:
-        decode_key = (mode, "decode")
-        builder = lambda cap: compute.profiled_jit(  # noqa: E731
-            tfm.forward_decode, site="serving.decode",
-            static_argnums=(6,), max_signatures=cap)
     progs = (_JIT_CACHE.get(prefill_key), _JIT_CACHE.get(decode_key))
     if progs[0] is None or progs[1] is None:
         # this cache outlives any one engine — if the first engine of
@@ -268,7 +245,6 @@ class InferenceEngine:
     """
 
     def __init__(self, params, cfg: "tfm.TransformerConfig", *,
-                 mesh=None,
                  n_blocks: Optional[int] = None,
                  block_size: Optional[int] = None,
                  max_active: Optional[int] = None,
@@ -279,7 +255,6 @@ class InferenceEngine:
                  slo_monitor=None):
         self.params = params
         self.cfg = cfg
-        self.mesh = mesh
         self.max_active = (max_active if max_active is not None
                            else get_env("DMLC_SERVE_MAX_ACTIVE", 8))
         self.admit_timeout_s = (
@@ -298,32 +273,6 @@ class InferenceEngine:
         self.priority_default = min(
             max(0, get_env("DMLC_SERVE_PRIORITY_DEFAULT", 1)),
             self.priority_levels - 1)
-        # decode fast path: paged attention reads the pool in place
-        # (no per-step dense gather / re-placement copy) and an n-gram
-        # drafter turns one verify launch into up to spec_k+1 committed
-        # tokens.  "auto" takes the paged path except when the mesh
-        # demands the gather view's dp/tp re-placement (the paged
-        # program is single-chip for now)
-        self.paged_mode = str(get_env("DMLC_SERVE_PAGED_ATTN",
-                                      "auto")).lower()
-        if self.paged_mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"DMLC_SERVE_PAGED_ATTN must be auto|on|off, got "
-                f"{self.paged_mode!r}")
-        if self.paged_mode == "auto":
-            self._use_paged = (mesh is None
-                               or kv_partition_spec(mesh) is None)
-        else:
-            self._use_paged = self.paged_mode == "on"
-        if cfg.latent and not self._use_paged:
-            raise ValueError(
-                "a latent-attention model is served on the paged path "
-                "only (its cache is the device pool of latent rows); "
-                f"DMLC_SERVE_PAGED_ATTN={self.paged_mode!r} or the mesh "
-                "chose the gather path")
-        # the path decides where the cache's bytes live: on the paged
-        # path the device pools are the cache, on the gather path the
-        # host's numpy pools are
         n_blocks = (n_blocks if n_blocks is not None
                     else get_env("DMLC_SERVE_KV_BLOCKS", 256))
         block_size = (block_size if block_size is not None
@@ -331,8 +280,7 @@ class InferenceEngine:
         self.cache = PagedKVCache(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
             n_blocks=n_blocks, block_size=block_size,
-            dtype=np.dtype(cfg.dtype), mesh=mesh,
-            device_resident=self._use_paged,
+            dtype=np.dtype(cfg.dtype),
             pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size))
         self.scheduler = ContinuousBatchScheduler(
             self.cache, max_active=self.max_active)
@@ -365,8 +313,7 @@ class InferenceEngine:
         self.spec_min_ctx = max(1, int(get_env("DMLC_SERVE_SPEC_MIN_CTX",
                                                4)))
         self._spec_window = 1 + self.spec_k
-        self._prefill, self._decode = _jitted_programs(
-            self._use_paged, self._spec_window, cfg.latent)
+        self._prefill, self._decode = _jitted_programs(cfg.latent)
         self._stop = threading.Event()
         self._draining = threading.Event()
         # iteration seqlock: odd = an engine iteration is mid-flight
@@ -745,17 +692,15 @@ class InferenceEngine:
             self._slots.release(slot)
 
     def _run_prefill(self, req: Request) -> None:
-        """Prefill ``req``'s context and cache its K/V: on the paged
-        path inside the device program, which scatters them into the
-        request's blocks of the pools it is donated (only the logits
-        come to the host); on the gather path through numpy into the
-        host-resident cache.  A fresh request
-        also samples its first token here (that IS the TTFT moment); a
-        preemption resume must NOT sample — its context already excludes
-        the un-consumed ``generated[-1]``, so the last-position logits
-        would deterministically re-derive that very token and duplicate
-        it in the output.  The resume's next token comes from the decode
-        step that consumes ``generated[-1]``."""
+        """Prefill ``req``'s context and cache its K/V inside the device
+        program, which scatters them into the request's blocks of the
+        pools it is donated (only the logits come to the host).  A
+        fresh request also samples its first token here (that IS the
+        TTFT moment); a preemption resume must NOT sample — its context
+        already excludes the un-consumed ``generated[-1]``, so the
+        last-position logits would deterministically re-derive that very
+        token and duplicate it in the output.  The resume's next token
+        comes from the decode step that consumes ``generated[-1]``."""
         with self._span("serving.schedule", req=req.id):
             ctx = req.context_ids()
             n = len(ctx)
@@ -779,16 +724,13 @@ class InferenceEngine:
             ids[0, :n] = ctx
             last = np.array([n - 1], np.int32)
             self.requests.on_prefill_begin(req.id, resume=resume)
-            if self._use_paged:
-                logits = self._prefill_paged(req, ids, last, n)
-            else:
-                logits = self._prefill_gather(req, ids, last, n)
+            logits = self._prefill_paged(req, ids, last, n)
             telemetry.inc("serving", "prefill_tokens", n)
         except Exception as e:  # noqa: BLE001 - fail THIS request only
             logger.error("prefill of request %d failed: %r", req.id, e)
             self._finish(req, error=f"prefill failed: {e!r}",
                          reason="prefill")
-            if self._use_paged and self.cache.drop_lost_pools():
+            if self.cache.drop_lost_pools():
                 # the program failed AFTER its donated pools were given
                 # up (a device fault, not a compile error): every live
                 # sequence's K/V went with them.  That is an iteration
@@ -840,25 +782,6 @@ class InferenceEngine:
                           float(held.max(axis=1).sum()))
             telemetry.inc("serving", "moe_expert_load_mean",
                           float(held.mean(axis=1).sum()))
-
-    def _prefill_gather(self, req: Request, ids, last, n: int):
-        """K and V come to numpy and are copied into the host-resident
-        cache."""
-        with self._span("serving.prefill", tokens=n, req=req.id):
-            with self._span("serving.prefill.run", req=req.id):
-                logits, k, v = self._prefill(self.params, ids, last,
-                                             self.cfg)
-                logits = np.asarray(logits[0])
-            with self._span("serving.prefill.kv_to_host",
-                            req=req.id) as crossed:
-                k = np.asarray(k)
-                v = np.asarray(v)
-                crossed["bytes"] = k.nbytes + v.nbytes
-        telemetry.inc("serving", "prefill_d2h_bytes",
-                      logits.nbytes + crossed["bytes"])
-        with self._span("serving.kv_write", req=req.id):
-            self.cache.write(req.id, k[:, 0, :n], v[:, 0, :n], start=0)
-        return logits
 
     def _after_prefill(self, req: Request, logits, resume: bool) -> None:
         """Sample the first token of a fresh request and activate it."""
@@ -995,18 +918,12 @@ class InferenceEngine:
         ids = np.zeros((pad_b, s_w), np.int32)
         positions = np.zeros((pad_b, s_w), np.int32)
         drafts: List[List[int]] = []
-        if self._use_paged:
-            # ONE cache visit covers the whole batch: the block-table
-            # fetch already reports every row's committed length, so
-            # the per-row length() round-trips (a lock each) are free
-            tables, lengths = self.cache.block_tables_array(
-                [r.id for r in active], pad_batch=pad_b)
-            base_lens = lengths[:b].astype(np.int64)
-        else:
-            tables = None
-            lengths = None
-            base_lens = np.array(
-                [self.cache.length(r.id) for r in active], np.int64)
+        # ONE cache visit covers the whole batch: the block-table fetch
+        # already reports every row's committed length, so the per-row
+        # length() round-trips (a lock each) are free
+        tables, lengths = self.cache.block_tables_array(
+            [r.id for r in active], pad_batch=pad_b)
+        base_lens = lengths[:b].astype(np.int64)
         for i, req in enumerate(active):
             ids[i, 0] = req.generated[-1]
             d = self._draft_tokens(req) if s_w > 1 else []
@@ -1035,48 +952,24 @@ class InferenceEngine:
         # delivery and bookkeeping open
         telemetry.step_begin()
         with self._span("serving.decode.dispatch"):
-            if self._use_paged:
-                # fast path: NO dense gather, NO re-placement copy — the
-                # program reads and writes the device-resident pools in
-                # place through the block tables (a [B, W] int32 array
-                # is all that ships) and hands no K/V back
-                try:
-                    logits, pools, moe = self._on_pools(
-                        self._decode, self.params, ids, positions, tables,
-                        lengths, at=3)
-                except Exception:
-                    # the donated pools went with a call that failed
-                    # after dispatch: the loop's requeue re-prefills
-                    # into fresh ones
-                    self.cache.drop_lost_pools()
-                    raise
-                self.cache.adopt_device_pools(*pools)
-            else:
-                moe = ()
-                with compute.phase("gather"):
-                    k, v, lengths = self.cache.gather(
-                        [r.id for r in active], pad_batch=self.max_active)
-                    k, v = self.cache.shard_gathered(k, v)
-                if s_w > 1:
-                    logits, k_new, v_new = self._decode(
-                        self.params, ids, positions, k, v, lengths,
-                        self.cfg)
-                else:
-                    logits, k_new, v_new = self._decode(
-                        self.params, ids[:, 0], positions[:, 0], k, v,
-                        lengths, self.cfg)
+            # the program reads and writes the device-resident pools in
+            # place through the block tables (a [B, W] int32 array is
+            # all that ships) and hands no K/V back
+            try:
+                logits, pools, moe = self._on_pools(
+                    self._decode, self.params, ids, positions, tables,
+                    lengths, at=3)
+            except Exception:
+                # the donated pools went with a call that failed after
+                # dispatch: the loop's requeue re-prefills into fresh
+                # ones
+                self.cache.drop_lost_pools()
+                raise
+            self.cache.adopt_device_pools(*pools)
         with self._span("serving.decode.fetch") as crossed:
             logits = np.asarray(logits)
             crossed["bytes"] = logits.nbytes
             moe = [np.asarray(m) for m in moe]
-            if not self._use_paged:
-                k_new = np.asarray(k_new)
-                v_new = np.asarray(v_new)
-                crossed["bytes"] += k_new.nbytes + v_new.nbytes
-                if logits.ndim == 2:  # single-token program: [B, V]
-                    logits = logits[:, None]
-                    k_new = k_new[:, :, None]
-                    v_new = v_new[:, :, None]
         telemetry.inc("serving", "decode_d2h_bytes", crossed["bytes"])
         self._count_moe(moe)
         # per-sequence numeric health: a non-finite logit row (NaN/Inf
@@ -1137,20 +1030,14 @@ class InferenceEngine:
                     n_tokens += n_row
             # ONE batched cache visit covering every row's committed
             # prefix (contiguous by construction): per-row calls were
-            # dominated by lock/GIL crossings, not bytes moved.  The
-            # paged program already wrote the window's K/V at each
-            # row's length, so there the commit is the lengths alone
-            # (a rejected draft's slots stay garbage past the length).
-            # Must land before any _finish below — finishing frees
-            # blocks.
-            if self._use_paged:
-                self.cache.advance_many(
-                    [(req.id, n_row)
-                     for req, _, n_row, _, _ in outcomes if n_row])
-            else:
-                self.cache.write_many(
-                    [(req.id, k_new[:, i, :n_row], v_new[:, i, :n_row])
-                     for req, i, n_row, _, _ in outcomes if n_row])
+            # dominated by lock/GIL crossings.  The program already
+            # wrote the window's K/V at each row's length, so the
+            # commit is the lengths alone (a rejected draft's slots
+            # stay garbage past the length).  Must land before any
+            # _finish below — finishing frees blocks.
+            self.cache.advance_many(
+                [(req.id, n_row)
+                 for req, _, n_row, _, _ in outcomes if n_row])
             for req, i, n_row, fail, done in outcomes:
                 if n_row:
                     self.requests.on_token(req.id, n=n_row)
@@ -1205,10 +1092,7 @@ class InferenceEngine:
             telemetry.inc("serving", "tokens_generated", n_tokens)
         telemetry.inc("serving", "decode_steps")
         telemetry.observe("serving", "decode_batch", b)
-        telemetry.set_gauge("serving", "paged_active",
-                            1.0 if self._use_paged else 0.0)
-        if self._use_paged:
-            telemetry.inc("serving", "paged_decode_steps")
+        telemetry.inc("serving", "paged_decode_steps")
         if s_w > 1:
             telemetry.inc("serving", "spec_proposed", n_proposed)
             telemetry.inc("serving", "spec_accepted", n_accepted)

@@ -19,31 +19,17 @@ latent attention ONE pool of rows ``[rms(c_kv) | rope(k_pe)]`` with no
 head axis and no separate V, ``[n_layers, n_blocks, 576, block_size]``.
 The allocator, block tables, lengths and ``stats()`` do not care.
 
-The bookkeeping (allocator, block tables, lengths, ``stats()``) is
-shared; the bytes live in exactly ONE place, chosen at construction,
-and the two residences share no data-plane code:
-
-* *device* (``device_resident=True``, the engine's paged path): the
-  pools are device arrays and they ARE the cache.  Device programs
-  write them in place (``models.forward_prefill_paged`` scatters a
-  prompt's K/V into its blocks, ``forward_decode_paged`` the decode
-  window's; the engine DONATES the pools to both, so the arrays handed
-  in are deleted by the call) and the engine swaps in what they return
-  (:meth:`device_pools` / :meth:`adopt_device_pools`, and
-  :meth:`drop_lost_pools` after a call that failed with the pools in
-  its hands); the host only advances lengths
-  (:meth:`advance_many`) and ships the tiny int32
-  :meth:`block_tables_array` per step.  No K/V ever crosses the host
-  link and no numpy pool exists; ``write`` / ``gather`` refuse.
-* *host* (the default; the engine's gather path — the oracle twin and
-  the sharded-mesh route): numpy pools.  :meth:`write` /
-  :meth:`write_many` copy K/V in, :meth:`PagedKVCache.gather`
-  materializes a dense padded ``[L, B, T, H, D]`` view for a decode
-  batch (whole blocks are copied; slots past a sequence's length carry
-  garbage the attention mask ignores), and :meth:`shard_gathered`
-  places that view over a ``parallel.mesh`` — batch over ``dp``, heads
-  over ``tp`` — so the decode matmuls run sharded under jit.  No device
-  twin; ``device_pools`` refuses.
+The bytes live in ONE place: the pools are device arrays and they ARE
+the cache.  Device programs write them in place
+(``models.forward_prefill_paged`` scatters a prompt's K/V into its
+blocks, ``forward_decode_paged`` the decode window's; the engine
+DONATES the pools to both, so the arrays handed in are deleted by the
+call) and the engine swaps in what they return (:meth:`device_pools` /
+:meth:`adopt_device_pools`, and :meth:`drop_lost_pools` after a call
+that failed with the pools in its hands); the host only advances
+lengths (:meth:`advance_many`) and ships the tiny int32
+:meth:`block_tables_array` per step.  No K/V ever crosses the host
+link.
 
 Thread-safety: all bookkeeping is lock-protected, but the data plane
 assumes the engine's single step thread — the same contract as the
@@ -60,7 +46,7 @@ from ..base import DMLCError
 from .. import telemetry
 from ..concurrency import make_lock
 
-__all__ = ["BlockAllocator", "PagedKVCache", "kv_partition_spec"]
+__all__ = ["BlockAllocator", "PagedKVCache"]
 
 
 class BlockAllocator:
@@ -133,42 +119,20 @@ class _SeqEntry:
         self.length = 0
 
 
-def kv_partition_spec(mesh) -> Optional[tuple]:
-    """PartitionSpec for a gathered ``[L, B, T, H, D]`` view over
-    ``mesh``: batch over dp, heads over tp, everything else replicated.
-    None when the mesh offers no divisible sharding (single device)."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import AXIS_DP, AXIS_TP
-
-    dp = mesh.shape.get(AXIS_DP, 1)
-    tp = mesh.shape.get(AXIS_TP, 1)
-    if dp <= 1 and tp <= 1:
-        return None
-    return P(None, AXIS_DP if dp > 1 else None, None,
-             AXIS_TP if tp > 1 else None, None)
-
-
 class PagedKVCache:
     """Block-paged K/V storage for a set of live sequences.
 
     ``n_layers/n_heads/head_dim`` come from the model config, or
     ``pool_shapes`` does (one shape per pool; the default is K and V
-    pools of ``[n_layers, n_blocks, block_size, n_heads, head_dim]``;
-    anything else lives on the device only).
+    pools of ``[n_layers, n_blocks, block_size, n_heads, head_dim]``).
     ``n_blocks × block_size`` is the total token capacity shared by all
-    concurrent requests.  ``device_resident`` picks where the bytes
-    live (module docstring): device arrays that device programs write,
-    or numpy pools that ``write`` fills and ``gather`` reads.  ``mesh``
-    (optional, host residence) enables :meth:`shard_gathered` device
-    placement.
+    concurrent requests.  The bytes are device arrays that device
+    programs write (module docstring).
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int, *,
                  n_blocks: int = 256, block_size: int = 16,
-                 dtype=np.float32, mesh=None,
-                 device_resident: bool = False,
-                 pool_shapes: Optional[tuple] = None):
+                 dtype=np.float32, pool_shapes: Optional[tuple] = None):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.n_layers = int(n_layers)
@@ -176,28 +140,14 @@ class PagedKVCache:
         self.head_dim = int(head_dim)
         self.n_blocks = int(n_blocks)
         self.block_size = int(block_size)
-        self.mesh = mesh
         self.dtype = np.dtype(dtype)
-        self.device_resident = bool(device_resident)
         self.pool_shape = (self.n_layers, self.n_blocks, self.block_size,
                            self.n_heads, self.head_dim)
         self.pool_shapes = (self.pool_shape,) * 2 if pool_shapes is None \
             else tuple(tuple(int(d) for d in shape) for shape in pool_shapes)
-        if self.pool_shapes != (self.pool_shape,) * 2 \
-                and not self.device_resident:
-            raise ValueError(
-                f"pools {self.pool_shapes} are not K and V heads: only a "
-                "device-resident cache holds them (the paged path)")
-        # host residence: the numpy pools (None on a device-resident
-        # cache, which never holds K/V on the host)
-        host = not self.device_resident
-        # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self.k_pool = np.zeros(self.pool_shape, self.dtype) if host else None
-        # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
-        self.v_pool = np.zeros(self.pool_shape, self.dtype) if host else None
-        # device residence: the pools, made as zeros ON the device by
-        # the first device_pools() and replaced by whatever the last
-        # prefill / decode program returned
+        # the pools, made as zeros ON the device by the first
+        # device_pools() and replaced by whatever the last prefill /
+        # decode program returned
         # dmlc-check: unguarded(data plane is single-step-thread by contract — class docstring)
         self._dev: Optional[tuple] = None
         # block-table memo: the tables themselves change only when some
@@ -337,7 +287,7 @@ class PagedKVCache:
             raise DMLCError(f"unknown sequence {seq_id}")
         return ent
 
-    # ---- lengths (both residences) --------------------------------------
+    # ---- lengths --------------------------------------------------------
     def _grow_to(self, seq_id: int, ent: _SeqEntry, end: int) -> None:
         """Lock held: the sequence now holds tokens up to ``end``.
         Capacity must already be reserved (allocate/extend); growing
@@ -351,64 +301,12 @@ class PagedKVCache:
             self._cached_tokens += end - ent.length
             ent.length = end
 
-    def _residence(self, device: bool, what: str) -> None:
-        if self.device_resident != device:
-            raise DMLCError(
-                f"{what} is for a {'device' if device else 'host'}-resident "
-                f"cache; this one keeps its K/V on the "
-                f"{'device' if self.device_resident else 'host'}")
-
-    # ---- data plane, host residence -------------------------------------
-    def write(self, seq_id: int, k, v, start: Optional[int] = None) -> None:
-        """Write ``k/v [L, T, H, D]`` at token offset ``start`` (default:
-        the current length — append semantics)."""
-        self.write_many([(seq_id, k, v)], start=start)
-
-    def write_many(self, updates, *, start: Optional[int] = None) -> None:
-        """Batched :meth:`write`: ``updates`` is ``[(seq_id, k, v), ...]``
-        with each ``k/v [L, T, H, D]`` written at ``start`` (default:
-        appended at that sequence's current length).
-
-        One lock acquisition covers the whole batch.  The per-row
-        ``write`` calls on the decode commit path were dominated not by
-        bytes moved but by lock/GIL handoffs — with a pool of HTTP
-        handler threads live, every release is a chance to lose the GIL
-        for a scheduler quantum, and the commit walk made one such
-        crossing per row per step."""
-        self._residence(False, "write")
-        plans = []
-        with self._lock:
-            for seq_id, k, v in updates:
-                k = np.asarray(k)
-                v = np.asarray(v)
-                ent = self._seq(seq_id)
-                pos = ent.length if start is None else int(start)
-                self._grow_to(seq_id, ent, pos + k.shape[1])
-                plans.append((list(ent.blocks), pos, k, v))
-        bs = self.block_size
-        for blocks, pos, k, v in plans:
-            t = k.shape[1]
-            off = 0
-            while off < t:
-                p = pos + off
-                blk = blocks[p // bs]
-                slot = p % bs
-                n = min(bs - slot, t - off)
-                self.k_pool[:, blk, slot:slot + n] = k[:, off:off + n]
-                self.v_pool[:, blk, slot:slot + n] = v[:, off:off + n]
-                off += n
-
-    def append(self, seq_id: int, k, v) -> None:
-        """Append ONE token's ``k/v [L, H, D]``."""
-        self.write(seq_id, np.asarray(k)[:, None], np.asarray(v)[:, None])
-
-    # ---- data plane, device residence -----------------------------------
+    # ---- data plane ------------------------------------------------------
     def device_pools(self) -> tuple:
         """The device arrays that are the cache itself, one per entry
         of ``pool_shapes``: ``(k_pool, v_pool)``, or the one latent pool.
         Made as zeros on the device at first use (nothing is uploaded);
         afterwards whatever :meth:`adopt_device_pools` installed last."""
-        self._residence(True, "device_pools")
         if self._dev is None:
             import jax.numpy as jnp
 
@@ -419,7 +317,6 @@ class PagedKVCache:
     def adopt_device_pools(self, *pools) -> None:
         """Install the pools a prefill or decode program returned (its
         in-program scatter made them the cache)."""
-        self._residence(True, "adopt_device_pools")
         assert len(pools) == len(self.pool_shapes), len(pools)
         self._dev = tuple(pools)
 
@@ -432,17 +329,15 @@ class PagedKVCache:
         :meth:`device_pools` starts from zeros) and returns True so the
         caller can recompute the live sequences; False when the pools
         are intact."""
-        self._residence(True, "drop_lost_pools")
         if self._dev is None or not any(p.is_deleted() for p in self._dev):
             return False
         self._dev = None
         return True
 
     def advance_many(self, updates) -> None:
-        """The length-only twin of :meth:`write_many`: ``updates`` is
-        ``[(seq_id, n_tokens), ...]``, tokens whose K/V a device program
-        already wrote at each sequence's current length."""
-        self._residence(True, "advance_many")
+        """``updates`` is ``[(seq_id, n_tokens), ...]``: tokens whose K/V
+        a device program already wrote at each sequence's current
+        length, under ONE lock acquisition for the batch."""
         with self._lock:
             for seq_id, n in updates:
                 ent = self._seq(seq_id)
@@ -459,8 +354,8 @@ class PagedKVCache:
         or the max owned-block count (min 1), ``B`` = ``pad_batch`` or
         ``len(seq_ids)``.  Rows are padded with block 0 — the attention
         mask keeps padded entries unreachable (positions past
-        ``lengths``), and dead rows carry length 0.  Like gather's
-        ``pad_len``, an insufficient explicit ``pad_width`` is loud.
+        ``lengths``), and dead rows carry length 0.  An insufficient
+        explicit ``pad_width`` is loud: it pins the jit shape.
 
         The tables array is memoized on (seq_ids, padding, allocator
         version): block OWNERSHIP changes only every ~block_size
@@ -496,70 +391,6 @@ class PagedKVCache:
             if version == self._tables_version:
                 self._tables_cache = (key, version, out)
         return out, lengths
-
-    def gather(self, seq_ids: Sequence[int], *, pad_len: Optional[int] = None,
-               pad_batch: Optional[int] = None
-               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Dense padded view for a decode batch.
-
-        Returns ``(k, v, lengths)`` with k/v ``[L, B, T, H, D]`` and
-        lengths ``[B] int32``; ``T`` = ``pad_len`` or the max sequence
-        length rounded up to a whole block, ``B`` = ``pad_batch`` or
-        ``len(seq_ids)`` (extra rows are zero with length 0 — dead rows
-        the decode mask ignores, used to pin the jit batch shape).
-        Whole blocks are copied, so slots in [length, T) are garbage by
-        contract."""
-        self._residence(False, "gather")
-        with self._lock:
-            ents = [self._seq(s) for s in seq_ids]
-            tables = [list(e.blocks) for e in ents]
-            lens = [e.length for e in ents]
-        bs = self.block_size
-        max_len = max(lens, default=0)
-        need = max(self.blocks_for(max_len) * bs, bs)
-        if pad_len is not None:
-            # an explicit pad_len pins the jit shape; widening it
-            # silently would defeat that, so insufficiency is loud
-            if pad_len % bs:
-                raise ValueError(f"pad_len {pad_len} not a multiple of "
-                                 f"block_size {bs}")
-            if pad_len < need:
-                raise ValueError(f"pad_len {pad_len} < required {need}")
-            t = pad_len
-        else:
-            t = need
-        b = max(pad_batch or 0, len(seq_ids))
-        shape = (self.n_layers, b, t, self.n_heads, self.head_dim)
-        k_out = np.zeros(shape, self.dtype)
-        v_out = np.zeros(shape, self.dtype)
-        for i, (table, n) in enumerate(zip(tables, lens)):
-            for j in range(self.blocks_for(n)):
-                blk = table[j]
-                k_out[:, i, j * bs:(j + 1) * bs] = self.k_pool[:, blk]
-                v_out[:, i, j * bs:(j + 1) * bs] = self.v_pool[:, blk]
-        lengths = np.zeros(b, np.int32)
-        lengths[:len(lens)] = lens
-        return k_out, v_out, lengths
-
-    def shard_gathered(self, k: np.ndarray, v: np.ndarray):
-        """Place a gathered view over the mesh (batch→dp, heads→tp) so
-        decode runs as a sharded jit program.  Falls back to plain
-        host→default-device arrays when no mesh was given or the shapes
-        do not divide the axes."""
-        if self.mesh is None:
-            return k, v
-        import jax
-
-        spec = kv_partition_spec(self.mesh)
-        if spec is None:
-            return k, v
-        from ..parallel.mesh import AXIS_DP, AXIS_TP
-
-        if (k.shape[1] % max(self.mesh.shape.get(AXIS_DP, 1), 1)
-                or k.shape[3] % max(self.mesh.shape.get(AXIS_TP, 1), 1)):
-            return k, v
-        sh = jax.sharding.NamedSharding(self.mesh, spec)
-        return jax.device_put(k, sh), jax.device_put(v, sh)
 
     # ---- observability --------------------------------------------------
     def stats(self) -> Dict[str, float]:

@@ -309,8 +309,8 @@ class _ProfiledJit:
         """Take the site in the registry: after a test-time
         :func:`reset_compute` orphaned a long-lived wrapper (the
         serving engine caches its jitted programs process-wide), or
-        from another wrapper of the same site (the engine's paged and
-        gather prefill programs share ``serving.prefill``; the engine
+        from another wrapper of the same site (the engine's MHA and
+        latent prefill programs share ``serving.prefill``; the engine
         built last runs one of them, and that one is the site)."""
         with _lock:
             _sites[self.site] = self

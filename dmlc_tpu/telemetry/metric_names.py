@@ -206,9 +206,8 @@ METRIC_NAMES = frozenset({
     # and the decode jit-signature population)
     "dmlc_serving_prompt_bucket_new",
     "dmlc_serving_decode_signatures",
-    # decode fast path — paged attention (pool read in place, no dense
-    # gather) and speculative decoding (n-gram drafts, exact verify)
-    "dmlc_serving_paged_active",
+    # decode — paged attention (pool read in place) and speculative
+    # decoding (n-gram drafts, exact verify)
     "dmlc_serving_paged_decode_steps",
     "dmlc_serving_spec_proposed",
     "dmlc_serving_spec_accepted",

@@ -307,7 +307,6 @@ def main():
                 "dmlc_step_membw_util_pct",
                 # PR 19 families: paged decode fast path + multi-token
                 # step accounting
-                "dmlc_serving_paged_active",
                 "dmlc_serving_paged_decode_steps",
                 "dmlc_step_tokens_per_step"):
         assert fam in text, f"{fam} missing from /metrics"
@@ -331,13 +330,7 @@ def main():
     comp = json.loads(urllib.request.urlopen(
         server.url + "/compute", timeout=30).read())
     assert comp["enabled"], "/compute reports the profile disabled"
-    # each decode program variant profiles under its own site name; on
-    # CPU the engine defaults to the paged fast path (PR 19)
-    decode_site = ("serving.decode_paged" if engine._use_paged
-                   else "serving.decode")
-    assert engine._use_paged, (
-        "smoke expects the paged decode fast path by default on CPU")
-    for site in ("serving.prefill", decode_site):
+    for site in ("serving.prefill", "serving.decode_paged"):
         st = comp["sites"].get(site)
         assert st and st["traces"] >= 1, f"/compute missing site {site}"
         assert st["hits"] > 0, f"{site}: no jit cache hits recorded"
@@ -488,17 +481,16 @@ def _run_engine_outputs(params, cfg, env, prompts, n_new):
 
 
 def decode_fast_path_phase(params, cfg):
-    """Paged attention + speculative decoding vs the dense-gather
-    control (PR 19).
+    """Speculative decoding against a control without it (PR 19).
 
-    Two fresh engines serve the SAME prompts greedily: a control
-    pinned to the legacy gather path (paged off, no drafting) and the
-    fast engine on the paged program with n-gram speculative decoding
-    (k=4).  The acceptance contract: BYTE-IDENTICAL outputs
-    (speculation may only change how many tokens land per step, never
-    which), > 1 committed token per batch row per step on the looping
-    outputs the drafter feeds on, a non-zero draft acceptance rate,
-    and ZERO recompiles after the fast engine's own warmup."""
+    Two fresh engines serve the SAME prompts greedily: a control that
+    drafts nothing (``DMLC_SERVE_SPEC_K=0``) and the fast engine with
+    n-gram speculative decoding (k=4).  The acceptance contract:
+    BYTE-IDENTICAL outputs (speculation may only change how many tokens
+    land per step, never which), > 1 committed token per batch row per
+    step on the looping outputs the drafter feeds on, a non-zero draft
+    acceptance rate, and ZERO recompiles after the fast engine's own
+    warmup."""
     from dmlc_tpu import telemetry
     from dmlc_tpu.telemetry.exporters import validate_exposition_text
 
@@ -510,16 +502,12 @@ def decode_fast_path_phase(params, cfg):
     n_new = 24
 
     control, _, _ = _run_engine_outputs(
-        params, cfg,
-        {"DMLC_SERVE_PAGED_ATTN": "off", "DMLC_SERVE_SPEC_K": "0"},
-        prompts, n_new)
+        params, cfg, {"DMLC_SERVE_SPEC_K": "0"}, prompts, n_new)
     fast, steady_recompiles, ledger = _run_engine_outputs(
-        params, cfg,
-        {"DMLC_SERVE_PAGED_ATTN": "on", "DMLC_SERVE_SPEC_K": "4"},
-        prompts, n_new)
+        params, cfg, {"DMLC_SERVE_SPEC_K": "4"}, prompts, n_new)
 
     assert fast == control, (
-        "fast-path output diverged from the gather control:\n"
+        "speculative output diverged from the control without it:\n"
         f"  control: {control}\n  fast:    {fast}")
     assert steady_recompiles == 0, (
         f"fast path recompiled {steady_recompiles}x after its warmup")

@@ -1,23 +1,20 @@
-"""Decode fast path: paged-vs-gather parity, spec-decode bit-parity.
+"""Paged decode: parity with dense attention, spec-decode bit-parity.
 
-The op-level matrix checks the paged attention op against the model's
-dense gather-path window attention on identical cache state — the
-1e-5 logits-parity contract, swept where a length matrix is cheapest.
-The engine-level tests pin the end-to-end contract instead: greedy
-outputs bit-identical with the fast path on, off, and with speculative
-decoding enabled, each through a forced preemption episode (the
-resume path is where a paged/spec bookkeeping bug would corrupt
-output).  The cache tests guard its two residences: host (the gather
-path: freed blocks' bytes never reach a live gather row, and the
-batched commit write is byte-equivalent to the per-row writes it
-replaced) and device (the paged path: prefill writes a sequence's
-blocks inside the program and nothing else, each residence refuses
-the other's data plane, and a prefill that fails fails alone unless it
-took the donated pools with it).  The decode program owns its pools
-for the length of a call: donated at the engine's jit site, never
-sliced by layer, dead rows harmless in every layer, and a call that
-fails with the pools in its hands is an iteration crash the loop
-recovers from.
+The op-level matrix checks the paged attention op against dense
+softmax attention written out in numpy on identical cache state — the
+1e-5 parity contract, swept where a length matrix is cheapest.  The
+engine-level tests pin the end-to-end contract instead: greedy outputs
+bit-identical to the no-cache oracle, with and without speculative
+decoding, each through a forced preemption episode (the resume path is
+where a paged/spec bookkeeping bug would corrupt output).  The cache
+tests guard its one residence, the device pools: freed blocks' bytes
+never reach a live row, prefill writes a sequence's blocks inside the
+program and nothing else, the constructors take no other residence,
+and a prefill that fails fails alone unless it took the donated pools
+with it.  The decode program owns its pools for the length of a call:
+donated at the engine's jit site, never sliced by layer, dead rows
+harmless in every layer, and a call that fails with the pools in its
+hands is an iteration crash the loop recovers from.
 """
 
 import json
@@ -31,26 +28,42 @@ from dmlc_tpu.base import DMLCError
 from dmlc_tpu.ops.paged_attention import paged_attention, supports
 from dmlc_tpu.serving import (InferenceEngine, PagedKVCache, Request,
                               ServingHTTPServer)
+from kv_rows import kv_get, kv_put
 
 
 # ---------------------------------------------------------------------------
-# op-level parity matrix: paged vs gather window attention
+# op-level parity matrix: paged vs dense window attention
 # ---------------------------------------------------------------------------
 
 def _rand(rng, *shape):
     return rng.standard_normal(shape).astype(np.float32)
 
 
+def _dense_window_attention(q, k_new, v_new, k_cache, v_cache, lengths):
+    """Softmax attention in numpy, independent of the package: window
+    token s of row b (q / k_new / v_new [B, S, H, D]) sees cache slots
+    j < lengths[b] of k_cache / v_cache [B, Tc, H, D] and window tokens
+    <= s, which ride as a tail behind the cache."""
+    tc, s_w, d = k_cache.shape[1], q.shape[1], q.shape[-1]
+    k_all = np.concatenate([k_cache, k_new], axis=1)
+    v_all = np.concatenate([v_cache, v_new], axis=1)
+    scores = np.einsum("bqhd,bkhd->bhqk", q, k_all) / np.sqrt(d)
+    idx = np.arange(tc + s_w)
+    valid = (idx[None, None] < lengths[:, None, None]) | (
+        (idx >= tc) & (idx - tc <= np.arange(s_w)[:, None]))[None]
+    scores = np.where(valid[:, None], scores, -np.inf)
+    p = np.exp(scores - scores.max(axis=-1, keepdims=True))
+    return np.einsum("bhqk,bkhd->bqhd", p / p.sum(axis=-1, keepdims=True),
+                     v_all)
+
+
 def _parity_case(rng, *, n_blocks, bs, w, h, d, s_w, lengths):
-    """Build one batch of paged state plus its dense gather-path view.
+    """Build one batch of paged state plus its dense view.
 
     Returns ``(paged_out, dense_out)`` for the same queries: the paged
-    op attends the scattered pool through block tables; the dense path
-    is the model's ``_cached_window_attention`` over the gathered view
-    with the window riding as a concatenated tail (exactly how the
-    gather decode program sees it)."""
-    from dmlc_tpu.models.transformer import _cached_window_attention
-
+    op attends the scattered pool through block tables; the dense
+    oracle sees the rows gathered through the same tables, with the
+    window riding as a concatenated tail."""
     b = len(lengths)
     lengths = np.asarray(lengths, np.int32)
     span = w * bs
@@ -63,7 +76,7 @@ def _parity_case(rng, *, n_blocks, bs, w, h, d, s_w, lengths):
     q = _rand(rng, b, s_w, h, d)
     k_new = _rand(rng, b, s_w, h, d)
     v_new = _rand(rng, b, s_w, h, d)
-    # paged path: scatter-then-attend at each row's real paged address
+    # paged: scatter-then-attend at each row's real paged address
     kp, vp = k_pool.copy(), v_pool.copy()
     for i in range(b):
         for s in range(s_w):
@@ -72,19 +85,19 @@ def _parity_case(rng, *, n_blocks, bs, w, h, d, s_w, lengths):
             vp[tables[i, p // bs], p % bs] = v_new[i, s]
     paged = np.asarray(paged_attention(q, kp, vp, tables, lengths,
                                        impl="lax"))
-    # gather path: the PRE-scatter pool is the cache (positions >=
-    # length are garbage the mask hides), window as explicit tail
+    # dense: the PRE-scatter pool is the cache (positions >= length
+    # are garbage the mask hides), window as explicit tail
     k_cache = k_pool[tables].reshape(b, span, h, d)
     v_cache = v_pool[tables].reshape(b, span, h, d)
-    dense = np.asarray(_cached_window_attention(q, k_new, v_new,
-                                                k_cache, v_cache, lengths))
+    dense = _dense_window_attention(q, k_new, v_new, k_cache, v_cache,
+                                    lengths)
     return paged, dense
 
 
 @pytest.mark.parametrize("s_w", [1, 3])
 def test_paged_vs_gather_parity_matrix(s_w):
     """Single-block, boundary-straddling, and max-length rows in one
-    batch: the paged op matches the gather-path oracle to 1e-5."""
+    batch: the paged op matches the dense oracle to 1e-5."""
     bs, w = 4, 4
     span = w * bs
     lengths = [1, bs - 1, bs, bs + 1, 2 * bs + 1, span - s_w]
@@ -134,7 +147,7 @@ def test_paged_attention_rejects_unknown_impl():
 
 
 # ---------------------------------------------------------------------------
-# the host residence (gather path): freed bytes, batched writes
+# the one residence: device pools
 # ---------------------------------------------------------------------------
 
 def _kv(rng, n, *, layers=2, heads=2, dim=3):
@@ -143,11 +156,11 @@ def _kv(rng, n, *, layers=2, heads=2, dim=3):
             rng.standard_normal(shape).astype(np.float32))
 
 
-def test_gather_never_reads_freed_blocks_bytes():
-    """Property: under interleaved alloc/free churn, a live row's valid
-    prefix never contains a freed block's bytes.  Every free block is
-    poisoned with a sentinel each iteration; any table/gather indexing
-    bug that routed a live row through a freed block would surface it."""
+def test_live_rows_never_read_freed_blocks_bytes():
+    """Property: under interleaved alloc/free churn, a live sequence's
+    pages never hold a freed block's bytes.  Every free block is
+    poisoned with a sentinel each iteration; any table indexing bug
+    that routed a live row through a freed block would surface it."""
     sent = np.float32(12345.0)
     cache = PagedKVCache(2, 2, 3, n_blocks=12, block_size=4)
     rng = np.random.default_rng(11)
@@ -162,96 +175,75 @@ def test_gather_never_reads_freed_blocks_bytes():
             n = int(rng.integers(1, 13))
             if cache.allocate(sid, n):
                 k, v = _kv(rng, n)
-                cache.write(sid, k, v)
+                kv_put(cache, sid, k, v)
                 live[sid] = (n, k, v)
         used = set()
         for s in live:
             used.update(cache.block_table(s))
-        for blk in set(range(12)) - used:
-            cache.k_pool[:, blk] = sent
-            cache.v_pool[:, blk] = sent
+        freed = np.array(sorted(set(range(12)) - used), np.int32)
+        cache.adopt_device_pools(*(
+            p.at[:, freed].set(sent) for p in cache.device_pools()))
         if not live:
             continue
         ids = sorted(live)
-        pad_len = -(-max(live[s][0] for s in ids) // 4) * 4
-        gk, gv, lens = cache.gather(ids, pad_batch=len(ids) + 2,
-                                    pad_len=pad_len)
+        gk, gv, lens = kv_get(cache, ids, pad_batch=len(ids) + 2)
         for row, s in enumerate(ids):
             n, k, v = live[s]
             assert lens[row] == n
             np.testing.assert_array_equal(gk[:, row, :n], k)
             np.testing.assert_array_equal(gv[:, row, :n], v)
-        # dead pad rows are zero-filled, never a freed block's bytes
-        assert not gk[:, len(ids):].any()
-        assert not gv[:, len(ids):].any()
+        # dead pad rows sit behind length 0
+        assert not lens[len(ids):].any()
 
 
-def test_write_many_matches_per_row_writes():
-    """The batched commit write (one lock for the whole batch) is
-    byte- and bookkeeping-equivalent to per-row appends, including a
-    window that straddles a block boundary."""
-    a = PagedKVCache(2, 2, 3, n_blocks=8, block_size=4)
-    b = PagedKVCache(2, 2, 3, n_blocks=8, block_size=4)
-    rng = np.random.default_rng(5)
-    prefixes = {1: 3, 2: 5}           # 3+2 straddles a block boundary
-    windows = {1: 2, 2: 3}
-    init = {s: _kv(np.random.default_rng(s), n)
-            for s, n in prefixes.items()}
-    for cache in (a, b):
-        for s, n in prefixes.items():
-            assert cache.allocate(s, n + windows[s])
-            cache.write(s, *init[s])
-    upd = {s: _kv(rng, n) for s, n in windows.items()}
-    for s in prefixes:
-        a.write(s, *upd[s])           # append semantics (start=None)
-    b.write_many([(s, k, v) for s, (k, v) in upd.items()])
-    np.testing.assert_array_equal(a.k_pool, b.k_pool)
-    np.testing.assert_array_equal(a.v_pool, b.v_pool)
-    for s, n in prefixes.items():
-        assert a.length(s) == b.length(s) == n + windows[s]
-    assert a.stats() == b.stats()
-    # empty batch is a no-op; over-reservation still raises
-    b.write_many([])
-    k_big, v_big = _kv(rng, 32)
-    with pytest.raises(DMLCError):
-        b.write_many([(1, k_big, v_big)])
+def test_the_cache_keeps_its_kv_in_device_pools():
+    """The one residence's contract: pools made as zeros on the device
+    at first use, lengths moved by advance_many alone and never past
+    the reservation, lost pools forgotten and made anew."""
+    import jax
 
-
-# ---------------------------------------------------------------------------
-# the device residence (paged path)
-# ---------------------------------------------------------------------------
-
-def test_each_residence_refuses_the_others_data_plane():
-    k, v = _kv(np.random.default_rng(0), 3)
-    dev = PagedKVCache(2, 2, 3, n_blocks=4, block_size=4,
-                       device_resident=True)
-    assert dev.k_pool is None and dev.v_pool is None  # no numpy pool
-    assert dev.allocate(1, 3)
-    for call in (lambda: dev.write(1, k, v),
-                 lambda: dev.write_many([(1, k, v)]),
-                 lambda: dev.append(1, k[:, 0], v[:, 0]),
-                 lambda: dev.gather([1])):
-        with pytest.raises(DMLCError, match="host-resident"):
-            call()
-    assert dev.length(1) == 0  # a refused write moved no length
-    k_pool, v_pool = dev.device_pools()
+    cache = PagedKVCache(2, 2, 3, n_blocks=4, block_size=4)
+    assert cache.allocate(1, 3)
+    k_pool, v_pool = cache.device_pools()
+    assert isinstance(k_pool, jax.Array) and isinstance(v_pool, jax.Array)
     assert k_pool.shape == v_pool.shape == (2, 4, 4, 2, 3)
     assert not np.asarray(k_pool).any()  # made as zeros, on the device
-    dev.advance_many([(1, 3)])
-    assert dev.length(1) == 3 and dev.stats()["cached_tokens"] == 3
+    assert cache.device_pools()[0] is k_pool  # and kept
+    assert cache.length(1) == 0
+    cache.advance_many([(1, 3)])
+    assert cache.length(1) == 3 and cache.stats()["cached_tokens"] == 3
     with pytest.raises(DMLCError, match="past reservation"):
-        dev.advance_many([(1, 2)])  # 5 tokens in a 1-block reservation
+        cache.advance_many([(1, 2)])  # 5 tokens in a 1-block reservation
+    assert cache.length(1) == 3
+    assert not cache.drop_lost_pools()  # intact pools are kept
+    k_pool.delete()
+    assert cache.drop_lost_pools()
+    assert not any(p.is_deleted() for p in cache.device_pools())
 
-    host = PagedKVCache(2, 2, 3, n_blocks=4, block_size=4)
-    assert host.allocate(1, 3)
-    for call in (host.device_pools,
-                 lambda: host.adopt_device_pools(k_pool, v_pool),
-                 lambda: host.advance_many([(1, 3)]),
-                 host.drop_lost_pools):
-        with pytest.raises(DMLCError, match="device-resident"):
-            call()
-    host.write(1, k, v)
-    assert host.length(1) == 3
+
+@pytest.mark.parametrize("build", [InferenceEngine, PagedKVCache])
+def test_serving_constructors_choose_no_residence(build):
+    """Where the cache's bytes live is not a parameter: no keyword
+    names a residence or a mesh to shard a host-side view over, and
+    one passed anyway is refused."""
+    import inspect
+
+    names = inspect.signature(build.__init__).parameters
+    assert not [n for n in names if "mesh" in n or "resident" in n]
+    args = _tiny_model() if build is InferenceEngine else (2, 2, 3)
+    with pytest.raises(TypeError, match="mesh"):
+        build(*args, n_blocks=4, block_size=4, mesh=None)
+
+
+def test_knob_count_only_goes_down():
+    """ROADMAP C3.  The knob that chose between two serving data paths
+    went with the second path: a PR that adds a knob argues with this
+    line."""
+    from dmlc_tpu.config_registry import KNOBS
+
+    assert not [k.name for k in KNOBS if "PAGED" in k.name]
+    assert len(KNOBS) <= 164
+    assert sum(k.name.startswith("DMLC_SERVE_") for k in KNOBS) <= 18
 
 
 @pytest.mark.parametrize("n", [8, 6])  # n % block_size == 0 and != 0
@@ -301,7 +293,6 @@ def test_failed_prefill_fails_alone_unless_it_took_the_pools(pools_lost):
     params, cfg = _tiny_model()
     eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
                           max_active=2, queue_depth=4)
-    assert eng._use_paged
     real = eng._prefill
     poison = 63
 
@@ -363,7 +354,6 @@ def test_engine_decode_program_donates_both_pools():
     params, cfg = _tiny_model()
     eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
                           max_active=2, queue_depth=4)
-    assert eng._use_paged
     returned = []
     real = eng._decode
 
@@ -479,7 +469,6 @@ def test_failed_decode_that_took_the_pools_is_recovered():
     params, cfg = _tiny_model()
     eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
                           max_active=2, queue_depth=4)
-    assert eng._use_paged
     real = eng._decode
     calls = {"n": 0, "lost": None}
 
@@ -553,10 +542,6 @@ def _run_requests(params, cfg, *, n_blocks=6, max_new=10):
     preemption + recompute-resume.  Returns their outputs."""
     eng = InferenceEngine(params, cfg, n_blocks=n_blocks, block_size=4,
                           max_active=3, queue_depth=8)
-    # the paged path keeps no K/V on the host, the gather path none on
-    # the device
-    assert (eng.cache.k_pool is None) == eng._use_paged
-    assert eng.cache.device_resident == eng._use_paged
     eng.start()
     try:
         reqs = [eng.submit(_prompt(i), max_new_tokens=max_new)
@@ -570,34 +555,28 @@ def _run_requests(params, cfg, *, n_blocks=6, max_new=10):
         eng.close()
 
 
-def test_paged_on_off_bit_identical_through_preemption(monkeypatch):
-    """DMLC_SERVE_PAGED_ATTN=on vs =off produce bit-identical greedy
-    output across a preemption episode, and both match the no-cache
-    oracle — the fast path is output-invisible end to end."""
+def test_paged_decode_bit_identical_to_oracle_through_preemption():
+    """Greedy output across a preemption episode matches the no-cache
+    oracle bit for bit — the paged cache is output-invisible end to
+    end."""
     params, cfg = _tiny_model()
     before = telemetry.snapshot()["counters"].get(
         "serving", {}).get("preemptions", 0)
-    outs = {}
-    for mode in ("on", "off"):
-        monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", mode)
-        outs[mode] = _run_requests(params, cfg)
+    outs = _run_requests(params, cfg)
     after = telemetry.snapshot()["counters"]["serving"]["preemptions"]
     assert after > before, "tiny pool must have forced preemption"
-    assert outs["on"] == outs["off"]
     for i in range(3):
-        assert outs["on"][i] == _greedy_oracle(params, cfg, _prompt(i), 10)
+        assert outs[i] == _greedy_oracle(params, cfg, _prompt(i), 10)
 
 
-@pytest.mark.parametrize("paged", ["on", "off"])
-def test_spec_decode_bit_parity_through_preemption(monkeypatch, paged):
+def test_spec_decode_bit_parity_through_preemption(monkeypatch):
     """Speculative decoding (k=3) through the same preemption-forcing
-    pool, on the paged path (the commit advances each length by the
-    accepted count; rejected window slots stay garbage in the device
-    pool) and on the gather path: greedy output stays bit-identical to
-    the oracle, and the drafter actually proposed (the accept walk, not
-    drafter silence, is what kept the output exact)."""
+    pool (the commit advances each length by the accepted count;
+    rejected window slots stay garbage in the device pool): greedy
+    output stays bit-identical to the oracle, and the drafter actually
+    proposed (the accept walk, not drafter silence, is what kept the
+    output exact)."""
     params, cfg = _tiny_model()
-    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", paged)
     monkeypatch.setenv("DMLC_SERVE_SPEC_K", "3")
     monkeypatch.setenv("DMLC_SERVE_SPEC_MIN_CTX", "4")
     snap = telemetry.snapshot()["counters"].get("serving", {})
@@ -636,8 +615,7 @@ def test_ngram_drafter_proposes_from_own_context(monkeypatch):
 def test_fast_path_metric_families_registered():
     from dmlc_tpu.telemetry.metric_names import METRIC_NAMES
 
-    for fam in ("dmlc_serving_paged_active",
-                "dmlc_serving_paged_decode_steps",
+    for fam in ("dmlc_serving_paged_decode_steps",
                 "dmlc_serving_spec_proposed",
                 "dmlc_serving_spec_accepted",
                 "dmlc_serving_spec_accept_rate",

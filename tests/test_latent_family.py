@@ -312,13 +312,6 @@ def test_the_same_request_twice_through_the_engine():
     assert grew["moe_expert_load_max"] >= grew["moe_expert_load_mean"] > 0
 
 
-def test_a_latent_model_takes_the_paged_path_only(monkeypatch):
-    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", "off")
-    cfg = small()
-    with pytest.raises(ValueError, match="paged path"):
-        InferenceEngine(weights(cfg), cfg, n_blocks=4, block_size=BS)
-
-
 def test_the_reference_control_moves_the_logits(model):
     cfg, params, ids, want = model
     low = np.asarray(ref.logits_at(params, ids[0], np.arange(32),

@@ -256,41 +256,57 @@ def test_prefill_matches_training_forward():
                                rtol=1e-6)
 
 
-def test_decode_step_matches_full_forward():
-    """The satellite contract: single-token decode against an external
-    KV cache reproduces the full-sequence forward's logits position by
-    position — including when the cache view is padded with garbage
-    past each sequence's true length."""
-    from dmlc_tpu.models import forward_decode, forward_prefill
+@pytest.mark.parametrize("n0", [4, 6])  # on a block boundary, and off
+@pytest.mark.parametrize("window", [1, 3])
+def test_decode_step_matches_full_forward(window, n0):
+    """The satellite contract: a decode window against the paged pools
+    reproduces the full-sequence forward's logits position by position
+    with every slot it was not given pre-filled with garbage, and the
+    step writes the window's K/V rows into the pools, nothing else."""
+    from dmlc_tpu.models import forward_prefill
+    from dmlc_tpu.models.transformer import forward_decode_paged
 
     params = init_params(jax.random.PRNGKey(0), CFG, n_stages=2)
-    t_total, n0, pad = 10, 4, 16
+    bs, n_blocks, t_total = 4, 8, n0 + 6
     ids, _ = _data(jax.random.PRNGKey(4), b=2, t=t_total)
     logits_full, k_full, v_full = forward_prefill(params, ids, CFG)
+    k_full, v_full = np.asarray(k_full), np.asarray(v_full)
 
-    shape = (CFG.n_layers, 2, pad, CFG.n_heads, CFG.head_dim)
-    # garbage sentinel past the valid region: the length mask must make
-    # these slots invisible, so parity proves masking, not luck
-    k_cache = np.full(shape, 7.7, np.float32)
-    v_cache = np.full(shape, -7.7, np.float32)
-    _, k0, v0 = forward_prefill(params, ids[:, :n0], CFG)
-    k_cache[:, :, :n0] = np.asarray(k0)
-    v_cache[:, :, :n0] = np.asarray(v0)
-    for pos in range(n0, t_total):
-        lengths = np.full(2, pos, np.int32)
-        positions = np.full(2, pos, np.int32)
-        lg, kn, vn = forward_decode(
-            params, np.asarray(ids[:, pos], np.int32), positions,
-            k_cache, v_cache, lengths, CFG)
+    shape = (CFG.n_layers, n_blocks, bs, CFG.n_heads, CFG.head_dim)
+    # garbage sentinel in every slot past the valid region: the length
+    # mask must make them invisible, so parity proves masking, not luck
+    k_pool = np.full(shape, 7.7, np.float32)
+    v_pool = np.full(shape, -7.7, np.float32)
+    tables = np.array([[5, 2, 7], [1, 6, 3]], np.int32)  # 12 tokens a row
+    rows = np.arange(2)[:, None]
+
+    def at(first, n):
+        """``[:, block, slot]`` of both rows' tokens first .. first+n-1."""
+        pos = first + np.arange(n)
+        return np.s_[:, tables[rows, pos // bs], pos % bs]
+
+    # the prefix, written through the block tables ([L, B, T, H, D])
+    k_pool[at(0, n0)] = k_full[:, :, :n0]
+    v_pool[at(0, n0)] = v_full[:, :, :n0]
+    for pos in range(n0, t_total, window):
+        win = slice(pos, pos + window)
+        lg, k_new, v_new = forward_decode_paged(
+            params, np.asarray(ids[:, win], np.int32),
+            np.tile(pos + np.arange(window, dtype=np.int32), (2, 1)),
+            jnp.asarray(k_pool), jnp.asarray(v_pool), tables,
+            np.full(2, pos, np.int32), CFG)
         np.testing.assert_allclose(
-            np.asarray(lg), np.asarray(logits_full[:, pos]),
+            np.asarray(lg), np.asarray(logits_full[:, win]),
             rtol=1e-5, atol=1e-5,
-            err_msg=f"decode logits diverge at position {pos}")
-        np.testing.assert_allclose(
-            np.asarray(kn), np.asarray(k_full[:, :, pos]),
-            rtol=1e-5, atol=1e-6)
-        k_cache[:, :, pos] = np.asarray(kn)
-        v_cache[:, :, pos] = np.asarray(vn)
+            err_msg=f"decode logits diverge in the window at {pos}")
+        wrote = np.zeros(shape[:3], bool)
+        wrote[at(pos, window)] = True
+        for new, old, full in ((np.asarray(k_new), k_pool, k_full),
+                               (np.asarray(v_new), v_pool, v_full)):
+            np.testing.assert_allclose(new[at(pos, window)],
+                                       full[:, :, win], rtol=1e-5, atol=1e-6)
+            np.testing.assert_array_equal(new[~wrote], old[~wrote])
+        k_pool, v_pool = np.array(k_new), np.array(v_new)
 
 
 def test_decode_flops_per_token_is_forward_third():

@@ -1,6 +1,7 @@
 """Serving plane: paged KV cache, continuous batching, HTTP surface.
 
-The allocator/cache tests are pure bookkeeping (no jax compute); the
+The allocator/cache tests are bookkeeping (rows go into the device
+pools by hand, ``kv_rows``, not through a model program); the
 engine tests run the real jitted prefill/decode on a tiny model (the
 jit wrappers are process-cached, so the whole file pays each shape's
 compile once).
@@ -29,6 +30,7 @@ from dmlc_tpu.serving import (
 )
 from dmlc_tpu.serving.scheduler import (ACTIVE, DONE, WAITING,
                                         PRIORITY_CLASSES, coerce_priority)
+from kv_rows import kv_get, kv_put
 
 
 # ---------------------------------------------------------------------------
@@ -80,22 +82,22 @@ def _seq_kv(cache, n, seed):
         rng.standard_normal(shape).astype(np.float32)
 
 
-def test_kv_write_gather_roundtrip_across_blocks():
+def test_kv_put_get_roundtrip_across_blocks():
     cache = _mk_cache()
     k, v = _seq_kv(cache, 10, seed=0)  # 10 tokens = 2.5 blocks
     assert cache.allocate(1, 10)
-    cache.write(1, k, v, start=0)
-    gk, gv, lens = cache.gather([1])
+    kv_put(cache, 1, k, v, start=0)
+    gk, gv, lens = kv_get(cache, [1])
     assert lens.tolist() == [10]
     assert gk.shape[2] % cache.block_size == 0
     np.testing.assert_array_equal(gk[:, 0, :10], k)
     np.testing.assert_array_equal(gv[:, 0, :10], v)
-    # append one token lands at position 10 (same block reservation is
+    # one more token lands at position 10 (same block reservation is
     # insufficient: 11 tokens need a 3rd block, so extend first)
     assert cache.extend(1, 1)
     k1, v1 = _seq_kv(cache, 1, seed=1)
-    cache.append(1, k1[:, 0], v1[:, 0])
-    gk, gv, lens = cache.gather([1])
+    kv_put(cache, 1, k1, v1)
+    gk, gv, lens = kv_get(cache, [1])
     assert lens.tolist() == [11]
     np.testing.assert_array_equal(gk[:, 0, 10], k1[:, 0])
 
@@ -105,9 +107,9 @@ def test_kv_exhaustion_then_free_then_reuse_without_aliasing():
     ka, va = _seq_kv(cache, 8, seed=0)
     kc, vc = _seq_kv(cache, 8, seed=2)
     assert cache.allocate(1, 8)          # seq A: blocks 0-1
-    cache.write(1, ka, va)
+    kv_put(cache, 1, ka, va)
     assert cache.allocate(3, 8)          # seq C: blocks 2-3
-    cache.write(3, kc, vc)
+    kv_put(cache, 3, kc, vc)
     assert not cache.allocate(2, 4)      # pool exhausted
     assert not cache.extend(1, 1)
     cache.free(1)                        # eviction frees A's blocks
@@ -116,9 +118,9 @@ def test_kv_exhaustion_then_free_then_reuse_without_aliasing():
     reused = set(cache.block_table(2)) & set([0, 1, 2, 3])
     assert reused, "freed blocks must be reused"
     kb, vb = _seq_kv(cache, 8, seed=1)
-    cache.write(2, kb, vb)
+    kv_put(cache, 2, kb, vb)
     # B reads back B's data, and surviving C is untouched (no aliasing)
-    gk, gv, lens = cache.gather([2, 3])
+    gk, gv, lens = kv_get(cache, [2, 3])
     np.testing.assert_array_equal(gk[:, 0, :8], kb)
     np.testing.assert_array_equal(gk[:, 1, :8], kc)
     np.testing.assert_array_equal(gv[:, 1, :8], vc)
@@ -139,7 +141,7 @@ def test_kv_fragmentation_bounded_under_mixed_length_churn():
             n = int(rng.integers(1, 14))
             if cache.allocate(sid, n):
                 k, v = _seq_kv(cache, n, seed=sid)
-                cache.write(sid, k, v)
+                kv_put(cache, sid, k, v)
                 live[sid] = (n, k)
         # invariants every iteration: conservation + bounded usage
         s = cache.stats()
@@ -153,7 +155,7 @@ def test_kv_fragmentation_bounded_under_mixed_length_churn():
                                      - s["cached_tokens"])
     # every surviving sequence still reads back its own data
     for seq, (n, k) in live.items():
-        gk, _, lens = cache.gather([seq])
+        gk, _, lens = kv_get(cache, [seq])
         assert lens[0] == n
         np.testing.assert_array_equal(gk[:, 0, :n], k)
     for seq in list(live):
@@ -162,32 +164,31 @@ def test_kv_fragmentation_bounded_under_mixed_length_churn():
     assert cache.n_blocks_in_use == 0
 
 
-def test_kv_gather_pads_batch_with_dead_rows():
+def test_kv_block_tables_pad_the_batch_with_dead_rows():
     cache = _mk_cache()
     k, v = _seq_kv(cache, 3, seed=0)
     assert cache.allocate(1, 3)
-    cache.write(1, k, v)
-    gk, gv, lens = cache.gather([1], pad_batch=4, pad_len=8)
-    assert gk.shape[1] == 4 and gk.shape[2] == 8
+    kv_put(cache, 1, k, v)
+    tables, lens = cache.block_tables_array([1], pad_batch=4, pad_width=2)
+    assert tables.shape == (4, 2) and tables.dtype == np.int32
     assert lens.tolist() == [3, 0, 0, 0]
-    assert not gk[:, 1:].any()
-    # an explicit pad_len pins the jit shape: insufficiency / bad
-    # granularity must raise, never silently widen
-    with pytest.raises(ValueError):
-        cache.gather([1], pad_len=6)  # not a block multiple
+    assert tables[0].tolist() == cache.block_table(1) + [0]
+    assert not tables[1:].any()  # dead rows: page 0, behind length 0
+    # an explicit pad_width pins the jit shape: insufficiency must
+    # raise, never silently widen
     assert cache.extend(1, 6)
     k9, v9 = _seq_kv(cache, 6, seed=3)
-    cache.write(1, k9, v9)  # now 9 tokens > pad_len 8
+    kv_put(cache, 1, k9, v9)  # now 9 tokens = 3 blocks > pad_width 2
     with pytest.raises(ValueError):
-        cache.gather([1], pad_len=8)
+        cache.block_tables_array([1], pad_width=2)
 
 
-def test_kv_write_past_reservation_raises():
+def test_kv_advance_past_reservation_raises():
     cache = _mk_cache()
     assert cache.allocate(1, 4)
-    k, v = _seq_kv(cache, 5, seed=0)
-    with pytest.raises(DMLCError):
-        cache.write(1, k, v)  # 5 tokens into a 1-block reservation
+    with pytest.raises(DMLCError, match="past reservation"):
+        cache.advance_many([(1, 5)])  # 5 tokens in a 1-block reservation
+    assert cache.length(1) == 0 and cache.stats()["cached_tokens"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -1122,23 +1123,12 @@ def _warm_engine(**kw):
     return eng
 
 
-@pytest.mark.parametrize("paged", ["on", "off"])
-def test_engine_iteration_yields_the_span_tree(monkeypatch, paged):
-    """One tree on both data paths, except that only the gather path
-    (host-resident cache) brings K and V to the host."""
-    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", paged)
-    to_host = [("serving.prefill.kv_to_host", "serving.prefill")] \
-        if paged == "off" else []
-    gather = [("compute.gather", "serving.decode.dispatch")] \
-        if paged == "off" else []
+def test_engine_iteration_yields_the_span_tree():
     eng = _warm_engine()
     telemetry.reset()
     req = eng.submit([9, 8, 7, 6, 5, 4], max_new_tokens=6)
     assert eng.step()  # one iteration: a prefill and a decode window
-    # (the gather path's decode compiles anew whenever its dense view
-    # grows a block: a compile row is not part of the tree)
-    recs = [r for r in _engine_thread_spans()
-            if not r["name"].startswith("compile:")]
+    recs = _engine_thread_spans()
     by_id = {r["id"]: r for r in recs}
     tree = sorted((r["name"], by_id[r["parent"]]["name"]
                    if r["parent"] else None) for r in recs)
@@ -1160,7 +1150,7 @@ def test_engine_iteration_yields_the_span_tree(monkeypatch, paged):
         ("compute.sampling", "serving.decode.commit"),
         ("serving.decode.deliver", "serving.decode"),
         ("serving.decode.bookkeeping", "serving.decode"),
-    ] + to_host + gather)
+    ])
     (it,) = [r for r in recs if r["name"] == "serving.iteration"]
     for r in recs:
         if r["name"].startswith("serving."):
@@ -1169,7 +1159,7 @@ def test_engine_iteration_yields_the_span_tree(monkeypatch, paged):
              if r.get("args", {}).get("req") == req.id}
     assert owned == {"serving.schedule", "serving.prefill",
                      "serving.prefill.run", "serving.kv_write",
-                     "serving.first_token"} | {name for name, _ in to_host}
+                     "serving.first_token"}
     eng.close()
 
 
@@ -1247,30 +1237,22 @@ def test_engine_starved_is_one_span_per_episode():
     assert telemetry.counters_snapshot()["serving"]["starved_count"] == 3
 
 
-@pytest.mark.parametrize("paged", ["on", "off"])
-def test_engine_byte_counters_equal_what_crossed(monkeypatch, paged):
-    """On the paged path the logits are all that crosses the link, for
-    a prefill and for a decode step; the gather path also brings the
-    K/V of both to its host-resident cache.  Nothing goes back up."""
-    monkeypatch.setenv("DMLC_SERVE_PAGED_ATTN", paged)
+def test_engine_byte_counters_equal_what_crossed():
+    """The logits are all that crosses the link, for a prefill and for
+    a decode step.  Nothing goes back up."""
     eng = _warm_engine()
     telemetry.reset()
     crossed = {"prefill": 0, "decode": 0}
     real_prefill, real_decode = eng._prefill, eng._decode
-    # paged programs return (logits, k_pool, v_pool) and the pools stay
-    # on the device; gather programs return (logits, k, v), all fetched
-    kv = slice(1, 1) if paged == "on" else slice(1, 3)
 
     def prefill(*a):
         out = real_prefill(*a)
-        crossed["prefill"] += np.asarray(out[0][0]).nbytes + sum(
-            np.asarray(o).nbytes for o in out[kv])
+        crossed["prefill"] += np.asarray(out[0][0]).nbytes
         return out
 
     def decode(*a):
         out = real_decode(*a)
-        crossed["decode"] += np.asarray(out[0]).nbytes + sum(
-            np.asarray(o).nbytes for o in out[kv])
+        crossed["decode"] += np.asarray(out[0]).nbytes
         return out
 
     eng._prefill, eng._decode = prefill, decode
@@ -1287,13 +1269,9 @@ def test_engine_byte_counters_equal_what_crossed(monkeypatch, paged):
                if r["name"] == "serving.decode.fetch") \
         == c["decode_d2h_bytes"]
     vocab_row = 64 * 4  # one float32 row of the tiny model's logits
-    if paged == "on":
-        assert c["prefill_d2h_bytes"] == vocab_row
-        # every step fetches the whole padded batch: max_active rows
-        assert c["decode_d2h_bytes"] == 3 * eng.max_active * vocab_row
-    else:
-        # + K and V of the padded prompt, [L=2, 1, 8, H=2, D=8] float32
-        assert c["prefill_d2h_bytes"] == vocab_row + 2 * (2 * 8 * 2 * 8 * 4)
+    assert c["prefill_d2h_bytes"] == vocab_row
+    # every step fetches the whole padded batch: max_active rows
+    assert c["decode_d2h_bytes"] == 3 * eng.max_active * vocab_row
     eng.close()
 
 
