@@ -699,7 +699,12 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
     ``n_blocks`` is out of bounds in every layer); the kernel gets all
     layers' pages as one run with the tables shifted to layer ``li``,
     so no layer's slice of a pool is ever made.  The engine donates
-    the pools, which makes the scatter in place.
+    the pools, which makes the scatter in place.  The kernel
+    (``paged_attn``, one call a layer) reads through the table only
+    the pages a live row's ``lengths[b] + S`` positions fill, in
+    blocks of many pages fetched ahead by its own DMAs: a padded
+    table entry or a dead row costs it nothing, so neither the table's
+    width ``W`` nor ``max_active`` is paid for beyond what is live.
 
     Returns ``(logits [B, S, V], k_pool, v_pool)``: the updated pools
     are the cache (the caller adopts them and advances each row's

@@ -25,33 +25,44 @@ the caller must have scattered the window's own K/V into the pool at
 positions ``lengths[b] .. lengths[b]+S-1`` first (scatter-then-attend),
 so this is the causal "cache + new token" mask with the new tokens
 living at their real paged addresses.  Dead batch rows (length 0,
-table all padding) read the padding page and produce garbage the
-engine never samples.
+table all padding) produce what the engine never samples: the twin
+reads the padding page for them, the kernel reads nothing and writes
+zeros.
 
-Two implementations: a Pallas TPU kernel whose block-table indirection
-lives in the BlockSpec index map (the scalar-prefetched table picks
-which physical block each grid step DMAs — the PagedAttention trick),
-and a ``lax``-composed reference (gather inside jit) that runs
+Two implementations: a Pallas TPU kernel that walks each row's pages
+itself, and a ``lax``-composed reference (gather inside jit) that runs
 everywhere and is the parity oracle; ops/dispatch.py decides which a
 call site gets.  interpret=True runs the kernel on CPU for tests.
-Layout/tiling per /opt/skills/guides/pallas_guide.md; the step body
-mirrors ops/flash_attention.py's forward at G=1 (KV walk in the grid,
-f32 accumulators in the revisited output blocks, predicated skip of
-fully-masked blocks).
+DMAs and double buffering per /opt/skills/guides/pallas_guide.md and
+boom_attention_tricks.md sections 9-11.
 
-Kernel layout.  The TPU lowering only takes blocks whose last two dims
-are tile multiples or the whole array dims, so a ``[bs, 1, D]`` slice
-of one head out of a ``[bs, H, D]`` page is not a legal block.  The
-kernel therefore takes WHOLE pages: the pool is viewed (free reshape)
-as ``[n_blocks, bs*H, D]`` and one grid step ``(b, j)`` multiplies all
-``H*S`` query rows of sequence ``b`` against all ``bs*H`` key rows of
-its j-th page in one 2-D matmul.  Query row ``(h, s)`` may only see
-key row ``(t, h')`` when ``h == h'`` and the position is in range;
-both conditions fold into ONE static int32 table ``pair[r, c] = t - s``
-(a huge value where the heads differ), compared against the scalar
-``lengths[b] - j*bs``.  The cross-head products are wasted MXU work
-(H-fold), which decode has to spare; the grid and its speed are
-ROADMAP A4.
+Kernel layout.  ONE invocation serves the whole batch: the pools stay
+in HBM, the tables and lengths sit in SMEM, q and the output (a few
+hundred KB) whole in VMEM.  A row's context is walked in *blocks* of
+up to ``_KV_VMEM_BYTES / 4`` of K and as much of V (32 pages = 512
+tokens at the flagship's 64 KB page), each page copied into its slot
+of a ``[2, pages, bs*H, D]`` VMEM scratch by a DMA of its own, all of
+a block's copies in flight at once and the NEXT block's (the next live
+row's first, at a row's end) started before the current one is
+computed.  How far a row walks follows ``lengths[b]``: it fetches
+``ceil((lengths[b] + S) / bs)`` pages and no padded table entry, a
+dead row (length 0) fetches nothing and costs one turn of a scalar
+loop, and no row pays for another's length.  A block is computed in
+*chunks* of a few pages (``_CHUNK_SCORES``), each waited for by its own
+semaphore, so the first product starts when the first chunk has
+landed.  The TPU tiles a page ``[bs, H, D]`` with the heads on
+sublanes, so one head's keys are no slice of it; a chunk is therefore
+ONE 2-D product of the ``S*H`` query rows (row ``s*H + h``, unpadded:
+16 rows in plain decode) against the chunk's ``pages*bs*H`` key rows
+(row ``t*H + h'``), masked to ``h == h'`` and position ``<=
+lengths[b] + s`` by one int32 table of ``t - s`` built from iotas once
+a call, followed by the online-softmax update in float32 and the value
+product with ``p`` in the pool's dtype (as the twin does).  The
+cross-head products cost the MXU sixteen times the useful work and it
+does not show: measured on the v5e against a per-head form over
+head-major pages ``[H, bs, D]``, this one is the faster (PERF.md, PR
+30), so the pool keeps its layout.  Block and chunk sizes follow from
+the shapes (:func:`_walk_shape`), not from a knob.
 
 Latent attention (MLA) decodes against another cache: ONE row per
 token and layer, ``[rms(c_kv) | rope(k_pe)]``, with no head axis and no
@@ -81,8 +92,7 @@ __all__ = ["paged_attention", "supports", "latent_paged_attention",
 def supports(head_dim: int, block_size: int, n_heads: int) -> bool:
     """Whether the Pallas kernel serves these shapes: the head dim must
     fill whole 128-element lanes, and so must one page's ``bs*H`` key
-    rows (they are the score matrix's lane dim); the window is padded
-    to whole sublanes inside."""
+    rows (a chunk of them is the score matrix's lane dim)."""
     return head_dim % 128 == 0 and (block_size * n_heads) % 128 == 0
 
 
@@ -109,113 +119,194 @@ def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale):
     return out.astype(q.dtype)
 
 
-def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, pair_ref,
-            pv_ref, m_ref, l_ref, *, bs: int, s_real: int, scale: float):
+#: VMEM the walk's K and V blocks take together: two of each, so that
+#: one block's pages land while the other is computed
+_KV_VMEM_BYTES = 8 << 20
+#: float32 scores one compute chunk makes, ``[S*H, chunk keys]``: the
+#: softmax of a chunk stays in registers' reach
+_CHUNK_SCORES = 32 * 1024
+#: scoped VMEM asked of the compiler: the blocks, the chunk's
+#: temporaries, the mask table, q and the output with room to spare
+_VMEM_LIMIT_BYTES = 32 << 20
+
+
+def _walk_shape(rows: int, cols: int, d: int, itemsize: int,
+                w: int) -> tuple:
+    """``(pages a compute chunk, chunks a block)`` from the shapes: a
+    chunk's scores fit ``_CHUNK_SCORES``, two K and two V blocks fit
+    ``_KV_VMEM_BYTES``, and neither outgrows a table row."""
+    chunk = max(1, min(_CHUNK_SCORES // (rows * cols), w))
+    fit = _KV_VMEM_BYTES // (4 * chunk * cols * d * itemsize)
+    return chunk, max(1, min(fit, -(-w // chunk)))
+
+
+def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, diff_ref, *, n_heads: int, bs: int,
+            s_w: int, chunk: int, scale: float):
+    """One invocation walks every row: ``k_buf`` / ``v_buf``
+    ``[2, pages, bs*H, D]`` are the two blocks, ``sems[kv, slot, c]``
+    counts the copies of chunk ``c`` of a block."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    bi = pl.program_id(0)
-    j = pl.program_id(1)
+    n_rows, rows, d = q_ref.shape
+    pages = k_buf.shape[1]
+    ckeys = chunk * bs * n_heads
 
-    @pl.when(j == 0)
-    def _init():
-        pv_ref[...] = jnp.zeros_like(pv_ref[...])
-        m_ref[...] = jnp.full_like(m_ref[...], _NEG_BIG)
-        l_ref[...] = jnp.zeros_like(l_ref[...])
+    # the mask's static part, once a call: key column c = t*H + h' may
+    # meet query row r = s*H + h where the heads agree, t - s tokens
+    # past the chunk's first position
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, ckeys), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, ckeys), 1)
+    diff_ref[...] = jnp.where(r % n_heads == c % n_heads,
+                              c // n_heads - r // n_heads, 1 << 30)
 
-    # the last pool position any window row of this sequence may
-    # attend; pages entirely past it are predicated no-op visits
-    limit = len_ref[bi] + s_real - 1
+    # a row's last block copies its live pages only; what the slot
+    # holds behind them is multiplied by p = 0, so it must be finite:
+    # stale pages are, the scratch's first bytes need not be
+    def _zero(i, _):
+        v_buf[i // pages, i % pages] = jnp.zeros(v_buf.shape[2:],
+                                                 v_buf.dtype)
+        return _
+    jax.lax.fori_loop(0, 2 * pages, _zero, 0)
 
-    @pl.when(j * bs <= limit)
-    def _step():
-        q = q_ref[...]                                 # [1, H*S_pad, D]
-        kb = k_ref[...]                                # [1, bs*H, D]
-        vb = v_ref[...]
-        s = jax.lax.dot_general(
-            q, kb, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [1, rows, cols]
-        # same head AND key position j*bs + t <= lengths[b] + s
-        keep = pair_ref[...] <= len_ref[bi] - j * bs
-        s = jnp.where(keep, s, _NEG_BIG)
-        m_old = m_ref[..., 0]                          # [1, rows]
-        l_old = l_ref[..., 0]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=2))
-        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m_old - m_new)
-        l_new = l_old * corr + jnp.sum(p, axis=2)
-        pv = jax.lax.dot_general(
-            p, vb.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [1, rows, D]
-        pv_ref[...] = pv_ref[...] * corr[..., None] + pv
-        # per-row scalars broadcast over an 8-lane minor axis (Mosaic
-        # lane tiling, same storage trick as flash_attention)
-        m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
+    def n_tokens(b):  # positions row b attends; a dead row has none
+        return jnp.where(len_ref[b] > 0, len_ref[b] + s_w, 0)
+
+    def live_pages(b, j):  # of block j of row b
+        return jnp.clip(pl.cdiv(n_tokens(b), bs) - j * pages, 0, pages)
+
+    def copies(b, j, slot, i):  # page i of block j of row b
+        page = tbl_ref[b, j * pages + i]
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, i],
+                                      sems.at[0, slot, i // chunk]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, i],
+                                      sems.at[1, slot, i // chunk]))
+
+    def start(b, j, slot):
+        def one(i, _):
+            for copy in copies(b, j, slot, i):
+                copy.start()
+            return _
+        jax.lax.fori_loop(0, live_pages(b, j), one, 0)
+
+    def next_live(b):  # the first live row at or after b, or n_rows
+        return jax.lax.while_loop(
+            lambda i: jnp.logical_and(
+                i < n_rows, len_ref[jnp.minimum(i, n_rows - 1)] <= 0),
+            lambda i: i + 1, b)
+
+    first = next_live(0)
+
+    @pl.when(first < n_rows)
+    def _prologue():
+        start(first, 0, 0)
+
+    def row(b, slot):
+        n_blocks = pl.cdiv(n_tokens(b), pages * bs)
+        # whose first block to fetch during this row's last one
+        nxt = next_live(jnp.where(n_blocks > 0, b + 1, n_rows))
+        q = q_ref[b]                                   # [S*H, D]
+
+        def block(j, carry):
+            slot, m, l, acc = carry
+            last = j + 1 == n_blocks
+            ahead_b = jnp.where(last, nxt, b)
+
+            @pl.when(ahead_b < n_rows)
+            def _fetch_ahead():
+                start(ahead_b, jnp.where(last, 0, j + 1), 1 - slot)
+
+            live = live_pages(b, j)
+
+            def step(ci, carry):
+                m, l, acc = carry
+
+                def landed(i, _):
+                    for copy in copies(b, j, slot, i):
+                        copy.wait()
+                    return _
+                jax.lax.fori_loop(ci * chunk,
+                                  jnp.minimum(live, (ci + 1) * chunk),
+                                  landed, 0)
+                at = pl.ds(ci * chunk, chunk)
+                k = k_buf[slot, at].reshape(ckeys, d)  # row = t*H + h
+                v = v_buf[slot, at].reshape(ckeys, d)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                # same head AND key position <= lengths[b] + s
+                keep = diff_ref[...] <= (
+                    len_ref[b] - (j * pages + ci * chunk) * bs)
+                s = jnp.where(keep, s, _NEG_BIG)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                # every row keeps position 0, so m_new is a real score
+                # from the first chunk on and a masked column's p is 0
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+                l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+                acc = acc * corr + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            m, l, acc = jax.lax.fori_loop(0, pl.cdiv(live, chunk), step,
+                                          (m, l, acc))
+            return 1 - slot, m, l, acc
+
+        slot, _, l, acc = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (slot, jnp.full((rows, 1), _NEG_BIG, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, d), jnp.float32)))
+        # a dead row walked nothing: acc = l = 0 writes zeros
+        o_ref[b] = acc / jnp.maximum(l, 1e-37)
+        return slot
+
+    jax.lax.fori_loop(0, n_rows, row, 0)
 
 
-@functools.lru_cache(maxsize=8)  # read-only; one per (H, window, page)
-def _pair_table(h: int, s_pad: int, bs: int) -> np.ndarray:
-    """``[1, H*S_pad, bs*H]`` int32: ``t - s`` where query row
-    ``r = h*S_pad + s`` and key row ``c = t*H + h'`` share a head, a
-    value no length can reach where they do not."""
-    r = np.arange(h * s_pad)
-    c = np.arange(bs * h)
-    same = (r // s_pad)[:, None] == (c % h)[None, :]
-    diff = (c // h)[None, :] - (r % s_pad)[:, None]
-    return np.where(same, diff, 1 << 30).astype(np.int32)[None]
-
-
-def _pallas_paged_attention(q, k_pool, v_pool, block_tables, lengths,
-                            scale, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret):
+    """The kernel's call.  Jitted so that a decode program's layers,
+    whose calls differ in their tables' values alone, trace and lower
+    the walk once and not once a layer; its name keeps clear of the
+    kernel's, which the benchmark counts by."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
-    n_blocks, bs = k_pool.shape[:2]
-    s_pad = -(-s_w // 8) * 8  # window rows fill whole sublanes
-    rows, cols = h * s_pad, bs * h
-    qt = jnp.transpose(q, (0, 2, 1, 3))                  # [B, H, S, D]
-    if s_pad != s_w:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, s_pad - s_w), (0, 0)))
-    qt = qt.reshape(b, rows, d)
-    kf = k_pool.reshape(n_blocks, cols, d)               # row = t*H + h
-    vf = v_pool.reshape(n_blocks, cols, d)
-    tbl = block_tables.astype(jnp.int32)
-    lens = lengths.astype(jnp.int32)
-    pair = jnp.asarray(_pair_table(h, s_pad, bs))
-
-    # the paged indirection: the K/V index maps read the scalar-
-    # prefetched block table to pick which PHYSICAL page each grid
-    # step DMAs — the kernel walks row b's logical pages j=0..W-1 but
-    # the pool is only ever touched at the table's addresses
-    of_seq = lambda bi, j, tbl_, lens_: (bi, 0, 0)  # noqa: E731
-    q_spec = pl.BlockSpec((1, rows, d), of_seq)
-    kv_spec = pl.BlockSpec((1, cols, d),
-                           lambda bi, j, tbl_, lens_: (tbl_[bi, j], 0, 0))
-    pair_spec = pl.BlockSpec((1, rows, cols),
-                             lambda bi, j, tbl_, lens_: (0, 0, 0))
-    acc_spec = pl.BlockSpec((1, rows, d), of_seq)
-    ml_spec = pl.BlockSpec((1, rows, 8), of_seq)
-    pv, _, l = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, s_real=s_w, scale=scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, w),  # innermost page walk revisits sequence bi
-            in_specs=[q_spec, kv_spec, kv_spec, pair_spec],
-            out_specs=[acc_spec, ml_spec, ml_spec],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, rows, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, rows, 8), jnp.float32),
-            jax.ShapeDtypeStruct((b, rows, 8), jnp.float32),
-        ],
+    n_pages, bs = k_pool.shape[:2]
+    rows, cols = s_w * h, bs * h
+    chunk, per_block = _walk_shape(rows, cols, d, k_pool.dtype.itemsize, w)
+    pages = chunk * per_block
+    block = pltpu.VMEM((2, pages, cols, d), k_pool.dtype)
+    out = pl.pallas_call(
+        functools.partial(_kernel, n_heads=h, bs=bs, s_w=s_w, chunk=chunk,
+                          scale=scale),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # the tables
+                  pl.BlockSpec(memory_space=pltpu.SMEM),   # the lengths
+                  pl.BlockSpec(memory_space=pltpu.VMEM),   # q, whole
+                  pl.BlockSpec(memory_space=pl.ANY),       # the pools stay
+                  pl.BlockSpec(memory_space=pl.ANY)],      # in HBM
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), jnp.float32),
+        scratch_shapes=[block, block,
+                        pltpu.SemaphoreType.DMA((2, 2, per_block)),
+                        pltpu.VMEM((rows, chunk * cols), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         name="paged_attn",
         interpret=interpret,
-    )(tbl, lens, qt, kf, vf, pair)
-    out = pv / jnp.maximum(l[..., :1], 1e-37)            # [B, rows, D]
-    out = out.reshape(b, h, s_pad, d)[:, :, :s_w]
-    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q.reshape(b, rows, d),                             # row = s*H + h
+      k_pool.reshape(n_pages, cols, d),                  # row = t*H + h
+      v_pool.reshape(n_pages, cols, d))
+    # float32 out of the kernel: whatever XLA does to hand the result
+    # on (a cast, a layout for the o projection) is then an op of its
+    # own name and not a second event under the kernel's
+    return out.astype(q.dtype).reshape(b, s_w, h, d)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
@@ -240,9 +331,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
     mode = dispatch.choose(
         supports(d, int(k_pool.shape[1]), int(q.shape[2])), impl)
     if mode != dispatch.LAX:
-        return _pallas_paged_attention(
-            q, k_pool, v_pool, block_tables, lengths, float(scale),
-            interpret or mode == dispatch.INTERPRET)
+        return _walk_pool(
+            q, k_pool, v_pool, block_tables, lengths, scale=float(scale),
+            interpret=bool(interpret or mode == dispatch.INTERPRET))
     return _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths,
                                 float(scale))
 

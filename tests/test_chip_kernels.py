@@ -86,12 +86,23 @@ def test_ring_step_kernel_lowers_for_tpu_inside_shard_map():
     _lower_for_tpu(fn, x, x, x)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize("s_w", [1, 4])
-def test_paged_attention_lowers_for_tpu(dtype, s_w):
+@pytest.mark.parametrize("b,s_w,n_blocks,w,dtype", [
+    (8, 1, 256, 66, jnp.bfloat16),
+    (8, 4, 256, 66, jnp.bfloat16),
+    (8, 1, 256, 66, jnp.float32),
+    (8, 4, 256, 66, jnp.float32),
+    # the flagship serve cells' own: 32 rows, all layers' pages as one
+    # run, the narrowest, the chat and the doc tables
+    (32, 1, 16 * 2560, 4, jnp.bfloat16),
+    (32, 1, 16 * 2560, 24, jnp.bfloat16),
+    (32, 1, 16 * 2560, 130, jnp.bfloat16),
+])
+def test_paged_attention_lowers_for_tpu(b, s_w, n_blocks, w, dtype):
     """H=16, block 16: the shape whose per-head [bs, 1, D] block the TPU
-    lowering refused."""
-    b, h, d, bs, n_blocks, w = 8, 16, 128, 16, 256, 66
+    lowering refused.  One call of the function is ONE custom call
+    named ``paged_attn``: the benchmark's roofline reader multiplies
+    the kernel's cost by the events of that name."""
+    h, d, bs = 16, 128, 16
     assert paged.supports(d, bs, h)
     q = jax.ShapeDtypeStruct((b, s_w, h, d), dtype)
     pool = jax.ShapeDtypeStruct((n_blocks, bs, h, d), dtype)
@@ -99,7 +110,56 @@ def test_paged_attention_lowers_for_tpu(dtype, s_w):
     lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
     text = _lower_for_tpu(paged.paged_attention,
                           q, pool, pool, tables, lengths)
-    assert "paged_attn" in text
+    assert text.count("stablehlo.custom_call") == 1
+    assert text.count('kernel_name = "paged_attn"') == 1
+
+
+@pytest.fixture(scope="module")
+def one_chip(request):
+    """A described v5e chip, nothing attached: what ``.compile()`` for
+    it refuses, the chip's compiler would.  Only this file asks for it,
+    inside a test (one process may hold the TPU's library)."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_paged_attention_compiles_to_one_named_op(one_chip):
+    """Compiled for the chip with the o projection behind it, at the
+    chat cell's shapes: Mosaic takes the kernel (DMAs, semaphores,
+    scalar trip counts, 32 MB of scoped VMEM), and the ONLY instruction
+    of the optimized program whose op_name carries ``paged_attn`` is
+    the kernel's custom call: a layout copy or a cast under that name
+    would be a second event to the roofline's reader."""
+    import re
+
+    b, h, d, bs, w, e = 32, 16, 128, 16, 24, 2048
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    pool = arr((16 * 2560, bs, h, d), jnp.bfloat16)
+
+    def layer(q, kp, vp, tables, lengths, wo):
+        o = paged.paged_attention(q, kp, vp, tables, lengths)
+        return jnp.einsum("bthd,hde->bte", o, wo)
+
+    with dispatch.force_kernel_mode(dispatch.MOSAIC):
+        hlo = jax.jit(layer).lower(
+            arr((b, 1, h, d), jnp.bfloat16), pool, pool,
+            arr((b, w), jnp.int32), arr((b,), jnp.int32),
+            arr((h, d, e), jnp.bfloat16)).compile().as_text()
+    named = [line for line in hlo.splitlines()
+             if re.search(r'op_name="[^"]*paged_attn', line)]
+    assert len(named) == 1, named
+    assert "custom-call(" in named[0] and "tpu_custom_call" in named[0]
 
 
 def test_dispatch_is_one_flippable_function():

@@ -25,6 +25,7 @@ import pytest
 
 from dmlc_tpu import telemetry
 from dmlc_tpu.base import DMLCError
+from dmlc_tpu.ops import paged_attention as paged_ops
 from dmlc_tpu.ops.paged_attention import paged_attention, supports
 from dmlc_tpu.serving import (InferenceEngine, PagedKVCache, Request,
                               ServingHTTPServer)
@@ -107,21 +108,47 @@ def test_paged_vs_gather_parity_matrix(s_w):
     np.testing.assert_allclose(paged, dense, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("h,bs,s_w,dtype,tol", [
-    (1, 8, 1, np.float32, 1e-5),
-    (16, 16, 1, np.float32, 1e-5),      # flagship heads and page size
-    (16, 16, 4, np.float32, 1e-5),      # ... under a verify window
-    (16, 16, 4, "bfloat16", 2e-2),      # ... in the serving dtype
+def _page_lengths(bs, w, s_w, edge):
+    """Lengths that sit on, before and after page boundaries, and one
+    dead row."""
+    return [1, bs - 1, bs, bs + 1, w * bs - s_w, 0]
+
+
+def _block_lengths(bs, w, s_w, edge):
+    """What a walk in blocks of ``edge`` positions adds: a row shorter
+    than one block, one whose last attended position is a block's
+    last, one a position (so a page) past that, a dead row before a
+    row many blocks long, and the longest row last."""
+    assert w * bs > 2 * edge and (w * bs) % edge, "W: no whole blocks"
+    return [5, edge - s_w, edge - s_w + 1, 0, 2 * edge + bs + 3,
+            w * bs - s_w]
+
+
+@pytest.mark.parametrize("h,bs,s_w,dtype,tol,w,lengths_of", [
+    (1, 8, 1, np.float32, 1e-5, 3, _page_lengths),
+    (16, 16, 1, np.float32, 1e-5, 3, _page_lengths),  # flagship heads, page
+    (16, 16, 4, np.float32, 1e-5, 3, _page_lengths),  # ... a verify window
+    (16, 16, 4, "bfloat16", 2e-2, 3, _page_lengths),  # ... serving dtype
+    # tables wider than two blocks and no multiple of one
+    (16, 16, 1, np.float32, 1e-5, 38, _block_lengths),
+    (16, 16, 3, np.float32, 1e-5, 38, _block_lengths),
+    (16, 16, 1, "bfloat16", 2e-2, 70, _block_lengths),
+    (16, 16, 4, "bfloat16", 2e-2, 70, _block_lengths),
 ])
-def test_paged_attention_pallas_interpret_parity(h, bs, s_w, dtype, tol):
+def test_paged_attention_pallas_interpret_parity(h, bs, s_w, dtype, tol, w,
+                                                 lengths_of):
     """The Pallas kernel (interpret mode on CPU) agrees with the lax
-    reference: lengths that sit on, before and after page boundaries,
-    and one dead row (garbage by contract, not compared)."""
+    reference on every live row (a dead row's output is garbage by
+    contract, not compared)."""
     import jax.numpy as jnp
 
-    w, d = 3, 128
+    d = 128
     assert supports(d, bs, h) == (h == 16)
-    lengths = [1, bs - 1, bs, bs + 1, w * bs - s_w, 0]
+    # the kernel's own block, from its shapes
+    chunk, per_block = paged_ops._walk_shape(
+        s_w * h, bs * h, d, jnp.dtype(dtype).itemsize, w)
+    edge = chunk * per_block * bs
+    lengths = lengths_of(bs, w, s_w, edge)
     rng = np.random.default_rng(1)
     n_blocks = len(lengths) * w
     k_pool = jnp.asarray(_rand(rng, n_blocks, bs, h, d), dtype)
@@ -136,6 +163,47 @@ def test_paged_attention_pallas_interpret_parity(h, bs, s_w, dtype, tol):
                                      impl="pallas", interpret=True),
                      np.float32)
     np.testing.assert_allclose(got[live], ref[live], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("s_w", [1, 3])
+def test_paged_attention_pallas_reads_only_named_pages(s_w):
+    """Every page that no live row's first ``ceil((length + S) / bs)``
+    table entries name holds NaN: the padded entries' page 0 (a dead
+    row's whole table), the pages of a table's tail a row has not
+    grown into, the pool's free pages.  The kernel's output is finite
+    and is the lax twin's on the clean pool (the twin gathers every
+    entry, and 0 x NaN is NaN)."""
+    import jax.numpy as jnp
+
+    h, bs, d, w = 16, 16, 128, 9
+    lengths = [3, 0, 2 * bs, 5 * bs + 7, 0, w * bs - s_w]
+    rng = np.random.default_rng(2)
+    n_blocks = len(lengths) * w + 5
+    lens = np.asarray(lengths, np.int32)
+    tables = np.zeros((len(lengths), w), np.int32)
+    free = list(rng.permutation(np.arange(1, n_blocks)))
+    named = []
+    for i, n in enumerate(lens):
+        pages = -(-(int(n) + s_w) // bs) if n else 0
+        tables[i, :pages] = [free.pop() for _ in range(pages)]
+        named.extend(tables[i, :pages])
+    clean_k = _rand(rng, n_blocks, bs, h, d)
+    clean_v = _rand(rng, n_blocks, bs, h, d)
+    poison = np.ones(n_blocks, bool)
+    poison[named] = False
+    assert poison[0] and poison.sum() > 5
+    k_pool, v_pool = clean_k.copy(), clean_v.copy()
+    k_pool[poison] = np.nan
+    v_pool[poison] = np.nan
+    q = jnp.asarray(_rand(rng, len(lengths), s_w, h, d))
+    live = lens > 0
+    ref = np.asarray(paged_attention(q, clean_k, clean_v, tables, lens,
+                                     impl="lax"))
+    got = np.asarray(paged_attention(q, jnp.asarray(k_pool),
+                                     jnp.asarray(v_pool), tables, lens,
+                                     impl="pallas", interpret=True))
+    assert np.isfinite(got[live]).all()
+    np.testing.assert_allclose(got[live], ref[live], rtol=1e-5, atol=1e-5)
 
 
 def test_paged_attention_rejects_unknown_impl():
