@@ -1,0 +1,28 @@
+"""100 x the least time the chip could take for the traced window's
+KDA decode state steps over the time they took, at the window's mean
+live rows per decode step (the cost is in costs_kda.py, where
+``kernel_roofline`` does not look).  The live rows come from the
+program's own counter: ``serving.kda_state_rw_bytes`` grows by 2 x
+state bytes x KDA layers x live rows a decode step."""
+
+from benchmarks import costs, costs_kda, reduce_trace
+
+
+def read(obs, params):
+    red = obs.get("reduction")
+    numbers = obs["numbers"]
+    moved = numbers.get("counters.serving.kda_state_rw_bytes")
+    steps = numbers.get("counters.serving.paged_decode_steps")
+    layers = costs_kda.kda_layers(obs["model"])
+    if red is None or not moved or not steps or not layers:
+        return None
+    match = reduce_trace.matcher(params["patterns"], "any")
+    took = red.seconds(match)
+    if not took:
+        return None
+    live_rows = moved / steps / layers / (
+        2 * costs_kda.state_bytes_per_row(obs["model"]))
+    least, bound = costs.min_seconds(
+        costs_kda.kda_state_step_cost(obs["model"], live_rows), obs["peaks"])
+    obs.setdefault("notes", {})["kda_state_step_cost"] = f"{bound}-bound"
+    return 100.0 * red.count(match) * least / took

@@ -76,7 +76,7 @@ class TransformerConfig:
     # shares).  The defaults above and below leave the MHA tree and
     # programs exactly as they are.
     attention: str = "mha"       # "mla": multi-head latent attention
-    q_lora_rank: int = 0         # MLA: query latent width
+    q_lora_rank: int = 0         # MLA: query latent width (0: q = W_q xn)
     kv_lora_rank: int = 0        # MLA: the cached K/V latent width
     qk_nope_head_dim: int = 0    # MLA: per-head score width without RoPE
     qk_rope_head_dim: int = 0    # MLA: RoPE'd score width, one key for all heads
@@ -100,14 +100,47 @@ class TransformerConfig:
     moe_d_ff: int = 0            # width of one routed (and one shared) expert
     moe_n_shared: int = 0        # shared experts, computed for every token
     moe_routed_scale: float = 1.0
+    # group-limited selection with a correction bias ("noaux_tc"): the
+    # router's outputs in moe_n_group groups, a group scored by its two
+    # largest biased scores, the picks taken inside the moe_topk_group
+    # best groups by biased score and weighed by the unbiased one
+    moe_n_group: int = 0         # 0: the picks range over all experts
+    moe_topk_group: int = 0
+    moe_router_bias: bool = False
+    # ---- a third block family, served only: ``attention="kda_mla"``,
+    # delta-rule linear attention (KDA, ops/kda.py) in most layers with
+    # its recurrent state in a slot of the cache manager, latent
+    # attention in the rest with its rows in the paged pool.  Layer i
+    # of THIS model is published layer ``layer_offset + i``, and a
+    # published layer j is MLA where (j + 1) % layer_group_size == 0,
+    # else KDA with n_heads heads of head_dim keys and values.
+    layer_group_size: int = 0
+    layer_offset: int = 0
+    kda_conv_size: int = 4       # causal depthwise convolution on q, k, v
+    kda_lower_bound: float = -5.0  # log decay = bound x sigmoid(.)
 
     @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
 
     @property
+    def hybrid(self) -> bool:
+        return self.attention == "kda_mla"
+
+    @property
     def latent(self) -> bool:
-        return self.attention == "mla"
+        """Whether the paged pool holds latent rows (and the family is
+        served only): all layers of "mla", the MLA layers of "kda_mla"."""
+        return self.attention in ("mla", "kda_mla")
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """Each layer's attention, in order."""
+        if not self.hybrid:
+            return (self.attention,) * self.n_layers
+        return tuple(
+            "mla" if (self.layer_offset + i + 1) % self.layer_group_size == 0
+            else "kda" for i in range(self.n_layers))
 
     def kv_pool_shapes(self, n_blocks: int, block_size: int) -> tuple:
         """Shapes of the paged cache's pools: K and V pages of
@@ -121,9 +154,31 @@ class TransformerConfig:
         whole pool into and out of every program)."""
         if self.latent:
             row = self.kv_lora_rank + self.qk_rope_head_dim
-            return ((self.n_layers, n_blocks, row, block_size),)
+            return ((self.layer_kinds.count("mla"), n_blocks, row,
+                     block_size),)
         return ((self.n_layers, n_blocks, block_size, self.n_heads,
                  self.head_dim),) * 2
+
+    def state_slot_shapes(self, n_slots: int) -> tuple:
+        """``(shape, dtype)`` of each array of per-sequence recurrent
+        state the cache manager keeps beside the pools, a sequence's
+        own at one slot of axis 1; none for a model without recurrent
+        layers.  KDA: the float32 state S^T ``[H, d_v, d_k]`` per layer
+        and slot, and the last ``kda_conv_size - 1`` inputs of the
+        short convolution (q, k, v side by side, as projected), a
+        slot's ``[W - 1, 3 H d]`` laid out as whole 128-lane rows where
+        they divide it: a slot is then whole tiles and the decode
+        program's write of the live rows' slots is in place (with 3
+        rows a slot the chip's compiler re-tiled the whole array into
+        and out of every step)."""
+        n_kda = self.layer_kinds.count("kda")
+        if not n_kda:
+            return ()
+        h, d = self.n_heads, self.head_dim
+        tail = (self.kda_conv_size - 1) * 3 * h * d
+        lanes = 128 if tail % 128 == 0 else tail
+        return (((n_kda, n_slots, h, d, d), "float32"),
+                ((n_kda, n_slots, tail // lanes, lanes), self.dtype))
 
 
 def flagship_config() -> TransformerConfig:
@@ -157,22 +212,29 @@ def count_params(cfg: TransformerConfig) -> int:
 
 def _latent_forward_flops(cfg: TransformerConfig, t: int,
                           causal: bool) -> float:
-    """Forward matmul FLOPs one token needs under the latent block at
-    context ``t``: the five MLA projections, scores at qk and values at
-    v width, the dense or the held-expert FFN (a token's k picks land
-    here in the held share of the router's outputs), the unembed."""
+    """Forward FLOPs one token needs under the latent block at context
+    ``t``: the MLA projections (the query's through its latent or
+    direct), scores at qk and values at v width, the dense or the
+    held-expert FFN (a token's k picks land here in the held share of
+    the router's outputs), the unembed.  A KDA layer of the hybrid
+    family instead: its five full projections, beta and the gate, and
+    about 6 operations a state element whatever the context."""
     e, h = cfg.d_model, cfg.n_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    mla = (e * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
-           + e * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+    q_proj = (e * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
+              if cfg.q_lora_rank else e * h * qk)
+    mla = (q_proj + e * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
            + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
            + h * cfg.v_head_dim * e)
     attn = (1 if causal else 2) * t * h * (qk + cfg.v_head_dim)
+    kda = 2 * (5 * e * h * cfg.head_dim + 2 * e * h) \
+        + 6 * h * cfg.head_dim * cfg.head_dim
     routed = cfg.moe_n_routed or cfg.n_experts
     expert = 3 * e * cfg.moe_d_ff * (
         cfg.moe_topk * cfg.n_experts / routed + cfg.moe_n_shared) + e * routed
     n_moe = cfg.n_layers - cfg.n_dense_layers
-    return (cfg.n_layers * (2 * mla + attn)
+    kinds = cfg.layer_kinds
+    return (kinds.count("mla") * (2 * mla + attn) + kinds.count("kda") * kda
             + cfg.n_dense_layers * 2 * 3 * e * cfg.d_ff
             + n_moe * 2 * expert + 2 * e * cfg.vocab)
 
@@ -204,6 +266,8 @@ def train_step_flops(cfg: TransformerConfig, batch: int, t: int,
 
 def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
     """Global (unsharded) parameter pytree; blocks stacked [S, L/S, ...]."""
+    if cfg.hybrid:
+        return _init_hybrid_params(key, cfg, n_stages)
     if cfg.latent:
         return _init_latent_params(key, cfg, n_stages)
     assert cfg.n_layers % n_stages == 0
@@ -280,6 +344,83 @@ def _init_latent_params(key, cfg: TransformerConfig, n_stages: int = 1):
             "gate": (e, routed),
             "w_in": (x, e, fm), "w_gate": (x, e, fm), "w_out": (x, fm, e),
             "s_in": (e, fs), "s_gate": (e, fs), "s_out": (fs, e)}),
+    }
+
+
+def _init_hybrid_params(key, cfg: TransformerConfig, n_stages: int = 1):
+    """The hybrid family's tree: attention and FFN in groups of their
+    own, because a layer's two halves vary independently.  ``kda``
+    stacked [n KDA layers, ...] and ``mla`` [n MLA layers, ...] hold
+    ``ln1`` and the attention weights, each in layer order; ``dense``
+    [n_dense_layers, ...] and ``blocks`` [1, n expert layers, ...] hold
+    ``ln2`` and the FFN as the latent tree's do, the router with its
+    float32 correction bias ``gate_bias``.  KDA: ``w_qkv`` (q, k, v
+    side by side), the convolution ``conv [width, 3 H d]``, the decay's
+    full-rank projection ``w_a`` with ``a_log [H]`` and ``dt_bias
+    [H, d]`` in float32 (seeded so that the decay spans its range:
+    exp(a_log) uniform in [0.5, 4], dt_bias uniform in [-3, 3]),
+    ``w_beta``, the head-wise output gate ``w_og``, the per-head output
+    norm ``o_norm`` and ``wo``.  MLA: the query projected directly
+    (``w_q``), then as the latent tree."""
+    kinds = cfg.layer_kinds
+    n_kda, n_mla = kinds.count("kda"), kinds.count("mla")
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    assert n_stages == 1 and cfg.moe_router == "sigmoid" \
+        and cfg.q_lora_rank == 0
+    e, h, d, x = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.n_experts
+    rkv = cfg.kv_lora_rank
+    nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    fm, fs = cfg.moe_d_ff, cfg.moe_n_shared * cfg.moe_d_ff
+    routed = cfg.moe_n_routed or x
+    keys = iter(jax.random.split(key, 40))
+
+    def norm(shape, dtype=cfg.jdtype):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * 0.02).astype(dtype)
+
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
+    def ones(*shape):
+        return jnp.ones(shape, cfg.jdtype)
+
+    blocks = {
+        "ln2": ones(1, n_moe, e), "gate": norm((1, n_moe, e, routed)),
+        "w_in": norm((1, n_moe, x, e, fm)),
+        "w_gate": norm((1, n_moe, x, e, fm)),
+        "w_out": norm((1, n_moe, x, fm, e)),
+        "s_in": norm((1, n_moe, e, fs)), "s_gate": norm((1, n_moe, e, fs)),
+        "s_out": norm((1, n_moe, fs, e))}
+    if cfg.moe_router_bias:
+        blocks["gate_bias"] = norm((1, n_moe, routed), jnp.float32)
+    return {
+        "embed": norm((cfg.vocab, e)),
+        "unembed": norm((e, cfg.vocab)),
+        "ln_f": ones(e),
+        "kda": {
+            "ln1": ones(n_kda, e),
+            "w_qkv": norm((n_kda, e, 3 * h * d)),
+            "conv": norm((n_kda, cfg.kda_conv_size, 3 * h * d)),
+            "w_a": norm((n_kda, e, h * d)),
+            "a_log": jnp.log(uniform((n_kda, h), 0.5, 4.0)),
+            "dt_bias": uniform((n_kda, h, d), -3.0, 3.0),
+            "w_beta": norm((n_kda, e, h)),
+            "w_og": norm((n_kda, e, h)),
+            "o_norm": ones(n_kda, d),
+            "wo": norm((n_kda, h, d, e))},
+        "mla": {
+            "ln1": ones(n_mla, e),
+            "w_q": norm((n_mla, e, h, nope + pe)),
+            "w_kva": norm((n_mla, e, rkv + pe)),
+            "kv_norm": ones(n_mla, rkv),
+            "w_kvb": norm((n_mla, rkv, h, nope + dv)),
+            "wo": norm((n_mla, h, dv, e))},
+        "dense": {
+            "ln2": ones(cfg.n_dense_layers, e),
+            "w_in": norm((cfg.n_dense_layers, e, cfg.d_ff)),
+            "w_gate": norm((cfg.n_dense_layers, e, cfg.d_ff)),
+            "w_out": norm((cfg.n_dense_layers, cfg.d_ff, e))},
+        "blocks": blocks,
     }
 
 
@@ -829,8 +970,11 @@ def _mla_project(xn, p, positions, cfg: TransformerConfig):
     what the cache holds per token and layer."""
     rkv, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     inv_freq = _yarn_inv_freq(cfg)
-    c_q = rms_norm(jnp.einsum("bte,er->btr", xn, p["w_qa"]), p["q_norm"])
-    q = jnp.einsum("btr,rhd->bthd", c_q, p["w_qb"])
+    if cfg.q_lora_rank:
+        c_q = rms_norm(jnp.einsum("bte,er->btr", xn, p["w_qa"]), p["q_norm"])
+        q = jnp.einsum("btr,rhd->bthd", c_q, p["w_qb"])
+    else:
+        q = jnp.einsum("bte,ehd->bthd", xn, p["w_q"])
     kva = jnp.einsum("bte,er->btr", xn, p["w_kva"])
     c_kv = rms_norm(kva[..., :rkv], p["kv_norm"])
     k_pe = _rope_rows(kva[..., rkv:], positions, inv_freq)
@@ -872,13 +1016,33 @@ def _mla_absorbed_output(o_lat, p, cfg: TransformerConfig):
     return jnp.einsum("bshd,hde->bse", o, p["wo"])
 
 
+def _group_limited_top_k(scores, bias, cfg: TransformerConfig):
+    """Group-limited selection with a correction bias: ``scores`` [n,
+    moe_n_routed] float32 in ``moe_n_group`` groups of neighbours (a
+    group is what one device of the deployment holds); selection runs on
+    ``scores + bias``: a group's score is the sum of its two largest,
+    the ``moe_topk_group`` best groups stay and the ``moe_topk`` largest
+    inside them are the picks.  Returns ``(the picks' UNBIASED scores
+    [n, k], their experts [n, k])``: the bias steers load, not weight."""
+    n, routed = scores.shape
+    biased = scores if bias is None else scores + bias.astype(jnp.float32)
+    groups = biased.reshape(n, cfg.moe_n_group, routed // cfg.moe_n_group)
+    group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+    kept = lax.top_k(group_score, cfg.moe_topk_group)[1]         # [n, kg]
+    keep = jnp.any(kept[:, :, None] == jnp.arange(cfg.moe_n_group), axis=1)
+    inside = jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(n, routed)
+    top_i = lax.top_k(inside, cfg.moe_topk)[1]
+    return jnp.take_along_axis(scores, top_i, axis=-1), top_i
+
+
 def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
                   first_group: int = 0):
     """Sigmoid-routed experts of which this chip holds a share, with
     the shared expert: dropless.
 
     x [B, T, E].  The router scores all ``moe_n_routed`` experts in
-    float32, picks the ``moe_topk`` largest and weighs them
+    float32, picks the ``moe_topk`` largest (or, with ``moe_n_group``,
+    :func:`_group_limited_top_k`'s) and weighs them
     ``moe_routed_scale * s_i / (sum of the picked s + 1e-20)`` (the
     normaliser is over all picks, held or not).  The (token, pick)
     pairs whose expert is held here, [moe_held_start, + n_experts), are
@@ -910,7 +1074,10 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     scores = jax.nn.sigmoid(jnp.einsum(
         "ne,ex->nx", xf, p["gate"], preferred_element_type=jnp.float32,
         precision=lax.Precision.HIGHEST))
-    top_s, top_i = lax.top_k(scores, k)                          # [n, k]
+    if cfg.moe_n_group:
+        top_s, top_i = _group_limited_top_k(scores, p.get("gate_bias"), cfg)
+    else:
+        top_s, top_i = lax.top_k(scores, k)                      # [n, k]
     weight = cfg.moe_routed_scale * top_s / (
         jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
 
@@ -973,9 +1140,9 @@ def _latent_layers(params):
     routed experts stay ONE stack [S * L/S * X, ...] with the layer's
     own at ``first_group`` (see :func:`_moe_held_ffn`)."""
     dense, blocks = params["dense"], params["blocks"]
-    for i in range(dense["ln1"].shape[0]):
+    for i in range(dense["ln2"].shape[0]):
         yield jax.tree.map(lambda a: a[i], dense), None
-    n_stages, lps = blocks["ln1"].shape[:2]
+    n_stages, lps = blocks["ln2"].shape[:2]
     stacks = {name: blocks[name].reshape((-1,) + blocks[name].shape[3:])
               for name in _EXPERT_STACKS}
     n_held = blocks["w_in"].shape[2]
@@ -1000,6 +1167,14 @@ def _latent_ffn(x, p, first_group, cfg: TransformerConfig, valid, counts):
     return x + y
 
 
+def _write_prompt_rows(pool, layer: int, block_ids, row):
+    """A whole prompt's latent rows ``row`` [1, T, r] into pages
+    ``block_ids`` of one layer, each page ``[r, slot]``."""
+    bs = pool.shape[3]
+    return pool.at[layer, block_ids].set(jnp.swapaxes(
+        row.reshape(-1, bs, row.shape[-1]), 1, 2).astype(pool.dtype))
+
+
 def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
                               cfg: TransformerConfig):
     """:func:`forward_prefill_paged` of the latent family: prefill of
@@ -1013,7 +1188,6 @@ def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
     pool donated and updated in place, and the routing counts of the
     prompt's real tokens (see :func:`_moe_held_ffn`)."""
     _, t = ids.shape
-    bs = pool.shape[3]
     positions = jnp.arange(t)[None]
     valid = positions <= last_index[:, None]
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
@@ -1023,8 +1197,7 @@ def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
             y, row = _mla_prefill_attention(rms_norm(x, p["ln1"]), p,
                                             positions, cfg)
             x = x + y
-            pool = pool.at[li, block_ids].set(jnp.swapaxes(
-                row.reshape(t // bs, bs, -1), 1, 2).astype(pool.dtype))
+            pool = _write_prompt_rows(pool, li, block_ids, row)
         x = _latent_ffn(x, p, first_group, cfg, valid, counts)
     x = rms_norm(x, params["ln_f"])
     return _logits_at(params, x, last_index), pool, jnp.stack(counts)
@@ -1047,6 +1220,38 @@ def _write_latent_rows(pool, layer: int, blocks, slots, row):
     return pool
 
 
+def _latent_window(pool, block_tables, lengths, s_w: int):
+    """Where a decode window's latent rows go: ``(blocks [B, S], slots
+    [B, S])``, positions ``lengths[b] + s`` through the block table; a
+    dead row (length 0) gets a block past the pool, which drops its
+    write."""
+    n_blocks, bs = pool.shape[1], pool.shape[3]
+    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]
+    wb = jnp.take_along_axis(
+        block_tables, jnp.clip(pos_w // bs, 0, block_tables.shape[1] - 1),
+        axis=1)
+    return jnp.where(lengths[:, None] > 0, wb, n_blocks), pos_w % bs
+
+
+def _mla_decode_attention(xn, p, positions, pool, li: int, wb, ws,
+                          block_tables, lengths, cfg: TransformerConfig):
+    """One layer's absorbed latent attention of a decode window: the
+    window's rows into pool layer ``li``, then the rows themselves
+    attended.  Returns ``(attention's addend [B, S, E], pool)``."""
+    from ..ops import paged_attention as _paged
+
+    q_nope, q_pe, row = _mla_project(xn, p, positions, cfg)
+    pool = _write_latent_rows(pool, li, wb, ws, row)
+    # the layers' pools as one run of pages: a per-layer slice of the
+    # pool would be copied for the kernel
+    o_lat = _paged.latent_paged_attention(
+        _mla_absorbed_queries(q_nope, q_pe, p, cfg),
+        pool.reshape((-1,) + pool.shape[2:]),
+        block_tables + li * pool.shape[1], lengths,
+        v_dim=cfg.kv_lora_rank, scale=_mla_scale(cfg))
+    return _mla_absorbed_output(o_lat, p, cfg), pool
+
+
 def forward_decode_paged_mla(params, ids, positions, pool, block_tables,
                              lengths, cfg: TransformerConfig):
     """:func:`forward_decode_paged` of the latent family, absorbed:
@@ -1056,37 +1261,211 @@ def forward_decode_paged_mla(params, ids, positions, pool, block_tables,
     :func:`ops.paged_attention.latent_paged_attention`, for all heads
     from the one page.  Returns ``(logits [B, S, V], pool, moe)``; dead
     rows (length 0) scatter out of bounds and are not counted."""
-    from ..ops import paged_attention as _paged
-
     b, s_w = ids.shape
-    n_blocks, bs = pool.shape[1], pool.shape[3]
-    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]
-    wb = jnp.take_along_axis(
-        block_tables, jnp.clip(pos_w // bs, 0, block_tables.shape[1] - 1),
-        axis=1)
-    wb = jnp.where(lengths[:, None] > 0, wb, n_blocks)           # OOB-drop
-    ws = pos_w % bs
+    wb, ws = _latent_window(pool, block_tables, lengths, s_w)
     valid = jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
     counts = []
     for li, (p, first_group) in enumerate(_latent_layers(params)):
         with jax.named_scope("mla"):
-            q_nope, q_pe, row = _mla_project(rms_norm(x, p["ln1"]), p,
-                                             positions, cfg)
-            pool = _write_latent_rows(pool, li, wb, ws, row)
-            # the layers' pools as one run of pages: a per-layer slice
-            # of the pool would be copied for the kernel
-            o_lat = _paged.latent_paged_attention(
-                _mla_absorbed_queries(q_nope, q_pe, p, cfg),
-                pool.reshape((-1,) + pool.shape[2:]),
-                block_tables + li * n_blocks, lengths,
-                v_dim=cfg.kv_lora_rank, scale=_mla_scale(cfg))
-            x = x + _mla_absorbed_output(o_lat, p, cfg)
+            y, pool = _mla_decode_attention(
+                rms_norm(x, p["ln1"]), p, positions, pool, li, wb, ws,
+                block_tables, lengths, cfg)
+            x = x + y
         x = _latent_ffn(x, p, first_group, cfg, valid, counts)
     with jax.named_scope("unembed"):
         x = rms_norm(x, params["ln_f"])
         logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
     return logits, pool, jnp.stack(counts)
+
+
+# ---------------------------------------------------------------------------
+# the hybrid family on the serving path: KDA (ops/kda.py) in most
+# layers, its per-sequence state in a slot of the cache manager, MLA in
+# the rest with its rows in the paged pool; the latent family's FFNs,
+# the router group-limited.  Paged path only, one token a decode step.
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_layers(params, cfg: TransformerConfig):
+    """Yields ``(kind, i, attention params, ffn params, first_group)``
+    in layer order: layer ``i`` of its kind's group (``params["kda"]``
+    or ``["mla"]``, which is also its index into the state slots or the
+    pool), and the FFN half as :func:`_latent_layers` gives it."""
+    seen = {"kda": 0, "mla": 0}
+    for kind, (ffn, first_group) in zip(cfg.layer_kinds,
+                                        _latent_layers(params)):
+        i = seen[kind]
+        seen[kind] += 1
+        yield (kind, i, jax.tree.map(lambda a: a[i], params[kind]), ffn,
+               first_group)
+
+
+def _kda_project(xn, p, cfg: TransformerConfig):
+    """The KDA projections of normed activations ``xn`` [B, T, E]:
+    ``(qkv [B, T, 3 H d]`` as projected, before the convolution, ``g
+    [B, T, H, d]`` the log decay in (kda_lower_bound, 0), ``beta
+    [B, T, H]``, ``gate [B, T, H])``, the last three in float32."""
+    f32 = {"preferred_element_type": jnp.float32}
+    qkv = jnp.einsum("bte,ef->btf", xn, p["w_qkv"])
+    a = jnp.einsum("bte,ef->btf", xn, p["w_a"], **f32).reshape(
+        xn.shape[:2] + p["dt_bias"].shape)
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(p["a_log"])[:, None] * (a + p["dt_bias"]))
+    beta = jax.nn.sigmoid(jnp.einsum("bte,eh->bth", xn, p["w_beta"], **f32))
+    gate = jax.nn.sigmoid(jnp.einsum("bte,eh->bth", xn, p["w_og"], **f32))
+    return qkv, g, beta, gate
+
+
+def _kda_qkv(conv_out, cfg: TransformerConfig):
+    """The convolution's output [..., 3 H d] (float32) through SiLU,
+    split by head, q and k L2-normalised and q scaled by d^-1/2."""
+    h, d = cfg.n_heads, cfg.head_dim
+    y = jax.nn.silu(conv_out).reshape(conv_out.shape[:-1] + (3, h, d))
+    q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
+
+    def unit(a):
+        return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * d ** -0.5, unit(k), v
+
+
+def _kda_output(o, gate, p, cfg: TransformerConfig):
+    """o [B, T, H, d] float32: RMSNorm per head, the head-wise gate,
+    the output projection."""
+    o = rms_norm(o, p["o_norm"].astype(jnp.float32)) * gate[..., None]
+    return jnp.einsum("bthd,hde->bte", o.astype(cfg.jdtype), p["wo"])
+
+
+def _kda_prefill_attention(xn, p, valid, last_index, cfg: TransformerConfig):
+    """KDA over ONE whole sequence ``xn`` [1, T, E] from a zero state.
+    ``valid`` [1, T] marks the prompt's real tokens: a padded position
+    neither decays nor writes the state (beta = 0, g = 0), and the
+    convolution's tail is taken at ``last_index`` [1].  Returns
+    ``(attention's addend [1, T, E], S^T [H, d, d], tail [W - 1,
+    3 H d])``."""
+    from ..ops import kda as _kda
+
+    w = cfg.kda_conv_size
+    qkv, g, beta, gate = _kda_project(xn, p, cfg)
+    t = qkv.shape[1]
+    with jax.named_scope("conv"):
+        padded = jnp.pad(qkv, ((0, 0), (w - 1, 0), (0, 0)))
+        conv = p["conv"].astype(jnp.float32)
+        y = sum(conv[j] * padded[:, j:j + t] for j in range(w))
+        # the tail is projected again from its own w - 1 tokens: cut
+        # out of ``qkv``, the slice was scheduled with the slot's write
+        # at the program's end and kept every KDA layer's projection
+        # alive until then (2.1 GB at 8k tokens)
+        at = last_index[0] - (w - 2) + jnp.arange(w - 1)
+        tail = jnp.where(
+            (at >= 0)[:, None],
+            jnp.einsum("te,ef->tf", jnp.take(xn[0], jnp.maximum(at, 0), 0),
+                       p["w_qkv"]), 0)
+    q, k, v = _kda_qkv(y, cfg)
+    with jax.named_scope("chunk_scan"):
+        o, s_t = _kda.kda_chunk_scan(
+            q[0], k[0], v[0], jnp.where(valid[0, :, None, None], g[0], 0.0),
+            jnp.where(valid[0, :, None], beta[0], 0.0))
+    return _kda_output(o[None], gate, p, cfg), s_t, tail
+
+
+def forward_prefill_paged_hybrid(params, ids, last_index, pool, state, tails,
+                                 block_ids, slot, cfg: TransformerConfig):
+    """:func:`forward_prefill_paged_mla` of the hybrid family: prefill
+    of ONE sequence that leaves its MLA layers' latent rows in the
+    paged pool (``pool [n MLA layers, n_blocks, row, block_size]``,
+    blocks ``block_ids``) and each KDA layer's final state and
+    convolution tail in the sequence's ``slot`` [1] of ``state`` / ``tails``
+    (``TransformerConfig.state_slot_shapes``), all three donated and
+    updated in place.  KDA runs chunk-wise (``ops.kda.kda_chunk_scan``);
+    padding does not touch the state.  Returns ``(logits [1, V], pool,
+    state, tails, moe)``."""
+    _, t = ids.shape
+    positions = jnp.arange(t)[None]
+    valid = positions <= last_index[:, None]
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
+    counts = []
+    for kind, i, p, ffn, first_group in _hybrid_layers(params, cfg):
+        xn = rms_norm(x, p["ln1"])
+        if kind == "mla":
+            with jax.named_scope("mla"):
+                y, row = _mla_prefill_attention(xn, p, positions, cfg)
+                pool = _write_prompt_rows(pool, i, block_ids, row)
+        else:
+            with jax.named_scope("kda"):
+                y, s_t, tail = _kda_prefill_attention(xn, p, valid,
+                                                      last_index, cfg)
+                state = state.at[i, slot[0]].set(s_t)
+                tails = tails.at[i, slot[0]].set(
+                    tail.reshape(tails.shape[2:]).astype(tails.dtype))
+        x = _latent_ffn(x + y, ffn, first_group, cfg, valid, counts)
+    x = rms_norm(x, params["ln_f"])
+    return (_logits_at(params, x, last_index), pool, state, tails,
+            jnp.stack(counts))
+
+
+def _kda_decode_attention(xn, p, state, tails, li: int, slots, live,
+                          cfg: TransformerConfig):
+    """One KDA layer of one decode token a row: the convolution over
+    the slot's tail and the new input, then ``ops.kda.kda_state_step``
+    on the row's state in place.  A dead row reads slot 0's tail, which
+    is harmless, and writes nothing.  Returns ``(attention's addend
+    [B, 1, E], state, tails)``."""
+    from ..ops import kda as _kda
+
+    n_slots = state.shape[1]
+    qkv, g, beta, gate = _kda_project(xn, p, cfg)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate(
+            [tails[li, slots].reshape(qkv.shape[0], -1, qkv.shape[-1]), qkv],
+            axis=1)
+        y = jnp.sum(window * p["conv"].astype(jnp.float32), axis=1)
+        tails = tails.at[li, jnp.where(live, slots, n_slots)].set(
+            window[:, 1:].reshape((-1,) + tails.shape[2:]), mode="drop")
+    q, k, v = _kda_qkv(y, cfg)
+    with jax.named_scope("state_step"):
+        # every layer's slots as one run: a per-layer slice of the
+        # state would be copied for the kernel, 134 MB a layer
+        o, flat = _kda.kda_state_step(
+            q, k, v, g[:, 0], beta[:, 0],
+            state.reshape((-1,) + state.shape[2:]), slots + li * n_slots,
+            live)
+    return (_kda_output(o[:, None], gate, p, cfg), flat.reshape(state.shape),
+            tails)
+
+
+def forward_decode_paged_hybrid(params, ids, positions, pool, state, tails,
+                                block_tables, lengths, slots,
+                                cfg: TransformerConfig):
+    """:func:`forward_decode_paged_mla` of the hybrid family, one token
+    a row (``ids`` [B, 1]: recurrent state has no rollback, so there is
+    no verify window).  ``slots`` [B] is each row's state slot beside
+    its block table; a dead row (length 0) writes neither pool nor
+    state.  Returns ``(logits [B, 1, V], pool, state, tails, moe)``."""
+    b, s_w = ids.shape
+    assert s_w == 1, "recurrent layers decode one token a step"
+    live = lengths > 0
+    wb, ws = _latent_window(pool, block_tables, lengths, s_w)
+    valid = live[:, None]
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
+    counts = []
+    for kind, i, p, ffn, first_group in _hybrid_layers(params, cfg):
+        xn = rms_norm(x, p["ln1"])
+        if kind == "mla":
+            with jax.named_scope("mla"):
+                y, pool = _mla_decode_attention(
+                    xn, p, positions, pool, i, wb, ws, block_tables,
+                    lengths, cfg)
+        else:
+            with jax.named_scope("kda"):
+                y, state, tails = _kda_decode_attention(
+                    xn, p, state, tails, i, slots, live, cfg)
+        x = _latent_ffn(x + y, ffn, first_group, cfg, valid, counts)
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["ln_f"])
+        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
+    return logits, pool, state, tails, jnp.stack(counts)
 
 
 def make_train_step(mesh, cfg: TransformerConfig, optimizer=None,

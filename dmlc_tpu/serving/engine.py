@@ -165,7 +165,12 @@ _MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held",
                  "moe_expert_load_max", "moe_expert_load_mean")
 
 
-def _jitted_programs(latent: bool = False):
+#: what a model with recurrent layers adds: slots handed out (the cache
+#: counts them) and the state bytes the decode steps read and wrote
+_STATE_COUNTERS = ("state_slot_allocs", "kda_state_rw_bytes")
+
+
+def _jitted_programs(family: str = "mha"):
     """Process-wide jitted prefill/decode (one jit wrapper per program,
     so every engine instance shares one compile cache — tests and
     smokes build several engines and must not pay XLA again for
@@ -180,9 +185,12 @@ def _jitted_programs(latent: bool = False):
     the window is a shape; its pools are DONATED like the prefill's, so
     a step scatters into them in place, and whoever calls the jitted
     program loses the arrays it passed in and goes on with the ones
-    returned.  A latent-attention model (``latent``) runs their twins
-    at the same two sites, ``forward_prefill_paged_mla`` /
-    ``forward_decode_paged_mla``, both donated their one pool.  All go
+    returned.  A latent-attention model (``family`` "mla") runs their
+    twins at the same two sites, ``forward_prefill_paged_mla`` /
+    ``forward_decode_paged_mla``, both donated their one pool; a model
+    with recurrent layers ("kda_mla") ``forward_prefill_paged_hybrid``
+    / ``forward_decode_paged_hybrid``, donated the pool and the two
+    state arrays that travel with it.  All go
     through :func:`telemetry.compute.profiled_jit`, which is plain
     ``jax.jit`` when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on
     that mode so toggling the knob between tests cannot hand a plain
@@ -192,7 +200,16 @@ def _jitted_programs(latent: bool = False):
     growth is a bug worth failing loudly on."""
     compute = telemetry.compute
     mode = "profiled" if compute.enabled() else "plain"
-    if latent:
+    if family == "kda_mla":
+        prefill_key = (mode, "prefill_paged_hybrid")
+        prefill_fn, prefill_kw = tfm.forward_prefill_paged_hybrid, {
+            "static_argnums": (8,), "donate_argnums": (3, 4, 5)}
+        decode_key = (mode, "decode_paged_hybrid")
+        builder = lambda cap: compute.profiled_jit(  # noqa: E731
+            tfm.forward_decode_paged_hybrid, site="serving.decode_paged",
+            static_argnums=(9,), donate_argnums=(3, 4, 5),
+            max_signatures=cap)
+    elif family == "mla":
         prefill_key = (mode, "prefill_paged_mla")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged_mla, {
             "static_argnums": (5,), "donate_argnums": (3,)}
@@ -281,7 +298,8 @@ class InferenceEngine:
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
             n_blocks=n_blocks, block_size=block_size,
             dtype=np.dtype(cfg.dtype),
-            pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size))
+            pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size),
+            state_shapes=cfg.state_slot_shapes(self.max_active))
         self.scheduler = ContinuousBatchScheduler(
             self.cache, max_active=self.max_active)
         depth = (queue_depth if queue_depth is not None
@@ -312,8 +330,21 @@ class InferenceEngine:
         self.spec_k = max(0, int(get_env("DMLC_SERVE_SPEC_K", 0)))
         self.spec_min_ctx = max(1, int(get_env("DMLC_SERVE_SPEC_MIN_CTX",
                                                4)))
+        if self.spec_k and self.cache.n_slots:
+            raise ValueError(
+                "DMLC_SERVE_SPEC_K > 0 with a model that has recurrent "
+                "layers: a rejected draft would need the state rolled "
+                "back, which the cache manager cannot do")
         self._spec_window = 1 + self.spec_k
-        self._prefill, self._decode = _jitted_programs(cfg.latent)
+        # bytes one live row's recurrent state costs a decode step:
+        # read once and written once in every layer that has one (the
+        # first of the state arrays; the convolution's tail is small)
+        self._state_rw_bytes = 0
+        if self.cache.n_slots:
+            shape, dt = self.cache.state_shapes[0]
+            self._state_rw_bytes = (
+                2 * int(np.prod(shape)) // shape[1] * dt.itemsize)
+        self._prefill, self._decode = _jitted_programs(cfg.attention)
         self._stop = threading.Event()
         self._draining = threading.Event()
         # iteration seqlock: odd = an engine iteration is mid-flight
@@ -475,7 +506,8 @@ class InferenceEngine:
             raise DMLCError("engine is closed")
         self._stop.clear()
         for name in _ZEROED_COUNTERS + (
-                _MOE_COUNTERS if self.cfg.moe_router == "sigmoid" else ()):
+                _MOE_COUNTERS if self.cfg.moe_router == "sigmoid" else ()
+                ) + (_STATE_COUNTERS if self.cache.n_slots else ()):
             telemetry.inc("serving", name, 0)
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="serving-engine")
@@ -750,7 +782,7 @@ class InferenceEngine:
                 logits, pools, moe = self._on_pools(
                     self._prefill, self.params, ids, last,
                     np.asarray(self.cache.block_table(req.id), np.int32),
-                    at=3)
+                    *self._slot_args([req.id]), at=3)
                 logits = np.asarray(logits[0])
                 moe = [np.asarray(m) for m in moe]
         telemetry.inc("serving", "prefill_d2h_bytes", logits.nbytes)
@@ -760,11 +792,19 @@ class InferenceEngine:
             self.cache.advance_many([(req.id, n)])
         return logits
 
+    def _slot_args(self, seq_ids, pad_batch=None) -> tuple:
+        """What a program of a model with recurrent layers takes after
+        the block tables: the sequences' state slots; nothing else."""
+        if not self.cache.n_slots:
+            return ()
+        return (self.cache.slot_ids(seq_ids, pad_batch),)
+
     def _on_pools(self, program, *args, at: int):
         """Call a paged program with the cache's pools spliced in at
         argument ``at`` and the config last; split what it returns into
-        ``(logits, pools, rest)``.  ``rest`` is empty for the MHA
-        programs and the routing counts for the latent family's."""
+        ``(logits, pools, rest)``.  The pools are the cache's device
+        arrays, recurrent state included; ``rest`` is empty for the
+        MHA programs and the routing counts for the other families'."""
         pools = self.cache.device_pools()
         out = program(*args[:at], *pools, *args[at:], self.cfg)
         return out[0], out[1:1 + len(pools)], out[1 + len(pools):]
@@ -907,7 +947,8 @@ class InferenceEngine:
 
     def _decode_inputs(self, active: List[Request]) -> tuple:
         """What the decode program takes from the host: ``(ids,
-        positions, drafts, tables, lengths, base_lens)``."""
+        positions, drafts, tables, lengths, base_lens, slots)``, the
+        last a tuple that is empty without recurrent state."""
         s_w = self._spec_window
         b = len(active)
         pad_b = self.max_active
@@ -931,11 +972,12 @@ class InferenceEngine:
                 ids[i, 1:1 + len(d)] = d
             drafts.append(d)
         positions[:b] = base_lens[:, None] + np.arange(s_w)
-        return ids, positions, drafts, tables, lengths, base_lens
+        return (ids, positions, drafts, tables, lengths, base_lens,
+                self._slot_args([r.id for r in active], pad_b))
 
     def _decode_step(self, active: List[Request], n_preempted: int,
                      ids, positions, drafts, tables, lengths,
-                     base_lens) -> None:
+                     base_lens, slots=()) -> None:
         s_w = self._spec_window
         b = len(active)
         compute = telemetry.compute
@@ -958,7 +1000,7 @@ class InferenceEngine:
             try:
                 logits, pools, moe = self._on_pools(
                     self._decode, self.params, ids, positions, tables,
-                    lengths, at=3)
+                    lengths, *slots, at=3)
             except Exception:
                 # the donated pools went with a call that failed after
                 # dispatch: the loop's requeue re-prefills into fresh
@@ -1093,6 +1135,9 @@ class InferenceEngine:
         telemetry.inc("serving", "decode_steps")
         telemetry.observe("serving", "decode_batch", b)
         telemetry.inc("serving", "paged_decode_steps")
+        if self._state_rw_bytes:
+            telemetry.inc("serving", "kda_state_rw_bytes",
+                          self._state_rw_bytes * b)
         if s_w > 1:
             telemetry.inc("serving", "spec_proposed", n_proposed)
             telemetry.inc("serving", "spec_accepted", n_accepted)

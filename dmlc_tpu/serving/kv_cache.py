@@ -19,6 +19,17 @@ latent attention ONE pool of rows ``[rms(c_kv) | rope(k_pe)]`` with no
 head axis and no separate V, ``[n_layers, n_blocks, 576, block_size]``.
 The allocator, block tables, lengths and ``stats()`` do not care.
 
+A model with recurrent layers keeps a second kind of state, fixed in
+size per sequence (``state_shapes``, from
+``TransformerConfig.state_slot_shapes``): arrays ``[layers, n_slots,
+...]`` of which a sequence owns ONE slot, taken with its blocks at
+:meth:`PagedKVCache.allocate` and returned with them at
+:meth:`PagedKVCache.free`, so admission is bounded by free slots as
+well as free blocks.  The arrays travel with the pools: the prefill
+program writes a sequence's slot, the decode program updates the live
+rows' slots in place (:meth:`PagedKVCache.slot_ids` beside the block
+tables), both donated and adopted alike.
+
 The bytes live in ONE place: the pools are device arrays and they ARE
 the cache.  Device programs write them in place
 (``models.forward_prefill_paged`` scatters a prompt's K/V into its
@@ -112,11 +123,12 @@ class BlockAllocator:
 
 
 class _SeqEntry:
-    __slots__ = ("blocks", "length")
+    __slots__ = ("blocks", "length", "slot")
 
     def __init__(self) -> None:
         self.blocks: List[int] = []
         self.length = 0
+        self.slot: Optional[int] = None  # recurrent-state slot, if any
 
 
 class PagedKVCache:
@@ -126,13 +138,16 @@ class PagedKVCache:
     ``pool_shapes`` does (one shape per pool; the default is K and V
     pools of ``[n_layers, n_blocks, block_size, n_heads, head_dim]``).
     ``n_blocks × block_size`` is the total token capacity shared by all
-    concurrent requests.  The bytes are device arrays that device
-    programs write (module docstring).
+    concurrent requests.  ``state_shapes`` is ``((shape, dtype), ...)``
+    of the recurrent-state arrays, axis 1 the slots (default none).  The
+    bytes are device arrays that device programs write (module
+    docstring).
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int, *,
                  n_blocks: int = 256, block_size: int = 16,
-                 dtype=np.float32, pool_shapes: Optional[tuple] = None):
+                 dtype=np.float32, pool_shapes: Optional[tuple] = None,
+                 state_shapes: tuple = ()):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.n_layers = int(n_layers)
@@ -145,6 +160,12 @@ class PagedKVCache:
                            self.n_heads, self.head_dim)
         self.pool_shapes = (self.pool_shape,) * 2 if pool_shapes is None \
             else tuple(tuple(int(d) for d in shape) for shape in pool_shapes)
+        self.state_shapes = tuple(
+            (tuple(int(d) for d in shape), np.dtype(dt))
+            for shape, dt in state_shapes)
+        self.n_slots = self.state_shapes[0][0][1] if self.state_shapes else 0
+        # pop() from the tail: ascending slots first, as the blocks
+        self._free_slots: List[int] = list(range(self.n_slots - 1, -1, -1))
         # the pools, made as zeros ON the device by the first
         # device_pools() and replaced by whatever the last prefill /
         # decode program returned
@@ -168,6 +189,8 @@ class PagedKVCache:
         self._cached_tokens = 0
         self._lock = make_lock("PagedKVCache._lock")
         telemetry.set_gauge("serving", "kv_blocks_total", self.n_blocks)
+        if self.n_slots:
+            telemetry.set_gauge("serving", "state_slots_total", self.n_slots)
         self._publish_usage()
 
     # ---- capacity arithmetic -------------------------------------------
@@ -183,7 +206,16 @@ class PagedKVCache:
     def n_blocks_in_use(self) -> int:
         return self._alloc.n_in_use
 
+    @property
+    def n_free_slots(self) -> int:
+        with self._lock:
+            return len(self._free_slots)
+
     def can_reserve(self, n_tokens: int) -> bool:
+        """Whether a NEW sequence of ``n_tokens`` fits now: its blocks
+        and, where sequences carry recurrent state, a slot."""
+        if self.n_slots and not self.n_free_slots:
+            return False
         return self.blocks_for(n_tokens) <= self._alloc.n_free
 
     def fits_at_all(self, n_tokens: int) -> bool:
@@ -198,12 +230,17 @@ class PagedKVCache:
         with self._lock:
             if seq_id in self._seqs:
                 raise DMLCError(f"sequence {seq_id} already allocated")
-            got = self._alloc.alloc_many(self.blocks_for(n_tokens))
+            got = None
+            if not self.n_slots or self._free_slots:
+                got = self._alloc.alloc_many(self.blocks_for(n_tokens))
             if got is None:
                 telemetry.inc("serving", "kv_alloc_failures")
                 return False
             ent = _SeqEntry()
             ent.blocks = got
+            if self.n_slots:
+                ent.slot = self._free_slots.pop()
+                telemetry.inc("serving", "state_slot_allocs")
             self._seqs[seq_id] = ent
             self._tables_version += 1
         self._publish_usage()
@@ -266,6 +303,8 @@ class PagedKVCache:
                 return
             self._cached_tokens -= ent.length
             self._alloc.free(ent.blocks)
+            if ent.slot is not None:
+                self._free_slots.append(ent.slot)
             self._tables_version += 1
         self._publish_usage()
 
@@ -276,6 +315,16 @@ class PagedKVCache:
     def block_table(self, seq_id: int) -> List[int]:
         with self._lock:
             return list(self._seq(seq_id).blocks)
+
+    def slot_ids(self, seq_ids: Sequence[int],
+                 pad_batch: Optional[int] = None) -> np.ndarray:
+        """Each sequence's recurrent-state slot as int32 ``[B]``, rows
+        past ``seq_ids`` padded with 0 (dead rows: their length 0 keeps
+        the decode program from touching the slot)."""
+        out = np.zeros(max(pad_batch or 0, len(seq_ids)), np.int32)
+        with self._lock:
+            out[:len(seq_ids)] = [self._seq(s).slot for s in seq_ids]
+        return out
 
     def live_sequences(self) -> List[int]:
         with self._lock:
@@ -304,20 +353,23 @@ class PagedKVCache:
     # ---- data plane ------------------------------------------------------
     def device_pools(self) -> tuple:
         """The device arrays that are the cache itself, one per entry
-        of ``pool_shapes``: ``(k_pool, v_pool)``, or the one latent pool.
-        Made as zeros on the device at first use (nothing is uploaded);
-        afterwards whatever :meth:`adopt_device_pools` installed last."""
+        of ``pool_shapes``: ``(k_pool, v_pool)``, or the one latent pool,
+        then one per entry of ``state_shapes``.  Made as zeros on the
+        device at first use (nothing is uploaded); afterwards whatever
+        :meth:`adopt_device_pools` installed last."""
         if self._dev is None:
             import jax.numpy as jnp
 
-            self._dev = tuple(jnp.zeros(shape, self.dtype)
-                              for shape in self.pool_shapes)
+            self._dev = tuple(
+                [jnp.zeros(shape, self.dtype) for shape in self.pool_shapes]
+                + [jnp.zeros(shape, dt) for shape, dt in self.state_shapes])
         return self._dev
 
     def adopt_device_pools(self, *pools) -> None:
         """Install the pools a prefill or decode program returned (its
         in-program scatter made them the cache)."""
-        assert len(pools) == len(self.pool_shapes), len(pools)
+        assert len(pools) == len(self.pool_shapes) + len(
+            self.state_shapes), len(pools)
         self._dev = tuple(pools)
 
     def drop_lost_pools(self) -> bool:
@@ -398,6 +450,7 @@ class PagedKVCache:
             live = len(self._seqs)
             tokens = self._cached_tokens
             in_use = self._alloc.n_in_use
+            slots_in_use = self.n_slots - len(self._free_slots)
         # occupancy: pool pressure the admission test acts on; waste:
         # allocated-but-unfilled token slots (final partial blocks +
         # reserve-ahead) — the paged layout's only fragmentation, so a
@@ -412,12 +465,18 @@ class PagedKVCache:
             "cached_tokens": tokens,
             "occupancy": in_use / self.n_blocks,
             "waste_tokens": in_use * self.block_size - tokens,
+            "state_slots": self.n_slots,
+            "state_slots_in_use": slots_in_use,
         }
 
     def _publish_usage(self) -> None:
         with self._lock:
             in_use = self._alloc.n_in_use
             tokens = self._cached_tokens
+            slots_in_use = self.n_slots - len(self._free_slots)
+        if self.n_slots:
+            telemetry.set_gauge("serving", "state_slots_in_use",
+                                slots_in_use)
         telemetry.set_gauge("serving", "kv_blocks_in_use", in_use)
         telemetry.set_gauge("serving", "kv_occupancy_pct",
                             100.0 * in_use / self.n_blocks)

@@ -19,6 +19,10 @@ class: priority encodes who pays for KV pressure (a background batch
 request is recomputed before an interactive one is ever touched), and
 youngest-within-class minimizes wasted recompute and cannot starve —
 the oldest request of the highest class only ever gains blocks.
+Where sequences also carry recurrent state (a slot of the cache
+manager beside their blocks), eviction frees the slot with the blocks
+and the re-prefill rebuilds the state from the context the same way;
+admission (``cache.can_reserve``) then needs a free slot as well.
 
 Priority also orders admission: ``next_prefill`` serves the
 highest-priority waiting request first (FIFO within a class, preserved

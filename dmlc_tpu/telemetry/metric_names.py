@@ -418,6 +418,12 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_moe_pairs_held",
     "dmlc_serving_moe_expert_load_max",
     "dmlc_serving_moe_expert_load_mean",
+    # recurrent state in the cache manager (hybrid family): slots
+    # handed out, and the state bytes the decode steps read and wrote
+    "dmlc_serving_state_slot_allocs",
+    "dmlc_serving_kda_state_rw_bytes",
+    "dmlc_serving_state_slots_in_use",
+    "dmlc_serving_state_slots_total",
 })
 
 #: span / jax-profiler annotation names that look like metric tokens in

@@ -162,6 +162,48 @@ def test_paged_attention_compiles_to_one_named_op(one_chip):
     assert "custom-call(" in named[0] and "tpu_custom_call" in named[0]
 
 
+def test_kda_state_step_compiles_and_updates_the_state_in_place(one_chip):
+    """Compiled for the chip inside a layer loop, at the reasoning
+    cell's shapes (64 rows, 32 heads of 128 x 128, all 11 KDA layers'
+    slots as one run, donated): Mosaic takes the kernel, the state
+    passes from call to call as the same buffer, and no instruction of
+    the program copies or slices an array of the state's size."""
+    import re
+
+    from dmlc_tpu.ops import kda
+
+    b, h, d, layers, n_slots = 64, 32, 128, 11, 64
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    vec = arr((b, h, d), jnp.float32)
+
+    def two_layers(q, k, v, g, beta, state, slots, live):
+        flat = state.reshape((-1,) + state.shape[2:])
+        o = 0.0
+        for li in (3, 7):
+            o_li, flat = kda.kda_state_step(q, k, v, g, beta, flat,
+                                            slots + li * n_slots, live)
+            o = o + o_li
+        return o, flat.reshape(state.shape)
+
+    with dispatch.force_kernel_mode(dispatch.MOSAIC):
+        compiled = jax.jit(two_layers, donate_argnums=(5,)).lower(
+            vec, vec, vec, vec, arr((b, h), jnp.float32),
+            arr((layers, n_slots, h, d, d), jnp.float32),
+            arr((b,), jnp.int32), arr((b,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "kda_state_step" in line]
+    assert len(calls) == 2 and all("tpu_custom_call" in c for c in calls)
+    state_sized = re.compile(r"= f32\[(704|11,64),?32,128,128\]\S* (\w[\w-]*)\(")
+    ops = {m.group(2) for m in map(state_sized.search, hlo.splitlines())
+           if m}
+    assert ops <= {"bitcast", "get-tuple-element", "parameter"}, ops
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= layers * n_slots * h * d * d * 4
+    assert m.temp_size_in_bytes < 64 * 2 ** 20
+
+
 def test_dispatch_is_one_flippable_function():
     counts = lambda: telemetry.counters_snapshot().get("kernels", {})  # noqa: E731
     assert dispatch.kernel_mode() == dispatch.LAX  # tier-1 is CPU
