@@ -24,6 +24,7 @@ standard static-shape trade).  Both combine with one psum over (ep, tp).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -899,6 +900,56 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
         x = rms_norm(x, params["ln_f"])
         logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
     return logits, k_pool, v_pool
+
+
+# ---------------------------------------------------------------------------
+# what the serving engine jits: a paged forward of any family with the
+# greedy pick as its epilogue, so the logits never leave the device
+# ---------------------------------------------------------------------------
+
+def greedy_pick(logits):
+    """The serving programs' epilogue, one for every family:
+    ``(ids, finite)`` over ``logits [..., V]`` in the dtype they have.
+    ``ids`` int32 is ``np.argmax``'s pick (the first index of the
+    maximum; a row with a NaN lands on its first NaN); ``finite`` says
+    whether the picked logit is finite, which it is not in a row with a
+    NaN or a +inf, nor in one that is all -inf."""
+    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    picked = jnp.take_along_axis(logits, ids[..., None], axis=-1)[..., 0]
+    return ids, jnp.isfinite(picked)
+
+
+def picking_prefill(forward):
+    """``forward_prefill_paged*`` as the engine runs it: same arguments,
+    ``(ids [1], finite [1], *rest)`` where ``forward`` returns
+    ``(logits [1, V], *rest)``.  The program keeps ``forward``'s name,
+    which is what a trace knows it by."""
+    @functools.wraps(forward)
+    def program(*args):
+        logits, *rest = forward(*args)
+        return (*greedy_pick(logits), *rest)
+    return program
+
+
+def picking_decode(forward):
+    """``forward_decode_paged*`` as the engine runs it.  In place of
+    ``ids`` it takes ``(host_ids [B, S], prev_ids [B, S], src [B])``:
+    row ``b`` consumes ``prev_ids[src[b]]``, the pick an earlier step
+    left on the device, or ``host_ids[b]`` where ``src[b] < 0`` (a row
+    the host knows the last token of: fresh from its prefill, or no
+    step is unread).  The row index lets a batch that compacted or grew
+    between two steps run the one program.  Returns ``(ids [B, S],
+    finite [B, S], *rest)`` where ``forward`` returns ``(logits
+    [B, S, V], *rest)``."""
+    @functools.wraps(forward)
+    def program(params, ids, *args):
+        host_ids, prev_ids, src = ids
+        fed = jnp.where(src[:, None] >= 0,
+                        jnp.take(prev_ids, jnp.maximum(src, 0), axis=0),
+                        host_ids)
+        logits, *rest = forward(params, fed, *args)
+        return (*greedy_pick(logits), *rest)
+    return program
 
 
 # ---------------------------------------------------------------------------

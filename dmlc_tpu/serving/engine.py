@@ -1,8 +1,9 @@
 """Inference engine: the per-iteration prefill/decode loop.
 
 The engine owns the compute half of serving: the jitted prefill and
-decode programs of ``models.transformer``, the paged cache's data
-plane, greedy sampling, and the instrumentation
+decode programs of ``models.transformer`` (each ends in the greedy
+pick, so a program returns token ids and never logits), the paged
+cache's data plane, and the instrumentation
 contract — every decode iteration is a **step** on the PR 5
 :class:`telemetry.StepLedger` (``step_begin``/``step_end`` with the
 batch's token count and the exact forward FLOPs given each sequence's
@@ -39,9 +40,31 @@ boundaries (README "Serving").
 
 The device pools are the cache: the prefill program scatters the
 prompt's K/V into the sequence's blocks and the decode program the
-window's, the engine adopts the pools they return, and only logits
-cross the link (``serving.kv_write`` is the adoption and the length
-bookkeeping).
+window's, the engine adopts the pools they return, and only the picked
+ids, their finiteness and the routing counts cross the link
+(``serving.kv_write`` is the adoption and the length bookkeeping).
+
+The loop keeps one decode step in flight.  An iteration of ``_loop``
+dispatches step n+1 and only then reads step n: the ids step n picked
+stay on the device and step n+1 takes each row's token from them (or
+from the host, for a row fresh from its prefill), while positions,
+lengths, block tables and state slots are host facts, because plain
+decode commits exactly one token a live row.  So
+``serving.decode.fetch`` is the wait for step n with step n+1 queued
+behind it, and the commit, delivery, bookkeeping, the next schedule and
+the next dispatch run under a busy chip.  A row that ends by count with
+step n is known before step n is read: it is out of step n+1 and the
+scheduler gives its place away at once (``retire``).  A row that ends
+by ``eos_id``, or fails the finiteness guard, at step n is found out
+after step n+1 went with it: that one token is discarded
+(``serving.lookahead_discarded_tokens``).  Whatever needs every
+request's ``generated`` current reads the step in flight first
+(``_settle``): eviction under KV pressure, the crash requeue, a loop
+that stops (``close`` / ``drain`` join it), and ``step()`` called
+without ``lookahead`` (the tests' single-stepping).  Under a
+speculative window the committed count depends on the ids, so nothing
+stays in flight there: the same loop at depth 0.  A prefill's read
+still blocks, behind the step in flight.
 
 Shape discipline (XLA recompiles per shape, so both are bucketed):
 prefill pads prompts up to a whole number of KV blocks (safe under
@@ -103,7 +126,8 @@ _SPAN_FAMILIES = (
 _ZEROED_COUNTERS = tuple(
     f + kind for f in _SPAN_FAMILIES for kind in ("_secs", "_count")
 ) + ("decode_d2h_bytes", "prefill_d2h_bytes", "kv_upload_bytes",
-     "queue_wait_secs", "queue_wait_count", "latency_secs")
+     "queue_wait_secs", "queue_wait_count", "latency_secs",
+     "decode_steps_overlapped", "lookahead_discarded_tokens")
 
 
 class _DedupeTable:
@@ -176,7 +200,12 @@ def _jitted_programs(family: str = "mha"):
     smokes build several engines and must not pay XLA again for
     identical shapes).
 
-    Which pair depends on the model family alone.  Prefill, site
+    Which pair depends on the model family alone, and every one ends
+    in the same epilogue (``tfm.picking_prefill`` / ``picking_decode``
+    around the family's forward): the greedy pick and its finiteness
+    are what a program returns, never the logits, and the decode
+    program takes the ids it consumes from the host or from an earlier
+    step's pick that is still on the device.  Prefill, site
     ``serving.prefill``: ``forward_prefill_paged`` with the pools
     DONATED — it has this one caller, which replaces its references
     with the returned pools at once, and an undonated scatter would
@@ -205,27 +234,22 @@ def _jitted_programs(family: str = "mha"):
         prefill_fn, prefill_kw = tfm.forward_prefill_paged_hybrid, {
             "static_argnums": (8,), "donate_argnums": (3, 4, 5)}
         decode_key = (mode, "decode_paged_hybrid")
-        builder = lambda cap: compute.profiled_jit(  # noqa: E731
-            tfm.forward_decode_paged_hybrid, site="serving.decode_paged",
-            static_argnums=(9,), donate_argnums=(3, 4, 5),
-            max_signatures=cap)
+        decode_fn, decode_kw = tfm.forward_decode_paged_hybrid, {
+            "static_argnums": (9,), "donate_argnums": (3, 4, 5)}
     elif family == "mla":
         prefill_key = (mode, "prefill_paged_mla")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged_mla, {
             "static_argnums": (5,), "donate_argnums": (3,)}
         decode_key = (mode, "decode_paged_mla")
-        builder = lambda cap: compute.profiled_jit(  # noqa: E731
-            tfm.forward_decode_paged_mla, site="serving.decode_paged",
-            static_argnums=(6,), donate_argnums=(3,), max_signatures=cap)
+        decode_fn, decode_kw = tfm.forward_decode_paged_mla, {
+            "static_argnums": (6,), "donate_argnums": (3,)}
     else:
         prefill_key = (mode, "prefill_paged")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged, {
             "static_argnums": (6,), "donate_argnums": (3, 4)}
         decode_key = (mode, "decode_paged")
-        builder = lambda cap: compute.profiled_jit(  # noqa: E731
-            tfm.forward_decode_paged, site="serving.decode_paged",
-            static_argnums=(7,), donate_argnums=(3, 4),
-            max_signatures=cap)
+        decode_fn, decode_kw = tfm.forward_decode_paged, {
+            "static_argnums": (7,), "donate_argnums": (3, 4)}
     progs = (_JIT_CACHE.get(prefill_key), _JIT_CACHE.get(decode_key))
     if progs[0] is None or progs[1] is None:
         # this cache outlives any one engine — if the first engine of
@@ -239,10 +263,13 @@ def _jitted_programs(family: str = "mha"):
         try:
             if progs[0] is None:
                 _JIT_CACHE[prefill_key] = compute.profiled_jit(
-                    prefill_fn, site="serving.prefill", **prefill_kw)
+                    tfm.picking_prefill(prefill_fn),
+                    site="serving.prefill", **prefill_kw)
             if progs[1] is None:
-                _JIT_CACHE[decode_key] = builder(
-                    get_env("DMLC_SERVE_MAX_DECODE_SIGS", 64))
+                _JIT_CACHE[decode_key] = compute.profiled_jit(
+                    tfm.picking_decode(decode_fn),
+                    site="serving.decode_paged", max_signatures=get_env(
+                        "DMLC_SERVE_MAX_DECODE_SIGS", 64), **decode_kw)
         finally:
             concurrency.set_lock_factory_hook(prev_hook)
         progs = (_JIT_CACHE[prefill_key], _JIT_CACHE[decode_key])
@@ -251,6 +278,38 @@ def _jitted_programs(family: str = "mha"):
         if rereg is not None:
             rereg()
     return progs
+
+
+def _start_fetch(*arrays) -> None:
+    """Ask for each device array's copy to the host now: the link takes
+    them as the program finishes, side by side, and the ``np.asarray``
+    that follows finds them there instead of asking one at a time."""
+    for a in arrays:
+        start = getattr(a, "copy_to_host_async", None)
+        if start is not None:  # a test's stand-in may hand numpy back
+            start()
+
+
+class _DecodeStep:
+    """A decode step on the device whose picks the host has not read:
+    the batch it ran (``rows``, and ``row_of`` from a request's id to
+    its row), what the commit needs of its inputs (``drafts``,
+    ``base_lens``), and the arrays the program returned."""
+
+    __slots__ = ("rows", "row_of", "drafts", "base_lens", "n_preempted",
+                 "overlapped", "ids", "finite", "moe")
+
+    def __init__(self, rows, drafts, base_lens, n_preempted, overlapped,
+                 picked, moe):
+        self.rows = rows
+        self.row_of = {req.id: i for i, req in enumerate(rows)}
+        self.drafts = drafts
+        self.base_lens = base_lens
+        self.n_preempted = n_preempted
+        # dispatched while the step before it was unread
+        self.overlapped = overlapped
+        self.ids, self.finite = picked
+        self.moe = moe
 
 
 class InferenceEngine:
@@ -370,6 +429,15 @@ class InferenceEngine:
         # number of the iteration in flight (``args.iter`` of its spans)
         # dmlc-check: unguarded(engine-thread-confined)
         self._iter = 0
+        # the decode step that is dispatched and not read (at most one)
+        # dmlc-check: unguarded(engine-thread-confined)
+        self._inflight: Optional[_DecodeStep] = None
+        # the ids the last decode step picked, on the device: what the
+        # next step is handed as ``prev_ids`` whether or not a row
+        # takes its token from there
+        # dmlc-check: unguarded(engine-thread-confined)
+        self._last_ids = np.zeros((self.max_active, self._spec_window),
+                                  np.int32)
 
     # ---- client surface -------------------------------------------------
     def submit(self, prompt_ids: List[int],
@@ -609,7 +677,7 @@ class InferenceEngine:
                 starved = None
             crashed = False
             try:
-                did = self.step()
+                did = self.step(lookahead=True)
             except Exception as e:  # noqa: BLE001 - engine must not die
                 crashed = True
                 # a crashed decode leaves the ACTIVE set's cache state
@@ -622,7 +690,11 @@ class InferenceEngine:
                 # deterministically poisonous request: past it, the
                 # request fails with reason "crash".  WAITING requests
                 # were never touched and keep serving either way.
-                for req in self.scheduler.active_requests():
+                # A step still in flight is read first where it can be
+                # (its picks are output like any other); where it
+                # cannot, the resume recomputes its tokens.
+                self._settle_or_drop()
+                for req in self.scheduler.running_requests():
                     if (req.crash_requeues < self._crash_requeue_max
                             and self.scheduler.requeue_active(req)):
                         telemetry.inc("serving", "crash_requeues")
@@ -660,12 +732,27 @@ class InferenceEngine:
                 time.sleep(0.002)
         if starved is not None:
             starved.__exit__(None, None, None)
+        # nothing stays unread behind a loop that has stopped: a request
+        # whose last token was in flight finishes, and close() fails the
+        # rest
+        self._settle_or_drop()
+
+    def _settle_or_drop(self) -> None:
+        try:
+            self._settle()
+        except Exception as e:  # noqa: BLE001 - the step is lost, not the engine
+            logger.error("the decode step in flight could not be read: "
+                         "%r", e)
 
     # ---- one iteration --------------------------------------------------
-    def step(self) -> bool:
+    def step(self, lookahead: bool = False) -> bool:
         """One continuous-batching iteration: drain admissible prefills
         (the scheduler's ``next_prefill`` stops at ``max_active``), then
-        one decode window for every active request.  Prefill-priority
+        one decode window for every active request.  With ``lookahead``
+        (the loop's way) the step dispatched here stays unread and the
+        one before it is read in its place; without (the default) every
+        step is read before this returns, so a single-stepped engine
+        shows each step's tokens at once.  Prefill-priority
         keeps the decode batch full — an 8-deep queue joins the batch in
         ONE iteration instead of ramping a row per step, which is where
         decode MFU goes to die on short bursts.  Decode still runs every
@@ -676,16 +763,16 @@ class InferenceEngine:
         single-step the engine deterministically."""
         self._step_seq += 1
         try:
-            if not any(self.scheduler.counts()):
+            if self._inflight is None and not any(self.scheduler.counts()):
                 return False  # the loop's serving.starved span has this
             self._iter += 1
             self.requests.iteration = self._iter
             with self._span("serving.iteration"):
-                return self._iterate()
+                return self._iterate(lookahead)
         finally:
             self._step_seq += 1
 
-    def _iterate(self) -> bool:
+    def _iterate(self, lookahead: bool) -> bool:
         did = False
         while True:
             with self._span("serving.schedule"):
@@ -698,7 +785,7 @@ class InferenceEngine:
                 # allocate lost a race and requeued the request;
                 # bail rather than spin on it inside one iteration
                 break
-        return self._run_decode() or did
+        return self._run_decode(lookahead) or did
 
     def _finish(self, req: Request, error: Optional[str] = None,
                 reason: Optional[str] = None) -> None:
@@ -726,9 +813,10 @@ class InferenceEngine:
     def _run_prefill(self, req: Request) -> None:
         """Prefill ``req``'s context and cache its K/V inside the device
         program, which scatters them into the request's blocks of the
-        pools it is donated (only the logits come to the host).  A
-        fresh request also samples its first token here (that IS the
-        TTFT moment); a preemption resume must NOT sample — its context
+        pools it is donated and picks the token after the last position
+        (the pick and its finiteness are all that come to the host).  A
+        fresh request takes its first token here (that IS the
+        TTFT moment); a preemption resume must NOT — its context
         already excludes the un-consumed ``generated[-1]``, so the
         last-position logits would deterministically re-derive that very
         token and duplicate it in the output.  The resume's next token
@@ -756,7 +844,7 @@ class InferenceEngine:
             ids[0, :n] = ctx
             last = np.array([n - 1], np.int32)
             self.requests.on_prefill_begin(req.id, resume=resume)
-            logits = self._prefill_paged(req, ids, last, n)
+            picked = self._prefill_paged(req, ids, last, n)
             telemetry.inc("serving", "prefill_tokens", n)
         except Exception as e:  # noqa: BLE001 - fail THIS request only
             logger.error("prefill of request %d failed: %r", req.id, e)
@@ -771,26 +859,30 @@ class InferenceEngine:
                 raise
             return
         with self._span("serving.first_token", req=req.id):
-            self._after_prefill(req, logits, resume)
+            self._after_prefill(req, *picked, resume)
 
     def _prefill_paged(self, req: Request, ids, last, n: int):
         """The device program writes the K/V into the request's blocks
         (prefill pads to whole blocks, so its block table IS the padded
-        prompt's); the logits alone cross the link."""
+        prompt's); ``(next id, whether its logit is finite)`` and the
+        routing counts alone cross the link.  The read waits for the
+        program, and for a decode step in flight before it."""
         with self._span("serving.prefill", tokens=n, req=req.id):
             with self._span("serving.prefill.run", req=req.id):
-                logits, pools, moe = self._on_pools(
+                picked, pools, moe = self._on_pools(
                     self._prefill, self.params, ids, last,
                     np.asarray(self.cache.block_table(req.id), np.int32),
                     *self._slot_args([req.id]), at=3)
-                logits = np.asarray(logits[0])
+                _start_fetch(*picked, *moe)
+                picked = [np.asarray(a) for a in picked]
                 moe = [np.asarray(m) for m in moe]
-        telemetry.inc("serving", "prefill_d2h_bytes", logits.nbytes)
+        telemetry.inc("serving", "prefill_d2h_bytes",
+                      sum(a.nbytes for a in picked + moe))
         self._count_moe(moe)
         with self._span("serving.kv_write", req=req.id):
             self.cache.adopt_device_pools(*pools)
             self.cache.advance_many([(req.id, n)])
-        return logits
+        return int(picked[0][0]), bool(picked[1][0])
 
     def _slot_args(self, seq_ids, pad_batch=None) -> tuple:
         """What a program of a model with recurrent layers takes after
@@ -802,12 +894,13 @@ class InferenceEngine:
     def _on_pools(self, program, *args, at: int):
         """Call a paged program with the cache's pools spliced in at
         argument ``at`` and the config last; split what it returns into
-        ``(logits, pools, rest)``.  The pools are the cache's device
-        arrays, recurrent state included; ``rest`` is empty for the
-        MHA programs and the routing counts for the other families'."""
+        ``((ids, finite), pools, rest)``.  The pools are the cache's
+        device arrays, recurrent state included; ``rest`` is empty for
+        the MHA programs and the routing counts for the other
+        families'."""
         pools = self.cache.device_pools()
         out = program(*args[:at], *pools, *args[at:], self.cfg)
-        return out[0], out[1:1 + len(pools)], out[1 + len(pools):]
+        return out[:2], out[2:2 + len(pools)], out[2 + len(pools):]
 
     def _count_moe(self, moe) -> None:
         """Add one program call's routing counts ``[n_moe_layers,
@@ -823,10 +916,11 @@ class InferenceEngine:
             telemetry.inc("serving", "moe_expert_load_mean",
                           float(held.mean(axis=1).sum()))
 
-    def _after_prefill(self, req: Request, logits, resume: bool) -> None:
-        """Sample the first token of a fresh request and activate it."""
+    def _after_prefill(self, req: Request, next_id: int, finite: bool,
+                       resume: bool) -> None:
+        """Give a fresh request its first token and activate it."""
         if not resume:
-            if not np.isfinite(logits).all():
+            if not finite:
                 # same guard at the prefill sample point: the first
                 # token must not come from a non-finite row either
                 telemetry.inc("serving", "nonfinite_failures")
@@ -834,7 +928,6 @@ class InferenceEngine:
                              "prefill (numeric corruption); retry the "
                              "request", reason="nonfinite")
                 return
-            next_id = int(np.argmax(logits))
             req.generated.append(next_id)
             telemetry.inc("serving", "tokens_generated")
             req.ttft_s = time.monotonic() - req.submit_t
@@ -924,31 +1017,53 @@ class InferenceEngine:
                 return ctx[p + m:p + m + self.spec_k]
         return []
 
-    def _run_decode(self) -> bool:
-        """One decode window for every active request; whether there
-        was one to run."""
+    def _run_decode(self, lookahead: bool) -> bool:
+        """One decode window for every active request, and the read of
+        a step's picks: of this one at once, or (``lookahead``, plain
+        decode) of the one before it, while this one runs.  Whether
+        there was anything to run or read."""
         s_w = self._spec_window
+        # a verify window commits as many tokens as the ids say, so the
+        # next step's lengths wait for this one's read: nothing stays
+        # unread behind it
+        ahead = lookahead and s_w == 1
+        did = False
+        if not ahead:
+            did = self._settle()
+        n_preempted = 0
         with self._span("serving.schedule"):
             active = self.scheduler.active_requests()
-            if not active:
-                return False
-            active, n_preempted = self._ensure_decode_capacity(active, s_w)
-            if active:
+            tight = bool(active) and not self.cache.extend_many(
+                [r.id for r in active], s_w)
+            if active and not tight:
                 inputs = self._decode_inputs(active)
+        if tight:
+            # eviction requeues its victims with what they generated:
+            # that has to be current
+            self._settle()
+            with self._span("serving.schedule"):
+                active, n_preempted = self._ensure_decode_capacity(
+                    self.scheduler.active_requests(), s_w)
+                if active:
+                    inputs = self._decode_inputs(active)
         if not active:
+            did = self._settle() or did or tight
             if n_preempted:
                 self.requests.on_iteration(
                     active=0, waiting=self.scheduler.n_waiting,
                     preempted=n_preempted, kv_stats=self.cache.stats())
-            return True
+            return did
         with self._span("serving.decode", rows=len(active)):
-            self._decode_step(active, n_preempted, *inputs)
+            self._decode_step(active, n_preempted, ahead, *inputs)
         return True
 
     def _decode_inputs(self, active: List[Request]) -> tuple:
-        """What the decode program takes from the host: ``(ids,
+        """What the decode program takes from the host: ``(feed,
         positions, drafts, tables, lengths, base_lens, slots)``, the
-        last a tuple that is empty without recurrent state."""
+        last a tuple that is empty without recurrent state.  ``feed``
+        is ``(ids, prev_ids, src)``: a row of the step in flight takes
+        its token from that step's picks on the device (``src`` is its
+        row there), every other row from ``ids``."""
         s_w = self._spec_window
         b = len(active)
         pad_b = self.max_active
@@ -957,30 +1072,37 @@ class InferenceEngine:
         # when it has none — the verify mask is causal inside the
         # window, so junk columns cannot influence earlier positions)
         ids = np.zeros((pad_b, s_w), np.int32)
+        src = np.full(pad_b, -1, np.int32)
         positions = np.zeros((pad_b, s_w), np.int32)
         drafts: List[List[int]] = []
         # ONE cache visit covers the whole batch: the block-table fetch
-        # already reports every row's committed length, so the per-row
+        # already reports every row's length, the token of a step in
+        # flight included (a dispatch advances it), so the per-row
         # length() round-trips (a lock each) are free
         tables, lengths = self.cache.block_tables_array(
             [r.id for r in active], pad_batch=pad_b)
         base_lens = lengths[:b].astype(np.int64)
+        unread = self._inflight.row_of if self._inflight else {}
         for i, req in enumerate(active):
-            ids[i, 0] = req.generated[-1]
+            row = unread.get(req.id)
+            if row is not None:
+                src[i] = row
+            else:
+                ids[i, 0] = req.generated[-1]
             d = self._draft_tokens(req) if s_w > 1 else []
             if d:
                 ids[i, 1:1 + len(d)] = d
             drafts.append(d)
         positions[:b] = base_lens[:, None] + np.arange(s_w)
-        return (ids, positions, drafts, tables, lengths, base_lens,
+        return ((ids, self._last_ids, src), positions, drafts, tables,
+                lengths, base_lens,
                 self._slot_args([r.id for r in active], pad_b))
 
     def _decode_step(self, active: List[Request], n_preempted: int,
-                     ids, positions, drafts, tables, lengths,
-                     base_lens, slots=()) -> None:
-        s_w = self._spec_window
-        b = len(active)
-        compute = telemetry.compute
+                     ahead: bool, feed, positions, drafts, tables,
+                     lengths, base_lens, slots=()) -> None:
+        """Dispatch a step for ``active``; then read the step before it
+        (``ahead``: this one stays in flight) or this one."""
         if not self._flops_declared:
             # per-token FLOPs vary with context; declared once for the
             # ledger's goodput math, exact FLOPs passed per step below
@@ -989,17 +1111,22 @@ class InferenceEngine:
             # the decode roofline needs the dtype's peak FLOPs/HBM BW
             telemetry.declare_dtype(self.cfg.dtype)
             self._flops_declared = True
-        # the ledger's step is the device program + the commit, nothing
-        # else: its span encloses those three and closes (LIFO) before
-        # delivery and bookkeeping open
-        telemetry.step_begin()
+        prev = self._inflight
+        # the ledger's step is one device program + one commit, nothing
+        # else: its span encloses the dispatch and the read that follows
+        # it (with a step in flight they are two steps' halves, and
+        # still one of each) and closes (LIFO) before delivery and
+        # bookkeeping open.  The first step of a run of lookahead reads
+        # nothing and is no ledger step; the read that ends the run is
+        if prev is not None or not ahead:
+            telemetry.step_begin()
         with self._span("serving.decode.dispatch"):
             # the program reads and writes the device-resident pools in
             # place through the block tables (a [B, W] int32 array is
             # all that ships) and hands no K/V back
             try:
-                logits, pools, moe = self._on_pools(
-                    self._decode, self.params, ids, positions, tables,
+                picked, pools, moe = self._on_pools(
+                    self._decode, self.params, feed, positions, tables,
                     lengths, *slots, at=3)
             except Exception:
                 # the donated pools went with a call that failed after
@@ -1008,42 +1135,95 @@ class InferenceEngine:
                 self.cache.drop_lost_pools()
                 raise
             self.cache.adopt_device_pools(*pools)
+            # the program wrote the window's K/V at each row's length,
+            # and a live row commits its first position or leaves: the
+            # lengths are host facts already, so that the next step can
+            # be built before this one is read
+            self.cache.advance_many([(req.id, 1) for req in active])
+            _start_fetch(*picked, *moe)
+        step = _DecodeStep(active, drafts, base_lens, n_preempted,
+                           prev is not None, picked, moe)
+        self._last_ids = step.ids
+        if not ahead:
+            self._read_step(step)
+            return
+        self._inflight = step
+        for req in active:
+            # a row that ends by count with this step is known to now:
+            # it is out of the next step, and its place is the next
+            # prefill's, as if this step had been read
+            if (req.n_generated + (req.id in prev.row_of if prev else 0)
+                    + 1 >= req.max_new_tokens):
+                self.scheduler.retire(req)
+        if prev is not None:
+            try:
+                self._read_step(prev)
+            except Exception:
+                # tokens after ones that were lost are no output
+                self._inflight = None
+                raise
+
+    def _settle(self) -> bool:
+        """Read the step in flight, if there is one: afterwards every
+        request's ``generated`` is current.  Whatever needs that calls
+        this first: eviction, the crash requeue, a step without
+        lookahead, a loop that stops."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return False
+        with self._span("serving.decode", rows=len(step.rows)):
+            telemetry.step_begin()
+            self._read_step(step)
+        return True
+
+    def _read_step(self, step: _DecodeStep) -> None:
+        """Fetch a dispatched step's picks and commit, deliver and
+        account them.  Closes the ledger step the caller opened."""
+        s_w = self._spec_window
+        active, drafts, base_lens = step.rows, step.drafts, step.base_lens
+        b = len(active)
+        compute = telemetry.compute
         with self._span("serving.decode.fetch") as crossed:
-            logits = np.asarray(logits)
-            crossed["bytes"] = logits.nbytes
-            moe = [np.asarray(m) for m in moe]
+            # the wait for the step (the next one, if dispatched, runs
+            # behind it) and the link: a [B, S] int32, a [B, S] bool
+            # and the routing counts
+            amax = np.asarray(step.ids)
+            fin = np.asarray(step.finite)
+            moe = [np.asarray(m) for m in step.moe]
+            crossed["bytes"] = sum(a.nbytes for a in [amax, fin] + moe)
         telemetry.inc("serving", "decode_d2h_bytes", crossed["bytes"])
         self._count_moe(moe)
         # per-sequence numeric health: a non-finite logit row (NaN/Inf
         # from a poisoned cache page or an overflowed activation) would
-        # serve garbage silently.  Checking only the sampled position is
-        # sufficient — argmax lands on the first NaN (NaN propagates
-        # through maximum) and an all--inf row argmaxes to -inf — and
-        # keeps the guard O(1) per row instead of O(vocab) on the decode
-        # hot path.  Fail exactly that request with a clear error; the
-        # rest of the batch (and the engine) keep serving.
+        # serve garbage silently.  The program checks the picked
+        # position only, which is sufficient — argmax lands on the
+        # first NaN (NaN propagates through maximum) and an all--inf
+        # row argmaxes to -inf.  Fail exactly that request with a clear
+        # error; the rest of the batch (and the engine) keep serving.
         #
         # Longest-accepted-prefix commit walk: window position s emits
-        # argmax(logits[s]); the walk continues past s only while the
-        # drafted token MATCHES that argmax, so the committed output is
-        # bit-identical to single-token greedy decoding — speculation
-        # can change only how many tokens land per step, never which.
+        # the program's pick at s; the walk continues past s only while
+        # the drafted token MATCHES that pick, so the committed output
+        # is bit-identical to single-token greedy decoding —
+        # speculation can change only how many tokens land per step,
+        # never which.
         n_tokens = 0
         n_proposed = 0
         n_accepted = 0
+        n_discarded = 0
         with self._span("serving.decode.commit"):
             with compute.phase("sampling"):
-                # one vectorized argmax + finiteness probe over the
-                # whole [B, S_w] window: the walk below touches only
-                # python ints (per-position np.argmax calls were a
-                # measurable slice of the step wall at batch 8 ×
-                # window 8)
-                amax = np.argmax(logits[:b], axis=2)
-                fin = np.isfinite(
-                    np.take_along_axis(logits[:b], amax[:, :, None],
-                                       axis=2))[:, :, 0]
+                # the walk touches only python ints
                 outcomes = []
                 for i, req in enumerate(active):
+                    if req.state != ACTIVE:
+                        # it ended (eos, a non-finite row) in the step
+                        # before this one, which was read after this
+                        # one was dispatched with it: the token is no
+                        # output, and its K/V went into blocks that
+                        # were the row's until that read freed them
+                        n_discarded += 1
+                        continue
                     draft = drafts[i]
                     n_proposed += len(draft)
                     n_row = 0
@@ -1070,16 +1250,15 @@ class InferenceEngine:
                         break
                     outcomes.append((req, i, n_row, fail, done))
                     n_tokens += n_row
-            # ONE batched cache visit covering every row's committed
-            # prefix (contiguous by construction): per-row calls were
-            # dominated by lock/GIL crossings.  The program already
-            # wrote the window's K/V at each row's length, so the
-            # commit is the lengths alone (a rejected draft's slots
-            # stay garbage past the length).  Must land before any
-            # _finish below — finishing frees blocks.
-            self.cache.advance_many(
-                [(req.id, n_row)
-                 for req, _, n_row, _, _ in outcomes if n_row])
+            # the dispatch advanced every row by its first position; a
+            # verify window's accepted drafts are the rest (contiguous
+            # by construction, in ONE batched cache visit; a rejected
+            # draft's slots stay garbage past the length).  Must land
+            # before any _finish below — finishing frees blocks.
+            if s_w > 1:
+                self.cache.advance_many(
+                    [(req.id, n_row - 1)
+                     for req, _, n_row, _, _ in outcomes if n_row > 1])
             for req, i, n_row, fail, done in outcomes:
                 if n_row:
                     self.requests.on_token(req.id, n=n_row)
@@ -1121,8 +1300,15 @@ class InferenceEngine:
                 elif done:
                     self._finish(req)
         with self._span("serving.decode.bookkeeping"):
+            # counted where paged_decode_steps is, so that a window's
+            # edge cuts both alike
+            if step.overlapped:
+                telemetry.inc("serving", "decode_steps_overlapped")
+            if n_discarded:
+                telemetry.inc("serving", "lookahead_discarded_tokens",
+                              n_discarded)
             self._decode_bookkeeping(b, n_tokens, n_proposed, n_accepted,
-                                     n_preempted, cost)
+                                     step.n_preempted, cost)
 
     def _decode_bookkeeping(self, b: int, n_tokens: int, n_proposed: int,
                             n_accepted: int, n_preempted: int,

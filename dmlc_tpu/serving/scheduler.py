@@ -230,6 +230,10 @@ class ContinuousBatchScheduler:
         self.max_active = int(max_active)
         self._waiting: deque = deque()
         self._active: List[Request] = []
+        # out of the batch with their last decode step dispatched and
+        # not yet read (:meth:`retire`); still running to every view
+        # but the batch's
+        self._retiring: List[Request] = []
         self._lock = make_lock("ContinuousBatchScheduler._lock")
 
     # ---- queue views ----------------------------------------------------
@@ -241,18 +245,27 @@ class ContinuousBatchScheduler:
     @property
     def n_active(self) -> int:
         with self._lock:
-            return len(self._active)
+            return len(self._active) + len(self._retiring)
 
     def active_requests(self) -> List[Request]:
+        """The decode batch: what the next step runs."""
         with self._lock:
             return list(self._active)
+
+    def running_requests(self) -> List[Request]:
+        """The decode batch and the retiring requests: everything that
+        is neither waiting nor finished (the crash requeue's set)."""
+        with self._lock:
+            return self._active + self._retiring
 
     def counts(self) -> tuple:
         """``(n_active, n_waiting)`` under ONE lock hold: composed
         views (``/healthz``, the router's load signal) get a consistent
-        pair instead of two reads an iteration can interleave."""
+        pair instead of two reads an iteration can interleave.  A
+        retiring request counts as active until it is finished."""
         with self._lock:
-            return len(self._active), len(self._waiting)
+            return (len(self._active) + len(self._retiring),
+                    len(self._waiting))
 
     # ---- admission ------------------------------------------------------
     def enqueue(self, req: Request) -> None:
@@ -300,7 +313,7 @@ class ContinuousBatchScheduler:
     def all_pending(self) -> List[Request]:
         """Every request not yet in a terminal state (shutdown sweep)."""
         with self._lock:
-            return list(self._active) + list(self._waiting)
+            return self._active + self._retiring + list(self._waiting)
 
     def activate(self, req: Request) -> None:
         with self._lock:
@@ -309,9 +322,28 @@ class ContinuousBatchScheduler:
             telemetry.set_gauge("serving", "active_requests",
                                 len(self._active))
 
+    def retire(self, req: Request) -> None:
+        """Take an active request out of the batch before it is
+        finished: the engine has dispatched the step that generates its
+        last token (it ends by count, which needs no token to know) and
+        reads that step only after the next one is on the device.  Its
+        blocks and state slot go back now, so the next prefill gets the
+        place in the batch no later than it would from a step that was
+        read at once; every program dispatched from here on runs after
+        that step on the device, so none can see the blocks while the
+        step still writes them.  The request stays ACTIVE, counted by
+        :meth:`counts` and swept by :meth:`all_pending`, until
+        :meth:`finish`."""
+        with self._lock:
+            self._active.remove(req)
+            self._retiring.append(req)
+            telemetry.set_gauge("serving", "active_requests",
+                                len(self._active))
+        self.cache.free(req.id)
+
     def requeue_active(self, req: Request) -> bool:
-        """Crash requeue: pull a SPECIFIC active request back to the
-        front of the wait queue (its cache state after a crashed
+        """Crash requeue: pull a SPECIFIC active (or retiring) request
+        back to the front of the wait queue (its cache state after a crashed
         iteration is unknowable, so its blocks are freed and the
         re-prefill recomputes from ``context_ids()`` — identical
         recompute-resume mechanics to preemption, but counted on the
@@ -319,9 +351,11 @@ class ContinuousBatchScheduler:
         Returns False when the request is not active (it finished or
         was swept concurrently)."""
         with self._lock:
-            if req not in self._active:
+            held = next((q for q in (self._active, self._retiring)
+                         if req in q), None)
+            if held is None:
                 return False
-            self._active.remove(req)
+            held.remove(req)
             req.state = WAITING
             req.crash_requeues += 1
             self._waiting.appendleft(req)
@@ -365,10 +399,10 @@ class ContinuousBatchScheduler:
         with self._lock:
             if req.state in (DONE, FAILED):
                 raise AlreadyFinished(f"request {req.id} finished twice")
-            if req in self._active:
-                self._active.remove(req)
-            elif req in self._waiting:
-                self._waiting.remove(req)
+            for held in (self._active, self._retiring, self._waiting):
+                if req in held:
+                    held.remove(req)
+                    break
             req.state = FAILED if error else DONE
             req.error = error
             req.finish_t = time.monotonic()

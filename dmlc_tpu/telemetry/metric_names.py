@@ -424,6 +424,10 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_kda_state_rw_bytes",
     "dmlc_serving_state_slots_in_use",
     "dmlc_serving_state_slots_total",
+    # the decode loop's lookahead: steps dispatched while the step
+    # before was unread, and tokens of rows that had ended in it
+    "dmlc_serving_decode_steps_overlapped",
+    "dmlc_serving_lookahead_discarded_tokens",
 })
 
 #: span / jax-profiler annotation names that look like metric tokens in

@@ -287,16 +287,24 @@ def test_ledger_splits_exposed_vs_overlapped():
     telemetry.reset_steps()
     led = telemetry.ledger()
 
+    # events, not two sleeps racing: a loaded host oversleeps by more
+    # than the 10 ms the race left
+    started, joining = threading.Event(), threading.Event()
+
     def background_collective():
         with telemetry.core.span("collective.allreduce",
                                  stage="collective"):
-            time.sleep(0.05)
+            started.set()
+            joining.wait(5)
+            time.sleep(0.02)
 
     led.step_begin()
     th = threading.Thread(target=background_collective)
     th.start()
+    started.wait(5)
     time.sleep(0.04)  # stepping thread computes: the worker's span hides
     with telemetry.core.span("collective.join", stage="collective"):
+        joining.set()
         th.join()  # the remainder is paid here, exposed
     rec = led.step_end(tokens=10)
     # worker time under the stepping thread's compute is overlapped;

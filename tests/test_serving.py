@@ -1064,22 +1064,24 @@ def test_engine_fails_only_nonfinite_logit_request():
     params, cfg = _tiny_model()
     eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
                           max_active=3, queue_depth=8)
-    r1 = eng.submit([1, 2, 3], max_new_tokens=6)
+    r1 = eng.submit([1, 2, 3, 4, 5, 6], max_new_tokens=6)
     r2 = eng.submit([4, 5, 6], max_new_tokens=3)
     eng.step()  # prefill r1
     eng.step()  # prefill r2 (+ decode r1)
     real = eng._decode
     fired = []
 
-    def poisoned(*a):
-        # signature-agnostic: works for both the gather decode program
-        # (7 args, 3 outputs) and the paged one (8 args, 5 outputs)
-        out = real(*a)
-        lg = np.asarray(out[0]).copy()
+    def poisoned(p, feed, positions, k_pool, v_pool, tables, lengths, c):
+        # a NaN in the second K page of r1's row (activation order;
+        # block 0 is what padded table entries name): the program's own
+        # epilogue has to report the row, and no other, as non-finite
         if not fired:
-            lg[0] = np.nan  # r1's row (activation order)
+            k_pool = k_pool.at[0, int(tables[0, 1])].set(np.nan)
             fired.append(True)
-        return (lg,) + tuple(out[1:])
+        out = real(p, feed, positions, k_pool, v_pool, tables, lengths, c)
+        if len(fired) == 1:
+            fired.append(np.asarray(out[1]).ravel().tolist())
+        return out
 
     eng._decode = poisoned
     before = telemetry.counters_snapshot().get("serving", {}).get(
@@ -1090,6 +1092,7 @@ def test_engine_fails_only_nonfinite_logit_request():
         eng.step()
     assert r1.error is not None and "non-finite" in r1.error
     assert r2.error is None and r2.n_generated == 3
+    assert fired[1] == [False, True, True]  # r1, r2, a dead row
     after = telemetry.counters_snapshot().get("serving", {}).get(
         "nonfinite_failures", 0)
     assert after == before + 1
@@ -1238,8 +1241,9 @@ def test_engine_starved_is_one_span_per_episode():
 
 
 def test_engine_byte_counters_equal_what_crossed():
-    """The logits are all that crosses the link, for a prefill and for
-    a decode step.  Nothing goes back up."""
+    """The picked ids and their finiteness are all that crosses the
+    link, for a prefill and for a decode step: never the logits.
+    Nothing goes back up."""
     eng = _warm_engine()
     telemetry.reset()
     crossed = {"prefill": 0, "decode": 0}
@@ -1247,12 +1251,12 @@ def test_engine_byte_counters_equal_what_crossed():
 
     def prefill(*a):
         out = real_prefill(*a)
-        crossed["prefill"] += np.asarray(out[0][0]).nbytes
+        crossed["prefill"] += sum(np.asarray(o).nbytes for o in out[:2])
         return out
 
     def decode(*a):
         out = real_decode(*a)
-        crossed["decode"] += np.asarray(out[0]).nbytes
+        crossed["decode"] += sum(np.asarray(o).nbytes for o in out[:2])
         return out
 
     eng._prefill, eng._decode = prefill, decode
@@ -1268,10 +1272,10 @@ def test_engine_byte_counters_equal_what_crossed():
     assert sum(r["args"]["bytes"] for r in recs
                if r["name"] == "serving.decode.fetch") \
         == c["decode_d2h_bytes"]
-    vocab_row = 64 * 4  # one float32 row of the tiny model's logits
-    assert c["prefill_d2h_bytes"] == vocab_row
+    picked = 4 + 1  # an int32 id and a bool
+    assert c["prefill_d2h_bytes"] == picked
     # every step fetches the whole padded batch: max_active rows
-    assert c["decode_d2h_bytes"] == 3 * eng.max_active * vocab_row
+    assert c["decode_d2h_bytes"] == 3 * eng.max_active * picked
     eng.close()
 
 
