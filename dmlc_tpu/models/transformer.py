@@ -23,6 +23,7 @@ standard static-shape trade).  Both combine with one psum over (ep, tp).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Optional
@@ -35,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.core import (
     ShardAxes,
     embed_lookup,
+    layer_norm,
     rms_norm,
     rope,
     softmax_xent,
@@ -119,10 +121,58 @@ class TransformerConfig:
     layer_offset: int = 0
     kda_conv_size: int = 4       # causal depthwise convolution on q, k, v
     kda_lower_bound: float = -5.0  # log decay = bound x sigmoid(.)
+    # ---- the MHA family widened, served only where any of these is
+    # set (Command A+'s block).  Grouped-query heads: ``n_kv_heads`` K/V
+    # heads (0: n_heads), query head h reads K/V head h // (n_heads /
+    # n_kv_heads).  ``sliding_window`` W > 0: published layer j
+    # (``layer_offset + i``) is "full" where (j + 1) % layer_group_size
+    # == 0, else "sliding": query i sees keys i - W < j <= i, and its
+    # K/V live in a pool and a block table of their own.  With
+    # ``moe_router="sigmoid"`` the FFN is the held-expert layer above.
+    n_kv_heads: int = 0
+    sliding_window: int = 0
+    full_layers_rope: bool = True   # False: full layers carry no positions
+    norm: str = "rms"               # "layer": LayerNorm, a weight, no bias
+    norm_eps: float = 1e-6
+    parallel_block: bool = False    # x + Attn(xn) + FFN(xn), one norm a layer
+    tie_embeddings: bool = False    # logits = logit_scale * x embed^T
+    logit_scale: float = 1.0
+    moe_shared_average: bool = False  # the shared experts' mean, not sum
+    # the residual stream's type where it is not ``dtype`` ("float32"
+    # under bf16 weights: every addend joins the stream as its matmul
+    # accumulated it, the norms and the router read it unrounded, and
+    # the logits come out in it; the matmuls' operands stay ``dtype``)
+    residual_dtype: str = ""
 
     @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def stream_dtype(self):
+        return jnp.dtype(self.residual_dtype or self.dtype)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def family(self) -> str:
+        """Which pair of serving programs runs the model: the
+        ``attention`` kind, and "mha_swa" for an MHA model whose
+        sliding layers have pools and tables of their own."""
+        if self.attention == "mha" and self.sliding_window:
+            return "mha_swa"
+        return self.attention
+
+    @property
+    def served_only(self) -> bool:
+        """Whether the model has a serving path alone: no train step,
+        sharding specs or backward kernels."""
+        return (self.latent or self.moe_router == "sigmoid"
+                or bool(self.n_kv_heads or self.sliding_window)
+                or self.norm != "rms" or self.parallel_block
+                or self.tie_embeddings or bool(self.residual_dtype))
 
     @property
     def hybrid(self) -> bool:
@@ -137,11 +187,15 @@ class TransformerConfig:
     @property
     def layer_kinds(self) -> tuple:
         """Each layer's attention, in order."""
-        if not self.hybrid:
+        if self.hybrid:
+            every, rest = "mla", "kda"
+        elif self.family == "mha_swa":
+            every, rest = "full", "sliding"
+        else:
             return (self.attention,) * self.n_layers
         return tuple(
-            "mla" if (self.layer_offset + i + 1) % self.layer_group_size == 0
-            else "kda" for i in range(self.n_layers))
+            every if (self.layer_offset + i + 1) % self.layer_group_size == 0
+            else rest for i in range(self.n_layers))
 
     def kv_pool_shapes(self, n_blocks: int, block_size: int) -> tuple:
         """Shapes of the paged cache's pools: K and V pages of
@@ -157,7 +211,23 @@ class TransformerConfig:
             row = self.kv_lora_rank + self.qk_rope_head_dim
             return ((self.layer_kinds.count("mla"), n_blocks, row,
                      block_size),)
-        return ((self.n_layers, n_blocks, block_size, self.n_heads,
+        # a sliding layer's K/V are in sliding_pool_shapes' pools
+        n_layers = self.n_layers - self.layer_kinds.count("sliding")
+        return ((n_layers, n_blocks, block_size, self.kv_heads,
+                 self.head_dim),) * 2
+
+    def sliding_pool_shapes(self, n_rows: int, block_size: int) -> tuple:
+        """Shapes of the sliding layers' K and V pools, none for a
+        model without such layers: pages as :meth:`kv_pool_shapes`, and
+        as many blocks as ``n_rows`` live sequences can hold, a ring of
+        ``ceil(sliding_window / block_size) + 1`` each (the block the
+        window's oldest key lies in, those up to the newest, and no
+        more however long the context: serving/kv_cache.py)."""
+        n_sliding = self.layer_kinds.count("sliding")
+        if not n_sliding:
+            return ()
+        ring = -(-self.sliding_window // block_size) + 1
+        return ((n_sliding, n_rows * ring, block_size, self.kv_heads,
                  self.head_dim),) * 2
 
     def state_slot_shapes(self, n_slots: int) -> tuple:
@@ -202,7 +272,7 @@ def flagship_config() -> TransformerConfig:
 
 def count_params(cfg: TransformerConfig) -> int:
     """Total parameter count of init_params' pytree."""
-    if cfg.latent:
+    if cfg.served_only:
         return sum(int(a.size) for a in jax.tree.leaves(
             jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))))
     e, hd, f, x = (cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff,
@@ -240,6 +310,30 @@ def _latent_forward_flops(cfg: TransformerConfig, t: int,
             + n_moe * 2 * expert + 2 * e * cfg.vocab)
 
 
+def _gqa_forward_flops(cfg: TransformerConfig, t: int,
+                       causal: bool) -> float:
+    """Forward FLOPs one token needs under the widened MHA block at
+    context ``t``: the q and o projections at n_heads and k, v at the
+    K/V heads, scores and values over the keys each layer kind sees (a
+    sliding layer at most its window), the FFN (dense, or the held
+    share of the routed experts beside the shared ones and the router),
+    the unembed."""
+    e, d = cfg.d_model, cfg.head_dim
+    proj = 2 * e * d * (2 * cfg.n_heads + 2 * cfg.kv_heads)
+    seen = {"sliding": min(t, cfg.sliding_window)}
+    attn = sum((2 if causal and seen.get(kind, t) == t else 4)
+               * seen.get(kind, t) * cfg.n_heads * d
+               for kind in cfg.layer_kinds)
+    if cfg.moe_router == "sigmoid":
+        routed = cfg.moe_n_routed or cfg.n_experts
+        ffn = 2 * (3 * e * cfg.moe_d_ff * (
+            cfg.moe_topk * cfg.n_experts / routed + cfg.moe_n_shared)
+            + e * routed)
+    else:
+        ffn = 2 * 3 * e * cfg.d_ff * cfg.n_experts
+    return cfg.n_layers * (proj + ffn) + attn + 2 * e * cfg.vocab
+
+
 def train_flops_per_token(cfg: TransformerConfig, t: int,
                           causal: bool = True) -> float:
     """Executed matmul FLOPs per token for one train step (fwd + bwd ≈ 3×
@@ -249,6 +343,8 @@ def train_flops_per_token(cfg: TransformerConfig, t: int,
     MFU (conservative: the partially-masked diagonal blocks run full)."""
     if cfg.latent:
         return 3.0 * _latent_forward_flops(cfg, t, causal)
+    if cfg.served_only:
+        return 3.0 * _gqa_forward_flops(cfg, t, causal)
     e, hd, f, x = (cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff,
                    cfg.n_experts)
     attn = (2 if causal else 4) * t * hd
@@ -271,9 +367,12 @@ def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
         return _init_hybrid_params(key, cfg, n_stages)
     if cfg.latent:
         return _init_latent_params(key, cfg, n_stages)
+    if cfg.moe_router == "sigmoid":
+        return _init_held_expert_params(key, cfg, n_stages)
     assert cfg.n_layers % n_stages == 0
     lps = cfg.n_layers // n_stages
     e, h, d, f, x = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff, cfg.n_experts
+    h_kv = cfg.kv_heads
     keys = iter(jax.random.split(key, 16))
 
     def norm(k, shape, scale=0.02):
@@ -283,20 +382,74 @@ def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
         "ln1": jnp.ones((n_stages, lps, e), cfg.jdtype),
         "ln2": jnp.ones((n_stages, lps, e), cfg.jdtype),
         "wq": norm(next(keys), (n_stages, lps, e, h, d)),
-        "wk": norm(next(keys), (n_stages, lps, e, h, d)),
-        "wv": norm(next(keys), (n_stages, lps, e, h, d)),
+        "wk": norm(next(keys), (n_stages, lps, e, h_kv, d)),
+        "wv": norm(next(keys), (n_stages, lps, e, h_kv, d)),
         "wo": norm(next(keys), (n_stages, lps, h, d, e)),
         "gate": norm(next(keys), (n_stages, lps, e, x)),
         "w_in": norm(next(keys), (n_stages, lps, x, e, f)),
         "w_gate": norm(next(keys), (n_stages, lps, x, e, f)),
         "w_out": norm(next(keys), (n_stages, lps, x, f, e)),
     }
-    return {
+    if cfg.parallel_block:
+        del blk["ln2"]
+    tree = {
         "embed": norm(next(keys), (cfg.vocab, e)),
         "unembed": norm(next(keys), (e, cfg.vocab)),
         "ln_f": jnp.ones((e,), cfg.jdtype),
         "blocks": blk,
     }
+    if cfg.tie_embeddings:
+        del tree["unembed"]
+    return tree
+
+
+def _init_held_expert_params(key, cfg: TransformerConfig, n_stages: int = 1):
+    """The widened MHA family with held experts (``moe_router=
+    "sigmoid"``), served only: ``layers`` is a LIST of one dict a layer
+    (``ln1``, ``ln2`` unless the block is parallel, ``wq [E, H, d]``,
+    ``wk`` / ``wv [E, H_kv, d]``, ``wo [H, d, E]``, the router ``gate
+    [E, moe_n_routed]`` and the shared experts side by side ``s_in`` /
+    ``s_gate [E, n_shared x F]``, ``s_out [n_shared x F, E]``), an array
+    a layer and matrix because a decode step would copy its layer's
+    slice out of a stack (689 MB a layer at Command A+'s widths); the
+    held routed experts of ALL layers stay one stack ``experts``
+    (``w_in`` / ``w_gate [L x X, E, F]``, ``w_out [L x X, F, E]``, layer
+    i's at [i X, (i + 1) X)), which is how the grouped product takes
+    them (:func:`_moe_held_ffn`).  ``unembed`` is absent where the head
+    is tied to ``embed``."""
+    assert n_stages == 1
+    e, h, h_kv, d = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    x, fm, fs = cfg.n_experts, cfg.moe_d_ff, cfg.moe_n_shared * cfg.moe_d_ff
+    routed = cfg.moe_n_routed or x
+    n = cfg.n_layers
+    keys = iter(jax.random.split(key, 8 * n + 8))
+
+    def norm(*shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * 0.02).astype(cfg.jdtype)
+
+    def ones():
+        return jnp.ones((e,), cfg.jdtype)
+
+    def layer():
+        p = {"ln1": ones(), "wq": norm(e, h, d), "wk": norm(e, h_kv, d),
+             "wv": norm(e, h_kv, d), "wo": norm(h, d, e),
+             "gate": norm(e, routed)}
+        if not cfg.parallel_block:
+            p["ln2"] = ones()
+        if fs:
+            p.update(s_in=norm(e, fs), s_gate=norm(e, fs), s_out=norm(fs, e))
+        return p
+
+    tree = {
+        "embed": norm(cfg.vocab, e), "ln_f": ones(),
+        "layers": [layer() for _ in range(n)],
+        "experts": {"w_in": norm(n * x, e, fm), "w_gate": norm(n * x, e, fm),
+                    "w_out": norm(n * x, fm, e)},
+    }
+    if not cfg.tie_embeddings:
+        tree["unembed"] = norm(e, cfg.vocab)
+    return tree
 
 
 def _init_latent_params(key, cfg: TransformerConfig, n_stages: int = 1):
@@ -631,10 +784,12 @@ def forward_local(params, ids, labels, cfg: TransformerConfig, axes: ShardAxes,
     would transpose into one fused gradient reduction at the very end
     of backward, fully exposed.
     """
-    if cfg.latent:
+    if cfg.served_only:
         raise NotImplementedError(
-            "the latent block (attention='mla') is served only: it has no "
-            "train step, sharding specs or backward attention kernel yet")
+            "this block (latent attention, held experts, grouped heads, a "
+            "sliding window, LayerNorm, a parallel block or a tied head) "
+            "is served only: it has no train step, sharding specs or "
+            "backward attention kernel yet")
     b, t_local = ids.shape
     sp_rank = lax.axis_index(axes.sp) if axes.sp is not None else 0
     positions = sp_rank * t_local + jnp.arange(t_local)
@@ -693,52 +848,202 @@ def decode_flops_per_token(cfg: TransformerConfig, ctx: int) -> float:
     return train_flops_per_token(cfg, ctx, causal=False) / 3.0
 
 
-def _causal_attention(q, k, v, scale=None):
+def _causal_attention(q, k, v, scale=None, span: int = 0, q_offset=None):
     """Causal full-sequence attention with the whole sequence on this
     device (serving prefill; training without an sp axis): the Pallas
     flash kernel — O(T) memory instead of a materialized [B,H,T,T]
     score matrix — or the lax oracle, as ops/dispatch decides.  ``v``
-    may have its own head size (latent attention: qk 192, v 128)."""
+    may have its own head size (latent attention: qk 192, v 128), k
+    and v fewer heads than q (grouped-query), ``span`` > 0 is a sliding
+    window and ``q_offset`` places q's rows at ``q_offset + i`` against
+    the keys (``ops.flash_attention.flash_attention``)."""
     from ..ops import dispatch
     from ..ops import flash_attention as _flash
 
     mode = dispatch.choose(_flash.supports(q.shape, k.shape, v.shape))
-    if mode == dispatch.LAX:
+    plain = k.shape[2] == q.shape[2] and not span and q_offset is None
+    if mode == dispatch.LAX and plain:
         return ring_attention_reference(q, k, v, causal=True, scale=scale)
+    if mode == dispatch.LAX:
+        return _flash.lax_attention(
+            q, k, v, scale=q.shape[-1] ** -0.5 if scale is None else scale,
+            span=span, q_offset=q_offset)
     return _flash.flash_attention(q, k, v, causal=True, scale=scale,
-                                  interpret=mode == dispatch.INTERPRET)
+                                  interpret=mode == dispatch.INTERPRET,
+                                  span=span, q_offset=q_offset)
 
 
 def _layer_params(blocks, stage: int, layer: int):
     return jax.tree.map(lambda a: a[stage, layer], blocks)
 
 
-def _prefill_trunk(params, ids, cfg: TransformerConfig):
-    """All prefill layers up to (and including) the final norm:
-    returns ``(x [B, T, E], k, v [L, B, T, H, hd])`` — shared by the
-    full-logits and last-position heads below."""
-    _, t = ids.shape
-    positions = jnp.arange(t)
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
+#: rows of a prompt one pass of a prefill layer takes where the prompt
+#: is longer: q, o and the FFN's hidden activations of 8k rows are a
+#: quarter of a 32k prompt's, which beside 9.5 GB of weights would not
+#: fit; K and V (67 MB a layer at 32k and 8 K/V heads) stay whole and
+#: every chunk of rows attends them at its offset
+PREFILL_ROWS = 8192
+
+
+def _norm(x, scale, cfg: TransformerConfig):
+    if cfg.norm == "layer":
+        return layer_norm(x, scale, cfg.norm_eps)
+    return rms_norm(x, scale, cfg.norm_eps)
+
+
+def _into_stream(cfg: TransformerConfig) -> dict:
+    """Keywords of a product whose result joins the residual stream:
+    none where the stream has the operands' type (the flagship's
+    programs lower as they always did)."""
+    if cfg.stream_dtype == cfg.jdtype:
+        return {}
+    return {"preferred_element_type": cfg.stream_dtype}
+
+
+def _mha_layers(params, cfg: TransformerConfig):
+    """Yields ``(kind, layer params, first_group)`` in layer order for
+    either tree of the MHA family: the stacked ``blocks`` (``first_group``
+    None), or the per-layer ``layers`` beside the one stack of held
+    experts, the layer's own at ``first_group`` (see
+    :func:`_init_held_expert_params`, :func:`_moe_held_ffn`)."""
+    kinds = cfg.layer_kinds
+    if "layers" in params:
+        for i, p in enumerate(params["layers"]):
+            yield kinds[i], {**p, **params["experts"]}, i * cfg.n_experts
+        return
     blocks = params["blocks"]
-    n_stages, lps = blocks["ln1"].shape[0], blocks["ln1"].shape[1]
-    ks, vs = [], []
+    n_stages, lps = blocks["ln1"].shape[:2]
     for s in range(n_stages):
         for i in range(lps):
-            p = _layer_params(blocks, s, i)
-            xn = rms_norm(x, p["ln1"])
-            q = jnp.einsum("bte,ehd->bthd", xn, p["wq"])
-            k = jnp.einsum("bte,ehd->bthd", xn, p["wk"])
-            v = jnp.einsum("bte,ehd->bthd", xn, p["wv"])
-            q = rope(q, positions)
-            k = rope(k, positions)
-            o = _causal_attention(q, k, v)
-            x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
-            x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
-            ks.append(k)
-            vs.append(v)
-    x = rms_norm(x, params["ln_f"])
-    return x, jnp.stack(ks), jnp.stack(vs)
+            yield kinds[s * lps + i], _layer_params(blocks, s, i), None
+
+
+def _rotates(kind: str, cfg: TransformerConfig) -> bool:
+    """Whether a layer of this kind rotates q and k by position."""
+    return kind != "full" or cfg.full_layers_rope
+
+
+def _mha_ffn(xn, p, first_group, cfg: TransformerConfig, valid, counts):
+    """The layer's FFN on normed activations: the trained mixtures, or
+    the held experts (their routing counts appended to ``counts``)."""
+    if cfg.moe_router != "sigmoid":
+        return _moe_ffn(xn.astype(cfg.jdtype), p, ShardAxes(), cfg)
+    with jax.named_scope("moe"):
+        y, c = _moe_held_ffn(xn, p, cfg, valid, first_group)
+    counts.append(c)
+    return y
+
+
+def _ffn_half(x, xn, attn, p, first_group, cfg, valid, counts):
+    """The residual stream after a layer.  Sequential block: ``x``
+    already holds attention's addend (``attn`` None), and the FFN takes
+    a second norm of it.  Parallel block: the FFN takes the layer's one
+    normed input ``xn`` and both addends land together."""
+    if cfg.parallel_block:
+        return x + attn + _mha_ffn(xn, p, first_group, cfg, valid, counts)
+    return x + _mha_ffn(_norm(x, p["ln2"], cfg), p, first_group, cfg, valid,
+                        counts)
+
+
+#: the named scope of a layer kind's attention half, projections
+#: included, in both serving programs ("mha": in decode alone, as the
+#: flagship's programs always had it)
+_ATTN_SCOPE = {"full": "attn_full", "sliding": "attn_sliding"}
+
+
+def _scope(name):
+    """``jax.named_scope(name)``, or nothing where there is no name."""
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def _mha_prefill_layer(x, p, first_group, kind, cfg: TransformerConfig,
+                       valid, counts):
+    """One layer over a whole prompt ``x`` [B, T, E]: ``(x, k, v)`` with
+    k, v ``[B, T, H_kv, hd]`` as the cache holds them.  A prompt longer
+    than :data:`PREFILL_ROWS` (a multiple of it) is walked in chunks of
+    rows against the whole K and V."""
+    b, t, e = x.shape
+    span = cfg.sliding_window if kind == "sliding" else 0
+    scope = _ATTN_SCOPE.get(kind)
+    n_chunks = t // PREFILL_ROWS if (
+        cfg.served_only and t > PREFILL_ROWS and t % PREFILL_ROWS == 0) else 1
+    rows = t // n_chunks
+    xn = _norm(x, p["ln1"], cfg)
+    with _scope(scope):
+        xa = xn.astype(cfg.jdtype)  # the products' operand
+        k = jnp.einsum("bte,ehd->bthd", xa, p["wk"])
+        v = jnp.einsum("bte,ehd->bthd", xa, p["wv"])
+        if _rotates(kind, cfg):
+            k = rope(k, jnp.arange(t), cfg.rope_theta)
+
+    def some_rows(x, xn, valid, first):
+        """Rows [first, first + rows) of the prompt through the layer."""
+        layer_counts = []
+        with _scope(scope):
+            q = jnp.einsum("bte,ehd->bthd", xn.astype(cfg.jdtype), p["wq"])
+            if _rotates(kind, cfg):
+                q = rope(q, first + jnp.arange(rows), cfg.rope_theta)
+            # the scope states the call's T, window and chunks: the
+            # benchmark's roofline reader counts each call's operations
+            # from them
+            with _scope(scope and f"prefill_attn_t{t}_w{span}_c{n_chunks}"):
+                o = _causal_attention(
+                    q, k, v, span=span,
+                    q_offset=None if n_chunks == 1 else first)
+            attn = jnp.einsum("bthd,hde->bte", o, p["wo"],
+                              **_into_stream(cfg))
+            if not cfg.parallel_block:
+                x, attn = x + attn, None
+        return _ffn_half(x, xn, attn, p, first_group, cfg, valid,
+                         layer_counts), layer_counts
+
+    if n_chunks == 1:
+        x, layer_counts = some_rows(x, xn, valid, 0)
+        counts.extend(layer_counts)
+        return x, k, v
+
+    def chunked(a):  # [B, T, ...] -> [n_chunks, B, rows, ...]
+        return jnp.moveaxis(a.reshape((b, n_chunks, rows) + a.shape[2:]), 1, 0)
+
+    def one(args):
+        # the chunk's rows normed again: the whole prompt's, which K
+        # and V were made of, need not outlive them
+        i, x_c, valid_c = args
+        return some_rows(x_c, _norm(x_c, p["ln1"], cfg), valid_c, i * rows)
+
+    valid_all = jnp.ones((b, t), bool) if valid is None else valid
+    x, layer_counts = lax.map(one, (jnp.arange(n_chunks), chunked(x),
+                                    chunked(valid_all)))
+    counts.extend(c.sum(axis=0) for c in layer_counts)
+    return jnp.moveaxis(x, 0, 1).reshape(b, t, e), k, v
+
+
+def _prefill_trunk(params, ids, cfg: TransformerConfig, valid=None):
+    """All prefill layers up to (and including) the final norm:
+    returns ``(x [B, T, E], ks, vs, counts)``: per layer the keys and
+    values ``[B, T, H_kv, hd]`` as the cache holds them (post-rope), and
+    the held-expert layers' routing counts (none for the trained
+    mixtures) — shared by the heads below."""
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
+        cfg.stream_dtype)
+    ks, vs, counts = [], [], []
+    for kind, p, first_group in _mha_layers(params, cfg):
+        x, k, v = _mha_prefill_layer(x, p, first_group, kind, cfg, valid,
+                                     counts)
+        ks.append(k)
+        vs.append(v)
+    return _norm(x, params["ln_f"], cfg), ks, vs, counts
+
+
+def _unembed(params, x, cfg: TransformerConfig):
+    """Logits of hidden states ``x`` [..., T, E]: the ``unembed``
+    matrix, or the tied embedding scaled by ``logit_scale``."""
+    x = x.astype(cfg.jdtype)
+    if cfg.tie_embeddings:
+        return cfg.logit_scale * jnp.einsum(
+            "bte,ve->btv", x, params["embed"], **_into_stream(cfg))
+    return jnp.einsum("bte,ev->btv", x, params["unembed"],
+                      **_into_stream(cfg))
 
 
 def forward_prefill(params, ids, cfg: TransformerConfig):
@@ -751,12 +1056,11 @@ def forward_prefill(params, ids, cfg: TransformerConfig):
     token, so their K/V and logits are unaffected — the serving engine
     pads prompts to length buckets to bound jit recompilation.
     """
-    x, k, v = _prefill_trunk(params, ids, cfg)
-    logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
-    return logits, k, v
+    x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
+    return _unembed(params, x, cfg), jnp.stack(ks), jnp.stack(vs)
 
 
-def _logits_at(params, x, last_index):
+def _logits_at(params, x, last_index, cfg: TransformerConfig):
     """Unembed ONE position per sequence: ``x [B, T, E]`` at
     ``last_index [B]`` -> ``[B, V]``.  The unembed is the model's
     largest single matmul at flagship vocab — projecting all T padded
@@ -764,7 +1068,7 @@ def _logits_at(params, x, last_index):
     prefill's dominant term by T."""
     x_last = jnp.take_along_axis(
         x, last_index[:, None, None].astype(jnp.int32), axis=1)  # [B,1,E]
-    return jnp.einsum("bte,ev->btv", x_last, params["unembed"])[:, 0]
+    return _unembed(params, x_last, cfg)[:, 0]
 
 
 def forward_prefill_last(params, ids, last_index, cfg: TransformerConfig):
@@ -772,8 +1076,17 @@ def forward_prefill_last(params, ids, last_index, cfg: TransformerConfig):
     ``(logits [B, V], k, v)`` for ``last_index`` [B] (each sequence's
     final real token in a right-padded batch).  The K/V come back
     dense: what :func:`forward_prefill_paged` must put into the pools."""
-    x, k, v = _prefill_trunk(params, ids, cfg)
-    return _logits_at(params, x, last_index), k, v
+    x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
+    return (_logits_at(params, x, last_index, cfg), jnp.stack(ks),
+            jnp.stack(vs))
+
+
+def _paged(a, pool):
+    """K or V of ONE sequence's whole blocks ``[..., 1, n x bs, H_kv,
+    hd]`` as pages ``[..., n, bs, H_kv, hd]`` in the pool's dtype."""
+    bs = pool.shape[2]
+    return a.reshape(a.shape[:-4] + (a.shape[-3] // bs, bs)
+                     + a.shape[-2:]).astype(pool.dtype)
 
 
 def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
@@ -782,7 +1095,7 @@ def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
     on the device: ``(logits [1, V], k_pool, v_pool)``.
 
     ids [1, T] with T a whole number of blocks (the engine pads prompts
-    so); k_pool / v_pool [L, n_blocks, block_size, H, hd]; block_ids
+    so); k_pool / v_pool [L, n_blocks, block_size, H_kv, hd]; block_ids
     [T / block_size] int32, the sequence's block table.  Logical block
     j of every layer lands in physical block ``block_ids[j]``, the
     layout :func:`forward_decode_paged` reads.  Slots of the last block
@@ -792,16 +1105,47 @@ def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
     uncommitted slots).  The caller donates the pools, so the scatter
     is in place and no K/V leaves the device.
     """
-    x, k, v = _prefill_trunk(params, ids, cfg)
-    n_layers, _, t, h, d = k.shape
-    bs = k_pool.shape[2]
+    x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
+    k_pool = k_pool.at[:, block_ids].set(_paged(jnp.stack(ks), k_pool))
+    v_pool = v_pool.at[:, block_ids].set(_paged(jnp.stack(vs), v_pool))
+    return _logits_at(params, x, last_index, cfg), k_pool, v_pool
 
-    def paged(a, pool):
-        return a.reshape(n_layers, t // bs, bs, h, d).astype(pool.dtype)
 
-    k_pool = k_pool.at[:, block_ids].set(paged(k, k_pool))
-    v_pool = v_pool.at[:, block_ids].set(paged(v, v_pool))
-    return _logits_at(params, x, last_index), k_pool, v_pool
+def forward_prefill_paged_swa(params, ids, last_index, k_pool, v_pool,
+                              ks_pool, vs_pool, block_ids, sliding_ids,
+                              cfg: TransformerConfig):
+    """:func:`forward_prefill_paged` of a model with sliding-window
+    layers, whose K/V have pools and a block table of their own: the
+    full layers' K/V go whole into ``k_pool`` / ``v_pool`` ``[n full
+    layers, n_blocks, ...]`` at ``block_ids``; of a sliding layer's
+    only the prompt's last ``len(sliding_ids)`` blocks are written,
+    into ``ks_pool`` / ``vs_pool`` ``[n sliding layers, n sliding
+    blocks, ...]`` at ``sliding_ids`` (in logical order: the ring's
+    entries as ``PagedKVCache.sliding_prefill_ids`` gives them), because
+    no later query's window reaches further back.  All four pools are
+    donated and updated in place.  Returns ``(logits [1, V], k_pool,
+    v_pool, ks_pool, vs_pool[, moe])``, ``moe [n_layers, n_experts +
+    1]`` the routing counts of the prompt's real tokens where the
+    experts are held ones."""
+    t = ids.shape[1]
+    valid = jnp.arange(t)[None] <= last_index[:, None]
+    x, ks, vs, counts = _prefill_trunk(params, ids, cfg, valid)
+    tail = sliding_ids.shape[0] * k_pool.shape[2]  # tokens a ring keeps
+    at = {"full": 0, "sliding": 0}
+    for kind, k, v in zip(cfg.layer_kinds, ks, vs):
+        i = at[kind]
+        at[kind] += 1
+        if kind == "full":
+            k_pool = k_pool.at[i, block_ids].set(_paged(k, k_pool))
+            v_pool = v_pool.at[i, block_ids].set(_paged(v, v_pool))
+        else:
+            ks_pool = ks_pool.at[i, sliding_ids].set(
+                _paged(k[:, t - tail:], ks_pool))
+            vs_pool = vs_pool.at[i, sliding_ids].set(
+                _paged(v[:, t - tail:], vs_pool))
+    moe = (jnp.stack(counts),) if counts else ()
+    return (_logits_at(params, x, last_index, cfg), k_pool, v_pool, ks_pool,
+            vs_pool) + moe
 
 
 def _rope_window(x, positions, theta: float = 10000.0):
@@ -819,13 +1163,81 @@ def _rope_window(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
+def _paged_decode(params, ids, positions, pools, tables, lengths,
+                  cfg: TransformerConfig):
+    """The decode window step of the MHA family over any set of layer
+    kinds: ``pools[kind]`` is that kind's ``(k_pool, v_pool)`` ``[its
+    layers, its blocks, block_size, H_kv, hd]`` and ``tables[kind]``
+    its block tables ``[B, W]`` (a sliding kind's the ring table, W
+    fixed by the window).  Returns ``(logits [B, S, V], pools, counts)``
+    (see :func:`forward_decode_paged`)."""
+    from ..ops import paged_attention as _paged_attn
+
+    b, s_w = ids.shape
+    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
+    where = {}
+    for kind, (k_pool, _) in pools.items():
+        # physical scatter addresses for the window: logical block ->
+        # table lookup -> (block, slot); dead rows go out of bounds
+        n_blocks, bs = k_pool.shape[1], k_pool.shape[2]
+        table = tables[kind]
+        lb = pos_w // bs
+        lb = lb % table.shape[1] if kind == "sliding" \
+            else jnp.clip(lb, 0, table.shape[1] - 1)
+        wb = jnp.take_along_axis(table, lb, axis=1)
+        where[kind] = (jnp.where(lengths[:, None] > 0, wb, n_blocks),
+                       pos_w % bs)                               # OOB-drop
+    valid = jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
+        cfg.stream_dtype)
+    at = dict.fromkeys(pools, 0)
+    counts = []
+    for kind, p, first_group in _mha_layers(params, cfg):
+        li = at[kind]
+        at[kind] += 1
+        k_pool, v_pool = pools[kind]
+        wb, ws = where[kind]
+        with jax.named_scope(_ATTN_SCOPE.get(kind, "attention")):
+            xn = _norm(x, p["ln1"], cfg)
+            xa = xn.astype(cfg.jdtype)  # the products' operand
+            q = jnp.einsum("bte,ehd->bthd", xa, p["wq"])
+            k = jnp.einsum("bte,ehd->bthd", xa, p["wk"])
+            v = jnp.einsum("bte,ehd->bthd", xa, p["wv"])
+            if _rotates(kind, cfg):
+                q = _rope_window(q, positions, cfg.rope_theta)
+                k = _rope_window(k, positions, cfg.rope_theta)
+            k_pool = k_pool.at[li, wb, ws].set(
+                k.astype(k_pool.dtype), mode="drop")
+            v_pool = v_pool.at[li, wb, ws].set(
+                v.astype(v_pool.dtype), mode="drop")
+            pools[kind] = (k_pool, v_pool)
+            # the layers' pools as one run of pages, a free view:
+            # a per-layer slice of a pool would be copied for the
+            # kernel
+            o = _paged_attn.paged_attention(
+                q, k_pool.reshape((-1,) + k_pool.shape[2:]),
+                v_pool.reshape((-1,) + v_pool.shape[2:]),
+                tables[kind] + li * k_pool.shape[1], lengths,
+                span=cfg.sliding_window if kind == "sliding" else 0)
+            attn = jnp.einsum("bthd,hde->bte", o, p["wo"],
+                              **_into_stream(cfg))
+            if not cfg.parallel_block:
+                x, attn = x + attn, None
+        # the held experts open their own scope, "moe"
+        with _scope(None if cfg.moe_router == "sigmoid" else "mlp"):
+            x = _ffn_half(x, xn, attn, p, first_group, cfg, valid, counts)
+    with jax.named_scope("unembed"):
+        logits = _unembed(params, _norm(x, params["ln_f"], cfg), cfg)
+    return logits, pools, counts
+
+
 def forward_decode_paged(params, ids, positions, k_pool, v_pool,
                          block_tables, lengths, cfg: TransformerConfig):
     """Decode window step attending the paged KV pool IN PLACE.
 
     The fast path: no dense gather, no re-placement copy.  ids /
     positions [B, S] (S=1 plain decode, S=k+1 speculative verify);
-    k_pool / v_pool [L, n_blocks, block_size, H, hd] — the cache's
+    k_pool / v_pool [L, n_blocks, block_size, H_kv, hd] — the cache's
     device-resident pools; block_tables [B, W] int32 (rows padded with
     0); lengths [B] int32 committed tokens per row.
 
@@ -853,53 +1265,32 @@ def forward_decode_paged(params, ids, positions, k_pool, v_pool,
     length by what it commits — window slots past that hold garbage by
     the same contract as gather padding).
     """
-    from ..ops import paged_attention as _paged
+    logits, pools, _ = _paged_decode(
+        params, ids, positions, {"mha": (k_pool, v_pool)},
+        {"mha": block_tables}, lengths, cfg)
+    return (logits,) + pools["mha"]
 
-    b, s_w = ids.shape
-    n_blocks = k_pool.shape[1]
-    bs = k_pool.shape[2]
-    # physical scatter addresses for the window: logical block ->
-    # table lookup -> (block, slot); dead rows go out of bounds
-    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
-    lb = pos_w // bs
-    wb = jnp.take_along_axis(block_tables,
-                             jnp.clip(lb, 0, block_tables.shape[1] - 1),
-                             axis=1)
-    wb = jnp.where(lengths[:, None] > 0, wb, n_blocks)           # OOB-drop
-    ws = pos_w % bs
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
-    blocks = params["blocks"]
-    n_stages, lps = blocks["ln1"].shape[0], blocks["ln1"].shape[1]
-    li = 0
-    for s in range(n_stages):
-        for i in range(lps):
-            p = _layer_params(blocks, s, i)
-            with jax.named_scope("attention"):
-                xn = rms_norm(x, p["ln1"])
-                q = jnp.einsum("bte,ehd->bthd", xn, p["wq"])
-                k = jnp.einsum("bte,ehd->bthd", xn, p["wk"])
-                v = jnp.einsum("bte,ehd->bthd", xn, p["wv"])
-                q = _rope_window(q, positions)
-                k = _rope_window(k, positions)
-                k_pool = k_pool.at[li, wb, ws].set(
-                    k.astype(k_pool.dtype), mode="drop")
-                v_pool = v_pool.at[li, wb, ws].set(
-                    v.astype(v_pool.dtype), mode="drop")
-                # the layers' pools as one run of pages, a free view:
-                # a per-layer slice of a pool would be copied for the
-                # kernel
-                o = _paged.paged_attention(
-                    q, k_pool.reshape((-1,) + k_pool.shape[2:]),
-                    v_pool.reshape((-1,) + v_pool.shape[2:]),
-                    block_tables + li * n_blocks, lengths)
-                x = x + jnp.einsum("bthd,hde->bte", o, p["wo"])
-            with jax.named_scope("mlp"):
-                x = x + _moe_ffn(rms_norm(x, p["ln2"]), p, ShardAxes(), cfg)
-            li += 1
-    with jax.named_scope("unembed"):
-        x = rms_norm(x, params["ln_f"])
-        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
-    return logits, k_pool, v_pool
+
+def forward_decode_paged_swa(params, ids, positions, k_pool, v_pool,
+                             ks_pool, vs_pool, block_tables, lengths,
+                             sliding_tables, cfg: TransformerConfig):
+    """:func:`forward_decode_paged` of a model with sliding-window
+    layers (one token a row: a ring has no room for a verify window).
+    The full layers read and write ``k_pool`` / ``v_pool`` through
+    ``block_tables`` [B, W] as ever; a sliding layer writes the token
+    into ``ks_pool`` / ``vs_pool`` at its ring table's entry
+    ``(position // block_size) mod R`` (``sliding_tables`` [B, R], R
+    fixed by the window) and attends the keys ``position - window < j
+    <= position`` alone, its walk starting at the page of the oldest.
+    Returns ``(logits [B, 1, V], k_pool, v_pool, ks_pool, vs_pool[,
+    moe])``."""
+    assert ids.shape[1] == 1, "a ring table holds one decode token's reach"
+    logits, pools, counts = _paged_decode(
+        params, ids, positions,
+        {"full": (k_pool, v_pool), "sliding": (ks_pool, vs_pool)},
+        {"full": block_tables, "sliding": sliding_tables}, lengths, cfg)
+    moe = (jnp.stack(counts),) if counts else ()
+    return (logits,) + pools["full"] + pools["sliding"] + moe
 
 
 # ---------------------------------------------------------------------------
@@ -1118,13 +1509,16 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     ``valid`` [B, T] marks (default all)."""
     b, t, e = x.shape
     n, k, x_l = b * t, cfg.moe_topk, cfg.n_experts
-    xf = x.reshape(n, e)
     # float32 from the operands as stored: products of bf16 values are
     # exact in float32, so accumulating there IS the float32 router,
-    # without a float32 copy of the activations
+    # without a float32 copy of the activations (under a float32
+    # residual stream the router reads x unrounded; the experts' products
+    # take it in the weights' type)
     scores = jax.nn.sigmoid(jnp.einsum(
-        "ne,ex->nx", xf, p["gate"], preferred_element_type=jnp.float32,
+        "ne,ex->nx", x.reshape(n, e), p["gate"],
+        preferred_element_type=jnp.float32,
         precision=lax.Precision.HIGHEST))
+    xf = x.reshape(n, e).astype(cfg.jdtype)
     if cfg.moe_n_group:
         top_s, top_i = _group_limited_top_k(scores, p.get("gate_bias"), cfg)
     else:
@@ -1163,7 +1557,11 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
         xs = jnp.take(xf, rows, axis=0)
         hidden = lax.ragged_dot(xs, p["w_in"], groups) * jax.nn.silu(
             lax.ragged_dot(xs, p["w_gate"], groups))
-        out = lax.ragged_dot(hidden, p["w_out"], groups)
+        # under a float32 stream the pairs' results are weighed and
+        # summed as the product accumulated them
+        out = lax.ragged_dot(hidden, p["w_out"], groups,
+                             preferred_element_type=None
+                             if x.dtype == xf.dtype else jnp.float32)
         # rows past the held pairs belong to no group: their product is
         # whatever the buffer held, so they are selected out, not scaled
         held = (lo + jnp.arange(tile) < n_held)[:, None]
@@ -1174,7 +1572,14 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
                       jnp.zeros((n, e), jnp.float32))
     y = y.astype(x.dtype).reshape(b, t, e)
     if cfg.moe_n_shared:
-        y = y + swiglu_ffn(x, p["s_in"], p["s_gate"], p["s_out"], ShardAxes())
+        # the shared experts lie side by side in one SwiGLU, which sums
+        # them; their average is that over their number
+        shared = swiglu_ffn(xf.reshape(b, t, e), p["s_in"], p["s_gate"],
+                            p["s_out"], ShardAxes(),
+                            **({} if x.dtype == xf.dtype
+                               else {"out_dtype": x.dtype}))
+        y = y + (shared / cfg.moe_n_shared if cfg.moe_shared_average
+                 else shared)
     routed = jnp.asarray(n * k, jnp.int32)
     if valid is not None:
         sizes = per_expert(jnp.where(valid.reshape(n, 1), key, x_l))
@@ -1251,7 +1656,7 @@ def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
             pool = _write_prompt_rows(pool, li, block_ids, row)
         x = _latent_ffn(x, p, first_group, cfg, valid, counts)
     x = rms_norm(x, params["ln_f"])
-    return _logits_at(params, x, last_index), pool, jnp.stack(counts)
+    return _logits_at(params, x, last_index, cfg), pool, jnp.stack(counts)
 
 
 def _write_latent_rows(pool, layer: int, blocks, slots, row):
@@ -1452,7 +1857,7 @@ def forward_prefill_paged_hybrid(params, ids, last_index, pool, state, tails,
                     tail.reshape(tails.shape[2:]).astype(tails.dtype))
         x = _latent_ffn(x + y, ffn, first_group, cfg, valid, counts)
     x = rms_norm(x, params["ln_f"])
-    return (_logits_at(params, x, last_index), pool, state, tails,
+    return (_logits_at(params, x, last_index, cfg), pool, state, tails,
             jnp.stack(counts))
 
 

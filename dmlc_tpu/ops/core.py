@@ -52,6 +52,15 @@ def rms_norm(x, scale, eps: float = 1e-6):
     return (x.astype(jnp.float32) * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def layer_norm(x, scale, eps: float = 1e-5):
+    """LayerNorm with a weight and no bias: mean and variance over the
+    last axis, in float32."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xc), axis=-1, keepdims=True)
+    return (xc * lax.rsqrt(var + eps)).astype(x.dtype) * scale
+
+
 def rope(x, positions, theta: float = 10000.0):
     """Rotary position embedding.  x: [B, T, H, D], positions: [T] global."""
     d = x.shape[-1]
@@ -112,13 +121,15 @@ def softmax_xent(logits_local, labels, axes: ShardAxes):
     return lse - correct
 
 
-def swiglu_ffn(x, w_in, w_gate, w_out, axes: ShardAxes, *, reduce: bool = True):
+def swiglu_ffn(x, w_in, w_gate, w_out, axes: ShardAxes, *, reduce: bool = True,
+               out_dtype=None):
     """Megatron-style column/row-parallel SwiGLU FFN.
 
     w_in/w_gate: [E, F_local] (column shards); w_out: [F_local, E] (row
     shard); the single psum over tp happens at the output (row-parallel),
     skipped with reduce=False so callers can batch it with other partial
-    sums (MoE).
+    sums (MoE).  ``out_dtype`` asks the output product for another type
+    than its operands' (a float32 residual stream under bf16 weights).
     """
     from jax.ad_checkpoint import checkpoint_name
 
@@ -130,7 +141,8 @@ def swiglu_ffn(x, w_in, w_gate, w_out, axes: ShardAxes, *, reduce: bool = True):
     # recompute in a rematerialized block (models.TransformerConfig
     # remat_policy='save_flash_mlp')
     h = checkpoint_name(h, "mlp_act")
-    y = jnp.einsum("...f,fe->...e", h, w_out)
+    y = jnp.einsum("...f,fe->...e", h, w_out,
+                   preferred_element_type=out_dtype)
     if reduce and axes.tp is not None:
         y = lax.psum(y, axes.tp)
     return y

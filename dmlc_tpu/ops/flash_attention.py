@@ -61,15 +61,29 @@ def _out_struct(shape, dtype, *operands):
 # one predicated no-op visit (pl.when) instead of compute.
 
 
+def _first_kv_block(first_q, kvoff, span: int, block_k: int):
+    """The KV block that holds the oldest key the query at global
+    position ``first_q`` sees under a sliding window of ``span`` keys
+    (itself and the span - 1 before it)."""
+    return jnp.maximum(first_q - (span - 1) - kvoff, 0) // block_k
+
+
 def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
             pv_ref, m_ref, l_ref, *, block_q: int, block_k: int,
-            causal: bool, kv_padded: bool, scale: float, interpret: bool):
+            causal: bool, kv_padded: bool, scale: float, interpret: bool,
+            span: int = 0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     j = pl.program_id(2)
+    first = j == 0
+    if span:
+        # under a window the grid's KV axis is only as long as a Q
+        # block's reach: step 0 is the block of its oldest visible key
+        j = j + _first_kv_block(qoff_ref[0] + qi * block_q, kvoff_ref[0],
+                                span, block_k)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         pv_ref[...] = jnp.zeros_like(pv_ref[...])
         m_ref[...] = jnp.full_like(m_ref[...], _NEG_BIG)
@@ -95,6 +109,8 @@ def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
                     jnp.int32, (g, bq, bk), 2)
             if causal:
                 keep = q_pos >= k_pos
+            if span:
+                keep = keep & (q_pos - k_pos < span)
             if kv_padded:
                 # tail KV rows past the real length are padding
                 in_range = k_pos < kvend_ref[0]
@@ -127,7 +143,7 @@ def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
     def walk():
         _dispatch_masked_step(pl, step, qi, j, block_q, block_k, causal,
                               kv_padded, kvend_ref, qoff=qoff_ref[0],
-                              kvoff=kvoff_ref[0])
+                              kvoff=kvoff_ref[0], span=span)
 
     if interpret:
         # The Pallas interpreter (jax 0.9.0) evaluates a kernel's
@@ -255,15 +271,24 @@ def _pad_seq(x, pad):
     return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
 
 
-def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False):
+def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False,
+                   span: int = 0):
     """``(pv, m, l)`` partials, or with ``normalized`` the finished
-    ``o [B, Tq, H, dv]`` in q's dtype (kernel ``flash_fwd_o``)."""
+    ``o [B, Tq, H, dv]`` in q's dtype (kernel ``flash_fwd_o``).  ``k``
+    and ``v`` may have fewer heads than ``q``: query head ``h`` reads
+    K/V head ``h // (H / H_kv)``, through the K/V blocks' index map, so
+    no head is repeated in memory.  ``span`` > 0 is a sliding window
+    (causal, query i sees keys i - span < j <= i): the grid's KV axis
+    covers a Q block's reach and no more."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     scale, causal, block_q, block_k, interpret = static[:5]
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, h_kv = k.shape[1], k.shape[2]
+    group = h // h_kv
+    assert h_kv * group == h, (h, h_kv)
+    assert causal or not span, "a sliding window is a causal mask"
     dv = v.shape[-1]  # its own size under latent attention (qk 192, v 128)
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
@@ -282,8 +307,8 @@ def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False):
     tq_p, tk_p = tq + tq_pad, tk + tk_pad
 
     qt = q.transpose(0, 2, 1, 3).reshape(bh, tq_p, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(bh, tk_p, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(bh, tk_p, dv)
+    kt = k.transpose(0, 2, 1, 3).reshape(b * h_kv, tk_p, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h_kv, tk_p, dv)
     kvend = kvoff + tk
 
     # The kernel body is written batched over G fused (b,h) pairs per
@@ -294,19 +319,46 @@ def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False):
     # on the flagship step — 52.4-53.2% vs 53.7% MFU at T=1024.
     gmax = get_env("DMLC_FLASH_BH_BLOCK", 0) or 1
     g = 1
-    while g * 2 <= gmax and bh % (g * 2) == 0:  # never exceed the cap
+    # grouped heads and a window map each (b, h) pair to K/V blocks of
+    # its own: nothing to fuse
+    while g * 2 <= gmax and bh % (g * 2) == 0 and group == 1 and not span:
         g *= 2
 
-    grid = (bh // g, tq_p // block_q, tk_p // block_k)
+    n_kv = tk_p // block_k
+    if group == 1 and not span:
+        kv_steps = n_kv
+
+        def kv_at(bi, qi, kj, *_):
+            return (bi, kj, 0)
+    else:
+        # the new callers' form: a step whose block is wholly masked
+        # (above the diagonal, behind the window, past a short walk's
+        # end) names the block its neighbour names, so the pipeline
+        # fetches nothing for it; under a window the KV axis is a Q
+        # block's reach, ``span + block_q`` keys, whatever T is
+        kv_steps = min(n_kv, (span + block_q - 2) // block_k + 2) \
+            if span else n_kv
+
+        def kv_at(bi, qi, kj, qoff_ref, kvoff_ref, _):
+            first_q = qoff_ref[0] + qi * block_q
+            if span:
+                kj = kj + _first_kv_block(first_q, kvoff_ref[0], span,
+                                          block_k)
+            if causal:
+                kj = jnp.minimum(kj, jnp.maximum(
+                    first_q + block_q - 1 - kvoff_ref[0], 0) // block_k)
+            return (bi // group, jnp.minimum(kj, n_kv - 1), 0)
+
+    grid = (bh // g, tq_p // block_q, kv_steps)
     in_specs = [
         pl.BlockSpec((g, block_q, d), lambda bi, qi, kj, *_: (bi, qi, 0)),
-        pl.BlockSpec((g, block_k, d), lambda bi, qi, kj, *_: (bi, kj, 0)),
-        pl.BlockSpec((g, block_k, dv), lambda bi, qi, kj, *_: (bi, kj, 0)),
+        pl.BlockSpec((g, block_k, d), kv_at),
+        pl.BlockSpec((g, block_k, dv), kv_at),
     ]
     operands = (qoff, kvoff, kvend, qt, kt, vt)
     kernel_static = dict(block_q=block_q, block_k=block_k, causal=causal,
                          kv_padded=kv_padded, scale=scale,
-                         interpret=bool(interpret))
+                         interpret=bool(interpret), span=int(span))
     if normalized:
         o = pl.pallas_call(
             functools.partial(_kernel_o, n_kv=grid[2], **kernel_static),
@@ -364,7 +416,7 @@ def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False):
 
 def _dispatch_masked_step(pl, step, qi, j, block_q: int, block_k: int,
                           causal: bool, kv_padded: bool, kvend_ref,
-                          qoff=0, kvoff=0):
+                          qoff=0, kvoff=0, span: int = 0):
     """Block-level mask classification (exact), shared by the forward
     and backward kernels: skip fully-invisible blocks, run the
     mask-free body on blocks the mask could not touch (all-keep), and
@@ -372,7 +424,10 @@ def _dispatch_masked_step(pl, step, qi, j, block_q: int, block_k: int,
     diagonal/padded-tail blocks — for every other visible block the
     mask would be all-True, and skipping it removes ~half the VPU work
     per step.  The forward passes its scalar-prefetch global offsets;
-    the backward runs in local positions (offsets 0)."""
+    the backward runs in local positions (offsets 0).  Under a
+    sliding window of ``span`` keys (forward only) a block wholly
+    behind the window is skipped like one above the diagonal, and one
+    the window's edge crosses is a boundary block."""
     first_q = qoff + qi * block_q
     last_q = first_q + block_q - 1
     kb_first = kvoff + j * block_k
@@ -381,6 +436,12 @@ def _dispatch_masked_step(pl, step, qi, j, block_q: int, block_k: int,
     boundary = None
     if causal:
         boundary = kb_last > first_q
+    if span:
+        # (a short walk's steps past the last KV block name blocks
+        # that do not exist)
+        visible = visible & (kb_last > first_q - span) & (
+            kb_first < kvend_ref[0])
+        boundary = boundary | (kb_first <= last_q - span)
     if kv_padded:
         pad = kb_last >= kvend_ref[0]
         boundary = pad if boundary is None else boundary | pad
@@ -632,16 +693,74 @@ def _flash_attn_bwd(static, res, do):
 _flash_attn.defvjp(_flash_attn_fwd, _flash_attn_bwd)
 
 
+def lax_attention(q, k, v, *, scale: float, causal: bool = True,
+                  span: int = 0, q_offset=None):
+    """The lax twin of the forward-only call: q [B, Tq, H, D] at global
+    positions ``q_offset + i`` against k, v [B, Tk, H_kv, D] at ``j``;
+    query head ``h`` reads K/V head ``h // (H / H_kv)``; ``span`` > 0
+    keeps keys ``i - span < j <= i``.  Dense softmax, f32 scores."""
+    b, tq, h, d = q.shape
+    tk, h_kv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, tq, h_kv, h // h_kv, d)
+    s = jnp.einsum("bqkgd,btkd->bkgqt", qg, k,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        i = ((0 if q_offset is None else q_offset)
+             + jnp.arange(tq))[:, None]
+        j = jnp.arange(tk)[None, :]
+        keep = j <= i
+        if span:
+            keep = keep & (i - j < span)
+        s = jnp.where(keep, s, _NEG_BIG)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgqt,btkd->bqkgd", p, v.astype(jnp.float32),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, tq, h, v.shape[-1]).astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_fwd_only(static, q, k, v, qoff):
+    """The forward alone (kernel ``flash_fwd_o``), for what the
+    backward kernels do not cover: a value head size of its own,
+    grouped heads, a sliding window, a query offset."""
+    return _flash_forward(static[:-1], q, k, v, qoff,
+                          jnp.zeros(1, jnp.int32), normalized=True,
+                          span=static[-1])
+
+
+def _flash_fwd_only_fwd(static, q, k, v, qoff):
+    return _flash_fwd_only(static, q, k, v, qoff), None
+
+
+def _flash_fwd_only_bwd(static, res, do):
+    raise NotImplementedError(
+        "flash attention with grouped heads, a sliding window, a query "
+        "offset or a value head size of its own is forward-only: the "
+        "backward kernels take none of them, and a causal gradient in "
+        "their place would be wrong")
+
+
+_flash_fwd_only.defvjp(_flash_fwd_only_fwd, _flash_fwd_only_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False):
+                    interpret: bool = False, span: int = 0,
+                    q_offset=None):
     """Standalone exact attention via the flash kernels (single device).
 
-    q/k/v: [B, T, H, D]; v may have its own head size (latent
-    attention's prefill), and the call is then forward-only: the
-    backward kernels assume one size.  The oracle-equivalent of
+    q/k/v: [B, T, H, D].  Differentiable where q, k and v share one
+    head count and size and the mask is the plain causal one.  k and v
+    may have fewer heads than q (grouped-query attention: no head is
+    repeated in memory), v a head size of its own (latent attention's
+    prefill), ``span`` > 0 makes the mask a sliding window (query i
+    sees keys i - span < j <= i; blocks behind it cost nothing), and
+    ``q_offset`` (a traced int) places q's rows at ``q_offset + i``
+    against keys at ``j`` (a prompt's rows walked in chunks against its
+    whole K/V): each of these is forward-only, and differentiating it
+    raises.  The oracle-equivalent of
     ring_attention_reference with O(T) memory in BOTH directions: the
     backward recomputes P from the saved (o, lse) residuals in blocks
     (dkv + dq kernels) instead of materializing the T×T matrix.
@@ -691,7 +810,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         block_k = get_env("DMLC_FLASH_BLOCK_K", 0) or 1024
     static = (float(scale), bool(causal), int(block_q), int(block_k),
               bool(interpret), int(bwd_q), int(bwd_k))
-    if dv != d:
-        zero = jnp.zeros(1, jnp.int32)
-        return _flash_forward(static, q, k, v, zero, zero, normalized=True)
+    if dv != d or k.shape[2] != h or span or q_offset is not None:
+        qoff = jnp.asarray(0 if q_offset is None else q_offset,
+                           jnp.int32).reshape(1)
+        return _flash_fwd_only(static[:5] + (int(span),), q, k, v, qoff)
     return _flash_attn(static, q, k, v)

@@ -12,12 +12,23 @@ passes ALL layers' pages as one run, the free view ``[L * n_blocks,
 ``li * n_blocks``: a per-layer slice ``pool[li]`` would be copied for
 the kernel at every call.
 
-    k_pool / v_pool : [n_pages, block_size, H, D]
+    k_pool / v_pool : [n_pages, block_size, H_kv, D]
     block_tables    : [B, W] int32   (row b's physical page ids;
                                       rows padded with 0, or a layer's
                                       page 0 — masked off)
     lengths         : [B]    int32   (committed tokens before the window)
     q               : [B, S, H, D]   (post-rope window queries)
+
+A page holds the K/V heads, of which grouped-query attention has fewer
+than query heads (``H_kv`` divides ``H``): query head ``h`` reads K/V
+head ``h // (H / H_kv)``.  A sliding-window layer (``span`` keys: the
+token itself and the ``span - 1`` before it) is called with its own
+pool and the sequences' *ring* tables, ``W = ceil(span / block_size) +
+1`` entries whatever the context, logical page ``p`` at entry ``p mod
+W`` (serving/kv_cache.py); the mask then also drops positions ``<=
+lengths[b] + s - span`` and a row's walk starts at the page of the
+oldest key it can see, so a sliding layer costs the window, not the
+context.
 
 Window position ``s`` of row ``b`` attends pool positions
 ``p <= lengths[b] + s`` within the table's ``W * block_size`` span —
@@ -50,18 +61,23 @@ dead row (length 0) fetches nothing and costs one turn of a scalar
 loop, and no row pays for another's length.  A block is computed in
 *chunks* of a few pages (``_CHUNK_SCORES``), each waited for by its own
 semaphore, so the first product starts when the first chunk has
-landed.  The TPU tiles a page ``[bs, H, D]`` with the heads on
+landed.  The TPU tiles a page ``[bs, H_kv, D]`` with the heads on
 sublanes, so one head's keys are no slice of it; a chunk is therefore
 ONE 2-D product of the ``S*H`` query rows (row ``s*H + h``, unpadded:
-16 rows in plain decode) against the chunk's ``pages*bs*H`` key rows
-(row ``t*H + h'``), masked to ``h == h'`` and position ``<=
-lengths[b] + s`` by one int32 table of ``t - s`` built from iotas once
-a call, followed by the online-softmax update in float32 and the value
-product with ``p`` in the pool's dtype (as the twin does).  The
-cross-head products cost the MXU sixteen times the useful work and it
-does not show: measured on the v5e against a per-head form over
-head-major pages ``[H, bs, D]``, this one is the faster (PERF.md, PR
-30), so the pool keeps its layout.  Block and chunk sizes follow from
+16 rows in the flagship's plain decode, 128 under Command A+'s 128
+query heads) against the chunk's ``pages*bs*H_kv`` key rows (row
+``t*H_kv + h'``), masked to ``h // (H / H_kv) == h'`` and position
+``<= lengths[b] + s`` (and inside the window, where there is one) by
+one int32 table of ``t - s`` built from iotas once a call, followed by
+the online-softmax update in float32 and the value product with ``p``
+in the pool's dtype (as the twin does).  The cross-head products cost
+the MXU ``H_kv`` times the useful work (sixteen at the flagship's 16
+heads, eight at Command A+'s 8 K/V heads, where the 16 query heads of
+a group fill the rows that 16 separate heads left masked) and it does
+not show: a key row is loaded into the MXU once either way.  Measured
+on the v5e against a per-head form over head-major pages ``[H, bs,
+D]``, this one is the faster (PERF.md, PR 30), so the pool keeps its
+layout, under grouped heads too (PERF.md, PR 33).  Block and chunk sizes follow from
 the shapes (:func:`_walk_shape`), not from a knob.
 
 Latent attention (MLA) decodes against another cache: ONE row per
@@ -89,34 +105,51 @@ __all__ = ["paged_attention", "supports", "latent_paged_attention",
            "latent_supports"]
 
 
-def supports(head_dim: int, block_size: int, n_heads: int) -> bool:
+def supports(head_dim: int, block_size: int, n_kv_heads: int) -> bool:
     """Whether the Pallas kernel serves these shapes: the head dim must
-    fill whole 128-element lanes, and so must one page's ``bs*H`` key
-    rows (a chunk of them is the score matrix's lane dim)."""
-    return head_dim % 128 == 0 and (block_size * n_heads) % 128 == 0
+    fill whole 128-element lanes, and so must one page's ``bs*H_kv``
+    key rows (a chunk of them is the score matrix's lane dim)."""
+    return head_dim % 128 == 0 and (block_size * n_kv_heads) % 128 == 0
 
 
-def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale):
+def _first_key(lengths, span: int):
+    """The oldest position a row's first window token (at
+    ``lengths[b]``) sees under a sliding window of ``span`` keys."""
+    return jnp.maximum(lengths - (span - 1), 0)
+
+
+def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale,
+                         span: int = 0):
     """Gather-composed fallback: the block gather happens INSIDE jit
     (one fused gather per layer, no host staging, no dense [B, maxlen]
     intermediate on the host) and the math is dense softmax attention
     with an f32 score path — the 1e-5 parity contract the kernel is
-    held to."""
+    held to.  Under ``span`` the table is a ring: entry ``i`` holds the
+    newest logical block ``j = i (mod W)`` the row has reached."""
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
-    bs = k_pool.shape[1]
-    k_ctx = k_pool[block_tables].reshape(b, w * bs, h, d)
-    v_ctx = v_pool[block_tables].reshape(b, w * bs, h, d)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_ctx,
+    bs, h_kv = k_pool.shape[1], k_pool.shape[2]
+    k_ctx = k_pool[block_tables].reshape(b, w * bs, h_kv, d)
+    v_ctx = v_pool[block_tables].reshape(b, w * bs, h_kv, d)
+    qg = q.reshape(b, s_w, h_kv, h // h_kv, d)
+    s = jnp.einsum("bqkgd,btkd->bkgqt", qg, k_ctx,
                    preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(w * bs)
     limit = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
-    keep = pos[None, None, :] <= limit[:, :, None]               # [B, S, K]
-    s = jnp.where(keep[:, None], s, _NEG_BIG)
+    entry = jnp.arange(w)[None, :]
+    if span:
+        newest = (lengths[:, None] + s_w - 1) // bs              # [B, 1]
+        block = newest - (newest - entry) % w                    # [B, W]
+    else:
+        block = jnp.broadcast_to(entry, (b, w))
+    pos = (block[:, :, None] * bs + jnp.arange(bs)).reshape(b, 1, w * bs)
+    keep = (pos >= 0) & (pos <= limit[:, :, None])               # [B, S, K]
+    if span:
+        keep = keep & (pos > limit[:, :, None] - span)
+    s = jnp.where(keep[:, None, None], s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v_ctx.dtype), v_ctx,
+    out = jnp.einsum("bkgqt,btkd->bqkgd", p.astype(v_ctx.dtype), v_ctx,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return out.reshape(b, s_w, h, d).astype(q.dtype)
 
 
 #: VMEM the walk's K and V blocks take together: two of each, so that
@@ -141,25 +174,29 @@ def _walk_shape(rows: int, cols: int, d: int, itemsize: int,
 
 
 def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, diff_ref, *, n_heads: int, bs: int,
-            s_w: int, chunk: int, scale: float):
+            k_buf, v_buf, sems, diff_ref, *, n_heads: int, n_kv_heads: int,
+            bs: int, s_w: int, chunk: int, scale: float, span: int):
     """One invocation walks every row: ``k_buf`` / ``v_buf``
-    ``[2, pages, bs*H, D]`` are the two blocks, ``sems[kv, slot, c]``
-    counts the copies of chunk ``c`` of a block."""
+    ``[2, pages, bs*H_kv, D]`` are the two blocks, ``sems[kv, slot, c]``
+    counts the copies of chunk ``c`` of a block.  Under ``span`` a
+    row's walk starts at the page of the oldest key its window reaches
+    and the table is a ring (page ``p`` at entry ``p mod W``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n_rows, rows, d = q_ref.shape
     pages = k_buf.shape[1]
-    ckeys = chunk * bs * n_heads
+    ckeys = chunk * bs * n_kv_heads
+    width = tbl_ref.shape[1]
+    group = n_heads // n_kv_heads
 
-    # the mask's static part, once a call: key column c = t*H + h' may
-    # meet query row r = s*H + h where the heads agree, t - s tokens
-    # past the chunk's first position
+    # the mask's static part, once a call: key column c = t*H_kv + h'
+    # may meet query row r = s*H + h where h reads K/V head h', t - s
+    # tokens past the chunk's first position
     r = jax.lax.broadcasted_iota(jnp.int32, (rows, ckeys), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (rows, ckeys), 1)
-    diff_ref[...] = jnp.where(r % n_heads == c % n_heads,
-                              c // n_heads - r // n_heads, 1 << 30)
+    diff_ref[...] = jnp.where(r % n_heads // group == c % n_kv_heads,
+                              c // n_kv_heads - r // n_heads, 1 << 30)
 
     # a row's last block copies its live pages only; what the slot
     # holds behind them is multiplied by p = 0, so it must be finite:
@@ -170,14 +207,19 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         return _
     jax.lax.fori_loop(0, 2 * pages, _zero, 0)
 
-    def n_tokens(b):  # positions row b attends; a dead row has none
-        return jnp.where(len_ref[b] > 0, len_ref[b] + s_w, 0)
+    def first_page(b):  # of row b's walk
+        return _first_key(len_ref[b], span) // bs if span else 0
+
+    def n_pages(b):  # pages row b walks; a dead row walks none
+        return jnp.where(len_ref[b] > 0,
+                         pl.cdiv(len_ref[b] + s_w, bs) - first_page(b), 0)
 
     def live_pages(b, j):  # of block j of row b
-        return jnp.clip(pl.cdiv(n_tokens(b), bs) - j * pages, 0, pages)
+        return jnp.clip(n_pages(b) - j * pages, 0, pages)
 
     def copies(b, j, slot, i):  # page i of block j of row b
-        page = tbl_ref[b, j * pages + i]
+        at = first_page(b) + j * pages + i
+        page = tbl_ref[b, at % width if span else at]
         return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, i],
                                       sems.at[0, slot, i // chunk]),
                 pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, i],
@@ -203,7 +245,8 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         start(first, 0, 0)
 
     def row(b, slot):
-        n_blocks = pl.cdiv(n_tokens(b), pages * bs)
+        n_blocks = pl.cdiv(n_pages(b), pages)
+        page0 = first_page(b)
         # whose first block to fetch during this row's last one
         nxt = next_live(jnp.where(n_blocks > 0, b + 1, n_rows))
         q = q_ref[b]                                   # [S*H, D]
@@ -235,13 +278,20 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
-                # same head AND key position <= lengths[b] + s
-                keep = diff_ref[...] <= (
-                    len_ref[b] - (j * pages + ci * chunk) * bs)
+                # the head's own K/V head AND key position <=
+                # lengths[b] + s (and inside the window, if there is one)
+                ahead = len_ref[b] - (page0 + j * pages + ci * chunk) * bs
+                keep = diff_ref[...] <= ahead
+                if span:
+                    keep = jnp.logical_and(keep,
+                                           diff_ref[...] > ahead - span)
                 s = jnp.where(keep, s, _NEG_BIG)
                 m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-                # every row keeps position 0, so m_new is a real score
-                # from the first chunk on and a masked column's p is 0
+                # every row keeps the walk's first position (a window
+                # token sees at least itself, and the walk starts in
+                # the page of token 0's oldest key), so m_new is a real
+                # score from the first chunk on and a masked column's p
+                # is 0
                 p = jnp.exp(s - m_new)
                 corr = jnp.exp(m - m_new)
                 l = l * corr + jnp.sum(p, axis=1, keepdims=True)
@@ -266,8 +316,9 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     jax.lax.fori_loop(0, n_rows, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "span"))
+def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret,
+               span=0):
     """The kernel's call.  Jitted so that a decode program's layers,
     whose calls differ in their tables' values alone, trace and lower
     the walk once and not once a layer; its name keeps clear of the
@@ -277,14 +328,14 @@ def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret):
 
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
-    n_pages, bs = k_pool.shape[:2]
-    rows, cols = s_w * h, bs * h
+    n_pages, bs, h_kv = k_pool.shape[:3]
+    rows, cols = s_w * h, bs * h_kv
     chunk, per_block = _walk_shape(rows, cols, d, k_pool.dtype.itemsize, w)
     pages = chunk * per_block
     block = pltpu.VMEM((2, pages, cols, d), k_pool.dtype)
     out = pl.pallas_call(
-        functools.partial(_kernel, n_heads=h, bs=bs, s_w=s_w, chunk=chunk,
-                          scale=scale),
+        functools.partial(_kernel, n_heads=h, n_kv_heads=h_kv, bs=bs,
+                          s_w=s_w, chunk=chunk, scale=scale, span=span),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # the tables
                   pl.BlockSpec(memory_space=pltpu.SMEM),   # the lengths
                   pl.BlockSpec(memory_space=pltpu.VMEM),   # q, whole
@@ -301,7 +352,7 @@ def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret):
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q.reshape(b, rows, d),                             # row = s*H + h
-      k_pool.reshape(n_pages, cols, d),                  # row = t*H + h
+      k_pool.reshape(n_pages, cols, d),                  # row = t*H_kv + h'
       v_pool.reshape(n_pages, cols, d))
     # float32 out of the kernel: whatever XLA does to hand the result
     # on (a cast, a layout for the o projection) is then an op of its
@@ -311,11 +362,12 @@ def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret):
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
                     scale: Optional[float] = None, impl: str = "auto",
-                    interpret: bool = False):
+                    interpret: bool = False, span: int = 0):
     """Window attention against a paged KV pool.
 
     See the module docstring for shapes and the mask contract.  Returns
-    ``[B, S, H, D]`` in q's dtype.  ``impl``: "auto" takes the Pallas
+    ``[B, S, H, D]`` in q's dtype.  ``span`` > 0: a sliding layer, whose
+    ``block_tables`` is the ring table.  ``impl``: "auto" takes the Pallas
     kernel or the lax reference as ops/dispatch decides (the kernel on
     a TPU when :func:`supports` allows); "pallas"/"lax" force a path
     (tests drive the kernel on CPU with ``impl="pallas"``, which runs
@@ -328,14 +380,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         raise ValueError(f"unknown paged-attention impl {impl!r}")
     from . import dispatch
 
+    if span and q.shape[1] > 1 + (-span % int(k_pool.shape[1])):
+        # the ring holds ceil(span / bs) + 1 pages: a window of S
+        # tokens reaches one more unless the slack covers it
+        raise ValueError(f"a decode window of {q.shape[1]} tokens does "
+                         f"not fit a sliding layer's ring (span {span})")
     mode = dispatch.choose(
-        supports(d, int(k_pool.shape[1]), int(q.shape[2])), impl)
+        supports(d, int(k_pool.shape[1]), int(k_pool.shape[2])), impl)
     if mode != dispatch.LAX:
         return _walk_pool(
             q, k_pool, v_pool, block_tables, lengths, scale=float(scale),
-            interpret=bool(interpret or mode == dispatch.INTERPRET))
+            interpret=bool(interpret or mode == dispatch.INTERPRET),
+            span=int(span))
     return _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths,
-                                float(scale))
+                                float(scale), int(span))
 
 
 # ---- latent attention: one row per token, shared by every head --------

@@ -193,6 +193,17 @@ _MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held",
 #: counts them) and the state bytes the decode steps read and wrote
 _STATE_COUNTERS = ("state_slot_allocs", "kda_state_rw_bytes")
 
+#: what a model with sliding-window layers adds.  The cache counts the
+#: ring blocks that were taken over; the rest grow when a decode span
+#: closes: the keys the step's live rows attended in ONE full layer
+#: (their lengths) and in ONE sliding layer (at most the window), and
+#: the cache's own counts at that moment summed over steps (blocks in
+#: use in either pool, tokens cached), whose quotients by
+#: ``paged_decode_steps`` are the window's means
+_SLIDING_COUNTERS = ("kv_sliding_blocks_released", "attn_full_ctx_tokens",
+                     "attn_sliding_ctx_tokens", "kv_block_steps",
+                     "kv_sliding_block_steps", "kv_cached_token_steps")
+
 
 def _jitted_programs(family: str = "mha"):
     """Process-wide jitted prefill/decode (one jit wrapper per program,
@@ -219,7 +230,10 @@ def _jitted_programs(family: str = "mha"):
     ``forward_decode_paged_mla``, both donated their one pool; a model
     with recurrent layers ("kda_mla") ``forward_prefill_paged_hybrid``
     / ``forward_decode_paged_hybrid``, donated the pool and the two
-    state arrays that travel with it.  All go
+    state arrays that travel with it; an MHA model with sliding-window
+    layers ("mha_swa") ``forward_prefill_paged_swa`` /
+    ``forward_decode_paged_swa``, donated the full layers' and the
+    sliding layers' K and V pools.  All go
     through :func:`telemetry.compute.profiled_jit`, which is plain
     ``jax.jit`` when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on
     that mode so toggling the knob between tests cannot hand a plain
@@ -236,6 +250,13 @@ def _jitted_programs(family: str = "mha"):
         decode_key = (mode, "decode_paged_hybrid")
         decode_fn, decode_kw = tfm.forward_decode_paged_hybrid, {
             "static_argnums": (9,), "donate_argnums": (3, 4, 5)}
+    elif family == "mha_swa":
+        prefill_key = (mode, "prefill_paged_swa")
+        prefill_fn, prefill_kw = tfm.forward_prefill_paged_swa, {
+            "static_argnums": (9,), "donate_argnums": (3, 4, 5, 6)}
+        decode_key = (mode, "decode_paged_swa")
+        decode_fn, decode_kw = tfm.forward_decode_paged_swa, {
+            "static_argnums": (10,), "donate_argnums": (3, 4, 5, 6)}
     elif family == "mla":
         prefill_key = (mode, "prefill_paged_mla")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged_mla, {
@@ -353,12 +374,17 @@ class InferenceEngine:
                     else get_env("DMLC_SERVE_KV_BLOCKS", 256))
         block_size = (block_size if block_size is not None
                       else get_env("DMLC_SERVE_KV_BLOCK_SIZE", 16))
+        # a ring for every row that can be live at once: the sliding
+        # pool never refuses what the batch has room for
+        sliding = cfg.sliding_pool_shapes(self.max_active, block_size)
         self.cache = PagedKVCache(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
             n_blocks=n_blocks, block_size=block_size,
             dtype=np.dtype(cfg.dtype),
             pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size),
-            state_shapes=cfg.state_slot_shapes(self.max_active))
+            state_shapes=cfg.state_slot_shapes(self.max_active),
+            sliding_shapes=sliding,
+            sliding_window=cfg.sliding_window if sliding else 0)
         self.scheduler = ContinuousBatchScheduler(
             self.cache, max_active=self.max_active)
         depth = (queue_depth if queue_depth is not None
@@ -394,6 +420,11 @@ class InferenceEngine:
                 "DMLC_SERVE_SPEC_K > 0 with a model that has recurrent "
                 "layers: a rejected draft would need the state rolled "
                 "back, which the cache manager cannot do")
+        if self.spec_k and self.cache.ring_blocks:
+            raise ValueError(
+                "DMLC_SERVE_SPEC_K > 0 with a model that has sliding-"
+                "window layers: a ring table holds one decode token's "
+                "reach, not a verify window's")
         self._spec_window = 1 + self.spec_k
         # bytes one live row's recurrent state costs a decode step:
         # read once and written once in every layer that has one (the
@@ -403,7 +434,7 @@ class InferenceEngine:
             shape, dt = self.cache.state_shapes[0]
             self._state_rw_bytes = (
                 2 * int(np.prod(shape)) // shape[1] * dt.itemsize)
-        self._prefill, self._decode = _jitted_programs(cfg.attention)
+        self._prefill, self._decode = _jitted_programs(cfg.family)
         self._stop = threading.Event()
         self._draining = threading.Event()
         # iteration seqlock: odd = an engine iteration is mid-flight
@@ -575,7 +606,9 @@ class InferenceEngine:
         self._stop.clear()
         for name in _ZEROED_COUNTERS + (
                 _MOE_COUNTERS if self.cfg.moe_router == "sigmoid" else ()
-                ) + (_STATE_COUNTERS if self.cache.n_slots else ()):
+                ) + (_STATE_COUNTERS if self.cache.n_slots else ()
+                     ) + (_SLIDING_COUNTERS if self.cache.ring_blocks
+                          else ()):
             telemetry.inc("serving", name, 0)
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="serving-engine")
@@ -872,7 +905,7 @@ class InferenceEngine:
                 picked, pools, moe = self._on_pools(
                     self._prefill, self.params, ids, last,
                     np.asarray(self.cache.block_table(req.id), np.int32),
-                    *self._slot_args([req.id]), at=3)
+                    *self._prefill_args(req.id), at=3)
                 _start_fetch(*picked, *moe)
                 picked = [np.asarray(a) for a in picked]
                 moe = [np.asarray(m) for m in moe]
@@ -890,6 +923,23 @@ class InferenceEngine:
         if not self.cache.n_slots:
             return ()
         return (self.cache.slot_ids(seq_ids, pad_batch),)
+
+    def _prefill_args(self, seq_id) -> tuple:
+        """What a prefill program takes after the block table: the
+        ring entries it writes (sliding layers), the sequence's state
+        slot (recurrent layers), or nothing."""
+        if self.cache.ring_blocks:
+            return (np.asarray(self.cache.sliding_prefill_ids(seq_id),
+                               np.int32),)
+        return self._slot_args([seq_id])
+
+    def _row_args(self, seq_ids, pad_batch) -> tuple:
+        """What a decode program takes after the tables and lengths:
+        the rows' ring tables (sliding layers), their state slots
+        (recurrent layers), or nothing."""
+        if self.cache.ring_blocks:
+            return (self.cache.sliding_tables_array(seq_ids, pad_batch),)
+        return self._slot_args(seq_ids, pad_batch)
 
     def _on_pools(self, program, *args, at: int):
         """Call a paged program with the cache's pools spliced in at
@@ -1096,7 +1146,7 @@ class InferenceEngine:
         positions[:b] = base_lens[:, None] + np.arange(s_w)
         return ((ids, self._last_ids, src), positions, drafts, tables,
                 lengths, base_lens,
-                self._slot_args([r.id for r in active], pad_b))
+                self._row_args([r.id for r in active], pad_b))
 
     def _decode_step(self, active: List[Request], n_preempted: int,
                      ahead: bool, feed, positions, drafts, tables,
@@ -1308,14 +1358,15 @@ class InferenceEngine:
                 telemetry.inc("serving", "lookahead_discarded_tokens",
                               n_discarded)
             self._decode_bookkeeping(b, n_tokens, n_proposed, n_accepted,
-                                     step.n_preempted, cost)
+                                     step.n_preempted, cost, base_lens)
 
     def _decode_bookkeeping(self, b: int, n_tokens: int, n_proposed: int,
                             n_accepted: int, n_preempted: int,
-                            cost) -> None:
+                            cost, base_lens) -> None:
         """Counters, gauges and the ledgers' per-iteration records."""
         s_w = self._spec_window
         compute = telemetry.compute
+        kv_stats = self.cache.stats()
         if n_tokens:
             telemetry.inc("serving", "tokens_generated", n_tokens)
         telemetry.inc("serving", "decode_steps")
@@ -1324,6 +1375,18 @@ class InferenceEngine:
         if self._state_rw_bytes:
             telemetry.inc("serving", "kda_state_rw_bytes",
                           self._state_rw_bytes * b)
+        if self.cache.ring_blocks:
+            # a row at length n attends its n keys and the token itself
+            keys = base_lens + 1
+            telemetry.inc("serving", "attn_full_ctx_tokens",
+                          float(keys.sum()))
+            telemetry.inc("serving", "attn_sliding_ctx_tokens", float(
+                np.minimum(keys, self.cache.sliding_window).sum()))
+            for name, key in (("kv_block_steps", "blocks_in_use"),
+                              ("kv_sliding_block_steps",
+                               "sliding_blocks_in_use"),
+                              ("kv_cached_token_steps", "cached_tokens")):
+                telemetry.inc("serving", name, kv_stats[key])
         if s_w > 1:
             telemetry.inc("serving", "spec_proposed", n_proposed)
             telemetry.inc("serving", "spec_accepted", n_accepted)
@@ -1350,8 +1413,7 @@ class InferenceEngine:
         # accepted draft lands several)
         self.requests.on_iteration(
             active=b, waiting=self.scheduler.n_waiting,
-            preempted=n_preempted, tokens=n_tokens,
-            kv_stats=self.cache.stats())
+            preempted=n_preempted, tokens=n_tokens, kv_stats=kv_stats)
         self.availability.note_tokens(n_tokens)
         self.slo.maybe_evaluate()
 

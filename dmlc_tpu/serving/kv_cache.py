@@ -4,22 +4,41 @@ vLLM-style PagedAttention bookkeeping adapted to this substrate: the
 cache is a fixed pool of fixed-size blocks (``block_size`` tokens each)
 handed out by a free-list :class:`BlockAllocator`, and every sequence
 owns a *block table* mapping its logical token positions to physical
-blocks.  Continuous batching lives or dies on this layout — sequences
+blocks (two of them where the model has sliding-window layers: see
+below).  Continuous batching lives or dies on this layout — sequences
 of wildly different lengths share one arena with zero fragmentation
 beyond the final partial block, and a finished (or preempted) request
 returns its blocks to the free list for immediate reuse.
 
 Pool layout (layer-major, mirroring the paged-attention kernel shapes):
 
-    k_pool / v_pool : [n_layers, n_blocks, block_size, n_heads, head_dim]
+    k_pool / v_pool : [n_layers, n_blocks, block_size, n_kv_heads, head_dim]
 
-What the pools are is the model's to say (``pool_shapes``, from
+(a page holds the K/V heads, which grouped-query attention has fewer of
+than query heads).  What the pools are is the model's to say (``pool_shapes``, from
 ``TransformerConfig.kv_pool_shapes``): K and V heads as above, or under
 latent attention ONE pool of rows ``[rms(c_kv) | rope(k_pe)]`` with no
 head axis and no separate V, ``[n_layers, n_blocks, 576, block_size]``.
 The allocator, block tables, lengths and ``stats()`` do not care.
 
-A model with recurrent layers keeps a second kind of state, fixed in
+A model with sliding-window layers has a block table per layer TYPE.
+Its full layers keep the growing table above over the pool of
+``n_blocks``.  Its sliding layers, which never look further back than
+``sliding_window`` keys, have a pool of their own (``sliding_shapes``,
+from ``TransformerConfig.sliding_pool_shapes``: sized for the rows that
+can be live at once, not by ``n_blocks``) and a second table a sequence,
+a *ring* of ``R = ceil(window / block_size) + 1`` entries: logical block
+``j`` lives at entry ``j mod R``.  A prompt's prefill writes only the
+last ``R`` blocks (:meth:`PagedKVCache.sliding_prefill_ids`), and when
+decode grows into logical block ``j >= R`` it takes over the physical
+block of ``j - R``, every key of which is out of every later query's
+reach: a sequence never holds more than ``R`` sliding blocks however
+long it grows.  ``allocate`` / ``extend`` / ``extend_many`` / ``free``
+cover both pools atomically; ``n_blocks``, ``blocks_in_use`` and
+``occupancy`` go on meaning the full layers' pool, and the sliding
+pool's counts stand beside them (``sliding_*``).
+
+A model with recurrent layers keeps another kind of state, fixed in
 size per sequence (``state_shapes``, from
 ``TransformerConfig.state_slot_shapes``): arrays ``[layers, n_slots,
 ...]`` of which a sequence owns ONE slot, taken with its blocks at
@@ -123,12 +142,16 @@ class BlockAllocator:
 
 
 class _SeqEntry:
-    __slots__ = ("blocks", "length", "slot")
+    __slots__ = ("blocks", "length", "slot", "ring", "ring_top")
 
     def __init__(self) -> None:
         self.blocks: List[int] = []
         self.length = 0
         self.slot: Optional[int] = None  # recurrent-state slot, if any
+        # sliding layers: the ring's physical blocks by entry, and the
+        # newest logical block it has room for
+        self.ring: List[int] = []
+        self.ring_top = -1
 
 
 class PagedKVCache:
@@ -139,15 +162,18 @@ class PagedKVCache:
     pools of ``[n_layers, n_blocks, block_size, n_heads, head_dim]``).
     ``n_blocks × block_size`` is the total token capacity shared by all
     concurrent requests.  ``state_shapes`` is ``((shape, dtype), ...)``
-    of the recurrent-state arrays, axis 1 the slots (default none).  The
-    bytes are device arrays that device programs write (module
-    docstring).
+    of the recurrent-state arrays, axis 1 the slots (default none).
+    ``sliding_shapes`` are the sliding layers' pools (axis 1 their own
+    blocks) and ``sliding_window`` their reach in keys (default: no
+    such layers).  The bytes are device arrays that device programs
+    write (module docstring).
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int, *,
                  n_blocks: int = 256, block_size: int = 16,
                  dtype=np.float32, pool_shapes: Optional[tuple] = None,
-                 state_shapes: tuple = ()):
+                 state_shapes: tuple = (), sliding_shapes: tuple = (),
+                 sliding_window: int = 0):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.n_layers = int(n_layers)
@@ -164,6 +190,18 @@ class PagedKVCache:
             (tuple(int(d) for d in shape), np.dtype(dt))
             for shape, dt in state_shapes)
         self.n_slots = self.state_shapes[0][0][1] if self.state_shapes else 0
+        self.sliding_shapes = tuple(
+            tuple(int(d) for d in shape) for shape in sliding_shapes)
+        if bool(self.sliding_shapes) != bool(sliding_window):
+            raise ValueError("sliding_shapes and sliding_window go together")
+        self.sliding_window = int(sliding_window)
+        #: entries of a sequence's ring table (0: no sliding layers)
+        self.ring_blocks = (self.blocks_for(self.sliding_window) + 1
+                            if self.sliding_shapes else 0)
+        self.n_sliding_blocks = (self.sliding_shapes[0][1]
+                                 if self.sliding_shapes else 0)
+        self._ring_alloc = (BlockAllocator(self.n_sliding_blocks)
+                            if self.sliding_shapes else None)
         # pop() from the tail: ascending slots first, as the blocks
         self._free_slots: List[int] = list(range(self.n_slots - 1, -1, -1))
         # the pools, made as zeros ON the device by the first
@@ -189,6 +227,9 @@ class PagedKVCache:
         self._cached_tokens = 0
         self._lock = make_lock("PagedKVCache._lock")
         telemetry.set_gauge("serving", "kv_blocks_total", self.n_blocks)
+        if self.ring_blocks:
+            telemetry.set_gauge("serving", "kv_sliding_blocks_total",
+                                self.n_sliding_blocks)
         if self.n_slots:
             telemetry.set_gauge("serving", "state_slots_total", self.n_slots)
         self._publish_usage()
@@ -211,17 +252,34 @@ class PagedKVCache:
         with self._lock:
             return len(self._free_slots)
 
+    def ring_blocks_for(self, n_tokens: int) -> int:
+        """Sliding blocks a sequence of ``n_tokens`` holds: its blocks
+        up to the ring's size (0 without sliding layers)."""
+        return min(self.blocks_for(n_tokens), self.ring_blocks)
+
+    @property
+    def n_free_sliding_blocks(self) -> int:
+        return self._ring_alloc.n_free if self._ring_alloc else 0
+
+    @property
+    def n_sliding_blocks_in_use(self) -> int:
+        return self._ring_alloc.n_in_use if self._ring_alloc else 0
+
     def can_reserve(self, n_tokens: int) -> bool:
         """Whether a NEW sequence of ``n_tokens`` fits now: its blocks
-        and, where sequences carry recurrent state, a slot."""
+        in the full layers' pool, its ring's in the sliding layers' and,
+        where sequences carry recurrent state, a slot."""
         if self.n_slots and not self.n_free_slots:
+            return False
+        if self.ring_blocks_for(n_tokens) > self.n_free_sliding_blocks:
             return False
         return self.blocks_for(n_tokens) <= self._alloc.n_free
 
     def fits_at_all(self, n_tokens: int) -> bool:
-        """Whether ``n_tokens`` could EVER be cached, even with the
-        whole pool free — the admission-time sanity bound."""
-        return self.blocks_for(n_tokens) <= self.n_blocks
+        """Whether ``n_tokens`` could EVER be cached, even with both
+        pools free — the admission-time sanity bound."""
+        return (self.blocks_for(n_tokens) <= self.n_blocks
+                and self.ring_blocks_for(n_tokens) <= self.n_sliding_blocks)
 
     # ---- sequence lifecycle --------------------------------------------
     def allocate(self, seq_id: int, n_tokens: int) -> bool:
@@ -231,13 +289,19 @@ class PagedKVCache:
             if seq_id in self._seqs:
                 raise DMLCError(f"sequence {seq_id} already allocated")
             got = None
-            if not self.n_slots or self._free_slots:
+            n_ring = self.ring_blocks_for(n_tokens)
+            if (not self.n_slots or self._free_slots) \
+                    and n_ring <= self.n_free_sliding_blocks:
                 got = self._alloc.alloc_many(self.blocks_for(n_tokens))
             if got is None:
                 telemetry.inc("serving", "kv_alloc_failures")
                 return False
             ent = _SeqEntry()
             ent.blocks = got
+            if self.ring_blocks:
+                # guarded above: one lock holds both allocators' users
+                ent.ring = self._ring_alloc.alloc_many(n_ring)
+                ent.ring_top = self.blocks_for(n_tokens) - 1
             if self.n_slots:
                 ent.slot = self._free_slots.pop()
                 telemetry.inc("serving", "state_slot_allocs")
@@ -252,16 +316,44 @@ class PagedKVCache:
         with self._lock:
             ent = self._seq(seq_id)
             need = self.blocks_for(ent.length + n_tokens) - len(ent.blocks)
-            if need <= 0:
+            ring = self._ring_need(ent, ent.length + n_tokens)
+            if need <= 0 and ring is None:
                 return True
-            got = self._alloc.alloc_many(need)
-            if got is None:
+            if (max(need, 0) > self._alloc.n_free or
+                    (ring or 0) > self.n_free_sliding_blocks):
                 telemetry.inc("serving", "kv_alloc_failures")
                 return False
-            ent.blocks.extend(got)
+            self._grow(ent, need, ring, ent.length + n_tokens)
             self._tables_version += 1
         self._publish_usage()
         return True
+
+    def _ring_need(self, ent: _SeqEntry, end: int) -> Optional[int]:
+        """Lock held.  Sliding blocks to allocate so that the ring has
+        room for tokens up to ``end`` (0: it only turns, taking over
+        blocks whose keys are out of reach), or None when it has."""
+        if not self.ring_blocks or self.blocks_for(end) - 1 <= ent.ring_top:
+            return None
+        return self.ring_blocks_for(end) - len(ent.ring)
+
+    def _grow(self, ent: _SeqEntry, need: int, ring: Optional[int],
+              end: int) -> None:
+        """Lock held, room checked: give ``ent`` ``need`` blocks and
+        turn its ring up to ``end``."""
+        if need > 0:
+            ent.blocks.extend(self._alloc.alloc_many(need))
+        if ring is not None:
+            if ring:
+                ent.ring.extend(self._ring_alloc.alloc_many(ring))
+            top = self.blocks_for(end) - 1
+            # every logical block at or past the ring's size took over
+            # the physical block of the one a ring before it
+            released = max(top, self.ring_blocks - 1) - max(
+                ent.ring_top, self.ring_blocks - 1)
+            if released:
+                telemetry.inc("serving", "kv_sliding_blocks_released",
+                              released)
+            ent.ring_top = top
 
     def extend_many(self, seq_ids: Sequence[int],
                     n_tokens: int = 1) -> bool:
@@ -275,21 +367,17 @@ class PagedKVCache:
             ents = [self._seq(s) for s in seq_ids]
             needs = [self.blocks_for(e.length + n_tokens) - len(e.blocks)
                      for e in ents]
+            rings = [self._ring_need(e, e.length + n_tokens) for e in ents]
             total = sum(n for n in needs if n > 0)
-            if total == 0:
+            if total == 0 and all(r is None for r in rings):
                 return True
-            if total > self._alloc.n_free:
+            if total > self._alloc.n_free or sum(
+                    r or 0 for r in rings) > self.n_free_sliding_blocks:
                 return False
-            grew = False
-            for ent, need in zip(ents, needs):
-                if need <= 0:
-                    continue
-                got = self._alloc.alloc_many(need)
-                assert got is not None  # guarded by the total check
-                ent.blocks.extend(got)
-                grew = True
-            if grew:
-                self._tables_version += 1
+            for ent, need, ring in zip(ents, needs, rings):
+                if need > 0 or ring is not None:
+                    self._grow(ent, need, ring, ent.length + n_tokens)
+            self._tables_version += 1
         self._publish_usage()
         return True
 
@@ -303,6 +391,8 @@ class PagedKVCache:
                 return
             self._cached_tokens -= ent.length
             self._alloc.free(ent.blocks)
+            if ent.ring:
+                self._ring_alloc.free(ent.ring)
             if ent.slot is not None:
                 self._free_slots.append(ent.slot)
             self._tables_version += 1
@@ -315,6 +405,30 @@ class PagedKVCache:
     def block_table(self, seq_id: int) -> List[int]:
         with self._lock:
             return list(self._seq(seq_id).blocks)
+
+    def sliding_prefill_ids(self, seq_id: int) -> List[int]:
+        """The physical sliding blocks a prefill writes, in logical
+        order: the sequence's last ``len(ring)`` logical blocks, which
+        are all a later query's window reaches."""
+        with self._lock:
+            ent = self._seq(seq_id)
+            n, r = len(ent.ring), self.ring_blocks
+            return [ent.ring[j % r]
+                    for j in range(ent.ring_top + 1 - n, ent.ring_top + 1)]
+
+    def sliding_tables_array(self, seq_ids: Sequence[int],
+                             pad_batch: Optional[int] = None) -> np.ndarray:
+        """The sequences' ring tables as int32 ``[B, ring_blocks]``,
+        unused entries and rows past ``seq_ids`` 0.  The width is fixed
+        by the model's window, so it is no part of a decode program's
+        signature."""
+        out = np.zeros((max(pad_batch or 0, len(seq_ids)),
+                        self.ring_blocks), np.int32)
+        with self._lock:
+            for i, s in enumerate(seq_ids):
+                ring = self._seq(s).ring
+                out[i, :len(ring)] = ring
+        return out
 
     def slot_ids(self, seq_ids: Sequence[int],
                  pad_batch: Optional[int] = None) -> np.ndarray:
@@ -354,14 +468,15 @@ class PagedKVCache:
     def device_pools(self) -> tuple:
         """The device arrays that are the cache itself, one per entry
         of ``pool_shapes``: ``(k_pool, v_pool)``, or the one latent pool,
-        then one per entry of ``state_shapes``.  Made as zeros on the
+        then one per entry of ``sliding_shapes`` and of ``state_shapes``.  Made as zeros on the
         device at first use (nothing is uploaded); afterwards whatever
         :meth:`adopt_device_pools` installed last."""
         if self._dev is None:
             import jax.numpy as jnp
 
             self._dev = tuple(
-                [jnp.zeros(shape, self.dtype) for shape in self.pool_shapes]
+                [jnp.zeros(shape, self.dtype)
+                 for shape in self.pool_shapes + self.sliding_shapes]
                 + [jnp.zeros(shape, dt) for shape, dt in self.state_shapes])
         return self._dev
 
@@ -369,7 +484,7 @@ class PagedKVCache:
         """Install the pools a prefill or decode program returned (its
         in-program scatter made them the cache)."""
         assert len(pools) == len(self.pool_shapes) + len(
-            self.state_shapes), len(pools)
+            self.sliding_shapes) + len(self.state_shapes), len(pools)
         self._dev = tuple(pools)
 
     def drop_lost_pools(self) -> bool:
@@ -451,6 +566,7 @@ class PagedKVCache:
             tokens = self._cached_tokens
             in_use = self._alloc.n_in_use
             slots_in_use = self.n_slots - len(self._free_slots)
+            ring_in_use = self.n_sliding_blocks_in_use
         # occupancy: pool pressure the admission test acts on; waste:
         # allocated-but-unfilled token slots (final partial blocks +
         # reserve-ahead) — the paged layout's only fragmentation, so a
@@ -467,6 +583,10 @@ class PagedKVCache:
             "waste_tokens": in_use * self.block_size - tokens,
             "state_slots": self.n_slots,
             "state_slots_in_use": slots_in_use,
+            "sliding_blocks": self.n_sliding_blocks,
+            "sliding_blocks_in_use": ring_in_use,
+            "sliding_occupancy": (ring_in_use / self.n_sliding_blocks
+                                  if self.n_sliding_blocks else 0.0),
         }
 
     def _publish_usage(self) -> None:
@@ -474,9 +594,13 @@ class PagedKVCache:
             in_use = self._alloc.n_in_use
             tokens = self._cached_tokens
             slots_in_use = self.n_slots - len(self._free_slots)
+            ring_in_use = self.n_sliding_blocks_in_use
         if self.n_slots:
             telemetry.set_gauge("serving", "state_slots_in_use",
                                 slots_in_use)
+        if self.ring_blocks:
+            telemetry.set_gauge("serving", "kv_sliding_blocks_in_use",
+                                ring_in_use)
         telemetry.set_gauge("serving", "kv_blocks_in_use", in_use)
         telemetry.set_gauge("serving", "kv_occupancy_pct",
                             100.0 * in_use / self.n_blocks)

@@ -424,6 +424,18 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_kda_state_rw_bytes",
     "dmlc_serving_state_slots_in_use",
     "dmlc_serving_state_slots_total",
+    # sliding-window layers' own pool and block table (the widened MHA
+    # family): the ring blocks in use and in all, those a ring took
+    # over, the keys a decode step's rows attended in one full and one
+    # sliding layer, and the cache's counts summed over decode steps
+    "dmlc_serving_kv_sliding_blocks_in_use",
+    "dmlc_serving_kv_sliding_blocks_total",
+    "dmlc_serving_kv_sliding_blocks_released",
+    "dmlc_serving_attn_full_ctx_tokens",
+    "dmlc_serving_attn_sliding_ctx_tokens",
+    "dmlc_serving_kv_block_steps",
+    "dmlc_serving_kv_sliding_block_steps",
+    "dmlc_serving_kv_cached_token_steps",
     # the decode loop's lookahead: steps dispatched while the step
     # before was unread, and tokens of rows that had ended in it
     "dmlc_serving_decode_steps_overlapped",

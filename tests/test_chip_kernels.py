@@ -204,6 +204,40 @@ def test_kda_state_step_compiles_and_updates_the_state_in_place(one_chip):
     assert m.temp_size_in_bytes < 64 * 2 ** 20
 
 
+@pytest.mark.parametrize("span,width", [(0, 257), (4096, 33)],
+                         ids=["full", "sliding"])
+def test_grouped_and_windowed_kernels_compile_for_the_chip(one_chip, span,
+                                                           width):
+    """Compiled for the chip at Command A+'s shapes (128 query heads on
+    8 K/V heads of 128, window 4096, block 128): the prefill kernel on
+    a chunk of 8k rows at a traced offset against a 32k prompt's whole
+    K/V, and the decode kernel of 8 rows over a full table of 257
+    entries or a ring of 33, all layers' pages as one run.  Mosaic takes
+    both (the K/V index maps that clamp masked blocks, the window's
+    shortened grid, the ring's modulus in the page walk), each as ONE
+    custom call under its kernel's name."""
+    bf = jnp.bfloat16
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    kv = arr((1, 32768, 8, 128), bf)
+    pool = arr((3 * 264, 128, 8, 128), bf)
+    assert paged.supports(128, 128, 8)
+    with dispatch.force_kernel_mode(dispatch.MOSAIC):
+        prefill = jax.jit(lambda q, k, v, off: flash.flash_attention(
+            q, k, v, span=span, q_offset=off)).lower(
+                arr((1, 8192, 128, 128), bf), kv, kv,
+                arr((), jnp.int32)).compile().as_text()
+        decode = jax.jit(lambda q, k, v, t, n: paged.paged_attention(
+            q, k, v, t, n, span=span)).lower(
+                arr((8, 1, 128, 128), bf), pool, pool,
+                arr((8, width), jnp.int32),
+                arr((8,), jnp.int32)).compile().as_text()
+    for hlo, kernel in ((prefill, "flash_fwd_o"), (decode, "paged_attn")):
+        calls = [line for line in hlo.splitlines()
+                 if "custom-call(" in line and kernel in line]
+        assert len(calls) == 1 and "tpu_custom_call" in calls[0], calls
+
+
 def test_dispatch_is_one_flippable_function():
     counts = lambda: telemetry.counters_snapshot().get("kernels", {})  # noqa: E731
     assert dispatch.kernel_mode() == dispatch.LAX  # tier-1 is CPU

@@ -377,7 +377,9 @@ def test_failed_prefill_fails_alone_unless_it_took_the_pools(pools_lost):
         "crash_requeues", 0)
     eng.start()
     try:
-        good = eng.submit([1, 2, 3, 4, 5], max_new_tokens=12)
+        # 40 tokens: on a loaded host 12 were generated before the
+        # poisoned prompt's turn came, one run in three
+        good = eng.submit([1, 2, 3, 4, 5], max_new_tokens=40)
         while good.n_generated < 3:  # live, with K/V in the pools
             assert not good.wait(0.005)
         bad = eng.submit([poison, 1, 2], max_new_tokens=4)
@@ -386,7 +388,7 @@ def test_failed_prefill_fails_alone_unless_it_took_the_pools(pools_lost):
         eng.close()
     assert bad.error is not None and "prefill failed" in bad.error
     assert good.error is None
-    assert good.generated == _greedy_oracle(params, cfg, [1, 2, 3, 4, 5], 12)
+    assert good.generated == _greedy_oracle(params, cfg, [1, 2, 3, 4, 5], 40)
     after = telemetry.counters_snapshot()["serving"].get("crash_requeues", 0)
     assert after - before == (1 if pools_lost else 0)
     assert eng.cache.n_blocks_in_use == 0
