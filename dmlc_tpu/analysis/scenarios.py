@@ -89,7 +89,7 @@ class DrainRaceScenario(Scenario):
                                 head_dim=4, d_ff=16, n_layers=1,
                                 n_experts=1)
         eng = InferenceEngine(
-            params=None, cfg=cfg, n_blocks=16, block_size=4,
+            params={}, cfg=cfg, n_blocks=16, block_size=4,
             max_active=2, queue_depth=4, admit_timeout_s=0.1,
             slo_monitor=SLOMonitor())
         eng.requests = RequestLedger(slo=eng.slo)

@@ -362,7 +362,10 @@ def train_step_flops(cfg: TransformerConfig, batch: int, t: int,
 
 
 def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
-    """Global (unsharded) parameter pytree; blocks stacked [S, L/S, ...]."""
+    """Global (unsharded) parameter pytree; blocks stacked [S, L/S, ...]:
+    the form the train step scans and shards.  The serving engine holds
+    an MHA model's weights as :func:`per_layer_params` makes them of
+    this tree, one array a layer and matrix."""
     if cfg.hybrid:
         return _init_hybrid_params(key, cfg, n_stages)
     if cfg.latent:
@@ -877,6 +880,26 @@ def _layer_params(blocks, stage: int, layer: int):
     return jax.tree.map(lambda a: a[stage, layer], blocks)
 
 
+def per_layer_params(params):
+    """The stacked MHA tree (``blocks`` with leading ``[S, L/S]``) as
+    the serving programs read it: ``layers``, a list of one dict a
+    layer, each matrix an array of its own, beside ``embed``,
+    ``unembed`` and ``ln_f`` as they are.  The engine calls it once,
+    outside any program; a program handed the stack makes the same
+    slices inside itself (:func:`_mha_layers`), on every call, and XLA
+    copies some of them (403 MB a decode step at the flagship's
+    widths).  Any other tree (one that has ``layers`` already, the
+    latent and hybrid families') is returned as the object it is."""
+    blocks = params.get("blocks")
+    if blocks is None or "wq" not in blocks:
+        return params
+    n_stages, lps = blocks["ln1"].shape[:2]
+    tree = {k: v for k, v in params.items() if k != "blocks"}
+    tree["layers"] = [_layer_params(blocks, s, i)
+                      for s in range(n_stages) for i in range(lps)]
+    return tree
+
+
 #: rows of a prompt one pass of a prefill layer takes where the prompt
 #: is longer: q, o and the FFN's hidden activations of 8k rows are a
 #: quarter of a 32k prompt's, which beside 9.5 GB of weights would not
@@ -902,20 +925,17 @@ def _into_stream(cfg: TransformerConfig) -> dict:
 
 def _mha_layers(params, cfg: TransformerConfig):
     """Yields ``(kind, layer params, first_group)`` in layer order for
-    either tree of the MHA family: the stacked ``blocks`` (``first_group``
-    None), or the per-layer ``layers`` beside the one stack of held
-    experts, the layer's own at ``first_group`` (see
+    either tree of the MHA family: the per-layer ``layers`` as the
+    serving engine holds them, or the stacked ``blocks`` as training
+    holds them, whose per-layer slices are then taken here, inside the
+    caller's program (:func:`per_layer_params`).  Where the model has
+    held experts, their one stack rides in every layer's dict and the
+    layer's own start at ``first_group`` (else None; see
     :func:`_init_held_expert_params`, :func:`_moe_held_ffn`)."""
-    kinds = cfg.layer_kinds
-    if "layers" in params:
-        for i, p in enumerate(params["layers"]):
-            yield kinds[i], {**p, **params["experts"]}, i * cfg.n_experts
-        return
-    blocks = params["blocks"]
-    n_stages, lps = blocks["ln1"].shape[:2]
-    for s in range(n_stages):
-        for i in range(lps):
-            yield kinds[s * lps + i], _layer_params(blocks, s, i), None
+    params = per_layer_params(params)
+    experts = params.get("experts", {})
+    for i, (kind, p) in enumerate(zip(cfg.layer_kinds, params["layers"])):
+        yield kind, {**p, **experts}, i * cfg.n_experts if experts else None
 
 
 def _rotates(kind: str, cfg: TransformerConfig) -> bool:

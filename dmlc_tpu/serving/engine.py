@@ -339,6 +339,13 @@ class InferenceEngine:
     Defaults come from the ``DMLC_SERVE_*`` knobs (see README
     "Serving") so ``bin/dmlc-serve`` and embedded uses read one
     configuration surface.
+
+    The tree the engine holds (``self.params``) is the one its programs
+    read: a stacked MHA tree is turned, once and here, into one array a
+    layer and matrix (``tfm.per_layer_params``: ``params["layers"]``,
+    no ``"blocks"``), and the engine keeps no reference to the stack,
+    so a caller that drops its own holds the weights once.  Every other
+    tree is held as the object that came in.
     """
 
     def __init__(self, params, cfg: "tfm.TransformerConfig", *,
@@ -350,7 +357,7 @@ class InferenceEngine:
                  max_new_tokens: Optional[int] = None,
                  eos_id: Optional[int] = None,
                  slo_monitor=None):
-        self.params = params
+        self.params = tfm.per_layer_params(params)
         self.cfg = cfg
         self.max_active = (max_active if max_active is not None
                            else get_env("DMLC_SERVE_MAX_ACTIVE", 8))

@@ -14,7 +14,9 @@ and a prefill that fails fails alone unless it took the donated pools
 with it.  The decode program owns its pools for the length of a call:
 donated at the engine's jit site, never sliced by layer, dead rows
 harmless in every layer, and a call that fails with the pools in its
-hands is an iteration crash the loop recovers from.
+hands is an iteration crash the loop recovers from.  The weights are
+the engine's in the same way: held one array a layer and matrix, so no
+program slices a stack, with the values and the ids the stack gives.
 """
 
 import json
@@ -576,6 +578,204 @@ def test_failed_decode_that_took_the_pools_is_recovered():
     assert not any(p.is_deleted() for p in fresh)
     assert all(a is not b for a, b in zip(fresh, calls["lost"]))
     assert eng.cache.n_blocks_in_use == 0
+
+
+# ---------------------------------------------------------------------------
+# the engine holds the weights as its programs read them: one array a
+# layer and matrix, so no program takes a layer's slice out of a stack
+# ---------------------------------------------------------------------------
+
+def _family_toy(family):
+    """``(params, cfg)`` of a small model of one family: the flagship's
+    stacked tree, or another family's own test module's."""
+    import importlib
+
+    if family == "mha":
+        return _tiny_model()
+    mod = importlib.import_module(
+        {"mha_swa": "test_cohere2_family", "mla": "test_latent_family",
+         "kda_mla": "test_hybrid_family"}[family])
+    cfg = mod.small()
+    assert cfg.family == family
+    return mod.weights(cfg), cfg
+
+
+def _slices_of_weights(program, n_weights, *args):
+    """The ``slice`` / ``dynamic_slice`` equations of the traced
+    ``program(*args)`` whose operand is one of the program's first
+    ``n_weights`` arguments (the leaves of ``params``), followed through
+    the calls that pass them on whole."""
+    found = []
+
+    def walk(jaxpr, weights):  # weights: ids (a literal has no hash)
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("slice", "dynamic_slice") \
+                    and id(eqn.invars[0]) in weights:
+                found.append(eqn)
+            for val in eqn.params.values():
+                sub = getattr(val, "jaxpr", val)
+                if hasattr(sub, "eqns") \
+                        and len(sub.invars) == len(eqn.invars):
+                    walk(sub, {id(inner) for inner, outer in zip(
+                        sub.invars, eqn.invars) if id(outer) in weights})
+
+    jaxpr = program.trace(*args).jaxpr.jaxpr
+    walk(jaxpr, {id(v) for v in jaxpr.invars[:n_weights]})
+    return found
+
+
+def test_engine_programs_slice_no_weight_argument():
+    """Neither program of an engine built on a stacked tree takes a
+    slice of a weight it was handed; the same programs handed the stack
+    take one a layer and matrix, which XLA is free to copy (403 MB a
+    decode step at the flagship's widths)."""
+    import jax
+
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
+                          max_active=2, queue_depth=4)
+    prefill = getattr(eng._prefill, "_jit", eng._prefill)
+    decode = getattr(eng._decode, "_jit", eng._decode)
+    pools = eng.cache.device_pools()
+    ids = np.zeros((2, 1), np.int32)
+    for tree, sliced in ((eng.params, False), (params, True)):
+        n = len(jax.tree.leaves(tree))
+        calls = (
+            (prefill, tree, np.zeros((1, 8), np.int32),
+             np.zeros((1,), np.int32), *pools, np.zeros((2,), np.int32),
+             cfg),
+            (decode, tree, (ids, ids, np.zeros((2,), np.int32)), ids,
+             *pools, np.zeros((2, 2), np.int32), np.zeros((2,), np.int32),
+             cfg))
+        for program, *args in calls:
+            found = _slices_of_weights(program, n, *args)
+            # ten matrices in each of the toy's two layers
+            assert len(found) == (10 * cfg.n_layers if sliced else 0)
+
+
+def _paged_greedy(params, cfg, prompt, n_new, bs=16, n_blocks=4):
+    """Greedy decode of one prompt through forward_prefill_paged and
+    forward_decode_paged called as they are, untraced: ``(ids, the
+    logits of every pick)``."""
+    import jax.numpy as jnp
+
+    from dmlc_tpu.models import transformer as tfm
+
+    shape = (cfg.n_layers, n_blocks, bs, cfg.kv_heads, cfg.head_dim)
+    k_pool, v_pool = jnp.zeros(shape), jnp.zeros(shape)
+    n = len(prompt)
+    padded = -(-n // bs) * bs
+    assert n + n_new <= n_blocks * bs
+    row, k_pool, v_pool = tfm.forward_prefill_paged(
+        params, np.array([prompt + [0] * (padded - n)], np.int32),
+        np.array([n - 1], np.int32), k_pool, v_pool,
+        np.arange(padded // bs, dtype=np.int32), cfg)
+    table = np.arange(n_blocks, dtype=np.int32)[None]
+    ids, logits = [], [np.asarray(row[0])]
+    for length in range(n, n + n_new - 1):
+        ids.append(int(np.argmax(logits[-1])))
+        row, k_pool, v_pool = tfm.forward_decode_paged(
+            params, np.array([[ids[-1]]], np.int32),
+            np.array([[length]], np.int32), k_pool, v_pool, table,
+            np.array([length], np.int32), cfg)
+        logits.append(np.asarray(row[0, 0]))
+    ids.append(int(np.argmax(logits[-1])))
+    return ids, np.stack(logits)
+
+
+@pytest.mark.parametrize("branch", ["lax", "kernel"])
+def test_both_trees_give_the_same_logits_and_the_engine_their_ids(branch):
+    """The per-layer tree is the stack's values in other arrays: the
+    programs give the same logits from either, bit for bit, and an
+    engine built on either emits the ids the stack's logits pick."""
+    import dataclasses
+
+    from dmlc_tpu.models import transformer as tfm
+    from dmlc_tpu.ops import dispatch
+
+    params, cfg, mode = _decode_branch(branch)
+    # a vocabulary no other test has: the engine's jitted programs are
+    # shared process-wide, and one traced under another mode would be
+    # found again
+    cfg = dataclasses.replace(cfg, vocab=cfg.vocab - 8)
+    params = {**params, "embed": params["embed"][:cfg.vocab],
+              "unembed": params["unembed"][:, :cfg.vocab]}
+    layered = tfm.per_layer_params(params)
+    prompt, n_new = [3, 1, 4, 1, 5, 9, 2], 12
+    with dispatch.force_kernel_mode(mode):
+        ids, logits = _paged_greedy(params, cfg, prompt, n_new)
+        ids_layered, logits_layered = _paged_greedy(
+            layered, cfg, prompt, n_new)
+        np.testing.assert_array_equal(logits_layered, logits)
+        assert ids_layered == ids
+        for tree in (params, layered):
+            eng = InferenceEngine(tree, cfg, n_blocks=8, block_size=16,
+                                  max_active=2, queue_depth=4)
+            req = eng.submit(prompt, max_new_tokens=n_new)
+            for _ in range(4 * n_new):
+                if req.wait(0):
+                    break
+                eng.step()
+            assert req.error is None and list(req.generated) == ids
+
+
+@pytest.mark.parametrize("family", ["mha", "mha_swa"])
+def test_mha_layers_yields_the_same_layers_from_either_tree(family):
+    """``_mha_layers`` over the stack and over what ``per_layer_params``
+    makes of it yields equal dicts; a tree that has ``layers`` (here
+    beside its one stack of held experts) is converted to itself and
+    yields each layer's own arrays with the experts' beside them."""
+    from dmlc_tpu.models import transformer as tfm
+
+    params, cfg = _family_toy(family)
+    layered = tfm.per_layer_params(params)
+    walked = list(tfm._mha_layers(layered, cfg))
+    assert [kind for kind, _, _ in walked] == list(cfg.layer_kinds)
+    if family == "mha":
+        assert "blocks" not in layered and set(layered) - {"layers"} == (
+            set(params) - {"blocks"})
+        for (kind, p, group), (kind_s, p_s, group_s) in zip(
+                walked, tfm._mha_layers(params, cfg), strict=True):
+            assert (kind, group) == (kind_s, group_s) == ("mha", None)
+            assert p.keys() == p_s.keys() == params["blocks"].keys()
+            for name in p:
+                np.testing.assert_array_equal(p[name], p_s[name])
+    else:
+        assert layered is params
+        for i, (_, p, group) in enumerate(walked):
+            assert group == i * cfg.n_experts
+            assert p.keys() == (params["layers"][i].keys()
+                                | params["experts"].keys())
+            for name, a in p.items():
+                assert a is params["layers"][i].get(
+                    name, params["experts"].get(name))
+
+
+@pytest.mark.parametrize("family", ["mha", "mha_swa", "mla", "kda_mla"])
+def test_the_tree_an_engine_holds(family):
+    """An MHA engine handed the stack holds one array a layer and
+    matrix and nothing with the stack's leading axes; every other tree
+    is held as the very object that came in."""
+    import jax
+
+    params, cfg = _family_toy(family)
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=8,
+                          max_active=2, queue_depth=4)
+    if family != "mha":
+        assert eng.params is params
+        return
+    lead = params["blocks"]["ln1"].shape[:2]
+    assert lead == (1, cfg.n_layers)
+    assert "blocks" not in eng.params
+    assert len(eng.params["layers"]) == cfg.n_layers
+    for name in ("embed", "unembed", "ln_f"):
+        assert eng.params[name] is params[name]
+    stacked = {a.shape for a in jax.tree.leaves(params["blocks"])}
+    for a in jax.tree.leaves(eng.params["layers"]):
+        assert a.shape[:2] != lead and (lead + a.shape) in stacked
+    again = InferenceEngine(eng.params, cfg, n_blocks=16, block_size=8,
+                            max_active=2, queue_depth=4)
+    assert again.params is eng.params
 
 
 # ---------------------------------------------------------------------------
