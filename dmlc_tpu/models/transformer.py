@@ -730,8 +730,8 @@ def _moe_ffn(x, p, axes: ShardAxes, cfg: "TransformerConfig"):
 
 def _block(x, p, positions, axes: ShardAxes, cfg: "TransformerConfig"):
     # named_scope labels are trace-time only (zero runtime cost); they
-    # name the HLO so profiler captures and the compute phase ledger
-    # can attribute device time to attention vs mlp
+    # name the HLO so a profiler capture's reduction can attribute
+    # device time to attention vs mlp
     with jax.named_scope("attention"):
         x = x + _attention(rms_norm(x, p["ln1"]), p, positions, axes)
     with jax.named_scope("mlp"):

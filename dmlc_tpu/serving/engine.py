@@ -29,12 +29,15 @@ burn-rate objectives behind ``/slo``).
 
 Where an iteration's time goes is measured from inside, with
 ``telemetry.span``: ``serving.iteration`` and under it disjoint
-children (``serving.schedule``, ``serving.prefill`` with ``.run``,
-``serving.kv_write``, ``serving.first_token``, ``serving.decode`` with
-``.dispatch`` / ``.fetch`` / ``.commit`` / ``.deliver`` /
-``.bookkeeping``), each carrying ``args.iter``; an idle episode of the
-loop is one ``serving.starved`` span.  Every span is a host event in a
-profiler capture and a ``<suffix>_secs`` / ``<suffix>_count`` counter
+children (``serving.schedule``, ``serving.prefill`` with ``.run``
+and, inside it, ``.dispatch`` and ``.fetch``, ``serving.kv_write``,
+``serving.first_token``, ``serving.decode`` with ``.dispatch`` /
+``.fetch`` / ``.commit`` / ``.deliver`` / ``.bookkeeping``), each
+carrying ``args.iter``; an idle episode of the loop is one
+``serving.starved`` span, and the engine's construction one
+``serving.engine_init`` (over ``.weights`` and ``.cache``) on the
+thread that builds it.  Every span is a host event in a profiler
+capture and a ``<suffix>_secs`` / ``<suffix>_count`` counter
 pair; the bytes that cross the host link are counted at the same
 boundaries (README "Serving").
 
@@ -120,7 +123,8 @@ _JIT_CACHE: dict = {}
 #: ``kv_write`` by name and read null without a term, so they stay, at 0
 _SPAN_FAMILIES = (
     "iteration", "schedule", "prefill", "prefill_run",
-    "prefill_kv_to_host", "kv_write", "kv_upload", "first_token",
+    "prefill_dispatch", "prefill_fetch", "prefill_kv_to_host",
+    "kv_write", "kv_upload", "first_token",
     "decode", "decode_dispatch", "decode_fetch", "decode_commit",
     "decode_deliver", "decode_bookkeeping", "starved", "http")
 _ZEROED_COUNTERS = tuple(
@@ -348,6 +352,10 @@ class InferenceEngine:
     tree is held as the object that came in.
     """
 
+    # the construction is a span, for the set-up it is part of: the
+    # weights' re-layout and the cache manager's construction are its
+    # children (the pools themselves are made at first use)
+    @telemetry.span("serving.engine_init", stage="serving")
     def __init__(self, params, cfg: "tfm.TransformerConfig", *,
                  n_blocks: Optional[int] = None,
                  block_size: Optional[int] = None,
@@ -357,7 +365,8 @@ class InferenceEngine:
                  max_new_tokens: Optional[int] = None,
                  eos_id: Optional[int] = None,
                  slo_monitor=None):
-        self.params = tfm.per_layer_params(params)
+        with telemetry.span("serving.engine_init.weights", stage="serving"):
+            self.params = tfm.per_layer_params(params)
         self.cfg = cfg
         self.max_active = (max_active if max_active is not None
                            else get_env("DMLC_SERVE_MAX_ACTIVE", 8))
@@ -384,14 +393,15 @@ class InferenceEngine:
         # a ring for every row that can be live at once: the sliding
         # pool never refuses what the batch has room for
         sliding = cfg.sliding_pool_shapes(self.max_active, block_size)
-        self.cache = PagedKVCache(
-            cfg.n_layers, cfg.n_heads, cfg.head_dim,
-            n_blocks=n_blocks, block_size=block_size,
-            dtype=np.dtype(cfg.dtype),
-            pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size),
-            state_shapes=cfg.state_slot_shapes(self.max_active),
-            sliding_shapes=sliding,
-            sliding_window=cfg.sliding_window if sliding else 0)
+        with telemetry.span("serving.engine_init.cache", stage="serving"):
+            self.cache = PagedKVCache(
+                cfg.n_layers, cfg.n_heads, cfg.head_dim,
+                n_blocks=n_blocks, block_size=block_size,
+                dtype=np.dtype(cfg.dtype),
+                pool_shapes=cfg.kv_pool_shapes(n_blocks, block_size),
+                state_shapes=cfg.state_slot_shapes(self.max_active),
+                sliding_shapes=sliding,
+                sliding_window=cfg.sliding_window if sliding else 0)
         self.scheduler = ContinuousBatchScheduler(
             self.cache, max_active=self.max_active)
         depth = (queue_depth if queue_depth is not None
@@ -909,13 +919,18 @@ class InferenceEngine:
         program, and for a decode step in flight before it."""
         with self._span("serving.prefill", tokens=n, req=req.id):
             with self._span("serving.prefill.run", req=req.id):
-                picked, pools, moe = self._on_pools(
-                    self._prefill, self.params, ids, last,
-                    np.asarray(self.cache.block_table(req.id), np.int32),
-                    *self._prefill_args(req.id), at=3)
-                _start_fetch(*picked, *moe)
-                picked = [np.asarray(a) for a in picked]
-                moe = [np.asarray(m) for m in moe]
+                with self._span("serving.prefill.dispatch", req=req.id):
+                    picked, pools, moe = self._on_pools(
+                        self._prefill, self.params, ids, last,
+                        np.asarray(self.cache.block_table(req.id),
+                                   np.int32),
+                        *self._prefill_args(req.id), at=3)
+                    _start_fetch(*picked, *moe)
+                # the wait for the decode step in flight, the prefill,
+                # and the runtime's notice of its end
+                with self._span("serving.prefill.fetch", req=req.id):
+                    picked = [np.asarray(a) for a in picked]
+                    moe = [np.asarray(m) for m in moe]
         telemetry.inc("serving", "prefill_d2h_bytes",
                       sum(a.nbytes for a in picked + moe))
         self._count_moe(moe)
@@ -1269,44 +1284,43 @@ class InferenceEngine:
         n_accepted = 0
         n_discarded = 0
         with self._span("serving.decode.commit"):
-            with compute.phase("sampling"):
-                # the walk touches only python ints
-                outcomes = []
-                for i, req in enumerate(active):
-                    if req.state != ACTIVE:
-                        # it ended (eos, a non-finite row) in the step
-                        # before this one, which was read after this
-                        # one was dispatched with it: the token is no
-                        # output, and its K/V went into blocks that
-                        # were the row's until that read freed them
-                        n_discarded += 1
-                        continue
-                    draft = drafts[i]
-                    n_proposed += len(draft)
-                    n_row = 0
-                    fail = False
-                    done = False
-                    for s in range(1 + len(draft)):
-                        if not fin[i, s]:
-                            telemetry.inc("serving", "nonfinite_failures")
-                            logger.error(
-                                "request %d produced non-finite logits "
-                                "at decode position %d", req.id,
-                                int(base_lens[i]) + s)
-                            fail = True
-                            break
-                        next_id = int(amax[i, s])
-                        req.generated.append(next_id)
-                        n_row += 1
-                        if req.is_finished_by(next_id):
-                            done = True
-                            break
-                        if s < len(draft) and draft[s] == next_id:
-                            n_accepted += 1
-                            continue
+            # the walk touches only python ints
+            outcomes = []
+            for i, req in enumerate(active):
+                if req.state != ACTIVE:
+                    # it ended (eos, a non-finite row) in the step
+                    # before this one, which was read after this
+                    # one was dispatched with it: the token is no
+                    # output, and its K/V went into blocks that
+                    # were the row's until that read freed them
+                    n_discarded += 1
+                    continue
+                draft = drafts[i]
+                n_proposed += len(draft)
+                n_row = 0
+                fail = False
+                done = False
+                for s in range(1 + len(draft)):
+                    if not fin[i, s]:
+                        telemetry.inc("serving", "nonfinite_failures")
+                        logger.error(
+                            "request %d produced non-finite logits "
+                            "at decode position %d", req.id,
+                            int(base_lens[i]) + s)
+                        fail = True
                         break
-                    outcomes.append((req, i, n_row, fail, done))
-                    n_tokens += n_row
+                    next_id = int(amax[i, s])
+                    req.generated.append(next_id)
+                    n_row += 1
+                    if req.is_finished_by(next_id):
+                        done = True
+                        break
+                    if s < len(draft) and draft[s] == next_id:
+                        n_accepted += 1
+                        continue
+                    break
+                outcomes.append((req, i, n_row, fail, done))
+                n_tokens += n_row
             # the dispatch advanced every row by its first position; a
             # verify window's accepted drafts are the rest (contiguous
             # by construction, in ONE batched cache visit; a rejected

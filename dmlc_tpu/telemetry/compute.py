@@ -1,18 +1,24 @@
-"""Compute observability: compile ledger, XLA cost/roofline, HBM, phases.
+"""Compute observability: compile ledger, XLA cost/roofline, HBM.
 
 The step ledger (PR 5) and request ledger (PR 12) decompose a step into
 feed / collective / "device-compute residual" and a request into
 queue / prefill / decode — but the residual itself was a black box.
-This module opens it along four axes:
+This module opens it along three axes:
 
   * **compile ledger** — :func:`profiled_jit` wraps every ``jax.jit``
     entry the repo owns and takes over its compile cache through the
-    AOT path (``lower().compile()``): exact cache-hit vs. trace
-    counting, compile wall-time spans on the flight recorder, and each
-    recompile attributed to the (shape, dtype) signature that
-    triggered it.  Signature churn beyond a threshold inside a sliding
-    window is a *recompile storm* — shipped to the tracker watchdog as
-    the ``recompile_storm`` anomaly kind.
+    AOT path (``trace().lower().compile()``): exact cache-hit vs. trace
+    counting, each recompile attributed to the (shape, dtype) signature
+    that triggered it, and a compile timed phase by phase with
+    ``telemetry.span``: ``compute.compile`` over ``.trace`` (Python to
+    a jaxpr), ``.lower`` (jaxpr to StableHLO) and ``.backend`` (XLA's
+    compile, or the persistent cache's read, deserialise and load),
+    then ``compute.first_call`` around a fresh executable's first
+    launch.  Whether the persistent cache answered is JAX's own word
+    (its ``/jax/compilation_cache/*`` monitoring events), never a
+    guess from a duration.  Signature churn beyond a threshold inside
+    a sliding window is a *recompile storm* — shipped to the tracker
+    watchdog as the ``recompile_storm`` anomaly kind.
   * **cost/roofline ledger** — the first compile of a signature pulls
     the executable's XLA cost analysis (FLOPs, bytes accessed) for
     free; combined with the per-dtype peak-FLOPs / HBM-bandwidth
@@ -23,11 +29,6 @@ This module opens it along four axes:
     ``Device.memory_stats()`` with a host-RSS fallback for backends
     (CPU) that report none, plus a headroom gauge future autoscaling /
     KV-quantization work gates on.
-  * **phase decomposition** — host-measured spans for the host-side
-    decode phases (KV gather, sampling), exported as per-phase time
-    shares.  What the device does inside the fused decode program is
-    a profiler capture's to say (``benchmarks/reduce_trace.py``), not
-    a host clock's.
 
 Everything here is dark-cheap: ``DMLC_COMPUTE_PROFILE=1`` (default)
 costs counters and one dict lookup per jitted call; ``=0`` makes
@@ -39,6 +40,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import threading
 import time
 from collections import deque
 from typing import Any, Dict, Optional, Tuple
@@ -48,17 +50,13 @@ from ..concurrency import make_lock
 from . import core
 
 __all__ = [
-    "PHASES", "profiled_jit", "enabled", "sites",
-    "roofline", "sample_hbm", "phase", "phase_shares",
+    "profiled_jit", "enabled", "sites",
+    "roofline", "sample_hbm",
     "recompiles_total", "status", "report", "prometheus_text",
     "reset_compute",
 ]
 
 logger = logging.getLogger("dmlc_tpu.telemetry")
-
-# the fixed decode-phase vocabulary: both are measured on the host
-# (they ARE host work)
-PHASES = ("gather", "sampling")
 
 
 def enabled() -> bool:
@@ -123,6 +121,59 @@ def _sig_text(key) -> str:
     return ";".join(parts)
 
 
+# JAX's own word on its persistent compilation cache: the events it
+# records as a compile finds (or writes) an entry.  ``cache_misses``
+# fires where an entry is WRITTEN, so a program the cache would not
+# keep (under its size or compile-time floors, or no cache at all)
+# fires neither: ``off``
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_cache_tls = threading.local()
+_listening = False  # guarded by _lock
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    kind = _CACHE_EVENTS.get(event)
+    if kind is None:
+        return
+    core.inc("compute", "cache_hits" if kind == "hit" else "cache_misses")
+    seen = getattr(_cache_tls, "seen", None)
+    if seen is not None:  # a site's backend compile, on this thread
+        seen.append(kind)
+
+
+def _on_cache_duration(event: str, secs: float, **_kw) -> None:
+    if event == _CACHE_RETRIEVAL:
+        core.inc("compute", "cache_retrieval_secs", secs)
+
+
+def _listen() -> None:
+    """Register the cache listener once a process (JAX calls a listener
+    on the thread that compiles): the process counters
+    ``compute.cache_hits`` / ``cache_misses`` / ``cache_retrieval_secs``
+    also count plain ``jax.jit`` sites."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+
+    monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_cache_duration)
+
+
+@contextlib.contextmanager
+def _timed(name: str, site: str, times: Dict[str, float]):
+    """A compile-phase span, its length (the span's own) kept in
+    ``times`` under the name's last part."""
+    with core.span(name, stage="compute", args={"site": site}) as args:
+        rec = core.current_span()
+        yield args
+    times[name.rsplit(".", 1)[-1]] = rec["dur"] * 1e-6
+
+
 class _ProfiledJit:
     """A ``jax.jit`` wrapper that owns its compile cache.
 
@@ -156,7 +207,12 @@ class _ProfiledJit:
         self.hits = 0
         self.recompiles = 0
         self.aot_fallbacks = 0
-        self.compile_secs_total = 0.0
+        # seconds by phase, each the sum of its spans' own lengths
+        self._phase_secs = dict.fromkeys(
+            ("trace", "lower", "backend", "first_call"), 0.0)
+        # the persistent cache's answers to this site's backend compiles
+        self.cache_hits = 0
+        self.cache_misses = 0
         self.last_cost: Optional[Dict] = None
         self.last_signature: Optional[str] = None
         self._trace_times: deque = deque(maxlen=256)
@@ -174,6 +230,7 @@ class _ProfiledJit:
         self._arg_sig_memo: Dict[int, Tuple[Any, Any]] = {}
         with _lock:
             _sites[self.site] = self
+        _listen()
 
     # -- signature ------------------------------------------------------
     def _signature(self, args) -> Tuple:
@@ -217,12 +274,16 @@ class _ProfiledJit:
 
     # -- compile (cache miss) -------------------------------------------
     def _compile(self, key, args):
+        """``(entry, fresh)``: the executable of a signature seen for
+        the first time, compiled under ``compute.compile`` and its
+        three children (a child of whatever span the caller has open: a
+        request's prefill, where nothing warmed the program up)."""
         with self._lock:
             entry = self._cache.get(key)
             if entry is not None:  # raced another thread's compile
                 self.hits += 1
                 self.last_cost = entry[1]
-                return entry
+                return entry, False
             if (self._max_sigs is not None
                     and len(self._cache) >= self._max_sigs):
                 raise DMLCError(
@@ -232,29 +293,54 @@ class _ProfiledJit:
                     f"is a full XLA recompile; bucket the inputs or "
                     f"raise the cap")
             sig = _sig_text(key)
-            t0 = time.perf_counter()
-            compiled = self._jit.lower(*args).compile()
-            t1 = time.perf_counter()
+            times: Dict[str, float] = {}
+            seen: list = []
+            with core.span("compute.compile", stage="compute",
+                           args={"site": self.site, "signature": sig,
+                                 "trace": self.traces + 1}) as span_args:
+                with _timed("compute.compile.trace", self.site, times):
+                    traced = self._jit.trace(*args)
+                with _timed("compute.compile.lower", self.site, times):
+                    lowered = traced.lower()
+                with _timed("compute.compile.backend", self.site,
+                            times) as backend_args:
+                    _cache_tls.seen = seen
+                    try:
+                        compiled = lowered.compile()
+                    finally:
+                        _cache_tls.seen = None
+                    # the last word counts: a miss is reported where
+                    # the entry is written, after the compile
+                    cache = seen[-1] if seen else "off"
+                    backend_args["cache"] = span_args["cache"] = cache
+            for phase, secs in times.items():
+                self._phase_secs[phase] += secs
             self.traces += 1
-            n_traces = self.traces
             n_recompiles = self.recompiles = self.traces - 1
             self._trace_times.append((time.time(), sig))
-            self.compile_secs_total += t1 - t0
+            self.cache_hits += seen.count("hit")
+            self.cache_misses += seen.count("miss")
             self.last_signature = sig
             cost = _extract_cost(compiled)
             self.last_cost = cost
             entry = (compiled, cost)
             self._cache[key] = entry
-        core.observe_duration("compute", "compile", t1 - t0)
-        core.record_span(f"compile:{self.site}", stage="compute",
-                         t0=t0, t1=t1,
-                         args={"site": self.site, "signature": sig,
-                               "trace": n_traces})
         if n_recompiles:
             logger.info("compute: recompile #%d at site %s for "
-                        "signature %s (%.3fs)", n_recompiles,
-                        self.site, sig, t1 - t0)
-        return entry
+                        "signature %s (%.3fs, cache %s)", n_recompiles,
+                        self.site, sig, sum(times.values()), cache)
+        return entry, True
+
+    def _first_call(self, compiled, dyn):
+        """The first launch of a fresh executable, on the host's clock
+        (what a program pays once beyond its compile: the runtime's
+        load, the first transfer of its constants)."""
+        times: Dict[str, float] = {}
+        with _timed("compute.first_call", self.site, times):
+            out = compiled(*dyn)
+        with self._lock:
+            self._phase_secs["first_call"] += times["first_call"]
+        return out
 
     # -- dispatch --------------------------------------------------------
     def __call__(self, *args):
@@ -266,17 +352,20 @@ class _ProfiledJit:
                 self.aot_fallbacks += 1
             core.inc("compute", "aot_fallbacks")
             return self._jit(*args)
+        fresh = False
         with self._lock:
             entry = self._cache.get(key)
             if entry is not None:
                 self.hits += 1
                 self.last_cost = entry[1]
         if entry is None:
-            entry = self._compile(key, args)
+            entry, fresh = self._compile(key, args)
         compiled, _cost = entry
         dyn = tuple(a for i, a in enumerate(args)
                     if i not in self._static)
         try:
+            if fresh:
+                return self._first_call(compiled, dyn)
             return compiled(*dyn)
         except Exception:  # noqa: BLE001 - e.g. committed-device mismatch
             with self._lock:
@@ -287,12 +376,18 @@ class _ProfiledJit:
     # -- views -----------------------------------------------------------
     def stats(self) -> Dict:
         with self._lock:
+            secs = self._phase_secs
             return {
                 "traces": self.traces,
                 "hits": self.hits,
                 "recompiles": self.recompiles,
                 "aot_fallbacks": self.aot_fallbacks,
-                "compile_secs_total": round(self.compile_secs_total, 6),
+                "compile_secs_total": round(
+                    secs["trace"] + secs["lower"] + secs["backend"], 6),
+                **{f"{phase}_secs_total": round(v, 6)
+                   for phase, v in secs.items()},
+                "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses,
                 "signatures": len(self._cache),
                 "last_signature": self.last_signature,
                 "last_cost": dict(self.last_cost)
@@ -526,48 +621,6 @@ def sample_hbm(publish: bool = True) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# phase decomposition
-# ---------------------------------------------------------------------------
-
-_phase_lock = make_lock("compute._phase_lock")
-_phase_secs: Dict[str, float] = {p: 0.0 for p in PHASES}
-
-
-def _add_phase(name: str, secs: float) -> None:
-    if secs <= 0:
-        return
-    with _phase_lock:
-        if name in _phase_secs:
-            _phase_secs[name] += secs
-    core.set_gauge("compute", f"phase_{name}_share",
-                   phase_shares().get(name, 0.0))
-
-
-@contextlib.contextmanager
-def phase(name: str):
-    """Host-measured phase scope (gather / sampling): the span
-    ``compute.<name>`` plus the phase-share accounting."""
-    if not enabled():
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        with core.span("compute." + name, stage="compute"):
-            yield
-    finally:
-        _add_phase(name, time.perf_counter() - t0)
-
-
-def phase_shares() -> Dict[str, float]:
-    """Normalized per-phase time shares (empty before any sample)."""
-    with _phase_lock:
-        total = sum(_phase_secs.values())
-        if total <= 0:
-            return {}
-        return {p: s / total for p, s in _phase_secs.items()}
-
-
-# ---------------------------------------------------------------------------
 # views: heartbeat status, /compute document, prometheus text
 # ---------------------------------------------------------------------------
 
@@ -618,7 +671,6 @@ def report() -> Dict:
                                    for s in site_map.values()),
         "storm": _storm_doc(),
         "hbm": hbm if hbm is not None else sample_hbm(),
-        "phases": {"shares": phase_shares(), "measured": PHASES},
         "roofline": _step_roofline(),
     }
 
@@ -638,6 +690,27 @@ def prometheus_text() -> str:
          "jit traces (compiles) per jit site", "traces"),
         ("dmlc_compute_cache_hits_total", "counter",
          "jit compile-cache hits per jit site", "hits"),
+        ("dmlc_compute_site_compile_secs_total", "counter",
+         "seconds compiling (trace + lower + backend) per jit site",
+         "compile_secs_total"),
+        ("dmlc_compute_site_trace_secs_total", "counter",
+         "seconds tracing Python to a jaxpr per jit site",
+         "trace_secs_total"),
+        ("dmlc_compute_site_lower_secs_total", "counter",
+         "seconds lowering a jaxpr to StableHLO per jit site",
+         "lower_secs_total"),
+        ("dmlc_compute_site_backend_secs_total", "counter",
+         "seconds in XLA's compile, or the persistent cache's read and "
+         "load, per jit site", "backend_secs_total"),
+        ("dmlc_compute_site_first_call_secs_total", "counter",
+         "host seconds of fresh executables' first launches per jit site",
+         "first_call_secs_total"),
+        ("dmlc_compute_site_persistent_cache_hits_total", "counter",
+         "backend compiles the persistent cache answered, per jit site",
+         "cache_hits"),
+        ("dmlc_compute_site_persistent_cache_misses_total", "counter",
+         "backend compiles written to the persistent cache, per jit site",
+         "cache_misses"),
     )
     lines = []
     for fam, typ, help_txt, key in fams:
@@ -655,6 +728,3 @@ def reset_compute() -> None:
         _sites.clear()
     with _hbm_lock:
         _last_hbm = None
-    with _phase_lock:
-        for p in PHASES:
-            _phase_secs[p] = 0.0

@@ -56,6 +56,7 @@ __all__ = [
     "spans",
     "spans_since",
     "open_spans",
+    "current_span",
     "anchor_epoch",
     "annotate",
     "snapshot",
@@ -328,6 +329,15 @@ def span(name: str, stage: str = "dmlc", args: Optional[Dict] = None):
             _spans.append(rec)
             _observe_duration_locked(stage, suffix, t1 - t0)
             _counters[stage][suffix + "_count"] += 1
+
+
+def current_span() -> Optional[Dict]:
+    """The record of the innermost span open on this thread (None
+    outside any).  Closing the span adds ``dur`` (µs) to it: a block
+    that keeps the record reads its own span's length afterwards, and
+    needs no second clock for a sum of its own."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
 
 
 #: a named span under stage ``annotate`` (the pre-bridge name of the

@@ -12,8 +12,9 @@ Three cooperating pieces:
 
 ``GoodputLedger``
     Per-process.  Sweeps the telemetry span ring (checkpoint.save /
-    checkpoint.restore / feed.wait / compile:* / step spans) plus explicit
-    :meth:`GoodputLedger.enter` overrides into a partition of wall time:
+    checkpoint.restore / feed.wait / compute.compile / step spans) plus
+    explicit :meth:`GoodputLedger.enter` overrides into a partition of
+    wall time:
     every instant lands in exactly **one** bucket, so the partition
     invariant ``sum(buckets) == wall`` holds by construction.  Ships on
     the heartbeat ``goodput`` sub-doc.
@@ -94,6 +95,9 @@ _SPAN_BUCKETS = {
     "checkpoint.save": "checkpoint_save",
     "checkpoint.restore": "checkpoint_restore",
     "feed.wait": "feed_stall",
+    # the compile ledger's span of a whole compile (its children and
+    # compute.first_call lie inside or beside it and are not swept)
+    "compute.compile": "compile",
 }
 
 _PRI_EXPLICIT = 0  # enter() override — always wins
@@ -107,8 +111,6 @@ def _span_bucket(name: str, cat: str) -> Optional[Tuple[str, int]]:
     b = _SPAN_BUCKETS.get(name)
     if b is not None:
         return (b, _PRI_SPECIFIC)
-    if name.startswith("compile:"):
-        return ("compile", _PRI_SPECIFIC)
     if name == "step" and cat == "step":
         return ("productive", _PRI_STEP)
     return None

@@ -36,7 +36,11 @@ METRIC_NAMES = frozenset({
     "dmlc_anomaly_recompile_storm_flags",
     # compute observability (telemetry.compute): compile ledger
     # (hand-rendered per-site *_total families + registry families),
-    # HBM accounting, time shares of the host-measured decode phases
+    # HBM accounting.  A compile's spans (compute.compile over .trace /
+    # .lower / .backend, then compute.first_call) feed the *_secs /
+    # *_count pairs; cache_hits / cache_misses / cache_retrieval_secs
+    # are JAX's persistent-cache events, process-wide (NOT the per-site
+    # cache_hits_total, which counts signatures found in the wrapper)
     "dmlc_compute_recompiles_total",
     "dmlc_compute_traces_total",
     "dmlc_compute_cache_hits_total",
@@ -45,8 +49,25 @@ METRIC_NAMES = frozenset({
     "dmlc_compute_hbm_live_bytes",
     "dmlc_compute_hbm_peak_bytes",
     "dmlc_compute_hbm_headroom_bytes",
-    "dmlc_compute_phase_gather_share",
-    "dmlc_compute_phase_sampling_share",
+    "dmlc_compute_compile_count",
+    "dmlc_compute_compile_trace_secs",
+    "dmlc_compute_compile_trace_count",
+    "dmlc_compute_compile_lower_secs",
+    "dmlc_compute_compile_lower_count",
+    "dmlc_compute_compile_backend_secs",
+    "dmlc_compute_compile_backend_count",
+    "dmlc_compute_first_call_secs",
+    "dmlc_compute_first_call_count",
+    "dmlc_compute_cache_hits",
+    "dmlc_compute_cache_misses",
+    "dmlc_compute_cache_retrieval_secs",
+    "dmlc_compute_site_compile_secs_total",
+    "dmlc_compute_site_trace_secs_total",
+    "dmlc_compute_site_lower_secs_total",
+    "dmlc_compute_site_backend_secs_total",
+    "dmlc_compute_site_first_call_secs_total",
+    "dmlc_compute_site_persistent_cache_hits_total",
+    "dmlc_compute_site_persistent_cache_misses_total",
     # elastic world resize (tracker generations + client + launcher)
     "dmlc_elastic_resizes_total",
     "dmlc_elastic_shrinks_total",
@@ -399,6 +420,16 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_prefill_kv_to_host_count",
     "dmlc_serving_prefill_run_secs",
     "dmlc_serving_prefill_run_count",
+    "dmlc_serving_prefill_dispatch_secs",
+    "dmlc_serving_prefill_dispatch_count",
+    "dmlc_serving_prefill_fetch_secs",
+    "dmlc_serving_prefill_fetch_count",
+    "dmlc_serving_engine_init_secs",
+    "dmlc_serving_engine_init_count",
+    "dmlc_serving_engine_init_weights_secs",
+    "dmlc_serving_engine_init_weights_count",
+    "dmlc_serving_engine_init_cache_secs",
+    "dmlc_serving_engine_init_cache_count",
     "dmlc_serving_schedule_secs",
     "dmlc_serving_schedule_count",
     "dmlc_serving_starved_secs",

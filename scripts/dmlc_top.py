@@ -27,7 +27,7 @@ per-rank FLAGS column via the heartbeat-shipped status.
 Either target also feeds a **compute pane** from ``/compute``: compile
 ledger totals (traces/hits/recompiles), the recompile-storm verdict,
 the step roofline (``mfu``/``membw_util``/``bound``), HBM peak and
-headroom, and the decode phase time shares; against a tracker the same
+headroom; against a tracker the same
 pane shows per-rank recompile totals and storm-flagged ranks.
 
 A **goodput pane** (``/goodput`` + ``/incidents``) shows the job-level
@@ -194,11 +194,6 @@ def render_compute_pane(doc: dict) -> list:
                     _num(bw * 100 if isinstance(bw, (int, float))
                          else None, "{:.1f}%"),
                     _num(roof.get("intensity"), "{:.1f}")))
-        shares = (comp.get("phases") or {}).get("shares") or {}
-        if shares:
-            lines.append("phases   " + "  ".join(
-                f"{p}={v * 100:.0f}%" for p, v in sorted(
-                    shares.items(), key=lambda kv: -kv[1])))
     elif comp.get("ranks"):  # tracker cluster document
         storming = comp.get("storming_ranks") or []
         parts = []

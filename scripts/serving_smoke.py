@@ -349,11 +349,6 @@ def main():
     assert roof["membw_util"] and roof["mfu"], f"roofline nulls: {roof}"
     assert comp["hbm"] and comp["hbm"].get("peak_bytes"), (
         f"HBM accounting empty: {comp.get('hbm')}")
-    shares = comp["phases"]["shares"]
-    assert shares and abs(sum(shares.values()) - 1.0) < 1e-6, (
-        f"phase shares must normalize to 1: {shares}")
-    assert shares.get("sampling", 0) > 0, (
-        f"the measured sampling phase is missing from shares: {shares}")
     print("serving_smoke: /compute "
           f"bound={roof['bound']} membw_util={roof['membw_util']:.3f} "
           f"recompiles={comp['recompiles_total']} (flat across load) "

@@ -24,7 +24,7 @@ fault-injected (``DMLC_FAULT_SPEC`` delay) to be a straggler — then:
   6. exports the smoke process's own spans as Chrome trace JSON and
      validates it is well-formed with >= 1 complete ("X") event;
   7. (PR 16) rank 1 churns six fresh shapes through a profiled jit
-     site: the compile ledger's ``compile:smoke.churn`` spans reach
+     site: the compile ledger's ``compute.compile`` spans reach
      the cluster /trace, the heartbeat-shipped compute doc trips a
      ``recompile_storm`` flag on rank 1 ONLY (/anomalies + the
      dmlc_anomaly_recompile_storm_flags family + tracker /compute
@@ -79,7 +79,7 @@ with telemetry.span("smoke.work.r%d" % c.rank, stage="smoke"):
     time.sleep(0.05)
 # rank 1 churns shapes through a profiled jit site: each novel shape
 # is a fresh XLA signature, so the compile ledger records the traces
-# (with compile:smoke.churn spans for /trace), the heartbeat ships
+# (with compute.compile spans for /trace), the heartbeat ships
 # the compute doc, and the tracker watchdog must flag a
 # recompile_storm on THIS rank only — rank 0 never touches jax and
 # so never even grows a compute doc
@@ -150,7 +150,7 @@ def validate_merged_trace(url: str) -> None:
     for want in ("smoke.work.r0", "smoke.work.r1", "step",
                  # rank 1's churned compiles draw real spans: compile
                  # wall time is attributable on the cluster trace
-                 "compile:smoke.churn"):
+                 "compute.compile"):
         if want not in names:
             fail(f"/trace missing worker span {want!r}; got {sorted(names)}")
     if any(e["ts"] < 0 for e in evs):

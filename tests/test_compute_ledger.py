@@ -1,5 +1,5 @@
-"""Compute observability (telemetry.compute): compile ledger, XLA
-cost/roofline, HBM accounting, phase decomposition (PR 16).
+"""Compute observability (telemetry.compute): compile ledger (by
+phase since PR 36), XLA cost/roofline, HBM accounting (PR 16).
 
 Everything runs on the virtual CPU mesh: the AOT compile path,
 cost_analysis extraction, the host-RSS memory fallback and the storm
@@ -22,6 +22,7 @@ from dmlc_tpu import telemetry
 from dmlc_tpu.base import DMLCError
 from dmlc_tpu.telemetry import compute
 from dmlc_tpu.telemetry.anomaly import COMPUTE_KINDS, Watchdog
+from dmlc_tpu.telemetry.exporters import validate_exposition_text
 
 
 @pytest.fixture(autouse=True)
@@ -146,9 +147,203 @@ def test_compile_span_lands_on_flight_recorder():
     pj = compute.profiled_jit(lambda x: x * x, site="t.span")
     pj(jnp.ones((3,), jnp.float32))
     trace = json.loads(telemetry.to_chrome_trace_json())
-    names = {e["name"] for e in trace["traceEvents"]
-             if e.get("ph") == "X"}
-    assert "compile:t.span" in names
+    (ev,) = [e for e in trace["traceEvents"]
+             if e.get("ph") == "X" and e["name"] == "compute.compile"]
+    assert ev["args"]["site"] == "t.span"
+    assert ev["args"]["signature"] == "3:float32"
+    assert ev["args"]["trace"] == 1
+
+
+def _compile_spans():
+    return [r for r in telemetry.spans()
+            if r["name"].startswith("compute.")]
+
+
+def test_compile_opens_three_children_in_order():
+    """A miss at a fresh signature: ``compute.compile`` over trace,
+    lower, backend (in that order, each its child), then the first
+    call; the site's seconds are the spans' own."""
+    pj = compute.profiled_jit(lambda x: x * x + 1.0, site="t.phases")
+    pj(jnp.ones((5,), jnp.float32))
+    recs = _compile_spans()
+    assert [r["name"] for r in recs] == [
+        "compute.compile.trace", "compute.compile.lower",
+        "compute.compile.backend", "compute.compile",
+        "compute.first_call"]  # the ring is in close order
+    trace, lower, backend, whole, first = recs
+    for child in (trace, lower, backend):
+        assert child["parent"] == whole["id"]
+        assert child["args"]["site"] == "t.phases"
+    assert whole["parent"] is None and first["parent"] is None
+    assert trace["ts"] + trace["dur"] <= lower["ts"]
+    assert lower["ts"] + lower["dur"] <= backend["ts"]
+    assert backend["ts"] + backend["dur"] <= whole["ts"] + whole["dur"]
+    assert whole["ts"] + whole["dur"] <= first["ts"]
+    assert whole["args"]["cache"] == backend["args"]["cache"]
+    assert whole["args"]["cache"] in ("hit", "miss", "off")
+    st = pj.stats()
+    assert st["trace_secs_total"] == pytest.approx(trace["dur"] / 1e6,
+                                                   abs=1e-6)
+    assert st["lower_secs_total"] == pytest.approx(lower["dur"] / 1e6,
+                                                   abs=1e-6)
+    assert st["backend_secs_total"] == pytest.approx(
+        backend["dur"] / 1e6, abs=1e-6)
+    assert st["first_call_secs_total"] == pytest.approx(
+        first["dur"] / 1e6, abs=1e-6)
+    assert min(st["trace_secs_total"], st["lower_secs_total"],
+               st["backend_secs_total"], st["first_call_secs_total"]) > 0
+    assert st["compile_secs_total"] == pytest.approx(
+        st["trace_secs_total"] + st["lower_secs_total"]
+        + st["backend_secs_total"], abs=1e-3)
+    assert st["compile_secs_total"] <= whole["dur"] / 1e6
+    # the counter pairs are the spans', under the names they had
+    c = telemetry.counters_snapshot()["compute"]
+    assert c["compile_count"] == c["compile_trace_count"] \
+        == c["compile_lower_count"] == c["compile_backend_count"] \
+        == c["first_call_count"] == 1
+    assert c["compile_secs"] == pytest.approx(whole["dur"] / 1e6)
+    assert c["compile_backend_secs"] == pytest.approx(
+        backend["dur"] / 1e6)
+
+
+def test_second_call_of_a_signature_opens_no_span():
+    pj = compute.profiled_jit(lambda x: x - 1.0, site="t.again")
+    x = jnp.ones((4,), jnp.float32)
+    pj(x)
+    n = len(_compile_spans())
+    first = pj.stats()
+    pj(x)
+    pj(x)
+    assert len(_compile_spans()) == n
+    again = pj.stats()
+    assert again["hits"] == 2
+    for field in ("compile_secs_total", "trace_secs_total",
+                  "lower_secs_total", "backend_secs_total",
+                  "first_call_secs_total"):
+        assert again[field] == first[field], field
+    # a second signature is a second compile and a second first call
+    pj(jnp.ones((6,), jnp.float32))
+    assert len(_compile_spans()) == 2 * n
+    assert pj.stats()["first_call_secs_total"] \
+        > first["first_call_secs_total"]
+
+
+def test_compile_inside_an_open_span_is_its_child():
+    """A deployment without warm-up pays a compile inside a request's
+    span: the ledger's spans hang under whatever the thread has open."""
+    pj = compute.profiled_jit(lambda x: x * 3.0, site="t.child")
+    with telemetry.span("serving.prefill.run", stage="serving"):
+        pj(jnp.ones((2,), jnp.float32))
+    by_name = {r["name"]: r for r in telemetry.spans()}
+    run = by_name["serving.prefill.run"]
+    assert by_name["compute.compile"]["parent"] == run["id"]
+    assert by_name["compute.first_call"]["parent"] == run["id"]
+    assert by_name["compute.compile.backend"]["parent"] \
+        == by_name["compute.compile"]["id"]
+    assert by_name["compute.compile"]["depth"] == 1
+
+
+def test_goodput_compile_bucket_fills_from_the_new_span():
+    from dmlc_tpu.telemetry import goodput
+
+    assert goodput._span_bucket("compute.compile", "compute") \
+        == ("compile", goodput._PRI_SPECIFIC)
+    # the children lie inside it: swept, they would count twice
+    for name in ("compute.compile.trace", "compute.compile.backend",
+                 "compute.first_call", "compile:t.site"):
+        assert goodput._span_bucket(name, "compute") is None
+    led = goodput.GoodputLedger()
+    pj = compute.profiled_jit(lambda x: jnp.sin(x) @ x, site="t.goodput")
+    pj(jnp.ones((8, 8), jnp.float32))
+    (whole,) = [r for r in telemetry.spans()
+                if r["name"] == "compute.compile"]
+    doc = led.status()
+    assert doc["buckets"]["compile"] == pytest.approx(
+        whole["dur"] / 1e6, rel=0.05)
+
+
+# ---------------------------------------------------------------------------
+# the persistent cache's own word: JAX's monitoring events
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """A persistent compilation cache of this test's own, that keeps
+    every program however small; JAX's process-wide cache object is
+    reset around it so that it looks at the directory."""
+    from jax._src import compilation_cache
+
+    keys = {"jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0,
+            "jax_persistent_cache_min_entry_size_bytes": -1}
+    was = {k: getattr(jax.config, k) for k in keys}
+    for k, v in keys.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    yield tmp_path
+    for k, v in was.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_persistent_cache_miss_then_hit(persistent_cache):
+    def fn(x):
+        return jnp.tanh(x) * 2.0 + x
+
+    x = jnp.ones((7,), jnp.float32)
+    jax.block_until_ready(x)  # its own small program, before we count
+    before = telemetry.counters_snapshot().get("compute", {})
+    cold = compute.profiled_jit(fn, site="t.cache")
+    cold(x)
+    st = cold.stats()
+    assert (st["cache_misses"], st["cache_hits"]) == (1, 0)
+    assert any(persistent_cache.iterdir())  # the entry was written
+    jax.clear_caches()
+    warm = compute.profiled_jit(fn, site="t.cache")  # a fresh wrapper
+    warm(x)
+    st = warm.stats()
+    assert (st["cache_misses"], st["cache_hits"]) == (0, 1)
+    words = [r["args"]["cache"] for r in telemetry.spans()
+             if r["name"] == "compute.compile"]
+    assert words == ["miss", "hit"]
+    after = telemetry.counters_snapshot()["compute"]
+    assert after["cache_misses"] - before.get("cache_misses", 0) == 1
+    assert after["cache_hits"] - before.get("cache_hits", 0) == 1
+    assert after["cache_retrieval_secs"] > before.get(
+        "cache_retrieval_secs", 0)
+
+
+def test_cache_events_reach_the_site_compiling_on_this_thread(
+        monkeypatch):
+    """The listener driven with JAX's own ``record_event``: an event
+    during a site's backend compile is that site's, one outside any is
+    the process's alone (a plain ``jax.jit`` site)."""
+    from jax import monitoring
+
+    pj = compute.profiled_jit(lambda x: x + 2.0, site="t.events")
+    real = type(pj._jit.trace(jnp.ones(3)).lower()).compile
+
+    def compile_and_report(lowered, *a, **kw):
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        return real(lowered, *a, **kw)
+
+    lowered_type = type(pj._jit.trace(jnp.ones(3)).lower())
+    monkeypatch.setattr(lowered_type, "compile", compile_and_report)
+    telemetry.reset()
+    pj(jnp.ones((3,), jnp.float32))
+    monkeypatch.undo()
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event("/jax/core/some_other_event")
+    st = pj.stats()
+    assert (st["cache_hits"], st["cache_misses"]) == (1, 0)
+    (whole,) = [r for r in telemetry.spans()
+                if r["name"] == "compute.compile"]
+    assert whole["args"]["cache"] == "hit"
+    c = telemetry.counters_snapshot()["compute"]
+    assert c["cache_hits"] == 1 and c["cache_misses"] == 1
+    assert c["cache_retrieval_secs"] == pytest.approx(0.25)
 
 
 def test_reregister_survives_reset():
@@ -239,28 +434,6 @@ def test_sample_hbm_host_rss_fallback(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# phase decomposition
-# ---------------------------------------------------------------------------
-
-def test_phase_shares_are_measured_spans():
-    telemetry.reset()
-    with compute.phase("gather"):
-        time.sleep(0.002)
-    with compute.phase("sampling"):
-        time.sleep(0.001)
-    shares = compute.phase_shares()
-    assert set(shares) == set(compute.PHASES) == {"gather", "sampling"}
-    assert sum(shares.values()) == pytest.approx(1.0)
-    assert shares["gather"] > shares["sampling"] > 0
-    # a phase IS a span: in the ring (and so in a profiler capture),
-    # with its counter pair
-    names = [r["name"] for r in telemetry.spans()]
-    assert names == ["compute.gather", "compute.sampling"]
-    counters = telemetry.counters_snapshot()["compute"]
-    assert counters["gather_count"] == 1 and counters["gather_secs"] > 0
-
-
-# ---------------------------------------------------------------------------
 # views: status / report / prometheus text
 # ---------------------------------------------------------------------------
 
@@ -282,7 +455,13 @@ def test_status_and_report_schema():
     assert rep["recompiles_total"] == 1
     assert rep["storm"]["threshold"] >= 1
     assert rep["hbm"]["peak_bytes"] > 0
-    assert set(rep["phases"]) == {"shares", "measured"}
+    assert "phases" not in rep
+    site = rep["sites"]["t.schema"]
+    assert site["compile_secs_total"] == pytest.approx(
+        site["trace_secs_total"] + site["lower_secs_total"]
+        + site["backend_secs_total"], abs=1e-3)
+    assert {"first_call_secs_total", "cache_hits", "cache_misses"} \
+        <= set(site)
     assert "bound" in rep["roofline"]
 
 
@@ -306,6 +485,20 @@ def test_prometheus_text_per_site_families():
     assert 'dmlc_compute_recompiles_total{site="t.prom"} 1' in text
     assert 'dmlc_compute_traces_total{site="t.prom"} 2' in text
     assert 'dmlc_compute_cache_hits_total{site="t.prom"} 0' in text
+    # the seconds by phase and the persistent cache's answers, through
+    # the same per-site families
+    for name in ("dmlc_compute_site_compile_secs_total",
+                 "dmlc_compute_site_trace_secs_total",
+                 "dmlc_compute_site_lower_secs_total",
+                 "dmlc_compute_site_backend_secs_total",
+                 "dmlc_compute_site_first_call_secs_total"):
+        assert f"# TYPE {name} counter" in text
+        (line,) = [ln for ln in text.splitlines()
+                   if ln.startswith(name + '{site="t.prom"}')]
+        assert float(line.split()[-1]) > 0
+    assert 'dmlc_compute_site_persistent_cache_hits_total{site="t.prom"}' \
+        in text
+    validate_exposition_text(text)
 
 
 # ---------------------------------------------------------------------------
@@ -322,11 +515,38 @@ def test_disabled_returns_plain_jit(monkeypatch):
     assert compute.status() == {}
 
 
-def test_disabled_phase_scope_accumulates_nothing(monkeypatch):
+def test_disabled_registers_no_listener_and_no_span(monkeypatch):
+    from jax._src import monitoring
+
     monkeypatch.setenv("DMLC_COMPUTE_PROFILE", "0")
-    with compute.phase("gather"):
-        time.sleep(0.001)
-    assert compute.phase_shares() == {}
+    # as in a process that never made a site
+    monkeypatch.setattr(compute, "_listening", False)
+    monkeypatch.setattr(
+        monitoring, "_event_listeners",
+        [f for f in monitoring._event_listeners
+         if f is not compute._on_cache_event])
+    monkeypatch.setattr(
+        monitoring, "_event_duration_secs_listeners",
+        [f for f in monitoring._event_duration_secs_listeners
+         if f is not compute._on_cache_duration])
+    pj = compute.profiled_jit(lambda x: x * 5.0, site="t.dark")
+    assert float(pj(jnp.ones((2,), jnp.float32))[0]) == 5.0
+    assert compute._listening is False
+    assert compute._on_cache_event not in monitoring.get_event_listeners()
+    assert compute._on_cache_duration \
+        not in monitoring.get_event_duration_listeners()
+    assert not [r for r in telemetry.spans()
+                if r["name"].startswith("compute.")]
+    assert "compute" not in telemetry.counters_snapshot()
+    # ... and with the profile on, the first site registers both, once
+    monkeypatch.setenv("DMLC_COMPUTE_PROFILE", "1")
+    compute.profiled_jit(lambda x: x, site="t.lit")
+    compute.profiled_jit(lambda x: x, site="t.lit2")
+    assert compute._listening is True
+    assert monitoring.get_event_listeners().count(
+        compute._on_cache_event) == 1
+    assert monitoring.get_event_duration_listeners().count(
+        compute._on_cache_duration) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -373,14 +593,12 @@ def test_render_compute_pane_replica_shape():
     top = _load_top()
     pj = compute.profiled_jit(lambda x: x, site="t.pane")
     pj(jnp.zeros((2,), jnp.float32))
-    with compute.phase("sampling"):
-        time.sleep(0.001)
     compute.sample_hbm()
     lines = top.render_compute_pane({"compute": compute.report()})
     text = "\n".join(lines)
     assert "compute  traces=1" in text
     assert "storm=ok" in text
-    assert "phases" in text and "sampling=100%" in text
+    assert "phases" not in text
 
 
 def test_render_compute_pane_tracker_shape():

@@ -1141,6 +1141,8 @@ def test_engine_iteration_yields_the_span_tree():
         ("serving.schedule", "serving.iteration"),   # allocation
         ("serving.prefill", "serving.iteration"),
         ("serving.prefill.run", "serving.prefill"),
+        ("serving.prefill.dispatch", "serving.prefill.run"),
+        ("serving.prefill.fetch", "serving.prefill.run"),
         ("serving.kv_write", "serving.iteration"),
         ("serving.first_token", "serving.iteration"),
         ("serving.schedule", "serving.iteration"),   # next_prefill: none
@@ -1150,7 +1152,6 @@ def test_engine_iteration_yields_the_span_tree():
         ("serving.decode.dispatch", "step"),
         ("serving.decode.fetch", "step"),
         ("serving.decode.commit", "step"),
-        ("compute.sampling", "serving.decode.commit"),
         ("serving.decode.deliver", "serving.decode"),
         ("serving.decode.bookkeeping", "serving.decode"),
     ])
@@ -1161,7 +1162,8 @@ def test_engine_iteration_yields_the_span_tree():
     owned = {r["name"] for r in recs
              if r.get("args", {}).get("req") == req.id}
     assert owned == {"serving.schedule", "serving.prefill",
-                     "serving.prefill.run", "serving.kv_write",
+                     "serving.prefill.run", "serving.prefill.dispatch",
+                     "serving.prefill.fetch", "serving.kv_write",
                      "serving.first_token"}
     eng.close()
 
@@ -1193,6 +1195,79 @@ def test_engine_iteration_children_are_disjoint_and_account_for_it():
     assert c["iteration_secs"] == pytest.approx(total / 1e6)
     assert c["prefill_count"] == 1 and c["prefill_tokens"] == 6
     eng.close()
+
+
+def test_prefill_dispatch_and_fetch_add_up_to_run():
+    """The prefill's device call is split as the decode's is: the
+    program's call, then the blocking read, both inside ``.run`` and
+    leaving out only the python between two spans."""
+    eng = _warm_engine()
+    telemetry.reset()
+    real_prefill = eng._prefill
+
+    def prefill(*a):
+        # a toy prefill is launched in half a millisecond, of which the
+        # three spans' own bookkeeping is a tenth: give the call a
+        # length that a real one has
+        time.sleep(0.005)
+        return real_prefill(*a)
+
+    eng._prefill = prefill
+    for i in range(6):
+        eng.submit([9, 8, 7, 6, 5, 4 + i], max_new_tokens=2)
+    for _ in range(12):
+        eng.step()
+    recs = _engine_thread_spans()
+    runs = [r for r in recs if r["name"] == "serving.prefill.run"]
+    assert len(runs) == 6
+    inside = 0.0
+    for run in runs:
+        kids = sorted((r for r in recs if r["parent"] == run["id"]),
+                      key=lambda r: r["ts"])
+        assert [k["name"] for k in kids] == ["serving.prefill.dispatch",
+                                             "serving.prefill.fetch"]
+        assert kids[0]["ts"] >= run["ts"]
+        assert kids[0]["ts"] + kids[0]["dur"] <= kids[1]["ts"]
+        assert kids[1]["ts"] + kids[1]["dur"] <= run["ts"] + run["dur"]
+        assert all(k["args"]["req"] == run["args"]["req"] for k in kids)
+        inside += kids[0]["dur"] + kids[1]["dur"]
+    assert inside >= 0.95 * sum(r["dur"] for r in runs)
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["prefill_dispatch_count"] == c["prefill_fetch_count"] == 6
+    assert c["prefill_dispatch_secs"] + c["prefill_fetch_secs"] \
+        == pytest.approx(inside / 1e6)
+    eng.close()
+
+
+def test_engine_construction_is_a_span_with_both_children():
+    params, cfg = _tiny_model()
+    telemetry.reset()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    recs = [r for r in telemetry.spans()
+            if r["name"].startswith("serving.engine_init")]
+    (init,) = [r for r in recs if r["name"] == "serving.engine_init"]
+    kids = sorted((r for r in recs if r["parent"] == init["id"]),
+                  key=lambda r: r["ts"])
+    assert [k["name"] for k in kids] == ["serving.engine_init.weights",
+                                         "serving.engine_init.cache"]
+    assert len(recs) == 3
+    assert kids[0]["ts"] >= init["ts"]
+    assert kids[0]["ts"] + kids[0]["dur"] <= kids[1]["ts"]
+    assert kids[1]["ts"] + kids[1]["dur"] <= init["ts"] + init["dur"]
+    # on the constructing thread, a child of nothing
+    assert init["parent"] is None
+    assert init["tid"] == threading.get_ident()
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["engine_init_count"] == 1
+    assert c["engine_init_secs"] == pytest.approx(init["dur"] / 1e6)
+    assert c["engine_init_weights_secs"] + c["engine_init_cache_secs"] \
+        <= c["engine_init_secs"]
+    # start() zeroes the iteration's families, not the construction's
+    eng.start()
+    after = telemetry.counters_snapshot()["serving"]
+    eng.close()
+    assert after["engine_init_secs"] == c["engine_init_secs"]
 
 
 def test_engine_starved_is_one_span_per_episode():
