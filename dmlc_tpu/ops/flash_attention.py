@@ -38,6 +38,7 @@ from ..base import get_env
 
 _NEG_BIG = -1e30
 _POS_BIG = 1e30
+_MASKED = 2 * _NEG_BIG  # a masked score, under m's start of _NEG_BIG
 
 
 def _out_struct(shape, dtype, *operands):
@@ -52,13 +53,22 @@ def _out_struct(shape, dtype, *operands):
 
 # Kernel structure note (performance-critical): the KV/Q walk lives in
 # the GRID, not in an in-kernel fori_loop.  A loop whose trip count
-# depends on program_id lowers to an unpipelined while loop in Mosaic —
-# measured 10-20× slower than the pipelined grid at flagship shapes —
-# and keeping whole sequences resident in VMEM overflows it past
-# T≈4k.  With the step in the grid, accumulators live in the revisited
-# output blocks (init on the first step, finalize implicitly on the
-# last), per-step VMEM is O(block), and causally-skipped blocks cost
-# one predicated no-op visit (pl.when) instead of compute.
+# depends on program_id lowers to an unpipelined while loop in Mosaic
+# (far slower than the pipelined grid when the kernel was written; not
+# timed again on the benchmark's chip), and keeping whole sequences
+# resident in VMEM overflows it past T~4k.  With the step in the grid,
+# accumulators live in the revisited output blocks (init on the first
+# step, finalize implicitly on the last), per-step VMEM is O(block),
+# and causally-skipped blocks cost one predicated no-op visit (pl.when)
+# instead of compute.
+#
+# What a tile costs (scripts/time_flash_fwd.py on the v5e, PR 37;
+# PERF.md section 6): a 1024 x 1024 tile of 128-wide heads 3.65 us
+# unmasked and 3.8 us on a boundary (the matrix unit's least is 2.7), a
+# tile at qk 192 / v 128 4.9 and 5.1, a 512 x 512 tile 1.7; a Q
+# block's walk 0.6 us on top.  The masks, exp2 in place of exp and a
+# bf16 p each moved a tile by under 2%; what halved it was keeping m
+# and l in their stored shape (see ``step``).
 
 
 def _first_kv_block(first_q, kvoff, span: int, block_k: int):
@@ -100,45 +110,51 @@ def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
         s = jax.lax.dot_general(
             q, kb, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale  # [G, bq, bk]
-        keep = None
         if masked:
-            if causal or kv_padded:
-                q_pos = qoff_ref[0] + qi * block_q + lax.broadcasted_iota(
-                    jnp.int32, (g, bq, bk), 1)
-                k_pos = kvoff_ref[0] + j * block_k + lax.broadcasted_iota(
-                    jnp.int32, (g, bq, bk), 2)
+            # a score's q_pos - k_pos is ONE iota difference plus a
+            # scalar, held against scalars: the diagonal, the window's
+            # edge, the padded tail
+            col = lax.broadcasted_iota(jnp.int32, (g, bq, bk), 2)
+            rel = lax.broadcasted_iota(jnp.int32, (g, bq, bk), 1) - col
+            k0 = kvoff_ref[0] + j * block_k
+            ahead = qoff_ref[0] + qi * block_q - k0
+            keep = None
             if causal:
-                keep = q_pos >= k_pos
+                keep = rel >= -ahead
             if span:
-                keep = keep & (q_pos - k_pos < span)
+                keep = keep & (rel < span - ahead)
             if kv_padded:
                 # tail KV rows past the real length are padding
-                in_range = k_pos < kvend_ref[0]
+                in_range = col < kvend_ref[0] - k0
                 keep = in_range if keep is None else keep & in_range
-            if keep is not None:
-                s = jnp.where(keep, s, _NEG_BIG)
-        m_old = m_ref[..., 0]             # [G, bq]
-        l_old = l_ref[..., 0]
-        bm = jnp.max(s, axis=2)
-        m_new = jnp.maximum(m_old, bm)
-        p = jnp.exp(s - m_new[..., None])
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
+            # (a masked step is a diagonal, window-edge or padded
+            # block, so keep is set.)  _MASKED lies under the running
+            # maximum's start, so exp(masked - m_new) is 0 even in a row
+            # that has seen no key yet (the first block of a windowed
+            # walk has such rows) and p needs no second select
+            s = jnp.where(keep, s, _MASKED)
+        # m and l stay [G, bq, 8], the shape they are stored in, and the
+        # row reductions keep their axis: as [G, bq] vectors each took a
+        # relayout between sublanes and lanes and back every step, which
+        # cost more than the rest of the step together (PERF.md section
+        # 6, PR 37: 6.85 -> 3.65 us a 1024 x 1024 tile of 128-wide heads)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new[..., :1])
         corr = jnp.exp(m_old - m_new)
-        l_new = l_old * corr + jnp.sum(p, axis=2)
-        # PV dot in f32: casting the [bq,bk] p down to bf16 is a full
-        # VPU pass over the tile, while casting the [bk,D] v up is
-        # ~bk/D times cheaper — and the MXU has headroom here (the
-        # kernel is VPU-bound).  The lax twin mirrors this so the
-        # ring-step VJP recompute stays consistent.
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=2, keepdims=True)
+        # the value product takes p as it is: float32 operands cost the
+        # matrix unit nothing here that a timing shows, and casting p to
+        # bf16 first made the tile 0.5-2.4% SLOWER (one more pass over
+        # the scores).  The lax twin mirrors float32 p so the ring-step
+        # VJP recompute stays consistent.
         pv = jax.lax.dot_general(
             p, vb.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        pv_ref[...] = pv_ref[...] * corr[..., None] + pv
-        # m/l are per-row scalars stored broadcast over an 8-lane minor
-        # axis (Mosaic lane tiling); callers slice lane 0
-        m_ref[...] = jnp.broadcast_to(m_new[..., None], (g, bq, 8))
-        l_ref[...] = jnp.broadcast_to(l_new[..., None], (g, bq, 8))
+        pv_ref[...] = pv_ref[...] * corr[..., :1] + pv
+        # (m/l are per-row scalars stored broadcast over an 8-lane minor
+        # axis, Mosaic's lane tiling; callers slice lane 0)
+        m_ref[...] = m_new
 
     def walk():
         _dispatch_masked_step(pl, step, qi, j, block_q, block_k, causal,
@@ -313,10 +329,11 @@ def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False,
 
     # The kernel body is written batched over G fused (b,h) pairs per
     # grid step (DMLC_FLASH_BH_BLOCK for sweeps), but G=1 is the
-    # measured default: fusing pairs forces smaller q/kv blocks (the
-    # f32 [G,bq,bk] softmax intermediates hit the 16 MB scoped-VMEM
-    # cap) and every (G>1, smaller-block) point lost to (G=1, 1024²)
-    # on the flagship step — 52.4-53.2% vs 53.7% MFU at T=1024.
+    # default: fusing pairs forces smaller q/kv blocks (the f32
+    # [G,bq,bk] softmax intermediates hit the 16 MB scoped-VMEM cap),
+    # and every (G>1, smaller-block) point lost to (G=1, 1024^2) on the
+    # flagship step when it was swept (not swept again on the
+    # benchmark's chip: ROADMAP A11).
     gmax = get_env("DMLC_FLASH_BH_BLOCK", 0) or 1
     g = 1
     # grouped heads and a window map each (b, h) pair to K/V blocks of
@@ -620,9 +637,9 @@ def _flash_backward(static, q, k, v, o, lse, do):
             _out_struct((bh, tk_p, d), jnp.float32, *operands),
         ],
         # bh and the accumulator's home dim are independent; only the
-        # innermost (accumulating) dim is order-dependent — measured
-        # ~15% faster than leaving the semantics unspecified.  (The fwd
-        # kernel regresses badly with the same hint, so it stays plain.)
+        # innermost (accumulating) dim is order-dependent: faster than
+        # leaving the semantics unspecified when it was tried.  (The fwd
+        # kernel regressed with the same hint, so it stays plain.)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_dkv",
@@ -765,15 +782,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
     backward recomputes P from the saved (o, lse) residuals in blocks
     (dkv + dq kernels) instead of materializing the T×T matrix.
 
-    Default block sizes: uniform 1024×1024 for forward AND backward
-    (clamped to T), the winner of a round-5 sweep on v5e over
-    {256..2048}² × fwd/bwd at both T=1024 and T=8192 on the full
-    flagship train step — 1024² beat the round-4 T-adaptive 512/1024
-    scheme by ~2 MFU points at short T and ~1.7 at long T (fewer grid
-    revisits of the accumulator blocks per walked byte; 2048-wide
-    blocks regress, VMEM pressure evicting the double-buffered
-    pipeline).  DMLC_FLASH_BLOCK_Q/K and DMLC_FLASH_BWD_BLOCK_Q/K
-    override for sweeps (read at trace time).
+    Default block sizes: uniform 1024x1024 for forward AND backward
+    (clamped to T); DMLC_FLASH_BLOCK_Q/K and DMLC_FLASH_BWD_BLOCK_Q/K
+    override for sweeps (read at trace time).  They were chosen by a
+    sweep on another v5e before the benchmark existed and have not
+    been swept on its chip (ROADMAP A11).  What that chip read of the
+    forward kernel at these blocks (scripts/time_flash_fwd.py, PR 37):
+    Command A+'s 8,192-row chunk against 32,768 keys 110 ms (70% of
+    its roofline; 21 ms and 52% under span=4096), A.X-K1's T=16,384
+    54 ms (52%: qk 192 fills the matrix unit as 256 would), the
+    flagship's T=2,048 0.19 ms (46%), a ring step of 4,096 x 4,096 at
+    blocks of 512 1.1-1.8 ms (33-39%).
     """
 
     from .. import telemetry

@@ -281,8 +281,13 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     (32, 96, 4, 2, 0, 64, 16, 16),
     (40, 90, 2, 2, 17, 50, 16, 16),      # ragged, padded K/V
     (48, 48, 2, 1, 200, None, 16, 32),   # a window wider than the context
+    # rows 70-79 see no key in their walk's FIRST block (keys 32-47),
+    # whose running maximum must survive a block of masked scores
+    (32, 96, 16, 1, 20, 64, 16, 16),
+    (40, 90, 16, 1, 21, 50, 16, 16),     # a padded tail AND a window, group 16
+    (32, 96, 16, 1, 20, 64, 16, 8),      # the window's edge inside a K/V block
 ], ids=["gqa", "gqa_w", "w_ragged", "offset_w", "offset", "padded",
-        "wide_w"])
+        "wide_w", "first_step_unseen", "padded_w_g16", "edge_in_block"])
 def test_flash_fwd_grouped_and_windowed_against_its_lax_twin(
         tq, tk, h, h_kv, span, off, bq, bk):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)

@@ -162,7 +162,8 @@ def test_absorbed_decode_against_the_naive_up_projected_one():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("shape", [(200, 2, 192, 128), (64, 3, 24, 16)])
+@pytest.mark.parametrize("shape", [(200, 2, 192, 128), (64, 3, 24, 16),
+                                   (96, 2, 192, 128)])
 def test_flash_forward_with_its_own_v_size_against_the_lax_twin(shape):
     t, h, d, dv = shape
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
