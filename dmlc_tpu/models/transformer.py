@@ -143,6 +143,32 @@ class TransformerConfig:
     # accumulated it, the norms and the router read it unrounded, and
     # the logits come out in it; the matmuls' operands stay ``dtype``)
     residual_dtype: str = ""
+    # ---- a fifth block family, served only: ``attention="nemotron_h"``
+    # (Nemotron-H's block).  Every layer is ONE thing under one norm,
+    # named by its letter of ``layer_pattern`` (the published
+    # ``hybrid_override_pattern``, cut): "M" a Mamba-2 mixer
+    # (ops/mamba2.py) whose state lives in a slot of the cache manager,
+    # "*" grouped-query attention without positions whose K/V live in
+    # the paged pool, "E" the held-expert layer.  Mamba-2: mamba_n_heads
+    # heads of mamba_head_dim channels, B and C of mamba_state values
+    # for each of mamba_n_groups groups of heads, a causal depthwise
+    # convolution of mamba_conv_size taps (with bias) over x, B and C.
+    layer_pattern: str = ""
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_state: int = 0
+    mamba_conv_size: int = 4
+    mamba_chunk: int = 128       # tokens a chunk of the prefill scan
+    # the held experts in a latent: the token goes down to ``moe_latent``
+    # once, gather, products, weighing and scatter-add run there, the
+    # sum goes up once (0: the experts work at d_model).  ``moe_act``
+    # "relu2": an expert is W2 relu(W1 u)^2, two matrices and no gate,
+    # and so is the shared expert, ``moe_shared_d_ff`` wide (0:
+    # moe_n_shared x moe_d_ff)
+    moe_latent: int = 0
+    moe_act: str = "swiglu"
+    moe_shared_d_ff: int = 0
 
     @property
     def jdtype(self):
@@ -179,6 +205,15 @@ class TransformerConfig:
         return self.attention == "kda_mla"
 
     @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C side by side."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_state
+
+    @property
     def latent(self) -> bool:
         """Whether the paged pool holds latent rows (and the family is
         served only): all layers of "mla", the MLA layers of "kda_mla"."""
@@ -186,7 +221,11 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self) -> tuple:
-        """Each layer's attention, in order."""
+        """Each layer's attention, in order; under ``layer_pattern``
+        what each layer IS ("mamba", "full" attention or "moe")."""
+        if self.layer_pattern:
+            return tuple({"M": "mamba", "*": "full", "E": "moe"}[c]
+                         for c in self.layer_pattern[:self.n_layers])
         if self.hybrid:
             every, rest = "mla", "kda"
         elif self.family == "mha_swa":
@@ -211,8 +250,9 @@ class TransformerConfig:
             row = self.kv_lora_rank + self.qk_rope_head_dim
             return ((self.layer_kinds.count("mla"), n_blocks, row,
                      block_size),)
-        # a sliding layer's K/V are in sliding_pool_shapes' pools
-        n_layers = self.n_layers - self.layer_kinds.count("sliding")
+        # a sliding layer's K/V are in sliding_pool_shapes' pools, and
+        # under a layer pattern the attention layers alone have K/V
+        n_layers = sum(kind in ("mha", "full") for kind in self.layer_kinds)
         return ((n_layers, n_blocks, block_size, self.kv_heads,
                  self.head_dim),) * 2
 
@@ -241,7 +281,17 @@ class TransformerConfig:
         they divide it: a slot is then whole tiles and the decode
         program's write of the live rows' slots is in place (with 3
         rows a slot the chip's compiler re-tiled the whole array into
-        and out of every step)."""
+        and out of every step).  Mamba-2: the float32 state ``[H, P,
+        N]`` per layer and slot, and the convolution's last
+        ``mamba_conv_size - 1`` inputs (x, B, C side by side, as
+        projected) in whole 128-lane rows for the same reason."""
+        n_mamba = self.layer_kinds.count("mamba")
+        if n_mamba:
+            tail = (self.mamba_conv_size - 1) * self.mamba_conv_dim
+            lanes = 128 if tail % 128 == 0 else tail
+            return (((n_mamba, n_slots, self.mamba_n_heads,
+                      self.mamba_head_dim, self.mamba_state), "float32"),
+                    ((n_mamba, n_slots, tail // lanes, lanes), self.dtype))
         n_kda = self.layer_kinds.count("kda")
         if not n_kda:
             return ()
@@ -334,6 +384,33 @@ def _gqa_forward_flops(cfg: TransformerConfig, t: int,
     return cfg.n_layers * (proj + ffn) + attn + 2 * e * cfg.vocab
 
 
+def _pattern_forward_flops(cfg: TransformerConfig, t: int,
+                           causal: bool) -> float:
+    """Forward FLOPs one token needs under a layer pattern at context
+    ``t``: a Mamba-2 layer's two projections, its convolution and about
+    5 operations a state element whatever the context; an attention
+    layer's projections at its heads and K/V heads and its scores and
+    values over the keys; an expert layer's router, the two latent
+    projections, the held share of the picked two-matrix experts in the
+    latent and the shared expert; the unembed."""
+    e, d = cfg.d_model, cfg.head_dim
+    inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    mamba = (2 * e * (inner + conv + cfg.mamba_n_heads) + 2 * inner * e
+             + 2 * cfg.mamba_conv_size * conv + 5 * inner * cfg.mamba_state)
+    attn = (2 * e * d * (2 * cfg.n_heads + 2 * cfg.kv_heads)
+            + (2 if causal else 4) * t * cfg.n_heads * d)
+    routed = cfg.moe_n_routed or cfg.n_experts
+    width = cfg.moe_latent or e
+    mats = 2 if cfg.moe_act == "relu2" else 3
+    shared = cfg.moe_shared_d_ff or cfg.moe_n_shared * cfg.moe_d_ff
+    moe = 2 * (e * routed + (2 * e * width if cfg.moe_latent else 0)
+               + mats * width * cfg.moe_d_ff * cfg.moe_topk
+               * cfg.n_experts / routed + mats * e * shared)
+    kinds = cfg.layer_kinds
+    return (kinds.count("mamba") * mamba + kinds.count("full") * attn
+            + kinds.count("moe") * moe + 2 * e * cfg.vocab)
+
+
 def train_flops_per_token(cfg: TransformerConfig, t: int,
                           causal: bool = True) -> float:
     """Executed matmul FLOPs per token for one train step (fwd + bwd ≈ 3×
@@ -343,6 +420,8 @@ def train_flops_per_token(cfg: TransformerConfig, t: int,
     MFU (conservative: the partially-masked diagonal blocks run full)."""
     if cfg.latent:
         return 3.0 * _latent_forward_flops(cfg, t, causal)
+    if cfg.layer_pattern:
+        return 3.0 * _pattern_forward_flops(cfg, t, causal)
     if cfg.served_only:
         return 3.0 * _gqa_forward_flops(cfg, t, causal)
     e, hd, f, x = (cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff,
@@ -368,6 +447,8 @@ def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
     this tree, one array a layer and matrix."""
     if cfg.hybrid:
         return _init_hybrid_params(key, cfg, n_stages)
+    if cfg.layer_pattern:
+        return _init_pattern_params(key, cfg, n_stages)
     if cfg.latent:
         return _init_latent_params(key, cfg, n_stages)
     if cfg.moe_router == "sigmoid":
@@ -578,6 +659,81 @@ def _init_hybrid_params(key, cfg: TransformerConfig, n_stages: int = 1):
             "w_gate": norm((cfg.n_dense_layers, e, cfg.d_ff)),
             "w_out": norm((cfg.n_dense_layers, cfg.d_ff, e))},
         "blocks": blocks,
+    }
+
+
+def _init_pattern_params(key, cfg: TransformerConfig, n_stages: int = 1):
+    """The tree of a model under a layer pattern (``attention=
+    "nemotron_h"``), served only: ``layers`` is a LIST of one dict a
+    layer, each with its one norm ``ln`` and what its letter says, an
+    array a layer and matrix (a decode step would copy its layer's
+    slice out of a stack, 152 MB for a Mamba-2 input projection).
+
+    "M": ``in_proj [E, d_inner + conv_dim + H]`` (z, xBC and dt side by
+    side), the convolution ``conv [width, conv_dim]`` with its bias
+    ``conv_b``, and in float32 ``dt_bias [H]`` (seeded so that
+    softplus(dt_bias) is log-uniform in [0.001, 0.1]), ``a_log [H]`` =
+    log(uniform[1, 16]) and the skip ``d [H]`` = 1; the gated norm's
+    weight ``norm [d_inner]`` and ``out_proj [d_inner, E]``.
+    "*": ``wq [E, H, d]``, ``wk`` / ``wv [E, H_kv, d]``, ``wo [H, d, E]``.
+    "E": the router ``gate [E, moe_n_routed]`` with its float32
+    correction bias ``gate_bias``, the latent projections ``w_down [E,
+    latent]`` / ``w_up [latent, E]`` and the shared expert ``s_in [E,
+    F_s]`` / ``s_out [F_s, E]``.  The held routed experts of ALL expert
+    layers stay one stack ``experts`` (``w_in [n_E x X, latent, F]``,
+    ``w_out [n_E x X, F, latent]``, the j-th expert layer's at [j X,
+    (j + 1) X)), which is how the grouped product takes them
+    (:func:`_moe_held_ffn`)."""
+    assert n_stages == 1 and cfg.moe_router == "sigmoid" \
+        and cfg.moe_act == "relu2" and len(cfg.layer_pattern) >= cfg.n_layers
+    e, h, h_kv, d = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    x, fm = cfg.n_experts, cfg.moe_d_ff
+    fs = cfg.moe_shared_d_ff or cfg.moe_n_shared * fm
+    width = cfg.moe_latent or e
+    routed = cfg.moe_n_routed or x
+    inner, conv, mh = cfg.mamba_d_inner, cfg.mamba_conv_dim, cfg.mamba_n_heads
+    kinds = cfg.layer_kinds
+    keys = iter(jax.random.split(key, 8 * cfg.n_layers + 8))
+
+    def norm(*shape, dtype=cfg.jdtype):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * 0.02).astype(dtype)
+
+    def uniform(lo, hi):
+        return jax.random.uniform(next(keys), (mh,), jnp.float32, lo, hi)
+
+    def ones(n):
+        return jnp.ones((n,), cfg.jdtype)
+
+    def layer(kind):
+        if kind == "mamba":
+            dt = jnp.exp(uniform(jnp.log(0.001), jnp.log(0.1)))
+            return {"ln": ones(e), "in_proj": norm(e, inner + conv + mh),
+                    "conv": norm(cfg.mamba_conv_size, conv),
+                    "conv_b": norm(conv),
+                    # softplus^-1(dt)
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                    "a_log": jnp.log(uniform(1.0, 16.0)),
+                    "d": jnp.ones((mh,), jnp.float32),
+                    "norm": ones(inner), "out_proj": norm(inner, e)}
+        if kind == "full":
+            return {"ln": ones(e), "wq": norm(e, h, d),
+                    "wk": norm(e, h_kv, d), "wv": norm(e, h_kv, d),
+                    "wo": norm(h, d, e)}
+        p = {"ln": ones(e), "gate": norm(e, routed), "s_in": norm(e, fs),
+             "s_out": norm(fs, e)}
+        if cfg.moe_router_bias:
+            p["gate_bias"] = norm(routed, dtype=jnp.float32)
+        if cfg.moe_latent:
+            p.update(w_down=norm(e, width), w_up=norm(width, e))
+        return p
+
+    n_moe = kinds.count("moe")
+    return {
+        "embed": norm(cfg.vocab, e), "unembed": norm(e, cfg.vocab),
+        "ln_f": ones(e), "layers": [layer(kind) for kind in kinds],
+        "experts": {"w_in": norm(n_moe * x, width, fm),
+                    "w_out": norm(n_moe * x, fm, width)},
     }
 
 
@@ -1524,6 +1680,18 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     (336 MB a matrix at A.X-K1's widths); the other layers' groups are
     empty and cost nothing.
 
+    With ``moe_latent`` the experts work in a latent (``w_in`` [G, latent,
+    F], ``w_out`` [G, F, latent]): the token goes down ``p["w_down"]``
+    ONCE, the gather, the grouped products, the weighing and the
+    scatter-add run at the latent width, and the sum of the held picks
+    goes up ``p["w_up"]`` ONCE (the map is linear, so the shares of a
+    deployment's chips add up, exchanged at the latent width); the
+    router still reads ``x``.  ``moe_act`` "relu2": an expert is ``W_out
+    relu(W_in u)^2``, no gate matrix, and so is the shared expert.  A
+    correction bias ``p["gate_bias"]`` without groups steers the plain
+    top-k: the picks are the largest ``s + b``, their weights come from
+    ``s``.
+
     Returns ``(y [B, T, E], counts [n_experts + 1] int32)``: pairs per
     held expert, then all pairs routed anywhere, both over the tokens
     ``valid`` [B, T] marks (default all)."""
@@ -1541,8 +1709,16 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     xf = x.reshape(n, e).astype(cfg.jdtype)
     if cfg.moe_n_group:
         top_s, top_i = _group_limited_top_k(scores, p.get("gate_bias"), cfg)
+    elif p.get("gate_bias") is not None:
+        top_i = lax.top_k(scores + p["gate_bias"].astype(jnp.float32), k)[1]
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     else:
         top_s, top_i = lax.top_k(scores, k)                      # [n, k]
+    relu2 = cfg.moe_act == "relu2"
+    xe = xf  # what the experts read: the token, or its latent
+    if cfg.moe_latent:
+        with jax.named_scope("latent_down"):
+            xe = jnp.einsum("ne,el->nl", xf, p["w_down"])
     weight = cfg.moe_routed_scale * top_s / (
         jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
 
@@ -1574,9 +1750,10 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
         groups = jnp.zeros(n_groups, jnp.int32).at[
             first_group:first_group + x_l].set(
                 hi - jnp.concatenate([jnp.full((1,), lo, hi.dtype), hi[:-1]]))
-        xs = jnp.take(xf, rows, axis=0)
-        hidden = lax.ragged_dot(xs, p["w_in"], groups) * jax.nn.silu(
-            lax.ragged_dot(xs, p["w_gate"], groups))
+        xs = jnp.take(xe, rows, axis=0)
+        hidden = lax.ragged_dot(xs, p["w_in"], groups)
+        hidden = jnp.square(jax.nn.relu(hidden)) if relu2 \
+            else hidden * jax.nn.silu(lax.ragged_dot(xs, p["w_gate"], groups))
         # under a float32 stream the pairs' results are weighed and
         # summed as the product accumulated them
         out = lax.ragged_dot(hidden, p["w_out"], groups,
@@ -1589,9 +1766,19 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
         return y.at[rows].add(jnp.where(held, out.astype(jnp.float32) * w, 0.0))
 
     y = lax.fori_loop(0, -(-n_held // tile), one_tile,
-                      jnp.zeros((n, e), jnp.float32))
-    y = y.astype(x.dtype).reshape(b, t, e)
-    if cfg.moe_n_shared:
+                      jnp.zeros((n, xe.shape[-1]), jnp.float32))
+    y = y.astype(x.dtype)
+    if cfg.moe_latent:
+        with jax.named_scope("latent_up"):
+            y = jnp.einsum("nl,le->ne", y.astype(cfg.jdtype), p["w_up"],
+                           **_into_stream(cfg))
+    y = y.reshape(b, t, e)
+    if cfg.moe_n_shared and relu2:
+        hidden = jnp.square(jax.nn.relu(jnp.einsum(
+            "bte,ef->btf", xf.reshape(b, t, e), p["s_in"])))
+        y = y + jnp.einsum("btf,fe->bte", hidden, p["s_out"],
+                           **_into_stream(cfg))
+    elif cfg.moe_n_shared:
         # the shared experts lie side by side in one SwiGLU, which sums
         # them; their average is that over their number
         shared = swiglu_ffn(xf.reshape(b, t, e), p["s_in"], p["s_gate"],
@@ -1942,6 +2129,304 @@ def forward_decode_paged_hybrid(params, ids, positions, pool, state, tails,
         x = rms_norm(x, params["ln_f"])
         logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
     return logits, pool, state, tails, jnp.stack(counts)
+
+
+# ---------------------------------------------------------------------------
+# a model under a layer pattern on the serving path (Nemotron-H's
+# block): every layer ONE thing under one norm.  "M" a Mamba-2 mixer
+# (ops/mamba2.py), its per-sequence state and convolution tail in a
+# slot of the cache manager; "*" grouped-query attention without
+# positions, its K/V in the paged pool; "E" the held experts in their
+# latent.  Paged path only, one token a decode step.
+# ---------------------------------------------------------------------------
+
+
+def _pattern_layers(params, cfg: TransformerConfig):
+    """Yields ``(kind, i, layer params, first_group)`` in layer order:
+    layer ``i`` of its kind (its index into the state slots or the
+    pool); an expert layer's dict carries the one stack of held experts
+    and its own start ``first_group`` in it (else None)."""
+    seen = dict.fromkeys(("mamba", "full", "moe"), 0)
+    for kind, p in zip(cfg.layer_kinds, params["layers"]):
+        i = seen[kind]
+        seen[kind] += 1
+        if kind == "moe":
+            yield kind, i, {**p, **params["experts"]}, i * cfg.n_experts
+        else:
+            yield kind, i, p, None
+
+
+def _mamba_split(zxbcdt, p, cfg: TransformerConfig):
+    """The input projection's columns ``[..., d_inner + conv_dim + H]``:
+    ``(z, xBC as projected, dt = softplus(dt + dt_bias) in float32)``."""
+    inner, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    dt = jax.nn.softplus(
+        zxbcdt[..., inner + conv:].astype(jnp.float32) + p["dt_bias"])
+    return zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv], dt
+
+
+def _mamba_xbc(conv_out, cfg: TransformerConfig):
+    """The convolution's output [..., conv_dim] (float32, bias added)
+    through SiLU and split: ``(x [..., H, P], B [..., G, N], C [..., G,
+    N])``."""
+    inner, g, n = cfg.mamba_d_inner, cfg.mamba_n_groups, cfg.mamba_state
+    y = jax.nn.silu(conv_out)
+    lead = y.shape[:-1]
+    return (y[..., :inner].reshape(lead + (cfg.mamba_n_heads,
+                                           cfg.mamba_head_dim)),
+            y[..., inner:inner + g * n].reshape(lead + (g, n)),
+            y[..., inner + g * n:].reshape(lead + (g, n)))
+
+
+def _mamba_output(y, z, p, cfg: TransformerConfig):
+    """y [B, T, H, P] float32 (skip term included) and the gate ``z``
+    [B, T, d_inner]: the gate BEFORE the norm, RMSNorm over each
+    group's channels, the norm's weight, the output projection."""
+    with jax.named_scope("gate_norm"):
+        y = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+        grouped = y.reshape(y.shape[:-1] + (cfg.mamba_n_groups, -1))
+        var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+        y = (grouped * lax.rsqrt(var + cfg.norm_eps)).reshape(y.shape) \
+            * p["norm"].astype(jnp.float32)
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("bti,ie->bte", y.astype(cfg.jdtype), p["out_proj"],
+                          **_into_stream(cfg))
+
+
+#: rows of a prompt one pass of a Mamba-2 prefill layer takes where the
+#: prompt is longer: the projection, the convolution's float32 sum, x,
+#: B, C and the scan's output of 8k rows are 2.2 GB beside 12.3 GB of
+#: weights, state and pool; the state and the convolution's last inputs
+#: are carried from one pass to the next
+MAMBA_PREFILL_ROWS = 2048
+
+
+def _mamba_rows(xa, valid, carry, p, cfg: TransformerConfig):
+    """Rows ``xa`` [1, R, E] (normed, the products' operand) of ONE
+    sequence through a Mamba-2 mixer, after ``carry``: the state ``[H,
+    P, N]`` and the convolution's inputs ``[W - 1, conv_dim]`` that the
+    rows before them left.  Returns ``(the mixer's addend [1, R, E],
+    carry)``."""
+    from ..ops import mamba2 as _mamba2
+
+    state, before = carry
+    r = xa.shape[1]
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = _mamba_split(
+            jnp.einsum("bte,ef->btf", xa, p["in_proj"]), p, cfg)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate([before[None].astype(xbc.dtype), xbc], 1)
+        conv = p["conv"].astype(jnp.float32)
+        y = sum(conv[j] * window[:, j:j + r]
+                for j in range(cfg.mamba_conv_size)) \
+            + p["conv_b"].astype(jnp.float32)
+    x, b, c = _mamba_xbc(y, cfg)
+    with jax.named_scope("chunk_scan"):
+        o, state = _mamba2.ssd_chunk_scan(
+            x[0], jnp.where(valid[0, :, None], dt[0], 0.0),
+            jnp.exp(p["a_log"]), b[0], c[0], chunk=cfg.mamba_chunk,
+            state=state)
+        o = o[None] + p["d"][:, None] * x
+    return _mamba_output(o, z, p, cfg), (state, window[0, r:])
+
+
+def _mamba_prefill(xn, p, valid, last_index, cfg: TransformerConfig):
+    """A Mamba-2 mixer over ONE whole sequence ``xn`` [1, T, E] from a
+    zero state, :data:`MAMBA_PREFILL_ROWS` rows a pass where the prompt
+    is longer (a multiple of it).  ``valid`` [1, T] marks the prompt's
+    real tokens: a padded position neither decays nor writes the state
+    (dt = 0), and the convolution's tail is taken at ``last_index``
+    [1].  Returns ``(the mixer's addend [1, T, E], state [H, P, N], tail
+    [W - 1, conv_dim])``."""
+    w = cfg.mamba_conv_size
+    _, t, e = xn.shape
+    xa = xn.astype(cfg.jdtype)
+    carry = (jnp.zeros((cfg.mamba_n_heads, cfg.mamba_head_dim,
+                        cfg.mamba_state), jnp.float32),
+             jnp.zeros((w - 1, cfg.mamba_conv_dim), cfg.jdtype))
+    rows = MAMBA_PREFILL_ROWS
+    if t > rows and t % rows == 0:
+        def one(carry, xs):
+            y, carry = _mamba_rows(xs[0][None], xs[1][None], carry, p, cfg)
+            return carry, y[0]
+
+        (state, _), y = lax.scan(one, carry, (
+            xa[0].reshape(t // rows, rows, e),
+            valid[0].reshape(t // rows, rows)))
+        y = y.reshape(1, t, e)
+    else:
+        y, (state, _) = _mamba_rows(xa, valid, carry, p, cfg)
+    with jax.named_scope("conv"):
+        # the tail is projected again from its own w - 1 tokens, as
+        # KDA's is: cut out of the projection, the slice would keep
+        # every layer's alive until the slot's write
+        at = last_index[0] - (w - 2) + jnp.arange(w - 1)
+        tail = jnp.where(
+            (at >= 0)[:, None],
+            _mamba_split(jnp.einsum(
+                "te,ef->tf", jnp.take(xa[0], jnp.maximum(at, 0), 0),
+                p["in_proj"]), p, cfg)[1], 0)
+    return y, state, tail
+
+
+def _mamba_decode(xn, p, state, tails, li: int, slots, live,
+                  cfg: TransformerConfig):
+    """One Mamba-2 layer of one decode token a row: the convolution
+    over the slot's tail and the new input, then
+    ``ops.mamba2.ssm_state_step`` on the row's state in place.  A dead
+    row reads slot 0's tail, which is harmless, and writes nothing.
+    Returns ``(the mixer's addend [B, 1, E], state, tails)``."""
+    from ..ops import mamba2 as _mamba2
+
+    n_slots = state.shape[1]
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = _mamba_split(jnp.einsum(
+            "bte,ef->btf", xn.astype(cfg.jdtype), p["in_proj"]), p, cfg)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate(
+            [tails[li, slots].reshape(xbc.shape[0], -1, xbc.shape[-1]), xbc],
+            axis=1)
+        y = jnp.sum(window * p["conv"].astype(jnp.float32), axis=1) \
+            + p["conv_b"].astype(jnp.float32)
+        tails = tails.at[li, jnp.where(live, slots, n_slots)].set(
+            window[:, 1:].reshape((-1,) + tails.shape[2:]), mode="drop")
+    x, b, c = _mamba_xbc(y, cfg)                       # [B, H, P], [B, G, N]
+    dt = dt[:, 0]                                      # [B, H]
+    with jax.named_scope("state_step"):
+        # every layer's slots as one run: a per-layer slice of the
+        # state would be copied for the kernel, 537 MB a layer
+        o, flat = _mamba2.ssm_state_step(
+            x * dt[..., None], jnp.exp(-dt * jnp.exp(p["a_log"])), b, c,
+            state.reshape((-1,) + state.shape[2:]), slots + li * n_slots,
+            live)
+        o = o + p["d"][:, None] * x
+    return (_mamba_output(o[:, None], z, p, cfg), flat.reshape(state.shape),
+            tails)
+
+
+def _pattern_qkv(xn, p, cfg: TransformerConfig):
+    """An attention layer's q ``[B, T, H, hd]``, k and v ``[B, T, H_kv,
+    hd]`` of normed activations: no bias, no positions."""
+    xa = xn.astype(cfg.jdtype)
+    return tuple(jnp.einsum("bte,ehd->bthd", xa, p[name])
+                 for name in ("wq", "wk", "wv"))
+
+
+def _stacked(counts, cfg: TransformerConfig):
+    """The expert layers' routing counts ``[n, n_experts + 1]``, none
+    under a pattern cut before its first expert layer."""
+    return jnp.stack(counts) if counts else jnp.zeros(
+        (0, cfg.n_experts + 1), jnp.int32)
+
+
+def _write_pages(pool, layer: int, block_ids, a):
+    """K or V of ONE sequence's whole blocks ``a [1, n x bs, H_kv, hd]``
+    into pages ``block_ids`` of one layer, each page written as ONE row
+    of ``bs x H_kv x hd`` values (a free view of the pool): with 2 K/V
+    heads a page ``[bs, 2, hd]`` is tiled (2, 128), and for a scatter of
+    whole ``[bs, H_kv, hd]`` windows the chip's compiler laid the pool
+    out anew, into and out of every prefill (0.6 GB of copies)."""
+    n_layers, n_blocks = pool.shape[:2]
+    flat = pool.reshape(n_layers, n_blocks, -1)
+    flat = flat.at[layer, block_ids].set(
+        a.reshape(block_ids.shape[0], -1).astype(pool.dtype))
+    return flat.reshape(pool.shape)
+
+
+def forward_prefill_paged_pattern(params, ids, last_index, k_pool, v_pool,
+                                  state, tails, block_ids, slot,
+                                  cfg: TransformerConfig):
+    """:func:`forward_prefill_paged` of a model under a layer pattern:
+    prefill of ONE sequence that leaves its attention layers' K/V in the
+    paged pools (``k_pool`` / ``v_pool [n attention layers, n_blocks,
+    block_size, H_kv, hd]``, blocks ``block_ids``) and each Mamba-2
+    layer's final state and convolution tail in the sequence's ``slot``
+    [1] of ``state`` / ``tails`` (``TransformerConfig.
+    state_slot_shapes``), all four donated and updated in place.  The
+    scan runs chunk-wise (``ops.mamba2.ssd_chunk_scan``); padding does
+    not touch the state.  Returns ``(logits [1, V], k_pool, v_pool,
+    state, tails, moe)``."""
+    _, t = ids.shape
+    valid = jnp.arange(t)[None] <= last_index[:, None]
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
+        cfg.stream_dtype)
+    counts = []
+    for kind, i, p, first_group in _pattern_layers(params, cfg):
+        xn = _norm(x, p["ln"], cfg)
+        if kind == "mamba":
+            with jax.named_scope("mamba"):
+                y, s_t, tail = _mamba_prefill(xn, p, valid, last_index, cfg)
+                state = state.at[i, slot[0]].set(s_t)
+                tails = tails.at[i, slot[0]].set(
+                    tail.reshape(tails.shape[2:]).astype(tails.dtype))
+        elif kind == "full":
+            with jax.named_scope("attn_full"):
+                q, k, v = _pattern_qkv(xn, p, cfg)
+                with jax.named_scope(f"prefill_attn_t{t}_w0_c1"):
+                    o = _causal_attention(q, k, v)
+                y = jnp.einsum("bthd,hde->bte", o, p["wo"],
+                               **_into_stream(cfg))
+                k_pool = _write_pages(k_pool, i, block_ids, k)
+                v_pool = _write_pages(v_pool, i, block_ids, v)
+        else:
+            y = _mha_ffn(xn, p, first_group, cfg, valid, counts)
+        x = x + y
+    x = _norm(x, params["ln_f"], cfg)
+    return (_logits_at(params, x, last_index, cfg), k_pool, v_pool, state,
+            tails, _stacked(counts, cfg))
+
+
+def forward_decode_paged_pattern(params, ids, positions, k_pool, v_pool,
+                                 state, tails, block_tables, lengths, slots,
+                                 cfg: TransformerConfig):
+    """:func:`forward_decode_paged` of a model under a layer pattern,
+    one token a row (``ids`` [B, 1]: recurrent state has no rollback, so
+    there is no verify window).  ``slots`` [B] is each row's state slot
+    beside its block table; a dead row (length 0) writes neither pools
+    nor state.  ``positions`` is taken as every decode program takes it
+    and not read: no layer rotates.  Returns ``(logits [B, 1, V],
+    k_pool, v_pool, state, tails, moe)``."""
+    from ..ops import paged_attention as _paged_attn
+
+    b, s_w = ids.shape
+    assert s_w == 1, "recurrent layers decode one token a step"
+    live = lengths > 0
+    n_blocks, bs = k_pool.shape[1], k_pool.shape[2]
+    wb = jnp.take_along_axis(
+        block_tables, jnp.clip(lengths[:, None] // bs, 0,
+                               block_tables.shape[1] - 1), axis=1)
+    wb = jnp.where(live[:, None], wb, n_blocks)                  # OOB-drop
+    ws = lengths[:, None] % bs
+    valid = live[:, None]
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
+        cfg.stream_dtype)
+    counts = []
+    for kind, i, p, first_group in _pattern_layers(params, cfg):
+        xn = _norm(x, p["ln"], cfg)
+        if kind == "mamba":
+            with jax.named_scope("mamba"):
+                y, state, tails = _mamba_decode(xn, p, state, tails, i,
+                                                slots, live, cfg)
+        elif kind == "full":
+            with jax.named_scope("attn_full"):
+                q, k, v = _pattern_qkv(xn, p, cfg)
+                k_pool = k_pool.at[i, wb, ws].set(
+                    k.astype(k_pool.dtype), mode="drop")
+                v_pool = v_pool.at[i, wb, ws].set(
+                    v.astype(v_pool.dtype), mode="drop")
+                # the layers' pools as one run of pages, a free view
+                o = _paged_attn.paged_attention(
+                    q, k_pool.reshape((-1,) + k_pool.shape[2:]),
+                    v_pool.reshape((-1,) + v_pool.shape[2:]),
+                    block_tables + i * n_blocks, lengths)
+                y = jnp.einsum("bthd,hde->bte", o, p["wo"],
+                               **_into_stream(cfg))
+        else:
+            y = _mha_ffn(xn, p, first_group, cfg, valid, counts)
+        x = x + y
+    with jax.named_scope("unembed"):
+        logits = _unembed(params, _norm(x, params["ln_f"], cfg), cfg)
+    return logits, k_pool, v_pool, state, tails, _stacked(counts, cfg)
 
 
 def make_train_step(mesh, cfg: TransformerConfig, optimizer=None,

@@ -1,8 +1,9 @@
 """Kernel-or-reference dispatch: the one place that decides.
 
 Every Pallas kernel in the repo (flash attention forward/backward, the
-ring step, paged decode attention) has a pure-lax twin that is its
-parity reference.  Which of the two a traced program gets is decided
+ring step, paged decode attention over K/V or latent rows, the decode
+state steps ``kda_state_step`` and ``ssm_state_step``) has a pure-lax
+twin that is its parity reference.  Which of the two a traced program gets is decided
 HERE and nowhere else: compiled by Mosaic on a TPU backend, the lax
 reference on every other backend, and — only when a test says so —
 the kernel under the Pallas interpreter.  The lax paths exist so the

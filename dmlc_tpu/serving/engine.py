@@ -194,8 +194,11 @@ _MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held",
 
 
 #: what a model with recurrent layers adds: slots handed out (the cache
-#: counts them) and the state bytes the decode steps read and wrote
-_STATE_COUNTERS = ("state_slot_allocs", "kda_state_rw_bytes")
+#: counts them), the live slots summed over decode steps, and the state
+#: bytes those steps read and wrote, under the name of the state's kind
+_STATE_COUNTERS = ("state_slot_allocs", "state_slot_steps")
+_STATE_BYTES_COUNTER = {"kda_mla": "kda_state_rw_bytes",
+                        "nemotron_h": "ssm_state_rw_bytes"}
 
 #: what a model with sliding-window layers adds.  The cache counts the
 #: ring blocks that were taken over; the rest grow when a decode span
@@ -234,7 +237,10 @@ def _jitted_programs(family: str = "mha"):
     ``forward_decode_paged_mla``, both donated their one pool; a model
     with recurrent layers ("kda_mla") ``forward_prefill_paged_hybrid``
     / ``forward_decode_paged_hybrid``, donated the pool and the two
-    state arrays that travel with it; an MHA model with sliding-window
+    state arrays that travel with it; a model under a layer pattern
+    ("nemotron_h") ``forward_prefill_paged_pattern`` /
+    ``forward_decode_paged_pattern``, donated the K and V pools and the
+    two state arrays; an MHA model with sliding-window
     layers ("mha_swa") ``forward_prefill_paged_swa`` /
     ``forward_decode_paged_swa``, donated the full layers' and the
     sliding layers' K and V pools.  All go
@@ -254,6 +260,13 @@ def _jitted_programs(family: str = "mha"):
         decode_key = (mode, "decode_paged_hybrid")
         decode_fn, decode_kw = tfm.forward_decode_paged_hybrid, {
             "static_argnums": (9,), "donate_argnums": (3, 4, 5)}
+    elif family == "nemotron_h":
+        prefill_key = (mode, "prefill_paged_pattern")
+        prefill_fn, prefill_kw = tfm.forward_prefill_paged_pattern, {
+            "static_argnums": (9,), "donate_argnums": (3, 4, 5, 6)}
+        decode_key = (mode, "decode_paged_pattern")
+        decode_fn, decode_kw = tfm.forward_decode_paged_pattern, {
+            "static_argnums": (10,), "donate_argnums": (3, 4, 5, 6)}
     elif family == "mha_swa":
         prefill_key = (mode, "prefill_paged_swa")
         prefill_fn, prefill_kw = tfm.forward_prefill_paged_swa, {
@@ -447,6 +460,7 @@ class InferenceEngine:
         # read once and written once in every layer that has one (the
         # first of the state arrays; the convolution's tail is small)
         self._state_rw_bytes = 0
+        self._state_bytes_counter = _STATE_BYTES_COUNTER.get(cfg.family)
         if self.cache.n_slots:
             shape, dt = self.cache.state_shapes[0]
             self._state_rw_bytes = (
@@ -623,7 +637,8 @@ class InferenceEngine:
         self._stop.clear()
         for name in _ZEROED_COUNTERS + (
                 _MOE_COUNTERS if self.cfg.moe_router == "sigmoid" else ()
-                ) + (_STATE_COUNTERS if self.cache.n_slots else ()
+                ) + (_STATE_COUNTERS + (self._state_bytes_counter,)
+                     if self.cache.n_slots else ()
                      ) + (_SLIDING_COUNTERS if self.cache.ring_blocks
                           else ()):
             telemetry.inc("serving", name, 0)
@@ -1394,7 +1409,8 @@ class InferenceEngine:
         telemetry.observe("serving", "decode_batch", b)
         telemetry.inc("serving", "paged_decode_steps")
         if self._state_rw_bytes:
-            telemetry.inc("serving", "kda_state_rw_bytes",
+            telemetry.inc("serving", "state_slot_steps", b)
+            telemetry.inc("serving", self._state_bytes_counter,
                           self._state_rw_bytes * b)
         if self.cache.ring_blocks:
             # a row at length n attends its n keys and the token itself

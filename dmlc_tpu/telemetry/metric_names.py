@@ -449,10 +449,13 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_moe_pairs_held",
     "dmlc_serving_moe_expert_load_max",
     "dmlc_serving_moe_expert_load_mean",
-    # recurrent state in the cache manager (hybrid family): slots
-    # handed out, and the state bytes the decode steps read and wrote
+    # recurrent state in the cache manager (the families with KDA or
+    # Mamba-2 layers): slots handed out, live slots summed over decode
+    # steps, and the state bytes those steps read and wrote by kind
     "dmlc_serving_state_slot_allocs",
+    "dmlc_serving_state_slot_steps",
     "dmlc_serving_kda_state_rw_bytes",
+    "dmlc_serving_ssm_state_rw_bytes",
     "dmlc_serving_state_slots_in_use",
     "dmlc_serving_state_slots_total",
     # sliding-window layers' own pool and block table (the widened MHA
