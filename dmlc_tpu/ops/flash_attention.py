@@ -14,10 +14,11 @@ halving attention compute at large T.
 The standalone `flash_attention` entry is fully differentiable with
 FlashAttention-style backward kernels (dkv + dq passes over saved
 (o, lse) residuals) — no T×T matrix is ever materialized, which is what
-makes long-context training fit in HBM.  The ring-step
-`block_attend_flash` is differentiable through a pure-lax recompute twin
-(its (pv, m, l) outputs feed the ring combine, whose rescales cancel
-analytically).
+makes long-context training fit in HBM.  The same two kernels take
+global offsets, so the ring's backward (parallel/ring_attention.py)
+runs them once a ring step over its saved (o, lse).  The ring-step
+`block_attend_flash` alone is differentiable through a pure-lax
+recompute twin, for its direct callers.
 
 Whether a call site gets these kernels or the pure-lax reference is
 decided in ops/dispatch.py; interpret=True runs the kernels on CPU for
@@ -161,13 +162,17 @@ def _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
                               kv_padded, kvend_ref, qoff=qoff_ref[0],
                               kvoff=kvoff_ref[0], span=span)
 
+    _walk(pl, walk, j, interpret)
+
+
+def _walk(pl, walk, j, interpret: bool):
+    """Run a kernel's masked walk.  The Pallas interpreter (jax 0.9.0)
+    evaluates a kernel's top-level equations one by one under the
+    caller's VMA check, where an offset that varies over a shard_map
+    axis (the ring step) cannot meet a constant, but it takes a cond
+    whole: an always-true pl.when lets the CPU tests drive the kernels
+    inside the VMA-checked train step.  Compiled kernels skip it."""
     if interpret:
-        # The Pallas interpreter (jax 0.9.0) evaluates a kernel's
-        # top-level equations one by one under the caller's VMA check,
-        # where an offset that varies over a shard_map axis (the ring
-        # step) cannot meet a constant, but it takes a cond whole: an
-        # always-true pl.when lets the CPU tests drive this kernel
-        # inside the VMA-checked train step.  Compiled kernels skip it.
         pl.when(j >= 0)(walk)
     else:
         walk()
@@ -440,8 +445,8 @@ def _dispatch_masked_step(pl, step, qi, j, block_q: int, block_k: int,
     pay the per-element iota/compare/select chain only on
     diagonal/padded-tail blocks — for every other visible block the
     mask would be all-True, and skipping it removes ~half the VPU work
-    per step.  The forward passes its scalar-prefetch global offsets;
-    the backward runs in local positions (offsets 0).  Under a
+    per step.  Every kernel passes its scalar-prefetch global offsets:
+    the ring step's, or zeros on one chip.  Under a
     sliding window of ``span`` keys (forward only) a block wholly
     behind the window is skipped like one above the diagonal, and one
     the window's edge crosses is a boundary block."""
@@ -473,19 +478,34 @@ def _dispatch_masked_step(pl, step, qi, j, block_q: int, block_k: int,
     pl.when(boundary)(lambda: step(True))
 
 
-def _bwd_dkv_kernel(kvend_ref, q_ref, do_ref, k_ref, v_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, *, block_q: int,
+def _bwd_offsets(qoff_ref, kvoff_ref, offsets: bool):
+    """The global positions of a call's first query and key: the ring
+    step's, from its scalar-prefetch operands, or the constant 0 on one
+    chip, where the kernels' bodies then lower as they did before they
+    took offsets (PERF.md section 6, PR 40)."""
+    return (qoff_ref[0], kvoff_ref[0]) if offsets else (0, 0)
+
+
+def _bwd_dkv_kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, do_ref, k_ref,
+                    v_ref, lse_ref, delta_ref, *refs, block_q: int,
                     block_k: int, causal: bool, kv_padded: bool,
-                    scale: float):
+                    scale: float, interpret: bool, offsets: bool):
     from jax.experimental import pallas as pl
 
+    # refs: (dk, dv) out, or (dk, dv) in and out, aliased: the ring's
+    # accumulators, which each step's partials are added to in place
+    dk_ref, dv_ref = refs[-2:]
     j = pl.program_id(1)   # KV block (the accumulator's home)
     qi = pl.program_id(2)  # Q step (innermost: pipelined)
 
     @pl.when(qi == 0)
     def _init():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
+        if len(refs) == 4:
+            dk_ref[0] = refs[0][0]
+            dv_ref[0] = refs[1][0]
+        else:
+            dk_ref[0] = jnp.zeros_like(dk_ref[0])
+            dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
     def step(masked: bool):
         kb = k_ref[0]                     # [block_k, D]
@@ -499,15 +519,16 @@ def _bwd_dkv_kernel(kvend_ref, q_ref, do_ref, k_ref, v_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale    # [bq, bk]
         p = jnp.exp(s - lse[:, None])
         if masked:
+            qoff, kvoff = _bwd_offsets(qoff_ref, kvoff_ref, offsets)
             keep = None
             if causal:
-                q_pos = qi * block_q + lax.broadcasted_iota(
+                q_pos = qoff + qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
-                k_pos = j * block_k + lax.broadcasted_iota(
+                k_pos = kvoff + j * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
                 keep = q_pos >= k_pos
             if kv_padded:
-                kp = j * block_k + lax.broadcasted_iota(
+                kp = kvoff + j * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
                 in_range = kp < kvend_ref[0]
                 keep = in_range if keep is None else keep & in_range
@@ -524,21 +545,25 @@ def _bwd_dkv_kernel(kvend_ref, q_ref, do_ref, k_ref, v_ref, lse_ref,
             ds, qb.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, D]
 
-    _dispatch_masked_step(pl, step, qi, j, block_q, block_k, causal,
-                          kv_padded, kvend_ref)
+    _walk(pl, lambda: _dispatch_masked_step(
+        pl, step, qi, j, block_q, block_k, causal, kv_padded, kvend_ref,
+        *_bwd_offsets(qoff_ref, kvoff_ref, offsets)), j, interpret)
 
 
-def _bwd_dq_kernel(kvend_ref, q_ref, do_ref, k_ref, v_ref, lse_ref,
-                   delta_ref, dq_ref, *, block_q: int, block_k: int,
-                   causal: bool, kv_padded: bool, scale: float):
+def _bwd_dq_kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, do_ref, k_ref,
+                   v_ref, lse_ref, delta_ref, *refs, block_q: int,
+                   block_k: int, causal: bool, kv_padded: bool,
+                   scale: float, interpret: bool, offsets: bool):
     from jax.experimental import pallas as pl
 
+    dq_ref = refs[-1]      # refs: dq out, or dq in and out (aliased)
     qi = pl.program_id(1)  # Q block (the accumulator's home)
     j = pl.program_id(2)   # KV step (innermost: pipelined)
 
     @pl.when(j == 0)
     def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dq_ref[0] = refs[0][0] if len(refs) == 2 else jnp.zeros_like(
+            dq_ref[0])
 
     def step(masked: bool):
         qb = q_ref[0]                      # [block_q, D]
@@ -552,12 +577,13 @@ def _bwd_dq_kernel(kvend_ref, q_ref, do_ref, k_ref, v_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse[:, None])
         if masked:
+            qoff, kvoff = _bwd_offsets(qoff_ref, kvoff_ref, offsets)
             keep = None
             if causal or kv_padded:
-                k_pos = j * block_k + lax.broadcasted_iota(
+                k_pos = kvoff + j * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
             if causal:
-                q_pos = qi * block_q + lax.broadcasted_iota(
+                q_pos = qoff + qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
                 keep = q_pos >= k_pos
             if kv_padded:
@@ -573,107 +599,149 @@ def _bwd_dq_kernel(kvend_ref, q_ref, do_ref, k_ref, v_ref, lse_ref,
             ds, kb.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _dispatch_masked_step(pl, step, qi, j, block_q, block_k, causal,
-                          kv_padded, kvend_ref)
+    _walk(pl, lambda: _dispatch_masked_step(
+        pl, step, qi, j, block_q, block_k, causal, kv_padded, kvend_ref,
+        *_bwd_offsets(qoff_ref, kvoff_ref, offsets)), j, interpret)
 
 
-def _flash_backward(static, q, k, v, o, lse, do):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def heads_layout(x, block: int):
+    """``[B, T, H, D]`` -> ``[B*H, T padded to a multiple of block, D]``:
+    the layout every kernel here reads."""
+    b, t, h, d = x.shape
+    x = _pad_seq(x, -t % block)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
 
-    scale, causal, block_q, block_k, interpret = static[:5]
-    if len(static) > 5:  # separately-tuned backward blocks
-        block_q, block_k = static[5], static[6]
-    b, tq, h, d = q.shape
-    tk = k.shape[1]
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    bh = b * h
 
+def heads_unlayout(x, like):
+    """:func:`heads_layout`'s inverse: ``[B*H, T', D]`` -> ``like``'s
+    ``[B, T, H, D]`` and dtype."""
+    b, t, h, d = like.shape
+    return (x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)[:, :t]
+            .astype(like.dtype))
+
+
+def bwd_blocks() -> Tuple[int, int]:
+    """The backward kernels' (block_q, block_k) where the caller names
+    none: 1024 x 1024, or DMLC_FLASH_BWD_BLOCK_Q/K for sweeps (read at
+    trace time)."""
+    return (get_env("DMLC_FLASH_BWD_BLOCK_Q", 0) or 1024,
+            get_env("DMLC_FLASH_BWD_BLOCK_K", 0) or 1024)
+
+
+def bwd_q_side(q, o, lse, do, block_q: int):
+    """What the backward kernels read of the queries' side, laid out
+    once: (q, dO) as :func:`heads_layout`, lse and delta = rowsum(dO o)
+    ``[B*H, T, 8]`` float32, lane-broadcast.  Padded Q rows get
+    lse = +BIG so exp(S - lse) underflows to exactly 0 and they add
+    nothing to dK / dV."""
+    b, tq, h, _ = q.shape
+    bh, tq_pad = b * h, -tq % block_q
     # delta = rowsum(dO ∘ O), [B, T, H] — cheap, fused by XLA
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
-    tq_pad = -tq % block_q
-    tk_pad = -tk % block_k
-    kv_padded = tk_pad != 0
-    q = _pad_seq(q, tq_pad)
-    do = _pad_seq(do, tq_pad)
-    k = _pad_seq(k, tk_pad)
-    v = _pad_seq(v, tk_pad)
-    tq_p, tk_p = tq + tq_pad, tk + tk_pad
-
-    qt = q.transpose(0, 2, 1, 3).reshape(bh, tq_p, d)
-    dot = do.transpose(0, 2, 1, 3).reshape(bh, tq_p, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(bh, tk_p, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(bh, tk_p, d)
-    # lse/delta: [B,H,Tq]-like → [bh, tq_p, 8] lane-broadcast; padded Q
-    # rows get lse=+BIG so exp(S - lse) underflows to exactly 0 and they
-    # contribute nothing to dK/dV
     lse_p = jnp.pad(lse.reshape(bh, tq), ((0, 0), (0, tq_pad)),
                     constant_values=_POS_BIG)
     delta_p = jnp.pad(delta.transpose(0, 2, 1).reshape(bh, tq),
                       ((0, 0), (0, tq_pad)))
-    lse8 = jnp.broadcast_to(lse_p[:, :, None], (bh, tq_p, 8))
-    delta8 = jnp.broadcast_to(delta_p[:, :, None], (bh, tq_p, 8))
-    kvend = jnp.asarray([tk], jnp.int32)
+    tq_p = tq + tq_pad
+    return (heads_layout(q, block_q), heads_layout(do, block_q),
+            jnp.broadcast_to(lse_p[:, :, None], (bh, tq_p, 8)),
+            jnp.broadcast_to(delta_p[:, :, None], (bh, tq_p, 8)))
+
+
+def bwd_kernels(static, qoff, kvoff, tk: int, qt, dot, lse8, delta8, kt, vt,
+                acc=None):
+    """``flash_dkv`` and ``flash_dq``: dQ, dK, dV ``[B*H, T, D]`` float32
+    of the queries at global position ``qoff`` against ONE K/V block at
+    ``kvoff`` (each a ``[1]`` int32, or both None: one chip, position 0)
+    of ``tk`` real rows, everything laid out by :func:`bwd_q_side` /
+    :func:`heads_layout`.  ``static``:
+    (scale, causal, block_q, block_k, interpret).  A tile wholly above
+    the global diagonal is skipped, one wholly below it runs the
+    mask-free body.  ``acc`` (dq, dk, dv) float32, when given, is added
+    to in place (the ring's accumulators) instead of a zero start."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    scale, causal, block_q, block_k, interpret = static
+    bh, tq_p, d = qt.shape
+    tk_p = kt.shape[1]
+    kernel_static = dict(block_q=block_q, block_k=block_k, causal=causal,
+                         kv_padded=tk != tk_p, scale=scale,
+                         interpret=bool(interpret), offsets=qoff is not None)
+    if qoff is None:
+        qoff = kvoff = jnp.zeros(1, jnp.int32)
+    operands = (qoff, kvoff, kvoff + tk, qt, dot, kt, vt, lse8, delta8)
+    n_in = len(operands)
+    if acc is None:
+        acc, aliases = (), ({}, {})
+    else:
+        aliases = ({n_in: 0, n_in + 1: 1}, {n_in: 0})
+    # bh and the accumulator's home dim are independent; only the
+    # innermost (accumulating) dim is order-dependent: faster than
+    # leaving the semantics unspecified when it was tried.  (The fwd
+    # kernel regressed with the same hint, so it stays plain.)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     # dkv grid (bh, kv, q): accumulators live in the kv-indexed output
     # blocks, revisited across the innermost q steps
     q_of_q = pl.BlockSpec((1, block_q, d), lambda bi, kj, qi, *_: (bi, qi, 0))
     k_of_kv = pl.BlockSpec((1, block_k, d), lambda bi, kj, qi, *_: (bi, kj, 0))
     s_of_q = pl.BlockSpec((1, block_q, 8), lambda bi, kj, qi, *_: (bi, qi, 0))
-    operands = (kvend, qt, dot, kt, vt, lse8, delta8)
+    dkv_acc = tuple(acc[1:])
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, kv_padded=kv_padded, scale=scale),
+        functools.partial(_bwd_dkv_kernel, **kernel_static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(bh, tk_p // block_k, tq_p // block_q),
-            in_specs=[q_of_q, q_of_q, k_of_kv, k_of_kv, s_of_q, s_of_q],
+            in_specs=[q_of_q, q_of_q, k_of_kv, k_of_kv, s_of_q, s_of_q]
+            + [k_of_kv] * len(dkv_acc),
             out_specs=[k_of_kv, k_of_kv],
         ),
         out_shape=[
-            _out_struct((bh, tk_p, d), jnp.float32, *operands),
-            _out_struct((bh, tk_p, d), jnp.float32, *operands),
+            _out_struct((bh, tk_p, d), jnp.float32, *operands, *dkv_acc),
+            _out_struct((bh, tk_p, d), jnp.float32, *operands, *dkv_acc),
         ],
-        # bh and the accumulator's home dim are independent; only the
-        # innermost (accumulating) dim is order-dependent: faster than
-        # leaving the semantics unspecified when it was tried.  (The fwd
-        # kernel regressed with the same hint, so it stays plain.)
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        input_output_aliases=aliases[0],
+        compiler_params=params,
         name="flash_dkv",
         interpret=interpret,
-    )(*operands)
+    )(*operands, *dkv_acc)
 
     # dq grid (bh, q, kv): accumulator in the q-indexed output block
     q_of_q2 = pl.BlockSpec((1, block_q, d), lambda bi, qi, kj, *_: (bi, qi, 0))
     k_of_kv2 = pl.BlockSpec((1, block_k, d), lambda bi, qi, kj, *_: (bi, kj, 0))
     s_of_q2 = pl.BlockSpec((1, block_q, 8), lambda bi, qi, kj, *_: (bi, qi, 0))
+    dq_acc = tuple(acc[:1])
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, kv_padded=kv_padded, scale=scale),
+        functools.partial(_bwd_dq_kernel, **kernel_static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(bh, tq_p // block_q, tk_p // block_k),
             in_specs=[q_of_q2, q_of_q2, k_of_kv2, k_of_kv2, s_of_q2,
-                      s_of_q2],
+                      s_of_q2] + [q_of_q2] * len(dq_acc),
             out_specs=q_of_q2,
         ),
-        out_shape=_out_struct((bh, tq_p, d), jnp.float32, *operands),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=_out_struct((bh, tq_p, d), jnp.float32, *operands,
+                              *dq_acc),
+        input_output_aliases=aliases[1],
+        compiler_params=params,
         name="flash_dq",
         interpret=interpret,
-    )(*operands)
-
-    def unpack(x, t):
-        return x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)[:, :t]
-
-    dq = unpack(dq, tq).astype(q.dtype)
-    dk = unpack(dk, tk).astype(k.dtype)
-    dv = unpack(dv, tk).astype(v.dtype)
+    )(*operands, *dq_acc)
     return dq, dk, dv
+
+
+def _flash_backward(static, q, k, v, o, lse, do):
+    tq, tk = q.shape[1], k.shape[1]
+    # the call's blocks, or the separately-tuned backward pair
+    block_q, block_k = static[2:4] if len(static) <= 5 else static[5:7]
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    dq, dk, dv = bwd_kernels(
+        static[:2] + (block_q, block_k, static[4]), None, None, tk,
+        *bwd_q_side(q, o, lse, do, block_q), heads_layout(k, block_k),
+        heads_layout(v, block_k))
+    return heads_unlayout(dq, q), heads_unlayout(dk, k), heads_unlayout(dv, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -819,10 +887,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # explicit caller blocks bind BOTH passes (a caller sizing for VMEM
     # must not get surprise-larger backward tiles); env/defaults fill
     # whatever remains
-    bwd_q = block_q if block_q is not None \
-        else get_env("DMLC_FLASH_BWD_BLOCK_Q", 0) or 1024
-    bwd_k = block_k if block_k is not None \
-        else get_env("DMLC_FLASH_BWD_BLOCK_K", 0) or 1024
+    bwd_q, bwd_k = bwd_blocks()
+    bwd_q = bwd_q if block_q is None else block_q
+    bwd_k = bwd_k if block_k is None else block_k
     if block_q is None:
         block_q = get_env("DMLC_FLASH_BLOCK_Q", 0) or 1024
     if block_k is None:

@@ -13,7 +13,10 @@ K/V blocks around the ring with `lax.ppermute`, folding each block into a
 flash-attention-style online softmax (running max + denominator), so the
 full-sequence result is exact while peak memory stays O(T/sp).  The KV
 rotation overlaps with compute at the XLA level (async collective
-permute on TPU).
+permute on TPU).  On the kernel path the ring has a VJP of its own: the
+forward keeps (o, lse), and the backward walks the ring once more with
+the flash backward kernels at global offsets, the dK / dV accumulators
+travelling with their K/V block.
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ from .collectives import match_vma as _match_vma
 _NEG_BIG = -1e30
 
 
+from ..ops import flash_attention as _flash  # noqa: E402
 # canonical lax (pv, m, l) block attend — one implementation, shared with
 # the flash kernel's VJP twin so the two can never diverge
 from ..ops.flash_attention import lax_block_attend as _block_attend  # noqa: E402
@@ -58,14 +62,12 @@ def ring_attention(
     off-TPU, for tests); 'lax' forces the reference.
     """
     n = lax.axis_size(axis_name)
-    my = lax.axis_index(axis_name)
     b, t_local, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
     from .. import telemetry
     from ..ops import dispatch
-    from ..ops import flash_attention as _flash
 
     # trace-time accounting (the ring loop runs device-side): each of
     # the n ring steps rotates the full local K+V block over ICI, so
@@ -91,11 +93,23 @@ def ring_attention(
 
     if n == 1 and use_flash:
         # degenerate ring (sp axis of size 1 — e.g. dp-only meshes): the
-        # standalone kernel path is strictly better — kernel backward
-        # (no T×T lax recompute) and save_flash remat policy both apply
+        # standalone kernel path is strictly better (no rotation)
         return _flash.flash_attention(q, k, v, causal=causal, scale=scale,
                                       interpret=interpret)
+    if use_flash:
+        return _ring_flash((axis_name, bool(causal), float(scale),
+                            interpret), q, k, v)
+    acc, _, l = _ring_forward(q, k, v, axis_name, causal, scale, None)
+    return _normalized(acc, l, q.dtype)
 
+
+def _ring_forward(q, k, v, axis_name, causal, scale, interpret):
+    """The ring's loop: (acc [B, T, H, D], m, l [B, H, T]) float32 of my
+    Q block against every rank's K/V block, each step through the flash
+    kernel or (``interpret`` None) the pure-lax block attend."""
+    n = lax.axis_size(axis_name)
+    my = lax.axis_index(axis_name)
+    b, t_local, h, d = q.shape
     q_pos = jnp.arange(t_local)  # local positions; global = blk*t_local + pos
     acc0 = jnp.zeros((b, t_local, h, d), jnp.float32)
     m0 = jnp.full((b, h, t_local), _NEG_BIG, jnp.float32)
@@ -107,7 +121,7 @@ def ring_attention(
     def step(i, carry):
         acc, m, l, k_blk, v_blk = carry
         src = (my - i) % n  # ring position the held KV block originated from
-        if use_flash:
+        if interpret is not None:
             # the kernel takes the global offsets as scalar-prefetch args,
             # so one compiled kernel serves every ring step
             pv, bm, bl = _flash.block_attend_flash(
@@ -131,14 +145,91 @@ def ring_attention(
             acc * jnp.transpose(corr, (0, 2, 1))[..., None]
             + pv * jnp.transpose(bcor, (0, 2, 1))[..., None]
         )
-        perm = [(j, (j + 1) % n) for j in range(n)]
-        k_next = lax.ppermute(k_blk, axis_name, perm)
-        v_next = lax.ppermute(v_blk, axis_name, perm)
+        k_next = lax.ppermute(k_blk, axis_name, _ring_perm(n))
+        v_next = lax.ppermute(v_blk, axis_name, _ring_perm(n))
         return acc_new, m_new, l_new, k_next, v_next
 
     acc, m, l, _, _ = lax.fori_loop(0, n, step, (acc0, m0, l0, k, v))
+    return acc, m, l
+
+
+def _ring_perm(n):
+    return [(j, (j + 1) % n) for j in range(n)]
+
+
+def _normalized(acc, l, dtype):
     denom = jnp.transpose(jnp.maximum(l, 1e-20), (0, 2, 1))[..., None]
-    return (acc / denom).astype(q.dtype)
+    return (acc / denom).astype(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ring_flash(static, q, k, v):
+    return _ring_flash_out(static, q, k, v)[0]
+
+
+def _ring_flash_out(static, q, k, v):
+    """The kernel path's forward: o in q's dtype and lse = m + log(l)
+    float32 [B, H, T], named as ops.flash_attention's one-chip call
+    names them, so that the save_flash remat policy keeps both and the
+    backward does not run the ring (and its rotations) again."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    axis_name, causal, scale, interpret = static
+    acc, m, l = _ring_forward(q, k, v, axis_name, causal, scale, interpret)
+    lse = m + jnp.log(jnp.maximum(l, 1e-20))
+    return (checkpoint_name(_normalized(acc, l, q.dtype), "flash_o"),
+            checkpoint_name(lse, "flash_lse"))
+
+
+def _ring_flash_fwd(static, q, k, v):
+    o, lse = _ring_flash_out(static, q, k, v)
+    return o, (q, k, v, o, lse)
+
+
+def _ring_flash_bwd(static, res, do):
+    """The ring again, backwards: my (q, dO, lse, delta) laid out once,
+    and at ring step i the kernels ``flash_dq`` / ``flash_dkv`` against
+    the K/V block I hold, at its global offset.  dQ accumulates here;
+    dK / dV accumulate in float32 buffers that travel with their K/V
+    block and arrive home after the n-th rotation, which carries them
+    alone."""
+    from .. import telemetry
+
+    axis_name, causal, scale, interpret = static
+    q, k, v, o, lse = res
+    n = lax.axis_size(axis_name)
+    my = lax.axis_index(axis_name)
+    t = q.shape[1]
+    block_q, block_k = (min(x, t) for x in _flash.bwd_blocks())
+    kernels = functools.partial(
+        _flash.bwd_kernels, (scale, causal, block_q, block_k, interpret),
+        (my * t).astype(jnp.int32).reshape(1))
+    q_side = _flash.bwd_q_side(q, o, lse, do, block_q)
+    perm = _ring_perm(n)
+
+    def grads(i, acc, kt, vt):
+        telemetry.inc("flash", "ring_step_bwd_calls")
+        kvoff = (((my - i) % n) * t).astype(jnp.int32).reshape(1)
+        return kernels(kvoff, t, *q_side, kt, vt, acc=acc)
+
+    def step(i, carry):
+        acc, kt, vt = carry[:3], carry[3], carry[4]
+        dq, dk, dv = grads(i, acc, kt, vt)
+        dk, dv, kt, vt = (lax.ppermute(x, axis_name, perm)
+                          for x in (dk, dv, kt, vt))
+        return dq, dk, dv, kt, vt
+
+    kt, vt = (_flash.heads_layout(x, block_k) for x in (k, v))
+    acc0 = tuple(_match_vma(jnp.zeros(x.shape, jnp.float32), x)
+                 for x in (q_side[0], kt, vt))
+    *acc, kt, vt = lax.fori_loop(0, n - 1, step, (*acc0, kt, vt))
+    dq, dk, dv = grads(n - 1, tuple(acc), kt, vt)
+    dk, dv = (lax.ppermute(x, axis_name, perm) for x in (dk, dv))
+    return tuple(_flash.heads_unlayout(x, like)
+                 for x, like in ((dq, q), (dk, k), (dv, v)))
+
+
+_ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
 def ring_attention_reference(q, k, v, *, causal: bool = True, scale=None):

@@ -118,6 +118,7 @@ METRIC_NAMES = frozenset({
     "dmlc_flash_fwd_calls",
     "dmlc_flash_fwd_flops",
     "dmlc_flash_ring_step_calls",
+    "dmlc_flash_ring_step_bwd_calls",
     "dmlc_flash_seq_len_q",
     # input split / io
     "dmlc_input_split_bytes",

@@ -1,13 +1,14 @@
 #!/usr/bin/env python
-"""The forward flash kernel alone, at the shapes the models call it with.
+"""The flash kernels alone, at the shapes the models call them with.
 
     chiprun -- python scripts/time_flash_fwd.py             # the chip
     python scripts/time_flash_fwd.py --interpret            # CPU smoke, toy sizes
     python scripts/time_flash_fwd.py --root .parent_check   # another checkout
 
 Times ``flash_attention`` / ``block_attend_flash`` (kernels ``flash_fwd_o``
-and ``flash_fwd`` of ``dmlc_tpu/ops/flash_attention.py``) and nothing
-around them, case by case:
+and ``flash_fwd`` of ``dmlc_tpu/ops/flash_attention.py``), and the ring
+step's backward (``flash_dkv`` + ``flash_dq``), and nothing around them,
+case by case:
 
   a   Command A+'s full layer: a chunk of 8,192 rows at ``q_offset`` 24,576
       against 32,768 keys, 128 query heads on 8 K/V heads of 128
@@ -16,10 +17,16 @@ around them, case by case:
   d1  the flagship's ``flash_attention``: B=1, T=2,048, 16 heads of 128
   d2  the ring step's ``block_attend_flash``: B=2, Tq=Tk=4,096, 8 heads,
       blocks of 512, on the diagonal (offsets equal) and behind it
+  d2_bwd  the same step's backward as ``parallel/ring_attention.py`` runs
+      it: ``flash_dkv`` + ``flash_dq`` at the offsets, blocks of
+      ``flash_attention.bwd_blocks()``, into float32 accumulators
+  d1_bwd  ``train-flagship-t1024``'s backward: ``flash_attention``'s VJP
+      at B=8, T=1,024, 16 heads (its two kernels' events alone)
 
 For each case it prints the kernel's time, the share of the roofline
 that is (the operations ``benchmarks/costs*.py`` count over the chip's
-peak, all of them compute-bound), and microseconds a tile: a Q block's
+peak, all of them compute-bound; 4 + 3 products a tile for the
+backward's two kernels), and microseconds a tile: a Q block's
 walk costs ``walk + unmasked x n_u + boundary x n_b``, and three probes
 with the case's heads tell the three apart (a walk of one unmasked
 tile, a walk of one boundary tile, a walk of ``LONG`` unmasked tiles).
@@ -41,6 +48,7 @@ import tempfile
 import time
 
 LONG = 8  # unmasked tiles in the long probe's walk
+FWD, BWD = ("flash_fwd",), ("flash_dkv", "flash_dq")
 
 
 def tiles(tq, tk, qoff, kvoff, bq, bk, causal=True, span=0):
@@ -76,6 +84,7 @@ def chunk_pairs(first, rows, window):
 
 def cases(toy: bool):
     """name -> dict(call=..., shapes, flops, tile counts, probe family)."""
+    import jax
     import jax.numpy as jnp
 
     from benchmarks import costs, costs_mla_moe
@@ -167,6 +176,48 @@ def cases(toy: bool):
     out["d2_diagonal"] = ring_case(t2, t2, 0.5)
     out["d2_behind"] = ring_case(t2, 0, 1.0)
 
+    b3, t3, h3 = (1, 32, 2) if toy else (8, 1024, 16)
+
+    def one_chip_bwd(q, k, v):
+        o, vjp = jax.vjp(lambda *a: flash.flash_attention(*a, **blk, **interp),
+                         q, k, v)
+        return vjp(o)
+
+    out["d1_bwd"] = dict(
+        call=one_chip_bwd, q=(b3, t3, h3, 128), k=(b3, t3, h3, 128),
+        v=(b3, t3, h3, 128), dtype=dt, heads=b3 * h3, kernels=BWD,
+        flops=costs._attn_block_flops(b3, t3, t3, h3, 128, 7, 0.5),
+        tiles=tiles(t3, t3, 0, 0, bq, bq))
+    if not hasattr(flash, "bwd_kernels"):
+        return out  # a checkout from before the ring's backward kernels
+    bb = tuple(min(x, t2) for x in ((16, 16) if toy else flash.bwd_blocks()))
+
+    def ring_bwd(q_off, kv_off):
+        # (o, lse) of the block from the forward kernel and dO = q: the
+        # case's time is the two backward kernels' events alone
+        def call(q, k, v):
+            pv, m, l = ring(q_off, kv_off)(q, k, v)
+            lsafe = jnp.maximum(l, 1e-20)
+            o = (pv / jnp.transpose(lsafe, (0, 2, 1))[..., None]
+                 ).astype(q.dtype)
+            q_side = flash.bwd_q_side(q, o, m + jnp.log(lsafe), q, bb[0])
+            kt, vt = (flash.heads_layout(x, bb[1]) for x in (k, v))
+            acc = tuple(jnp.zeros(x.shape, jnp.float32)
+                        for x in (q_side[0], kt, vt))
+            off = [jnp.asarray([x], jnp.int32) for x in (q_off, kv_off)]
+            return flash.bwd_kernels((128 ** -0.5, True, *bb, toy), *off,
+                                     k.shape[1], *q_side, kt, vt, acc=acc)
+        return call
+
+    for name, q_off, kv_off, visible in (("d2_bwd_diagonal", t2, t2, 0.5),
+                                         ("d2_bwd_behind", t2, 0, 1.0)):
+        out[name] = dict(
+            call=ring_bwd(q_off, kv_off), q=(b2, t2, h2, 128),
+            k=(b2, t2, h2, 128), v=(b2, t2, h2, 128), dtype=dt,
+            heads=b2 * h2, kernels=BWD,
+            flops=costs._attn_block_flops(b2, t2, t2, h2, 128, 7, visible),
+            tiles=tiles(t2, t2, q_off, kv_off, *bb))
+
     def mha_probe(tq, tk_, q_off, b):
         # the flagship's kernel through the ring step's entry, which
         # takes the offsets a probe needs
@@ -186,7 +237,8 @@ def cases(toy: bool):
 
 
 def _kernel_events(trace_dir):
-    """scope -> seconds of the flash kernels' device events under it."""
+    """(scope, kernel) -> seconds of the flash kernels' device events
+    under it (``flash_fwd`` counts ``flash_fwd_o`` too)."""
     from benchmarks import reduce_trace
 
     found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -197,18 +249,18 @@ def _kernel_events(trace_dir):
     seconds = {}
     for events in trace.devices.values():
         for e in events:
-            if "flash_fwd" not in e.path:
-                continue
+            kernel = next((k for k in BWD + FWD if k in e.path), None)
             scope = next((part for part in e.path.split("/")
                           if part.startswith("time_")), None)
-            if scope:
-                seconds[scope] = seconds.get(scope, 0.0) + e.seconds
+            if kernel and scope:
+                key = (scope, kernel)
+                seconds[key] = seconds.get(key, 0.0) + e.seconds
     return seconds
 
 
 def measure(todo, reps: int, trace: bool):
     """``todo``: label -> case-like dict.  Returns label -> (seconds a
-    call, "device_trace" | "host_clock")."""
+    call, "device_trace" | "host_clock", {kernel: seconds a call})."""
     import jax
 
     fns, host = {}, {}
@@ -216,9 +268,9 @@ def measure(todo, reps: int, trace: bool):
         scope = f"time_{n}"
 
         def fn(q, k, v, _call=c["call"], _scope=scope):
+            # all of it: an output left out takes its kernel with it
             with jax.named_scope(_scope):
-                out = _call(q, k, v)
-            return out[0] if isinstance(out, tuple) else out
+                return _call(q, k, v)
 
         keys = jax.random.split(jax.random.PRNGKey(n), 3)
         args = [jax.random.normal(key, c[name], c["dtype"])
@@ -233,7 +285,7 @@ def measure(todo, reps: int, trace: bool):
         if trace:
             fns[label] = (scope, jitted, args)
     if not trace:
-        return {label: (s, "host_clock") for label, s in host.items()}
+        return {label: (s, "host_clock", {}) for label, s in host.items()}
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             for scope, jitted, args in fns.values():
@@ -242,18 +294,22 @@ def measure(todo, reps: int, trace: bool):
         by_scope = _kernel_events(trace_dir)
     out = {}
     for label, (scope, _, _) in fns.items():
-        if by_scope.get(scope):
-            out[label] = (by_scope[scope] / reps, "device_trace")
+        each = {k: by_scope[(scope, k)] / reps
+                for k in todo[label].get("kernels", FWD)
+                if by_scope.get((scope, k))}
+        if each:
+            out[label] = (sum(each.values()), "device_trace", each)
         else:
-            out[label] = (host[label], "host_clock")
+            out[label] = (host[label], "host_clock", {})
     return out
 
 
 def report(name, case, times, peak_flops):
     """One case's lines as a dict: time, roofline share, us a tile."""
-    seconds, source = times[name]
+    seconds, source, each = times[name]
     n_u, n_b = case["tiles"]
     line = {"case": name, "ms": seconds * 1e3, "clock": source,
+            **{f"ms_{k}": s * 1e3 for k, s in each.items()},
             "tiles_unmasked": n_u * case["heads"],
             "tiles_boundary": n_b * case["heads"],
             "boundary_tile_share": n_b / float(n_u + n_b),
@@ -280,7 +336,8 @@ def main(argv=None):
                          "run, its times mean nothing")
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), help="the checkout to time")
-    ap.add_argument("--cases", default="a,b,c,d1,d2_diagonal,d2_behind")
+    ap.add_argument("--cases", default="a,b,c,d1,d2_diagonal,d2_behind,"
+                    "d2_bwd_diagonal,d2_bwd_behind,d1_bwd")
     ap.add_argument("--reps", type=int, default=3)
     args = ap.parse_args(argv)
     sys.path.insert(0, os.path.abspath(args.root))

@@ -85,6 +85,26 @@ def test_ring_step_kernel_lowers_for_tpu_inside_shard_map():
                        in_specs=(spec, spec, spec), out_specs=out)
     _lower_for_tpu(fn, x, x, x)
 
+    # its backward as the ring runs it: both kernels at traced offsets,
+    # adding into float32 accumulators in place
+    def step_bwd(q, k, v, o):
+        my = jax.lax.axis_index("sp")
+        off = (my * 4096).astype(jnp.int32).reshape(1)
+        lse = jnp.zeros((1, 16, 4096), jnp.float32)
+        q_side = flash.bwd_q_side(q, o, lse, o, 1024)
+        kt, vt = (flash.heads_layout(a, 1024) for a in (k, v))
+        acc = tuple(jnp.zeros(a.shape, jnp.float32)
+                    for a in (q_side[0], kt, vt))
+        return flash.bwd_kernels((128 ** -0.5, True, 1024, 1024, False),
+                                 off, off, 4096, *q_side, kt, vt, acc=acc)
+
+    flat = P(("dp", "tp", "sp"), None, None)
+    fn = jax.shard_map(step_bwd, mesh=_one_device_mesh(),
+                       in_specs=(spec,) * 4, out_specs=(flat,) * 3)
+    text = _lower_for_tpu(fn, x, x, x, x)
+    for name in ("flash_dkv", "flash_dq"):
+        assert name in text, f"{name} missing from the lowered step"
+
 
 @pytest.mark.parametrize("b,s_w,n_blocks,w,dtype", [
     (8, 1, 256, 66, jnp.bfloat16),

@@ -177,6 +177,87 @@ def test_block_attend_flash_gradients_with_offsets(qoff, kvoff):
                                    atol=3e-4, rtol=1e-3)
 
 
+def _bwd_at(q, k, v, w, qoff, kvoff, o=None, lse=None, block=16):
+    """dQ, dK, dV of sum(attention(q, k, v) * w) from the backward
+    kernels at global offsets, over (o, lse) of the same block (from
+    the lax forward unless given), unpacked to [B, T, H, D]."""
+    from dmlc_tpu.ops import flash_attention as flash
+
+    b, tq, h, d = q.shape
+    tk = k.shape[1]
+    scale = 1.0 / (d ** 0.5)
+    if o is None:
+        pv, m, l = flash._lax_block_attend(q, k, v, qoff, kvoff,
+                                           scale=scale, causal=True)
+        lsafe = jnp.maximum(l, 1e-20)
+        o = pv / jnp.transpose(lsafe, (0, 2, 1))[..., None]
+        lse = m + jnp.log(lsafe)
+    bq, bk = min(block, tq), min(block, tk)
+    off = [jnp.asarray([x], jnp.int32) for x in (qoff, kvoff)]
+    grads = flash.bwd_kernels(
+        (scale, True, bq, bk, True), *off, tk,
+        *flash.bwd_q_side(q, o, lse, w, bq),
+        flash.heads_layout(k, bk), flash.heads_layout(v, bk))
+    return [g.reshape(b, h, -1, d).transpose(0, 2, 1, 3)[:, :t]
+            for g, t in zip(grads, (tq, tk, tk))]
+
+
+@pytest.mark.parametrize("qoff,kvoff,tk", [
+    (64, 32, 32),    # a block behind the queries: every tile unmasked
+    (32, 32, 32),    # on the diagonal
+    (40, 32, 40),    # offsets apart by less than a block, a padded tail
+    (0, 16, 32),     # rows 0-15 see no key
+    (0, 64, 32),     # wholly above the diagonal: every tile skipped
+], ids=["behind", "diagonal", "skewed_padded", "unseen_rows", "above"])
+def test_bwd_kernels_with_offsets_match_lax_vjp(qoff, kvoff, tk):
+    """``flash_dkv`` / ``flash_dq`` at the ring step's global offsets
+    against differentiating the lax block attend, normalised by its own
+    l, at the same offsets."""
+    from dmlc_tpu.ops import flash_attention as flash
+
+    b, tq, h, d = 1, 32, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(13), 4)
+    q = jax.random.normal(ks[0], (b, tq, h, d), jnp.float32)
+    k, v = (jax.random.normal(k_, (b, tk, h, d), jnp.float32)
+            for k_ in ks[1:3])
+    w = jax.random.normal(ks[3], (b, tq, h, d), jnp.float32)
+
+    def f_lax(q, k, v):
+        pv, _, l = flash._lax_block_attend(q, k, v, qoff, kvoff,
+                                           scale=d ** -0.5, causal=True)
+        lsafe = jnp.transpose(jnp.maximum(l, 1e-20), (0, 2, 1))[..., None]
+        return jnp.sum(pv / lsafe * w)
+
+    want = jax.grad(f_lax, argnums=(0, 1, 2))(q, k, v)
+    got = _bwd_at(q, k, v, w, qoff, kvoff)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=3e-5, rtol=1e-4)
+    if kvoff >= qoff + tq:
+        assert all(not np.asarray(g).any() for g in got)
+
+
+def test_bwd_kernels_at_zero_offsets_are_the_one_chip_backward():
+    """The one-chip ``flash_attention`` backward is the kernels at
+    offsets (0, 0), bit for bit, and a shift of both offsets together
+    (the ring's diagonal step) changes no bit."""
+    from dmlc_tpu.ops import flash_attention as flash
+
+    b, t, h, d = 1, 48, 2, 128  # blocks of 32: a padded tail
+    ks = jax.random.split(jax.random.PRNGKey(14), 4)
+    q, k, v, w = (jax.random.normal(k_, (b, t, h, d), jnp.float32)
+                  for k_ in ks)
+    static = (d ** -0.5, True, 32, 32, True)
+    o, lse = flash._flash_attn_impl(static, q, k, v)
+    _, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, block_q=32, block_k=32, interpret=True), q, k, v)
+    one_chip = vjp(w)
+    for off in (0, 96):
+        got = _bwd_at(q, k, v, w, off, off, o=o, lse=lse, block=32)
+        for a, b_ in zip(got, one_chip):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
 def test_supports_gate():
     assert supports((1, 64, 2, 128), (1, 64, 2, 128))
     assert not supports((1, 64, 2, 96), (1, 64, 2, 96))  # lane
@@ -274,7 +355,8 @@ def test_time_flash_fwd_script_smoke():
     spec.loader.exec_module(mod)
     lines = mod.main(["--interpret", "--reps", "1"])
     assert [ln["case"] for ln in lines] == [
-        "a", "b", "c", "d1", "d2_diagonal", "d2_behind"]
+        "a", "b", "c", "d1", "d2_diagonal", "d2_behind", "d2_bwd_diagonal",
+        "d2_bwd_behind", "d1_bwd"]
     assert all(ln["clock"] == "host_clock" and ln["ms"] > 0
                and ln["roofline_share_pct"] is None for ln in lines)
     by = {ln["case"]: ln for ln in lines}
@@ -282,6 +364,10 @@ def test_time_flash_fwd_script_smoke():
     assert (by["a"]["tiles_unmasked"], by["a"]["tiles_boundary"]) == (20, 8)
     assert by["b"]["boundary_tile_share"] > by["a"]["boundary_tile_share"]
     assert by["d2_behind"]["tiles_boundary"] == 0
+    # the ring's backward at toy blocks of 16: 2 x 2 tiles a head pair
+    assert (by["d2_bwd_diagonal"]["tiles_unmasked"],
+            by["d2_bwd_diagonal"]["tiles_boundary"]) == (2, 4)
+    assert by["d2_bwd_behind"]["tiles_unmasked"] == 8
     # the tile counter against the issue's figures for Command A+'s
     # sliding layers: a Q block reaches five K/V blocks, two of them
     # boundary; the full layer has one boundary tile a Q block's walk

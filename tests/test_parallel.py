@@ -223,6 +223,70 @@ def test_ring_attention_flash_impl_matches_reference(causal):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+@pytest.mark.parametrize("sp,tp,t,causal", [
+    (2, 1, 64, True),    # t_local 32: two backward blocks of 16
+    (2, 2, 48, False),   # t_local 24: a padded block; heads split over tp
+    (4, 2, 96, True),    # the same, causal, four ring steps
+    (4, 1, 96, False),
+])
+def test_ring_attention_flash_grads_match_reference(monkeypatch, sp, tp, t,
+                                                    causal):
+    """The kernel path's own VJP: dQ, dK, dV from the backward kernels at
+    global offsets, the dK / dV accumulators travelling the ring, against
+    jax.grad of the unsharded oracle."""
+    from dmlc_tpu import telemetry
+
+    monkeypatch.setenv("DMLC_FLASH_BWD_BLOCK_Q", "16")
+    monkeypatch.setenv("DMLC_FLASH_BWD_BLOCK_K", "16")
+    mesh = build_mesh(sp * tp, sp=sp, tp=tp, pp=1, dp=1)
+    b, h, d = 1, 2 * tp, 128
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, w = (jax.random.normal(k_, (b, t, h, d), jnp.float32)
+                  for k_ in ks)
+    fn = make_sharded_ring_attention(mesh, causal=causal, impl="flash")
+
+    def loss(q, k, v):
+        return jnp.sum(fn(q, k, v) * w)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(ring_attention_reference(q, k, v, causal=causal) * w)
+
+    before = telemetry.counters_snapshot().get("flash", {})
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    after = telemetry.counters_snapshot().get("flash", {})
+    assert (after.get("ring_step_bwd_calls", 0)
+            > before.get("ring_step_bwd_calls", 0))
+    want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-5)
+
+
+@pytest.mark.parametrize("policy,fwd_calls", [("save_flash", 1),
+                                              ("full", 2)])
+def test_ring_flash_forward_runs_once_under_save_flash(policy, fwd_calls):
+    """The kernel path names its (o, lse) as the one-chip call does, so
+    under the save_flash remat policy the gradient holds the ring's
+    ``flash_fwd`` once (the backward does not run the ring again);
+    under "full" it holds it twice."""
+    from dmlc_tpu.parallel.ring_attention import ring_attention
+
+    mesh = build_mesh(2, sp=2, tp=1, pp=1, dp=1)
+    spec = P(None, "sp", None, None)
+    x = jax.ShapeDtypeStruct((1, 64, 2, 128), jnp.float32)
+    names = jax.checkpoint_policies.save_only_these_names(
+        "flash_o", "flash_lse") if policy == "save_flash" else None
+
+    def local(q, k, v):
+        f = jax.checkpoint(lambda q, k, v: jnp.sum(ring_attention(
+            q, k, v, axis_name="sp", impl="flash")), policy=names)
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    text = str(jax.make_jaxpr(_smap(mesh, local, (spec,) * 3, (spec,) * 3))(
+        x, x, x))
+    assert text.count("name=flash_fwd") == fwd_calls
+    assert text.count("name=flash_dkv") == text.count("name=flash_dq") == 2
+
+
 # ---------------------------------------------------------------------------
 # bucketed_psum_mean (parallel/overlap.py device path): one lax.psum per
 # reverse-topological bucket must equal the fused pmean — vmap's named
