@@ -169,6 +169,14 @@ class TransformerConfig:
     moe_latent: int = 0
     moe_act: str = "swiglu"
     moe_shared_d_ff: int = 0
+    # ---- the MHA family looped, served only (Ouro's LoopLM block): the
+    # same ``n_layers`` run ``loop_passes`` times a token, each pass
+    # closed by the final norm, whose output starts the next; layer l
+    # of pass t keeps its K/V in cache layer ``t * n_layers + l`` of
+    # the pools.  ``sandwich_norm``: each sublayer's output is normed
+    # (``ln1_post``, ``ln2_post``) before it joins the residual stream
+    loop_passes: int = 1
+    sandwich_norm: bool = False
 
     @property
     def jdtype(self):
@@ -198,7 +206,14 @@ class TransformerConfig:
         return (self.latent or self.moe_router == "sigmoid"
                 or bool(self.n_kv_heads or self.sliding_window)
                 or self.norm != "rms" or self.parallel_block
-                or self.tie_embeddings or bool(self.residual_dtype))
+                or self.tie_embeddings or bool(self.residual_dtype)
+                or self.looped)
+
+    @property
+    def looped(self) -> bool:
+        """Whether the tree is :func:`_init_looped_params`'s: layers
+        run more than once a token, or with sandwich norms."""
+        return self.loop_passes > 1 or self.sandwich_norm
 
     @property
     def hybrid(self) -> bool:
@@ -250,11 +265,12 @@ class TransformerConfig:
             row = self.kv_lora_rank + self.qk_rope_head_dim
             return ((self.layer_kinds.count("mla"), n_blocks, row,
                      block_size),)
-        # a sliding layer's K/V are in sliding_pool_shapes' pools, and
-        # under a layer pattern the attention layers alone have K/V
+        # a sliding layer's K/V are in sliding_pool_shapes' pools, under
+        # a layer pattern the attention layers alone have K/V, and each
+        # pass of a looped model keeps its own
         n_layers = sum(kind in ("mha", "full") for kind in self.layer_kinds)
-        return ((n_layers, n_blocks, block_size, self.kv_heads,
-                 self.head_dim),) * 2
+        return ((self.loop_passes * n_layers, n_blocks, block_size,
+                 self.kv_heads, self.head_dim),) * 2
 
     def sliding_pool_shapes(self, n_rows: int, block_size: int) -> tuple:
         """Shapes of the sliding layers' K and V pools, none for a
@@ -367,7 +383,7 @@ def _gqa_forward_flops(cfg: TransformerConfig, t: int,
     K/V heads, scores and values over the keys each layer kind sees (a
     sliding layer at most its window), the FFN (dense, or the held
     share of the routed experts beside the shared ones and the router),
-    the unembed."""
+    the unembed; the layers as many times as a looped model runs them."""
     e, d = cfg.d_model, cfg.head_dim
     proj = 2 * e * d * (2 * cfg.n_heads + 2 * cfg.kv_heads)
     seen = {"sliding": min(t, cfg.sliding_window)}
@@ -381,7 +397,8 @@ def _gqa_forward_flops(cfg: TransformerConfig, t: int,
             + e * routed)
     else:
         ffn = 2 * 3 * e * cfg.d_ff * cfg.n_experts
-    return cfg.n_layers * (proj + ffn) + attn + 2 * e * cfg.vocab
+    return (cfg.loop_passes * (cfg.n_layers * (proj + ffn) + attn)
+            + 2 * e * cfg.vocab)
 
 
 def _pattern_forward_flops(cfg: TransformerConfig, t: int,
@@ -453,6 +470,8 @@ def init_params(key, cfg: TransformerConfig, n_stages: int = 1):
         return _init_latent_params(key, cfg, n_stages)
     if cfg.moe_router == "sigmoid":
         return _init_held_expert_params(key, cfg, n_stages)
+    if cfg.looped:
+        return _init_looped_params(key, cfg, n_stages)
     assert cfg.n_layers % n_stages == 0
     lps = cfg.n_layers // n_stages
     e, h, d, f, x = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff, cfg.n_experts
@@ -534,6 +553,43 @@ def _init_held_expert_params(key, cfg: TransformerConfig, n_stages: int = 1):
     if not cfg.tie_embeddings:
         tree["unembed"] = norm(e, cfg.vocab)
     return tree
+
+
+def _init_looped_params(key, cfg: TransformerConfig, n_stages: int = 1):
+    """The MHA family looped (``loop_passes``) or with sandwich norms,
+    served only: ``layers`` is a LIST of one dict a layer from the
+    start (``ln1``, ``ln1_post``, ``ln2``, ``ln2_post``, ``wq [H, E,
+    d]`` and ``wk`` / ``wv [H_kv, E, d]`` head-major (:func:`_heads`),
+    ``wo [H, d, E]`` and ONE dense SwiGLU ``w_in`` / ``w_gate [E, F]``,
+    ``w_out [F, E]``, no router), so that the engine and the
+    benchmark's reference read the same arrays and the weights are on
+    the chip once.  ``w_exit [E]`` and
+    ``b_exit [1]`` are the exit gate after each pass; the serving
+    programs run every pass and do not compute it."""
+    assert n_stages == 1 and cfg.n_experts == 1
+    e, h, h_kv, d, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                        cfg.d_ff)
+    keys = iter(jax.random.split(key, 7 * cfg.n_layers + 3))
+
+    def norm(*shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * 0.02).astype(cfg.jdtype)
+
+    def ones():
+        return jnp.ones((e,), cfg.jdtype)
+
+    def layer():
+        p = {"ln1": ones(), "ln2": ones(), "wq": norm(h, e, d),
+             "wk": norm(h_kv, e, d), "wv": norm(h_kv, e, d),
+             "wo": norm(h, d, e), "w_in": norm(e, f), "w_gate": norm(e, f),
+             "w_out": norm(f, e)}
+        if cfg.sandwich_norm:
+            p.update(ln1_post=ones(), ln2_post=ones())
+        return p
+
+    return {"embed": norm(cfg.vocab, e), "unembed": norm(e, cfg.vocab),
+            "ln_f": ones(), "layers": [layer() for _ in range(cfg.n_layers)],
+            "w_exit": norm(e), "b_exit": jnp.zeros((1,), jnp.float32)}
 
 
 def _init_latent_params(key, cfg: TransformerConfig, n_stages: int = 1):
@@ -1100,8 +1156,14 @@ def _rotates(kind: str, cfg: TransformerConfig) -> bool:
 
 
 def _mha_ffn(xn, p, first_group, cfg: TransformerConfig, valid, counts):
-    """The layer's FFN on normed activations: the trained mixtures, or
-    the held experts (their routing counts appended to ``counts``)."""
+    """The layer's FFN on normed activations: the trained mixtures, the
+    held experts (their routing counts appended to ``counts``), or a
+    looped model's one SwiGLU."""
+    if cfg.looped:
+        return swiglu_ffn(xn.astype(cfg.jdtype), p["w_in"], p["w_gate"],
+                          p["w_out"], ShardAxes(),
+                          out_dtype=_into_stream(cfg).get(
+                              "preferred_element_type"))
     if cfg.moe_router != "sigmoid":
         return _moe_ffn(xn.astype(cfg.jdtype), p, ShardAxes(), cfg)
     with jax.named_scope("moe"):
@@ -1117,8 +1179,26 @@ def _ffn_half(x, xn, attn, p, first_group, cfg, valid, counts):
     normed input ``xn`` and both addends land together."""
     if cfg.parallel_block:
         return x + attn + _mha_ffn(xn, p, first_group, cfg, valid, counts)
-    return x + _mha_ffn(_norm(x, p["ln2"], cfg), p, first_group, cfg, valid,
-                        counts)
+    return x + _post_norm(_mha_ffn(_norm(x, p["ln2"], cfg), p, first_group,
+                                   cfg, valid, counts), p, "ln2_post", cfg)
+
+
+def _post_norm(y, p, name: str, cfg: TransformerConfig):
+    """A sublayer's addend as it joins the stream: under sandwich norms
+    normed by the layer's ``p[name]``, else as it is."""
+    return _norm(y, p[name], cfg) if cfg.sandwich_norm else y
+
+
+def _heads(xa, w, cfg: TransformerConfig):
+    """``xa [B, T, E]`` projected to heads ``[B, T, H, d]`` by ``w``,
+    which a looped model stores head-major ``[H, E, d]`` and every other
+    tree ``[E, H, d]``: inside the pass loop the chip's compiler lays an
+    ``[E, H, d]`` matrix out head-major anyway, in a copy of every
+    layer's q, k and v matrices made before the loop on every call (1.2
+    GB at Ouro-2.6B's widths, by the compile for a described v5e)."""
+    if cfg.looped:
+        return jnp.einsum("bte,hed->bthd", xa, w)
+    return jnp.einsum("bte,ehd->bthd", xa, w)
 
 
 #: the named scope of a layer kind's attention half, projections
@@ -1147,8 +1227,8 @@ def _mha_prefill_layer(x, p, first_group, kind, cfg: TransformerConfig,
     xn = _norm(x, p["ln1"], cfg)
     with _scope(scope):
         xa = xn.astype(cfg.jdtype)  # the products' operand
-        k = jnp.einsum("bte,ehd->bthd", xa, p["wk"])
-        v = jnp.einsum("bte,ehd->bthd", xa, p["wv"])
+        k = _heads(xa, p["wk"], cfg)
+        v = _heads(xa, p["wv"], cfg)
         if _rotates(kind, cfg):
             k = rope(k, jnp.arange(t), cfg.rope_theta)
 
@@ -1156,7 +1236,7 @@ def _mha_prefill_layer(x, p, first_group, kind, cfg: TransformerConfig,
         """Rows [first, first + rows) of the prompt through the layer."""
         layer_counts = []
         with _scope(scope):
-            q = jnp.einsum("bte,ehd->bthd", xn.astype(cfg.jdtype), p["wq"])
+            q = _heads(xn.astype(cfg.jdtype), p["wq"], cfg)
             if _rotates(kind, cfg):
                 q = rope(q, first + jnp.arange(rows), cfg.rope_theta)
             # the scope states the call's T, window and chunks: the
@@ -1166,8 +1246,9 @@ def _mha_prefill_layer(x, p, first_group, kind, cfg: TransformerConfig,
                 o = _causal_attention(
                     q, k, v, span=span,
                     q_offset=None if n_chunks == 1 else first)
-            attn = jnp.einsum("bthd,hde->bte", o, p["wo"],
-                              **_into_stream(cfg))
+            attn = _post_norm(jnp.einsum("bthd,hde->bte", o, p["wo"],
+                                         **_into_stream(cfg)),
+                              p, "ln1_post", cfg)
             if not cfg.parallel_block:
                 x, attn = x + attn, None
         return _ffn_half(x, xn, attn, p, first_group, cfg, valid,
@@ -1199,7 +1280,9 @@ def _prefill_trunk(params, ids, cfg: TransformerConfig, valid=None):
     returns ``(x [B, T, E], ks, vs, counts)``: per layer the keys and
     values ``[B, T, H_kv, hd]`` as the cache holds them (post-rope), and
     the held-expert layers' routing counts (none for the trained
-    mixtures) — shared by the heads below."""
+    mixtures) — shared by the heads below.  One pass: a looped model's
+    prompt goes through :func:`_looped_prefill`."""
+    assert cfg.loop_passes == 1, "a looped model prefills into its pools"
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
         cfg.stream_dtype)
     ks, vs, counts = [], [], []
@@ -1209,6 +1292,48 @@ def _prefill_trunk(params, ids, cfg: TransformerConfig, valid=None):
         ks.append(k)
         vs.append(v)
     return _norm(x, params["ln_f"], cfg), ks, vs, counts
+
+
+def _loop_passes(x, carry, one_pass, params, cfg: TransformerConfig):
+    """A looped model's passes: ``one_pass(first, x, carry) -> (x,
+    carry)`` runs the ``n_layers`` body with layer l at cache layer
+    ``first + l``, and the final norm closes each pass.  One
+    ``lax.fori_loop``, so the body is traced once however many passes
+    run, and ``carry`` (the donated pools) is updated in place."""
+    assert cfg.family == "mha" and cfg.moe_router != "sigmoid", \
+        "a looped model is dense MHA"
+
+    def body(t, state):
+        x, carry = state
+        with jax.named_scope("loop_pass"):
+            x, carry = one_pass(t * cfg.n_layers, x, carry)
+            return _norm(x, params["ln_f"], cfg), carry
+
+    return lax.fori_loop(0, cfg.loop_passes, body, (x, carry))
+
+
+def _looped_prefill(params, ids, k_pool, v_pool, block_ids,
+                    cfg: TransformerConfig):
+    """The prefill trunk of a looped model: ``(x [B, T, E], k_pool,
+    v_pool)`` after every pass, layer l of pass t having written the
+    prompt's K/V into cache layer ``t * n_layers + l`` at
+    ``block_ids``; ``x`` is the last pass's final norm."""
+    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
+        cfg.stream_dtype)
+
+    def one_pass(first, x, pools):
+        k_pool, v_pool = pools
+        for i, (kind, p, first_group) in enumerate(_mha_layers(params,
+                                                              cfg)):
+            x, k, v = _mha_prefill_layer(x, p, first_group, kind, cfg, None,
+                                         [])
+            k_pool = k_pool.at[first + i, block_ids].set(_paged(k, k_pool))
+            v_pool = v_pool.at[first + i, block_ids].set(_paged(v, v_pool))
+        return x, (k_pool, v_pool)
+
+    x, (k_pool, v_pool) = _loop_passes(x, (k_pool, v_pool), one_pass,
+                                       params, cfg)
+    return x, k_pool, v_pool
 
 
 def _unembed(params, x, cfg: TransformerConfig):
@@ -1279,8 +1404,14 @@ def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
     reads a slot at or past a row's length, and decode overwrites each
     before the length passes it (the contract of the decode window's
     uncommitted slots).  The caller donates the pools, so the scatter
-    is in place and no K/V leaves the device.
+    is in place and no K/V leaves the device.  A looped model writes
+    each pass's K/V into that pass's cache layers as it goes
+    (:func:`_looped_prefill`).
     """
+    if cfg.loop_passes > 1:
+        x, k_pool, v_pool = _looped_prefill(params, ids, k_pool, v_pool,
+                                            block_ids, cfg)
+        return _logits_at(params, x, last_index, cfg), k_pool, v_pool
     x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
     k_pool = k_pool.at[:, block_ids].set(_paged(jnp.stack(ks), k_pool))
     v_pool = v_pool.at[:, block_ids].set(_paged(jnp.stack(vs), v_pool))
@@ -1346,7 +1477,9 @@ def _paged_decode(params, ids, positions, pools, tables, lengths,
     layers, its blocks, block_size, H_kv, hd]`` and ``tables[kind]``
     its block tables ``[B, W]`` (a sliding kind's the ring table, W
     fixed by the window).  Returns ``(logits [B, S, V], pools, counts)``
-    (see :func:`forward_decode_paged`)."""
+    (see :func:`forward_decode_paged`).  A looped model runs the layers
+    once a pass (:func:`_loop_passes`), pass t's layer l reading and
+    writing cache layer ``t * n_layers + l``."""
     from ..ops import paged_attention as _paged_attn
 
     b, s_w = ids.shape
@@ -1366,44 +1499,59 @@ def _paged_decode(params, ids, positions, pools, tables, lengths,
     valid = jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
         cfg.stream_dtype)
-    at = dict.fromkeys(pools, 0)
     counts = []
-    for kind, p, first_group in _mha_layers(params, cfg):
-        li = at[kind]
-        at[kind] += 1
-        k_pool, v_pool = pools[kind]
-        wb, ws = where[kind]
-        with jax.named_scope(_ATTN_SCOPE.get(kind, "attention")):
-            xn = _norm(x, p["ln1"], cfg)
-            xa = xn.astype(cfg.jdtype)  # the products' operand
-            q = jnp.einsum("bte,ehd->bthd", xa, p["wq"])
-            k = jnp.einsum("bte,ehd->bthd", xa, p["wk"])
-            v = jnp.einsum("bte,ehd->bthd", xa, p["wv"])
-            if _rotates(kind, cfg):
-                q = _rope_window(q, positions, cfg.rope_theta)
-                k = _rope_window(k, positions, cfg.rope_theta)
-            k_pool = k_pool.at[li, wb, ws].set(
-                k.astype(k_pool.dtype), mode="drop")
-            v_pool = v_pool.at[li, wb, ws].set(
-                v.astype(v_pool.dtype), mode="drop")
-            pools[kind] = (k_pool, v_pool)
-            # the layers' pools as one run of pages, a free view:
-            # a per-layer slice of a pool would be copied for the
-            # kernel
-            o = _paged_attn.paged_attention(
-                q, k_pool.reshape((-1,) + k_pool.shape[2:]),
-                v_pool.reshape((-1,) + v_pool.shape[2:]),
-                tables[kind] + li * k_pool.shape[1], lengths,
-                span=cfg.sliding_window if kind == "sliding" else 0)
-            attn = jnp.einsum("bthd,hde->bte", o, p["wo"],
-                              **_into_stream(cfg))
-            if not cfg.parallel_block:
-                x, attn = x + attn, None
-        # the held experts open their own scope, "moe"
-        with _scope(None if cfg.moe_router == "sigmoid" else "mlp"):
-            x = _ffn_half(x, xn, attn, p, first_group, cfg, valid, counts)
+
+    def one_pass(first, x, pools):
+        """The layers once, a kind's layer l at its cache layer first +
+        l (``first`` a pass's offset into a looped model's pools)."""
+        pools = dict(pools)
+        at = dict.fromkeys(pools, first)
+        for kind, p, first_group in _mha_layers(params, cfg):
+            li = at[kind]
+            at[kind] += 1
+            k_pool, v_pool = pools[kind]
+            wb, ws = where[kind]
+            with jax.named_scope(_ATTN_SCOPE.get(kind, "attention")):
+                xn = _norm(x, p["ln1"], cfg)
+                xa = xn.astype(cfg.jdtype)  # the products' operand
+                q = _heads(xa, p["wq"], cfg)
+                k = _heads(xa, p["wk"], cfg)
+                v = _heads(xa, p["wv"], cfg)
+                if _rotates(kind, cfg):
+                    q = _rope_window(q, positions, cfg.rope_theta)
+                    k = _rope_window(k, positions, cfg.rope_theta)
+                k_pool = k_pool.at[li, wb, ws].set(
+                    k.astype(k_pool.dtype), mode="drop")
+                v_pool = v_pool.at[li, wb, ws].set(
+                    v.astype(v_pool.dtype), mode="drop")
+                pools[kind] = (k_pool, v_pool)
+                # the layers' pools as one run of pages, a free view:
+                # a per-layer slice of a pool would be copied for the
+                # kernel
+                o = _paged_attn.paged_attention(
+                    q, k_pool.reshape((-1,) + k_pool.shape[2:]),
+                    v_pool.reshape((-1,) + v_pool.shape[2:]),
+                    tables[kind] + li * k_pool.shape[1], lengths,
+                    span=cfg.sliding_window if kind == "sliding" else 0)
+                attn = _post_norm(jnp.einsum("bthd,hde->bte", o, p["wo"],
+                                             **_into_stream(cfg)),
+                                  p, "ln1_post", cfg)
+                if not cfg.parallel_block:
+                    x, attn = x + attn, None
+            # the held experts open their own scope, "moe"
+            with _scope(None if cfg.moe_router == "sigmoid" else "mlp"):
+                x = _ffn_half(x, xn, attn, p, first_group, cfg, valid,
+                              counts)
+        return x, pools
+
+    if cfg.loop_passes > 1:
+        x, pools = _loop_passes(x, pools, one_pass, params, cfg)
+    else:
+        x, pools = one_pass(0, x, pools)
     with jax.named_scope("unembed"):
-        logits = _unembed(params, _norm(x, params["ln_f"], cfg), cfg)
+        if cfg.loop_passes == 1:  # a looped model's passes end normed
+            x = _norm(x, params["ln_f"], cfg)
+        logits = _unembed(params, x, cfg)
     return logits, pools, counts
 
 

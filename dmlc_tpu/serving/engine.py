@@ -131,7 +131,8 @@ _ZEROED_COUNTERS = tuple(
     f + kind for f in _SPAN_FAMILIES for kind in ("_secs", "_count")
 ) + ("decode_d2h_bytes", "prefill_d2h_bytes", "kv_upload_bytes",
      "queue_wait_secs", "queue_wait_count", "latency_secs",
-     "decode_steps_overlapped", "lookahead_discarded_tokens")
+     "decode_steps_overlapped", "lookahead_discarded_tokens",
+     "loop_kv_read_bytes")
 
 
 class _DedupeTable:
@@ -465,6 +466,14 @@ class InferenceEngine:
             shape, dt = self.cache.state_shapes[0]
             self._state_rw_bytes = (
                 2 * int(np.prod(shape)) // shape[1] * dt.itemsize)
+        # the K and V a looped model's decode step reads for an attended
+        # token, in every cache layer of all passes (0 for any other
+        # model)
+        self._loop_kv_token_bytes = 0
+        if cfg.loop_passes > 1:
+            self._loop_kv_token_bytes = (
+                2 * cfg.loop_passes * cfg.n_layers * cfg.kv_heads
+                * cfg.head_dim * np.dtype(cfg.dtype).itemsize)
         self._prefill, self._decode = _jitted_programs(cfg.family)
         self._stop = threading.Event()
         self._draining = threading.Event()
@@ -1412,6 +1421,11 @@ class InferenceEngine:
             telemetry.inc("serving", "state_slot_steps", b)
             telemetry.inc("serving", self._state_bytes_counter,
                           self._state_rw_bytes * b)
+        if self._loop_kv_token_bytes:
+            # window token s of a row at length n attends n + s + 1 keys
+            telemetry.inc("serving", "loop_kv_read_bytes",
+                          self._loop_kv_token_bytes * float(
+                              s_w * base_lens.sum() + b * s_w * (s_w + 1) // 2))
         if self.cache.ring_blocks:
             # a row at length n attends its n keys and the token itself
             keys = base_lens + 1

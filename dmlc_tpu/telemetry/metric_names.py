@@ -457,6 +457,9 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_state_slot_steps",
     "dmlc_serving_kda_state_rw_bytes",
     "dmlc_serving_ssm_state_rw_bytes",
+    # a looped model's decode steps: the K/V bytes of every attended
+    # token in all passes' cache layers (registered at 0 for every model)
+    "dmlc_serving_loop_kv_read_bytes",
     "dmlc_serving_state_slots_in_use",
     "dmlc_serving_state_slots_total",
     # sliding-window layers' own pool and block table (the widened MHA
