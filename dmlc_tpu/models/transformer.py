@@ -1432,8 +1432,8 @@ def forward_prefill_paged_swa(params, ids, last_index, k_pool, v_pool,
     no later query's window reaches further back.  All four pools are
     donated and updated in place.  Returns ``(logits [1, V], k_pool,
     v_pool, ks_pool, vs_pool[, moe])``, ``moe [n_layers, n_experts +
-    1]`` the routing counts of the prompt's real tokens where the
-    experts are held ones."""
+    2]`` the routing counts of the prompt's real tokens where the
+    experts are held ones (:func:`_moe_held_ffn`)."""
     t = ids.shape[1]
     valid = jnp.arange(t)[None] <= last_index[:, None]
     x, ks, vs, counts = _prefill_trunk(params, ids, cfg, valid)
@@ -1674,11 +1674,52 @@ def picking_decode(forward):
 # holds a share.  Paged path only (the device pool is the cache).
 # ---------------------------------------------------------------------------
 
-#: rows of the sorted (token, pick) list one grouped product takes.  At
-#: 512 rows an expert's three [E, F] matrices are read once per 512 x
-#: E x F x 6 FLOPs, past the v5e's ridge; XLA's grouped matmul tiles
-#: its rows by 512 too
-MOE_TILE = 512
+#: bytes one trip of the held experts' loop may hold in temporaries (the
+#: gathered rows, their hidden activations, the product and its float32
+#: addend: :func:`moe_tile`).  A compile for a described v5e of A.X-K1's
+#: 16k prefill held 0.42 GB more temporaries at trips of 1,445 rows or
+#: more, and none more at 968 (its row is 68 KiB)
+MOE_TRIP_BYTES = 64 << 20
+
+#: held pairs a layer expects per held expert below which its pairs are
+#: sparse over its groups (:func:`moe_tile`)
+MOE_SPARSE_PAIRS = 8
+
+
+def moe_tile(n: int, cfg: TransformerConfig) -> int:
+    """Rows of the sorted (token, pick) list one trip of
+    :func:`_moe_held_ffn` takes, for a program of ``n`` tokens.
+
+    XLA's grouped product (``ragged-dot``) pays for the groups its rows
+    visit, and a call walks the whole stack.  Timed alone on a v5e
+    (``scripts/time_ragged_dot.py``, Nemotron 3's stack of 640 groups):
+    where a layer's pairs are dense over its held experts (a prefill),
+    fewer and larger calls cost as much or more (a 1k prompt's 5,632
+    pairs: 7.6 ms a layer in 512-row trips, 9.3 ms in one), so a trip
+    takes 512 rows; where they are sparse, fewer than
+    :data:`MOE_SPARSE_PAIRS` a held expert (a decode step), a 512-row
+    trip spans most of the groups and the next walks them again (704
+    pairs: 6.8 ms in two trips, 5.1 ms in one), so a trip holds twice the
+    pairs the layer expects (``n`` x topk x the held share of the
+    router; the routing's skew makes them more or less).  Never more
+    than can land here, nor than :data:`MOE_TRIP_BYTES` over what a row
+    of the trip holds.  What one trip does not hold takes another: the
+    trip count follows the pairs, so the layer stays dropless whatever
+    the routing."""
+    k, x_l = cfg.moe_topk, cfg.n_experts
+    expected = -(-n * k * x_l // (cfg.moe_n_routed or x_l))
+    tile = 512
+    if expected < MOE_SPARSE_PAIRS * x_l:
+        tile = max(tile, 2 * expected)
+    width = cfg.moe_latent or cfg.d_model
+    mats = 2 if cfg.moe_act == "relu2" else 3
+    item = jnp.dtype(cfg.jdtype).itemsize
+    # the gathered row and its hidden activations in the weights' type,
+    # the product in the stream's, its weighted addend in float32
+    row = item * (width + mats * cfg.moe_d_ff) + width * (
+        jnp.dtype(cfg.stream_dtype).itemsize + 4)
+    tile = min(tile, n * min(k, x_l), MOE_TRIP_BYTES // row)
+    return max(-(-tile // 8) * 8, 8)
 
 
 def _yarn_inv_freq(cfg: TransformerConfig):
@@ -1812,9 +1853,9 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     ``moe_routed_scale * s_i / (sum of the picked s + 1e-20)`` (the
     normaliser is over all picks, held or not).  The (token, pick)
     pairs whose expert is held here, [moe_held_start, + n_experts), are
-    sorted by expert; the sorted list is walked in tiles of
-    :data:`MOE_TILE` rows under a trip count that follows how many
-    pairs landed here, each tile one grouped product per matrix
+    sorted by expert; the sorted list is walked in trips of
+    :func:`moe_tile` rows under a trip count that follows how many
+    pairs landed here, each trip one grouped product per matrix
     (``lax.ragged_dot``), scattered back onto its tokens.  No capacity,
     no bound that can overflow: every held pair is computed whatever
     the routing, and what the absent experts would add is left out.
@@ -1840,9 +1881,11 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     top-k: the picks are the largest ``s + b``, their weights come from
     ``s``.
 
-    Returns ``(y [B, T, E], counts [n_experts + 1] int32)``: pairs per
+    Returns ``(y [B, T, E], counts [n_experts + 2] int32)``: pairs per
     held expert, then all pairs routed anywhere, both over the tokens
-    ``valid`` [B, T] marks (default all)."""
+    ``valid`` [B, T] marks (default all), then the grouped products
+    invoked (trips x matrices: the trips walk every held pair, pad
+    tokens' too)."""
     b, t, e = x.shape
     n, k, x_l = b * t, cfg.moe_topk, cfg.n_experts
     # float32 from the operands as stored: products of bf16 values are
@@ -1884,7 +1927,8 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     sizes = per_expert(key)
     ends = jnp.cumsum(sizes)
     n_held = ends[-1]
-    tile = min(MOE_TILE, -(-n * k // 8) * 8)
+    tile = moe_tile(n, cfg)
+    trips = -(-n_held // tile)
     pad = -(n * k) % tile
     token = jnp.pad(order // k, (0, pad))                        # [pairs]
     w_sorted = jnp.pad(weight.reshape(-1)[order], (0, pad))
@@ -1913,7 +1957,7 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
         w = lax.dynamic_slice_in_dim(w_sorted, lo, tile)[:, None]
         return y.at[rows].add(jnp.where(held, out.astype(jnp.float32) * w, 0.0))
 
-    y = lax.fori_loop(0, -(-n_held // tile), one_tile,
+    y = lax.fori_loop(0, trips, one_tile,
                       jnp.zeros((n, xe.shape[-1]), jnp.float32))
     y = y.astype(x.dtype)
     if cfg.moe_latent:
@@ -1939,7 +1983,8 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
     if valid is not None:
         sizes = per_expert(jnp.where(valid.reshape(n, 1), key, x_l))
         routed = jnp.sum(valid.astype(jnp.int32)) * k
-    return y, jnp.concatenate([sizes, routed.reshape(1)])
+    calls = trips * (2 if relu2 else 3)
+    return y, jnp.concatenate([sizes, routed.reshape(1), calls.reshape(1)])
 
 
 _EXPERT_STACKS = ("w_in", "w_gate", "w_out")
@@ -1995,7 +2040,7 @@ def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
     slot]: ``TransformerConfig.kv_pool_shapes``); block_ids [T /
     block_size].  Attention runs up-projected at qk / v width (the
     flash kernel); only ``[rms(c_kv) | rope(k_pe)]`` is cached.  Returns
-    ``(logits [1, V], pool, moe [n_moe_layers, n_experts + 1])``: the
+    ``(logits [1, V], pool, moe [n_moe_layers, n_experts + 2])``: the
     pool donated and updated in place, and the routing counts of the
     prompt's real tokens (see :func:`_moe_held_ffn`)."""
     _, t = ids.shape
@@ -2461,10 +2506,10 @@ def _pattern_qkv(xn, p, cfg: TransformerConfig):
 
 
 def _stacked(counts, cfg: TransformerConfig):
-    """The expert layers' routing counts ``[n, n_experts + 1]``, none
+    """The expert layers' routing counts ``[n, n_experts + 2]``, none
     under a pattern cut before its first expert layer."""
     return jnp.stack(counts) if counts else jnp.zeros(
-        (0, cfg.n_experts + 1), jnp.int32)
+        (0, cfg.n_experts + 2), jnp.int32)
 
 
 def _write_pages(pool, layer: int, block_ids, a):

@@ -132,7 +132,7 @@ _ZEROED_COUNTERS = tuple(
 ) + ("decode_d2h_bytes", "prefill_d2h_bytes", "kv_upload_bytes",
      "queue_wait_secs", "queue_wait_count", "latency_secs",
      "decode_steps_overlapped", "lookahead_discarded_tokens",
-     "loop_kv_read_bytes")
+     "loop_kv_read_bytes", "moe_grouped_calls")
 
 
 class _DedupeTable:
@@ -1000,11 +1000,13 @@ class InferenceEngine:
 
     def _count_moe(self, moe) -> None:
         """Add one program call's routing counts ``[n_moe_layers,
-        n_experts + 1]`` (pairs per held expert, then pairs routed
-        anywhere) to the ``serving.moe_*`` counters."""
+        n_experts + 2]`` (pairs per held expert, pairs routed anywhere,
+        grouped products invoked) to the ``serving.moe_*`` counters."""
         for counts in moe:
-            held = counts[:, :-1]
+            held = counts[:, :-2]
             telemetry.inc("serving", "moe_pairs_total",
+                          float(counts[:, -2].sum()))
+            telemetry.inc("serving", "moe_grouped_calls",
                           float(counts[:, -1].sum()))
             telemetry.inc("serving", "moe_pairs_held", float(held.sum()))
             telemetry.inc("serving", "moe_expert_load_max",

@@ -450,6 +450,9 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_moe_pairs_held",
     "dmlc_serving_moe_expert_load_max",
     "dmlc_serving_moe_expert_load_mean",
+    # the held experts' grouped products invoked: trips x matrices,
+    # summed over the expert layers (registered at 0 for every model)
+    "dmlc_serving_moe_grouped_calls",
     # recurrent state in the cache manager (the families with KDA or
     # Mamba-2 layers): slots handed out, live slots summed over decode
     # steps, and the state bytes those steps read and wrote by kind

@@ -22,6 +22,7 @@ from dmlc_tpu.ops import flash_attention as flash
 from dmlc_tpu.ops import paged_attention as paged
 from dmlc_tpu.serving import InferenceEngine
 from dmlc_tpu.serving.kv_cache import PagedKVCache
+from held_experts import check_case
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 4   # block size of the small pools
@@ -159,7 +160,7 @@ def test_prefill_then_decode_through_the_cache_agree_with_the_reference(
     cache = new_cache(cfg)
     logits, moe = _prefill(cfg, params, cache, 1, ids, n_prompt)
     np.testing.assert_allclose(logits, want[n_prompt - 1], atol=5e-4)
-    assert moe.shape == (4, 5) and (moe[:, -1] == n_prompt * 2).all()
+    assert moe.shape == (4, 6) and (moe[:, -2] == n_prompt * 2).all()
     for at in range(n_prompt, 52):
         logits = _decode(cfg, params, cache, [1], [ids[at]])
         np.testing.assert_allclose(logits[0], want[at], atol=5e-4,
@@ -178,7 +179,7 @@ def test_a_prompt_walked_in_chunks_of_rows_is_the_same_prompt(
     chunked, moe = _prefill(cfg, params, cache, 1, ids, 48)
     np.testing.assert_allclose(chunked, whole, atol=2e-5)
     np.testing.assert_allclose(chunked, want[47], atol=5e-4)
-    assert (moe[:, -1] == 48 * 2).all()
+    assert (moe[:, -2] == 48 * 2).all()
     # and the cache it left decodes on
     logits = _decode(cfg, params, cache, [1], [ids[48]])
     np.testing.assert_allclose(logits[0], want[48], atol=5e-4)
@@ -265,10 +266,39 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
                           for n in ("w_in", "w_gate", "w_out")})
         y, counts = tfm._moe_held_ffn(x, mine, cfg)
         total = total + (y - shared)
-        pairs += int(np.asarray(counts)[:-1].sum())
+        pairs += int(np.asarray(counts)[:-2].sum())
     np.testing.assert_allclose(np.asarray(total[0]), np.asarray(want),
                                atol=5e-5)
     assert pairs == 24 * whole.moe_topk
+
+
+@pytest.mark.parametrize("case", ["rule", "overflow", "last_group",
+                                  "float32_stream"])
+def test_the_held_experts_under_the_tile_rule(case, monkeypatch):
+    """SwiGLU experts through the trip loop: the rule's one trip and
+    forced 8-row trips (also with bf16 weights under a float32 stream,
+    against the float32 reference within 0.05), routing skewed onto the
+    held experts past the rule's tile (4 of 32 routed held: 512 tokens
+    put 1,024 pairs on a tile of 512), and the layer's groups last in a
+    junk stack: the reference's layer every time."""
+    over = dict(n_layers=1, layer_group_size=1)
+    if case == "overflow":
+        over.update(moe_n_routed=32)
+    if case == "float32_stream":
+        over.update(dtype="bfloat16", residual_dtype="float32")
+    cfg = small(**over)
+    params = weights(cfg, seed=5)
+    p = {**params["layers"][0], **params["experts"]}
+    x = jax.random.normal(jax.random.PRNGKey(6),
+                          (1, 512 if case == "overflow" else 24, 64))
+
+    def reference(x, p, cfg):
+        return ref._ffn(x[0], p, p, 0, cfg.n_experts, lambda a: a,
+                        spec_of(cfg))
+
+    check_case("rule" if case == "float32_stream" else case, p, x, cfg,
+               reference, monkeypatch,
+               atol=5e-2 if case == "float32_stream" else 5e-5)
 
 
 # ---- the kernels against their lax twins, interpreted ------------------

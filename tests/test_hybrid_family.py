@@ -20,6 +20,7 @@ from dmlc_tpu.ops import dispatch
 from dmlc_tpu.ops import kda
 from dmlc_tpu.serving import InferenceEngine
 from dmlc_tpu.serving.kv_cache import PagedKVCache
+from held_experts import check_case
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 8  # block size of the small pools
@@ -197,8 +198,8 @@ def test_prefill_logits_against_the_reference(model):
             a.shape[0], a.shape[1], -1).sum(axis=(0, 2)))
         assert used.tolist() == [2]
     moe = np.asarray(moe)
-    assert moe.shape == (6, cfg.n_experts + 1)
-    assert (moe[:, -1] == 29 * cfg.moe_topk).all()  # pad tokens left out
+    assert moe.shape == (6, cfg.n_experts + 2)
+    assert (moe[:, -2] == 29 * cfg.moe_topk).all()  # pad tokens left out
 
 
 @pytest.mark.parametrize("others", [False, True], ids=["alone", "in_a_batch"])
@@ -238,7 +239,7 @@ def test_prefill_then_decode_through_cache_and_slots(model, others):
         if others:
             np.testing.assert_allclose(np.asarray(logits[1, 0]), want[1][t],
                                        atol=1e-4)
-        assert (np.asarray(moe)[:, -1] == (1 + others) * cfg.moe_topk).all()
+        assert (np.asarray(moe)[:, -2] == (1 + others) * cfg.moe_topk).all()
     # dead rows carry slot 0: without a second sequence it stays zero
     if not others:
         assert not np.asarray(cache[1][:, 0]).any()
@@ -280,6 +281,20 @@ def test_group_limited_selection_against_the_reference():
             -1, keepdims=True)), np.sort(np.asarray(want_w)), rtol=1e-6)
 
 
+@pytest.mark.parametrize("case", ["rule", "overflow", "last_group"])
+def test_the_held_experts_under_the_tile_rule(case, monkeypatch):
+    """Group-limited top-k through the trip loop: the rule's one trip
+    and forced 8-row trips, routing skewed onto the held routing group
+    past the rule's tile (256 tokens put 1,024 pairs on a tile of 512),
+    and the layer's groups last in a junk stack: the reference's expert
+    layer every time."""
+    cfg = small()
+    p, x = _one_expert_layer(cfg, seed=7)
+    if case == "overflow":
+        x = jax.random.normal(jax.random.PRNGKey(8), (1, 256, cfg.d_model))
+    check_case(case, p, x, cfg, _reference_layer, monkeypatch, atol=5e-5)
+
+
 def test_the_shares_add_up():
     """The partial results of all four shares of one layer (a routing
     group each: experts [0,4), [4,8), [8,12), [12,16) of 16), the
@@ -301,7 +316,7 @@ def test_the_shares_add_up():
             np.asarray(y[0]), np.asarray(_reference_layer(x, mine, cfg)),
             atol=2e-5)
         total = total + (y - shared)
-        pairs += int(np.asarray(counts)[:-1].sum())
+        pairs += int(np.asarray(counts)[:-2].sum())
     np.testing.assert_allclose(np.asarray(total[0]), np.asarray(want),
                                atol=5e-5)
     assert pairs == 24 * whole.moe_topk

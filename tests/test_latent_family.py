@@ -19,6 +19,7 @@ from dmlc_tpu.ops import flash_attention as flash
 from dmlc_tpu.ops import paged_attention as paged
 from dmlc_tpu.parallel.ring_attention import ring_attention_reference
 from dmlc_tpu.serving import InferenceEngine
+from held_experts import check_case
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 8  # block size of the small pools
@@ -102,9 +103,9 @@ def test_prefill_logits_against_the_reference(model):
     assert written.tolist() == blocks.tolist()
     # the counts are of the 29 real tokens, pad tokens left out
     moe = np.asarray(moe)
-    assert moe.shape == (2, cfg.n_experts + 1)
-    assert (moe[:, -1] == 29 * cfg.moe_topk).all()
-    assert (moe[:, :-1].sum(axis=1) <= 29 * cfg.moe_topk).all()
+    assert moe.shape == (2, cfg.n_experts + 2)
+    assert (moe[:, -2] == 29 * cfg.moe_topk).all()
+    assert (moe[:, :-2].sum(axis=1) <= 29 * cfg.moe_topk).all()
 
 
 def test_prefill_then_decode_through_the_latent_paged_cache(model):
@@ -127,7 +128,7 @@ def test_prefill_then_decode_through_the_latent_paged_cache(model):
             np.array([t, 0], np.int32), cfg)
         np.testing.assert_allclose(np.asarray(logits[0, 0]), want[t],
                                    atol=2e-5)
-        assert (np.asarray(moe)[:, -1] == cfg.moe_topk).all()  # one live row
+        assert (np.asarray(moe)[:, -2] == cfg.moe_topk).all()  # one live row
     # the dead row (table all zeros) wrote nothing into block 0
     np.testing.assert_array_equal(np.asarray(pool[:, 0]), block0)
 
@@ -241,11 +242,11 @@ def test_expert_layer_is_dropless(case):
     np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want),
                                atol=2e-5, rtol=1e-5)
     counts = np.asarray(counts)
-    assert counts[-1] == 24 * cfg.moe_topk
+    assert counts[-2] == 24 * cfg.moe_topk
     if case == "all_on_one":
-        assert counts[:-1].tolist() == [0, 24, 0, 0]
+        assert counts[:-2].tolist() == [0, 24, 0, 0]
     if case == "none_held":
-        assert counts[:-1].sum() == 0
+        assert counts[:-2].sum() == 0
         shared = tfm.swiglu_ffn(x, p["s_in"], p["s_gate"], p["s_out"],
                                 tfm.ShardAxes())
         np.testing.assert_allclose(np.asarray(y), np.asarray(shared),
@@ -253,14 +254,97 @@ def test_expert_layer_is_dropless(case):
 
 
 def test_expert_layer_walks_more_than_one_tile(monkeypatch):
-    """Tiles of 8 rows over 96 pairs: the data-dependent trip count and
-    the groups cut at tile edges."""
+    """Tiles of 8 rows over 96 pairs (a trip budget of one byte: the
+    rule's floor): the data-dependent trip count and the groups cut at
+    tile edges."""
     cfg = small()
     p, x = _one_expert_layer(cfg, seed=5)
-    want, _ = tfm._moe_held_ffn(x, p, cfg)
-    monkeypatch.setattr(tfm, "MOE_TILE", 8)
-    got, _ = tfm._moe_held_ffn(x, p, cfg)
+    want, whole = tfm._moe_held_ffn(x, p, cfg)
+    monkeypatch.setattr(tfm, "MOE_TRIP_BYTES", 1)
+    assert tfm.moe_tile(24, cfg) == 8
+    got, counts = tfm._moe_held_ffn(x, p, cfg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # one trip of 96 rows before, a trip of 8 rows per 8 held pairs now
+    held = int(np.asarray(counts)[:-2].sum())
+    assert np.asarray(whole)[-1] == 3
+    assert np.asarray(counts)[-1] == 3 * -(-held // 8)
+
+
+@pytest.mark.parametrize("case", ["rule", "overflow", "last_group"])
+def test_the_held_experts_under_the_tile_rule(case, monkeypatch):
+    """The rule's one trip and forced 8-row trips, routing skewed onto
+    the held experts past the rule's tile (256 tokens put 1,024 pairs
+    on a tile of 512: two trips), and the layer's groups last in a stack
+    of three layers' with junk in the others: the reference's expert
+    layer every time."""
+    cfg = small()
+    p, x = _one_expert_layer(cfg, seed=7)
+    if case == "overflow":
+        x = jax.random.normal(jax.random.PRNGKey(8), (1, 256, cfg.d_model))
+    check_case(case, p, x, cfg,
+               lambda x, p, cfg: _reference_layer(x, p, cfg, spec_of(cfg)),
+               monkeypatch, atol=5e-5)
+
+
+# the largest program a catalog cell hands the held experts: its longest
+# prompt, or a chunk of PREFILL_ROWS where the family walks one in chunks
+LARGEST_PREFILL = {"axk1-ep16-serve": 16384, "ling3-flash-ep8-serve": 8192,
+                   "command-a-plus-ep8-serve": tfm.PREFILL_ROWS,
+                   "nemotron3-super-ep4-serve": 8192}
+
+
+@pytest.mark.parametrize("name", sorted(LARGEST_PREFILL))
+def test_the_tile_rule_at_the_catalog_cells(name):
+    """Shape -> tile at each catalog cell's published widths: at every
+    decode batch up to ``max_active`` rows no count of held pairs takes
+    more trips than 512-row tiles did, and where the layer expects fewer
+    than MOE_SPARSE_PAIRS pairs a held expert a trip holds twice them
+    (Nemotron 3's full batch: 704 expected, one trip of 1,408 rows); a
+    prefill's dense pairs keep trips of 512 rows; and no trip's rows,
+    hidden activations, product and float32 addend pass MOE_TRIP_BYTES."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        doc = json.load(f)
+    cfg = tfm.TransformerConfig(**doc["model"])
+    k, x_l = cfg.moe_topk, cfg.n_experts
+    width = cfg.moe_latent or cfg.d_model
+    mats = 2 if cfg.moe_act == "relu2" else 3
+    row = 2 * (width + mats * cfg.moe_d_ff) + width * (
+        4 if cfg.residual_dtype == "float32" else 2) + 4 * width
+    rows = doc["serve"]["max_active"]
+    for n in range(1, rows + 1):
+        most = n * min(k, x_l)
+        old = min(512, -(-n * k // 8) * 8)
+        held = np.arange(most + 1)
+        tile = tfm.moe_tile(n, cfg)
+        assert (-(-held // tile) <= -(-held // old)).all(), (n, tile, old)
+        expected = n * k * x_l / cfg.moe_n_routed
+        if expected < tfm.MOE_SPARSE_PAIRS * x_l:
+            assert tile >= min(2 * expected, most)
+    if name.startswith("nemotron3"):
+        assert tfm.moe_tile(rows, cfg) == 1408 > 512
+    for n in (1024, 8192, LARGEST_PREFILL[name]):
+        assert tfm.moe_tile(n, cfg) == 512
+        assert 512 * row <= tfm.MOE_TRIP_BYTES
+
+
+def test_time_ragged_dot_script_smoke():
+    """scripts/time_ragged_dot.py at toy sizes on the CPU: every case of
+    two cells builds, runs and reports (its times mean nothing here);
+    the walks' trips and calls follow their tiles."""
+    import importlib.util
+
+    path = os.path.join(ROOT, "scripts", "time_ragged_dot.py")
+    spec = importlib.util.spec_from_file_location("time_ragged_dot", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    lines = mod.main(["--toy", "--reps", "1", "--cells", "ling3,nemotron3"])
+    assert {line["cell"] for line in lines} == {"ling3", "nemotron3"}
+    assert all(line["ms"] > 0 and line["clock"] == "host_clock"
+               for line in lines)
+    walks = [line for line in lines if line["kind"] == "walk"]
+    assert {(w["tile"], w["trips"]) for w in walks} == {(16, 3), (40, 1)}
+    assert all(w["calls"] == w["trips"] * w["matrices"] for w in walks)
 
 
 def test_the_shares_add_up():
@@ -285,7 +369,7 @@ def test_the_shares_add_up():
             np.asarray(_reference_layer(x, mine, cfg, spec_of(cfg))),
             atol=2e-5)
         total = total + (y - shared)
-        pairs += int(np.asarray(counts)[:-1].sum())
+        pairs += int(np.asarray(counts)[:-2].sum())
     # ... and the shares add up to the whole, every pair counted once
     np.testing.assert_allclose(np.asarray(total[0]), np.asarray(want),
                                atol=5e-5)

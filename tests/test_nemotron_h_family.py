@@ -20,6 +20,7 @@ from dmlc_tpu.ops import dispatch
 from dmlc_tpu.ops import mamba2
 from dmlc_tpu.serving import InferenceEngine
 from dmlc_tpu.serving.kv_cache import PagedKVCache
+from held_experts import check_case
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 8  # block size of the small pools
@@ -216,8 +217,8 @@ def test_prefill_logits_against_the_reference(model, n):
         assert used.tolist() == [2]
     assert state.dtype == jnp.float32
     moe = np.asarray(moe)
-    assert moe.shape == (3, cfg.n_experts + 1)
-    assert (moe[:, -1] == n * cfg.moe_topk).all()  # pad tokens left out
+    assert moe.shape == (3, cfg.n_experts + 2)
+    assert (moe[:, -2] == n * cfg.moe_topk).all()  # pad tokens left out
 
 
 def test_a_long_prompt_walked_in_passes_equals_one_pass(model, monkeypatch):
@@ -311,7 +312,7 @@ def test_prefill_then_decode_through_cache_and_slots(model, others):
         if others:
             np.testing.assert_allclose(np.asarray(logits[1, 0]), want[1][t],
                                        atol=2e-4)
-        assert (np.asarray(moe)[:, -1] == (1 + others) * cfg.moe_topk).all()
+        assert (np.asarray(moe)[:, -2] == (1 + others) * cfg.moe_topk).all()
     # dead rows carry slot 0: without a second sequence it stays zero
     if not others:
         assert not np.asarray(cache[2][:, 0]).any()
@@ -347,7 +348,27 @@ def test_the_bias_steers_an_ungrouped_top_k():
     _, counts = tfm._moe_held_ffn(x, p, cfg)
     held = [(np.asarray(want_i) == cfg.moe_held_start + j).sum()
             for j in range(cfg.n_experts)]
-    assert np.asarray(counts).tolist() == held + [24 * cfg.moe_topk]
+    assert np.asarray(counts)[:-1].tolist() == held + [24 * cfg.moe_topk]
+
+
+@pytest.mark.parametrize("case", ["rule", "overflow", "last_group",
+                                  "sparse"])
+def test_the_held_experts_under_the_tile_rule(case, monkeypatch):
+    """Experts in a latent, relu-squared, under a biased top-k, through
+    the trip loop: the rule's one trip and forced 8-row trips, routing
+    skewed onto the held experts past the rule's tile (256 tokens put
+    1,024 pairs on a tile of 512), the layer's groups last in a junk
+    stack, and a layer whose pairs are sparse over 64 held experts (96
+    tokens expect 288 pairs: one trip of 576 rows holds the 576 that the
+    skew puts here, where 512-row trips took two): the reference's
+    expert layer every time."""
+    cfg = small(**({"n_experts": 64, "moe_n_routed": 128,
+                    "moe_held_start": 0} if case == "sparse" else {}))
+    p, x = _one_expert_layer(cfg, seed=7)
+    if case in ("overflow", "sparse"):
+        n = 256 if case == "overflow" else 96
+        x = jax.random.normal(jax.random.PRNGKey(8), (1, n, cfg.d_model))
+    check_case(case, p, x, cfg, _reference_layer, monkeypatch, atol=1e-4)
 
 
 def test_the_four_shares_of_a_latent_layer_add_up():
@@ -371,7 +392,7 @@ def test_the_four_shares_of_a_latent_layer_add_up():
             np.asarray(y[0]), np.asarray(_reference_layer(x, mine, cfg)),
             atol=5e-5)
         total = total + (y - shared)
-        pairs += int(np.asarray(counts)[:-1].sum())
+        pairs += int(np.asarray(counts)[:-2].sum())
     np.testing.assert_allclose(np.asarray(total[0]), np.asarray(want),
                                atol=1e-4)
     assert pairs == 24 * whole.moe_topk
@@ -438,6 +459,30 @@ def test_the_engine_end_to_end_takes_and_frees_slots():
     assert grew["moe_pairs_total"] > 0
     assert c.get("kda_state_rw_bytes", 0) == before.get(
         "kda_state_rw_bytes", 0)
+
+
+def test_the_engine_counts_the_grouped_products():
+    """One request alone through InferenceEngine: a prefill and one
+    decode step.  At this size the rule's tile holds every pair a
+    program can route here, so each of the 3 expert layers makes one
+    trip of 2 grouped products (relu-squared: no gate matrix) a program
+    call, pad tokens and dead rows included."""
+    cfg = small()
+    assert all(tfm.moe_tile(n, cfg) == n * cfg.n_experts for n in (2, 16))
+    before = telemetry.counters_snapshot().get("serving", {})
+    eng = InferenceEngine(weights(cfg), cfg, n_blocks=8, block_size=BS,
+                          max_active=2, queue_depth=4)
+    eng.start()
+    try:
+        req = eng.submit([(3 * i + 1) % cfg.vocab for i in range(14)], 2)
+        assert req.wait(120) and req.error is None, req.error
+    finally:
+        eng.close()
+    c = telemetry.counters_snapshot()["serving"]
+    grew = {k: c[k] - before.get(k, 0) for k in (
+        "prefill_count", "paged_decode_steps", "moe_grouped_calls")}
+    assert grew["prefill_count"] == 1 and grew["paged_decode_steps"] == 1
+    assert grew["moe_grouped_calls"] == 2 * 3 * 2
 
 
 def test_speculation_is_refused_for_recurrent_layers(monkeypatch):

@@ -350,6 +350,26 @@ def test_engine_continuous_batching_end_to_end():
         eng.close()
 
 
+def test_engine_registers_the_grouped_call_counter_without_experts():
+    """``serving.moe_grouped_calls`` (the held experts' grouped products)
+    is registered at 0 by every engine's start, a model without held
+    experts too, and that model's programs never grow it."""
+    params, cfg = _tiny_model()
+    telemetry.reset()
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=4,
+                          max_active=2, queue_depth=4)
+    eng.start()
+    try:
+        assert telemetry.counters_snapshot()["serving"][
+            "moe_grouped_calls"] == 0
+        r = eng.submit([1, 2, 3], max_new_tokens=4)
+        assert r.wait(300) and r.error is None
+    finally:
+        eng.close()
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["paged_decode_steps"] == 3 and c["moe_grouped_calls"] == 0
+
+
 def test_engine_single_step_interleaves_admission():
     """Iteration-level scheduling: a request submitted mid-generation
     joins the running batch instead of waiting for drain."""
