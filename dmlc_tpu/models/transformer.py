@@ -1635,14 +1635,21 @@ def greedy_pick(logits):
 
 
 def picking_prefill(forward):
-    """``forward_prefill_paged*`` as the engine runs it: same arguments,
-    ``(ids [1], finite [1], *rest)`` where ``forward`` returns
+    """``forward_prefill_paged*`` as the engine runs it.  In place of
+    ``last_index`` it takes ``(last_index [1], into [B, S], row [])``:
+    the pick is also written into ``into[row, 0]`` (nowhere where
+    ``row`` is out of range), so that a decode step can take it as a
+    row's ``prev_ids`` before the host has read it.  Returns ``(ids
+    [1], finite [1], into, *rest)`` where ``forward`` returns
     ``(logits [1, V], *rest)``.  The program keeps ``forward``'s name,
     which is what a trace knows it by."""
     @functools.wraps(forward)
-    def program(*args):
-        logits, *rest = forward(*args)
-        return (*greedy_pick(logits), *rest)
+    def program(params, ids, at, *args):
+        last_index, into, row = at
+        logits, *rest = forward(params, ids, last_index, *args)
+        picked, finite = greedy_pick(logits)
+        into = jnp.asarray(into).at[row, 0].set(picked[0], mode="drop")
+        return (picked, finite, into, *rest)
     return program
 
 
@@ -1650,10 +1657,10 @@ def picking_decode(forward):
     """``forward_decode_paged*`` as the engine runs it.  In place of
     ``ids`` it takes ``(host_ids [B, S], prev_ids [B, S], src [B])``:
     row ``b`` consumes ``prev_ids[src[b]]``, the pick an earlier step
-    left on the device, or ``host_ids[b]`` where ``src[b] < 0`` (a row
-    the host knows the last token of: fresh from its prefill, or no
-    step is unread).  The row index lets a batch that compacted or grew
-    between two steps run the one program.  Returns ``(ids [B, S],
+    or a prefill left on the device, or ``host_ids[b]`` where ``src[b]
+    < 0`` (a row the host knows the last token of).  The row index lets
+    a batch that compacted or grew between two steps run the one
+    program.  Returns ``(ids [B, S],
     finite [B, S], *rest)`` where ``forward`` returns ``(logits
     [B, S, V], *rest)``."""
     @functools.wraps(forward)

@@ -66,8 +66,19 @@ request's ``generated`` current reads the step in flight first
 that stops (``close`` / ``drain`` join it), and ``step()`` called
 without ``lookahead`` (the tests' single-stepping).  Under a
 speculative window the committed count depends on the ids, so nothing
-stays in flight there: the same loop at depth 0.  A prefill's read
-still blocks, behind the step in flight.
+stays in flight there: the same loop at depth 0.
+
+A prefill does not drain that pipeline either.  Where the loop runs
+ahead, the prefill program also writes its pick into a free row of the
+ids the next step is handed (``prev_ids``), the request joins that
+step with its token taken from there, and the pick is read with that
+step, after the step behind it went out (``serving.prefills_deferred``
+counts such reads).  Only then does the request have its first token:
+TTFT is stamped there, and a first token that ends it (``eos_id``, a
+non-finite pick) discards what the steps dispatched with it computed.
+A request whose first token is its last is known to end by count and
+joins no step.  The single-stepped engine and a speculative window
+read each pick at once, behind the step in flight.
 
 Shape discipline (XLA recompiles per shape, so both are bucketed):
 prefill pads prompts up to a whole number of KV blocks (safe under
@@ -81,7 +92,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import List, Optional
 
 import numpy as np
@@ -132,7 +143,7 @@ _ZEROED_COUNTERS = tuple(
 ) + ("decode_d2h_bytes", "prefill_d2h_bytes", "kv_upload_bytes",
      "queue_wait_secs", "queue_wait_count", "latency_secs",
      "decode_steps_overlapped", "lookahead_discarded_tokens",
-     "loop_kv_read_bytes", "moe_grouped_calls")
+     "prefills_deferred", "loop_kv_read_bytes", "moe_grouped_calls")
 
 
 class _DedupeTable:
@@ -329,17 +340,33 @@ def _start_fetch(*arrays) -> None:
             start()
 
 
+class _Prefill:
+    """A prefill on the device whose pick the host has not read: the
+    request, whether it resumes a preempted one, the row of the next
+    decode step's ``prev_ids`` that holds its pick (None where no step
+    takes it from there), and the arrays the program returned."""
+
+    __slots__ = ("req", "resume", "row", "ids", "finite", "moe")
+
+    def __init__(self, req, resume, row):
+        self.req = req
+        self.resume = resume
+        self.row = row
+        self.ids = self.finite = self.moe = None
+
+
 class _DecodeStep:
     """A decode step on the device whose picks the host has not read:
     the batch it ran (``rows``, and ``row_of`` from a request's id to
     its row), what the commit needs of its inputs (``drafts``,
-    ``base_lens``), and the arrays the program returned."""
+    ``base_lens``), the arrays the program returned, and the prefills
+    dispatched before it whose picks are read with it (``fresh``)."""
 
     __slots__ = ("rows", "row_of", "drafts", "base_lens", "n_preempted",
-                 "overlapped", "ids", "finite", "moe")
+                 "overlapped", "ids", "finite", "moe", "fresh")
 
     def __init__(self, rows, drafts, base_lens, n_preempted, overlapped,
-                 picked, moe):
+                 picked, moe, fresh):
         self.rows = rows
         self.row_of = {req.id: i for i, req in enumerate(rows)}
         self.drafts = drafts
@@ -349,6 +376,7 @@ class _DecodeStep:
         self.overlapped = overlapped
         self.ids, self.finite = picked
         self.moe = moe
+        self.fresh = fresh
 
 
 class InferenceEngine:
@@ -503,12 +531,16 @@ class InferenceEngine:
         # the decode step that is dispatched and not read (at most one)
         # dmlc-check: unguarded(engine-thread-confined)
         self._inflight: Optional[_DecodeStep] = None
-        # the ids the last decode step picked, on the device: what the
-        # next step is handed as ``prev_ids`` whether or not a row
-        # takes its token from there
+        # the ids the last decode step picked, and the picks of the
+        # prefills since, on the device: what the next step is handed as
+        # ``prev_ids`` whether or not a row takes its token from there
         # dmlc-check: unguarded(engine-thread-confined)
         self._last_ids = np.zeros((self.max_active, self._spec_window),
                                   np.int32)
+        # the prefills dispatched since the last decode step whose picks
+        # are unread: the next step goes out with them
+        # dmlc-check: unguarded(engine-thread-confined)
+        self._fresh: List[_Prefill] = []
 
     # ---- client surface -------------------------------------------------
     def submit(self, prompt_ids: List[int],
@@ -848,12 +880,14 @@ class InferenceEngine:
 
     def _iterate(self, lookahead: bool) -> bool:
         did = False
+        # where the decode step runs ahead, so do the prefills' picks
+        defer = lookahead and self._spec_window == 1
         while True:
             with self._span("serving.schedule"):
                 req = self.scheduler.next_prefill()
             if req is None:
                 break
-            self._run_prefill(req)
+            self._run_prefill(req, defer)
             did = True
             if req.state == WAITING:
                 # allocate lost a race and requeued the request;
@@ -884,17 +918,19 @@ class InferenceEngine:
         if slot is not None:
             self._slots.release(slot)
 
-    def _run_prefill(self, req: Request) -> None:
+    def _run_prefill(self, req: Request, defer: bool) -> None:
         """Prefill ``req``'s context and cache its K/V inside the device
         program, which scatters them into the request's blocks of the
         pools it is donated and picks the token after the last position
         (the pick and its finiteness are all that come to the host).  A
-        fresh request takes its first token here (that IS the
-        TTFT moment); a preemption resume must NOT — its context
-        already excludes the un-consumed ``generated[-1]``, so the
-        last-position logits would deterministically re-derive that very
-        token and duplicate it in the output.  The resume's next token
-        comes from the decode step that consumes ``generated[-1]``."""
+        fresh request takes its first token from that pick (that IS the
+        TTFT moment): here, or (``defer``) where the decode step that
+        went out with it is read.  A preemption resume must NOT — its
+        context already excludes the un-consumed ``generated[-1]``, so
+        the last-position logits would deterministically re-derive that
+        very token and duplicate it in the output.  The resume's next
+        token comes from the decode step that consumes
+        ``generated[-1]``."""
         with self._span("serving.schedule", req=req.id):
             ctx = req.context_ids()
             n = len(ctx)
@@ -918,7 +954,11 @@ class InferenceEngine:
             ids[0, :n] = ctx
             last = np.array([n - 1], np.int32)
             self.requests.on_prefill_begin(req.id, resume=resume)
-            picked = self._prefill_paged(req, ids, last, n)
+            # a first token that is the last one is known by count: no
+            # decode step takes the row, so none needs the pick
+            fed = defer and not resume and req.max_new_tokens > 1
+            prefill = _Prefill(req, resume, self._free_row() if fed else None)
+            picked = self._prefill_paged(prefill, ids, last, n, defer)
             telemetry.inc("serving", "prefill_tokens", n)
         except Exception as e:  # noqa: BLE001 - fail THIS request only
             logger.error("prefill of request %d failed: %r", req.id, e)
@@ -932,36 +972,90 @@ class InferenceEngine:
                 # active requests, whose re-prefill fills fresh pools
                 raise
             return
+        if defer:
+            # read with the decode step that goes out next
+            self._fresh.append(prefill)
+            if resume:
+                self.requests.on_prefill_end(req.id)
+            self.scheduler.activate(req)
+            if not (resume or fed):
+                # out of the next step, as a row that ends by count with
+                # a decode step is; its place is the next prefill's
+                self.scheduler.retire(req)
+            return
         with self._span("serving.first_token", req=req.id):
-            self._after_prefill(req, *picked, resume)
+            if resume:
+                # resume prefill re-cached context without sampling;
+                # decode resumes from generated[-1] next iteration
+                self.requests.on_prefill_end(req.id)
+            elif not self._first_token(req, *picked):
+                return
+            self.scheduler.activate(req)
 
-    def _prefill_paged(self, req: Request, ids, last, n: int):
+    def _prefill_paged(self, prefill: _Prefill, ids, last, n: int,
+                       defer: bool):
         """The device program writes the K/V into the request's blocks
         (prefill pads to whole blocks, so its block table IS the padded
         prompt's); ``(next id, whether its logit is finite)`` and the
-        routing counts alone cross the link.  The read waits for the
-        program, and for a decode step in flight before it."""
+        routing counts alone cross the link.  It also writes the pick
+        into ``prefill.row`` of ``_last_ids`` (if a row), where the next
+        decode step takes it.  Without ``defer`` the pick is read here,
+        which waits for the program and for a decode step in flight
+        before it, and returned as ``(next id, finite)``."""
+        req, row = prefill.req, prefill.row
         with self._span("serving.prefill", tokens=n, req=req.id):
             with self._span("serving.prefill.run", req=req.id):
                 with self._span("serving.prefill.dispatch", req=req.id):
-                    picked, pools, moe = self._on_pools(
-                        self._prefill, self.params, ids, last,
+                    at = (last, self._last_ids,
+                          np.int32(self.max_active if row is None else row))
+                    out, pools, prefill.moe = self._on_pools(
+                        self._prefill, self.params, ids, at,
                         np.asarray(self.cache.block_table(req.id),
                                    np.int32),
-                        *self._prefill_args(req.id), at=3)
-                    _start_fetch(*picked, *moe)
+                        *self._prefill_args(req.id), at=3, picks=3)
+                    prefill.ids, prefill.finite, self._last_ids = out
+                    _start_fetch(prefill.ids, prefill.finite, *prefill.moe)
                 # the wait for the decode step in flight, the prefill,
                 # and the runtime's notice of its end
-                with self._span("serving.prefill.fetch", req=req.id):
-                    picked = [np.asarray(a) for a in picked]
-                    moe = [np.asarray(m) for m in moe]
-        telemetry.inc("serving", "prefill_d2h_bytes",
-                      sum(a.nbytes for a in picked + moe))
-        self._count_moe(moe)
+                picked = None if defer else self._read_prefill(prefill)
         with self._span("serving.kv_write", req=req.id):
             self.cache.adopt_device_pools(*pools)
             self.cache.advance_many([(req.id, n)])
-        return int(picked[0][0]), bool(picked[1][0])
+        return picked
+
+    def _free_row(self) -> int:
+        """A row of ``_last_ids`` that the next decode step reads for no
+        other request: neither the row in the step in flight of a
+        request still in the batch nor a prefill's since that step.
+        The batch has room for the request being prefilled, so one is
+        free."""
+        taken = {p.row for p in self._fresh}
+        if self._inflight is not None:
+            row_of = self._inflight.row_of
+            taken.update(row_of[r.id]
+                         for r in self.scheduler.active_requests()
+                         if r.id in row_of)
+        return next(i for i in range(self.max_active) if i not in taken)
+
+    def _read_prefill(self, prefill: _Prefill) -> tuple:
+        """Fetch a prefill's pick, its finiteness and the routing counts
+        and account them: ``(next id, finite)``."""
+        with self._span("serving.prefill.fetch", req=prefill.req.id):
+            got = [np.asarray(a) for a in
+                   (prefill.ids, prefill.finite, *prefill.moe)]
+        telemetry.inc("serving", "prefill_d2h_bytes",
+                      sum(a.nbytes for a in got))
+        self._count_moe(got[2:])
+        return int(got[0][0]), bool(got[1][0])
+
+    def _read_fresh(self, fresh: List[_Prefill]) -> None:
+        """Read prefills whose picks were left on the device and give
+        each fresh request its first token."""
+        for prefill in fresh:
+            picked = self._read_prefill(prefill)
+            if not prefill.resume:
+                with self._span("serving.first_token", req=prefill.req.id):
+                    self._first_token(prefill.req, *picked)
 
     def _slot_args(self, seq_ids, pad_batch=None) -> tuple:
         """What a program of a model with recurrent layers takes after
@@ -987,16 +1081,18 @@ class InferenceEngine:
             return (self.cache.sliding_tables_array(seq_ids, pad_batch),)
         return self._slot_args(seq_ids, pad_batch)
 
-    def _on_pools(self, program, *args, at: int):
+    def _on_pools(self, program, *args, at: int, picks: int = 2):
         """Call a paged program with the cache's pools spliced in at
         argument ``at`` and the config last; split what it returns into
-        ``((ids, finite), pools, rest)``.  The pools are the cache's
-        device arrays, recurrent state included; ``rest`` is empty for
-        the MHA programs and the routing counts for the other
-        families'."""
+        ``(picks, pools, rest)``: its first ``picks`` outputs (``(ids,
+        finite)``, and the prefill's ``prev_ids`` after them), the pools
+        and the rest.  The pools are the cache's device arrays,
+        recurrent state included; ``rest`` is empty for the MHA
+        programs and the routing counts for the other families'."""
         pools = self.cache.device_pools()
         out = program(*args[:at], *pools, *args[at:], self.cfg)
-        return out[:2], out[2:2 + len(pools)], out[2 + len(pools):]
+        n = picks + len(pools)
+        return out[:picks], out[picks:n], out[n:]
 
     def _count_moe(self, moe) -> None:
         """Add one program call's routing counts ``[n_moe_layers,
@@ -1014,33 +1110,28 @@ class InferenceEngine:
             telemetry.inc("serving", "moe_expert_load_mean",
                           float(held.mean(axis=1).sum()))
 
-    def _after_prefill(self, req: Request, next_id: int, finite: bool,
-                       resume: bool) -> None:
-        """Give a fresh request its first token and activate it."""
-        if not resume:
-            if not finite:
-                # same guard at the prefill sample point: the first
-                # token must not come from a non-finite row either
-                telemetry.inc("serving", "nonfinite_failures")
-                self._finish(req, error="non-finite logits during "
-                             "prefill (numeric corruption); retry the "
-                             "request", reason="nonfinite")
-                return
-            req.generated.append(next_id)
-            telemetry.inc("serving", "tokens_generated")
-            req.ttft_s = time.monotonic() - req.submit_t
-            telemetry.observe_duration("serving", "ttft", req.ttft_s)
-            # the ledger's TTFT moment: stamps ttft_s = queue_s +
-            # prefill_s exactly (all from one clock)
-            self.requests.on_first_token(req.id)
-            if req.is_finished_by(next_id):
-                self._finish(req)
-                return
-        else:
-            # resume prefill re-cached context without sampling; decode
-            # resumes from generated[-1] next iteration
-            self.requests.on_prefill_end(req.id)
-        self.scheduler.activate(req)
+    def _first_token(self, req: Request, next_id: int,
+                     finite: bool) -> bool:
+        """Give a fresh request its first token; whether it goes on."""
+        if not finite:
+            # same guard at the prefill sample point: the first token
+            # must not come from a non-finite row either
+            telemetry.inc("serving", "nonfinite_failures")
+            self._finish(req, error="non-finite logits during prefill "
+                         "(numeric corruption); retry the request",
+                         reason="nonfinite")
+            return False
+        req.generated.append(next_id)
+        telemetry.inc("serving", "tokens_generated")
+        req.ttft_s = time.monotonic() - req.submit_t
+        telemetry.observe_duration("serving", "ttft", req.ttft_s)
+        # the ledger's TTFT moment: stamps ttft_s = queue_s + prefill_s
+        # exactly (all from one clock)
+        self.requests.on_first_token(req.id)
+        if req.is_finished_by(next_id):
+            self._finish(req)
+            return False
+        return True
 
     def _ensure_decode_capacity(self, active: List[Request],
                                 n_tokens: int = 1) -> tuple:
@@ -1161,7 +1252,8 @@ class InferenceEngine:
         last a tuple that is empty without recurrent state.  ``feed``
         is ``(ids, prev_ids, src)``: a row of the step in flight takes
         its token from that step's picks on the device (``src`` is its
-        row there), every other row from ``ids``."""
+        row there), a row whose prefill's pick is unread from the row
+        the prefill wrote it into, every other row from ``ids``."""
         s_w = self._spec_window
         b = len(active)
         pad_b = self.max_active
@@ -1181,8 +1273,9 @@ class InferenceEngine:
             [r.id for r in active], pad_batch=pad_b)
         base_lens = lengths[:b].astype(np.int64)
         unread = self._inflight.row_of if self._inflight else {}
+        fed = {p.req.id: p.row for p in self._fresh if p.row is not None}
         for i, req in enumerate(active):
-            row = unread.get(req.id)
+            row = unread.get(req.id, fed.get(req.id))
             if row is not None:
                 src[i] = row
             else:
@@ -1239,19 +1332,31 @@ class InferenceEngine:
             # be built before this one is read
             self.cache.advance_many([(req.id, 1) for req in active])
             _start_fetch(*picked, *moe)
+        fresh, self._fresh = self._fresh, []
+        if fresh:
+            # their picks are read with this step, so behind the next
+            # one: counted now, in the iteration whose prefill spans
+            # are counted, so that a window's edge cuts both alike
+            telemetry.inc("serving", "prefills_deferred", len(fresh))
         step = _DecodeStep(active, drafts, base_lens, n_preempted,
-                           prev is not None, picked, moe)
+                           prev is not None, picked, moe, fresh)
         self._last_ids = step.ids
         if not ahead:
             self._read_step(step)
             return
         self._inflight = step
+        # each row's tokens that are on the device and not yet read: the
+        # step before this one's and first tokens of the prefills before
+        before = fresh + (prev.fresh if prev else [])
+        unread = Counter(p.req.id for p in before if not p.resume)
+        if prev is not None:
+            unread.update(prev.row_of.keys())
         for req in active:
             # a row that ends by count with this step is known to now:
             # it is out of the next step, and its place is the next
             # prefill's, as if this step had been read
-            if (req.n_generated + (req.id in prev.row_of if prev else 0)
-                    + 1 >= req.max_new_tokens):
+            if (req.n_generated + unread[req.id] + 1
+                    >= req.max_new_tokens):
                 self.scheduler.retire(req)
         if prev is not None:
             try:
@@ -1262,17 +1367,19 @@ class InferenceEngine:
                 raise
 
     def _settle(self) -> bool:
-        """Read the step in flight, if there is one: afterwards every
-        request's ``generated`` is current.  Whatever needs that calls
-        this first: eviction, the crash requeue, a step without
-        lookahead, a loop that stops."""
+        """Read the step in flight and the prefills since, if there are
+        any: afterwards every request's ``generated`` is current.
+        Whatever needs that calls this first: eviction, the crash
+        requeue, a step without lookahead, a loop that stops."""
         step, self._inflight = self._inflight, None
-        if step is None:
-            return False
-        with self._span("serving.decode", rows=len(step.rows)):
-            telemetry.step_begin()
-            self._read_step(step)
-        return True
+        fresh, self._fresh = self._fresh, []
+        if step is not None:
+            with self._span("serving.decode", rows=len(step.rows)):
+                telemetry.step_begin()
+                self._read_step(step)
+        # dispatched after the step in flight, and no step after them
+        self._read_fresh(fresh)
+        return step is not None or bool(fresh)
 
     def _read_step(self, step: _DecodeStep) -> None:
         """Fetch a dispatched step's picks and commit, deliver and
@@ -1281,6 +1388,10 @@ class InferenceEngine:
         active, drafts, base_lens = step.rows, step.drafts, step.base_lens
         b = len(active)
         compute = telemetry.compute
+        # the prefills this step went out with ran before it, in order:
+        # their picks are the first tokens of its fresh rows, due before
+        # its own, and each is known as soon as its prefill is done
+        self._read_fresh(step.fresh)
         with self._span("serving.decode.fetch") as crossed:
             # the wait for the step (the next one, if dispatched, runs
             # behind it) and the link: a [B, S] int32, a [B, S] bool

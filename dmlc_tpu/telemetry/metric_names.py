@@ -478,9 +478,11 @@ METRIC_NAMES = frozenset({
     "dmlc_serving_kv_sliding_block_steps",
     "dmlc_serving_kv_cached_token_steps",
     # the decode loop's lookahead: steps dispatched while the step
-    # before was unread, and tokens of rows that had ended in it
+    # before was unread, tokens of rows that had ended in it, and
+    # prefills whose pick was read behind a later step
     "dmlc_serving_decode_steps_overlapped",
     "dmlc_serving_lookahead_discarded_tokens",
+    "dmlc_serving_prefills_deferred",
 })
 
 #: span / jax-profiler annotation names that look like metric tokens in

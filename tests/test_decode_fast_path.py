@@ -642,8 +642,8 @@ def test_engine_programs_slice_no_weight_argument():
         n = len(jax.tree.leaves(tree))
         calls = (
             (prefill, tree, np.zeros((1, 8), np.int32),
-             np.zeros((1,), np.int32), *pools, np.zeros((2,), np.int32),
-             cfg),
+             (np.zeros((1,), np.int32), ids, np.int32(0)), *pools,
+             np.zeros((2,), np.int32), cfg),
             (decode, tree, (ids, ids, np.zeros((2,), np.int32)), ids,
              *pools, np.zeros((2, 2), np.int32), np.zeros((2,), np.int32),
              cfg))

@@ -1,6 +1,7 @@
 """The decode loop without the host on its critical path: the programs
 pick the token, a step's picks stay on the device for the next step,
-and the engine reads a step while the next one runs.  Every family
+and the engine reads a step while the next one runs; a prefill's pick
+too is read behind the step that went out with its row.  Every family
 (MHA, latent, hybrid) at a small size on the CPU; ``step(lookahead=
 True)`` is the loop's way, driven here from the test's own thread."""
 
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 
 import test_hybrid_family as hybrid
 import test_latent_family as latent
+import test_nemotron_h_family as pattern
 from dmlc_tpu import telemetry
 from dmlc_tpu.models import transformer as tfm
 from dmlc_tpu.serving import InferenceEngine, PagedKVCache, Request
@@ -37,6 +39,9 @@ def _model(family):
     if family == "mla":
         cfg = latent.small()
         return latent.weights(cfg), cfg
+    if family == "nemotron_h":  # Mamba-2 state beside a K/V pool
+        cfg = pattern.small()
+        return pattern.weights(cfg), cfg
     cfg = hybrid.small(n_layers=4)  # dense + KDA, MLA, KDA + KDA
     return hybrid.weights(cfg), cfg
 
@@ -147,8 +152,9 @@ def test_epilogue_picks_as_numpy_does(case, dtype):
 
 def test_decode_program_takes_each_row_from_the_host_or_the_device():
     """``picking_decode`` feeds row b ``prev_ids[src[b]]`` or, where
-    ``src[b]`` < 0, ``host_ids[b]``; ``picking_prefill`` only swaps the
-    logits for the pick."""
+    ``src[b]`` < 0, ``host_ids[b]``; ``picking_prefill`` swaps the
+    logits for the pick and writes the pick into row ``row`` of the ids
+    it is handed, or nowhere where the row is out of range."""
     def forward(params, ids, scale):
         return jax.nn.one_hot(ids, 16) * scale, "pool"
 
@@ -159,8 +165,14 @@ def test_decode_program_takes_each_row_from_the_host_or_the_device():
         None, (host, prev, src), 2.0)
     assert np.asarray(ids)[:, 0].tolist() == [12, 4, 10, 6]
     assert np.asarray(finite).all() and rest == "pool"
-    ids, finite, rest = tfm.picking_prefill(forward)(None, host[:1, 0], 1.0)
+    ids, finite, fed, rest = tfm.picking_prefill(forward)(
+        None, host[:1, 0], (1.0, prev, np.int32(2)))
     assert np.asarray(ids).tolist() == [3] and rest == "pool"
+    assert np.asarray(fed)[:, 0].tolist() == [10, 11, 3, 13]
+    for row in (4, 9):
+        fed = tfm.picking_prefill(forward)(
+            None, host[:1, 0], (1.0, prev, np.int32(row)))[2]
+        np.testing.assert_array_equal(np.asarray(fed), prev)
 
 
 # ---------------------------------------------------------------------------
@@ -435,3 +447,222 @@ def test_the_loop_runs_ahead_and_a_stop_reads_what_is_left(family, settled):
     # a run of lookahead ends where the batch empties; five requests
     # make at most five runs
     assert steps - 5 <= overlapped < steps
+
+
+# ---------------------------------------------------------------------------
+# a prefill's pick, read behind the step that goes out with its row
+# ---------------------------------------------------------------------------
+
+#: eight short requests through a batch of two: each refill's prefill
+#: lands while a decode step is in flight
+STREAM = [[(7 * i + 3 * j) % 60 + 1 for i in range(2 + j % 5)]
+          for j in range(8)]
+STREAM_NEW = [2 + (5 * j) % 7 for j in range(8)]
+
+
+def _logging(eng, events):
+    """Wrap the programs and the prefill's read: ``events`` gets
+    "prefill", "decode" or "read" as each happens; returns the feeds
+    the decode program was handed."""
+    prefill, decode, read = eng._prefill, eng._decode, eng._read_prefill
+    feeds = []
+
+    def logged_prefill(*a):
+        events.append("prefill")
+        return prefill(*a)
+
+    def logged_decode(p, feed, *rest):
+        events.append("decode")
+        feeds.append(feed)
+        return decode(p, feed, *rest)
+
+    def logged_read(pf):
+        events.append("read")
+        return read(pf)
+
+    eng._prefill, eng._decode = logged_prefill, logged_decode
+    eng._read_prefill = logged_read
+    return feeds
+
+
+@pytest.mark.parametrize("family", FAMILIES + ("nemotron_h",))
+def test_prefills_land_behind_a_step_in_flight(family):
+    """Under the engine's own thread a stream of short requests refills
+    the batch while a step is in flight: every prefill's pick is read
+    behind the step that went out with its row, and the ids are a
+    single-stepped engine's, a recurrent model's state slots joining
+    with their rows."""
+    eng = _engine(family)
+    before = _serving()
+    want = [list(r.generated) for r in _drive(eng, False, STREAM,
+                                               STREAM_NEW)]
+    (settled_deferred,) = _grew(before, "prefills_deferred")
+    eng.close()
+    eng = _engine(family)
+    before = _serving()
+    eng.start()
+    try:
+        reqs = [eng.submit(list(p), max_new_tokens=m)
+                for p, m in zip(STREAM, STREAM_NEW)]
+        for r in reqs:
+            assert r.wait(300), f"request {r.id} never finished"
+    finally:
+        eng.close()
+    deferred, prefills, discarded = _grew(
+        before, "prefills_deferred", "prefill_count",
+        "lookahead_discarded_tokens")
+    assert settled_deferred == 0
+    assert [r.error for r in reqs] == [None] * len(reqs)
+    assert [list(r.generated) for r in reqs] == want
+    assert [len(g) for g in want] == STREAM_NEW
+    assert deferred == prefills == len(STREAM) and discarded == 0
+    assert all(r.ttft_s is not None for r in reqs)
+    assert eng.cache.n_blocks_in_use == 0 and not eng._fresh
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_prefills_of_one_iteration_go_out_before_any_is_read(family):
+    """Four requests admitted in one iteration: the four prefills and
+    the step that takes their rows go out before any pick is read, each
+    pick in a row of its own of the ids that step is handed; the picks
+    are read with that step, after the next one went out."""
+    prompts, max_new = PROMPTS[:4], (5, 4, 6, 3)
+    eng = _engine(family, max_active=4)
+    want = [list(r.generated) for r in _drive(eng, False, prompts,
+                                              max_new)]
+    eng.close()
+    eng = _engine(family, max_active=4)
+    events = []
+    feeds = _logging(eng, events)
+    reqs = _drive(eng, True, prompts, max_new)
+    eng.close()
+    assert [list(r.generated) for r in reqs] == want
+    assert events[:10] == ["prefill"] * 4 + ["decode"] * 2 + ["read"] * 4
+    _, prev_ids, src = feeds[0]
+    assert np.asarray(src).tolist() == [0, 1, 2, 3]
+    assert np.asarray(prev_ids)[:, 0].tolist() == [w[0] for w in want]
+
+
+def _first_token_eos(want):
+    """``(row, eos)``: a row's first token that the other row never
+    emits, so that it ends that row alone."""
+    for row in (0, 1):
+        if want[row][0] not in want[1 - row]:
+            return row, want[row][0]
+    raise AssertionError(f"no usable eos in {want}")
+
+
+@pytest.mark.parametrize("end", ["eos", "max_tokens"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_first_token_that_ends_its_request(family, end):
+    """A first token that is ``eos_id`` is found out where the pick is
+    read, after the step that took the row and the one behind it went
+    out: it is the one token delivered and those two are discarded.
+    One that ends the request by count is known at the dispatch: the row
+    joins no step and nothing is discarded."""
+    prompts, max_new = PROMPTS[:2], [6, 6]
+    eng = _engine(family)
+    want = [list(r.generated) for r in _drive(eng, False, prompts,
+                                              max_new)]
+    eng.close()
+    row, eos = _first_token_eos(want)
+    kw = {}
+    if end == "eos":
+        kw["eos_id"] = eos
+    else:
+        max_new[row] = 1
+    want[row] = want[row][:1]
+    eng = _engine(family, **kw)
+    calls = _recording(eng)
+    before = _serving()
+    reqs = _drive(eng, True, prompts, max_new)
+    discarded, deferred = _grew(before, "lookahead_discarded_tokens",
+                                "prefills_deferred")
+    eng.close()
+    assert [list(r.generated) for r in reqs] == want
+    assert [r.error for r in reqs] == [None, None]
+    assert deferred == 2
+    rows = [n for _, n in calls]
+    if end == "eos":
+        assert discarded == 2 and rows[:2] == [2, 2] and max(rows[2:]) == 1
+    else:
+        assert discarded == 0 and max(rows) == 1
+    assert eng.cache.n_blocks_in_use == 0 and eng._inflight is None
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_nonfinite_first_pick_fails_its_request_under_lookahead(family,
+                                                                  settled):
+    """A prefill whose pick is not finite fails its request where the
+    pick is read: no token is delivered, the two steps that went out
+    with the row discard theirs, and the other requests get the settled
+    engine's ids."""
+    want = settled(family)[0]
+    eng = _engine(family)
+    real, n = eng._prefill, [0]
+
+    def prefill(*a):
+        out = real(*a)
+        n[0] += 1
+        if n[0] != 3:
+            return out
+        return (out[0], np.zeros(1, bool)) + tuple(out[2:])
+
+    eng._prefill = prefill
+    before = _serving()
+    reqs = _drive(eng, lookahead=True)
+    failures, discarded = _grew(before, "nonfinite_failures",
+                                "lookahead_discarded_tokens")
+    eng.close()
+    assert reqs[2].error is not None and "non-finite" in reqs[2].error
+    assert reqs[2].generated == [] and reqs[2].ttft_s is None
+    assert [r.error for i, r in enumerate(reqs) if i != 2] == [None] * 4
+    assert [list(r.generated) for i, r in enumerate(reqs) if i != 2] \
+        == [w for i, w in enumerate(want) if i != 2]
+    assert (failures, discarded) == (1, 2)
+    assert eng.cache.n_blocks_in_use == 0
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_resumed_request_joins_at_its_dispatch(family):
+    """A preempted request's re-prefill needs no pick: the step after it
+    goes out with the row before anything is read, and the answers are
+    an engine's that never preempts."""
+    prompts, max_new = ([1, 2, 3, 4, 5, 6], [6, 5, 4, 3, 2, 1]), (12, 12)
+    eng = _engine(family)
+    want = [list(r.generated) for r in _drive(eng, False, prompts, max_new)]
+    eng.close()
+    eng = _engine(family, n_blocks=3)
+    events = []
+    _logging(eng, events)
+    before = _serving()
+    reqs = _drive(eng, True, prompts, max_new)
+    deferred, prefills = _grew(before, "prefills_deferred", "prefill_count")
+    eng.close()
+    resumes = sum(r.preemptions for r in reqs)
+    assert resumes >= 1 and prefills == 2 + resumes
+    assert [list(r.generated) for r in reqs] == want
+    # every prefill, the resumes too, is followed by a dispatch
+    at = [i for i, e in enumerate(events) if e == "prefill"]
+    assert all(events[i + 1] in ("prefill", "decode") for i in at)
+    assert deferred == prefills
+
+
+@pytest.mark.parametrize("family", ["mha", "mla"])
+def test_a_speculative_window_reads_each_pick_at_once(family, settled,
+                                                      monkeypatch):
+    """Under a verify window nothing stays in flight, the prefills'
+    picks included: each is read inside its own prefill."""
+    monkeypatch.setenv("DMLC_SERVE_SPEC_K", "2")
+    eng = _engine(family)
+    events = []
+    _logging(eng, events)
+    before = _serving()
+    reqs = _drive(eng, lookahead=True)
+    (deferred,) = _grew(before, "prefills_deferred")
+    eng.close()
+    assert [list(r.generated) for r in reqs] == settled(family)[0]
+    at = [i for i, e in enumerate(events) if e == "prefill"]
+    assert len(at) == len(PROMPTS)
+    assert all(events[i + 1] == "read" for i in at)
+    assert deferred == 0

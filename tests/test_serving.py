@@ -8,6 +8,7 @@ compile once).
 """
 
 import json
+import os
 import threading
 import time
 import urllib.error
@@ -1372,6 +1373,133 @@ def test_engine_byte_counters_equal_what_crossed():
     # every step fetches the whole padded batch: max_active rows
     assert c["decode_d2h_bytes"] == 3 * eng.max_active * picked
     eng.close()
+
+
+def _loop_answers(eng, prompts, max_new):
+    """Run ``prompts`` through the engine's own thread; their ids."""
+    eng.start()
+    try:
+        reqs = [eng.submit(list(p), max_new_tokens=m)
+                for p, m in zip(prompts, max_new)]
+        for r in reqs:
+            assert r.wait(300), f"request {r.id} never finished"
+    finally:
+        eng.close()
+    assert [r.error for r in reqs] == [None] * len(reqs)
+    return [list(r.generated) for r in reqs]
+
+
+def test_the_loop_reads_a_prefills_pick_behind_the_next_step():
+    """Under the engine's own thread a prefill's ``.run`` is its
+    dispatch alone; its pick is read in a ``serving.prefill.fetch`` under
+    the decode span that reads the step that went out with its row,
+    before that step's own ids, and the first token is taken there, TTFT
+    with it.  The ids are a single-stepped engine's."""
+    params, cfg = _tiny_model()
+    prompts = [[(3 * i + j) % 60 + 1 for i in range(3 + j)] for j in range(6)]
+    max_new = [3, 7, 4, 6, 2, 5]
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=3, queue_depth=8)
+    want = []
+    for p, m in zip(prompts, max_new):
+        req = eng.submit(list(p), max_new_tokens=m)
+        while not req.wait(0):
+            eng.step()
+        want.append(list(req.generated))
+    eng.close()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=3, queue_depth=8)
+    telemetry.reset()
+    assert _loop_answers(eng, prompts, max_new) == want
+    recs = _engine_thread_spans()
+    by_id = {r["id"]: r for r in recs}
+    runs = [r for r in recs if r["name"] == "serving.prefill.run"]
+    assert len(runs) == len(prompts)
+    for run in runs:
+        kids = [r["name"] for r in recs if r["parent"] == run["id"]]
+        assert kids == ["serving.prefill.dispatch"]
+
+    def enclosing(rec):  # the nearest engine span around it
+        rec = by_id[rec["parent"]]
+        while not rec["name"].startswith("serving."):
+            rec = by_id[rec["parent"]]  # the ledger's step
+        return rec
+
+    for name in ("serving.prefill.fetch", "serving.first_token"):
+        spans = [r for r in recs if r["name"] == name]
+        assert len(spans) == len(prompts)
+        assert {enclosing(r)["name"] for r in spans} == {"serving.decode"}
+    own = {enclosing(r)["id"]: r for r in recs
+           if r["name"] == "serving.decode.fetch"}
+    for r in recs:
+        if r["name"] == "serving.prefill.fetch":
+            assert r["ts"] + r["dur"] <= own[enclosing(r)["id"]]["ts"]
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["prefills_deferred"] == c["prefill_count"] == len(prompts)
+    assert c["prefill_fetch_count"] == len(prompts)
+    assert c["prefill_d2h_bytes"] == 5 * len(prompts)
+
+
+def test_prefills_deferred_is_zeroed_at_start_and_counts_the_loops():
+    """``serving.prefills_deferred`` reads 0 from ``start()`` on, stays 0
+    for a single-stepped engine, and counts every prefill the loop's
+    own thread reads behind a later step."""
+    params, cfg = _tiny_model()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    telemetry.reset()
+    for i in range(3):
+        eng.submit([5, 4, 3, 2 + i], max_new_tokens=3)
+    for _ in range(12):
+        eng.step()
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["prefill_count"] == 3 and "prefills_deferred" not in c
+    eng.close()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    telemetry.reset()
+    eng.start()
+    assert telemetry.counters_snapshot()["serving"]["prefills_deferred"] == 0
+    eng.close()
+    eng = InferenceEngine(params, cfg, n_blocks=32, block_size=4,
+                          max_active=2, queue_depth=8)
+    _loop_answers(eng, [[5, 4, 3, 2 + i] for i in range(3)], [3, 3, 3])
+    c = telemetry.counters_snapshot()["serving"]
+    assert c["prefills_deferred"] == c["prefill_count"] == 3
+
+
+def test_prefill_overlap_share_reads_through_the_ratio_reader():
+    """The per-layer metric ``prefill_overlap_share``: deferred prefills
+    over the ``serving.prefill`` span's count, x100, in every serve cell,
+    beside ``decode_overlap_share`` with the same layer, direction and
+    end-to-end metric; a program without the counter reads nothing."""
+    from benchmarks import harness
+
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    mine, model = entries["prefill_overlap_share"], entries[
+        "decode_overlap_share"]
+    assert bench["per_layer"][-1] is mine
+    assert dict(mine, name="decode_overlap_share") == model
+    serve = [w["name"] for w in bench["workloads"]
+             if w["name"].startswith("serve-")]
+    assert mine["workloads"] == serve
+    for cell in serve:
+        (m,) = [m for m in harness.load_cell(cell).per_layer
+                if m["name"] == "prefill_overlap_share"]
+        read = harness.layer_reader(m["spec"])
+        params = m["spec"]["params"]
+        numbers = {"counters.serving.prefills_deferred": 57.0,
+                   "counters.serving.prefill_count": 60.0}
+        assert read({"numbers": numbers}, params) == pytest.approx(95.0)
+        # the parent's program: no counter
+        del numbers["counters.serving.prefills_deferred"]
+        assert read({"numbers": numbers}, params) is None
+        # no prefill in the window
+        assert read({"numbers": {
+            "counters.serving.prefills_deferred": 0.0,
+            "counters.serving.prefill_count": 0.0}}, params) is None
 
 
 def test_engine_start_zeroes_its_phase_counters():
