@@ -26,7 +26,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -1048,7 +1048,8 @@ def unsharded_loss(params, ids, labels, cfg: TransformerConfig):
 # ---------------------------------------------------------------------------
 # serving forward paths: prefill (full sequence) and decode against the
 # KV cache dmlc_tpu.serving owns (serving/kv_cache.py), whose
-# device-resident pools the paged heads read and write in place
+# device-resident pools the paged programs read and write in place (the
+# one walk of every family: the end of this module)
 # ---------------------------------------------------------------------------
 
 
@@ -1098,7 +1099,7 @@ def per_layer_params(params):
     layer, each matrix an array of its own, beside ``embed``,
     ``unembed`` and ``ln_f`` as they are.  The engine calls it once,
     outside any program; a program handed the stack makes the same
-    slices inside itself (:func:`_mha_layers`), on every call, and XLA
+    slices inside itself (:func:`_serving_layers`), on every call, and XLA
     copies some of them (403 MB a decode step at the flagship's
     widths).  Any other tree (one that has ``layers`` already, the
     latent and hybrid families') is returned as the object it is."""
@@ -1135,52 +1136,9 @@ def _into_stream(cfg: TransformerConfig) -> dict:
     return {"preferred_element_type": cfg.stream_dtype}
 
 
-def _mha_layers(params, cfg: TransformerConfig):
-    """Yields ``(kind, layer params, first_group)`` in layer order for
-    either tree of the MHA family: the per-layer ``layers`` as the
-    serving engine holds them, or the stacked ``blocks`` as training
-    holds them, whose per-layer slices are then taken here, inside the
-    caller's program (:func:`per_layer_params`).  Where the model has
-    held experts, their one stack rides in every layer's dict and the
-    layer's own start at ``first_group`` (else None; see
-    :func:`_init_held_expert_params`, :func:`_moe_held_ffn`)."""
-    params = per_layer_params(params)
-    experts = params.get("experts", {})
-    for i, (kind, p) in enumerate(zip(cfg.layer_kinds, params["layers"])):
-        yield kind, {**p, **experts}, i * cfg.n_experts if experts else None
-
-
 def _rotates(kind: str, cfg: TransformerConfig) -> bool:
     """Whether a layer of this kind rotates q and k by position."""
     return kind != "full" or cfg.full_layers_rope
-
-
-def _mha_ffn(xn, p, first_group, cfg: TransformerConfig, valid, counts):
-    """The layer's FFN on normed activations: the trained mixtures, the
-    held experts (their routing counts appended to ``counts``), or a
-    looped model's one SwiGLU."""
-    if cfg.looped:
-        return swiglu_ffn(xn.astype(cfg.jdtype), p["w_in"], p["w_gate"],
-                          p["w_out"], ShardAxes(),
-                          out_dtype=_into_stream(cfg).get(
-                              "preferred_element_type"))
-    if cfg.moe_router != "sigmoid":
-        return _moe_ffn(xn.astype(cfg.jdtype), p, ShardAxes(), cfg)
-    with jax.named_scope("moe"):
-        y, c = _moe_held_ffn(xn, p, cfg, valid, first_group)
-    counts.append(c)
-    return y
-
-
-def _ffn_half(x, xn, attn, p, first_group, cfg, valid, counts):
-    """The residual stream after a layer.  Sequential block: ``x``
-    already holds attention's addend (``attn`` None), and the FFN takes
-    a second norm of it.  Parallel block: the FFN takes the layer's one
-    normed input ``xn`` and both addends land together."""
-    if cfg.parallel_block:
-        return x + attn + _mha_ffn(xn, p, first_group, cfg, valid, counts)
-    return x + _post_norm(_mha_ffn(_norm(x, p["ln2"], cfg), p, first_group,
-                                   cfg, valid, counts), p, "ln2_post", cfg)
 
 
 def _post_norm(y, p, name: str, cfg: TransformerConfig):
@@ -1201,97 +1159,9 @@ def _heads(xa, w, cfg: TransformerConfig):
     return jnp.einsum("bte,ehd->bthd", xa, w)
 
 
-#: the named scope of a layer kind's attention half, projections
-#: included, in both serving programs ("mha": in decode alone, as the
-#: flagship's programs always had it)
-_ATTN_SCOPE = {"full": "attn_full", "sliding": "attn_sliding"}
-
-
 def _scope(name):
     """``jax.named_scope(name)``, or nothing where there is no name."""
     return jax.named_scope(name) if name else contextlib.nullcontext()
-
-
-def _mha_prefill_layer(x, p, first_group, kind, cfg: TransformerConfig,
-                       valid, counts):
-    """One layer over a whole prompt ``x`` [B, T, E]: ``(x, k, v)`` with
-    k, v ``[B, T, H_kv, hd]`` as the cache holds them.  A prompt longer
-    than :data:`PREFILL_ROWS` (a multiple of it) is walked in chunks of
-    rows against the whole K and V."""
-    b, t, e = x.shape
-    span = cfg.sliding_window if kind == "sliding" else 0
-    scope = _ATTN_SCOPE.get(kind)
-    n_chunks = t // PREFILL_ROWS if (
-        cfg.served_only and t > PREFILL_ROWS and t % PREFILL_ROWS == 0) else 1
-    rows = t // n_chunks
-    xn = _norm(x, p["ln1"], cfg)
-    with _scope(scope):
-        xa = xn.astype(cfg.jdtype)  # the products' operand
-        k = _heads(xa, p["wk"], cfg)
-        v = _heads(xa, p["wv"], cfg)
-        if _rotates(kind, cfg):
-            k = rope(k, jnp.arange(t), cfg.rope_theta)
-
-    def some_rows(x, xn, valid, first):
-        """Rows [first, first + rows) of the prompt through the layer."""
-        layer_counts = []
-        with _scope(scope):
-            q = _heads(xn.astype(cfg.jdtype), p["wq"], cfg)
-            if _rotates(kind, cfg):
-                q = rope(q, first + jnp.arange(rows), cfg.rope_theta)
-            # the scope states the call's T, window and chunks: the
-            # benchmark's roofline reader counts each call's operations
-            # from them
-            with _scope(scope and f"prefill_attn_t{t}_w{span}_c{n_chunks}"):
-                o = _causal_attention(
-                    q, k, v, span=span,
-                    q_offset=None if n_chunks == 1 else first)
-            attn = _post_norm(jnp.einsum("bthd,hde->bte", o, p["wo"],
-                                         **_into_stream(cfg)),
-                              p, "ln1_post", cfg)
-            if not cfg.parallel_block:
-                x, attn = x + attn, None
-        return _ffn_half(x, xn, attn, p, first_group, cfg, valid,
-                         layer_counts), layer_counts
-
-    if n_chunks == 1:
-        x, layer_counts = some_rows(x, xn, valid, 0)
-        counts.extend(layer_counts)
-        return x, k, v
-
-    def chunked(a):  # [B, T, ...] -> [n_chunks, B, rows, ...]
-        return jnp.moveaxis(a.reshape((b, n_chunks, rows) + a.shape[2:]), 1, 0)
-
-    def one(args):
-        # the chunk's rows normed again: the whole prompt's, which K
-        # and V were made of, need not outlive them
-        i, x_c, valid_c = args
-        return some_rows(x_c, _norm(x_c, p["ln1"], cfg), valid_c, i * rows)
-
-    valid_all = jnp.ones((b, t), bool) if valid is None else valid
-    x, layer_counts = lax.map(one, (jnp.arange(n_chunks), chunked(x),
-                                    chunked(valid_all)))
-    counts.extend(c.sum(axis=0) for c in layer_counts)
-    return jnp.moveaxis(x, 0, 1).reshape(b, t, e), k, v
-
-
-def _prefill_trunk(params, ids, cfg: TransformerConfig, valid=None):
-    """All prefill layers up to (and including) the final norm:
-    returns ``(x [B, T, E], ks, vs, counts)``: per layer the keys and
-    values ``[B, T, H_kv, hd]`` as the cache holds them (post-rope), and
-    the held-expert layers' routing counts (none for the trained
-    mixtures) — shared by the heads below.  One pass: a looped model's
-    prompt goes through :func:`_looped_prefill`."""
-    assert cfg.loop_passes == 1, "a looped model prefills into its pools"
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
-        cfg.stream_dtype)
-    ks, vs, counts = [], [], []
-    for kind, p, first_group in _mha_layers(params, cfg):
-        x, k, v = _mha_prefill_layer(x, p, first_group, kind, cfg, valid,
-                                     counts)
-        ks.append(k)
-        vs.append(v)
-    return _norm(x, params["ln_f"], cfg), ks, vs, counts
 
 
 def _loop_passes(x, carry, one_pass, params, cfg: TransformerConfig):
@@ -1312,30 +1182,6 @@ def _loop_passes(x, carry, one_pass, params, cfg: TransformerConfig):
     return lax.fori_loop(0, cfg.loop_passes, body, (x, carry))
 
 
-def _looped_prefill(params, ids, k_pool, v_pool, block_ids,
-                    cfg: TransformerConfig):
-    """The prefill trunk of a looped model: ``(x [B, T, E], k_pool,
-    v_pool)`` after every pass, layer l of pass t having written the
-    prompt's K/V into cache layer ``t * n_layers + l`` at
-    ``block_ids``; ``x`` is the last pass's final norm."""
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
-        cfg.stream_dtype)
-
-    def one_pass(first, x, pools):
-        k_pool, v_pool = pools
-        for i, (kind, p, first_group) in enumerate(_mha_layers(params,
-                                                              cfg)):
-            x, k, v = _mha_prefill_layer(x, p, first_group, kind, cfg, None,
-                                         [])
-            k_pool = k_pool.at[first + i, block_ids].set(_paged(k, k_pool))
-            v_pool = v_pool.at[first + i, block_ids].set(_paged(v, v_pool))
-        return x, (k_pool, v_pool)
-
-    x, (k_pool, v_pool) = _loop_passes(x, (k_pool, v_pool), one_pass,
-                                       params, cfg)
-    return x, k_pool, v_pool
-
-
 def _unembed(params, x, cfg: TransformerConfig):
     """Logits of hidden states ``x`` [..., T, E]: the ``unembed``
     matrix, or the tied embedding scaled by ``logit_scale``."""
@@ -1345,6 +1191,17 @@ def _unembed(params, x, cfg: TransformerConfig):
             "bte,ve->btv", x, params["embed"], **_into_stream(cfg))
     return jnp.einsum("bte,ev->btv", x, params["unembed"],
                       **_into_stream(cfg))
+
+
+def _dense_prefill(params, ids, cfg: TransformerConfig):
+    """The prefill walk of an MHA model of one pass without a cache:
+    ``(x [B, T, E] after the final norm, ks, vs)``, per layer the keys
+    and values ``[B, T, H_kv, hd]`` as the cache holds them
+    (post-rope)."""
+    assert cfg.loop_passes == 1, "a looped model prefills into its pools"
+    cache = _Cache(cfg)
+    x = _prefill_walk(params, ids, cache, cfg)
+    return x, *zip(*cache.kept["pages"])
 
 
 def forward_prefill(params, ids, cfg: TransformerConfig):
@@ -1357,7 +1214,7 @@ def forward_prefill(params, ids, cfg: TransformerConfig):
     token, so their K/V and logits are unaffected — the serving engine
     pads prompts to length buckets to bound jit recompilation.
     """
-    x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
+    x, ks, vs = _dense_prefill(params, ids, cfg)
     return _unembed(params, x, cfg), jnp.stack(ks), jnp.stack(vs)
 
 
@@ -1376,8 +1233,8 @@ def forward_prefill_last(params, ids, last_index, cfg: TransformerConfig):
     """Prefill with logits at ONE position per sequence:
     ``(logits [B, V], k, v)`` for ``last_index`` [B] (each sequence's
     final real token in a right-padded batch).  The K/V come back
-    dense: what :func:`forward_prefill_paged` must put into the pools."""
-    x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
+    dense: what :func:`prefill_paged` puts into the pools."""
+    x, ks, vs = _dense_prefill(params, ids, cfg)
     return (_logits_at(params, x, last_index, cfg), jnp.stack(ks),
             jnp.stack(vs))
 
@@ -1388,71 +1245,6 @@ def _paged(a, pool):
     bs = pool.shape[2]
     return a.reshape(a.shape[:-4] + (a.shape[-3] // bs, bs)
                      + a.shape[-2:]).astype(pool.dtype)
-
-
-def forward_prefill_paged(params, ids, last_index, k_pool, v_pool,
-                          block_ids, cfg: TransformerConfig):
-    """Prefill of ONE sequence that writes its K/V into the paged pools
-    on the device: ``(logits [1, V], k_pool, v_pool)``.
-
-    ids [1, T] with T a whole number of blocks (the engine pads prompts
-    so); k_pool / v_pool [L, n_blocks, block_size, H_kv, hd]; block_ids
-    [T / block_size] int32, the sequence's block table.  Logical block
-    j of every layer lands in physical block ``block_ids[j]``, the
-    layout :func:`forward_decode_paged` reads.  Slots of the last block
-    past the prompt's true length hold the pad tokens' K/V: nothing
-    reads a slot at or past a row's length, and decode overwrites each
-    before the length passes it (the contract of the decode window's
-    uncommitted slots).  The caller donates the pools, so the scatter
-    is in place and no K/V leaves the device.  A looped model writes
-    each pass's K/V into that pass's cache layers as it goes
-    (:func:`_looped_prefill`).
-    """
-    if cfg.loop_passes > 1:
-        x, k_pool, v_pool = _looped_prefill(params, ids, k_pool, v_pool,
-                                            block_ids, cfg)
-        return _logits_at(params, x, last_index, cfg), k_pool, v_pool
-    x, ks, vs, _ = _prefill_trunk(params, ids, cfg)
-    k_pool = k_pool.at[:, block_ids].set(_paged(jnp.stack(ks), k_pool))
-    v_pool = v_pool.at[:, block_ids].set(_paged(jnp.stack(vs), v_pool))
-    return _logits_at(params, x, last_index, cfg), k_pool, v_pool
-
-
-def forward_prefill_paged_swa(params, ids, last_index, k_pool, v_pool,
-                              ks_pool, vs_pool, block_ids, sliding_ids,
-                              cfg: TransformerConfig):
-    """:func:`forward_prefill_paged` of a model with sliding-window
-    layers, whose K/V have pools and a block table of their own: the
-    full layers' K/V go whole into ``k_pool`` / ``v_pool`` ``[n full
-    layers, n_blocks, ...]`` at ``block_ids``; of a sliding layer's
-    only the prompt's last ``len(sliding_ids)`` blocks are written,
-    into ``ks_pool`` / ``vs_pool`` ``[n sliding layers, n sliding
-    blocks, ...]`` at ``sliding_ids`` (in logical order: the ring's
-    entries as ``PagedKVCache.sliding_prefill_ids`` gives them), because
-    no later query's window reaches further back.  All four pools are
-    donated and updated in place.  Returns ``(logits [1, V], k_pool,
-    v_pool, ks_pool, vs_pool[, moe])``, ``moe [n_layers, n_experts +
-    2]`` the routing counts of the prompt's real tokens where the
-    experts are held ones (:func:`_moe_held_ffn`)."""
-    t = ids.shape[1]
-    valid = jnp.arange(t)[None] <= last_index[:, None]
-    x, ks, vs, counts = _prefill_trunk(params, ids, cfg, valid)
-    tail = sliding_ids.shape[0] * k_pool.shape[2]  # tokens a ring keeps
-    at = {"full": 0, "sliding": 0}
-    for kind, k, v in zip(cfg.layer_kinds, ks, vs):
-        i = at[kind]
-        at[kind] += 1
-        if kind == "full":
-            k_pool = k_pool.at[i, block_ids].set(_paged(k, k_pool))
-            v_pool = v_pool.at[i, block_ids].set(_paged(v, v_pool))
-        else:
-            ks_pool = ks_pool.at[i, sliding_ids].set(
-                _paged(k[:, t - tail:], ks_pool))
-            vs_pool = vs_pool.at[i, sliding_ids].set(
-                _paged(v[:, t - tail:], vs_pool))
-    moe = (jnp.stack(counts),) if counts else ()
-    return (_logits_at(params, x, last_index, cfg), k_pool, v_pool, ks_pool,
-            vs_pool) + moe
 
 
 def _rope_window(x, positions, theta: float = 10000.0):
@@ -1470,151 +1262,27 @@ def _rope_window(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def _paged_decode(params, ids, positions, pools, tables, lengths,
-                  cfg: TransformerConfig):
-    """The decode window step of the MHA family over any set of layer
-    kinds: ``pools[kind]`` is that kind's ``(k_pool, v_pool)`` ``[its
-    layers, its blocks, block_size, H_kv, hd]`` and ``tables[kind]``
-    its block tables ``[B, W]`` (a sliding kind's the ring table, W
-    fixed by the window).  Returns ``(logits [B, S, V], pools, counts)``
-    (see :func:`forward_decode_paged`).  A looped model runs the layers
-    once a pass (:func:`_loop_passes`), pass t's layer l reading and
-    writing cache layer ``t * n_layers + l``."""
-    from ..ops import paged_attention as _paged_attn
-
-    b, s_w = ids.shape
-    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
-    where = {}
-    for kind, (k_pool, _) in pools.items():
-        # physical scatter addresses for the window: logical block ->
-        # table lookup -> (block, slot); dead rows go out of bounds
-        n_blocks, bs = k_pool.shape[1], k_pool.shape[2]
-        table = tables[kind]
-        lb = pos_w // bs
-        lb = lb % table.shape[1] if kind == "sliding" \
-            else jnp.clip(lb, 0, table.shape[1] - 1)
-        wb = jnp.take_along_axis(table, lb, axis=1)
-        where[kind] = (jnp.where(lengths[:, None] > 0, wb, n_blocks),
-                       pos_w % bs)                               # OOB-drop
-    valid = jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
-        cfg.stream_dtype)
-    counts = []
-
-    def one_pass(first, x, pools):
-        """The layers once, a kind's layer l at its cache layer first +
-        l (``first`` a pass's offset into a looped model's pools)."""
-        pools = dict(pools)
-        at = dict.fromkeys(pools, first)
-        for kind, p, first_group in _mha_layers(params, cfg):
-            li = at[kind]
-            at[kind] += 1
-            k_pool, v_pool = pools[kind]
-            wb, ws = where[kind]
-            with jax.named_scope(_ATTN_SCOPE.get(kind, "attention")):
-                xn = _norm(x, p["ln1"], cfg)
-                xa = xn.astype(cfg.jdtype)  # the products' operand
-                q = _heads(xa, p["wq"], cfg)
-                k = _heads(xa, p["wk"], cfg)
-                v = _heads(xa, p["wv"], cfg)
-                if _rotates(kind, cfg):
-                    q = _rope_window(q, positions, cfg.rope_theta)
-                    k = _rope_window(k, positions, cfg.rope_theta)
-                k_pool = k_pool.at[li, wb, ws].set(
-                    k.astype(k_pool.dtype), mode="drop")
-                v_pool = v_pool.at[li, wb, ws].set(
-                    v.astype(v_pool.dtype), mode="drop")
-                pools[kind] = (k_pool, v_pool)
-                # the layers' pools as one run of pages, a free view:
-                # a per-layer slice of a pool would be copied for the
-                # kernel
-                o = _paged_attn.paged_attention(
-                    q, k_pool.reshape((-1,) + k_pool.shape[2:]),
-                    v_pool.reshape((-1,) + v_pool.shape[2:]),
-                    tables[kind] + li * k_pool.shape[1], lengths,
-                    span=cfg.sliding_window if kind == "sliding" else 0)
-                attn = _post_norm(jnp.einsum("bthd,hde->bte", o, p["wo"],
-                                             **_into_stream(cfg)),
-                                  p, "ln1_post", cfg)
-                if not cfg.parallel_block:
-                    x, attn = x + attn, None
-            # the held experts open their own scope, "moe"
-            with _scope(None if cfg.moe_router == "sigmoid" else "mlp"):
-                x = _ffn_half(x, xn, attn, p, first_group, cfg, valid,
-                              counts)
-        return x, pools
-
-    if cfg.loop_passes > 1:
-        x, pools = _loop_passes(x, pools, one_pass, params, cfg)
-    else:
-        x, pools = one_pass(0, x, pools)
-    with jax.named_scope("unembed"):
-        if cfg.loop_passes == 1:  # a looped model's passes end normed
-            x = _norm(x, params["ln_f"], cfg)
-        logits = _unembed(params, x, cfg)
-    return logits, pools, counts
-
-
 def forward_decode_paged(params, ids, positions, k_pool, v_pool,
                          block_tables, lengths, cfg: TransformerConfig):
-    """Decode window step attending the paged KV pool IN PLACE.
+    """:func:`decode_paged` of a model whose cache is one K and one V
+    pool (the flagship, a looped model), the pools as arguments of
+    their own: ``(logits [B, S, V], k_pool, v_pool)``.
 
-    The fast path: no dense gather, no re-placement copy.  ids /
-    positions [B, S] (S=1 plain decode, S=k+1 speculative verify);
-    k_pool / v_pool [L, n_blocks, block_size, H_kv, hd] — the cache's
-    device-resident pools; block_tables [B, W] int32 (rows padded with
-    0); lengths [B] int32 committed tokens per row.
-
-    Scatter-then-attend per layer: each layer writes the window's K/V
-    into the pool at positions ``lengths[b] + s`` (physical address via
-    the block table) and then attends positions ``<= lengths[b] + s``
-    through :func:`ops.paged_attention.paged_attention` — the same mask
-    the gather path applies to its dense view, with the window tokens
-    at their real paged addresses instead of a concatenated tail.
-    Dead rows (length 0) route their scatter out of bounds
-    (``mode="drop"``) so padding can never corrupt a live block.  The
-    scatter addresses the 5-D pool (``[li, block, slot]``, block
-    ``n_blocks`` is out of bounds in every layer); the kernel gets all
-    layers' pages as one run with the tables shifted to layer ``li``,
-    so no layer's slice of a pool is ever made.  The engine donates
-    the pools, which makes the scatter in place.  The kernel
-    (``paged_attn``, one call a layer) reads through the table only
-    the pages a live row's ``lengths[b] + S`` positions fill, in
-    blocks of many pages fetched ahead by its own DMAs: a padded
-    table entry or a dead row costs it nothing, so neither the table's
-    width ``W`` nor ``max_active`` is paid for beyond what is live.
-
-    Returns ``(logits [B, S, V], k_pool, v_pool)``: the updated pools
-    are the cache (the caller adopts them and advances each row's
-    length by what it commits — window slots past that hold garbage by
-    the same contract as gather padding).
-    """
-    logits, pools, _ = _paged_decode(
-        params, ids, positions, {"mha": (k_pool, v_pool)},
-        {"mha": block_tables}, lengths, cfg)
-    return (logits,) + pools["mha"]
-
-
-def forward_decode_paged_swa(params, ids, positions, k_pool, v_pool,
-                             ks_pool, vs_pool, block_tables, lengths,
-                             sliding_tables, cfg: TransformerConfig):
-    """:func:`forward_decode_paged` of a model with sliding-window
-    layers (one token a row: a ring has no room for a verify window).
-    The full layers read and write ``k_pool`` / ``v_pool`` through
-    ``block_tables`` [B, W] as ever; a sliding layer writes the token
-    into ``ks_pool`` / ``vs_pool`` at its ring table's entry
-    ``(position // block_size) mod R`` (``sliding_tables`` [B, R], R
-    fixed by the window) and attends the keys ``position - window < j
-    <= position`` alone, its walk starting at the page of the oldest.
-    Returns ``(logits [B, 1, V], k_pool, v_pool, ks_pool, vs_pool[,
-    moe])``."""
-    assert ids.shape[1] == 1, "a ring table holds one decode token's reach"
-    logits, pools, counts = _paged_decode(
-        params, ids, positions,
-        {"full": (k_pool, v_pool), "sliding": (ks_pool, vs_pool)},
-        {"full": block_tables, "sliding": sliding_tables}, lengths, cfg)
-    moe = (jnp.stack(counts),) if counts else ()
-    return (logits,) + pools["full"] + pools["sliding"] + moe
+    ids / positions [B, S] (S=1 plain decode, S=k+1 speculative
+    verify); k_pool / v_pool [L, n_blocks, block_size, H_kv, hd] — the
+    cache's device-resident pools; block_tables [B, W] int32 (rows
+    padded with 0); lengths [B] int32 committed tokens per row.  Each
+    layer writes the window's K/V into the pool at positions
+    ``lengths[b] + s`` (physical address via the block table) and then
+    attends positions ``<= lengths[b] + s`` through
+    :func:`ops.paged_attention.paged_attention` in place: no dense
+    gather, no re-placement copy.  The updated pools are the cache (the
+    caller adopts them and advances each row's length by what it
+    commits — window slots past that hold garbage)."""
+    logits, pools, _ = decode_paged(params, ids, positions,
+                                    (k_pool, v_pool), block_tables,
+                                    lengths, (), cfg)
+    return (logits,) + pools
 
 
 # ---------------------------------------------------------------------------
@@ -1634,15 +1302,22 @@ def greedy_pick(logits):
     return ids, jnp.isfinite(picked)
 
 
-def picking_prefill(forward):
-    """``forward_prefill_paged*`` as the engine runs it.  In place of
-    ``last_index`` it takes ``(last_index [1], into [B, S], row [])``:
-    the pick is also written into ``into[row, 0]`` (nowhere where
+def _named(program, name):
+    """``program`` known to a trace as ``jit(name)``."""
+    if name:
+        program.__name__ = program.__qualname__ = name
+    return program
+
+
+def picking_prefill(forward, name=None):
+    """A prefill (:func:`prefill_paged`) as the engine runs it.  In
+    place of ``last_index`` it takes ``(last_index [1], into [B, S], row
+    [])``: the pick is also written into ``into[row, 0]`` (nowhere where
     ``row`` is out of range), so that a decode step can take it as a
     row's ``prev_ids`` before the host has read it.  Returns ``(ids
-    [1], finite [1], into, *rest)`` where ``forward`` returns
-    ``(logits [1, V], *rest)``.  The program keeps ``forward``'s name,
-    which is what a trace knows it by."""
+    [1], finite [1], into, *rest)`` where ``forward`` returns ``(logits
+    [1, V], *rest)``.  The program is known to a trace by ``name``
+    (default ``forward``'s own)."""
     @functools.wraps(forward)
     def program(params, ids, at, *args):
         last_index, into, row = at
@@ -1650,19 +1325,19 @@ def picking_prefill(forward):
         picked, finite = greedy_pick(logits)
         into = jnp.asarray(into).at[row, 0].set(picked[0], mode="drop")
         return (picked, finite, into, *rest)
-    return program
+    return _named(program, name)
 
 
-def picking_decode(forward):
-    """``forward_decode_paged*`` as the engine runs it.  In place of
-    ``ids`` it takes ``(host_ids [B, S], prev_ids [B, S], src [B])``:
-    row ``b`` consumes ``prev_ids[src[b]]``, the pick an earlier step
-    or a prefill left on the device, or ``host_ids[b]`` where ``src[b]
-    < 0`` (a row the host knows the last token of).  The row index lets
-    a batch that compacted or grew between two steps run the one
-    program.  Returns ``(ids [B, S],
-    finite [B, S], *rest)`` where ``forward`` returns ``(logits
-    [B, S, V], *rest)``."""
+def picking_decode(forward, name=None):
+    """A decode step (:func:`decode_paged`) as the engine runs it.  In
+    place of ``ids`` it takes ``(host_ids [B, S], prev_ids [B, S], src
+    [B])``: row ``b`` consumes ``prev_ids[src[b]]``, the pick an earlier
+    step or a prefill left on the device, or ``host_ids[b]`` where
+    ``src[b] < 0`` (a row the host knows the last token of).  The row
+    index lets a batch that compacted or grew between two steps run the
+    one program.  Returns ``(ids [B, S], finite [B, S], *rest)`` where
+    ``forward`` returns ``(logits [B, S, V], *rest)``; known to a trace
+    by ``name`` (default ``forward``'s own)."""
     @functools.wraps(forward)
     def program(params, ids, *args):
         host_ids, prev_ids, src = ids
@@ -1671,14 +1346,14 @@ def picking_decode(forward):
                         host_ids)
         logits, *rest = forward(params, fed, *args)
         return (*greedy_pick(logits), *rest)
-    return program
+    return _named(program, name)
 
 
 # ---------------------------------------------------------------------------
-# the latent family on the serving path: multi-head latent attention
+# the latent family's mixer and experts: multi-head latent attention
 # whose paged cache holds one latent row per token and layer, a leading
 # dense layer, and sigmoid-routed dropless experts of which this chip
-# holds a share.  Paged path only (the device pool is the cache).
+# holds a share.
 # ---------------------------------------------------------------------------
 
 #: bytes one trip of the held experts' loop may hold in temporaries (the
@@ -1796,12 +1471,13 @@ def _mla_project(xn, p, positions, cfg: TransformerConfig):
     return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], -1)
 
 
-def _mla_prefill_attention(xn, p, positions, cfg: TransformerConfig):
+def _mla_prefill(xn, layer, cache, cfg: TransformerConfig):
     """Causal MLA over a whole sequence, up-projected: keys
     [k_nope | k_pe] at qk width and values at v width per head, through
-    the flash kernel (or its lax twin).  Returns ``(y [B,T,E], row)``."""
-    rkv, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    q_nope, q_pe, row = _mla_project(xn, p, positions, cfg)
+    the flash kernel (or its lax twin).  Returns ``(y [B,T,E], row)``,
+    ``row`` what the cache keeps of each token."""
+    rkv, nope, p = cfg.kv_lora_rank, cfg.qk_nope_head_dim, layer.p
+    q_nope, q_pe, row = _mla_project(xn, p, cache.positions, cfg)
     kv = jnp.einsum("btr,rhd->bthd", row[..., :rkv], p["w_kvb"])
     k_pe = jnp.broadcast_to(row[:, :, None, rkv:],
                             q_pe.shape[:2] + (cfg.n_heads, q_pe.shape[-1]))
@@ -1997,73 +1673,12 @@ def _moe_held_ffn(x, p, cfg: TransformerConfig, valid=None,
 _EXPERT_STACKS = ("w_in", "w_gate", "w_out")
 
 
-def _latent_layers(params):
-    """Yields ``(layer params, first_group)`` in order: the leading
-    dense group (``first_group`` None), then the expert layers, whose
-    routed experts stay ONE stack [S * L/S * X, ...] with the layer's
-    own at ``first_group`` (see :func:`_moe_held_ffn`)."""
-    dense, blocks = params["dense"], params["blocks"]
-    for i in range(dense["ln2"].shape[0]):
-        yield jax.tree.map(lambda a: a[i], dense), None
-    n_stages, lps = blocks["ln2"].shape[:2]
-    stacks = {name: blocks[name].reshape((-1,) + blocks[name].shape[3:])
-              for name in _EXPERT_STACKS}
-    n_held = blocks["w_in"].shape[2]
-    rest = {name: a for name, a in blocks.items() if name not in stacks}
-    for s in range(n_stages):
-        for i in range(lps):
-            yield ({**_layer_params(rest, s, i), **stacks},
-                   (s * lps + i) * n_held)
-
-
-def _latent_ffn(x, p, first_group, cfg: TransformerConfig, valid, counts):
-    """The layer's second half on the residual stream; an expert
-    layer's routing counts are appended to ``counts``."""
-    xn = rms_norm(x, p["ln2"])
-    if first_group is None:
-        with jax.named_scope("mlp"):
-            return x + swiglu_ffn(xn, p["w_in"], p["w_gate"], p["w_out"],
-                                  ShardAxes())
-    with jax.named_scope("moe"):
-        y, c = _moe_held_ffn(xn, p, cfg, valid, first_group)
-    counts.append(c)
-    return x + y
-
-
 def _write_prompt_rows(pool, layer: int, block_ids, row):
     """A whole prompt's latent rows ``row`` [1, T, r] into pages
     ``block_ids`` of one layer, each page ``[r, slot]``."""
     bs = pool.shape[3]
     return pool.at[layer, block_ids].set(jnp.swapaxes(
         row.reshape(-1, bs, row.shape[-1]), 1, 2).astype(pool.dtype))
-
-
-def forward_prefill_paged_mla(params, ids, last_index, pool, block_ids,
-                              cfg: TransformerConfig):
-    """:func:`forward_prefill_paged` of the latent family: prefill of
-    ONE sequence that writes its latent rows into the paged pool on the
-    device.  ids [1, T], T a whole number of blocks; pool [L, n_blocks,
-    kv_lora_rank + qk_rope_head_dim, block_size] (a page is [row,
-    slot]: ``TransformerConfig.kv_pool_shapes``); block_ids [T /
-    block_size].  Attention runs up-projected at qk / v width (the
-    flash kernel); only ``[rms(c_kv) | rope(k_pe)]`` is cached.  Returns
-    ``(logits [1, V], pool, moe [n_moe_layers, n_experts + 2])``: the
-    pool donated and updated in place, and the routing counts of the
-    prompt's real tokens (see :func:`_moe_held_ffn`)."""
-    _, t = ids.shape
-    positions = jnp.arange(t)[None]
-    valid = positions <= last_index[:, None]
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
-    counts = []
-    for li, (p, first_group) in enumerate(_latent_layers(params)):
-        with jax.named_scope("mla"):
-            y, row = _mla_prefill_attention(rms_norm(x, p["ln1"]), p,
-                                            positions, cfg)
-            x = x + y
-            pool = _write_prompt_rows(pool, li, block_ids, row)
-        x = _latent_ffn(x, p, first_group, cfg, valid, counts)
-    x = rms_norm(x, params["ln_f"])
-    return _logits_at(params, x, last_index, cfg), pool, jnp.stack(counts)
 
 
 def _write_latent_rows(pool, layer: int, blocks, slots, row):
@@ -2083,85 +1698,37 @@ def _write_latent_rows(pool, layer: int, blocks, slots, row):
     return pool
 
 
-def _latent_window(pool, block_tables, lengths, s_w: int):
-    """Where a decode window's latent rows go: ``(blocks [B, S], slots
-    [B, S])``, positions ``lengths[b] + s`` through the block table; a
-    dead row (length 0) gets a block past the pool, which drops its
-    write."""
-    n_blocks, bs = pool.shape[1], pool.shape[3]
-    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]
-    wb = jnp.take_along_axis(
-        block_tables, jnp.clip(pos_w // bs, 0, block_tables.shape[1] - 1),
-        axis=1)
-    return jnp.where(lengths[:, None] > 0, wb, n_blocks), pos_w % bs
-
-
-def _mla_decode_attention(xn, p, positions, pool, li: int, wb, ws,
-                          block_tables, lengths, cfg: TransformerConfig):
+def _mla_decode(xn, layer, li: int, cache, cfg: TransformerConfig):
     """One layer's absorbed latent attention of a decode window: the
-    window's rows into pool layer ``li``, then the rows themselves
-    attended.  Returns ``(attention's addend [B, S, E], pool)``."""
+    window's rows into pool layer ``li`` at ``lengths[b] + s``, then the
+    rows themselves attended (K = the row, V = its first kv_lora_rank
+    values) through :func:`ops.paged_attention.latent_paged_attention`,
+    for all heads from the one page.  Returns attention's addend [B, S,
+    E]."""
     from ..ops import paged_attention as _paged
 
-    q_nope, q_pe, row = _mla_project(xn, p, positions, cfg)
+    p = layer.p
+    pool, = cache.get("pages")
+    wb, ws = cache.where["pages"]
+    q_nope, q_pe, row = _mla_project(xn, p, cache.positions, cfg)
     pool = _write_latent_rows(pool, li, wb, ws, row)
+    cache.set("pages", pool)
     # the layers' pools as one run of pages: a per-layer slice of the
     # pool would be copied for the kernel
     o_lat = _paged.latent_paged_attention(
         _mla_absorbed_queries(q_nope, q_pe, p, cfg),
         pool.reshape((-1,) + pool.shape[2:]),
-        block_tables + li * pool.shape[1], lengths,
+        cache.tables["pages"] + li * pool.shape[1], cache.lengths,
         v_dim=cfg.kv_lora_rank, scale=_mla_scale(cfg))
-    return _mla_absorbed_output(o_lat, p, cfg), pool
-
-
-def forward_decode_paged_mla(params, ids, positions, pool, block_tables,
-                             lengths, cfg: TransformerConfig):
-    """:func:`forward_decode_paged` of the latent family, absorbed:
-    each layer scatters the window's latent rows into the pool at
-    ``lengths[b] + s`` and attends the rows themselves (K = the row,
-    V = its first kv_lora_rank values) through
-    :func:`ops.paged_attention.latent_paged_attention`, for all heads
-    from the one page.  Returns ``(logits [B, S, V], pool, moe)``; dead
-    rows (length 0) scatter out of bounds and are not counted."""
-    b, s_w = ids.shape
-    wb, ws = _latent_window(pool, block_tables, lengths, s_w)
-    valid = jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
-    counts = []
-    for li, (p, first_group) in enumerate(_latent_layers(params)):
-        with jax.named_scope("mla"):
-            y, pool = _mla_decode_attention(
-                rms_norm(x, p["ln1"]), p, positions, pool, li, wb, ws,
-                block_tables, lengths, cfg)
-            x = x + y
-        x = _latent_ffn(x, p, first_group, cfg, valid, counts)
-    with jax.named_scope("unembed"):
-        x = rms_norm(x, params["ln_f"])
-        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
-    return logits, pool, jnp.stack(counts)
+    return _mla_absorbed_output(o_lat, p, cfg)
 
 
 # ---------------------------------------------------------------------------
-# the hybrid family on the serving path: KDA (ops/kda.py) in most
-# layers, its per-sequence state in a slot of the cache manager, MLA in
-# the rest with its rows in the paged pool; the latent family's FFNs,
-# the router group-limited.  Paged path only, one token a decode step.
+# the hybrid family's KDA mixer (ops/kda.py), in most of its layers, its
+# per-sequence state in a slot of the cache manager; MLA in the rest
+# with its rows in the paged pool; the latent family's FFNs, the router
+# group-limited.  One token a decode step.
 # ---------------------------------------------------------------------------
-
-
-def _hybrid_layers(params, cfg: TransformerConfig):
-    """Yields ``(kind, i, attention params, ffn params, first_group)``
-    in layer order: layer ``i`` of its kind's group (``params["kda"]``
-    or ``["mla"]``, which is also its index into the state slots or the
-    pool), and the FFN half as :func:`_latent_layers` gives it."""
-    seen = {"kda": 0, "mla": 0}
-    for kind, (ffn, first_group) in zip(cfg.layer_kinds,
-                                        _latent_layers(params)):
-        i = seen[kind]
-        seen[kind] += 1
-        yield (kind, i, jax.tree.map(lambda a: a[i], params[kind]), ffn,
-               first_group)
 
 
 def _kda_project(xn, p, cfg: TransformerConfig):
@@ -2200,15 +1767,16 @@ def _kda_output(o, gate, p, cfg: TransformerConfig):
     return jnp.einsum("bthd,hde->bte", o.astype(cfg.jdtype), p["wo"])
 
 
-def _kda_prefill_attention(xn, p, valid, last_index, cfg: TransformerConfig):
+def _kda_prefill(xn, layer, cache, cfg: TransformerConfig):
     """KDA over ONE whole sequence ``xn`` [1, T, E] from a zero state.
-    ``valid`` [1, T] marks the prompt's real tokens: a padded position
-    neither decays nor writes the state (beta = 0, g = 0), and the
-    convolution's tail is taken at ``last_index`` [1].  Returns
-    ``(attention's addend [1, T, E], S^T [H, d, d], tail [W - 1,
-    3 H d])``."""
+    ``cache.valid`` [1, T] marks the prompt's real tokens: a padded
+    position neither decays nor writes the state (beta = 0, g = 0), and
+    the convolution's tail is taken at ``cache.last_index`` [1].
+    Chunk-wise (``ops.kda.kda_chunk_scan``).  Returns ``(attention's
+    addend [1, T, E], (S^T [H, d, d], tail [W - 1, 3 H d]))``."""
     from ..ops import kda as _kda
 
+    p, valid, last_index = layer.p, cache.valid, cache.last_index
     w = cfg.kda_conv_size
     qkv, g, beta, gate = _kda_project(xn, p, cfg)
     t = qkv.shape[1]
@@ -2230,53 +1798,19 @@ def _kda_prefill_attention(xn, p, valid, last_index, cfg: TransformerConfig):
         o, s_t = _kda.kda_chunk_scan(
             q[0], k[0], v[0], jnp.where(valid[0, :, None, None], g[0], 0.0),
             jnp.where(valid[0, :, None], beta[0], 0.0))
-    return _kda_output(o[None], gate, p, cfg), s_t, tail
+    return _kda_output(o[None], gate, p, cfg), (s_t, tail)
 
 
-def forward_prefill_paged_hybrid(params, ids, last_index, pool, state, tails,
-                                 block_ids, slot, cfg: TransformerConfig):
-    """:func:`forward_prefill_paged_mla` of the hybrid family: prefill
-    of ONE sequence that leaves its MLA layers' latent rows in the
-    paged pool (``pool [n MLA layers, n_blocks, row, block_size]``,
-    blocks ``block_ids``) and each KDA layer's final state and
-    convolution tail in the sequence's ``slot`` [1] of ``state`` / ``tails``
-    (``TransformerConfig.state_slot_shapes``), all three donated and
-    updated in place.  KDA runs chunk-wise (``ops.kda.kda_chunk_scan``);
-    padding does not touch the state.  Returns ``(logits [1, V], pool,
-    state, tails, moe)``."""
-    _, t = ids.shape
-    positions = jnp.arange(t)[None]
-    valid = positions <= last_index[:, None]
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
-    counts = []
-    for kind, i, p, ffn, first_group in _hybrid_layers(params, cfg):
-        xn = rms_norm(x, p["ln1"])
-        if kind == "mla":
-            with jax.named_scope("mla"):
-                y, row = _mla_prefill_attention(xn, p, positions, cfg)
-                pool = _write_prompt_rows(pool, i, block_ids, row)
-        else:
-            with jax.named_scope("kda"):
-                y, s_t, tail = _kda_prefill_attention(xn, p, valid,
-                                                      last_index, cfg)
-                state = state.at[i, slot[0]].set(s_t)
-                tails = tails.at[i, slot[0]].set(
-                    tail.reshape(tails.shape[2:]).astype(tails.dtype))
-        x = _latent_ffn(x + y, ffn, first_group, cfg, valid, counts)
-    x = rms_norm(x, params["ln_f"])
-    return (_logits_at(params, x, last_index, cfg), pool, state, tails,
-            jnp.stack(counts))
-
-
-def _kda_decode_attention(xn, p, state, tails, li: int, slots, live,
-                          cfg: TransformerConfig):
+def _kda_decode(xn, layer, li: int, cache, cfg: TransformerConfig):
     """One KDA layer of one decode token a row: the convolution over
     the slot's tail and the new input, then ``ops.kda.kda_state_step``
     on the row's state in place.  A dead row reads slot 0's tail, which
-    is harmless, and writes nothing.  Returns ``(attention's addend
-    [B, 1, E], state, tails)``."""
+    is harmless, and writes nothing.  Returns attention's addend [B, 1,
+    E]."""
     from ..ops import kda as _kda
 
+    p, slots, live = layer.p, cache.slots, cache.live
+    state, tails = cache.get("state")
     n_slots = state.shape[1]
     qkv, g, beta, gate = _kda_project(xn, p, cfg)
     with jax.named_scope("conv"):
@@ -2294,66 +1828,19 @@ def _kda_decode_attention(xn, p, state, tails, li: int, slots, live,
             q, k, v, g[:, 0], beta[:, 0],
             state.reshape((-1,) + state.shape[2:]), slots + li * n_slots,
             live)
-    return (_kda_output(o[:, None], gate, p, cfg), flat.reshape(state.shape),
-            tails)
-
-
-def forward_decode_paged_hybrid(params, ids, positions, pool, state, tails,
-                                block_tables, lengths, slots,
-                                cfg: TransformerConfig):
-    """:func:`forward_decode_paged_mla` of the hybrid family, one token
-    a row (``ids`` [B, 1]: recurrent state has no rollback, so there is
-    no verify window).  ``slots`` [B] is each row's state slot beside
-    its block table; a dead row (length 0) writes neither pool nor
-    state.  Returns ``(logits [B, 1, V], pool, state, tails, moe)``."""
-    b, s_w = ids.shape
-    assert s_w == 1, "recurrent layers decode one token a step"
-    live = lengths > 0
-    wb, ws = _latent_window(pool, block_tables, lengths, s_w)
-    valid = live[:, None]
-    x = embed_lookup(params["embed"], ids, ShardAxes()).astype(cfg.jdtype)
-    counts = []
-    for kind, i, p, ffn, first_group in _hybrid_layers(params, cfg):
-        xn = rms_norm(x, p["ln1"])
-        if kind == "mla":
-            with jax.named_scope("mla"):
-                y, pool = _mla_decode_attention(
-                    xn, p, positions, pool, i, wb, ws, block_tables,
-                    lengths, cfg)
-        else:
-            with jax.named_scope("kda"):
-                y, state, tails = _kda_decode_attention(
-                    xn, p, state, tails, i, slots, live, cfg)
-        x = _latent_ffn(x + y, ffn, first_group, cfg, valid, counts)
-    with jax.named_scope("unembed"):
-        x = rms_norm(x, params["ln_f"])
-        logits = jnp.einsum("bte,ev->btv", x, params["unembed"])
-    return logits, pool, state, tails, jnp.stack(counts)
+    y = _kda_output(o[:, None], gate, p, cfg)
+    cache.set("state", flat.reshape(state.shape), tails)
+    return y
 
 
 # ---------------------------------------------------------------------------
-# a model under a layer pattern on the serving path (Nemotron-H's
-# block): every layer ONE thing under one norm.  "M" a Mamba-2 mixer
+# the Mamba-2 mixer of a model under a layer pattern (Nemotron-H's
+# block: every layer ONE thing under one norm).  "M" a Mamba-2 mixer
 # (ops/mamba2.py), its per-sequence state and convolution tail in a
 # slot of the cache manager; "*" grouped-query attention without
 # positions, its K/V in the paged pool; "E" the held experts in their
-# latent.  Paged path only, one token a decode step.
+# latent.  One token a decode step.
 # ---------------------------------------------------------------------------
-
-
-def _pattern_layers(params, cfg: TransformerConfig):
-    """Yields ``(kind, i, layer params, first_group)`` in layer order:
-    layer ``i`` of its kind (its index into the state slots or the
-    pool); an expert layer's dict carries the one stack of held experts
-    and its own start ``first_group`` in it (else None)."""
-    seen = dict.fromkeys(("mamba", "full", "moe"), 0)
-    for kind, p in zip(cfg.layer_kinds, params["layers"]):
-        i = seen[kind]
-        seen[kind] += 1
-        if kind == "moe":
-            yield kind, i, {**p, **params["experts"]}, i * cfg.n_experts
-        else:
-            yield kind, i, p, None
 
 
 def _mamba_split(zxbcdt, p, cfg: TransformerConfig):
@@ -2430,14 +1917,16 @@ def _mamba_rows(xa, valid, carry, p, cfg: TransformerConfig):
     return _mamba_output(o, z, p, cfg), (state, window[0, r:])
 
 
-def _mamba_prefill(xn, p, valid, last_index, cfg: TransformerConfig):
+def _mamba_prefill(xn, layer, cache, cfg: TransformerConfig):
     """A Mamba-2 mixer over ONE whole sequence ``xn`` [1, T, E] from a
     zero state, :data:`MAMBA_PREFILL_ROWS` rows a pass where the prompt
-    is longer (a multiple of it).  ``valid`` [1, T] marks the prompt's
-    real tokens: a padded position neither decays nor writes the state
-    (dt = 0), and the convolution's tail is taken at ``last_index``
-    [1].  Returns ``(the mixer's addend [1, T, E], state [H, P, N], tail
-    [W - 1, conv_dim])``."""
+    is longer (a multiple of it), chunk-wise
+    (``ops.mamba2.ssd_chunk_scan``).  ``cache.valid`` [1, T] marks the
+    prompt's real tokens: a padded position neither decays nor writes
+    the state (dt = 0), and the convolution's tail is taken at
+    ``cache.last_index`` [1].  Returns ``(the mixer's addend [1, T, E],
+    (state [H, P, N], tail [W - 1, conv_dim]))``."""
+    p, valid, last_index = layer.p, cache.valid, cache.last_index
     w = cfg.mamba_conv_size
     _, t, e = xn.shape
     xa = xn.astype(cfg.jdtype)
@@ -2466,18 +1955,19 @@ def _mamba_prefill(xn, p, valid, last_index, cfg: TransformerConfig):
             _mamba_split(jnp.einsum(
                 "te,ef->tf", jnp.take(xa[0], jnp.maximum(at, 0), 0),
                 p["in_proj"]), p, cfg)[1], 0)
-    return y, state, tail
+    return y, (state, tail)
 
 
-def _mamba_decode(xn, p, state, tails, li: int, slots, live,
-                  cfg: TransformerConfig):
+def _mamba_decode(xn, layer, li: int, cache, cfg: TransformerConfig):
     """One Mamba-2 layer of one decode token a row: the convolution
     over the slot's tail and the new input, then
     ``ops.mamba2.ssm_state_step`` on the row's state in place.  A dead
     row reads slot 0's tail, which is harmless, and writes nothing.
-    Returns ``(the mixer's addend [B, 1, E], state, tails)``."""
+    Returns the mixer's addend [B, 1, E]."""
     from ..ops import mamba2 as _mamba2
 
+    p, slots, live = layer.p, cache.slots, cache.live
+    state, tails = cache.get("state")
     n_slots = state.shape[1]
     with jax.named_scope("in_proj"):
         z, xbc, dt = _mamba_split(jnp.einsum(
@@ -2500,133 +1990,594 @@ def _mamba_decode(xn, p, state, tails, li: int, slots, live,
             state.reshape((-1,) + state.shape[2:]), slots + li * n_slots,
             live)
         o = o + p["d"][:, None] * x
-    return (_mamba_output(o[:, None], z, p, cfg), flat.reshape(state.shape),
-            tails)
+    y = _mamba_output(o[:, None], z, p, cfg)
+    cache.set("state", flat.reshape(state.shape), tails)
+    return y
 
 
-def _pattern_qkv(xn, p, cfg: TransformerConfig):
-    """An attention layer's q ``[B, T, H, hd]``, k and v ``[B, T, H_kv,
-    hd]`` of normed activations: no bias, no positions."""
-    xa = xn.astype(cfg.jdtype)
-    return tuple(jnp.einsum("bte,ehd->bthd", xa, p[name])
-                 for name in ("wq", "wk", "wv"))
+# ---------------------------------------------------------------------------
+# the serving walk: ONE prefill and ONE decode program for every family.
+# A layer is a mixer (attention of a kind, latent attention, KDA, Mamba-2,
+# or under a layer pattern the held experts alone) and, outside a layer
+# pattern, an FFN half.  Each kind's mixer has a prefill and a decode
+# half (_MIXERS), which read and write that kind's arrays of the cache
+# through one handle (_Cache).  Paged path only: the device arrays are
+# the cache.
+# ---------------------------------------------------------------------------
 
 
-def _stacked(counts, cfg: TransformerConfig):
-    """The expert layers' routing counts ``[n, n_experts + 2]``, none
-    under a pattern cut before its first expert layer."""
-    return jnp.stack(counts) if counts else jnp.zeros(
-        (0, cfg.n_experts + 2), jnp.int32)
+class _Layer(NamedTuple):
+    """One layer as the walk takes it: its kind (``cfg.layer_kinds``),
+    its index among the layers of that kind (its index into the kind's
+    pool layers or state slots), its params, and where its held experts
+    start in the one stack of them (None where it holds none:
+    :func:`_moe_held_ffn`)."""
+    kind: str
+    i: int
+    p: dict
+    first_group: Optional[int]
 
 
-def _write_pages(pool, layer: int, block_ids, a):
-    """K or V of ONE sequence's whole blocks ``a [1, n x bs, H_kv, hd]``
-    into pages ``block_ids`` of one layer, each page written as ONE row
-    of ``bs x H_kv x hd`` values (a free view of the pool): with 2 K/V
-    heads a page ``[bs, 2, hd]`` is tiled (2, 128), and for a scatter of
-    whole ``[bs, H_kv, hd]`` windows the chip's compiler laid the pool
-    out anew, into and out of every prefill (0.6 GB of copies)."""
+def _one_sublayer(cfg: TransformerConfig) -> bool:
+    """Whether a layer is ONE sublayer under one norm (a layer
+    pattern's), not a mixer followed by an FFN half."""
+    return bool(cfg.layer_pattern)
+
+
+def _stacked_halves(params):
+    """The layers of a stacked tree (the latent and hybrid families')
+    as ``(layer params, first_group)`` in order: the leading dense group
+    (``first_group`` None), then the expert layers, whose routed experts
+    stay ONE stack [S * L/S * X, ...] with the layer's own at
+    ``first_group``.  A latent tree's attention rides in the same
+    dicts."""
+    dense, blocks = params["dense"], params["blocks"]
+    for i in range(dense["ln2"].shape[0]):
+        yield jax.tree.map(lambda a: a[i], dense), None
+    n_stages, lps = blocks["ln2"].shape[:2]
+    stacks = {name: blocks[name].reshape((-1,) + blocks[name].shape[3:])
+              for name in _EXPERT_STACKS}
+    n_held = blocks["w_in"].shape[2]
+    rest = {name: a for name, a in blocks.items() if name not in stacks}
+    for s in range(n_stages):
+        for i in range(lps):
+            yield ({**_layer_params(rest, s, i), **stacks},
+                   (s * lps + i) * n_held)
+
+
+def _listed_halves(params, cfg: TransformerConfig):
+    """The layers of a tree that lists them (``layers``, one dict a
+    layer) as ``(layer params, first_group)``: where the tree has held
+    experts (``experts``, one stack), every layer that has an FFN (under
+    a layer pattern an expert layer) carries the stack, its own at
+    ``first_group``."""
+    experts = params.get("experts")
+    held = 0
+    for kind, p in zip(cfg.layer_kinds, params["layers"]):
+        if experts is None or (_one_sublayer(cfg) and kind != "moe"):
+            yield p, None
+        else:
+            yield {**p, **experts}, held * cfg.n_experts
+            held += 1
+
+
+def _serving_layers(params, cfg: TransformerConfig):
+    """Yields every layer of any family's tree, in order, as a
+    :class:`_Layer`.  A stacked MHA tree's per-layer slices are taken
+    here, inside the caller's program (:func:`per_layer_params`); a
+    hybrid's attention is stacked by kind (``params[kind]``) beside its
+    FFN halves."""
+    if "dense" in params:
+        halves = _stacked_halves(params)
+    else:
+        halves = _listed_halves(per_layer_params(params), cfg)
+    seen = {}
+    for kind, (p, first_group) in zip(cfg.layer_kinds, halves):
+        i = seen.get(kind, 0)
+        seen[kind] = i + 1
+        if kind in params:
+            p = {**p, **jax.tree.map(lambda a: a[i], params[kind])}
+        yield _Layer(kind, i, p, first_group)
+
+
+def _ffn(xn, layer: _Layer, valid, counts, cfg: TransformerConfig):
+    """A layer's FFN on normed activations: the held experts (their
+    routing counts over ``valid`` appended to ``counts``), one dense
+    SwiGLU (a looped model's, a stacked tree's leading dense layers), or
+    the trained mixtures."""
+    p = layer.p
+    if layer.first_group is not None:
+        with jax.named_scope("moe"):
+            y, c = _moe_held_ffn(xn, p, cfg, valid, layer.first_group)
+        counts.append(c)
+        return y
+    if p["w_in"].ndim == 2:
+        return swiglu_ffn(xn.astype(cfg.jdtype), p["w_in"], p["w_gate"],
+                          p["w_out"], ShardAxes(),
+                          out_dtype=_into_stream(cfg).get(
+                              "preferred_element_type"))
+    return _moe_ffn(xn.astype(cfg.jdtype), p, ShardAxes(), cfg)
+
+
+def _ffn_half(x, xn, y, layer: _Layer, valid, counts,
+              cfg: TransformerConfig):
+    """The residual stream after a layer.  Sequential block: ``x``
+    already holds the mixer's addend (``y`` None), and the FFN takes a
+    second norm of it.  Parallel block: the FFN takes the layer's one
+    normed input ``xn`` and both addends land together.  A stacked
+    tree's dense FFN and its addend are named "mlp"."""
+    if cfg.parallel_block:
+        return x + y + _ffn(xn, layer, valid, counts, cfg)
+    xn = _norm(x, layer.p["ln2"], cfg)
+    with _scope("mlp" if cfg.latent and layer.first_group is None
+                else None):
+        return x + _post_norm(_ffn(xn, layer, valid, counts, cfg), layer.p,
+                              "ln2_post", cfg)
+
+
+def _mixer_addend(x, y, p, cfg: TransformerConfig):
+    """``(x, y)`` after the mixer's addend ``y`` joined the stream, which
+    in a parallel block waits for the FFN's (``y`` stays)."""
+    y = _post_norm(y, p, "ln1_post", cfg)
+    if cfg.parallel_block:
+        return x, y
+    return x + y, None
+
+
+def _attention_keys(xn, layer: _Layer, cfg: TransformerConfig):
+    """An attention layer's keys and values of a whole prompt (normed
+    ``xn`` [B, T, E]), ``[B, T, H_kv, hd]`` as the cache holds them:
+    rotated where the kind rotates, none otherwise."""
+    xa = xn.astype(cfg.jdtype)  # the products' operand
+    k = _heads(xa, layer.p["wk"], cfg)
+    v = _heads(xa, layer.p["wv"], cfg)
+    if _rotates(layer.kind, cfg):
+        k = rope(k, jnp.arange(xn.shape[1]), cfg.rope_theta)
+    return k, v
+
+
+def _attention_rows(xn, layer: _Layer, cfg: TransformerConfig, kv, first,
+                    n_chunks: int):
+    """Rows ``[first, first + R)`` of a prompt (normed ``xn`` [B, R, E])
+    attending its keys and values ``kv`` causally, a sliding layer
+    within its window: the addend [B, R, E]."""
+    k, v = kv
+    t = k.shape[1]
+    span = cfg.sliding_window if layer.kind == "sliding" else 0
+    q = _heads(xn.astype(cfg.jdtype), layer.p["wq"], cfg)
+    if _rotates(layer.kind, cfg):
+        q = rope(q, first + jnp.arange(xn.shape[1]), cfg.rope_theta)
+    # the scope states the call's T, window and chunks: the benchmark's
+    # roofline reader counts each call's operations from them
+    with _scope(_MIXERS[layer.kind].scopes[0]
+                and f"prefill_attn_t{t}_w{span}_c{n_chunks}"):
+        o = _causal_attention(q, k, v, span=span,
+                              q_offset=None if n_chunks == 1 else first)
+    return jnp.einsum("bthd,hde->bte", o, layer.p["wo"], **_into_stream(cfg))
+
+
+def _attention_decode(xn, layer: _Layer, li, cache, cfg: TransformerConfig):
+    """An attention layer of a decode window: the window's K/V written
+    into its pools' layer ``li`` at ``lengths[b] + s`` (dead rows drop
+    their write), then attended in place through
+    :func:`ops.paged_attention.paged_attention` (``paged_attn``, one
+    call a layer), which reads through the table only the pages a live
+    row's positions fill: a padded table entry or a dead row costs it
+    nothing.  A sliding layer writes its ring and attends its window
+    alone.  Returns the addend [B, S, E]."""
+    from ..ops import paged_attention as _paged_attn
+
+    p, group = layer.p, _MIXERS[layer.kind].group
+    k_pool, v_pool = cache.get(group)
+    wb, ws = cache.where[group]
+    xa = xn.astype(cfg.jdtype)  # the products' operand
+    q = _heads(xa, p["wq"], cfg)
+    k = _heads(xa, p["wk"], cfg)
+    v = _heads(xa, p["wv"], cfg)
+    if _rotates(layer.kind, cfg):
+        q = _rope_window(q, cache.positions, cfg.rope_theta)
+        k = _rope_window(k, cache.positions, cfg.rope_theta)
+    k_pool = k_pool.at[li, wb, ws].set(k.astype(k_pool.dtype), mode="drop")
+    v_pool = v_pool.at[li, wb, ws].set(v.astype(v_pool.dtype), mode="drop")
+    cache.set(group, k_pool, v_pool)
+    # the layers' pools as one run of pages, a free view: a per-layer
+    # slice of a pool would be copied for the kernel
+    o = _paged_attn.paged_attention(
+        q, k_pool.reshape((-1,) + k_pool.shape[2:]),
+        v_pool.reshape((-1,) + v_pool.shape[2:]),
+        cache.tables[group] + li * k_pool.shape[1], cache.lengths,
+        span=cfg.sliding_window if layer.kind == "sliding" else 0)
+    return jnp.einsum("bthd,hde->bte", o, p["wo"], **_into_stream(cfg))
+
+
+def _moe_prefill(xn, layer: _Layer, cache, cfg: TransformerConfig):
+    """A layer pattern's expert layer, whose mixer is the FFN itself."""
+    return _ffn(xn, layer, cache.valid, cache.counts, cfg), None
+
+
+def _moe_decode(xn, layer: _Layer, li, cache, cfg: TransformerConfig):
+    return _ffn(xn, layer, cache.valid, cache.counts, cfg)
+
+
+class _Mixer(NamedTuple):
+    """A kind of layer's mixer.  ``scopes``: the named scope of its
+    prefill half and of its decode half.  ``group``: which arrays of the
+    cache it reads and writes (:class:`_Cache`).  ``prefill(xn, layer,
+    cache, cfg) -> (addend, what the cache keeps)``, or where ``keys``
+    is set (attention) ``keys(xn, layer, cfg) -> kept`` of the whole
+    prompt and ``prefill(xn, layer, cfg, kept, first, n_chunks) ->
+    addend`` of its rows from ``first``.  ``decode(xn, layer, li, cache,
+    cfg) -> addend``, ``li`` the layer's index into the group's arrays
+    (a looped model's pass offset added)."""
+    scopes: tuple
+    group: str
+    prefill: Callable
+    decode: Callable
+    keys: Optional[Callable] = None
+
+
+def _attention_mixer(scopes, group):
+    return _Mixer(scopes, group, _attention_rows, _attention_decode,
+                  _attention_keys)
+
+
+#: every kind of layer's mixer.  The flagship's ("mha") prefill has no
+#: scope and its decode is "attention", as traces have always known them
+_MIXERS = {
+    "mha": _attention_mixer((None, "attention"), "pages"),
+    "full": _attention_mixer(("attn_full",) * 2, "pages"),
+    "sliding": _attention_mixer(("attn_sliding",) * 2, "ring"),
+    "mla": _Mixer(("mla",) * 2, "pages", _mla_prefill, _mla_decode),
+    "kda": _Mixer(("kda",) * 2, "state", _kda_prefill, _kda_decode),
+    "mamba": _Mixer(("mamba",) * 2, "state", _mamba_prefill, _mamba_decode),
+    "moe": _Mixer((None, None), "", _moe_prefill, _moe_decode),
+}
+
+
+def _wide_scopes(cfg: TransformerConfig) -> bool:
+    """Whether a mixer's scope holds the layer's first norm and the
+    addend's join too (as the programs of the families without
+    recurrent layers always had it), or the mixer alone."""
+    return all(_MIXERS[kind].group != "state" for kind in cfg.layer_kinds)
+
+
+def _write_prompt_pages(pool, layers, block_ids, a, rows: bool = False):
+    """K or V of ONE sequence's whole blocks ``a [..., 1, n x bs, H_kv,
+    hd]`` into pages ``block_ids`` of pool layer(s) ``layers``.  With
+    ``rows`` each page is written as ONE row of ``bs x H_kv x hd``
+    values (a free view of the pool): Nemotron-H's page of 2 K/V heads
+    ``[bs, 2, hd]`` is tiled (2, 128), and for a scatter of whole
+    ``[bs, H_kv, hd]`` windows the chip's compiler laid the pool out
+    anew, into and out of every prefill (0.6 GB of copies)."""
+    if not rows:
+        return pool.at[layers, block_ids].set(_paged(a, pool))
     n_layers, n_blocks = pool.shape[:2]
     flat = pool.reshape(n_layers, n_blocks, -1)
-    flat = flat.at[layer, block_ids].set(
-        a.reshape(block_ids.shape[0], -1).astype(pool.dtype))
+    flat = flat.at[layers, block_ids].set(
+        a.reshape(a.shape[:-4] + (block_ids.shape[0], -1)).astype(pool.dtype))
     return flat.reshape(pool.shape)
 
 
-def forward_prefill_paged_pattern(params, ids, last_index, k_pool, v_pool,
-                                  state, tails, block_ids, slot,
-                                  cfg: TransformerConfig):
-    """:func:`forward_prefill_paged` of a model under a layer pattern:
-    prefill of ONE sequence that leaves its attention layers' K/V in the
-    paged pools (``k_pool`` / ``v_pool [n attention layers, n_blocks,
-    block_size, H_kv, hd]``, blocks ``block_ids``) and each Mamba-2
-    layer's final state and convolution tail in the sequence's ``slot``
-    [1] of ``state`` / ``tails`` (``TransformerConfig.
-    state_slot_shapes``), all four donated and updated in place.  The
-    scan runs chunk-wise (``ops.mamba2.ssd_chunk_scan``); padding does
-    not touch the state.  Returns ``(logits [1, V], k_pool, v_pool,
-    state, tails, moe)``."""
-    _, t = ids.shape
-    valid = jnp.arange(t)[None] <= last_index[:, None]
+def _write_prompt_rows(pool, layer: int, block_ids, row):
+    """A whole prompt's latent rows ``row`` [1, T, r] into pages
+    ``block_ids`` of one layer, each page ``[r, slot]``."""
+    bs = pool.shape[3]
+    return pool.at[layer, block_ids].set(jnp.swapaxes(
+        row.reshape(-1, bs, row.shape[-1]), 1, 2).astype(pool.dtype))
+
+
+def _window_at(pool, table, lengths, pos_w, ring: bool):
+    """Where a decode window's entries go in ``pool`` (a K/V pool, whose
+    page is ``[block_size, ...]``, or the latent pool's ``[row,
+    block_size]``): ``(blocks [B, S], slots [B, S])`` of positions
+    ``pos_w`` [B, S] through ``table`` [B, W], a ring's modulo its
+    width; a dead row (length 0) gets a block past the pool, which
+    drops its write."""
+    n_blocks = pool.shape[1]
+    bs = pool.shape[3] if pool.ndim == 4 else pool.shape[2]
+    lb = pos_w // bs
+    lb = lb % table.shape[1] if ring else jnp.clip(lb, 0, table.shape[1] - 1)
+    wb = jnp.take_along_axis(table, lb, axis=1)
+    return jnp.where(lengths[:, None] > 0, wb, n_blocks), pos_w % bs
+
+
+class _Cache:
+    """The paged cache as one serving program holds it, and what
+    addresses it.  ``arrays`` is ``PagedKVCache.device_pools()`` in its
+    order, by group: "pages" (the K and V pools, or the one latent
+    pool), "ring" (the sliding layers' K and V pools) and "state" (the
+    recurrent state and its convolution tails), each where the model
+    has such layers.  A program's ``extra`` argument is the ring's
+    entries (a prefill) or tables (decode), or the rows' state slots.
+    Without arrays a prefill's K/V are only kept."""
+
+    def __init__(self, cfg: TransformerConfig, pools=(), extra=()):
+        self.cfg = cfg
+        self.arrays = list(pools)
+        groups = {_MIXERS[kind].group for kind in cfg.layer_kinds}
+        self.groups, at = {}, 0
+        for group, n in (("pages", 1 if cfg.latent else 2), ("ring", 2),
+                         ("state", 2)):
+            if group == "pages" or group in groups:
+                self.groups[group] = slice(at, at + n)
+                at += n
+        self.ring = extra[0] if "ring" in self.groups else None
+        self.slots = extra[0] if "state" in self.groups else None
+        self.kept = {}       # K/V a one-pass prefill writes after its walk
+        self.counts = []     # the held experts' routing counts
+        self.first = 0       # a looped model's pass offset
+        self.at = {}         # each kind's next cache layer in a pass
+        self.valid = self.positions = self.lengths = self.live = None
+        self.block_ids = self.last_index = None
+        self.tables, self.where = {}, {}
+
+    def get(self, group):
+        return tuple(self.arrays[self.groups[group]])
+
+    def set(self, group, *arrays):
+        self.arrays[self.groups[group]] = arrays
+
+    def moe(self):
+        """The program's routing counts ``([n, n_experts + 2],)`` where
+        the model holds experts (none under a pattern cut before its
+        first expert layer), else ``()``."""
+        if self.cfg.moe_router != "sigmoid":
+            return ()
+        return (jnp.stack(self.counts) if self.counts else jnp.zeros(
+            (0, self.cfg.n_experts + 2), jnp.int32),)
+
+    def keep(self, layer: _Layer, kept):
+        """What a prefill mixer leaves in the cache: a latent layer's
+        rows into its pages, a recurrent layer's final state and tail
+        into the sequence's slot, an attention layer's K/V into the
+        pages of this pass (a looped model), or after the walk
+        (:meth:`flush`)."""
+        if kept is None:  # a decode step's mixers write their own
+            return
+        group = _MIXERS[layer.kind].group
+        if group == "state":
+            state, tails = self.get("state")
+            s_t, tail = kept
+            state = state.at[layer.i, self.slots[0]].set(s_t)
+            tails = tails.at[layer.i, self.slots[0]].set(
+                tail.reshape(tails.shape[2:]).astype(tails.dtype))
+            self.set("state", state, tails)
+        elif self.cfg.latent:
+            pool, = self.get("pages")
+            self.set("pages", _write_prompt_rows(pool, layer.i,
+                                                 self.block_ids, kept))
+        elif self.cfg.loop_passes > 1:
+            self.set(group, *(
+                _write_prompt_pages(pool, self.first + layer.i,
+                                    self.block_ids, a)
+                for pool, a in zip(self.get(group), kept)))
+        else:
+            self.kept.setdefault(group, []).append(kept)
+
+    def flush(self):
+        """The kept K/V into their pools, one write a pool: every page
+        layer's at ``block_ids``, and the last blocks of every sliding
+        layer's into its ring entries (``extra``, in logical order: no
+        later query's window reaches further back)."""
+        for group in ("pages", "ring"):
+            kept = self.kept.get(group)
+            if not kept:
+                continue
+            pools = []
+            for pool, a in zip(self.get(group), zip(*kept)):
+                a, ids = jnp.stack(a), self.block_ids
+                if group == "ring":
+                    ids = self.ring
+                    a = a[:, :, a.shape[2] - ids.shape[0] * pool.shape[2]:]
+                pools.append(_write_prompt_pages(
+                    pool, slice(None), ids, a, rows=_one_sublayer(self.cfg)))
+            self.set(group, *pools)
+        return tuple(self.arrays)
+
+
+def _prefill_attention_layer(x, layer: _Layer, cache, cfg: TransformerConfig):
+    """An attention layer over a whole prompt ``x`` [B, T, E]: its keys
+    and values of every row, kept, then the rows through the layer.  A
+    prompt longer than :data:`PREFILL_ROWS` (a multiple of it) is walked
+    in chunks of rows against the whole K and V."""
+    b, t, e = x.shape
+    mixer = _MIXERS[layer.kind]
+    scope = mixer.scopes[0]
+    n_chunks = t // PREFILL_ROWS if (
+        cfg.served_only and t > PREFILL_ROWS and t % PREFILL_ROWS == 0) else 1
+    rows = t // n_chunks
+    norm = "ln" if _one_sublayer(cfg) else "ln1"
+    xn = _norm(x, layer.p[norm], cfg)
+    with _scope(scope):
+        kv = mixer.keys(xn, layer, cfg)
+
+    def some_rows(x, xn, valid, first):
+        """Rows [first, first + rows) of the prompt through the layer."""
+        counts = []
+        with _scope(scope):
+            y = mixer.prefill(xn, layer, cfg, kv, first, n_chunks)
+            x, y = _mixer_addend(x, y, layer.p, cfg)
+        if not _one_sublayer(cfg):
+            x = _ffn_half(x, xn, y, layer, valid, counts, cfg)
+        return x, counts
+
+    if n_chunks == 1:
+        x, counts = some_rows(x, xn, cache.valid, 0)
+        cache.counts.extend(counts)
+        cache.keep(layer, kv)
+        return x
+
+    def chunked(a):  # [B, T, ...] -> [n_chunks, B, rows, ...]
+        return jnp.moveaxis(a.reshape((b, n_chunks, rows) + a.shape[2:]), 1, 0)
+
+    def one(args):
+        # the chunk's rows normed again: the whole prompt's, which K
+        # and V were made of, need not outlive them
+        i, x_c, valid_c = args
+        return some_rows(x_c, _norm(x_c, layer.p[norm], cfg), valid_c,
+                         i * rows)
+
+    valid = jnp.ones((b, t), bool) if cache.valid is None else cache.valid
+    x, counts = lax.map(one, (jnp.arange(n_chunks), chunked(x),
+                              chunked(valid)))
+    cache.counts.extend(c.sum(axis=0) for c in counts)
+    cache.keep(layer, kv)
+    return jnp.moveaxis(x, 0, 1).reshape(b, t, e)
+
+
+def _layer(x, layer: _Layer, cache, cfg: TransformerConfig, direction: int,
+           mix):
+    """A layer: its mixer (``mix(xn) -> (addend, what the cache keeps)``,
+    ``direction`` 0 a prefill's, 1 a decode step's), what the mixer
+    leaves in the cache, and its FFN half (the held experts open their
+    own scope, "moe")."""
+    scope, wide = _MIXERS[layer.kind].scopes[direction], _wide_scopes(cfg)
+    with _scope(scope if wide else None):
+        xn = _norm(x, layer.p["ln" if _one_sublayer(cfg) else "ln1"], cfg)
+        with _scope(None if wide else scope):
+            y, kept = mix(xn)
+            if not wide:
+                cache.keep(layer, kept)
+        x, y = _mixer_addend(x, y, layer.p, cfg)
+        if wide:
+            cache.keep(layer, kept)
+    if not _one_sublayer(cfg):
+        with _scope("mlp" if layer.first_group is None and not cfg.latent
+                    and direction else None):
+            x = _ffn_half(x, xn, y, layer, cache.valid, cache.counts, cfg)
+    return x
+
+
+def _walk(params, x, cache, step, cfg: TransformerConfig):
+    """``x`` through ``step(x, layer) -> x`` for every layer, in order:
+    once, or once a pass of a looped model (:func:`_loop_passes`, which
+    closes each pass with the final norm), pass t's layer l at cache
+    layer ``t * n_layers + l`` (``cache.first`` the pass's offset)."""
+    def one_pass(first, x, arrays):
+        cache.arrays, cache.first, cache.at = list(arrays), first, {}
+        for layer in _serving_layers(params, cfg):
+            x = step(x, layer)
+        return x, tuple(cache.arrays)
+
+    if cfg.loop_passes > 1:
+        x, arrays = _loop_passes(x, tuple(cache.arrays), one_pass, params,
+                                 cfg)
+    else:
+        x, arrays = one_pass(0, x, tuple(cache.arrays))
+    cache.arrays = list(arrays)
+    return x
+
+
+def _prefill_walk(params, ids, cache, cfg: TransformerConfig):
+    """Every layer over a whole prompt ``ids`` [B, T], writing or
+    keeping what the cache holds of it: the hidden states [B, T, E]
+    after the final norm."""
+    t = ids.shape[1]
+    if cfg.moe_router == "sigmoid" or cache.slots is not None:
+        # the prompt's real tokens: what the routing counts and the
+        # recurrences take in
+        cache.positions = jnp.arange(t)[None]
+        cache.valid = cache.positions <= cache.last_index[:, None]
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
         cfg.stream_dtype)
-    counts = []
-    for kind, i, p, first_group in _pattern_layers(params, cfg):
-        xn = _norm(x, p["ln"], cfg)
-        if kind == "mamba":
-            with jax.named_scope("mamba"):
-                y, s_t, tail = _mamba_prefill(xn, p, valid, last_index, cfg)
-                state = state.at[i, slot[0]].set(s_t)
-                tails = tails.at[i, slot[0]].set(
-                    tail.reshape(tails.shape[2:]).astype(tails.dtype))
-        elif kind == "full":
-            with jax.named_scope("attn_full"):
-                q, k, v = _pattern_qkv(xn, p, cfg)
-                with jax.named_scope(f"prefill_attn_t{t}_w0_c1"):
-                    o = _causal_attention(q, k, v)
-                y = jnp.einsum("bthd,hde->bte", o, p["wo"],
-                               **_into_stream(cfg))
-                k_pool = _write_pages(k_pool, i, block_ids, k)
-                v_pool = _write_pages(v_pool, i, block_ids, v)
-        else:
-            y = _mha_ffn(xn, p, first_group, cfg, valid, counts)
-        x = x + y
-    x = _norm(x, params["ln_f"], cfg)
-    return (_logits_at(params, x, last_index, cfg), k_pool, v_pool, state,
-            tails, _stacked(counts, cfg))
+
+    def step(x, layer):
+        mixer = _MIXERS[layer.kind]
+        if mixer.keys is not None:
+            return _prefill_attention_layer(x, layer, cache, cfg)
+        return _layer(x, layer, cache, cfg, 0,
+                      lambda xn: mixer.prefill(xn, layer, cache, cfg))
+
+    x = _walk(params, x, cache, step, cfg)
+    return x if cfg.loop_passes > 1 else _norm(x, params["ln_f"], cfg)
 
 
-def forward_decode_paged_pattern(params, ids, positions, k_pool, v_pool,
-                                 state, tails, block_tables, lengths, slots,
-                                 cfg: TransformerConfig):
-    """:func:`forward_decode_paged` of a model under a layer pattern,
-    one token a row (``ids`` [B, 1]: recurrent state has no rollback, so
-    there is no verify window).  ``slots`` [B] is each row's state slot
-    beside its block table; a dead row (length 0) writes neither pools
-    nor state.  ``positions`` is taken as every decode program takes it
-    and not read: no layer rotates.  Returns ``(logits [B, 1, V],
-    k_pool, v_pool, state, tails, moe)``."""
-    from ..ops import paged_attention as _paged_attn
+def prefill_paged(params, ids, last_index, pools, block_ids, extra,
+                  cfg: TransformerConfig):
+    """Prefill of ONE sequence of any family that leaves what the cache
+    keeps of it on the device: ``(logits [1, V], pools, moe)``.
 
+    ids [1, T] with T a whole number of blocks (the engine pads prompts
+    so); ``last_index`` [1] its last real token; ``pools`` the cache's
+    arrays (``PagedKVCache.device_pools()``, donated and updated in
+    place); ``block_ids`` [T / block_size] int32, the sequence's block
+    table: logical block j of every layer lands in physical block
+    ``block_ids[j]``, the layout :func:`decode_paged` reads.  ``extra``
+    is ``()``, the ring entries a sliding layer's last blocks go to
+    (``PagedKVCache.sliding_prefill_ids``), or the sequence's state slot
+    [1].  Slots of the last block past the prompt's true length hold
+    the pad tokens' K/V: nothing reads a slot at or past a row's
+    length, and decode overwrites each before the length passes it; a
+    pad token neither decays nor writes a recurrent state.  ``moe``:
+    ``(counts [n expert layers, n_experts + 2],)`` of the prompt's real
+    tokens where the model holds experts (:func:`_moe_held_ffn`), else
+    ``()``."""
+    cache = _Cache(cfg, pools, extra)
+    cache.block_ids, cache.last_index = block_ids, last_index
+    x = _prefill_walk(params, ids, cache, cfg)
+    pools = cache.flush()
+    return _logits_at(params, x, last_index, cfg), pools, cache.moe()
+
+
+def decode_paged(params, ids, positions, pools, block_tables, lengths,
+                 extra, cfg: TransformerConfig):
+    """Decode window step of any family against the cache IN PLACE:
+    ``(logits [B, S, V], pools, moe)``.
+
+    ids / positions [B, S] (S=1 plain decode, S=k+1 speculative verify;
+    a ring or recurrent state holds one token's reach, so S=1 there);
+    ``pools`` the cache's arrays (donated; each layer writes the
+    window's entries at ``lengths[b] + s`` and then reads them, so the
+    write is in place); block_tables [B, W] int32 (rows padded with 0);
+    lengths [B] int32 committed tokens per row, a dead row's 0 (it
+    writes nothing and is not counted); ``extra`` ``()``, the rows' ring
+    tables [B, R] (sliding layers) or state slots [B] (recurrent
+    layers).  ``moe`` as :func:`prefill_paged`'s, of the live rows."""
     b, s_w = ids.shape
-    assert s_w == 1, "recurrent layers decode one token a step"
-    live = lengths > 0
-    n_blocks, bs = k_pool.shape[1], k_pool.shape[2]
-    wb = jnp.take_along_axis(
-        block_tables, jnp.clip(lengths[:, None] // bs, 0,
-                               block_tables.shape[1] - 1), axis=1)
-    wb = jnp.where(live[:, None], wb, n_blocks)                  # OOB-drop
-    ws = lengths[:, None] % bs
-    valid = live[:, None]
+    cache = _Cache(cfg, pools, extra)
+    assert s_w == 1 or set(cache.groups) == {"pages"}, \
+        "a ring or a recurrent state holds one decode token's reach"
+    if cache.slots is not None:
+        cache.live = lengths > 0
+    cache.positions, cache.lengths = positions, lengths
+    cache.tables = {"pages": block_tables}
+    if cache.ring is not None:
+        cache.tables["ring"] = cache.ring
+    pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
+    for group, table in cache.tables.items():
+        cache.where[group] = _window_at(cache.get(group)[0], table, lengths,
+                                        pos_w, group == "ring")
+    cache.valid = cache.live[:, None] if cache.live is not None \
+        else jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(
         cfg.stream_dtype)
-    counts = []
-    for kind, i, p, first_group in _pattern_layers(params, cfg):
-        xn = _norm(x, p["ln"], cfg)
-        if kind == "mamba":
-            with jax.named_scope("mamba"):
-                y, state, tails = _mamba_decode(xn, p, state, tails, i,
-                                                slots, live, cfg)
-        elif kind == "full":
-            with jax.named_scope("attn_full"):
-                q, k, v = _pattern_qkv(xn, p, cfg)
-                k_pool = k_pool.at[i, wb, ws].set(
-                    k.astype(k_pool.dtype), mode="drop")
-                v_pool = v_pool.at[i, wb, ws].set(
-                    v.astype(v_pool.dtype), mode="drop")
-                # the layers' pools as one run of pages, a free view
-                o = _paged_attn.paged_attention(
-                    q, k_pool.reshape((-1,) + k_pool.shape[2:]),
-                    v_pool.reshape((-1,) + v_pool.shape[2:]),
-                    block_tables + i * n_blocks, lengths)
-                y = jnp.einsum("bthd,hde->bte", o, p["wo"],
-                               **_into_stream(cfg))
-        else:
-            y = _mha_ffn(xn, p, first_group, cfg, valid, counts)
-        x = x + y
+
+    def step(x, layer):
+        # a kind's layer l at its cache layer first + l
+        li = cache.at.get(layer.kind, cache.first)
+        cache.at[layer.kind] = li + 1
+        mixer = _MIXERS[layer.kind]
+        return _layer(x, layer, cache, cfg, 1, lambda xn: (
+            mixer.decode(xn, layer, li, cache, cfg), None))
+
+    x = _walk(params, x, cache, step, cfg)
     with jax.named_scope("unembed"):
-        logits = _unembed(params, _norm(x, params["ln_f"], cfg), cfg)
-    return logits, k_pool, v_pool, state, tails, _stacked(counts, cfg)
+        if cfg.loop_passes == 1:  # a looped model's passes end normed
+            x = _norm(x, params["ln_f"], cfg)
+        logits = _unembed(params, x, cfg)
+    return logits, tuple(cache.arrays), cache.moe()
+
+
+#: the names a trace knows each family's two serving programs by
+#: (``jit(<name>)``), which the benchmark's trace readers match
+PROGRAM_NAMES = {
+    "mha": ("forward_prefill_paged", "forward_decode_paged"),
+    "mha_swa": ("forward_prefill_paged_swa", "forward_decode_paged_swa"),
+    "mla": ("forward_prefill_paged_mla", "forward_decode_paged_mla"),
+    "kda_mla": ("forward_prefill_paged_hybrid", "forward_decode_paged_hybrid"),
+    "nemotron_h": ("forward_prefill_paged_pattern",
+                   "forward_decode_paged_pattern"),
+}
 
 
 def make_train_step(mesh, cfg: TransformerConfig, optimizer=None,

@@ -209,8 +209,8 @@ _MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held",
 #: counts them), the live slots summed over decode steps, and the state
 #: bytes those steps read and wrote, under the name of the state's kind
 _STATE_COUNTERS = ("state_slot_allocs", "state_slot_steps")
-_STATE_BYTES_COUNTER = {"kda_mla": "kda_state_rw_bytes",
-                        "nemotron_h": "ssm_state_rw_bytes"}
+_STATE_BYTES_COUNTER = {"kda": "kda_state_rw_bytes",
+                        "mamba": "ssm_state_rw_bytes"}
 
 #: what a model with sliding-window layers adds.  The cache counts the
 #: ring blocks that were taken over; the rest grow when a decode span
@@ -225,81 +225,34 @@ _SLIDING_COUNTERS = ("kv_sliding_blocks_released", "attn_full_ctx_tokens",
 
 
 def _jitted_programs(family: str = "mha"):
-    """Process-wide jitted prefill/decode (one jit wrapper per program,
-    so every engine instance shares one compile cache — tests and
-    smokes build several engines and must not pay XLA again for
-    identical shapes).
+    """Process-wide jitted prefill/decode of a model family (one jit
+    wrapper per program, so every engine instance shares one compile
+    cache — tests and smokes build several engines and must not pay XLA
+    again for identical shapes).
 
-    Which pair depends on the model family alone, and every one ends
-    in the same epilogue (``tfm.picking_prefill`` / ``picking_decode``
-    around the family's forward): the greedy pick and its finiteness
-    are what a program returns, never the logits, and the decode
-    program takes the ids it consumes from the host or from an earlier
-    step's pick that is still on the device.  Prefill, site
-    ``serving.prefill``: ``forward_prefill_paged`` with the pools
-    DONATED — it has this one caller, which replaces its references
-    with the returned pools at once, and an undonated scatter would
-    copy both pools per prompt.  Decode, site ``serving.decode_paged``:
-    ``forward_decode_paged`` — one program serves any verify window,
-    the window is a shape; its pools are DONATED like the prefill's, so
-    a step scatters into them in place, and whoever calls the jitted
-    program loses the arrays it passed in and goes on with the ones
-    returned.  A latent-attention model (``family`` "mla") runs their
-    twins at the same two sites, ``forward_prefill_paged_mla`` /
-    ``forward_decode_paged_mla``, both donated their one pool; a model
-    with recurrent layers ("kda_mla") ``forward_prefill_paged_hybrid``
-    / ``forward_decode_paged_hybrid``, donated the pool and the two
-    state arrays that travel with it; a model under a layer pattern
-    ("nemotron_h") ``forward_prefill_paged_pattern`` /
-    ``forward_decode_paged_pattern``, donated the K and V pools and the
-    two state arrays; an MHA model with sliding-window
-    layers ("mha_swa") ``forward_prefill_paged_swa`` /
-    ``forward_decode_paged_swa``, donated the full layers' and the
-    sliding layers' K and V pools.  All go
-    through :func:`telemetry.compute.profiled_jit`, which is plain
-    ``jax.jit`` when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on
-    that mode so toggling the knob between tests cannot hand a plain
-    engine a profiled program or vice versa.  The decode site carries
-    the ``DMLC_SERVE_MAX_DECODE_SIGS`` signature cap — every distinct
+    Every family runs the same two programs, ``tfm.prefill_paged`` at
+    site ``serving.prefill`` and ``tfm.decode_paged`` at site
+    ``serving.decode_paged``, each with the greedy pick as its epilogue
+    (``tfm.picking_prefill`` / ``picking_decode``: the pick and its
+    finiteness are what a program returns, never the logits, and the
+    decode program takes the ids it consumes from the host or from an
+    earlier step's pick still on the device); the family only names
+    them as a trace knows them (``tfm.PROGRAM_NAMES``).  Both take the
+    cache's arrays as one argument and have it DONATED: each has one
+    caller, which replaces its references with the returned arrays at
+    once, so the programs' writes are in place and no K/V or state
+    leaves the device.  All go through
+    :func:`telemetry.compute.profiled_jit`, which is plain ``jax.jit``
+    when ``DMLC_COMPUTE_PROFILE=0``; the cache is keyed on that mode so
+    toggling the knob between tests cannot hand a plain engine a
+    profiled program or vice versa.  The decode site carries the
+    ``DMLC_SERVE_MAX_DECODE_SIGS`` signature cap — every distinct
     context depth is a full XLA recompile, so unbounded signature
     growth is a bug worth failing loudly on."""
     compute = telemetry.compute
     mode = "profiled" if compute.enabled() else "plain"
-    if family == "kda_mla":
-        prefill_key = (mode, "prefill_paged_hybrid")
-        prefill_fn, prefill_kw = tfm.forward_prefill_paged_hybrid, {
-            "static_argnums": (8,), "donate_argnums": (3, 4, 5)}
-        decode_key = (mode, "decode_paged_hybrid")
-        decode_fn, decode_kw = tfm.forward_decode_paged_hybrid, {
-            "static_argnums": (9,), "donate_argnums": (3, 4, 5)}
-    elif family == "nemotron_h":
-        prefill_key = (mode, "prefill_paged_pattern")
-        prefill_fn, prefill_kw = tfm.forward_prefill_paged_pattern, {
-            "static_argnums": (9,), "donate_argnums": (3, 4, 5, 6)}
-        decode_key = (mode, "decode_paged_pattern")
-        decode_fn, decode_kw = tfm.forward_decode_paged_pattern, {
-            "static_argnums": (10,), "donate_argnums": (3, 4, 5, 6)}
-    elif family == "mha_swa":
-        prefill_key = (mode, "prefill_paged_swa")
-        prefill_fn, prefill_kw = tfm.forward_prefill_paged_swa, {
-            "static_argnums": (9,), "donate_argnums": (3, 4, 5, 6)}
-        decode_key = (mode, "decode_paged_swa")
-        decode_fn, decode_kw = tfm.forward_decode_paged_swa, {
-            "static_argnums": (10,), "donate_argnums": (3, 4, 5, 6)}
-    elif family == "mla":
-        prefill_key = (mode, "prefill_paged_mla")
-        prefill_fn, prefill_kw = tfm.forward_prefill_paged_mla, {
-            "static_argnums": (5,), "donate_argnums": (3,)}
-        decode_key = (mode, "decode_paged_mla")
-        decode_fn, decode_kw = tfm.forward_decode_paged_mla, {
-            "static_argnums": (6,), "donate_argnums": (3,)}
-    else:
-        prefill_key = (mode, "prefill_paged")
-        prefill_fn, prefill_kw = tfm.forward_prefill_paged, {
-            "static_argnums": (6,), "donate_argnums": (3, 4)}
-        decode_key = (mode, "decode_paged")
-        decode_fn, decode_kw = tfm.forward_decode_paged, {
-            "static_argnums": (7,), "donate_argnums": (3, 4)}
+    prefill_name, decode_name = tfm.PROGRAM_NAMES[family]
+    prefill_key, decode_key = (mode, prefill_name), (mode, decode_name)
     progs = (_JIT_CACHE.get(prefill_key), _JIT_CACHE.get(decode_key))
     if progs[0] is None or progs[1] is None:
         # this cache outlives any one engine — if the first engine of
@@ -313,13 +266,15 @@ def _jitted_programs(family: str = "mha"):
         try:
             if progs[0] is None:
                 _JIT_CACHE[prefill_key] = compute.profiled_jit(
-                    tfm.picking_prefill(prefill_fn),
-                    site="serving.prefill", **prefill_kw)
+                    tfm.picking_prefill(tfm.prefill_paged, prefill_name),
+                    site="serving.prefill", static_argnums=(6,),
+                    donate_argnums=(3,))
             if progs[1] is None:
                 _JIT_CACHE[decode_key] = compute.profiled_jit(
-                    tfm.picking_decode(decode_fn),
+                    tfm.picking_decode(tfm.decode_paged, decode_name),
                     site="serving.decode_paged", max_signatures=get_env(
-                        "DMLC_SERVE_MAX_DECODE_SIGS", 64), **decode_kw)
+                        "DMLC_SERVE_MAX_DECODE_SIGS", 64), static_argnums=(7,),
+                    donate_argnums=(3,))
         finally:
             concurrency.set_lock_factory_hook(prev_hook)
         progs = (_JIT_CACHE[prefill_key], _JIT_CACHE[decode_key])
@@ -489,7 +444,9 @@ class InferenceEngine:
         # read once and written once in every layer that has one (the
         # first of the state arrays; the convolution's tail is small)
         self._state_rw_bytes = 0
-        self._state_bytes_counter = _STATE_BYTES_COUNTER.get(cfg.family)
+        self._state_bytes_counter = next(
+            (_STATE_BYTES_COUNTER[kind] for kind in cfg.layer_kinds
+             if kind in _STATE_BYTES_COUNTER), None)
         if self.cache.n_slots:
             shape, dt = self.cache.state_shapes[0]
             self._state_rw_bytes = (
@@ -1008,12 +965,12 @@ class InferenceEngine:
                 with self._span("serving.prefill.dispatch", req=req.id):
                     at = (last, self._last_ids,
                           np.int32(self.max_active if row is None else row))
-                    out, pools, prefill.moe = self._on_pools(
-                        self._prefill, self.params, ids, at,
+                    (prefill.ids, prefill.finite, self._last_ids, pools,
+                     prefill.moe) = self._prefill(
+                        self.params, ids, at, self.cache.device_pools(),
                         np.asarray(self.cache.block_table(req.id),
                                    np.int32),
-                        *self._prefill_args(req.id), at=3, picks=3)
-                    prefill.ids, prefill.finite, self._last_ids = out
+                        self._extra([req.id], prefill=True), self.cfg)
                     _start_fetch(prefill.ids, prefill.finite, *prefill.moe)
                 # the wait for the decode step in flight, the prefill,
                 # and the runtime's notice of its end
@@ -1057,42 +1014,19 @@ class InferenceEngine:
                 with self._span("serving.first_token", req=prefill.req.id):
                     self._first_token(prefill.req, *picked)
 
-    def _slot_args(self, seq_ids, pad_batch=None) -> tuple:
-        """What a program of a model with recurrent layers takes after
-        the block tables: the sequences' state slots; nothing else."""
-        if not self.cache.n_slots:
-            return ()
-        return (self.cache.slot_ids(seq_ids, pad_batch),)
-
-    def _prefill_args(self, seq_id) -> tuple:
-        """What a prefill program takes after the block table: the
-        ring entries it writes (sliding layers), the sequence's state
-        slot (recurrent layers), or nothing."""
-        if self.cache.ring_blocks:
-            return (np.asarray(self.cache.sliding_prefill_ids(seq_id),
+    def _extra(self, seq_ids, pad_batch=None, prefill=False) -> tuple:
+        """What a program takes after the block tables (decode: and the
+        lengths), from what the cache holds: the ring entries a prefill
+        writes or the rows' ring tables (sliding layers), the sequences'
+        state slots (recurrent layers), or nothing."""
+        if self.cache.ring_blocks and prefill:
+            return (np.asarray(self.cache.sliding_prefill_ids(seq_ids[0]),
                                np.int32),)
-        return self._slot_args([seq_id])
-
-    def _row_args(self, seq_ids, pad_batch) -> tuple:
-        """What a decode program takes after the tables and lengths:
-        the rows' ring tables (sliding layers), their state slots
-        (recurrent layers), or nothing."""
         if self.cache.ring_blocks:
             return (self.cache.sliding_tables_array(seq_ids, pad_batch),)
-        return self._slot_args(seq_ids, pad_batch)
-
-    def _on_pools(self, program, *args, at: int, picks: int = 2):
-        """Call a paged program with the cache's pools spliced in at
-        argument ``at`` and the config last; split what it returns into
-        ``(picks, pools, rest)``: its first ``picks`` outputs (``(ids,
-        finite)``, and the prefill's ``prev_ids`` after them), the pools
-        and the rest.  The pools are the cache's device arrays,
-        recurrent state included; ``rest`` is empty for the MHA
-        programs and the routing counts for the other families'."""
-        pools = self.cache.device_pools()
-        out = program(*args[:at], *pools, *args[at:], self.cfg)
-        n = picks + len(pools)
-        return out[:picks], out[picks:n], out[n:]
+        if self.cache.n_slots:
+            return (self.cache.slot_ids(seq_ids, pad_batch),)
+        return ()
 
     def _count_moe(self, moe) -> None:
         """Add one program call's routing counts ``[n_moe_layers,
@@ -1248,8 +1182,8 @@ class InferenceEngine:
 
     def _decode_inputs(self, active: List[Request]) -> tuple:
         """What the decode program takes from the host: ``(feed,
-        positions, drafts, tables, lengths, base_lens, slots)``, the
-        last a tuple that is empty without recurrent state.  ``feed``
+        positions, drafts, tables, lengths, base_lens, extra)``, the
+        last what :meth:`_extra` gives the rows.  ``feed``
         is ``(ids, prev_ids, src)``: a row of the step in flight takes
         its token from that step's picks on the device (``src`` is its
         row there), a row whose prefill's pick is unread from the row
@@ -1286,12 +1220,11 @@ class InferenceEngine:
             drafts.append(d)
         positions[:b] = base_lens[:, None] + np.arange(s_w)
         return ((ids, self._last_ids, src), positions, drafts, tables,
-                lengths, base_lens,
-                self._row_args([r.id for r in active], pad_b))
+                lengths, base_lens, self._extra([r.id for r in active], pad_b))
 
     def _decode_step(self, active: List[Request], n_preempted: int,
                      ahead: bool, feed, positions, drafts, tables,
-                     lengths, base_lens, slots=()) -> None:
+                     lengths, base_lens, extra=()) -> None:
         """Dispatch a step for ``active``; then read the step before it
         (``ahead``: this one stays in flight) or this one."""
         if not self._flops_declared:
@@ -1316,9 +1249,9 @@ class InferenceEngine:
             # place through the block tables (a [B, W] int32 array is
             # all that ships) and hands no K/V back
             try:
-                picked, pools, moe = self._on_pools(
-                    self._decode, self.params, feed, positions, tables,
-                    lengths, *slots, at=3)
+                *picked, pools, moe = self._decode(
+                    self.params, feed, positions, self.cache.device_pools(),
+                    tables, lengths, extra, self.cfg)
             except Exception:
                 # the donated pools went with a call that failed after
                 # dispatch: the loop's requeue re-prefills into fresh

@@ -51,8 +51,8 @@ tables), both donated and adopted alike.
 
 The bytes live in ONE place: the pools are device arrays and they ARE
 the cache.  Device programs write them in place
-(``models.forward_prefill_paged`` scatters a prompt's K/V into its
-blocks, ``forward_decode_paged`` the decode window's; the engine
+(``models.transformer.prefill_paged`` scatters a prompt's K/V into its
+blocks, ``decode_paged`` the decode window's; the engine
 DONATES the pools to both, so the arrays handed in are deleted by the
 call) and the engine swaps in what they return (:meth:`device_pools` /
 :meth:`adopt_device_pools`, and :meth:`drop_lost_pools` after a call

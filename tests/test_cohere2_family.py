@@ -123,28 +123,27 @@ def _prefill(cfg, params, cache, seq, ids, n):
     padded = n + (-n % BS)
     row = np.zeros((1, padded), np.int32)
     row[0, :n] = ids[:n]
-    out = tfm.forward_prefill_paged_swa(
-        params, jnp.asarray(row), jnp.array([n - 1]),
-        *cache.device_pools(),
+    logits, pools, (moe,) = tfm.prefill_paged(
+        params, jnp.asarray(row), jnp.array([n - 1]), cache.device_pools(),
         jnp.asarray(cache.block_table(seq), jnp.int32),
-        jnp.asarray(cache.sliding_prefill_ids(seq), jnp.int32), cfg)
-    cache.adopt_device_pools(*out[1:5])
+        (jnp.asarray(cache.sliding_prefill_ids(seq), jnp.int32),), cfg)
+    cache.adopt_device_pools(*pools)
     cache.advance_many([(seq, n)])
-    return np.asarray(out[0][0]), np.asarray(out[5])
+    return np.asarray(logits[0]), np.asarray(moe)
 
 
 def _decode(cfg, params, cache, seqs, tokens):
     """One decode step of the rows ``seqs`` consuming ``tokens``."""
     assert cache.extend_many(seqs, 1)
     tables, lengths = cache.block_tables_array(seqs)
-    out = tfm.forward_decode_paged_swa(
+    logits, pools, _ = tfm.decode_paged(
         params, jnp.asarray(tokens, jnp.int32)[:, None],
-        jnp.asarray(lengths)[:, None], *cache.device_pools(),
+        jnp.asarray(lengths)[:, None], cache.device_pools(),
         jnp.asarray(tables), jnp.asarray(lengths),
-        jnp.asarray(cache.sliding_tables_array(seqs)), cfg)
-    cache.adopt_device_pools(*out[1:5])
+        (jnp.asarray(cache.sliding_tables_array(seqs)),), cfg)
+    cache.adopt_device_pools(*pools)
     cache.advance_many([(s, 1) for s in seqs])
-    return np.asarray(out[0][:, 0])
+    return np.asarray(logits[:, 0])
 
 
 @pytest.mark.parametrize("n_prompt", [3, 8, 21, 33],

@@ -338,10 +338,11 @@ def test_paged_prefill_writes_exactly_the_sequences_blocks(n):
     shape = (cfg.n_layers, n_blocks, bs, cfg.n_heads, cfg.head_dim)
     k0, v0 = _rand(rng, *shape), _rand(rng, *shape)
     table = np.array([5, 2], np.int32)  # neither contiguous nor ordered
-    prog = jax.jit(tfm.forward_prefill_paged, static_argnums=(6,),
-                   donate_argnums=(3, 4))  # as the engine compiles it
-    logits, k_pool, v_pool = prog(params, ids, last, jax.numpy.asarray(k0),
-                                  jax.numpy.asarray(v0), table, cfg)
+    prog = jax.jit(tfm.prefill_paged, static_argnums=(6,),
+                   donate_argnums=(3,))  # as the engine compiles it
+    logits, (k_pool, v_pool), _ = prog(
+        params, ids, last, (jax.numpy.asarray(k0), jax.numpy.asarray(v0)),
+        table, (), cfg)
     np.testing.assert_array_equal(np.asarray(logits),
                                   np.asarray(want_logits))
     for got, want, before in ((np.asarray(k_pool), np.asarray(want_k), k0),
@@ -366,13 +367,14 @@ def test_failed_prefill_fails_alone_unless_it_took_the_pools(pools_lost):
     real = eng._prefill
     poison = 63
 
-    def prefill(p, ids, last, k_pool, v_pool, blocks, c):
+    def prefill(p, ids, last, pools, blocks, extra, c):
         if ids[0, 0] == poison:
             if pools_lost:
-                k_pool.delete()  # what a donating call that raised after
-                v_pool.delete()  # dispatch leaves behind
+                for pool in pools:
+                    pool.delete()  # what a donating call that raised
+                    # after dispatch leaves behind
             raise RuntimeError("injected prefill failure")
-        return real(p, ids, last, k_pool, v_pool, blocks, c)
+        return real(p, ids, last, pools, blocks, extra, c)
 
     eng._prefill = prefill
     before = telemetry.counters_snapshot().get("serving", {}).get(
@@ -431,7 +433,7 @@ def test_engine_decode_program_donates_both_pools():
 
     def decode(*a):
         out = real(*a)
-        returned.append(out[2:4])
+        returned.append(out[2])
         return out
 
     eng._decode = decode
@@ -450,8 +452,8 @@ def test_engine_decode_program_donates_both_pools():
     ids = np.zeros((2, 1), np.int32)
     lowered = getattr(real, "_jit", real).lower(
         params, (ids, ids, np.zeros((2,), np.int32)), ids,
-        *eng.cache.device_pools(),
-        np.zeros((2, 2), np.int32), np.zeros((2,), np.int32), cfg)
+        eng.cache.device_pools(),
+        np.zeros((2, 2), np.int32), np.zeros((2,), np.int32), (), cfg)
     import jax
 
     donated = [a.donated
@@ -548,14 +550,15 @@ def test_failed_decode_that_took_the_pools_is_recovered():
     real = eng._decode
     calls = {"n": 0, "lost": None}
 
-    def decode(p, ids, positions, k_pool, v_pool, tables, lengths, c):
+    def decode(p, ids, positions, pools, tables, lengths, extra, c):
         calls["n"] += 1
         if calls["n"] == 3:
-            k_pool.delete()  # what a donating call that raised after
-            v_pool.delete()  # dispatch leaves behind
-            calls["lost"] = (k_pool, v_pool)
+            for pool in pools:
+                pool.delete()  # what a donating call that raised after
+                # dispatch leaves behind
+            calls["lost"] = pools
             raise RuntimeError("injected decode failure")
-        return real(p, ids, positions, k_pool, v_pool, tables, lengths, c)
+        return real(p, ids, positions, pools, tables, lengths, extra, c)
 
     eng._decode = decode
     before = telemetry.counters_snapshot().get("serving", {}).get(
@@ -585,6 +588,14 @@ def test_failed_decode_that_took_the_pools_is_recovered():
 # layer and matrix, so no program takes a layer's slice out of a stack
 # ---------------------------------------------------------------------------
 
+#: the test module of each family's small model ("looped": the MHA
+#: family run in passes)
+_TOY_MODULES = {"mha_swa": "test_cohere2_family", "mla": "test_latent_family",
+                "kda_mla": "test_hybrid_family",
+                "nemotron_h": "test_nemotron_h_family",
+                "looped": "test_ouro_family"}
+
+
 def _family_toy(family):
     """``(params, cfg)`` of a small model of one family: the flagship's
     stacked tree, or another family's own test module's."""
@@ -592,11 +603,9 @@ def _family_toy(family):
 
     if family == "mha":
         return _tiny_model()
-    mod = importlib.import_module(
-        {"mha_swa": "test_cohere2_family", "mla": "test_latent_family",
-         "kda_mla": "test_hybrid_family"}[family])
+    mod = importlib.import_module(_TOY_MODULES[family])
     cfg = mod.small()
-    assert cfg.family == family
+    assert cfg.family == ("mha" if family == "looped" else family)
     return mod.weights(cfg), cfg
 
 
@@ -642,11 +651,11 @@ def test_engine_programs_slice_no_weight_argument():
         n = len(jax.tree.leaves(tree))
         calls = (
             (prefill, tree, np.zeros((1, 8), np.int32),
-             (np.zeros((1,), np.int32), ids, np.int32(0)), *pools,
-             np.zeros((2,), np.int32), cfg),
+             (np.zeros((1,), np.int32), ids, np.int32(0)), pools,
+             np.zeros((2,), np.int32), (), cfg),
             (decode, tree, (ids, ids, np.zeros((2,), np.int32)), ids,
-             *pools, np.zeros((2, 2), np.int32), np.zeros((2,), np.int32),
-             cfg))
+             pools, np.zeros((2, 2), np.int32), np.zeros((2,), np.int32),
+             (), cfg))
         for program, *args in calls:
             found = _slices_of_weights(program, n, *args)
             # ten matrices in each of the toy's two layers
@@ -654,7 +663,7 @@ def test_engine_programs_slice_no_weight_argument():
 
 
 def _paged_greedy(params, cfg, prompt, n_new, bs=16, n_blocks=4):
-    """Greedy decode of one prompt through forward_prefill_paged and
+    """Greedy decode of one prompt through prefill_paged and
     forward_decode_paged called as they are, untraced: ``(ids, the
     logits of every pick)``."""
     import jax.numpy as jnp
@@ -666,10 +675,10 @@ def _paged_greedy(params, cfg, prompt, n_new, bs=16, n_blocks=4):
     n = len(prompt)
     padded = -(-n // bs) * bs
     assert n + n_new <= n_blocks * bs
-    row, k_pool, v_pool = tfm.forward_prefill_paged(
+    row, (k_pool, v_pool), _ = tfm.prefill_paged(
         params, np.array([prompt + [0] * (padded - n)], np.int32),
-        np.array([n - 1], np.int32), k_pool, v_pool,
-        np.arange(padded // bs, dtype=np.int32), cfg)
+        np.array([n - 1], np.int32), (k_pool, v_pool),
+        np.arange(padded // bs, dtype=np.int32), (), cfg)
     table = np.arange(n_blocks, dtype=np.int32)[None]
     ids, logits = [], [np.asarray(row[0])]
     for length in range(n, n + n_new - 1):
@@ -721,21 +730,25 @@ def test_both_trees_give_the_same_logits_and_the_engine_their_ids(branch):
 
 @pytest.mark.parametrize("family", ["mha", "mha_swa"])
 def test_mha_layers_yields_the_same_layers_from_either_tree(family):
-    """``_mha_layers`` over the stack and over what ``per_layer_params``
-    makes of it yields equal dicts; a tree that has ``layers`` (here
-    beside its one stack of held experts) is converted to itself and
-    yields each layer's own arrays with the experts' beside them."""
+    """The serving walk's layers (``_serving_layers``) over the stack and
+    over what ``per_layer_params`` makes of it are equal dicts; a tree
+    that has ``layers`` (here beside its one stack of held experts) is
+    converted to itself and yields each layer's own arrays with the
+    experts' beside them."""
     from dmlc_tpu.models import transformer as tfm
 
     params, cfg = _family_toy(family)
     layered = tfm.per_layer_params(params)
-    walked = list(tfm._mha_layers(layered, cfg))
+    walked = [(layer.kind, layer.p, layer.first_group)
+              for layer in tfm._serving_layers(layered, cfg)]
     assert [kind for kind, _, _ in walked] == list(cfg.layer_kinds)
     if family == "mha":
         assert "blocks" not in layered and set(layered) - {"layers"} == (
             set(params) - {"blocks"})
+        stacked = [(layer.kind, layer.p, layer.first_group)
+                   for layer in tfm._serving_layers(params, cfg)]
         for (kind, p, group), (kind_s, p_s, group_s) in zip(
-                walked, tfm._mha_layers(params, cfg), strict=True):
+                walked, stacked, strict=True):
             assert (kind, group) == (kind_s, group_s) == ("mha", None)
             assert p.keys() == p_s.keys() == params["blocks"].keys()
             for name in p:
@@ -749,6 +762,50 @@ def test_mha_layers_yields_the_same_layers_from_either_tree(family):
             for name, a in p.items():
                 assert a is params["layers"][i].get(
                     name, params["experts"].get(name))
+
+
+#: the names a trace knows each family's two serving programs by; the
+#: benchmark's trace readers match them (``loop_decode_roofline``:
+#: ``jit(forward_decode_paged)``)
+_PROGRAM_NAMES = {
+    "mha": ("forward_prefill_paged", "forward_decode_paged"),
+    "looped": ("forward_prefill_paged", "forward_decode_paged"),
+    "mha_swa": ("forward_prefill_paged_swa", "forward_decode_paged_swa"),
+    "mla": ("forward_prefill_paged_mla", "forward_decode_paged_mla"),
+    "kda_mla": ("forward_prefill_paged_hybrid", "forward_decode_paged_hybrid"),
+    "nemotron_h": ("forward_prefill_paged_pattern",
+                   "forward_decode_paged_pattern"),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_PROGRAM_NAMES))
+def test_every_family_runs_the_one_walk_under_its_own_names(family):
+    """Every kind of the model's layers has a prefill and a decode
+    mixer in the one table, and the two programs an engine jits, traced
+    on the arguments the engine hands them, are known to a trace by the
+    family's own names."""
+    from dmlc_tpu.models import transformer as tfm
+
+    params, cfg = _family_toy(family)
+    for kind in set(cfg.layer_kinds):
+        mixer = tfm._MIXERS[kind]
+        assert callable(mixer.prefill) and callable(mixer.decode), kind
+    eng = InferenceEngine(params, cfg, n_blocks=16, block_size=8,
+                          max_active=2, queue_depth=4)
+    assert eng.cache.allocate(0, 16)
+    tables, lengths = eng.cache.block_tables_array([0], pad_batch=2)
+    ids = np.zeros((2, 1), np.int32)
+    calls = (
+        (eng._prefill, eng.params, np.zeros((1, 16), np.int32),
+         (np.array([15], np.int32), ids, np.int32(0)),
+         eng.cache.device_pools(), np.asarray(eng.cache.block_table(0),
+                                              np.int32),
+         eng._extra([0], prefill=True), cfg),
+        (eng._decode, eng.params, (ids, ids, np.zeros((2,), np.int32)), ids,
+         eng.cache.device_pools(), tables, lengths, eng._extra([0], 2), cfg))
+    for (program, *args), name in zip(calls, _PROGRAM_NAMES[family]):
+        traced = getattr(program, "_jit", program).trace(*args)
+        assert traced.fun_name == name
 
 
 @pytest.mark.parametrize("family", ["mha", "mha_swa", "mla", "kda_mla"])

@@ -83,13 +83,10 @@ def _recording(eng):
     """Wrap the engine's decode program: ``calls`` gets ``(table width,
     live rows)`` of every dispatch."""
     real, calls = eng._decode, []
-    # what follows the pools: tables, lengths, the slots if any, cfg
-    at = -4 if eng.cache.n_slots else -3
 
-    def decode(p, feed, positions, *rest):
-        tables, lengths = rest[at], rest[at + 1]
+    def decode(p, feed, positions, pools, tables, lengths, *rest):
         calls.append((tables.shape[1], int((lengths > 0).sum())))
-        return real(p, feed, positions, *rest)
+        return real(p, feed, positions, pools, tables, lengths, *rest)
 
     eng._decode = decode
     return calls
@@ -369,16 +366,15 @@ def test_crash_requeue_reads_the_step_in_flight_first(family, pools_lost,
     real, n = eng._decode, [0]
     seen = {}
 
-    def decode(p, feed, positions, *rest):
+    def decode(p, feed, positions, pools, *rest):
         n[0] += 1
         if n[0] == 4:
             seen["unread"] = eng._inflight is not None
             if pools_lost:
-                for a in rest:
-                    if hasattr(a, "delete") and a.ndim > 2:
-                        a.delete()
+                for a in pools:
+                    a.delete()
             raise RuntimeError("injected decode failure")
-        return real(p, feed, positions, *rest)
+        return real(p, feed, positions, pools, *rest)
 
     eng._decode = decode
     before = _serving()

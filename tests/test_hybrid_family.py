@@ -186,9 +186,9 @@ def model():
 def test_prefill_logits_against_the_reference(model):
     cfg, params, ids, want = model
     blocks = np.array([3, 5, 7, 9], np.int32)
-    logits, pool, state, tails, moe = tfm.forward_prefill_paged_hybrid(
-        params, ids[:1, :32], np.array([28], np.int32), *empty_cache(cfg),
-        blocks, np.array([2], np.int32), cfg)
+    logits, (pool, state, tails), (moe,) = tfm.prefill_paged(
+        params, ids[:1, :32], np.array([28], np.int32), empty_cache(cfg),
+        blocks, (np.array([2], np.int32),), cfg)
     np.testing.assert_allclose(np.asarray(logits[0]), want[0][28], atol=5e-5)
     # only the sequence's own pages and its own slot were written
     written = np.flatnonzero(np.abs(np.asarray(pool)).sum(axis=(0, 2, 3)))
@@ -213,27 +213,27 @@ def test_prefill_then_decode_through_cache_and_slots(model, others):
     blocks = np.arange(1, 9, dtype=np.int32)
     padded = np.zeros((1, 32), np.int32)
     padded[0, :24] = ids[0, :24]
-    logits, *cache, _ = tfm.forward_prefill_paged_hybrid(
+    logits, cache, _ = tfm.prefill_paged(
         params, padded, np.array([23], np.int32),
-        *empty_cache(cfg, n_blocks=24), blocks[:4], np.array([2], np.int32),
+        empty_cache(cfg, n_blocks=24), blocks[:4], (np.array([2], np.int32),),
         cfg)
     np.testing.assert_allclose(np.asarray(logits[0]), want[0][23], atol=5e-5)
     tables = np.zeros((3, 8), np.int32)
     tables[0] = blocks
     slots = np.array([2, 0, 0], np.int32)
     if others:
-        logits, *cache, _ = tfm.forward_prefill_paged_hybrid(
-            params, ids[1:, :24], np.array([23], np.int32), *cache,
-            np.arange(9, 12, dtype=np.int32), np.array([0], np.int32), cfg)
+        logits, cache, _ = tfm.prefill_paged(
+            params, ids[1:, :24], np.array([23], np.int32), cache,
+            np.arange(9, 12, dtype=np.int32), (np.array([0], np.int32),), cfg)
         np.testing.assert_allclose(np.asarray(logits[0]), want[1][23],
                                    atol=5e-5)
         tables[1] = np.arange(9, 17)
-    step = jax.jit(tfm.forward_decode_paged_hybrid, static_argnums=(9,))
+    step = jax.jit(tfm.decode_paged, static_argnums=(7,))
     for t in range(24, 64):
         live = np.array([t, t if others else 0, 0], np.int32)
-        logits, *cache, moe = step(
+        logits, cache, (moe,) = step(
             params, np.array([[ids[0, t]], [ids[1, t]], [0]], np.int32),
-            live[:, None], *cache, tables, live, slots, cfg)
+            live[:, None], cache, tables, live, (slots,), cfg)
         np.testing.assert_allclose(np.asarray(logits[0, 0]), want[0][t],
                                    atol=1e-4)
         if others:

@@ -95,8 +95,9 @@ def test_latent_tree_has_a_leading_dense_group_beside_the_expert_layers():
 def test_prefill_logits_against_the_reference(model):
     cfg, params, ids, want = model
     blocks = np.array([3, 5, 7, 9], np.int32)
-    logits, pool, moe = tfm.forward_prefill_paged_mla(
-        params, ids, np.array([28], np.int32), empty_pool(cfg), blocks, cfg)
+    logits, (pool,), (moe,) = tfm.prefill_paged(
+        params, ids, np.array([28], np.int32), (empty_pool(cfg),), blocks, (),
+        cfg)
     np.testing.assert_allclose(np.asarray(logits[0]), want[28], atol=2e-5)
     # only the sequence's own pages were written
     written = np.flatnonzero(np.abs(np.asarray(pool)).sum(axis=(0, 2, 3)))
@@ -114,18 +115,18 @@ def test_prefill_then_decode_through_the_latent_paged_cache(model):
     full forward."""
     cfg, params, ids, want = model
     blocks = np.array([3, 5, 7, 9], np.int32)
-    logits, pool, _ = tfm.forward_prefill_paged_mla(
-        params, ids[:, :24], np.array([23], np.int32), empty_pool(cfg),
-        blocks[:3], cfg)
+    logits, (pool,), _ = tfm.prefill_paged(
+        params, ids[:, :24], np.array([23], np.int32), (empty_pool(cfg),),
+        blocks[:3], (), cfg)
     np.testing.assert_allclose(np.asarray(logits[0]), want[23], atol=2e-5)
     tables = np.zeros((2, 4), np.int32)
     tables[0] = blocks
     block0 = np.asarray(pool[:, 0]).copy()
     for t in range(24, 32):
-        logits, pool, moe = tfm.forward_decode_paged_mla(
+        logits, (pool,), (moe,) = tfm.decode_paged(
             params, np.array([[ids[0, t]], [0]], np.int32),
-            np.array([[t], [0]], np.int32), pool, tables,
-            np.array([t, 0], np.int32), cfg)
+            np.array([[t], [0]], np.int32), (pool,), tables,
+            np.array([t, 0], np.int32), (), cfg)
         np.testing.assert_allclose(np.asarray(logits[0, 0]), want[t],
                                    atol=2e-5)
         assert (np.asarray(moe)[:, -2] == cfg.moe_topk).all()  # one live row

@@ -201,10 +201,9 @@ def test_prefill_logits_against_the_reference(model, n):
     blocks = np.array([3, 5, 7, 9], np.int32)[:padded // BS]
     prompt = np.zeros((1, padded), np.int32)
     prompt[0, :n] = ids[0, :n]
-    logits, k_pool, v_pool, state, tails, moe = \
-        tfm.forward_prefill_paged_pattern(
-            params, prompt, np.array([n - 1], np.int32), *empty_cache(cfg),
-            blocks, np.array([2], np.int32), cfg)
+    logits, (k_pool, v_pool, state, tails), (moe,) = tfm.prefill_paged(
+        params, prompt, np.array([n - 1], np.int32), empty_cache(cfg),
+        blocks, (np.array([2], np.int32),), cfg)
     np.testing.assert_allclose(np.asarray(logits[0]), want[0][n - 1],
                                atol=1e-4)
     for pool in (k_pool, v_pool):
@@ -230,14 +229,14 @@ def test_a_long_prompt_walked_in_passes_equals_one_pass(model, monkeypatch):
     n = 61
     prompt = np.zeros((1, 64), np.int32)
     prompt[0, :n] = ids[0, :n]
-    args = (params, prompt, np.array([n - 1], np.int32), *empty_cache(cfg),
-            np.arange(8, dtype=np.int32), np.array([3], np.int32), cfg)
-    whole = tfm.forward_prefill_paged_pattern(*args)
+    args = (params, prompt, np.array([n - 1], np.int32), empty_cache(cfg),
+            np.arange(8, dtype=np.int32), (np.array([3], np.int32),), cfg)
+    whole = tfm.prefill_paged(*args)
     monkeypatch.setattr(tfm, "MAMBA_PREFILL_ROWS", 16)
-    passes = tfm.forward_prefill_paged_pattern(*args)
+    passes = tfm.prefill_paged(*args)
     np.testing.assert_allclose(np.asarray(passes[0][0]), want[0][n - 1],
                                atol=1e-4)
-    for got, one in zip(passes[:5], whole[:5]):
+    for got, one in zip((passes[0], *passes[1]), (whole[0], *whole[1])):
         np.testing.assert_allclose(np.asarray(got), np.asarray(one),
                                    atol=2e-5)
 
@@ -252,19 +251,19 @@ def test_the_state_and_tail_prefill_hands_over_are_the_recurrences(model):
     prompt = np.zeros((1, 24), np.int32)
     prompt[0, :n] = ids[0, :n]
     blocks = np.array([1, 2, 3], np.int32)
-    _, *cache, _ = tfm.forward_prefill_paged_pattern(
-        params, prompt, np.array([n - 1], np.int32), *empty_cache(cfg),
-        blocks, np.array([1], np.int32), cfg)
+    _, cache, _ = tfm.prefill_paged(
+        params, prompt, np.array([n - 1], np.int32), empty_cache(cfg),
+        blocks, (np.array([1], np.int32),), cfg)
     longer = prompt.copy()
     longer[0, n] = ids[0, n]
-    _, _, _, state_n1, tails_n1, _ = tfm.forward_prefill_paged_pattern(
-        params, longer, np.array([n], np.int32), *empty_cache(cfg), blocks,
-        np.array([1], np.int32), cfg)
+    _, (_, _, state_n1, tails_n1), _ = tfm.prefill_paged(
+        params, longer, np.array([n], np.int32), empty_cache(cfg), blocks,
+        (np.array([1], np.int32),), cfg)
     tables = np.zeros((1, 4), np.int32)
     tables[0, :3] = blocks
-    _, _, _, state, tails, _ = tfm.forward_decode_paged_pattern(
-        params, ids[:1, n:n + 1], np.array([[n]], np.int32), *cache, tables,
-        np.array([n], np.int32), np.array([1], np.int32), cfg)
+    _, (_, _, state, tails), _ = tfm.decode_paged(
+        params, ids[:1, n:n + 1], np.array([[n]], np.int32), cache, tables,
+        np.array([n], np.int32), (np.array([1], np.int32),), cfg)
     np.testing.assert_allclose(np.asarray(state[:, 1]),
                                np.asarray(state_n1[:, 1]), atol=2e-5)
     np.testing.assert_allclose(np.asarray(tails[:, 1]),
@@ -283,9 +282,9 @@ def test_prefill_then_decode_through_cache_and_slots(model, others):
     blocks = np.arange(1, 9, dtype=np.int32)
     padded = np.zeros((1, 24), np.int32)
     padded[0, :n] = ids[0, :n]
-    logits, *cache, _ = tfm.forward_prefill_paged_pattern(
+    logits, cache, _ = tfm.prefill_paged(
         params, padded, np.array([n - 1], np.int32),
-        *empty_cache(cfg, n_blocks=24), blocks[:3], np.array([2], np.int32),
+        empty_cache(cfg, n_blocks=24), blocks[:3], (np.array([2], np.int32),),
         cfg)
     np.testing.assert_allclose(np.asarray(logits[0]), want[0][n - 1],
                                atol=1e-4)
@@ -295,18 +294,18 @@ def test_prefill_then_decode_through_cache_and_slots(model, others):
     if others:
         second = np.zeros((1, 24), np.int32)
         second[0, :n] = ids[1, :n]
-        logits, *cache, _ = tfm.forward_prefill_paged_pattern(
-            params, second, np.array([n - 1], np.int32), *cache,
-            np.arange(9, 12, dtype=np.int32), np.array([0], np.int32), cfg)
+        logits, cache, _ = tfm.prefill_paged(
+            params, second, np.array([n - 1], np.int32), cache,
+            np.arange(9, 12, dtype=np.int32), (np.array([0], np.int32),), cfg)
         np.testing.assert_allclose(np.asarray(logits[0]), want[1][n - 1],
                                    atol=1e-4)
         tables[1] = np.arange(9, 17)
-    step = jax.jit(tfm.forward_decode_paged_pattern, static_argnums=(10,))
+    step = jax.jit(tfm.decode_paged, static_argnums=(7,))
     for t in range(n, 64):
         live = np.array([t, t if others else 0, 0], np.int32)
-        logits, *cache, moe = step(
+        logits, cache, (moe,) = step(
             params, np.array([[ids[0, t]], [ids[1, t]], [0]], np.int32),
-            live[:, None], *cache, tables, live, slots, cfg)
+            live[:, None], cache, tables, live, (slots,), cfg)
         np.testing.assert_allclose(np.asarray(logits[0, 0]), want[0][t],
                                    atol=2e-4)
         if others:

@@ -77,8 +77,9 @@ def model():
 def _prefill(cfg, params, pools, ids, n, blocks):
     padded = np.zeros((1, len(blocks) * BS), np.int32)
     padded[0, :n] = ids[:n]
-    logits, *pools = tfm.forward_prefill_paged(
-        params, padded, np.array([n - 1], np.int32), *pools, blocks, cfg)
+    logits, pools, _ = tfm.prefill_paged(
+        params, padded, np.array([n - 1], np.int32), tuple(pools), blocks,
+        (), cfg)
     return np.asarray(logits[0]), pools
 
 
@@ -288,8 +289,8 @@ def test_one_pass_traces_the_flagship_programs_as_before():
             params, np.zeros((2, 1), i32), np.zeros((2, 1), i32), *pools,
             np.zeros((2, 4), i32), np.zeros((2,), i32))
         prefill = jax.make_jaxpr(lambda p, i, last, k, v, b:
-                                 tfm.forward_prefill_paged(p, i, last, k, v,
-                                                           b, cfg))(
+                                 tfm.prefill_paged(p, i, last, (k, v), b,
+                                                   (), cfg))(
             params, np.zeros((1, 8), i32), np.zeros((1,), i32), *pools,
             np.zeros((2,), i32))
     got = {name: hashlib.sha256(str(j).encode()).hexdigest()

@@ -1092,14 +1092,16 @@ def test_engine_fails_only_nonfinite_logit_request():
     real = eng._decode
     fired = []
 
-    def poisoned(p, feed, positions, k_pool, v_pool, tables, lengths, c):
+    def poisoned(p, feed, positions, pools, tables, lengths, extra, c):
         # a NaN in the second K page of r1's row (activation order;
         # block 0 is what padded table entries name): the program's own
         # epilogue has to report the row, and no other, as non-finite
+        k_pool, v_pool = pools
         if not fired:
             k_pool = k_pool.at[0, int(tables[0, 1])].set(np.nan)
             fired.append(True)
-        out = real(p, feed, positions, k_pool, v_pool, tables, lengths, c)
+        out = real(p, feed, positions, (k_pool, v_pool), tables, lengths,
+                   extra, c)
         if len(fired) == 1:
             fired.append(np.asarray(out[1]).ravel().tolist())
         return out
