@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -83,7 +84,10 @@ class TransformerConfig:
     kv_lora_rank: int = 0        # MLA: the cached K/V latent width
     qk_nope_head_dim: int = 0    # MLA: per-head score width without RoPE
     qk_rope_head_dim: int = 0    # MLA: RoPE'd score width, one key for all heads
-    v_head_dim: int = 0          # MLA: per-head value width
+    # MLA: per-head value width; the widened MHA family: a value width
+    # of its own (0: head_dim), whose K and V pages are then lane-flat
+    # (:meth:`TransformerConfig.kv_pool_shapes`)
+    v_head_dim: int = 0
     rope_theta: float = 10000.0
     # yarn (MLA only; factor 1 = plain RoPE): blended frequencies, and
     # the softmax scale times (0.1 * mscale_all_dim * ln factor + 1)^2
@@ -143,6 +147,21 @@ class TransformerConfig:
     # accumulated it, the norms and the router read it unrounded, and
     # the logits come out in it; the matmuls' operands stay ``dtype``)
     residual_dtype: str = ""
+    # ---- each kind of attention layer with a geometry of its own
+    # (MiMo-V2's block).  ``attn_pattern``: published layer j is full
+    # where its letter is "F", sliding where it is "S" (empty: the
+    # layer_group_size rule above).  A sliding layer has
+    # ``swa_n_kv_heads`` K/V heads (0: kv_heads), rotates by
+    # ``swa_rope_theta`` (0: rope_theta) and, with ``swa_sink``, adds a
+    # learned per-head logit to its softmax's denominator.  RoPE turns
+    # the first ``rope_dim`` dims of a head (0: all of them), and the
+    # attention's output is scaled by ``attn_value_scale`` before W_o
+    attn_pattern: str = ""
+    swa_n_kv_heads: int = 0
+    swa_rope_theta: float = 0.0
+    swa_sink: bool = False
+    rope_dim: int = 0
+    attn_value_scale: float = 1.0
     # ---- a fifth block family, served only: ``attention="nemotron_h"``
     # (Nemotron-H's block).  Every layer is ONE thing under one norm,
     # named by its letter of ``layer_pattern`` (the published
@@ -241,6 +260,9 @@ class TransformerConfig:
         if self.layer_pattern:
             return tuple({"M": "mamba", "*": "full", "E": "moe"}[c]
                          for c in self.layer_pattern[:self.n_layers])
+        if self.attn_pattern:
+            return tuple({"F": "full", "S": "sliding"}[c] for c in
+                         self.attn_pattern[self.layer_offset:][:self.n_layers])
         if self.hybrid:
             every, rest = "mla", "kda"
         elif self.family == "mha_swa":
@@ -269,8 +291,8 @@ class TransformerConfig:
         # a layer pattern the attention layers alone have K/V, and each
         # pass of a looped model keeps its own
         n_layers = sum(kind in ("mha", "full") for kind in self.layer_kinds)
-        return ((self.loop_passes * n_layers, n_blocks, block_size,
-                 self.kv_heads, self.head_dim),) * 2
+        return self._kv_shapes(
+            (self.loop_passes * n_layers, n_blocks, block_size), "full")
 
     def sliding_pool_shapes(self, n_rows: int, block_size: int) -> tuple:
         """Shapes of the sliding layers' K and V pools, none for a
@@ -283,8 +305,33 @@ class TransformerConfig:
         if not n_sliding:
             return ()
         ring = -(-self.sliding_window // block_size) + 1
-        return ((n_sliding, n_rows * ring, block_size, self.kv_heads,
-                 self.head_dim),) * 2
+        return self._kv_shapes((n_sliding, n_rows * ring, block_size),
+                               "sliding")
+
+    @property
+    def flat_pages(self) -> bool:
+        """Whether an MHA model's K and V pages lie ``[block_size, H_kv
+        x width]``, the heads side by side in the lanes: where values
+        have a width of their own.  A page ``[block_size, H_kv, 192]``
+        would be laid out with the slots minor (192 is no whole number
+        of 128-lane tiles) and copied whole for the kernel, which reads
+        a page as rows of whole lanes."""
+        return bool(self.v_head_dim) and not self.latent
+
+    def kv_geometry(self, kind: str) -> tuple:
+        """``(K/V heads, key width, value width)`` of an attention layer
+        of this kind."""
+        heads = self.swa_n_kv_heads if kind == "sliding" else 0
+        return (heads or self.kv_heads, self.head_dim,
+                self.v_head_dim or self.head_dim)
+
+    def _kv_shapes(self, lead: tuple, kind: str) -> tuple:
+        """The K and V pools of ``lead`` (layers, blocks, slots) for
+        layers of this kind."""
+        h_kv, dk, dv = self.kv_geometry(kind)
+        if self.flat_pages:
+            return lead + (h_kv * dk,), lead + (h_kv * dv,)
+        return lead + (h_kv, dk), lead + (h_kv, dv)
 
     def state_slot_shapes(self, n_slots: int) -> tuple:
         """``(shape, dtype)`` of each array of per-sequence recurrent
@@ -383,13 +430,10 @@ def _gqa_forward_flops(cfg: TransformerConfig, t: int,
     K/V heads, scores and values over the keys each layer kind sees (a
     sliding layer at most its window), the FFN (dense, or the held
     share of the routed experts beside the shared ones and the router),
-    the unembed; the layers as many times as a looped model runs them."""
-    e, d = cfg.d_model, cfg.head_dim
-    proj = 2 * e * d * (2 * cfg.n_heads + 2 * cfg.kv_heads)
-    seen = {"sliding": min(t, cfg.sliding_window)}
-    attn = sum((2 if causal and seen.get(kind, t) == t else 4)
-               * seen.get(kind, t) * cfg.n_heads * d
-               for kind in cfg.layer_kinds)
+    the unembed; the layers as many times as a looped model runs them.
+    Each kind of layer at its own K/V heads and widths, the leading
+    dense layers at d_ff."""
+    e, h = cfg.d_model, cfg.n_heads
     if cfg.moe_router == "sigmoid":
         routed = cfg.moe_n_routed or cfg.n_experts
         ffn = 2 * (3 * e * cfg.moe_d_ff * (
@@ -397,8 +441,15 @@ def _gqa_forward_flops(cfg: TransformerConfig, t: int,
             + e * routed)
     else:
         ffn = 2 * 3 * e * cfg.d_ff * cfg.n_experts
-    return (cfg.loop_passes * (cfg.n_layers * (proj + ffn) + attn)
-            + 2 * e * cfg.vocab)
+    total = 0.0
+    for i, kind in enumerate(cfg.layer_kinds):
+        h_kv, dk, dv = cfg.kv_geometry(kind)
+        seen = min(t, cfg.sliding_window) if kind == "sliding" else t
+        total += (2 * e * (h + h_kv) * (dk + dv)
+                  + (1 if causal and seen == t else 2) * seen * h * (dk + dv)
+                  + (2 * 3 * e * cfg.d_ff if i < cfg.n_dense_layers
+                     else ffn))
+    return cfg.loop_passes * total + 2 * e * cfg.vocab
 
 
 def _pattern_forward_flops(cfg: TransformerConfig, t: int,
@@ -519,25 +570,47 @@ def _init_held_expert_params(key, cfg: TransformerConfig, n_stages: int = 1):
     (``w_in`` / ``w_gate [L x X, E, F]``, ``w_out [L x X, F, E]``, layer
     i's at [i X, (i + 1) X)), which is how the grouped product takes
     them (:func:`_moe_held_ffn`).  ``unembed`` is absent where the head
-    is tied to ``embed``."""
+    is tied to ``embed``.
+
+    Each layer's K/V heads and value width are its kind's
+    (:meth:`TransformerConfig.kv_geometry`: ``wv [E, H_kv, d_v]``,
+    ``wo [H, d_v, E]``).  A leading dense layer (i < n_dense_layers)
+    has the d_ff SwiGLU ``w_in`` / ``w_gate [E, d_ff]``, ``w_out [d_ff,
+    E]`` in place of the router, and no experts in the stack.  With
+    ``moe_router_bias`` a router has its float32 correction bias
+    ``gate_bias [routed]``; with ``swa_sink`` a sliding layer has its
+    float32 ``sink [H]``, seeded uniform in [0, ln(2 x window)]: from
+    under one key's weight to twice the whole window's."""
     assert n_stages == 1
-    e, h, h_kv, d = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    e, h, d = cfg.d_model, cfg.n_heads, cfg.head_dim
     x, fm, fs = cfg.n_experts, cfg.moe_d_ff, cfg.moe_n_shared * cfg.moe_d_ff
     routed = cfg.moe_n_routed or x
     n = cfg.n_layers
+    n_moe = n - cfg.n_dense_layers
     keys = iter(jax.random.split(key, 8 * n + 8))
 
-    def norm(*shape):
+    def norm(*shape, dtype=cfg.jdtype):
         return (jax.random.normal(next(keys), shape, jnp.float32)
-                * 0.02).astype(cfg.jdtype)
+                * 0.02).astype(dtype)
 
     def ones():
         return jnp.ones((e,), cfg.jdtype)
 
-    def layer():
+    def layer(i, kind):
+        h_kv, _, dv = cfg.kv_geometry(kind)
         p = {"ln1": ones(), "wq": norm(e, h, d), "wk": norm(e, h_kv, d),
-             "wv": norm(e, h_kv, d), "wo": norm(h, d, e),
-             "gate": norm(e, routed)}
+             "wv": norm(e, h_kv, dv), "wo": norm(h, dv, e)}
+        if i < cfg.n_dense_layers:
+            p.update(w_in=norm(e, cfg.d_ff), w_gate=norm(e, cfg.d_ff),
+                     w_out=norm(cfg.d_ff, e))
+        else:
+            p["gate"] = norm(e, routed)
+            if cfg.moe_router_bias:
+                p["gate_bias"] = norm(routed, dtype=jnp.float32)
+        if kind == "sliding" and cfg.swa_sink:
+            p["sink"] = jax.random.uniform(
+                next(keys), (h,), jnp.float32, 0.0,
+                math.log(2.0 * cfg.sliding_window))
         if not cfg.parallel_block:
             p["ln2"] = ones()
         if fs:
@@ -546,9 +619,10 @@ def _init_held_expert_params(key, cfg: TransformerConfig, n_stages: int = 1):
 
     tree = {
         "embed": norm(cfg.vocab, e), "ln_f": ones(),
-        "layers": [layer() for _ in range(n)],
-        "experts": {"w_in": norm(n * x, e, fm), "w_gate": norm(n * x, e, fm),
-                    "w_out": norm(n * x, fm, e)},
+        "layers": [layer(i, kind) for i, kind in enumerate(cfg.layer_kinds)],
+        "experts": {"w_in": norm(n_moe * x, e, fm),
+                    "w_gate": norm(n_moe * x, e, fm),
+                    "w_out": norm(n_moe * x, fm, e)},
     }
     if not cfg.tie_embeddings:
         tree["unembed"] = norm(e, cfg.vocab)
@@ -1064,29 +1138,32 @@ def decode_flops_per_token(cfg: TransformerConfig, ctx: int) -> float:
     return train_flops_per_token(cfg, ctx, causal=False) / 3.0
 
 
-def _causal_attention(q, k, v, scale=None, span: int = 0, q_offset=None):
+def _causal_attention(q, k, v, scale=None, span: int = 0, q_offset=None,
+                      sink=None):
     """Causal full-sequence attention with the whole sequence on this
     device (serving prefill; training without an sp axis): the Pallas
     flash kernel — O(T) memory instead of a materialized [B,H,T,T]
     score matrix — or the lax oracle, as ops/dispatch decides.  ``v``
     may have its own head size (latent attention: qk 192, v 128), k
     and v fewer heads than q (grouped-query), ``span`` > 0 is a sliding
-    window and ``q_offset`` places q's rows at ``q_offset + i`` against
-    the keys (``ops.flash_attention.flash_attention``)."""
+    window, ``q_offset`` places q's rows at ``q_offset + i`` against
+    the keys and ``sink`` [H] is a per-head logit in the softmax's
+    denominator (``ops.flash_attention.flash_attention``)."""
     from ..ops import dispatch
     from ..ops import flash_attention as _flash
 
     mode = dispatch.choose(_flash.supports(q.shape, k.shape, v.shape))
-    plain = k.shape[2] == q.shape[2] and not span and q_offset is None
+    plain = (k.shape[2] == q.shape[2] and not span and q_offset is None
+             and sink is None)
     if mode == dispatch.LAX and plain:
         return ring_attention_reference(q, k, v, causal=True, scale=scale)
     if mode == dispatch.LAX:
         return _flash.lax_attention(
             q, k, v, scale=q.shape[-1] ** -0.5 if scale is None else scale,
-            span=span, q_offset=q_offset)
+            span=span, q_offset=q_offset, sink=sink)
     return _flash.flash_attention(q, k, v, causal=True, scale=scale,
                                   interpret=mode == dispatch.INTERPRET,
-                                  span=span, q_offset=q_offset)
+                                  span=span, q_offset=q_offset, sink=sink)
 
 
 def _layer_params(blocks, stage: int, layer: int):
@@ -1139,6 +1216,27 @@ def _into_stream(cfg: TransformerConfig) -> dict:
 def _rotates(kind: str, cfg: TransformerConfig) -> bool:
     """Whether a layer of this kind rotates q and k by position."""
     return kind != "full" or cfg.full_layers_rope
+
+
+def _rotate(rotation, x, positions, kind: str, cfg: TransformerConfig):
+    """``x [B, T, H, d]`` rotated by ``rotation`` (``rope`` or
+    :func:`_rope_window`) at its kind's theta: the whole head, or its
+    first ``rope_dim`` dims as two halves, the rest as it is."""
+    theta = (cfg.swa_rope_theta if kind == "sliding" and cfg.swa_rope_theta
+             else cfg.rope_theta)
+    n = cfg.rope_dim
+    if not n:
+        return rotation(x, positions, theta)
+    return jnp.concatenate([rotation(x[..., :n], positions, theta),
+                            x[..., n:]], axis=-1)
+
+
+def _attention_out(o, p, cfg: TransformerConfig):
+    """The attention's addend: ``o [B, T, H, d_v]`` scaled by
+    ``attn_value_scale`` (as scaling v would), then W_o."""
+    if cfg.attn_value_scale != 1.0:
+        o = o * jnp.asarray(cfg.attn_value_scale, o.dtype)
+    return jnp.einsum("bthd,hde->bte", o, p["wo"], **_into_stream(cfg))
 
 
 def _post_norm(y, p, name: str, cfg: TransformerConfig):
@@ -1241,10 +1339,12 @@ def forward_prefill_last(params, ids, last_index, cfg: TransformerConfig):
 
 def _paged(a, pool):
     """K or V of ONE sequence's whole blocks ``[..., 1, n x bs, H_kv,
-    hd]`` as pages ``[..., n, bs, H_kv, hd]`` in the pool's dtype."""
+    hd]`` as pages ``[..., n, bs, H_kv, hd]`` in the pool's dtype (a
+    lane-flat page, :attr:`TransformerConfig.flat_pages`, has the heads
+    side by side: ``[..., n, bs, H_kv x hd]``)."""
     bs = pool.shape[2]
     return a.reshape(a.shape[:-4] + (a.shape[-3] // bs, bs)
-                     + a.shape[-2:]).astype(pool.dtype)
+                     + pool.shape[3:]).astype(pool.dtype)
 
 
 def _rope_window(x, positions, theta: float = 10000.0):
@@ -1430,8 +1530,6 @@ def _yarn_inv_freq(cfg: TransformerConfig):
 
 def _mla_scale(cfg: TransformerConfig) -> float:
     """Softmax scale: qk width^-0.5 times yarn's mscale squared."""
-    import math
-
     m = 1.0
     if cfg.rope_yarn_factor > 1.0:
         m += 0.1 * cfg.rope_yarn_mscale_all_dim * math.log(
@@ -2048,13 +2146,13 @@ def _stacked_halves(params):
 def _listed_halves(params, cfg: TransformerConfig):
     """The layers of a tree that lists them (``layers``, one dict a
     layer) as ``(layer params, first_group)``: where the tree has held
-    experts (``experts``, one stack), every layer that has an FFN (under
-    a layer pattern an expert layer) carries the stack, its own at
-    ``first_group``."""
+    experts (``experts``, one stack), every layer that has a router
+    (under a layer pattern an expert layer; not a leading dense one)
+    carries the stack, its own at ``first_group``."""
     experts = params.get("experts")
     held = 0
-    for kind, p in zip(cfg.layer_kinds, params["layers"]):
-        if experts is None or (_one_sublayer(cfg) and kind != "moe"):
+    for p in params["layers"]:
+        if experts is None or "gate" not in p:
             yield p, None
         else:
             yield {**p, **experts}, held * cfg.n_experts
@@ -2132,7 +2230,7 @@ def _attention_keys(xn, layer: _Layer, cfg: TransformerConfig):
     k = _heads(xa, layer.p["wk"], cfg)
     v = _heads(xa, layer.p["wv"], cfg)
     if _rotates(layer.kind, cfg):
-        k = rope(k, jnp.arange(xn.shape[1]), cfg.rope_theta)
+        k = _rotate(rope, k, jnp.arange(xn.shape[1]), layer.kind, cfg)
     return k, v
 
 
@@ -2146,14 +2244,15 @@ def _attention_rows(xn, layer: _Layer, cfg: TransformerConfig, kv, first,
     span = cfg.sliding_window if layer.kind == "sliding" else 0
     q = _heads(xn.astype(cfg.jdtype), layer.p["wq"], cfg)
     if _rotates(layer.kind, cfg):
-        q = rope(q, first + jnp.arange(xn.shape[1]), cfg.rope_theta)
+        q = _rotate(rope, q, first + jnp.arange(xn.shape[1]), layer.kind, cfg)
     # the scope states the call's T, window and chunks: the benchmark's
     # roofline reader counts each call's operations from them
     with _scope(_MIXERS[layer.kind].scopes[0]
                 and f"prefill_attn_t{t}_w{span}_c{n_chunks}"):
         o = _causal_attention(q, k, v, span=span,
-                              q_offset=None if n_chunks == 1 else first)
-    return jnp.einsum("bthd,hde->bte", o, layer.p["wo"], **_into_stream(cfg))
+                              q_offset=None if n_chunks == 1 else first,
+                              sink=layer.p.get("sink"))
+    return _attention_out(o, layer.p, cfg)
 
 
 def _attention_decode(xn, layer: _Layer, li, cache, cfg: TransformerConfig):
@@ -2175,10 +2274,14 @@ def _attention_decode(xn, layer: _Layer, li, cache, cfg: TransformerConfig):
     k = _heads(xa, p["wk"], cfg)
     v = _heads(xa, p["wv"], cfg)
     if _rotates(layer.kind, cfg):
-        q = _rope_window(q, cache.positions, cfg.rope_theta)
-        k = _rope_window(k, cache.positions, cfg.rope_theta)
-    k_pool = k_pool.at[li, wb, ws].set(k.astype(k_pool.dtype), mode="drop")
-    v_pool = v_pool.at[li, wb, ws].set(v.astype(v_pool.dtype), mode="drop")
+        q = _rotate(_rope_window, q, cache.positions, layer.kind, cfg)
+        k = _rotate(_rope_window, k, cache.positions, layer.kind, cfg)
+    k_pool = k_pool.at[li, wb, ws].set(
+        k.reshape(k.shape[:2] + k_pool.shape[3:]).astype(k_pool.dtype),
+        mode="drop")
+    v_pool = v_pool.at[li, wb, ws].set(
+        v.reshape(v.shape[:2] + v_pool.shape[3:]).astype(v_pool.dtype),
+        mode="drop")
     cache.set(group, k_pool, v_pool)
     # the layers' pools as one run of pages, a free view: a per-layer
     # slice of a pool would be copied for the kernel
@@ -2186,8 +2289,9 @@ def _attention_decode(xn, layer: _Layer, li, cache, cfg: TransformerConfig):
         q, k_pool.reshape((-1,) + k_pool.shape[2:]),
         v_pool.reshape((-1,) + v_pool.shape[2:]),
         cache.tables[group] + li * k_pool.shape[1], cache.lengths,
-        span=cfg.sliding_window if layer.kind == "sliding" else 0)
-    return jnp.einsum("bthd,hde->bte", o, p["wo"], **_into_stream(cfg))
+        span=cfg.sliding_window if layer.kind == "sliding" else 0,
+        sink=p.get("sink"))
+    return _attention_out(o, p, cfg)
 
 
 def _moe_prefill(xn, layer: _Layer, cache, cfg: TransformerConfig):
@@ -2266,15 +2370,15 @@ def _write_prompt_rows(pool, layer: int, block_ids, row):
         row.reshape(-1, bs, row.shape[-1]), 1, 2).astype(pool.dtype))
 
 
-def _window_at(pool, table, lengths, pos_w, ring: bool):
+def _window_at(pool, table, lengths, pos_w, ring: bool, latent: bool):
     """Where a decode window's entries go in ``pool`` (a K/V pool, whose
-    page is ``[block_size, ...]``, or the latent pool's ``[row,
+    page is ``[block_size, ...]``, or the ``latent`` pool's ``[row,
     block_size]``): ``(blocks [B, S], slots [B, S])`` of positions
     ``pos_w`` [B, S] through ``table`` [B, W], a ring's modulo its
     width; a dead row (length 0) gets a block past the pool, which
     drops its write."""
     n_blocks = pool.shape[1]
-    bs = pool.shape[3] if pool.ndim == 4 else pool.shape[2]
+    bs = pool.shape[3] if latent else pool.shape[2]
     lb = pos_w // bs
     lb = lb % table.shape[1] if ring else jnp.clip(lb, 0, table.shape[1] - 1)
     wb = jnp.take_along_axis(table, lb, axis=1)
@@ -2546,7 +2650,7 @@ def decode_paged(params, ids, positions, pools, block_tables, lengths,
     pos_w = lengths[:, None] + jnp.arange(s_w)[None, :]          # [B, S]
     for group, table in cache.tables.items():
         cache.where[group] = _window_at(cache.get(group)[0], table, lengths,
-                                        pos_w, group == "ring")
+                                        pos_w, group == "ring", cfg.latent)
     cache.valid = cache.live[:, None] if cache.live is not None \
         else jnp.broadcast_to(lengths[:, None] > 0, (b, s_w))
     x = embed_lookup(params["embed"], ids, ShardAxes()).astype(

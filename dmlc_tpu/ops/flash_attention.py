@@ -178,22 +178,30 @@ def _walk(pl, walk, j, interpret: bool):
         walk()
 
 
-def _kernel_o(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref, o_ref,
-              pv_ref, m_ref, l_ref, *, n_kv: int, **static):
+def _kernel_o(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref, *refs,
+              n_kv: int, n_heads: int = 0, **static):
     """:func:`_kernel` with its accumulators in VMEM scratch and the
     normalised ``o = pv / l`` written once, in the inputs' dtype, at
     the sequence's last KV step: for a forward that needs no (m, l).
     In HBM an 8-lane f32 row is padded to 128 lanes, so m and l of a
-    16k-token, 64-head call cost 512 MB each, and pv in f32 twice o."""
+    16k-token, 64-head call cost 512 MB each, and pv in f32 twice o.
+    ``refs`` is ``(o, pv, m, l)``, after the per-head sinks ``[H]`` in
+    SMEM where there are any: head h's sink b joins the denominator as
+    ``l + exp(b - m)`` (grid step ``bi`` is one (b, h) pair, h = bi mod
+    ``n_heads``)."""
     from jax.experimental import pallas as pl
 
+    o_ref, pv_ref, m_ref, l_ref = refs[-4:]
+    sink = refs[0][pl.program_id(0) % n_heads] if len(refs) == 5 else None
     _kernel(qoff_ref, kvoff_ref, kvend_ref, q_ref, k_ref, v_ref,
             pv_ref, m_ref, l_ref, **static)
 
     @pl.when(pl.program_id(2) == n_kv - 1)
     def _write():
-        o_ref[...] = (pv_ref[...] / jnp.maximum(l_ref[..., :1], 1e-20)
-                      ).astype(o_ref.dtype)
+        pv, l = pv_ref[...], l_ref[..., :1]
+        if sink is not None:
+            l = l + jnp.exp(sink - m_ref[..., :1])
+        o_ref[...] = (pv / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
 def supports(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
@@ -293,14 +301,15 @@ def _pad_seq(x, pad):
 
 
 def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False,
-                   span: int = 0):
+                   span: int = 0, sink=None):
     """``(pv, m, l)`` partials, or with ``normalized`` the finished
     ``o [B, Tq, H, dv]`` in q's dtype (kernel ``flash_fwd_o``).  ``k``
     and ``v`` may have fewer heads than ``q``: query head ``h`` reads
     K/V head ``h // (H / H_kv)``, through the K/V blocks' index map, so
     no head is repeated in memory.  ``span`` > 0 is a sliding window
     (causal, query i sees keys i - span < j <= i): the grid's KV axis
-    covers a Q block's reach and no more."""
+    covers a Q block's reach and no more.  ``sink`` [H] float32
+    (``normalized`` only): a per-head logit in each row's denominator."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -382,6 +391,10 @@ def _flash_forward(static, q, k, v, qoff, kvoff, normalized: bool = False,
                          kv_padded=kv_padded, scale=scale,
                          interpret=bool(interpret), span=int(span))
     if normalized:
+        if sink is not None:
+            in_specs = in_specs + [pl.BlockSpec(memory_space=pltpu.SMEM)]
+            operands = operands + (sink.astype(jnp.float32),)
+            kernel_static["n_heads"] = h
         o = pl.pallas_call(
             functools.partial(_kernel_o, n_kv=grid[2], **kernel_static),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -779,11 +792,13 @@ _flash_attn.defvjp(_flash_attn_fwd, _flash_attn_bwd)
 
 
 def lax_attention(q, k, v, *, scale: float, causal: bool = True,
-                  span: int = 0, q_offset=None):
+                  span: int = 0, q_offset=None, sink=None):
     """The lax twin of the forward-only call: q [B, Tq, H, D] at global
     positions ``q_offset + i`` against k, v [B, Tk, H_kv, D] at ``j``;
     query head ``h`` reads K/V head ``h // (H / H_kv)``; ``span`` > 0
-    keeps keys ``i - span < j <= i``.  Dense softmax, f32 scores."""
+    keeps keys ``i - span < j <= i``; ``sink`` [H] is a per-head logit
+    that joins each row's denominator and adds no value.  Dense
+    softmax, f32 scores."""
     b, tq, h, d = q.shape
     tk, h_kv = k.shape[1], k.shape[2]
     qg = q.reshape(b, tq, h_kv, h // h_kv, d)
@@ -797,30 +812,47 @@ def lax_attention(q, k, v, *, scale: float, causal: bool = True,
         if span:
             keep = keep & (i - j < span)
         s = jnp.where(keep, s, _NEG_BIG)
+    if sink is not None:
+        return _sunk_softmax_values(
+            s, sink.reshape(h_kv, h // h_kv), v).reshape(
+                b, tq, h, v.shape[-1]).astype(q.dtype)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqt,btkd->bqkgd", p, v.astype(jnp.float32),
                    preferred_element_type=jnp.float32)
     return o.reshape(b, tq, h, v.shape[-1]).astype(q.dtype)
 
 
+def _sunk_softmax_values(s, sink, v):
+    """``s [B, H_kv, G, Tq, Tk]`` float32 scores with the sinks ``[H_kv,
+    G]`` as one more logit each row, softmaxed; the sink's share is
+    dropped and the rest weighs ``v [B, Tk, H_kv, dv]``: ``[B, Tq,
+    H_kv, G, dv]`` float32."""
+    col = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, :, None, None],
+                           s.shape[:-1] + (1,))
+    p = jax.nn.softmax(jnp.concatenate([s, col], axis=-1), axis=-1)
+    return jnp.einsum("bkgqt,btkd->bqkgd", p[..., :-1], v.astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash_fwd_only(static, q, k, v, qoff):
+def _flash_fwd_only(static, q, k, v, qoff, sink):
     """The forward alone (kernel ``flash_fwd_o``), for what the
     backward kernels do not cover: a value head size of its own,
-    grouped heads, a sliding window, a query offset."""
+    grouped heads, a sliding window, a query offset, sinks (None: no
+    sink)."""
     return _flash_forward(static[:-1], q, k, v, qoff,
                           jnp.zeros(1, jnp.int32), normalized=True,
-                          span=static[-1])
+                          span=static[-1], sink=sink)
 
 
-def _flash_fwd_only_fwd(static, q, k, v, qoff):
-    return _flash_fwd_only(static, q, k, v, qoff), None
+def _flash_fwd_only_fwd(static, q, k, v, qoff, sink):
+    return _flash_fwd_only(static, q, k, v, qoff, sink), None
 
 
 def _flash_fwd_only_bwd(static, res, do):
     raise NotImplementedError(
         "flash attention with grouped heads, a sliding window, a query "
-        "offset or a value head size of its own is forward-only: the "
+        "offset, a sink or a value head size of its own is forward-only: the "
         "backward kernels take none of them, and a causal gradient in "
         "their place would be wrong")
 
@@ -833,7 +865,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False, span: int = 0,
-                    q_offset=None):
+                    q_offset=None, sink=None):
     """Standalone exact attention via the flash kernels (single device).
 
     q/k/v: [B, T, H, D].  Differentiable where q, k and v share one
@@ -844,8 +876,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     sees keys i - span < j <= i; blocks behind it cost nothing), and
     ``q_offset`` (a traced int) places q's rows at ``q_offset + i``
     against keys at ``j`` (a prompt's rows walked in chunks against its
-    whole K/V): each of these is forward-only, and differentiating it
-    raises.  The oracle-equivalent of
+    whole K/V), and ``sink`` [H] adds a learned logit per query head to
+    every row's softmax denominator (in the kernel's last write, so a
+    call without one lowers as it did): each of these is forward-only,
+    and differentiating it raises.  The oracle-equivalent of
     ring_attention_reference with O(T) memory in BOTH directions: the
     backward recomputes P from the saved (o, lse) residuals in blocks
     (dkv + dq kernels) instead of materializing the T×T matrix.
@@ -896,8 +930,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
         block_k = get_env("DMLC_FLASH_BLOCK_K", 0) or 1024
     static = (float(scale), bool(causal), int(block_q), int(block_k),
               bool(interpret), int(bwd_q), int(bwd_k))
-    if dv != d or k.shape[2] != h or span or q_offset is not None:
+    if (dv != d or k.shape[2] != h or span or q_offset is not None
+            or sink is not None):
         qoff = jnp.asarray(0 if q_offset is None else q_offset,
                            jnp.int32).reshape(1)
-        return _flash_fwd_only(static[:5] + (int(span),), q, k, v, qoff)
+        return _flash_fwd_only(static[:5] + (int(span),), q, k, v, qoff,
+                               sink)
     return _flash_attn(static, q, k, v)

@@ -80,6 +80,20 @@ D]``, this one is the faster (PERF.md, PR 30), so the pool keeps its
 layout, under grouped heads too (PERF.md, PR 33).  Block and chunk sizes follow from
 the shapes (:func:`_walk_shape`), not from a knob.
 
+Keys and values of different widths (MiMo-V2's 192 and 128) come in
+*lane-flat* pages ``[bs, H_kv x width]``, the heads side by side in the
+lanes (a ``[bs, H_kv, 192]`` page is no whole number of lane tiles, and
+the chip's compiler would lay it out slots-minor).  The same walk takes
+them: a chunk is ``pages*bs`` key rows of ``H_kv x d_k`` lanes, q comes
+in block-diagonal (query row ``s*H + h`` holds its head's q in the
+lanes of its own K/V head and zeros elsewhere), so ONE product scores
+every head against a page's tokens at the cost the cross-head product
+above pays, the mask is the position alone, and at a row's end each
+query row keeps the ``d_v`` value lanes of its own K/V head.  A
+per-head *sink* (a learned logit with no value) joins each row's
+denominator in that last normalisation, ``l + exp(b_h - m)``; a call
+without one lowers as it did.
+
 Latent attention (MLA) decodes against another cache: ONE row per
 token and layer, ``[rms(c_kv) | rope(k_pe)]``, with no head axis and no
 separate V.  :func:`latent_paged_attention` is that kernel
@@ -101,8 +115,8 @@ import numpy as np
 
 _NEG_BIG = -1e30
 
-__all__ = ["paged_attention", "supports", "latent_paged_attention",
-           "latent_supports"]
+__all__ = ["paged_attention", "supports", "flat_supports",
+           "latent_paged_attention", "latent_supports"]
 
 
 def supports(head_dim: int, block_size: int, n_kv_heads: int) -> bool:
@@ -112,6 +126,14 @@ def supports(head_dim: int, block_size: int, n_kv_heads: int) -> bool:
     return head_dim % 128 == 0 and (block_size * n_kv_heads) % 128 == 0
 
 
+def flat_supports(k_lanes: int, v_lanes: int, block_size: int) -> bool:
+    """Whether the Pallas kernel serves lane-flat pages ``[block_size,
+    H_kv x width]``: K's and V's rows fill whole 128-element lanes, and
+    a page's tokens whole bf16 sublane tiles (the chunk of pages is
+    then one matrix of rows without a copy)."""
+    return k_lanes % 128 == 0 and v_lanes % 128 == 0 and block_size % 16 == 0
+
+
 def _first_key(lengths, span: int):
     """The oldest position a row's first window token (at
     ``lengths[b]``) sees under a sliding window of ``span`` keys."""
@@ -119,18 +141,21 @@ def _first_key(lengths, span: int):
 
 
 def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale,
-                         span: int = 0):
+                         span: int = 0, sink=None):
     """Gather-composed fallback: the block gather happens INSIDE jit
     (one fused gather per layer, no host staging, no dense [B, maxlen]
     intermediate on the host) and the math is dense softmax attention
     with an f32 score path — the 1e-5 parity contract the kernel is
     held to.  Under ``span`` the table is a ring: entry ``i`` holds the
-    newest logical block ``j = i (mod W)`` the row has reached."""
+    newest logical block ``j = i (mod W)`` the row has reached.  Pages
+    of either layout (:func:`paged_attention`); ``sink`` [H] joins each
+    row's softmax as one more logit with no value."""
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
-    bs, h_kv = k_pool.shape[1], k_pool.shape[2]
+    bs = k_pool.shape[1]
+    h_kv = k_pool.shape[2] if k_pool.ndim == 4 else k_pool.shape[2] // d
     k_ctx = k_pool[block_tables].reshape(b, w * bs, h_kv, d)
-    v_ctx = v_pool[block_tables].reshape(b, w * bs, h_kv, d)
+    v_ctx = v_pool[block_tables].reshape(b, w * bs, h_kv, -1)
     qg = q.reshape(b, s_w, h_kv, h // h_kv, d)
     s = jnp.einsum("bqkgd,btkd->bkgqt", qg, k_ctx,
                    preferred_element_type=jnp.float32) * scale
@@ -146,10 +171,17 @@ def _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths, scale,
     if span:
         keep = keep & (pos > limit[:, :, None] - span)
     s = jnp.where(keep[:, None, None], s, _NEG_BIG)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(h_kv, h // h_kv)[
+                None, :, :, None, None], s.shape[:-1] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, col], axis=-1),
+                           axis=-1)[..., :-1]
     out = jnp.einsum("bkgqt,btkd->bqkgd", p.astype(v_ctx.dtype), v_ctx,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, s_w, h, d).astype(q.dtype)
+    return out.reshape(b, s_w, h, v_ctx.shape[-1]).astype(q.dtype)
 
 
 #: VMEM the walk's K and V blocks take together: two of each, so that
@@ -173,30 +205,40 @@ def _walk_shape(rows: int, cols: int, d: int, itemsize: int,
     return chunk, max(1, min(fit, -(-w // chunk)))
 
 
-def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, diff_ref, *, n_heads: int, n_kv_heads: int,
-            bs: int, s_w: int, chunk: int, scale: float, span: int):
+def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, n_heads: int,
+            n_kv_heads: int, bs: int, s_w: int, chunk: int, scale: float,
+            span: int, flat: bool = False):
     """One invocation walks every row: ``k_buf`` / ``v_buf``
-    ``[2, pages, bs*H_kv, D]`` are the two blocks, ``sems[kv, slot, c]``
-    counts the copies of chunk ``c`` of a block.  Under ``span`` a
-    row's walk starts at the page of the oldest key its window reaches
-    and the table is a ring (page ``p`` at entry ``p mod W``)."""
+    ``[2, pages, bs*H_kv, D]`` (lane-flat pages: ``[2, pages, bs, H_kv
+    x width]``) are the two blocks, ``sems[kv, slot, c]`` counts the
+    copies of chunk ``c`` of a block.  Under ``span`` a row's walk
+    starts at the page of the oldest key its window reaches and the
+    table is a ring (page ``p`` at entry ``p mod W``).  ``refs`` is
+    ``(o, k_buf, v_buf, sems, diff)``, after the rows' sinks ``[S*H,
+    1]`` where there are any."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    sink_ref = refs[0] if len(refs) == 6 else None
+    o_ref, k_buf, v_buf, sems, diff_ref = refs[-5:]
     n_rows, rows, d = q_ref.shape
     pages = k_buf.shape[1]
-    ckeys = chunk * bs * n_kv_heads
+    ckeys = chunk * bs * (1 if flat else n_kv_heads)
     width = tbl_ref.shape[1]
     group = n_heads // n_kv_heads
 
     # the mask's static part, once a call: key column c = t*H_kv + h'
     # may meet query row r = s*H + h where h reads K/V head h', t - s
-    # tokens past the chunk's first position
+    # tokens past the chunk's first position.  A lane-flat page's
+    # column is a token, its heads side by side in the lanes, and the
+    # query row holds its head's q in its own K/V head's lanes alone
     r = jax.lax.broadcasted_iota(jnp.int32, (rows, ckeys), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (rows, ckeys), 1)
-    diff_ref[...] = jnp.where(r % n_heads // group == c % n_kv_heads,
-                              c // n_kv_heads - r // n_heads, 1 << 30)
+    if flat:
+        diff_ref[...] = c - r // n_heads
+    else:
+        diff_ref[...] = jnp.where(r % n_heads // group == c % n_kv_heads,
+                                  c // n_kv_heads - r // n_heads, 1 << 30)
 
     # a row's last block copies its live pages only; what the slot
     # holds behind them is multiplied by p = 0, so it must be finite:
@@ -273,8 +315,9 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                                   jnp.minimum(live, (ci + 1) * chunk),
                                   landed, 0)
                 at = pl.ds(ci * chunk, chunk)
-                k = k_buf[slot, at].reshape(ckeys, d)  # row = t*H + h
-                v = v_buf[slot, at].reshape(ckeys, d)
+                # row = t*H + h, or t of lane-flat pages
+                k = k_buf[slot, at].reshape(ckeys, k_buf.shape[-1])
+                v = v_buf[slot, at].reshape(ckeys, v_buf.shape[-1])
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
@@ -304,11 +347,22 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                                           (m, l, acc))
             return 1 - slot, m, l, acc
 
-        slot, _, l, acc = jax.lax.fori_loop(
+        slot, m, l, acc = jax.lax.fori_loop(
             0, n_blocks, block,
             (slot, jnp.full((rows, 1), _NEG_BIG, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
-             jnp.zeros((rows, d), jnp.float32)))
+             jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)))
+        if flat:
+            # each row's own K/V head's values, lane-aligned slices
+            dv = o_ref.shape[-1]
+            head = jax.lax.broadcasted_iota(jnp.int32, (rows, dv),
+                                            0) % n_heads // group
+            acc = sum(jnp.where(head == g, acc[:, g * dv:(g + 1) * dv], 0.0)
+                      for g in range(n_kv_heads))
+        if sink_ref is not None:
+            # the row's sink joins the denominator (a dead row's m is
+            # -1e30: l = inf and acc = 0 write zeros)
+            l = l + jnp.exp(sink_ref[...] - m)
         # a dead row walked nothing: acc = l = 0 writes zeros
         o_ref[b] = acc / jnp.maximum(l, 1e-37)
         return slot
@@ -318,56 +372,83 @@ def _kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "span"))
 def _walk_pool(q, k_pool, v_pool, block_tables, lengths, scale, interpret,
-               span=0):
+               span=0, sink=None):
     """The kernel's call.  Jitted so that a decode program's layers,
     whose calls differ in their tables' values alone, trace and lower
     the walk once and not once a layer; its name keeps clear of the
-    kernel's, which the benchmark counts by."""
+    kernel's, which the benchmark counts by.  Lane-flat pages
+    (``k_pool.ndim == 3``) take q as each row's block-diagonal row,
+    head h's q in the lanes of its own K/V head and zeros elsewhere, so
+    that ONE product against a page's tokens scores every head."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s_w, h, d = q.shape
     w = block_tables.shape[1]
-    n_pages, bs, h_kv = k_pool.shape[:3]
-    rows, cols = s_w * h, bs * h_kv
-    chunk, per_block = _walk_shape(rows, cols, d, k_pool.dtype.itemsize, w)
+    flat = k_pool.ndim == 3
+    n_pages, bs = k_pool.shape[:2]
+    rows = s_w * h
+    if flat:
+        h_kv = k_pool.shape[2] // d
+        dv, cols = v_pool.shape[2] // h_kv, bs
+        own = (jnp.arange(h)[:, None] // (h // h_kv)
+               == jnp.arange(h_kv)[None, :]).astype(q.dtype)
+        q_in = jnp.einsum("bshd,hg->bshgd", q, own).reshape(b, rows, -1)
+        k_in, v_in = k_pool, v_pool
+        lanes = (k_pool.shape[2] + v_pool.shape[2]) // 2
+    else:
+        h_kv = k_pool.shape[2]
+        dv, cols, lanes = d, bs * h_kv, d
+        q_in = q.reshape(b, rows, d)                     # row = s*H + h
+        k_in = k_pool.reshape(n_pages, cols, d)          # row = t*H_kv + h'
+        v_in = v_pool.reshape(n_pages, cols, d)
+    chunk, per_block = _walk_shape(rows, cols, lanes, k_pool.dtype.itemsize,
+                                   w)
     pages = chunk * per_block
-    block = pltpu.VMEM((2, pages, cols, d), k_pool.dtype)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),   # the tables
+                pl.BlockSpec(memory_space=pltpu.SMEM),   # the lengths
+                pl.BlockSpec(memory_space=pltpu.VMEM),   # q, whole
+                pl.BlockSpec(memory_space=pl.ANY),       # the pools stay
+                pl.BlockSpec(memory_space=pl.ANY)]       # in HBM
+    operands = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+                q_in, k_in, v_in]
+    if sink is not None:  # row s*H + h's
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+        operands.append(jnp.tile(sink.astype(jnp.float32), s_w)[:, None])
     out = pl.pallas_call(
         functools.partial(_kernel, n_heads=h, n_kv_heads=h_kv, bs=bs,
-                          s_w=s_w, chunk=chunk, scale=scale, span=span),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # the tables
-                  pl.BlockSpec(memory_space=pltpu.SMEM),   # the lengths
-                  pl.BlockSpec(memory_space=pltpu.VMEM),   # q, whole
-                  pl.BlockSpec(memory_space=pl.ANY),       # the pools stay
-                  pl.BlockSpec(memory_space=pl.ANY)],      # in HBM
+                          s_w=s_w, chunk=chunk, scale=scale, span=span,
+                          flat=flat),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), jnp.float32),
-        scratch_shapes=[block, block,
+        out_shape=jax.ShapeDtypeStruct((b, rows, dv), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, pages) + k_in.shape[1:], k_pool.dtype),
+                        pltpu.VMEM((2, pages) + v_in.shape[1:], v_pool.dtype),
                         pltpu.SemaphoreType.DMA((2, 2, per_block)),
                         pltpu.VMEM((rows, chunk * cols), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         name="paged_attn",
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q.reshape(b, rows, d),                             # row = s*H + h
-      k_pool.reshape(n_pages, cols, d),                  # row = t*H_kv + h'
-      v_pool.reshape(n_pages, cols, d))
+    )(*operands)
     # float32 out of the kernel: whatever XLA does to hand the result
     # on (a cast, a layout for the o projection) is then an op of its
     # own name and not a second event under the kernel's
-    return out.astype(q.dtype).reshape(b, s_w, h, d)
+    return out.astype(q.dtype).reshape(b, s_w, h, dv)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
                     scale: Optional[float] = None, impl: str = "auto",
-                    interpret: bool = False, span: int = 0):
+                    interpret: bool = False, span: int = 0, sink=None):
     """Window attention against a paged KV pool.
 
-    See the module docstring for shapes and the mask contract.  Returns
-    ``[B, S, H, D]`` in q's dtype.  ``span`` > 0: a sliding layer, whose
-    ``block_tables`` is the ring table.  ``impl``: "auto" takes the Pallas
+    See the module docstring for shapes and the mask contract; pools of
+    lane-flat pages ``[n_pages, block_size, H_kv x width]`` (K and V
+    each of its own width) are taken too.  Returns ``[B, S, H, D_v]``
+    in q's dtype.  ``span`` > 0: a sliding layer, whose
+    ``block_tables`` is the ring table.  ``sink`` [H] float32: a
+    per-head logit in each row's softmax denominator, folded into the
+    kernel's last normalisation.  ``impl``: "auto" takes the Pallas
     kernel or the lax reference as ops/dispatch decides (the kernel on
     a TPU when :func:`supports` allows); "pallas"/"lax" force a path
     (tests drive the kernel on CPU with ``impl="pallas"``, which runs
@@ -385,15 +466,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         # tokens reaches one more unless the slack covers it
         raise ValueError(f"a decode window of {q.shape[1]} tokens does "
                          f"not fit a sliding layer's ring (span {span})")
-    mode = dispatch.choose(
-        supports(d, int(k_pool.shape[1]), int(k_pool.shape[2])), impl)
+    if k_pool.ndim == 3:
+        ok = flat_supports(int(k_pool.shape[2]), int(v_pool.shape[2]),
+                           int(k_pool.shape[1]))
+    else:
+        ok = supports(d, int(k_pool.shape[1]), int(k_pool.shape[2]))
+    mode = dispatch.choose(ok, impl)
     if mode != dispatch.LAX:
         return _walk_pool(
             q, k_pool, v_pool, block_tables, lengths, scale=float(scale),
             interpret=bool(interpret or mode == dispatch.INTERPRET),
-            span=int(span))
+            span=int(span), sink=sink)
     return _lax_paged_attention(q, k_pool, v_pool, block_tables, lengths,
-                                float(scale), int(span))
+                                float(scale), int(span), sink)
 
 
 # ---- latent attention: one row per token, shared by every head --------

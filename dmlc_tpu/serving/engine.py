@@ -143,7 +143,8 @@ _ZEROED_COUNTERS = tuple(
 ) + ("decode_d2h_bytes", "prefill_d2h_bytes", "kv_upload_bytes",
      "queue_wait_secs", "queue_wait_count", "latency_secs",
      "decode_steps_overlapped", "lookahead_discarded_tokens",
-     "prefills_deferred", "loop_kv_read_bytes", "moe_grouped_calls")
+     "prefills_deferred", "loop_kv_read_bytes", "moe_grouped_calls",
+     "attn_kv_read_bytes")
 
 
 class _DedupeTable:
@@ -459,6 +460,14 @@ class InferenceEngine:
             self._loop_kv_token_bytes = (
                 2 * cfg.loop_passes * cfg.n_layers * cfg.kv_heads
                 * cfg.head_dim * np.dtype(cfg.dtype).itemsize)
+        # the K/V bytes of one page in every attention layer of a pool
+        # group, by each pool's own page geometry ([layers, blocks,
+        # page...]): the full layers' pools, and the sliding layers'
+        itemsize = np.dtype(cfg.dtype).itemsize
+        self._kv_page_bytes = tuple(
+            sum(int(np.prod(shape)) // shape[1] for shape in shapes)
+            * itemsize
+            for shapes in (self.cache.pool_shapes, self.cache.sliding_shapes))
         self._prefill, self._decode = _jitted_programs(cfg.family)
         self._stop = threading.Event()
         self._draining = threading.Event()
@@ -1472,6 +1481,18 @@ class InferenceEngine:
             telemetry.inc("serving", "loop_kv_read_bytes",
                           self._loop_kv_token_bytes * float(
                               s_w * base_lens.sum() + b * s_w * (s_w + 1) // 2))
+        # the pages that hold the keys the rows attend: a row at length
+        # n reaches position n + S - 1, a sliding layer back to n - (W -
+        # 1) and no further
+        bs = self.cache.block_size
+        reach = -(-(base_lens + s_w) // bs)
+        full, ring = self._kv_page_bytes
+        read = full * float(reach.sum())
+        if self.cache.ring_blocks:
+            first = np.maximum(base_lens - (self.cache.sliding_window - 1),
+                               0) // bs
+            read += ring * float((reach - first).sum())
+        telemetry.inc("serving", "attn_kv_read_bytes", read)
         if self.cache.ring_blocks:
             # a row at length n attends its n keys and the token itself
             keys = base_lens + 1

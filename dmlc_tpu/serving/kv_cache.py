@@ -15,7 +15,9 @@ Pool layout (layer-major, mirroring the paged-attention kernel shapes):
     k_pool / v_pool : [n_layers, n_blocks, block_size, n_kv_heads, head_dim]
 
 (a page holds the K/V heads, which grouped-query attention has fewer of
-than query heads).  What the pools are is the model's to say (``pool_shapes``, from
+than query heads; where keys and values have widths of their own a page
+is lane-flat, ``[block_size, n_kv_heads x width]``, and each kind of
+layer has its own K/V heads).  What the pools are is the model's to say (``pool_shapes``, from
 ``TransformerConfig.kv_pool_shapes``): K and V heads as above, or under
 latent attention ONE pool of rows ``[rms(c_kv) | rope(k_pe)]`` with no
 head axis and no separate V, ``[n_layers, n_blocks, 576, block_size]``.

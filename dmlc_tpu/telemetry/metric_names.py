@@ -463,6 +463,10 @@ METRIC_NAMES = frozenset({
     # a looped model's decode steps: the K/V bytes of every attended
     # token in all passes' cache layers (registered at 0 for every model)
     "dmlc_serving_loop_kv_read_bytes",
+    # every decode step: the K/V bytes of the pages that hold the keys
+    # its rows' attention layers attend, by each pool's page geometry
+    # (registered at 0 for every model)
+    "dmlc_serving_attn_kv_read_bytes",
     "dmlc_serving_state_slots_in_use",
     "dmlc_serving_state_slots_total",
     # sliding-window layers' own pool and block table (the widened MHA

@@ -1482,7 +1482,9 @@ def test_prefill_overlap_share_reads_through_the_ratio_reader():
     entries = {m["name"]: m for m in bench["per_layer"]}
     mine, model = entries["prefill_overlap_share"], entries[
         "decode_overlap_share"]
-    assert bench["per_layer"][-1] is mine
+    # appended last when it came, and kept in that place since: later
+    # metrics are appended after it
+    assert bench["per_layer"][68] is mine
     assert dict(mine, name="decode_overlap_share") == model
     serve = [w["name"] for w in bench["workloads"]
              if w["name"].startswith("serve-")]

@@ -111,8 +111,9 @@ def test_entries_list_the_cells_the_issue_names():
     bench, entries = _entries()
     cells = [w["name"] for w in bench["workloads"]]
     serve = [c for c in cells if c.startswith("serve-")]
-    # two catalog serve cells came after these metrics
-    assert len(cells) == 9 and len(serve) == 7
+    # two catalog serve cells came after these metrics, then a third
+    # and a train cell
+    assert len(cells) == 11 and len(serve) == 8
     for name in SETUP:
         m = entries[name]
         assert (m["layer"], m["moves"], m["better"]) \
